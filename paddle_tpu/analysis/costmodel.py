@@ -38,10 +38,29 @@ import numpy as np
 from ..core import registry
 from ..core.registry import get_op, has_op
 
-# v5e-class chip constants (PERF.md "Roofline position"): bf16 peak and
-# HBM stream bandwidth; the ridge point is their ratio (~240 FLOP/byte).
-V5E_PEAK_FLOPS = 197e12
-V5E_HBM_BW = 819e9
+# Published per-chip peaks, keyed by the ``device_kind`` jax reports:
+# (dense bf16 FLOP/s, HBM bytes/s). Source: Google Cloud documentation,
+# "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s; ridge ~240 FLOP/byte. THE one
+# table: the live MFU gauge (trace/goodput.py) and bench.py resolve the
+# running device through it, and a kind that is not here has no peak —
+# add the row with its source rather than defaulting.
+DEVICE_PEAKS = {
+    "TPU v5 lite": (197e12, 819e9),
+}
+# the static analyzer models a NAMED v5e whatever device runs the analysis
+V5E_PEAK_FLOPS, V5E_HBM_BW = DEVICE_PEAKS["TPU v5 lite"]
+
+
+def device_peaks(device_kind: str):
+    """(peak bf16 FLOP/s, HBM bytes/s) of ``device_kind``; KeyError naming
+    the kind when it is not in :data:`DEVICE_PEAKS`."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peak for device_kind {device_kind!r} "
+            f"(known: {sorted(DEVICE_PEAKS)}); add it to "
+            f"analysis.costmodel.DEVICE_PEAKS with its source") from None
 
 
 @dataclasses.dataclass

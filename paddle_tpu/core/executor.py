@@ -45,11 +45,7 @@ logger = logging.getLogger("paddle_tpu")
 # program+seed. The partitionable implementation is invariant to
 # sharding, which is the whole reproducibility contract of the one
 # sharding plane: dp/tp runs must match their single-device reference.
-# (No-op on jax versions where partitionable is already the default.)
-try:
-    jax.config.update("jax_threefry_partitionable", True)
-except AttributeError:  # flag retired: partitionable is the only mode
-    pass
+jax.config.update("jax_threefry_partitionable", True)
 
 
 class TPUPlace:
@@ -138,29 +134,28 @@ _cache_enabled = False
 
 
 def _pc_enabled() -> bool:
-    """Is a persistent (on-disk) compilation cache active? Covers both
-    the --compilation_cache_dir wiring below and a jax config set by the
-    embedding application."""
-    if _cache_enabled:
-        return True
-    try:
-        return bool(jax.config.jax_compilation_cache_dir)
-    except AttributeError:
-        return False
+    """Is a persistent (on-disk) compilation cache active? Covers the
+    wiring below, ``$JAX_COMPILATION_CACHE_DIR`` and a jax config set by
+    the embedding application."""
+    return _cache_enabled or bool(jax.config.jax_compilation_cache_dir)
 
 
 def reset_compilation_cache() -> None:
     """Unwire the persistent compilation cache (tests / re-pointing the
-    dir mid-process): the next Executor constructed re-reads
-    --compilation_cache_dir and re-initialises the cache there."""
+    dir mid-process): the next compile re-resolves
+    ``xla_env.compilation_cache_dir()`` and re-initialises the cache
+    there. A directory placed by ``$JAX_COMPILATION_CACHE_DIR`` is never
+    cleared — the environment owns it."""
     global _cache_enabled
-    jax.config.update("jax_compilation_cache_dir", None)
-    try:
-        from jax._src.compilation_cache import reset_cache
+    import os
 
-        reset_cache()
-    except Exception:  # cache never initialised / private API moved
-        pass
+    from jax._src.compilation_cache import reset_cache
+
+    from ..xla_env import CACHE_DIR_ENV
+
+    if not os.environ.get(CACHE_DIR_ENV):
+        jax.config.update("jax_compilation_cache_dir", None)
+    reset_cache()
     _cache_enabled = False
 
 
@@ -187,12 +182,9 @@ def _ensure_cache_listener() -> None:
     if _pc_listener_on:
         return
     _pc_listener_on = True
-    try:
-        from jax._src import monitoring
+    from jax._src import monitoring
 
-        monitoring.register_event_listener(_on_jax_compile_event)
-    except Exception:  # monitoring API moved: every compile reads 'fresh'
-        pass
+    monitoring.register_event_listener(_on_jax_compile_event)
 
 
 @contextlib.contextmanager
@@ -206,154 +198,39 @@ def _compile_window():
         _pc_local.window = prev
 
 
-# ---------------------------------------------------------------------------
-# Donation verdict for cache-restored executables. Known defect (was
-# tests/conftest.py's suite-wide workaround): on some jaxlibs, CPU
-# executables DESERIALIZED from the persistent cache mishandle
-# donated/aliased buffers — a training step that donates state reads freed
-# memory and NaNs the model. The first execution of a restored donating
-# executable is therefore verified against its no-donation twin
-# (Executor._first_restored_donating_call); the verdict is memoized
-# in-process and persisted into the cache dir so a fleet pays the check
-# once per backend, not once per boot.
-# ---------------------------------------------------------------------------
-_donation_verdicts: Dict[str, str] = {}
-_verdict_lock = threading.Lock()
-DONATION_VERDICT_NAME = "donation_verify.json"
-
-# Platforms whose RESTORED executables are known to corrupt donated
-# buffers. On CPU this is witnessed as use-after-free: NaN'd training
-# state, and (allocation-pattern-dependent) glibc heap aborts — so the
-# probe itself is unsafe and restored donating executables are routed to
-# their no-donation twin WITHOUT ever executing the donated form. Other
-# platforms verify once on first execution and persist the verdict.
-_RESTORED_DONATION_DENYLIST = ("cpu",)
-_denylist_logged = False
-
-
-def _verdict_key(platform: str) -> str:
-    return f"{platform}/jax-{jax.__version__}"
-
-
-def _verdict_path() -> Optional[str]:
-    import os
-
-    from ..flags import FLAGS
-
-    d = FLAGS.compilation_cache_dir
-    if not d:
-        try:
-            d = jax.config.jax_compilation_cache_dir
-        except AttributeError:
-            d = None
-    if not d:
-        return None
-    return os.path.join(d, DONATION_VERDICT_NAME)
-
-
-def _read_donation_verdict(platform: str) -> Optional[str]:
-    """'ok' | 'broken' | None (never verified on this backend)."""
-    import json
-    import os
-
-    key = _verdict_key(platform)
-    with _verdict_lock:
-        if key in _donation_verdicts:
-            return _donation_verdicts[key]
-        path = _verdict_path()
-        if path and os.path.exists(path):
-            try:
-                with open(path) as f:
-                    verdict = json.load(f).get(key)
-            except (OSError, ValueError):
-                verdict = None
-            if verdict in ("ok", "broken"):
-                _donation_verdicts[key] = verdict
-                return verdict
-        return None
-
-
-def _write_donation_verdict(platform: str, verdict: str) -> None:
-    import json
-    import os
-
-    key = _verdict_key(platform)
-    with _verdict_lock:
-        _donation_verdicts[key] = verdict
-        path = _verdict_path()
-        if path is None:
-            return
-        data = {}
-        try:
-            if os.path.exists(path):
-                with open(path) as f:
-                    data = json.load(f)
-        except (OSError, ValueError):
-            data = {}
-        data[key] = verdict
-        tmp = path + f".tmp{os.getpid()}"
-        try:
-            with open(tmp, "w") as f:
-                json.dump(data, f)
-            os.replace(tmp, path)
-        except OSError:
-            pass  # read-only cache volume: the in-process memo still holds
-
-
-def _values_close(test, ref) -> bool:
-    """Donation write-back comparison: the two executables run the same
-    HLO, so honest outputs agree to float noise — corruption shows up as
-    garbage/NaN, not as a rounding delta."""
-    xs = jax.tree_util.tree_leaves(test)
-    ys = jax.tree_util.tree_leaves(ref)
-    if len(xs) != len(ys):
-        return False
-    for x, y in zip(xs, ys):
-        x, y = np.asarray(x), np.asarray(y)
-        if x.shape != y.shape or x.dtype != y.dtype:
-            return False
-        if x.dtype.kind in "iub":
-            if not np.array_equal(x, y):
-                return False
-        else:
-            xf = x.astype(np.float32) if x.dtype.kind not in "fc" else x
-            yf = y.astype(np.float32) if y.dtype.kind not in "fc" else y
-            if not np.allclose(xf, yf, rtol=1e-4, atol=1e-6,
-                               equal_nan=True):
-                return False
-    return True
-
-
 def _maybe_enable_compilation_cache() -> None:
-    """Wire --compilation_cache_dir into jax's persistent compilation
-    cache (once per process): repeat runs of the same program skip the
-    first-compile latency entirely — the whole-block-compile design's
-    answer to the reference's kernel warmup costs."""
+    """Wire jax's persistent compilation cache (once per process) at the
+    directory ``xla_env.compilation_cache_dir()`` resolves: repeat runs of
+    the same program skip the first-compile latency entirely — the
+    whole-block-compile design's answer to the reference's kernel warmup
+    costs. Runs before the first compile (not at Executor construction),
+    because the TPU default needs the backend's platform."""
     global _cache_enabled
     if _cache_enabled:
         return
-    from ..flags import FLAGS
-
-    d = FLAGS.compilation_cache_dir
-    if not d:
-        return
     import os
 
-    os.makedirs(d, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", d)
-    # cache every compile, however small/fast
+    from ..xla_env import CACHE_DIR_ENV, compilation_cache_dir
+
+    d = compilation_cache_dir()
+    if not d:
+        return
+    # cache every compile, however small/fast: a startup block that
+    # recompiles on the warm run breaks the zero-fresh-compile boot
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    # jax initialises the persistent cache once, on the first compile:
-    # if anything compiled before this flag was read (or a different
-    # cache dir was active), the dir change would silently not take —
-    # drop the initialised cache so the next compile re-inits at ``d``
-    try:
+    if not os.environ.get(CACHE_DIR_ENV):
+        # jax read the env var itself at import; only the flag / TPU
+        # default route sets the directory here
+        os.makedirs(d, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", d)
+        # jax initialises the persistent cache once, on the first
+        # compile: if anything compiled before this point the dir change
+        # would silently not take — drop the initialised cache so the
+        # next compile re-inits at ``d``
         from jax._src.compilation_cache import reset_cache
 
         reset_cache()
-    except Exception:  # cache never initialised / private API moved
-        pass
     _cache_enabled = True
 
 
@@ -363,25 +240,20 @@ class _Compiled:
     ``fn`` is the jitted callable; ``aot`` is its eagerly-compiled XLA
     executable (``.lower().compile()``), built under a classification
     window so ``source`` says whether it was a fresh compile or a
-    persistent-cache (disk) restore. Donating entries restored from disk
-    stay quarantined (``donation_checked=False``) until their first
-    execution verifies donated write-back against the no-donation twin
-    (``safe_aot``); a failed verdict flips ``use_safe`` permanently.
-    ``jit_fallback`` routes everything through plain jit dispatch when
-    the AOT plane rejects an entry (exotic pytrees, aval drift)."""
+    persistent-cache (disk) restore. Every execution goes through
+    ``aot``: an executable that rejects its arguments (aval, device or
+    sharding drift) raises — there is no quiet jit re-dispatch."""
 
-    __slots__ = ("fn", "raw_fn", "make_jit", "feed_names", "ro_state_names",
+    __slots__ = ("fn", "raw_fn", "feed_names", "ro_state_names",
                  "rw_state_names", "out_state_names", "uses_rng",
                  "feed_shardings", "ro_shardings", "rw_shardings",
-                 "aot", "safe_aot", "safe_fn", "source", "donation_checked",
-                 "use_safe", "jit_fallback")
+                 "aot", "source")
 
     def __init__(self, fn, raw_fn, feed_names, ro_state_names, rw_state_names,
                  out_state_names, uses_rng, feed_shardings=None,
-                 ro_shardings=None, rw_shardings=None, make_jit=None):
+                 ro_shardings=None, rw_shardings=None):
         self.fn = fn
         self.raw_fn = raw_fn
-        self.make_jit = make_jit
         self.feed_names = feed_names
         self.ro_state_names = ro_state_names
         self.rw_state_names = rw_state_names
@@ -391,12 +263,7 @@ class _Compiled:
         self.ro_shardings = ro_shardings
         self.rw_shardings = rw_shardings
         self.aot = None
-        self.safe_aot = None
-        self.safe_fn = None
         self.source = None
-        self.donation_checked = False
-        self.use_safe = False
-        self.jit_fallback = False
 
 
 class RunHandle:
@@ -481,9 +348,9 @@ class Executor:
         """
         from ..flags import FLAGS
 
-        _maybe_enable_compilation_cache()
         _ensure_cache_listener()
         self.place = place or TPUPlace(0)
+        self._device = None  # resolved lazily: see device()
         self.check_nan_inf = (FLAGS.check_nan_inf if check_nan_inf is None
                               else check_nan_inf)
         if mesh is None and plan is not None:
@@ -498,14 +365,13 @@ class Executor:
         # Compile-cache observability (the serving warm-path contract:
         # after warmup a steady-state server shows hits only). Counts
         # in-process (program, signature) cache lookups; misses further
-        # classify into persistent_hits (executable restored from
-        # --compilation_cache_dir) vs fresh_compiles (paid XLA compile) —
-        # the cold-start A/B dimension bench_cold_start pins.
+        # classify into persistent_hits (executable restored from the
+        # persistent cache directory) vs fresh_compiles (paid XLA
+        # compile) — the cold-vs-warm boot dimension.
         self.cache_hits = 0
         self.cache_misses = 0
         self.persistent_hits = 0
         self.fresh_compiles = 0
-        self.donation_fallbacks = 0
         # cumulative seconds inside ``.lower().compile()``, split by
         # source — the goodput plane's fresh_compile bucket deltas
         # fresh_compile_seconds around each run to re-attribute compile
@@ -520,15 +386,34 @@ class Executor:
 
     def cache_stats(self) -> Dict[str, int]:
         """{'hits', 'misses', 'entries', 'persistent_hits',
-        'fresh_compiles', 'donation_fallbacks'} of the (program, shapes)
-        -> compiled-executable cache. ``misses`` split into disk restores
-        (persistent_hits) and real compiles (fresh_compiles); a
-        manifest+cache-warm boot shows fresh_compiles == 0."""
+        'fresh_compiles'} of the (program, shapes) -> compiled-executable
+        cache. ``misses`` split into disk restores (persistent_hits) and
+        real compiles (fresh_compiles); a manifest+cache-warm boot shows
+        fresh_compiles == 0."""
         return {"hits": self.cache_hits, "misses": self.cache_misses,
                 "entries": len(self._cache),
                 "persistent_hits": self.persistent_hits,
-                "fresh_compiles": self.fresh_compiles,
-                "donation_fallbacks": self.donation_fallbacks}
+                "fresh_compiles": self.fresh_compiles}
+
+    def device(self):
+        """The device this executor computes on when no mesh is in play
+        (``place.device()``, resolved once)."""
+        if self._device is None:
+            self._device = self.place.device()
+        return self._device
+
+    def device_ctx(self, program: Optional[Program] = None):
+        """Context under which single-device work compiles, executes and
+        allocates: ``jax.default_device(self.device())`` — uncommitted
+        inputs (numpy feeds, fresh ``jnp`` state) land on THIS executor's
+        device, so ``Executor(TPUPlace(i))`` computes on chip i. Mesh runs
+        (the executor's own mesh, or ``program``'s sharding plan) place by
+        sharding instead."""
+        mesh = self.mesh if program is None \
+            else self._mesh_plan_for(program)[0]
+        if mesh is not None:
+            return contextlib.nullcontext()
+        return jax.default_device(self.device())
 
     # ------------------------------------------------------------------
     def run(
@@ -558,8 +443,10 @@ class Executor:
 
         level = trace.active_level() if trace_level is None else trace_level
         if level >= 2 and self._mesh_plan_for(program)[0] is None:
-            return self._run_interpreted(program, feed_vals, fetch_names,
-                                         scope, return_numpy)
+            with self.device_ctx(program):
+                return self._run_interpreted(program, feed_vals,
+                                             fetch_names, scope,
+                                             return_numpy)
 
         key = self._cache_key(program, feed_vals, fetch_names, scope)
         compiled = self._cache.get(key)
@@ -616,8 +503,10 @@ class Executor:
 
         level = trace.active_level() if trace_level is None else trace_level
         if level >= 2 and self._mesh_plan_for(program)[0] is None:
-            outs = self._run_interpreted(program, feed_vals, fetch_names,
-                                         scope, return_numpy=False)
+            with self.device_ctx(program):
+                outs = self._run_interpreted(program, feed_vals,
+                                             fetch_names, scope,
+                                             return_numpy=False)
             return RunHandle(outs, fetch_names,
                              check_nan_inf=self.check_nan_inf)
 
@@ -683,16 +572,14 @@ class Executor:
                        for a, s in zip(ro_args, compiled.ro_shardings)]
             rw_args = [self._put(a, s)
                        for a, s in zip(rw_args, compiled.rw_shardings)]
-        rng = self._rng_state(program, scope) if compiled.uses_rng else None
-        if compiled.aot is None and not compiled.jit_fallback:
-            # entry compiled lazily (as_function path): classify before
-            # the first execution so a restored donating executable never
-            # touches real state unverified
-            self._finish_compile(compiled, feed_vals, scope, program)
-        if not compiled.donation_checked:
-            return self._first_restored_donating_call(
-                compiled, feed_args, ro_args, rw_args, rng)
-        out = self._invoke(compiled, feed_args, ro_args, rw_args, rng)
+        with self.device_ctx(program):
+            rng = self._rng_state(program, scope) if compiled.uses_rng \
+                else None
+            if compiled.aot is None:
+                # entry compiled lazily (as_function path)
+                self._finish_compile(compiled, feed_vals, scope, program)
+            tail = (rng,) if rng is not None else ()
+            out = compiled.aot(feed_args, ro_args, rw_args, *tail)
         return self._unpack(compiled, out)
 
     @staticmethod
@@ -703,44 +590,7 @@ class Executor:
         fetches, new_states = out
         return fetches, new_states, None
 
-    def _invoke(self, compiled: "_Compiled", feed_args, ro_args, rw_args,
-                rng):
-        """Call through the AOT executable (the steady-state fast path);
-        an argument-layout rejection falls back to jit dispatch
-        permanently for this entry."""
-        tail = (rng,) if rng is not None else ()
-        if compiled.use_safe:
-            fn = compiled.safe_aot
-            if fn is None:
-                if compiled.safe_fn is None:
-                    compiled.safe_fn = compiled.make_jit(False)
-                fn = compiled.safe_fn
-        else:
-            fn = compiled.aot if compiled.aot is not None else compiled.fn
-        try:
-            return fn(feed_args, ro_args, rw_args, *tail)
-        except (TypeError, ValueError) as exc:
-            if fn is compiled.fn or fn is compiled.safe_fn:
-                raise
-            # AOT executables pin exact avals; drift (weak types, exotic
-            # pytrees) reroutes through jit, which retraces as needed
-            logger.warning(
-                "AOT executable rejected the call (%s); falling back to "
-                "jit dispatch for this signature", exc)
-            compiled.aot = compiled.safe_aot = None
-            compiled.jit_fallback = True
-            if compiled.use_safe:
-                compiled.safe_fn = compiled.make_jit(False)
-                return compiled.safe_fn(feed_args, ro_args, rw_args, *tail)
-            return compiled.fn(feed_args, ro_args, rw_args, *tail)
-
-    # -- cold-start plane: AOT compile, classification, donation guard ---
-    def _platform(self) -> str:
-        try:
-            return self.place.device().platform
-        except Exception:  # noqa: BLE001 - backend probing must not fail
-            return jax.default_backend()
-
+    # -- cold-start plane: AOT compile + source classification ------------
     @staticmethod
     def _aval_like(x):
         """Shape/dtype skeleton for AOT lowering (no data touched)."""
@@ -769,6 +619,7 @@ class Executor:
         (executable, restored_from_disk) and bumps the source counters."""
         from .. import profiler
 
+        _maybe_enable_compilation_cache()
         t0 = time.perf_counter()
         with _compile_window() as window:
             executable = jitted.lower(*args).compile()
@@ -790,131 +641,35 @@ class Executor:
     def _finish_compile(self, compiled: "_Compiled", feed_vals,
                         scope: Scope, program: Program, span=None) -> None:
         """Compile the entry's executable NOW (ahead of execution) and
-        classify its source. Donating entries restored from the
-        persistent cache get their no-donation twin compiled alongside
-        and stay quarantined until the first execution verifies donated
-        write-back; fresh donating compiles pre-populate the twin's disk
-        entry so later boots verify without a fresh compile."""
-        from ..flags import FLAGS
-
-        if compiled.aot is not None or compiled.jit_fallback:
+        classify its source. A compile failure propagates: the jit
+        dispatch it used to degrade to would hit the same compiler."""
+        if compiled.aot is not None:
             return
-        args = self._aval_args(compiled, feed_vals, scope, program)
-        try:
+        if compiled.feed_shardings is None:
+            self._check_state_device(compiled, scope)
+        with self.device_ctx(program):
+            args = self._aval_args(compiled, feed_vals, scope, program)
             compiled.aot, restored = self._aot_compile(compiled.fn, args)
-        except Exception as exc:  # noqa: BLE001 - AOT is an optimisation
-            logger.warning("AOT compile failed (%s); using jit dispatch "
-                           "for this signature", exc)
-            compiled.jit_fallback = True
-            compiled.source = "fresh"
-            compiled.donation_checked = True
-            return
         compiled.source = "persistent" if restored else "fresh"
         if span is not None:
             span.set_attr("source", compiled.source)
-        if not compiled.rw_state_names:
-            compiled.donation_checked = True  # nothing donated
-            return
-        platform = self._platform()
-        verdict = _read_donation_verdict(platform)
-        if verdict is None and platform in _RESTORED_DONATION_DENYLIST:
-            # witnessed heap corruption: never probe, go straight to the
-            # twin (the conftest-documented NaN bug, now handled here)
-            verdict = "broken"
-        if not restored or not FLAGS.verify_restored_donation:
-            # freshly-built executables handle donation correctly; with a
-            # persistent cache active, also land the no-donation twin on
-            # disk (unless this backend's restores are known-good) so a
-            # future boot's verification/fallback is never a fresh compile
-            compiled.donation_checked = True
-            if restored or not _pc_enabled() or verdict == "ok":
-                return
-            try:
-                self._aot_compile(compiled.make_jit(False), args)
-            except Exception:  # noqa: BLE001 - best-effort prewarm
-                pass
-            return
-        if verdict == "ok":
-            compiled.donation_checked = True
-            return
-        try:
-            compiled.safe_aot, _ = self._aot_compile(
-                compiled.make_jit(False), args)
-        except Exception as exc:  # noqa: BLE001
-            logger.warning(
-                "no-donation twin failed to compile (%s); restored "
-                "executable runs unverified", exc)
-            compiled.donation_checked = True
-            return
-        if verdict == "broken":
-            global _denylist_logged
 
-            compiled.use_safe = True
-            compiled.donation_checked = True
-            self.donation_fallbacks += 1
-            from .. import profiler
-
-            profiler.global_stat.add_count(
-                "executor/compile_cache/donation_fallback", 1)
-            if not _denylist_logged:
-                _denylist_logged = True
-                logger.warning(
-                    "executables restored from the persistent compilation "
-                    "cache mishandle donated buffers on %s; cache-restored "
-                    "steps run their no-donation twin (bit-identical "
-                    "results, one extra state copy per step)", platform)
-        # verdict unknown: donation_checked stays False — the first
-        # execution runs _first_restored_donating_call
-
-    def _first_restored_donating_call(self, compiled: "_Compiled",
-                                      feed_args, ro_args, rw_args, rng):
-        """First execution of a disk-restored executable that donates
-        state: run the no-donation twin on the REAL state (reference;
-        nothing donated, nothing at risk) and the restored donated
-        executable on disposable copies, compare the written-back state,
-        and persist the verdict. A mismatch — the known CPU jaxlib defect
-        where deserialized executables read freed donated buffers —
-        permanently reroutes this entry through the twin; the reference
-        results are returned either way, so even the probing step is
-        correct."""
-        from .. import profiler
-
-        tail = (rng,) if rng is not None else ()
-        ref = compiled.safe_aot(feed_args, ro_args, rw_args, *tail)
-        copies = [self._device_copy(a) for a in rw_args]
-        test = None
-        try:
-            test = compiled.aot(feed_args, ro_args, copies, *tail)
-        except Exception as exc:  # noqa: BLE001 - crash == broken
-            logger.warning("restored donating executable failed its "
-                           "verification run: %s", exc)
-        broken = test is None or not _values_close(test, ref)
-        compiled.donation_checked = True
-        platform = self._platform()
-        if broken:
-            compiled.use_safe = True
-            self.donation_fallbacks += 1
-            profiler.global_stat.add_count(
-                "executor/compile_cache/donation_fallback", 1)
-            logger.warning(
-                "executables restored from the persistent compilation "
-                "cache mishandle donated buffers on %s; donation disabled "
-                "for cache-restored executables (no-donation twin in use)",
-                platform)
-        _write_donation_verdict(platform, "broken" if broken else "ok")
-        trace.record("executor/donation_verify", time.perf_counter(),
-                     time.perf_counter(), platform=platform,
-                     verdict="broken" if broken else "ok")
-        return self._unpack(compiled, ref)
-
-    @staticmethod
-    def _device_copy(a):
-        """Fresh buffer for a donation probe (np inputs are transferred
-        into a new device buffer by the call itself — only live device
-        arrays need protecting)."""
-        if isinstance(a, jax.Array):
-            return jnp.array(a)
-        return a
+    def _check_state_device(self, compiled: "_Compiled",
+                            scope: Scope) -> None:
+        """Single-device entries compile for ``self.device()``; state
+        committed elsewhere would be rejected by the executable (or, if
+        uncommitted, silently copied across on every call — read-only
+        state is never written back). Checked once per entry, here."""
+        want = {self.device()}
+        for name in compiled.ro_state_names + compiled.rw_state_names:
+            val = scope.get(name)
+            if isinstance(val, jax.Array) and val.devices() != want:
+                raise ValueError(
+                    f"state variable {name!r} lives on "
+                    f"{sorted(str(d) for d in val.devices())} but this "
+                    f"executor ({self.place!r}) computes on "
+                    f"{self.device()}; load or device_put it there (or run "
+                    f"under the mesh/plan that sharded it)")
 
     def _record_signature(self, program: Program, feed_vals,
                           fetch_names) -> None:
@@ -951,12 +706,12 @@ class Executor:
         if any(op_uses_rng(get_op(op.type), op.attrs) for op in block.ops):
             # seed the scope RNG plane BEFORE keying, so the scope key set
             # matches live traffic (the GenerationEngine.warmup contract)
-            self._rng_state(program, scope)
+            with self.device_ctx(program):
+                self._rng_state(program, scope)
         key = self._cache_key(program, feed_vals, fetch_names, scope)
         compiled = self._cache.get(key)
         if compiled is not None:
-            if compiled.aot is None and not compiled.jit_fallback:
-                self._finish_compile(compiled, feed_vals, scope, program)
+            self._finish_compile(compiled, feed_vals, scope, program)
             return False
         self.cache_misses += 1
         with trace.span("executor/compile", cache="miss", mode="aot_warm",
@@ -1314,7 +1069,7 @@ class Executor:
         def run_traced(feed_args, ro_args, rw_args, rng=None):
             from ..parallel.context import mesh_context
 
-            with mesh_context(mesh):
+            with mesh_context(mesh, plan.data_axis if plan else None):
                 return _run_body(feed_args, ro_args, rw_args, rng)
 
         def _run_body(feed_args, ro_args, rw_args, rng=None):
@@ -1423,23 +1178,17 @@ class Executor:
                 in_shardings = in_shardings + (replicated,)
                 out_shardings = out_shardings + (replicated,)
 
-            def make_jit(donate: bool = True):
-                return jax.jit(run_traced,
-                               donate_argnums=(2,) if donate else (),
-                               in_shardings=in_shardings,
-                               out_shardings=out_shardings)
+            jitted = jax.jit(run_traced, donate_argnums=(2,),
+                             in_shardings=in_shardings,
+                             out_shardings=out_shardings)
         else:
-            def make_jit(donate: bool = True):
-                return jax.jit(run_traced,
-                               donate_argnums=(2,) if donate else ())
-        jitted = make_jit(True)
+            jitted = jax.jit(run_traced, donate_argnums=(2,))
         logger.debug(
             "compiled block: %d ops, %d feeds, %d state vars, %d outputs",
             len(ops), len(feed_names), len(state_names), len(fetch_names),
         )
         return _Compiled(jitted, run_traced, feed_names, ro_state, rw_state,
-                         written_persist, uses_rng, feed_sh, ro_sh, rw_sh,
-                         make_jit=make_jit)
+                         written_persist, uses_rng, feed_sh, ro_sh, rw_sh)
 
     def close(self):
         self._cache.clear()

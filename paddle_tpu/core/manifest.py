@@ -188,8 +188,8 @@ def try_load(dirname: str,
 
 
 def replay(executor, programs, scope=None, manifest=None,
-           dirname: Optional[str] = None, max_workers: Optional[int] = None,
-           device_ctx=None) -> dict:
+           dirname: Optional[str] = None,
+           max_workers: Optional[int] = None) -> dict:
     """AOT-compile every manifest signature that matches one of
     ``programs`` — ``Executor.warm_signature`` per record, fanned out over
     a thread pool (XLA compilation releases the GIL, so this is real
@@ -221,16 +221,11 @@ def replay(executor, programs, scope=None, manifest=None,
             max_workers = 4
 
     def one(job):
-        import contextlib
-
         prog, sig = job
         feeds = {n: (tuple(s), dt) for n, s, dt in
                  (tuple(f) for f in sig["feeds"])}
-        ctx = device_ctx() if device_ctx is not None \
-            else contextlib.nullcontext()
-        with ctx:
-            return executor.warm_signature(prog, feeds, sig["fetches"],
-                                           scope=scope)
+        return executor.warm_signature(prog, feeds, sig["fetches"],
+                                       scope=scope)
 
     t0 = time.perf_counter()
     if len(jobs) > 1 and max_workers > 1:

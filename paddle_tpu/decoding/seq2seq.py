@@ -129,8 +129,9 @@ class Seq2SeqGenerationEngine(PagedGenerationEngine):
         # row `slots` is the scrap row (vacant decode slots attend it)
         shape = (s.n_layers, self.slots + 1, s.kv_heads, s.max_src_len,
                  s.head_dim)
-        self.scope.set(CROSS_K, jnp.zeros(shape, jnp.float32))
-        self.scope.set(CROSS_V, jnp.zeros(shape, jnp.float32))
+        with self.executor.device_ctx():
+            self.scope.set(CROSS_K, jnp.zeros(shape, jnp.float32))
+            self.scope.set(CROSS_V, jnp.zeros(shape, jnp.float32))
         # host-side cross-row accounting: a request takes one row at
         # admission; beam forks share it by refcount
         self._xrow_free = list(range(self.slots - 1, -1, -1))
@@ -378,7 +379,7 @@ class Seq2SeqGenerationEngine(PagedGenerationEngine):
             feed["serving.src_n"][i] = src.size
             feed["serving.src_row"][i] = row
         t0 = time.perf_counter()
-        with self._device_ctx(), profiler.timer("serving/encode"), \
+        with profiler.timer("serving/encode"), \
                 trace.span("serving/encode", batch=len(items),
                            bucket=ts, padded=nb):
             self.executor.run(prog, feed=feed, fetch_list=[ok],
@@ -467,9 +468,8 @@ class Seq2SeqGenerationEngine(PagedGenerationEngine):
                         "serving.src_n": np.ones(nb, np.int32),
                         "serving.src_row": np.full(nb, self.slots,
                                                    np.int32)}
-                with self._device_ctx():
-                    self.executor.run(prog, feed=feed, fetch_list=[ok],
-                                      scope=self.scope)
+                self.executor.run(prog, feed=feed, fetch_list=[ok],
+                                  scope=self.scope)
                 combos += 1
         self.metrics.inc("warmup_compiles",
                          len(self.src_buckets)

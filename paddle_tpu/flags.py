@@ -179,21 +179,17 @@ define_bool("fused_conv_epilogue", False,
             "models as the fused conv1x1_bn_act op (Pallas forward that "
             "computes BN stats in the conv pass and folds the epilogue "
             "into the output tile; ops/fusion_ops.py). Default off until "
-            "the chip A/B lands (tools/chip_session_r5.py)")
+            "the chip A/B lands (ROADMAP S6/D6). On a TPU a layer whose "
+            "shape has no VMEM tile raises instead of running unfused")
 define_string("compilation_cache_dir", "",
               "persist XLA compilations here (jax persistent cache): "
               "repeat runs of the same program skip the 20-40s "
-              "first-compile; empty = in-memory only. Pair with a "
-              "warmup manifest (core.manifest / tools/warmup.py) for "
+              "first-compile. $JAX_COMPILATION_CACHE_DIR, when set, wins "
+              "over this flag; with neither, a TPU process caches in "
+              "<repo>/.jax_cache and a CPU process stays in-memory "
+              "(xla_env.compilation_cache_dir). Pair with a warmup "
+              "manifest (core.manifest / tools/warmup.py) for "
               "zero-fresh-compile boots")
-define_bool("verify_restored_donation", True,
-            "verify donated-state write-back the first time an "
-            "executable RESTORED from --compilation_cache_dir executes "
-            "(vs its no-donation twin), falling back to the twin on "
-            "mismatch — guards the jaxlib defect where deserialized CPU "
-            "executables read freed donated buffers and NaN training "
-            "state; the verdict persists in the cache dir so a fleet "
-            "pays the check once per backend")
 define_int32("warmup_concurrency", 4,
              "thread-pool width for AOT manifest replay "
              "(core.manifest.replay): XLA compilation is host-side and "

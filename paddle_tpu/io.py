@@ -152,7 +152,10 @@ def load_vars(executor, dirname, main_program=None, vars=None, predicate=None,
             import ml_dtypes  # noqa: F401 — registers bfloat16/fp8 names
 
             arr = arr.view(np.dtype(entry["dtype"]))
-        scope.set(v.name, jnp.asarray(arr))
+        # values land on the loading executor's device (mesh executors
+        # reshard at the first step)
+        with executor.device_ctx():
+            scope.set(v.name, jnp.asarray(arr))
 
 
 def load_params(executor, dirname, main_program=None, scope=None):

@@ -21,8 +21,10 @@ reduction. These kernels attack the structure directly:
   are known up front, so the affine+act+residual ride in the dot
   kernel's output tile and the raw conv output NEVER touches HBM.
 
-Everything falls back to plain XLA ops when shapes don't tile or the
-backend is not TPU (CPU tests run the pallas path in interpret mode).
+Off the chip (CPU tests) the kernels run in interpret mode, or as the
+plain XLA composition when a shape has no tile. ON the chip a shape with
+no tile inside the VMEM budget raises, naming the shape: a fused op that
+was asked for never quietly becomes the unfused one.
 The backward stays XLA: the fused-linear-backward tombstone (PERF.md)
 showed hand-written backward contractions lose under the 16 MB
 scoped-vmem limit; forward epilogue fusion does not fight that wall
@@ -54,6 +56,23 @@ def _pick_block_r(R: int, I: int, O: int, itemsize: int) -> int:
     return 0
 
 
+def _use_pallas(kernel: str, block_r: int, shape, dtype,
+                interpret: bool) -> bool:
+    """Whether the Pallas path runs (else the plain XLA composition). On
+    TPU a shape without a tile is an error naming it; off the chip the
+    kernel runs only in interpret mode."""
+    on_tpu = jax.default_backend() == "tpu"
+    if block_r == 0:
+        if on_tpu:
+            raise ValueError(
+                f"{kernel}: no row tile (128..2048 rows dividing the row "
+                f"count) of {tuple(shape)} {jnp.dtype(dtype).name} fits "
+                f"the {_VMEM_BUDGET >> 20} MiB VMEM budget; use the "
+                f"unfused conv2d + batch_norm ops for this layer")
+        return False
+    return on_tpu or interpret
+
+
 def _stats_kernel(x_ref, w_ref, y_ref, stat_ref, acc_ref, *, nsteps,
                   precision):
     step = pl.program_id(0)
@@ -78,13 +97,13 @@ def conv1x1_stats(x2, w, precision=None, interpret=False):
     """y_raw = x2 @ w plus per-channel (sum, sumsq) in one pass.
 
     x2: [R, I]; w: [I, O]. Returns (y_raw [R, O] in x2.dtype,
-    stats [2, O] f32). Falls back to XLA when the shape doesn't tile.
+    stats [2, O] f32).
     """
     R, I = x2.shape
     O = w.shape[1]
     block_r = _pick_block_r(R, I, O, x2.dtype.itemsize)
-    on_tpu = jax.default_backend() == "tpu"
-    if block_r == 0 or not (on_tpu or interpret):
+    if not _use_pallas("conv1x1_stats", block_r, (R, I, O), x2.dtype,
+                       interpret):
         y = jax.lax.dot_general(x2, w, (((1,), (0,)), ((), ())),
                                 precision=precision,
                                 preferred_element_type=jnp.float32)
@@ -137,8 +156,8 @@ def conv1x1_epilogue(x2, w, scale, shift, residual=None, act=None,
     R, I = x2.shape
     O = w.shape[1]
     block_r = _pick_block_r(R, I, O, x2.dtype.itemsize)
-    on_tpu = jax.default_backend() == "tpu"
-    if block_r == 0 or not (on_tpu or interpret):
+    if not _use_pallas("conv1x1_epilogue", block_r, (R, I, O), x2.dtype,
+                       interpret):
         y = jax.lax.dot_general(x2, w, (((1,), (0,)), ((), ())),
                                 precision=precision,
                                 preferred_element_type=jnp.float32)
@@ -198,9 +217,7 @@ def scale_shift_act(y_raw, scale, shift, residual=None, act=None,
     # Mirror _pick_block_r's accounting: every R-streamed tile (y_raw in,
     # y out, optional residual in) is DOUBLE-BUFFERED by Pallas while the
     # grid walks R — 2 streams without a residual, 3 with one, i.e.
-    # ~4-6x b*O*itemsize resident, not the single-copy 3x the old
-    # estimate assumed (which overshot the budget and silently fell back
-    # to XLA at sizes that actually fit, and vice versa near the edge).
+    # ~4-6x b*O*itemsize resident, not a single copy of each.
     streams = 3 if residual is not None else 2
     fixed = 2 * O * 4  # scale + shift f32 rows, revisited (not streamed)
     for b in (2048, 1024, 512, 256, 128):
@@ -208,8 +225,8 @@ def scale_shift_act(y_raw, scale, shift, residual=None, act=None,
                 <= _VMEM_BUDGET:
             block_r = b
             break
-    on_tpu = jax.default_backend() == "tpu"
-    if block_r == 0 or not (on_tpu or interpret):
+    if not _use_pallas("scale_shift_act", block_r, (R, O), y_raw.dtype,
+                       interpret):
         y = y_raw.astype(jnp.float32) * scale + shift
         if residual is not None:
             y = y + residual.astype(jnp.float32)

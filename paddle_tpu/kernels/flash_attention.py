@@ -9,7 +9,7 @@ q-block) grid cell streams K/V blocks through VMEM, maintaining the running
 max/denominator of the softmax (the standard online-softmax recurrence), so
 HBM traffic is O(T*d) instead of O(T^2).
 
-On non-TPU backends (the CPU test mesh) ``flash_attention`` falls back to a
+On non-TPU backends (the CPU test mesh) ``flash_attention`` is the
 pure-jnp reference — same semantics, XLA-fused — for both passes. On TPU
 the BACKWARD is also Pallas (``_flash_dq_kernel`` / ``_flash_dkv_kernel``):
 p-tiles are recomputed from the forward's saved logsumexp per block, so the
@@ -40,6 +40,13 @@ def _pick_block(t, preferred):
     while t % b:
         b //= 2
     return max(b, 1)
+
+
+def _out_struct(shape, dtype, like):
+    """Kernel output type varying over the same manual mesh axes as
+    ``like`` — what ``shard_map``'s vma typing needs from a pallas_call
+    (empty outside a shard_map)."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def rotary(x, pos0=0, base=10000.0):
@@ -229,8 +236,8 @@ def _flash_forward(q, k, v, lengths, causal, sm_scale, block_q, block_k,
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
-                   jax.ShapeDtypeStruct((BH, 1, Tq), jnp.float32)],
+        out_shape=[_out_struct((BH, Tq, D), q.dtype, q),
+                   _out_struct((BH, 1, Tq), jnp.float32, q)],
         interpret=interpret,
     )(lens_bh, q3, k3, v3)
     return out.reshape(B, H, Tq, D), lse
@@ -376,7 +383,7 @@ def _flash_backward(q, k, v, o, lse, lengths, g, causal, sm_scale, block_q,
             ],
             out_specs=pl.BlockSpec((1, bq, D), lambda b, i, lens: (b, i, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
+        out_shape=_out_struct((BH, Tq, D), q.dtype, q),
         interpret=interpret,
     )(lens_bh, q3, k3, v3, do3, lse, dd)
 
@@ -401,8 +408,8 @@ def _flash_backward(q, k, v, o, lse, lengths, g, causal, sm_scale, block_q,
                 pl.BlockSpec((1, bk, D), lambda b, j, lens: (b, j, 0)),
             ],
         ),
-        out_shape=[jax.ShapeDtypeStruct((BH, Tk, D), k.dtype),
-                   jax.ShapeDtypeStruct((BH, Tk, D), v.dtype)],
+        out_shape=[_out_struct((BH, Tk, D), k.dtype, k),
+                   _out_struct((BH, Tk, D), v.dtype, v)],
         interpret=interpret,
     )(lens_bh, q3, k3, v3, do3, lse, dd)
     return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),
@@ -476,12 +483,46 @@ def _attention_bwd(causal, sm_scale, res, g):
 _attention.defvjp(_attention_fwd, _attention_bwd)
 
 
+def _batch_local(mesh, q, k, v, lengths, causal, sm_scale):
+    """The kernel on each device's LOCAL batch shard. A Mosaic call is
+    opaque to the GSPMD partitioner (it refuses: "Mosaic kernels cannot
+    be automatically partitioned"), so inside a sharded executor block the
+    kernel runs as a ``shard_map`` island: batch split over the plan's
+    data axis, everything else replicated — the layout GSPMD gives the
+    rest of a block whose weights are not head-sharded. A batch the axis
+    does not divide runs replicated."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.context import current_data_axis
+
+    axis = current_data_axis()
+    if axis is not None and q.shape[0] % mesh.shape[axis]:
+        axis = None
+    spec = P(axis)
+    args = (q, k, v) if lengths is None else (q, k, v, lengths)
+
+    def local(q, k, v, lengths=None):
+        return _attention(q, k, v, lengths, causal, sm_scale)
+
+    return shard_map(local, mesh=mesh, in_specs=(spec,) * len(args),
+                     out_specs=spec)(*args)
+
+
 def flash_attention(q, k, v, lengths=None, causal=False, sm_scale=None):
     """Scaled-dot-product attention over [B, H, T, D] tensors.
 
     Pallas flash kernel on TPU, jnp reference elsewhere; differentiable via
     recompute. ``lengths`` [B] masks K/V padding columns.
     """
+    from ..parallel.context import current_mesh
+
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    mesh = current_mesh()
+    if (jax.default_backend() == "tpu" and mesh is not None
+            and mesh.size > 1
+            and not jax.sharding.get_abstract_mesh().manual_axes):
+        # (already-manual callers — the GPipe stages — hold local shards)
+        return _batch_local(mesh, q, k, v, lengths, causal, float(sm_scale))
     return _attention(q, k, v, lengths, causal, float(sm_scale))

@@ -67,6 +67,16 @@ class LayerHelper:
         block = self.main_program.global_block
         if name in block.vars:
             return block.vars[name]
+        from ..ops.common import amp_enabled
+
+        if amp_enabled() and to_dtype(dtype) == to_dtype("bfloat16"):
+            # AMP is bf16 COMPUTE over f32 MASTER weights: a layer that
+            # sizes its weight by ``input.dtype`` sees the bf16 an
+            # upstream activation carries, not a dtype the user chose.
+            # Declared bf16, the weight was initialised in bf16 and the
+            # first optimizer update promoted it to f32 — an aval change
+            # under a compiled step.
+            dtype = "float32"
         param = block.create_parameter(
             name=name, shape=shape, dtype=dtype, trainable=attr.trainable,
             initializer={"lr": attr.learning_rate,

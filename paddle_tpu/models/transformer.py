@@ -234,8 +234,8 @@ def transformer_lm_speculative_generate(prompt, vocab_size, d_model=256,
     pinned (tests/test_generate.py) and a trained draft head cuts verify
     rounds well below N on the CPU mesh, but the only wall-clock A/B on
     record (r3 chip, UNtrained model — zero acceptance) was a 2.4x
-    slowdown. Until tools/chip_session_r5.py's trained-model A/B records
-    a speedup > 1, prefer plain ``transformer_lm_generate`` in
+    slowdown. Until a trained-model A/B on the chip records a speedup
+    > 1 (ROADMAP D4), prefer plain ``transformer_lm_generate`` in
     production."""
     from ..initializer import ConstantInitializer
 

@@ -13,21 +13,30 @@ import contextlib
 from typing import Optional
 
 _CURRENT_MESH = None
+_CURRENT_DATA_AXIS = None
 
 
 @contextlib.contextmanager
-def mesh_context(mesh):
-    global _CURRENT_MESH
-    prev = _CURRENT_MESH
-    _CURRENT_MESH = mesh
+def mesh_context(mesh, data_axis: Optional[str] = None):
+    """Publish the executor's mesh (and the plan's batch axis, when it
+    has one) for the duration of a block trace."""
+    global _CURRENT_MESH, _CURRENT_DATA_AXIS
+    prev = _CURRENT_MESH, _CURRENT_DATA_AXIS
+    _CURRENT_MESH, _CURRENT_DATA_AXIS = mesh, data_axis
     try:
         yield
     finally:
-        _CURRENT_MESH = prev
+        _CURRENT_MESH, _CURRENT_DATA_AXIS = prev
 
 
 def current_mesh():
     return _CURRENT_MESH
+
+
+def current_data_axis() -> Optional[str]:
+    """Mesh axis the batch dim of feeds shards on (the plan's
+    ``data_axis``), None without a mesh or a data axis."""
+    return _CURRENT_DATA_AXIS
 
 
 def mesh_axis(name: str) -> int:

@@ -24,9 +24,10 @@ def make_mesh(axes: Optional[Dict[str, int]] = None,
     means "all remaining devices". Defaults to a pure data-parallel mesh over
     every visible device.
 
-    For multi-dim TPU topologies prefer jax.experimental.mesh_utils ordering;
-    on a single host (or the virtual CPU mesh used in tests) a plain reshape
-    of jax.devices() is correct.
+    Device order comes from ``jax.experimental.mesh_utils``: on a TPU it
+    follows the physical topology (so mesh neighbours are ICI neighbours),
+    on the virtual CPU mesh it is a plain reshape. A topology it cannot
+    lay out raises — a silently chosen device order is not a fallback.
     """
     devices = list(devices if devices is not None else jax.devices())
     if axes is None:
@@ -51,12 +52,10 @@ def make_mesh(axes: Optional[Dict[str, int]] = None,
         raise ValueError(
             f"mesh axes {axes} require {known} devices, have {len(devices)}")
     if len(devices) > 1:
-        try:
-            from jax.experimental import mesh_utils
-            dev_array = mesh_utils.create_device_mesh(
-                tuple(axes.values()), devices=devices)
-        except Exception:
-            dev_array = np.array(devices).reshape(tuple(axes.values()))
+        from jax.experimental import mesh_utils
+
+        dev_array = mesh_utils.create_device_mesh(
+            tuple(axes.values()), devices=devices)
     else:
         dev_array = np.array(devices).reshape(tuple(axes.values()))
     return Mesh(dev_array, tuple(axes.keys()))
@@ -76,9 +75,5 @@ def make_abstract_mesh(axes: Dict[str, int]):
     executor a plan over a real :func:`make_mesh` mesh instead."""
     from jax.sharding import AbstractMesh
 
-    pairs = tuple((str(k), int(v)) for k, v in axes.items())
-    try:
-        return AbstractMesh(pairs)
-    except TypeError:  # newer signature: (axis_sizes, axis_names)
-        return AbstractMesh(tuple(v for _, v in pairs),
-                            tuple(k for k, _ in pairs))
+    return AbstractMesh(tuple(int(v) for v in axes.values()),
+                        tuple(str(k) for k in axes))

@@ -63,7 +63,7 @@ def initialize(coordinator_address: Optional[str] = None,
     # (which would itself initialise the backend).
     from ..xla_env import backend_initialized
 
-    if backend_initialized() is True:
+    if backend_initialized():
         raise RuntimeError(
             "initialize_multihost() must run before any JAX computation "
             "(the XLA backend is already initialised in this process)")

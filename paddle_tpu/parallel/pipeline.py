@@ -21,9 +21,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from .compat import pvary, shard_map
 
 
 def gpipe(stage_fn, stage_params, x, mesh, axis="pp", n_microbatches=None,
@@ -67,7 +67,8 @@ def gpipe(stage_fn, stage_params, x, mesh, axis="pp", n_microbatches=None,
         outbuf = jnp.zeros_like(xl)
         # device-varying carries so the loop types stay fixed once
         # ppermuted activations mix in (shard_map vma typing)
-        state, outbuf = (pvary(a, (axis,)) for a in (state, outbuf))
+        state, outbuf = (jax.lax.pcast(a, (axis,), to="varying")
+                         for a in (state, outbuf))
 
         def step(t, carry):
             state, outbuf = carry

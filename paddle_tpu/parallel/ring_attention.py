@@ -23,9 +23,9 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .compat import pvary, shard_map
 
 
 def _block_attn(q, k, v, m, l, acc, q_off, k_off, causal, sm_scale):
@@ -76,7 +76,8 @@ def ring_attention(q, k, v, mesh, seq_axis="sp", causal=False, sm_scale=None):
         acc = jnp.zeros(ql.shape, jnp.float32)
         # type the carries as device-varying so the fori_loop carry types
         # stay fixed once ppermuted K/V mix in (shard_map vma typing)
-        m, l, acc = (pvary(a, (seq_axis,)) for a in (m, l, acc))
+        m, l, acc = (jax.lax.pcast(a, (seq_axis,), to="varying")
+                     for a in (m, l, acc))
         perm = [(i, (i + 1) % n) for i in range(n)]
 
         def attend(c, kc, vc, m, l, acc):

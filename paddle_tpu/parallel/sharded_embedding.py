@@ -37,9 +37,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from .compat import shard_map
 
 
 def rows_per_shard(vocab: int, mesh, vocab_axis: str) -> int:

@@ -21,9 +21,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from .compat import pvary, shard_map
 
 
 def _axes(mesh, data_axis, vp_axis):
@@ -67,7 +67,7 @@ def vp_fused_head_lse(x2, w, lab, chunk, mesh, vp_axis, data_axis):
         # (shard_map vma typing) — pcast them up front
         zeros = jnp.zeros((n,), jnp.float32)
         carry = tuple(
-            pvary(a, varying)
+            jax.lax.pcast(a, varying, to="varying")
             for a in (jnp.full((n,), -jnp.inf, jnp.float32),
                       zeros, zeros, zeros))
         m, s, ll, rs = jax.lax.fori_loop(
@@ -120,7 +120,7 @@ def vp_fused_head_grad(x2, w, lab, dl, lse, chunk, mesh, vp_axis,
                                                         axis=1))
 
         carry = tuple(
-            pvary(a, varying)
+            jax.lax.pcast(a, varying, to="varying")
             for a in (jnp.zeros((n, d), jnp.float32),
                       jnp.zeros((d, n_chunks_l, chunk_l), jnp.float32)))
         dx, dw = jax.lax.fori_loop(0, n_chunks_l, body, carry)

@@ -238,7 +238,10 @@ def device_prefetch(feed_reader, depth: int = 2, device=None):
     dispatch.
 
     ``feed_reader()`` must yield {name: np.ndarray} dicts (e.g. a
-    DataFeeder.feed applied to batches).
+    DataFeeder.feed applied to batches). ``device`` defaults to device 0;
+    for an executor placed elsewhere pass ``exe.device()`` — a feed
+    committed to another chip is rejected by the compiled step, not
+    copied across.
     """
     import jax
 

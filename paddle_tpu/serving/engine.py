@@ -201,7 +201,6 @@ class InferenceEngine:
         if mesh is not None and plan is None:
             from ..parallel import data_parallel_plan
             plan = data_parallel_plan(mesh, data_axis=mesh.axis_names[0])
-        self._place = place
         self.executor = Executor(place or TPUPlace(0), mesh=mesh, plan=plan)
         if model_dir is not None:
             from ..io import load_inference_model
@@ -300,12 +299,6 @@ class InferenceEngine:
         }
 
     # ------------------------------------------------------------------
-    def _device_ctx(self):
-        if self.mesh is None and self._place is not None:
-            import jax
-            return jax.default_device(self._place.device())
-        return contextlib.nullcontext()
-
     def bucket_for(self, n: int) -> int:
         for b in self.batch_buckets:
             if n <= b:
@@ -399,9 +392,7 @@ class InferenceEngine:
     def _dispatch_padded(self, arrays: Dict[str, np.ndarray], n: int):
         fed, bucket = self._pad_feed(arrays, n)
         t0 = time.perf_counter()
-        with self._device_ctx(), \
-                trace.span("serving/dispatch_batch", bucket=bucket,
-                           rows=n):
+        with trace.span("serving/dispatch_batch", bucket=bucket, rows=n):
             handle = self.executor.run_async(
                 self.program, feed=fed, fetch_list=self.fetch_names,
                 scope=self.scope)
@@ -423,8 +414,7 @@ class InferenceEngine:
         t0 = time.perf_counter()
         self._track(+1)
         try:
-            with self._device_ctx(), \
-                    profiler.timer("serving/infer_batch"), \
+            with profiler.timer("serving/infer_batch"), \
                     trace.span("serving/infer_batch", bucket=bucket,
                                rows=n):
                 res = self.executor.run(self.program, feed=fed,
@@ -471,10 +461,9 @@ class InferenceEngine:
                 if not ok:
                     self.metrics.inc("warmup_skipped")
                     continue
-                with self._device_ctx():
-                    self.executor.run(self.program, feed=feed,
-                                      fetch_list=self.fetch_names,
-                                      scope=self.scope)
+                self.executor.run(self.program, feed=feed,
+                                  fetch_list=self.fetch_names,
+                                  scope=self.scope)
                 combos += 1
         self.metrics.inc("warmup_compiles", combos)
         self.save_manifest()
@@ -514,7 +503,7 @@ class InferenceEngine:
             return None
         stats = manifest_mod.replay(
             self.executor, [self.program], scope=self.scope,
-            manifest=manifest, device_ctx=self._device_ctx)
+            manifest=manifest)
         self.metrics.inc("warmup_replayed", stats["compiled"])
         if stats["skipped"]:
             self.metrics.inc("warmup_manifest_skipped", stats["skipped"])
@@ -553,7 +542,7 @@ class InferenceEngine:
         every warm executable. Outstanding async dispatches keep the old
         arrays alive until they resolve (donation-safe)."""
         return swap_scope_params(self.scope, source, strict=strict,
-                                 device_ctx=self._device_ctx,
+                                 device_ctx=self.executor.device_ctx,
                                  metrics=self.metrics)
 
     # ------------------------------------------------------------------

@@ -276,7 +276,6 @@ class GenerationEngine:
         # resident models against ONE artifact directory keeps each
         # tenant's warmup manifest under its own filename
         self.namespace = str(namespace or "")
-        self._place = place
         self.metrics = metrics or MetricsRegistry()
         # flight recorder: live engine state + last-N request timelines
         # become part of every crash/SIGUSR1/admin dump (weak
@@ -285,6 +284,7 @@ class GenerationEngine:
         self._flight.add_source(type(self).__name__, self.flight_state)
         self.model_dir: Optional[str] = None  # set by from_saved
         self.executor = Executor(place or TPUPlace(0))
+        self._adopt_scope()
         self.prompt_buckets = sorted(set(
             min(int(b), self.tmax) for b in
             (prompt_buckets or _default_prompt_buckets(self.tmax))))
@@ -332,14 +332,28 @@ class GenerationEngine:
         eng.model_dir = model_dir  # manifest home for warm_start
         return eng
 
+    def _adopt_scope(self):
+        """Move weights handed over in ``scope`` (trained elsewhere, then
+        copied per engine) onto this engine's device, once: the executor
+        refuses state that lives on another chip rather than copying it
+        across on every tick."""
+        import jax
+
+        dev = self.executor.device()
+        for name in list(self.scope.keys()):
+            val = self.scope.get(name)
+            if isinstance(val, jax.Array) and val.devices() != {dev}:
+                self.scope.set(name, jax.device_put(val, dev))
+
     def _init_cache(self):
         import jax.numpy as jnp
 
         s = self.spec
         shape = (s.n_layers, self._nslots, s.kv_heads, self.tmax,
                  s.head_dim)
-        self.scope.set(CACHE_K, jnp.zeros(shape, jnp.float32))
-        self.scope.set(CACHE_V, jnp.zeros(shape, jnp.float32))
+        with self.executor.device_ctx():
+            self.scope.set(CACHE_K, jnp.zeros(shape, jnp.float32))
+            self.scope.set(CACHE_V, jnp.zeros(shape, jnp.float32))
 
     def _cache_vars(self, helper):
         s = self.spec
@@ -493,13 +507,6 @@ class GenerationEngine:
     def free_slots(self) -> int:
         return self.slots - self.active
 
-    def _device_ctx(self):
-        if self._place is not None:
-            import jax
-            return jax.default_device(self._place.device())
-        import contextlib
-        return contextlib.nullcontext()
-
     def _needs_scope_rng(self) -> bool:
         """Does the decode family draw from the SCOPE RNG plane? Only
         the dense engine's legacy attrs-based sampling does; the paged
@@ -527,12 +534,10 @@ class GenerationEngine:
                     "serving.slot_ids": np.full(b, self.slots, np.int32),
                     "serving.lengths": np.ones(b, np.int32),
                 }
-                with self._device_ctx():
-                    self.executor.run(prog, feed=feed, fetch_list=[nxt],
-                                      scope=self.scope)
+                self.executor.run(prog, feed=feed, fetch_list=[nxt],
+                                  scope=self.scope)
                 combos += 1
-        with self._device_ctx():
-            self._run_decode()
+        self._run_decode()
         combos += 1
         self.metrics.inc("warmup_compiles", combos)
         self.save_manifest()
@@ -594,7 +599,7 @@ class GenerationEngine:
             self.executor._rng_state(self._decode_prog[0], self.scope)
         stats = manifest_mod.replay(
             self.executor, self._warm_programs(), scope=self.scope,
-            manifest=manifest, device_ctx=self._device_ctx)
+            manifest=manifest)
         self.metrics.inc("warmup_replayed", stats["compiled"])
         if stats["skipped"]:
             self.metrics.inc("warmup_manifest_skipped", stats["skipped"])
@@ -676,7 +681,7 @@ class GenerationEngine:
             lengths[row] = p.size
         prog, nxt = self._prefill_prog(tp)
         t0 = time.perf_counter()
-        with self._device_ctx(), profiler.timer("serving/prefill"):
+        with profiler.timer("serving/prefill"):
             first, = self.executor.run(
                 prog, feed={"serving.prompt": prompt,
                             "serving.slot_ids": slot_ids,
@@ -784,7 +789,7 @@ class GenerationEngine:
         if self.active == 0:
             return False
         t0 = time.perf_counter()
-        with self._device_ctx(), profiler.timer("serving/decode_step"), \
+        with profiler.timer("serving/decode_step"), \
                 trace.span("serving/decode_step", active=self.active):
             nxt = self._run_decode()
         self.metrics.observe_latency(time.perf_counter() - t0,
@@ -923,7 +928,7 @@ class GenerationEngine:
 
         return swap_scope_params(self.scope, source,
                                  skip=self._cache_names, strict=strict,
-                                 device_ctx=self._device_ctx,
+                                 device_ctx=self.executor.device_ctx,
                                  metrics=self.metrics)
 
     # -- server-driver interface -----------------------------------------
@@ -1124,8 +1129,9 @@ class PagedGenerationEngine(GenerationEngine):
         shape = (s.n_layers, self.n_pages, s.kv_heads, self.page_size,
                  s.head_dim)
         if src is None:
-            self.scope.set(PAGED_CACHE_K, jnp.zeros(shape, jnp.float32))
-            self.scope.set(PAGED_CACHE_V, jnp.zeros(shape, jnp.float32))
+            with self.executor.device_ctx():
+                self.scope.set(PAGED_CACHE_K, jnp.zeros(shape, jnp.float32))
+                self.scope.set(PAGED_CACHE_V, jnp.zeros(shape, jnp.float32))
         # shared-pool engines never re-zero: the scope tensors already
         # hold the source pool's live pages
         self._page_copy_prog_cache = None
@@ -1386,13 +1392,11 @@ class PagedGenerationEngine(GenerationEngine):
                                                     np.int32),
                 }
                 feed.update(self._neutral_sampling_feed(b))
-                with self._device_ctx():
-                    self.executor.run(prog, feed=feed,
-                                      fetch_list=self._fetches(outs),
-                                      scope=self.scope)
+                self.executor.run(prog, feed=feed,
+                                  fetch_list=self._fetches(outs),
+                                  scope=self.scope)
                 combos += 1
-        with self._device_ctx():
-            self._run_decode()
+        self._run_decode()
         combos += 1
         self._run_page_copy(0, 0)  # scrap onto itself: harmless
         combos += 1
@@ -1434,11 +1438,10 @@ class PagedGenerationEngine(GenerationEngine):
     # -- page bookkeeping -------------------------------------------------
     def _run_page_copy(self, src: int, dst: int) -> None:
         prog, ok = self._page_copy_prog
-        with self._device_ctx():
-            self.executor.run(
-                prog, feed={"serving.cow_src": np.asarray([src], np.int32),
-                            "serving.cow_dst": np.asarray([dst], np.int32)},
-                fetch_list=[ok], scope=self.scope)
+        self.executor.run(
+            prog, feed={"serving.cow_src": np.asarray([src], np.int32),
+                        "serving.cow_dst": np.asarray([dst], np.int32)},
+            fetch_list=[ok], scope=self.scope)
 
     def _cow_guard(self, decoding) -> None:
         """Before a decode tick writes position ``pos`` for each slot,
@@ -1797,7 +1800,7 @@ class PagedGenerationEngine(GenerationEngine):
                      "serving.block_table": table})
         prog, outs = self._prefill_prog(tc)
         t0 = time.perf_counter()
-        with self._device_ctx(), profiler.timer("serving/prefill"):
+        with profiler.timer("serving/prefill"):
             res = self.executor.run(prog, feed=feed,
                                     fetch_list=self._fetches(outs),
                                     scope=self.scope)
@@ -1922,7 +1925,7 @@ class PagedGenerationEngine(GenerationEngine):
                      "serving.block_table": table})
         prog, outs = self._prefill_prog(tc)
         t0 = time.perf_counter()
-        with self._device_ctx(), profiler.timer("serving/prefill"), \
+        with profiler.timer("serving/prefill"), \
                 trace.span("serving/prefill_chunk", slot=slot,
                            start=start0, tokens=k):
             res = self.executor.run(prog, feed=feed,
@@ -1992,7 +1995,7 @@ class PagedGenerationEngine(GenerationEngine):
             return False
         self._cow_guard(decoding)
         t0 = time.perf_counter()
-        with self._device_ctx(), profiler.timer("serving/decode_step"), \
+        with profiler.timer("serving/decode_step"), \
                 trace.span("serving/decode_step", active=len(decoding)):
             nxt, topv, topi = self._run_decode()
         self.metrics.observe_latency(time.perf_counter() - t0,

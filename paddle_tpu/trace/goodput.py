@@ -28,10 +28,11 @@ span tracing is enabled and costs one clock read per region.
 
 Live MFU: :meth:`set_program_flops` (from
 ``analysis.analyze_memory(...).total_flops``) plus per-step
-:meth:`note_step` device walls yield achieved-FLOPs/s over the
-device peak (v5e roofline by default) as an instantaneous gauge and an
-EMA — the ROADMAP north star measured continuously instead of
-bench-only.
+:meth:`note_step` device walls yield achieved-FLOPs/s over the peak of
+the device that is actually running (``analysis.costmodel.DEVICE_PEAKS``
+by ``device_kind``) as an instantaneous gauge and an EMA. On a device
+the table does not know — the CPU mesh included — there is no peak and
+no ``mfu`` is computed or published.
 
 Publishing: :meth:`publish` pushes ``goodput_seconds_total{bucket=...}``
 labeled series, ``goodput_fraction``/``mfu`` gauges and the cumulative
@@ -74,9 +75,13 @@ class GoodputMeter:
     def __init__(self, peak_flops: Optional[float] = None,
                  ema_alpha: float = MFU_EMA_ALPHA):
         if peak_flops is None:
-            from ..analysis.costmodel import V5E_PEAK_FLOPS
-            peak_flops = V5E_PEAK_FLOPS
-        self.peak_flops = float(peak_flops)
+            import jax
+
+            from ..analysis.costmodel import DEVICE_PEAKS
+
+            peak_flops = DEVICE_PEAKS.get(
+                jax.devices()[0].device_kind, (None, None))[0]
+        self.peak_flops = float(peak_flops) if peak_flops else None
         self.ema_alpha = float(ema_alpha)
         self._lock = threading.Lock()
         self._seconds: Dict[str, float] = {b: 0.0 for b in BUCKETS}
@@ -133,11 +138,12 @@ class GoodputMeter:
 
     def note_step(self, device_s: float) -> Optional[float]:
         """Record one step's measured device wall; returns the step's
-        MFU (None when flops unknown or the wall is degenerate)."""
+        MFU (None when flops or the device's peak are unknown, or the
+        wall is degenerate)."""
         with self._lock:
             self._steps += 1
             if (self._program_flops is None or device_s <= 0.0
-                    or self.peak_flops <= 0.0):
+                    or self.peak_flops is None):
                 return None
             mfu = self._program_flops / device_s / self.peak_flops
             self._mfu = mfu

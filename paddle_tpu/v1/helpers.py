@@ -961,7 +961,7 @@ class GeneratedInput:
     """Accepted for source compatibility; in-config generation through
     recurrent_group is NOT the TPU path — beam/greedy generation runs
     through the in-graph decode ops instead (models.transformer_lm_*,
-    layers.beam_search_decoder; see STATUS.md row 29)."""
+    layers.beam_search_decoder)."""
 
     def __init__(self, size=0, embedding_name=None, embedding_size=0,
                  **kw):
@@ -1673,7 +1673,7 @@ class BeamInput:
 
 
 def cross_entropy_over_beam(input=None, **kw):
-    """Deliberate absence with guidance (STATUS.md): beam-level CE
+    """Deliberate absence with guidance: beam-level CE
     exists for the reference's recurrent_group beam TRAINING machinery
     (CrossEntropyOverBeam.cpp); beam decoding/training here runs through
     the in-graph beam ops (layers.beam_search_decoder,

@@ -82,86 +82,74 @@ def test_decode_bench_path_runs():
     assert res["tokens_per_sec"] > 0
 
 
-def test_source_digest_stable_and_sensitive(tmp_path):
+def test_bench_requires_a_tpu_and_measures_nothing_without(monkeypatch,
+                                                          capsys):
+    """``python bench.py`` off-chip: non-zero exit, no record on stdout,
+    and no measurement function is ever entered."""
     b = _bench()
-    assert b._source_digest() == b._source_digest()
-    # content sensitivity, proven on a synthetic tree
-    pkg = tmp_path / "paddle_tpu"
-    pkg.mkdir()
-    (tmp_path / "bench.py").write_text("x = 1\n")
-    (pkg / "mod.py").write_text("y = 1\n")
-    d1 = b._source_digest(root=str(tmp_path))
-    (pkg / "mod.py").write_text("y = 2\n")
-    d2 = b._source_digest(root=str(tmp_path))
-    assert d1 != d2 and len(d1) == 16
-    (pkg / "mod.py").write_text("y = 1\n")
-    assert b._source_digest(root=str(tmp_path)) == d1
+
+    def boom(*a, **kw):
+        raise AssertionError("bench measured something on a CPU")
+
+    monkeypatch.setattr(b, "run_bench", boom)
+    assert b.main() == 1
+    out = capsys.readouterr()
+    assert out.out == ""
+    assert "needs a TPU" in out.err and "'cpu'" in out.err
 
 
-def test_sidecar_roundtrip_and_digest_isolation(tmp_path, monkeypatch):
+def test_row_that_raises_is_recorded_and_fails_the_run(monkeypatch, capsys):
     b = _bench()
-    monkeypatch.setattr(b, "SIDECAR_PATH", str(tmp_path / "sc.jsonl"))
-    b._sidecar_append("aaaa", "resnet", result={"img_per_sec": 100.0})
-    b._sidecar_append("aaaa", "lstm", error="boom")
-    b._sidecar_append("bbbb", "resnet", result={"img_per_sec": 1.0})
-    rows = b._sidecar_load("aaaa")
-    assert rows["resnet"]["result"]["img_per_sec"] == 100.0
-    assert rows["lstm"]["error"] == "boom"
-    assert b._sidecar_load("bbbb")["resnet"]["result"]["img_per_sec"] == 1.0
-    assert b._sidecar_load("cccc") == {}
+    rows = b.run_rows([("good", lambda x: x + 1, (1,), {}),
+                       ("bad", lambda: 1 / 0, (), {}),
+                       ("after", lambda: "ran", (), {})])
+    assert rows["good"] == {"result": 2}
+    assert "ZeroDivisionError" in rows["bad"]["error"]
+    assert rows["after"] == {"result": "ran"}  # the sweep goes on
+    assert "ZeroDivisionError" in capsys.readouterr().err  # loudly
+
+    # main(): the record still prints, the exit code is non-zero
+    class _Dev:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+    import jax
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+    rows["info"] = {"result": {"platform": "tpu",
+                               "device_kind": "TPU v5 lite",
+                               "device_count": 1, "batch": 256,
+                               "image_size": 224}}
+    monkeypatch.setattr(b, "run_bench", lambda jax_, dev: rows)
+    assert b.main() == 1
+    rec = __import__("json").loads(capsys.readouterr().out)
+    assert rec["value"] is None  # no headline: never a plausible 0.0
+    assert "bad" in rec["extra"]["failed"]
 
 
-def test_assemble_partial_rows_emit_nulls():
+def test_assemble_names_the_device_and_needs_its_peak():
     b = _bench()
     rows = {
-        "info": {"result": {"platform": "tpu", "device_kind": "TPU v5e",
-                            "batch": 256, "image_size": 224}},
-        "resnet": {"result": {"img_per_sec": 1000.0,
-                              "notes": None}},
+        "info": {"result": {"platform": "tpu", "device_kind": "TPU v5 lite",
+                            "device_count": 1, "batch": 256,
+                            "image_size": 224}},
+        "resnet": {"result": {"img_per_sec": 1000.0}},
         "transformer_wide": {"result": [39100.0, 110e12]},
-        "lstm": {"error": "dropped mid-run"},
-    }
-    out = b.assemble(rows, parent_notes=["partial"])
-    assert out["value"] == 1000.0
-    assert out["extra"]["platform"] == "tpu"
-    assert out["extra"]["mfu"] is not None
-    assert out["extra"]["transformer_wide_mfu"] is not None
-    assert out["extra"]["transformer_lm_tokens_per_sec"] is None
-    assert out["extra"]["degraded"]["lstm"] == "dropped mid-run"
-    assert out["extra"]["bench_notes"] == ["partial"]
-    # the r3 schema keys all survive
-    for key in ("lstm_varlen", "decode_kv_cache", "image_zoo_train_bs128",
-                "infer_bs16", "transformer_mfu"):
-        assert key in out["extra"]
-
-
-def test_assemble_cpu_smoke_schema():
-    b = _bench()
-    rows = {
-        "info": {"result": {"platform": "cpu", "device_kind": "cpu",
-                            "batch": 8, "image_size": 64}},
-        "resnet": {"result": {"img_per_sec": 1.2,
-                              "notes": None}},
+        "lstm": {"error": "raised"},
     }
     out = b.assemble(rows)
-    assert out["extra"]["mfu"] is None and out["value"] == 1.2
-
-
-def test_sidecar_device_filtering(tmp_path, monkeypatch):
-    b = _bench()
-    monkeypatch.setattr(b, "SIDECAR_PATH", str(tmp_path / "sc.jsonl"))
-    b._sidecar_append("aaaa", "info", result={"device_kind": "v5e"},
-                      device="v5e")
-    b._sidecar_append("aaaa", "resnet", result={"img_per_sec": 9.0},
-                      device="v5e")
-    # chip swap: same digest, different device
-    assert b._sidecar_load("aaaa", device="v4") == {}
-    assert "resnet" in b._sidecar_load("aaaa", device="v5e")
-    # device=None trusts the latest info row
-    assert "resnet" in b._sidecar_load("aaaa")
-    b._sidecar_append("aaaa", "info", result={"device_kind": "v4"},
-                      device="v4")
-    assert "resnet" not in b._sidecar_load("aaaa")
+    assert out["value"] == 1000.0
+    assert out["extra"]["platform"] == "tpu"
+    assert out["extra"]["device_kind"] == "TPU v5 lite"
+    assert out["extra"]["mfu"] == pytest.approx(
+        1000.0 * b.RESNET50_TRAIN_FLOPS_224 / 197e12, rel=1e-3)
+    assert out["extra"]["transformer_wide_mfu"] is not None
+    assert out["extra"]["transformer_lm_tokens_per_sec"] is None
+    assert out["extra"]["failed"] == {"lstm": "raised"}
+    # a device the one peaks table does not know is an error, not a
+    # record without an MFU
+    rows["info"]["result"]["device_kind"] = "TPU v9 imaginary"
+    with pytest.raises(KeyError, match="no published peak"):
+        b.assemble(rows)
 
 
 @pytest.mark.slow  # tier-1 budget: overhead A/B is a sweep row, not a correctness gate

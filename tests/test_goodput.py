@@ -142,6 +142,33 @@ class TestGoodputMeter:
         assert m.steps == 3                   # every step counts, MFU
         #                                       only once priced
 
+    def test_no_mfu_on_a_device_without_a_published_peak(self):
+        """The meter resolves its peak from the RUNNING device's
+        ``device_kind`` through ``costmodel.DEVICE_PEAKS``. The CPU mesh
+        is not in the table, so a priced program still yields no MFU —
+        nothing divides a CPU step by the v5e peak."""
+        import jax
+
+        from paddle_tpu.analysis import costmodel
+
+        assert jax.devices()[0].device_kind not in costmodel.DEVICE_PEAKS
+        m = GoodputMeter()
+        assert m.peak_flops is None
+        m.set_program_flops(5e7)
+        assert m.note_step(0.1) is None
+        assert m.mfu is None and m.mfu_ema is None
+        reg = MetricsRegistry()
+        m.account("device_compute", 0.1)
+        m.publish(reg)
+        gauges = reg.snapshot()["gauges"]
+        assert "mfu" not in gauges and "mfu_ema" not in gauges
+        assert m.telemetry()["mfu"] is None
+        # the table itself: the chip this repo measures on is known,
+        # anything else is a located error, never a default
+        assert costmodel.device_peaks("TPU v5 lite") == (197e12, 819e9)
+        with pytest.raises(KeyError, match="no published peak"):
+            costmodel.device_peaks("TPU v9 imaginary")
+
     def test_publish_prometheus_series_and_ratio_counters(self):
         reg = MetricsRegistry()
         m = GoodputMeter()
@@ -652,8 +679,10 @@ def test_trace_summary_goodput_waterfall(tmp_path):
     tr = _build_fc(seed=21)
     path = str(tmp_path / "run.jsonl")
     with RunLog(path) as rl:
+        # an explicit peak: the CPU mesh has none in the device table,
+        # so the default meter prices no MFU here
         tr.train(_rows(6), num_passes=1, event_handler=lambda e: None,
-                 run_log=rl)
+                 run_log=rl, goodput=GoodputMeter(peak_flops=1e12))
     sys.path.insert(0, "tools")
     try:
         import trace_summary
