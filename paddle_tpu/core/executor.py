@@ -566,8 +566,9 @@ class Executor:
             # — every process provides the full array and keeps only its
             # local shards, the analogue of each reference trainer feeding
             # its slice of the global batch.
-            feed_args = [self._put(a, s)
-                         for a, s in zip(feed_args, compiled.feed_shardings)]
+            with trace.span("executor/shard_feed", feeds=len(feed_args)):
+                feed_args = [self._put(a, s) for a, s in
+                             zip(feed_args, compiled.feed_shardings)]
             ro_args = [self._put(a, s)
                        for a, s in zip(ro_args, compiled.ro_shardings)]
             rw_args = [self._put(a, s)
@@ -614,16 +615,23 @@ class Executor:
                 self._rng_state(program, scope)),)
         return args
 
-    def _aot_compile(self, jitted, args) -> Tuple[Any, bool]:
-        """``.lower().compile()`` under a classification window; returns
-        (executable, restored_from_disk) and bumps the source counters."""
+    def _aot_compile(self, jitted, args) -> Tuple[Any, Dict[str, Any]]:
+        """``.lower()`` then ``.compile()`` under a classification window;
+        bumps the source counters and returns the executable with what
+        the ``executor/compile`` span carries: ``restored`` (loaded from
+        the persistent cache, not compiled), ``lower_s`` (tracing the
+        block and lowering it to StableHLO) and ``compile_s`` (XLA's
+        compile, or the cache load)."""
         from .. import profiler
 
         _maybe_enable_compilation_cache()
         t0 = time.perf_counter()
         with _compile_window() as window:
-            executable = jitted.lower(*args).compile()
-        dt = time.perf_counter() - t0
+            lowered = jitted.lower(*args)
+            t1 = time.perf_counter()
+            executable = lowered.compile()
+        t2 = time.perf_counter()
+        dt = t2 - t0
         self.compile_seconds += dt
         restored = window["persistent_hits"] > 0
         if restored:
@@ -636,7 +644,8 @@ class Executor:
             profiler.global_stat.add_count(
                 "executor/compile_cache/fresh_compile", 1)
             profiler.global_stat.add("executor/fresh_compile", dt)
-        return executable, restored
+        return executable, {"restored": restored, "lower_s": t1 - t0,
+                            "compile_s": t2 - t1}
 
     def _finish_compile(self, compiled: "_Compiled", feed_vals,
                         scope: Scope, program: Program, span=None) -> None:
@@ -649,10 +658,10 @@ class Executor:
             self._check_state_device(compiled, scope)
         with self.device_ctx(program):
             args = self._aval_args(compiled, feed_vals, scope, program)
-            compiled.aot, restored = self._aot_compile(compiled.fn, args)
-        compiled.source = "persistent" if restored else "fresh"
+            compiled.aot, how = self._aot_compile(compiled.fn, args)
+        compiled.source = "persistent" if how["restored"] else "fresh"
         if span is not None:
-            span.set_attr("source", compiled.source)
+            span.set_attrs(source=compiled.source, **how)
 
     def _check_state_device(self, compiled: "_Compiled",
                             scope: Scope) -> None:

@@ -365,7 +365,7 @@ class Seq2SeqGenerationEngine(PagedGenerationEngine):
         cross K/V into its row; padding rows target the scrap row."""
         import time
 
-        from .. import profiler, trace
+        from .. import trace
 
         nb = self._enc_bucket_for(len(items))
         prog, ok = self._encode_prog(ts)
@@ -379,9 +379,8 @@ class Seq2SeqGenerationEngine(PagedGenerationEngine):
             feed["serving.src_n"][i] = src.size
             feed["serving.src_row"][i] = row
         t0 = time.perf_counter()
-        with profiler.timer("serving/encode"), \
-                trace.span("serving/encode", batch=len(items),
-                           bucket=ts, padded=nb):
+        with trace.span("serving/encode", batch=len(items), bucket=ts,
+                        padded=nb):
             self.executor.run(prog, feed=feed, fetch_list=[ok],
                               scope=self.scope)
         self.metrics.observe_latency(time.perf_counter() - t0,

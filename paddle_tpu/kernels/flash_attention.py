@@ -239,6 +239,7 @@ def _flash_forward(q, k, v, lengths, causal, sm_scale, block_q, block_k,
         out_shape=[_out_struct((BH, Tq, D), q.dtype, q),
                    _out_struct((BH, 1, Tq), jnp.float32, q)],
         interpret=interpret,
+        name="flash_fwd",
     )(lens_bh, q3, k3, v3)
     return out.reshape(B, H, Tq, D), lse
 
@@ -385,6 +386,7 @@ def _flash_backward(q, k, v, o, lse, lengths, g, causal, sm_scale, block_q,
         ),
         out_shape=_out_struct((BH, Tq, D), q.dtype, q),
         interpret=interpret,
+        name="flash_dq",
     )(lens_bh, q3, k3, v3, do3, lse, dd)
 
     dkv_kernel = functools.partial(_flash_dkv_kernel, block_q=bq,
@@ -411,6 +413,7 @@ def _flash_backward(q, k, v, o, lse, lengths, g, causal, sm_scale, block_q,
         out_shape=[_out_struct((BH, Tk, D), k.dtype, k),
                    _out_struct((BH, Tk, D), v.dtype, v)],
         interpret=interpret,
+        name="flash_dkv",
     )(lens_bh, q3, k3, v3, do3, lse, dd)
     return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),
             dv.reshape(B, H, Tk, D))

@@ -26,7 +26,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from .. import profiler, trace
+from .. import trace
 from ..core.executor import Executor, TPUPlace
 from ..core.scope import Scope
 from .errors import BadRequestError, EngineClosedError
@@ -400,8 +400,7 @@ class InferenceEngine:
         return handle, bucket, n, t0
 
     def _resolve_padded(self, handle, bucket: int, n: int, t0: float):
-        with profiler.timer("serving/infer_batch"), \
-                trace.span("serving/resolve_batch", bucket=bucket, rows=n):
+        with trace.span("serving/resolve_batch", bucket=bucket, rows=n):
             res = handle.result()
         self.metrics.observe_latency(
             time.perf_counter() - t0, name="batch_execute")
@@ -414,9 +413,7 @@ class InferenceEngine:
         t0 = time.perf_counter()
         self._track(+1)
         try:
-            with profiler.timer("serving/infer_batch"), \
-                    trace.span("serving/infer_batch", bucket=bucket,
-                               rows=n):
+            with trace.span("serving/infer_batch", bucket=bucket, rows=n):
                 res = self.executor.run(self.program, feed=fed,
                                         fetch_list=self.fetch_names,
                                         scope=self.scope)

@@ -36,7 +36,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from .. import profiler, trace
+from .. import trace
 from ..trace import flight as trace_flight
 from ..core.executor import Executor, TPUPlace
 from ..core.program import Program, program_guard
@@ -681,7 +681,8 @@ class GenerationEngine:
             lengths[row] = p.size
         prog, nxt = self._prefill_prog(tp)
         t0 = time.perf_counter()
-        with profiler.timer("serving/prefill"):
+        with trace.span("serving/prefill_group", rows=len(todo),
+                        bucket=bucket, tokens=tp):
             first, = self.executor.run(
                 prog, feed={"serving.prompt": prompt,
                             "serving.slot_ids": slot_ids,
@@ -789,8 +790,7 @@ class GenerationEngine:
         if self.active == 0:
             return False
         t0 = time.perf_counter()
-        with profiler.timer("serving/decode_step"), \
-                trace.span("serving/decode_step", active=self.active):
+        with trace.span("serving/decode_step", active=self.active):
             nxt = self._run_decode()
         self.metrics.observe_latency(time.perf_counter() - t0,
                                      name="decode_step")
@@ -938,15 +938,22 @@ class GenerationEngine:
         then advance the decode loop one step."""
         if self._killed:
             return self._drain_killed(batcher)
-        did = False
-        free = self.free_slots
-        if free:
-            wait = 0 if self.active else idle_wait_s
-            reqs = batcher.next_batch(max_n=free, wait_s=wait)
+        reqs = None
+        if not self.active:
+            # idle: the coalescing wait is no part of a pass
+            reqs = batcher.next_batch(max_n=self.free_slots,
+                                      wait_s=idle_wait_s)
+            if not reqs:
+                return False
+        with trace.span("serving/pass", active=self.active):
+            free = self.free_slots
+            if reqs is None and free:
+                reqs = batcher.next_batch(max_n=free, wait_s=0)
+            did = False
             if reqs:
-                did = self.admit(reqs) > 0
-        did = self.decode_tick() or did
-        return did
+                with trace.span("serving/admit", requests=len(reqs)):
+                    did = self.admit(reqs) > 0
+            return self.decode_tick() or did
 
     # -- synchronous convenience ------------------------------------------
     def generate_all(self, prompts: Sequence[Sequence[int]],
@@ -1800,7 +1807,8 @@ class PagedGenerationEngine(GenerationEngine):
                      "serving.block_table": table})
         prog, outs = self._prefill_prog(tc)
         t0 = time.perf_counter()
-        with profiler.timer("serving/prefill"):
+        with trace.span("serving/prefill_group", rows=len(group),
+                        bucket=bucket, tokens=tc):
             res = self.executor.run(prog, feed=feed,
                                     fetch_list=self._fetches(outs),
                                     scope=self.scope)
@@ -1925,9 +1933,8 @@ class PagedGenerationEngine(GenerationEngine):
                      "serving.block_table": table})
         prog, outs = self._prefill_prog(tc)
         t0 = time.perf_counter()
-        with profiler.timer("serving/prefill"), \
-                trace.span("serving/prefill_chunk", slot=slot,
-                           start=start0, tokens=k):
+        with trace.span("serving/prefill_chunk", slot=slot,
+                        offset=start0, tokens=k):
             res = self.executor.run(prog, feed=feed,
                                     fetch_list=self._fetches(outs),
                                     scope=self.scope)
@@ -1938,7 +1945,7 @@ class PagedGenerationEngine(GenerationEngine):
         if st.request.span is not None:
             trace.record("serving/execute", t0, t1,
                          parent=st.request.span, phase="prefill_chunk",
-                         slot=slot, start=start0, tokens=k)
+                         slot=slot, offset=start0, tokens=k)
         st.prefill_done = start0 + k
         if st.prefill_done >= plen:
             self.metrics.inc("prefills")
@@ -1995,8 +2002,7 @@ class PagedGenerationEngine(GenerationEngine):
             return False
         self._cow_guard(decoding)
         t0 = time.perf_counter()
-        with profiler.timer("serving/decode_step"), \
-                trace.span("serving/decode_step", active=len(decoding)):
+        with trace.span("serving/decode_step", active=len(decoding)):
             nxt, topv, topi = self._run_decode()
         self.metrics.observe_latency(time.perf_counter() - t0,
                                      name="decode_step")
@@ -2348,17 +2354,25 @@ class PagedGenerationEngine(GenerationEngine):
                    idle_wait_s: Optional[float] = None) -> bool:
         if self._killed:
             return self._drain_killed(batcher)
-        did = self._beam_maintenance()
-        did = self._admit_deferred() > 0 or did
-        free = self.free_slots
-        if free and not self._deferred:
-            wait = 0 if (self.active or did) else idle_wait_s
-            reqs = batcher.next_batch(max_n=free, wait_s=wait)
-            if reqs:
-                did = self.admit(reqs) > 0 or did
-        did = self.prefill_tick() or did
-        did = self.decode_tick() or did
-        return did
+        reqs = None
+        if not (self.active or self._deferred or self._beam_jobs):
+            # idle: the coalescing wait is no part of a pass
+            reqs = batcher.next_batch(max_n=self.free_slots,
+                                      wait_s=idle_wait_s)
+            if not reqs:
+                return False
+        with trace.span("serving/pass", active=self.active):
+            did = self._beam_maintenance()
+            with trace.span("serving/admit"):
+                did = self._admit_deferred() > 0 or did
+                free = self.free_slots
+                if reqs is None and free and not self._deferred:
+                    wait = 0 if (self.active or did) else idle_wait_s
+                    reqs = batcher.next_batch(max_n=free, wait_s=wait)
+                if reqs:
+                    did = self.admit(reqs) > 0 or did
+            did = self.prefill_tick() or did
+            return self.decode_tick() or did
 
     def _drive(self, reqs: List[Request]) -> None:
         """Run the engine loop until every given request completes (the

@@ -20,12 +20,22 @@ its entire subtree — children cost one thread-local check, nothing is
 recorded.
 
 Levels (the ``--trace_level`` flag / ``trace.enable(level=...)``):
-  0  tracing off — every ``span()`` is a near-free no-op;
+  0  nothing kept — a scoped ``span()`` is one profiler annotation
+     (below), every other call a no-op;
   1  span tracing: executor compile/run, serving request/queue/execute,
      trainer iterations;
   2  per-op debug: ``Executor.run`` additionally switches to the
      interpret-mode path (op-by-op host dispatch with per-op spans,
      output stats, and located NaN/Inf diagnosis).
+
+A scoped span is also a profiler annotation: ``span(name, **attrs)``
+opens a ``jax.profiler.TraceAnnotation(name, **attrs)`` round the same
+region at EVERY level. With no profiler session that records nothing
+(about a microsecond a span); under one (``jax.profiler.start_trace`` on
+a live server or trainer) the program's spans sit in the xplane's host
+plane beside the device ops, on the profiler's clock. ``record()``
+(already-timed regions, which is what the level-2 per-op spans are) and
+``start_span()`` (detached request spans) stay ring-buffer only.
 
 Cross-process context: ``Tracer.inject()`` renders the current (or a
 given) span as a W3C ``traceparent`` header value and
@@ -39,13 +49,14 @@ from N processes stitch without collisions
 """
 from __future__ import annotations
 
-import contextlib
 import itertools
 import secrets
 import threading
 import time
 from collections import deque
-from typing import Dict, Iterator, List, Optional
+from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 # Default ring-buffer capacity: generous for a debug session, bounded for
 # a long-lived traced server (at ~200 B/span this is ~3 MB).
@@ -126,6 +137,33 @@ class Span:
             else "open"
         return (f"Span({self.name!r}, id={self.span_id}, "
                 f"parent={self.parent_id}, {dur})")
+
+
+class _Scope:
+    """What ``Tracer.span`` returns: the profiler annotation and, at
+    level >= 1, the ring-buffer span of one region. The annotation opens
+    first and closes last, so it contains the span by microseconds."""
+
+    __slots__ = ("_tracer", "_name", "_attrs", "_annotation", "_span",
+                 "_pushed")
+
+    def __init__(self, tracer: "Tracer", name: str, attrs: dict):
+        self._tracer = tracer
+        self._name = name
+        self._attrs = attrs
+
+    def __enter__(self) -> Optional[Span]:
+        self._annotation = TraceAnnotation(self._name, **self._attrs)
+        self._annotation.__enter__()
+        self._pushed = self._tracer.enabled
+        self._span = self._tracer.start_span(self._name, **self._attrs) \
+            if self._pushed else None
+        return self._span
+
+    def __exit__(self, *exc) -> None:
+        if self._pushed:
+            self._tracer._pop(self._span)
+        self._annotation.__exit__(*exc)
 
 
 class Tracer:
@@ -249,19 +287,14 @@ class Tracer:
         elif sp is not None:
             self._end_span(sp)
 
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs) -> Iterator[Optional[Span]]:
-        """Scoped span: nests under the current thread's open span.
-        Yields the Span (or None when disabled/sampled out) so the body
-        can attach attributes."""
-        if not self.enabled:
-            yield None
-            return
-        sp = self.start_span(name, **attrs)
-        try:
-            yield sp
-        finally:
-            self._pop(sp)
+    def span(self, name: str, **attrs) -> _Scope:
+        """Scoped span: ``with tracer.span("name", k=v) as sp:`` nests
+        under the current thread's open span and yields the Span (None
+        when disabled or sampled out) so the body can attach attributes.
+        At every level the region is also one
+        ``jax.profiler.TraceAnnotation(name, **attrs)``, so ``attrs`` are
+        counts and short strings, never arrays."""
+        return _Scope(self, name, attrs)
 
     def record(self, name: str, start: float, end: float,
                parent: Optional[Span] = None, **attrs) -> Optional[Span]:
@@ -387,8 +420,10 @@ def active_level() -> int:
 
 
 def span(name: str, **attrs):
-    """``with trace.span("name", k=v) as sp:`` against the global
-    tracer."""
+    """Scoped span on the global tracer AND a ``jax.profiler.TraceAnnotation``
+    (at every level, so ``jax.profiler.start_trace`` on a live process
+    shows it beside the device ops): ``with trace.span("name", k=v) as
+    sp:``."""
     return _global_tracer.span(name, **attrs)
 
 

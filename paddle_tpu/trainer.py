@@ -536,7 +536,9 @@ class SGD:
         is full. Iteration spans split into ``trainer/dispatch`` and
         ``trainer/resolve`` phases carrying a ``queue_depth`` attr, so
         tools/trace_summary.py --pipeline shows host gap vs device
-        time."""
+        time; the feed thread's ``trainer/feed_stack`` (rows -> one host
+        array per feed) and ``trainer/feed_put`` (the ``device_put``
+        calls) say what a ``data_wait`` waited for."""
         import time as time_mod
         from collections import deque
 
@@ -557,16 +559,21 @@ class SGD:
                     bs = len(batch)
                 except TypeError:
                     bs = None
-                yield batch_id, bs, feeder.feed(batch)
+                with trace.span("trainer/feed_stack", batch_id=batch_id):
+                    feed = feeder.feed(batch)
+                yield batch_id, bs, feed
 
         def to_device(item):
             batch_id, bs, feed = item
             if dev is None:  # mesh runs: the executor shards feeds itself
                 return batch_id, bs, feed
-            return batch_id, bs, {k: (jax.device_put(v, dev)
-                                      if not isinstance(v, jax.Array)
-                                      else v)
-                                  for k, v in feed.items()}
+            # the span times the host call: the transfer is the device
+            # trace's, and nothing here waits for it
+            with trace.span("trainer/feed_put", batch_id=batch_id):
+                feed = {k: (jax.device_put(v, dev)
+                            if not isinstance(v, jax.Array) else v)
+                        for k, v in feed.items()}
+            return batch_id, bs, feed
 
         m = meter
         perf = time_mod.perf_counter

@@ -606,3 +606,239 @@ class TestDistributedFleetTrace:
             capture_output=True, text=True, timeout=120)
         assert out2.returncode == 0
         assert f"{tid:032x}" in out2.stdout
+
+
+# ---------------------------------------------------------------------------
+# PR 24: a scoped span is also a profiler annotation, on the profiler's
+# clock, and changes no compiled program
+# ---------------------------------------------------------------------------
+def _toy_trainer():
+    from paddle_tpu import reader as reader_mod
+    from paddle_tpu.trainer import SGD
+
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        x = layers.data("x", shape=[8])
+        y = layers.data("y", shape=[1], dtype="int64")
+        cost = layers.mean(layers.softmax_with_cross_entropy(
+            layers.fc(x, size=3), y))
+        sgd = SGD(cost=cost, optimizer=pt.optimizer.SGDOptimizer(0.2),
+                  feed_list=[x, y], place=pt.CPUPlace())
+    rng = np.random.RandomState(0)
+    xs = rng.rand(32, 8).astype("float32")
+    ys = rng.randint(0, 3, size=(32, 1)).astype("int64")
+
+    def rows():
+        for i in range(32):
+            yield xs[i], ys[i]
+
+    return sgd, reader_mod.batch(rows, 8)
+
+
+class _Profile:
+    """``with _Profile(dir) as p:`` ... then ``p.host()``: every event of
+    the xplane's host plane as (thread line index, name, start s, end s,
+    stats), read the way ``benchmark/trace_reduce.load`` does."""
+
+    def __init__(self, logdir):
+        self.dir = str(logdir)
+
+    def __enter__(self):
+        import jax
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(self.dir, profiler_options=opts)
+        return self
+
+    def __exit__(self, *exc):
+        import jax
+
+        jax.profiler.stop_trace()
+
+    def host(self):
+        import glob
+
+        import jax
+
+        path = sorted(glob.glob(os.path.join(
+            self.dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+        out = []
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            if plane.name != "/host:CPU":
+                continue
+            for i, line in enumerate(plane.lines):
+                for ev in line.events:
+                    if "/" not in ev.name or ev.name.startswith("$"):
+                        continue
+                    out.append((i, ev.name, ev.start_ns * 1e-9,
+                                (ev.start_ns + ev.duration_ns) * 1e-9,
+                                {k: v for k, v in ev.stats}))
+        return out
+
+
+class TestSpansOnTheProfilerClock:
+    def test_level1_chunked_prefill_finishes_and_carries_offset(self):
+        """Pins the collision ``prefill_tick`` had: ``start=`` handed to
+        ``trace.record(name, start, end, ...)`` raised after the device
+        ran, so with the tracer on no prompt longer than one chunk ever
+        finished."""
+        trace.enable(level=1)
+        eng = _gen_engine(prefill_chunk=8, prompt_buckets=(8, 16))
+        prompt = np.arange(20, dtype=np.int64) % VOCAB   # 3 chunks
+        with Server(eng, max_wait_ms=1.0) as srv:
+            ids = srv.generate(prompt, max_new_tokens=3, timeout_s=120)
+        assert len(np.asarray(ids)) == 23
+        spans = trace.get_tracer().spans()
+        chunks = [s for s in spans if s.name == "serving/prefill_chunk"]
+        assert [s.attrs["offset"] for s in chunks] == [0, 8, 16]
+        execs = [s for s in spans if s.name == "serving/execute"
+                 and s.attrs.get("phase") == "prefill_chunk"]
+        assert [s.attrs["offset"] for s in execs] == [0, 8, 16]
+        assert [s.attrs["tokens"] for s in execs] == [8, 8, 4]
+        assert eng.metrics.counter("prefill_chunks") == 3
+
+    def test_level0_spans_sit_in_the_profile_by_thread(self, tmp_path):
+        """Tracer off, profiler on: the serve pass holds its chunk and
+        its tick, the trainer's feed stage shows on a thread of its own,
+        and the ring buffer keeps nothing."""
+        eng = _gen_engine(prefill_chunk=8, prompt_buckets=(8, 16))
+        sgd, batches = _toy_trainer()
+        prompt = np.arange(20, dtype=np.int64) % VOCAB
+        with Server(eng, max_wait_ms=1.0) as srv:
+            # warm every shape on another prompt: the same one again
+            # would hit the prefix cache and skip its chunks
+            srv.generate((prompt + 5) % VOCAB, max_new_tokens=2,
+                         timeout_s=120)
+            with _Profile(tmp_path) as prof:
+                srv.generate(prompt, max_new_tokens=4, timeout_s=120)
+                sgd.train(batches, num_passes=1,
+                          event_handler=lambda e: None, async_depth=2)
+        assert len(trace.get_tracer()) == 0
+        events = prof.host()
+        passes = [e for e in events if e[1] == "serving/pass"]
+        assert passes
+
+        def inside(name):
+            return [e for e in events if e[1] == name and any(
+                p[0] == e[0] and p[2] <= e[2] and e[3] <= p[3]
+                for p in passes)]
+
+        chunks = inside("serving/prefill_chunk")
+        assert [c[4]["offset"] for c in chunks] == [0, 8, 16]
+        assert all(c[4]["tokens"] in (8, 4) for c in chunks)
+        assert len(inside("serving/decode_step")) >= 3
+        assert inside("serving/admit")
+        # every pass did work: one device call at least
+        calls = [e for e in events if e[1] in (
+            "serving/prefill_chunk", "serving/prefill_group",
+            "serving/decode_step")]
+        for p in passes:
+            assert any(p[2] <= c[2] and c[3] <= p[3] for c in calls), p
+        stack = [e for e in events if e[1] == "trainer/feed_stack"]
+        put = [e for e in events if e[1] == "trainer/feed_put"]
+        dispatch = [e for e in events if e[1] == "trainer/dispatch"]
+        assert len(stack) == len(put) == len(dispatch) == 4
+        feed_threads = {e[0] for e in stack + put}
+        assert len(feed_threads) == 1
+        assert feed_threads.isdisjoint({e[0] for e in dispatch})
+        assert feed_threads.isdisjoint({p[0] for p in passes})
+
+    def test_level1_span_and_annotation_agree_to_100us(self, tmp_path):
+        """One region, two records: the ring-buffer span (perf_counter)
+        mapped through a ``bench/clock_sync``-style marker lands on its
+        annotation (the profiler's clock)."""
+        import jax
+
+        tracer = trace.enable(level=1)
+        with _Profile(tmp_path) as prof:
+            for _ in range(20):
+                with jax.profiler.TraceAnnotation(
+                        "test/clock_sync", perf_ns=time.perf_counter_ns()):
+                    pass
+            for i in range(20):
+                with trace.span("test/probe", i=i):
+                    time.sleep(0.001)
+        pc = time.perf_counter()
+        epoch = pc - trace.record("test/epoch", pc, pc).start
+        events = prof.host()
+        # the clock is read before the marker opens: the largest
+        # difference is the one with the least delay in between
+        offset = max(int(e[4]["perf_ns"]) * 1e-9 - e[2]
+                     for e in events if e[1] == "test/clock_sync")
+        anns = {int(e[4]["i"]): e for e in events if e[1] == "test/probe"}
+        spans = {s.attrs["i"]: s for s in tracer.spans()
+                 if s.name == "test/probe"}
+        assert len(anns) == len(spans) == 20
+        off_start, off_end = [], []
+        for i, sp in spans.items():
+            _, _, a0, a1, _ = anns[i]
+            s0, s1 = sp.start + epoch - offset, sp.end + epoch - offset
+            assert a0 - 1e-4 <= s0 and s1 <= a1 + 1e-4   # contained
+            off_start.append(s0 - a0)
+            off_end.append(a1 - s1)
+        assert sorted(off_start)[10] < 100e-6
+        assert sorted(off_end)[10] < 100e-6
+
+    @pytest.mark.parametrize("what", ["train_step", "paged_decode"])
+    def test_tracer_level_changes_no_lowered_program(self, what,
+                                                     monkeypatch):
+        """The StableHLO text of every program the flow compiles, debug
+        locations included, is byte-identical with the tracer at level 0
+        and at level 1: a span adds nothing to what is lowered."""
+        from paddle_tpu.core.executor import Executor
+
+        texts = []
+        real = Executor._aot_compile
+
+        def spy(self, jitted, args):
+            texts.append(jitted.lower(*args).as_text(debug_info=True))
+            return real(self, jitted, args)
+
+        _init_lm_scope()    # the module's weight cache compiles but once
+        monkeypatch.setattr(Executor, "_aot_compile", spy)
+        seen = []
+        for level in (0, 1):
+            trace.get_tracer().configure(level=level)
+            texts.clear()
+            if what == "train_step":
+                sgd, batches = _toy_trainer()
+                sgd.train(batches, num_passes=1,
+                          event_handler=lambda e: None, async_depth=2)
+            else:
+                eng = _gen_engine(prefill_chunk=8, prompt_buckets=(8, 16))
+                eng.generate_all([np.arange(20, dtype=np.int64) % VOCAB],
+                                 max_new_tokens=3)
+            seen.append(list(texts))
+        assert seen[0] and seen[0] == seen[1]
+        assert any("stablehlo" in t for t in seen[0])
+
+    def test_feed_put_span_waits_for_no_transfer(self, monkeypatch):
+        """No span site syncs: ``trainer/feed_put`` closes on the feed
+        thread while what ``device_put`` returned is still in flight, and
+        nothing ever asks it to be ready."""
+        import jax
+
+        waited = []
+
+        class InFlight(np.ndarray):
+            def block_until_ready(self):
+                waited.append(threading.get_ident())
+                return self
+
+        def device_put(value, device=None, **kw):
+            return np.asarray(value).view(InFlight)
+
+        monkeypatch.setattr(jax, "device_put", device_put)
+        tracer = trace.enable(level=1)
+        sgd, batches = _toy_trainer()
+        sgd.train(batches, num_passes=1, event_handler=lambda e: None,
+                  async_depth=2)
+        puts = [s for s in tracer.spans() if s.name == "trainer/feed_put"]
+        stacks = [s for s in tracer.spans()
+                  if s.name == "trainer/feed_stack"]
+        assert len(puts) == len(stacks) == 4
+        assert {s.thread for s in puts} == {s.thread for s in stacks}
+        assert threading.get_ident() not in {s.thread for s in puts}
+        assert waited == []
