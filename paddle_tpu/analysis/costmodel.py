@@ -530,19 +530,19 @@ def _encdec_cost(attrs, ins, outs):
 def _paged_cache_cost(attrs, ins, outs):
     """transformer_stack_paged_prefill/decode: the slot-cache cost plus
     the per-row gathered context — every row streams its table-width
-    [Hkv, P*ps, dh] K/V block per layer (x2 for K and V), which is the
+    [P*ps, Hkv*dh] K/V block per layer (x2 for K and V), which is the
     decode plane's dominant HBM term and what the dense path reads as
     contiguous slot rows."""
     base = _slot_cache_cost(attrs, ins, outs)
     table = _first(ins, "BlockTable")
     pool = _first(ins, "CacheK")
     gathered = 0.0
-    if table is not None and pool is not None and len(pool.shape) == 5:
-        L, _, hkv, ps, dh = pool.shape
+    if table is not None and pool is not None and len(pool.shape) == 4:
+        L, _, ps, width = pool.shape  # width = Hkv*dh
         rows, P = table.shape
         itemsize = np.dtype(pool.dtype).itemsize
-        gathered = 2.0 * float(L) * float(rows) * float(hkv) \
-            * float(P) * float(ps) * float(dh) * itemsize
+        gathered = 2.0 * float(L) * float(rows) * float(P) * float(ps) \
+            * float(width) * itemsize
     return OpCost(flops=base.flops, bytes=base.bytes + gathered)
 
 

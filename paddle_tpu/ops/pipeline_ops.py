@@ -750,7 +750,7 @@ def transformer_stack_slot_decode(attrs, ins, rng=None):
 # ---------------------------------------------------------------------------
 # Paged-cache decode ops: the block-table serving path (vLLM's
 # PagedAttention layout on the slot-op machinery). The KV cache is a PAGE
-# POOL [L, N, Hkv, page_size, dh] living in the scope; a per-row int32
+# POOL [L, N, page_size, Hkv*dh] living in the scope; a per-row int32
 # block table maps logical positions to physical pages, so a sequence
 # holds exactly ceil(len / page_size) pages instead of a dense Tmax row —
 # and a page shared by several sequences (a common system prompt) is
@@ -758,9 +758,25 @@ def transformer_stack_slot_decode(attrs, ins, rng=None):
 # Page 0 is the scrap page: padding rows and vacant decode slots write
 # there and nothing ever attends to it. Both ops read AND write the pool,
 # so the executor threads it as donated read-write state exactly like the
-# dense slot table. (The gather materialises each row's table-width
-# context per layer — same decode HBM traffic as the dense path; the win
-# is CAPACITY. A Pallas per-page-DMA kernel is the follow-on TPU lever.)
+# dense slot table.
+#
+# Why a token's K/V is ONE row of Hkv*dh floats: the TPU runtime derives
+# an array's device layout from its shape alone, and puts the LARGEST
+# dimension on the 128 lanes when the last one is narrower than 128. A
+# pool [L, N, Hkv, ps, dh] with dh 64 therefore lived on the v5e with the
+# PAGE axis on the lanes (minor-to-major {1,4,3,2,0}): every page was
+# strided through its whole layer, and each tick re-laid out every
+# layer's pool round the scatter and the gather and copied the whole pool
+# once (~95 of a 162 ms tick, all proportional to N). With Hkv*dh last a
+# page is contiguous and lane-dense on the device as it is in this shape.
+#
+# What a step moves now (_scan_paged_layers): per layer and pool, one
+# in-place scatter of the new tokens' rows (t * Hkv*dh floats a batch
+# row) and one gather of each row's table-width context [P*ps, Hkv*dh],
+# which reference_attention then reads — proportional to rows x table
+# width, not to N. It no longer slices, transposes, restacks or copies
+# the pool. The table-width gather is what is left for a Pallas
+# paged-attention kernel that reads only the pages a row holds.
 # ---------------------------------------------------------------------------
 
 _SAMPLING_SLOTS = ("Temperature", "TopK", "TopP", "Seed", "Step", "Mask")
@@ -806,15 +822,74 @@ def _maybe_topk(attrs, ins, logits, outs):
     return outs
 
 
-def _gather_pages(pool_l, table):
-    """pool_l [N, Hkv, ps, dh] gathered by table [b, P] -> the flattened
-    context [b, Hkv, P*ps, dh]: flattened position j holds the token at
-    sequence position j (table entry i covers positions i*ps..(i+1)*ps-1,
-    so position order survives the transpose/reshape)."""
+def _gather_pages(pool, layer, table, num_kv_heads):
+    """Layer ``layer``'s pages of pool [L, N, ps, Hkv*dh] gathered by
+    table [b, P] -> the flattened context [b, Hkv, P*ps, dh]: flattened
+    position j holds the token at sequence position j (table entry i
+    covers positions i*ps..(i+1)*ps-1, and a page keeps its rows in
+    position order). ONE gather at (layer, page) straight from the whole
+    pool — no layer slice of the pool is ever materialised."""
     b, P = table.shape
-    _, hkv, ps, dh = pool_l.shape
-    ctx = pool_l[table]  # [b, P, Hkv, ps, dh]
-    return ctx.transpose(0, 2, 1, 3, 4).reshape(b, hkv, P * ps, dh)
+    ps, width = pool.shape[2:]
+    ctx = pool[layer, table]  # [b, P, ps, Hkv*dh]
+    ctx = ctx.reshape(b, P * ps, num_kv_heads, width // num_kv_heads)
+    return ctx.transpose(0, 2, 1, 3)
+
+
+def _finish_ffn(layer_p, h, ctx, _x_l):
+    """The plain LM block's ``finish``: out-projection + FFN."""
+    return _attn_out_ffn(layer_p, h, ctx)
+
+
+def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
+                       page_row, project, mask, finish=_finish_ffn,
+                       xs=None):
+    """The layer loop of every paged op: h [b, t, d] through the L
+    stacked blocks with the page pools [L, N, ps, Hkv*dh] as the scan's
+    CARRY, updated in place.
+
+    Per layer l: ``project(layer_p, h)`` -> q [b, H, t, dh], k/v
+    [b, Hkv, t, dh]; the t new tokens of each batch row are written with
+    ONE scatter per pool at (l, page_id, page_row) — page_id / page_row
+    are [b, t] (decode: t == 1), the three index arrays are adjacent and
+    the update window is a token's whole [Hkv*dh] row, contiguous in the
+    pool; the context is gathered at (l, table) and attended with
+    ``reference_attention(**mask)``; ``finish(layer_p, h, ctx, x_l)``
+    closes the block, x_l being layer l's slice of the optional
+    scanned-over ``xs``. Returns (h, cache_k, cache_v).
+
+    The pools are never scanned-over inputs or stacked outputs: that form
+    sliced layer l's pool out, re-laid it out round the scatter, restacked
+    it and copied the whole pool once per call — all proportional to N,
+    none to the tokens in flight. As carry the donated buffers ARE the
+    loop state and the only pool-shaped ops are the two in-place
+    scatters."""
+    from ..kernels.flash_attention import reference_attention
+
+    b, t, d = h.shape
+    n_layers = cache_k.shape[0]
+    ix_page = page_id.reshape(b, t)
+    ix_row = page_row.reshape(b, t)
+
+    def token_rows(a):  # [b, Hkv, t, dh] -> [b, t, Hkv*dh]
+        return a.transpose(0, 2, 1, 3).reshape(b, t, -1)
+
+    def layer(carry, inp):
+        h, ck, cv = carry
+        layer_p, l, x_l = inp
+        q, k, v = project(layer_p, h)
+        hkv = k.shape[1]
+        ck = ck.at[l, ix_page, ix_row].set(token_rows(k))
+        cv = cv.at[l, ix_page, ix_row].set(token_rows(v))
+        ctx = reference_attention(q, _gather_pages(ck, l, table, hkv),
+                                  _gather_pages(cv, l, table, hkv), **mask)
+        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, t, d)
+        return (finish(layer_p, h, ctx, x_l), ck, cv), None
+
+    (h, cache_k, cache_v), _ = jax.lax.scan(
+        layer, (h, cache_k, cache_v),
+        (params, jnp.arange(n_layers, dtype=jnp.int32), xs))
+    return h, cache_k, cache_v
 
 
 @register_op("transformer_stack_paged_prefill",
@@ -829,12 +904,18 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     the k-th chunk of a streaming long prompt), Lengths [b] int32 (valid
     tokens in THIS chunk, 0..Tc; 0 marks a padding row), BlockTable
     [b, P] int32 (the row's full logical->physical page map; padding
-    entries 0), CacheK/CacheV [L, N, Hkv, ps, dh] page pools, plus the
+    entries 0), CacheK/CacheV [L, N, ps, Hkv*dh] page pools, plus the
     shared LM weights. attrs carry ``page_size`` next to the decode-op
     set. Returns NextTok [b] — argmax/sample from each row's LAST VALID
     chunk position (the first generated token when this chunk completes
     the prompt; garbage otherwise) — and the pools with the chunk's K/V
     scattered into rows StartPos..StartPos+Lengths-1 of each row's pages.
+
+    The pools ride the layer loop as its in-place carry
+    (``_scan_paged_layers``): a chunk writes b*Tc token rows per layer
+    and pool and gathers b table-width contexts; nothing it moves is
+    proportional to N, and no page outside the written (layer, page,
+    row) cells changes.
 
     Queries attend the row's WHOLE gathered context block-causally (chunk
     token at absolute position p sees cached position j iff j <= p), so a
@@ -868,9 +949,8 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     num_kv_heads = attrs.get("num_kv_heads") or num_heads
     use_rope = attrs.get("use_rope", False)
     b, Tc = chunk.shape
-    ps = cache_k.shape[3]
+    ps = cache_k.shape[2]
     P = table.shape[1]
-    d = params["ln1_s"].shape[1]
     # absolute positions + per-token page targets (padding -> scrap 0)
     pos = start[:, None] + jnp.arange(Tc, dtype=jnp.int32)[None, :]
     valid = jnp.arange(Tc, dtype=jnp.int32)[None, :] < lengths[:, None]
@@ -881,23 +961,11 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     x = tok_emb[chunk]
     if pos_emb is not None:
         x = x + pos_emb[jnp.clip(pos, 0, pos_emb.shape[0] - 1)]
-    from ..kernels.flash_attention import reference_attention
-
-    def layer(h, inp):
-        layer_p, ck_l, cv_l = inp  # pools [N, Hkv, ps, dh]
-        q, k, v = _attn_proj(layer_p, h, num_heads, num_kv_heads,
-                             use_rope, pos0=start)
-        # k/v [b, Hkv, Tc, dh] -> page (page_id, page_row) per token
-        ck_l = ck_l.at[page_id, :, page_row, :].set(k.transpose(0, 2, 1, 3))
-        cv_l = cv_l.at[page_id, :, page_row, :].set(v.transpose(0, 2, 1, 3))
-        ctx = reference_attention(q, _gather_pages(ck_l, table),
-                                  _gather_pages(cv_l, table),
-                                  causal=True, q_pos0=start)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, Tc, d)
-        return _attn_out_ffn(layer_p, h, ctx), (ck_l, cv_l)
-
-    h, (cache_k, cache_v) = jax.lax.scan(layer, x,
-                                         (params, cache_k, cache_v))
+    h, cache_k, cache_v = _scan_paged_layers(
+        params, x, cache_k, cache_v, table, page_id, page_row,
+        lambda p, h: _attn_proj(p, h, num_heads, num_kv_heads, use_rope,
+                                pos0=start),
+        dict(causal=True, q_pos0=start))
     last = h[jnp.arange(b), jnp.clip(lengths, 1, Tc) - 1]  # [b, d]
     logits = _logits_fn(ln_s, ln_b, head_w)(last)
     nxt = _pick_rows(attrs, ins, rng, head_w.shape[1], logits)
@@ -915,10 +983,16 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     Tok [S] int (the pending token per slot), Pos [S] int32 (its sequence
     position == rows already cached for the slot), BlockTable [S, P]
     int32 (per-slot page map; vacant slots feed all-zeros + Pos 0, so
-    their write lands in the scrap page), CacheK/CacheV [L, N, Hkv, ps,
-    dh] page pools, plus the shared LM weights. Returns NextTok [S] and
-    the pools with each slot's token K/V written at page
+    their write lands in the scrap page), CacheK/CacheV [L, N, ps,
+    Hkv*dh] page pools, plus the shared LM weights. Returns NextTok [S]
+    and the pools with each slot's token K/V written at page
     BlockTable[s, Pos//ps] row Pos%ps.
+
+    The pools ride the layer loop as its in-place carry
+    (``_scan_paged_layers``): a tick writes S token rows per layer and
+    pool and gathers S table-width contexts [P*ps, Hkv*dh] — the weights
+    plus that gather are what a tick moves; no layer of the pool is
+    sliced, re-laid out, restacked or copied.
 
     The slot axis is the batch axis and the table width is static, so the
     compiled shape never depends on occupancy or sequence lengths — the
@@ -952,9 +1026,8 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     if S != table.shape[0]:
         raise ValueError(f"Tok has {S} slots but the block table holds "
                          f"{table.shape[0]}")
-    ps = cache_k.shape[3]
+    ps = cache_k.shape[2]
     P = table.shape[1]
-    d = params["ln1_s"].shape[1]
     pos = jnp.clip(pos, 0, P * ps - 1)
     x = tok_emb[tok]
     if pos_emb is not None:
@@ -963,22 +1036,11 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     srange = jnp.arange(S)
     page_id = table[srange, pos // ps]  # [S]
     page_row = pos % ps
-    from ..kernels.flash_attention import reference_attention
-
-    def layer(h1, inp):
-        layer_p, ck_l, cv_l = inp  # pools [N, Hkv, ps, dh]
-        q, k, v = _attn_proj(layer_p, h1, num_heads, num_kv_heads,
-                             use_rope, pos0=pos)
-        ck_l = ck_l.at[page_id, :, page_row, :].set(k[:, :, 0, :])
-        cv_l = cv_l.at[page_id, :, page_row, :].set(v[:, :, 0, :])
-        ctx = reference_attention(q, _gather_pages(ck_l, table),
-                                  _gather_pages(cv_l, table),
-                                  lengths=pos + 1)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(S, 1, d)
-        return _attn_out_ffn(layer_p, h1, ctx), (ck_l, cv_l)
-
-    h1, (cache_k, cache_v) = jax.lax.scan(layer, h1,
-                                          (params, cache_k, cache_v))
+    h1, cache_k, cache_v = _scan_paged_layers(
+        params, h1, cache_k, cache_v, table, page_id, page_row,
+        lambda p, h: _attn_proj(p, h, num_heads, num_kv_heads, use_rope,
+                                pos0=pos),
+        dict(lengths=pos + 1))
     logits = _logits_fn(ln_s, ln_b, head_w)(h1[:, 0])
     nxt = _pick_rows(attrs, ins, rng, head_w.shape[1], logits)
     outs = out(NextTok=nxt.astype(tok.dtype),
@@ -991,7 +1053,7 @@ def kv_cache_page_copy(attrs, ins):
     """Copy whole KV pages inside the pools: the copy-on-write step.
 
     Src [n] int32, Dst [n] int32 (distinct destination pages),
-    CacheK/CacheV [L, N, Hkv, ps, dh]. Writes pool[:, Dst[i]] =
+    CacheK/CacheV [L, N, ps, Hkv*dh]. Writes pool[:, Dst[i]] =
     pool[:, Src[i]] for both pools and echoes Dst as Ok [n] (a fetchable
     witness — the real outputs are the donated pool updates). The serving
     engine runs this when a sequence is about to write into a page whose

@@ -28,8 +28,8 @@ import jax.numpy as jnp
 from ..core.registry import register_op
 from .common import maybe, out, single
 from .pipeline_ops import (_SAMPLING_SLOTS, _STACK_SLOTS, _attn_out_ffn,
-                           _attn_proj, _expand_kv, _gather_pages,
-                           _logits_fn, _ln, _maybe_topk, _pick_rows)
+                           _attn_proj, _expand_kv, _logits_fn, _ln,
+                           _maybe_topk, _pick_rows, _scan_paged_layers)
 
 # encoder stack slots: the same 10-weight block layout, Enc-prefixed
 _ENC_SLOTS = {f"Enc{slot}": key for slot, key in _STACK_SLOTS.items()}
@@ -55,6 +55,26 @@ def _cross_attend(h1, xw, ck_x, cv_x, src_len, num_heads):
     ctx = reference_attention(q, ck_x, cv_x, lengths=src_len)
     ctx = ctx.transpose(0, 2, 1, 3).reshape(b, t, d)
     return h1 + jnp.einsum("btd,de->bte", ctx, xw["xoutw"])
+
+
+def _cross_block(xslot, src_len, num_heads):
+    """The ``finish`` of _scan_paged_layers for a decoder layer: self-attn
+    out-projection + residual, the cross-attention block over the layer's
+    parked encoder rows (x_l = its CrossK/CrossV [S+1, Hkv, Ts, dh] and
+    cross weights), then the FFN."""
+
+    def finish(layer_p, h, ctx, x_l):
+        xk_l, xv_l, xw = x_l
+        h = h + jnp.einsum("btd,de->bte", ctx, layer_p["out_w"])
+        h = _cross_attend(h, xw, xk_l[xslot], xv_l[xslot], src_len,
+                          num_heads)
+        h2 = _ln(h, layer_p["ln2_s"], layer_p["ln2_b"])
+        ff = jax.nn.gelu(jnp.einsum("btd,df->btf", h2, layer_p["ff_w1"])
+                         + layer_p["ff_b1"])
+        return h + jnp.einsum("btf,fd->btd", ff, layer_p["ff_w2"]) \
+            + layer_p["ff_b2"]
+
+    return finish
 
 
 def _encode_memory(ins, attrs, src, src_len):
@@ -167,9 +187,8 @@ def transformer_stack_cross_prefill(attrs, ins, rng=None):
     num_heads = attrs["num_heads"]
     num_kv_heads = attrs.get("num_kv_heads") or num_heads
     b, Tc = chunk.shape
-    ps = cache_k.shape[3]
+    ps = cache_k.shape[2]
     P = table.shape[1]
-    d = params["ln1_s"].shape[1]
     pos = start[:, None] + jnp.arange(Tc, dtype=jnp.int32)[None, :]
     valid = jnp.arange(Tc, dtype=jnp.int32)[None, :] < lengths[:, None]
     entry = jnp.clip(pos // ps, 0, P - 1)
@@ -179,35 +198,12 @@ def transformer_stack_cross_prefill(attrs, ins, rng=None):
     x = tok_emb[chunk]
     if pos_emb is not None:
         x = x + pos_emb[jnp.clip(pos, 0, pos_emb.shape[0] - 1)]
-    from ..kernels.flash_attention import reference_attention
-
-    def layer(h, inp):
-        (layer_p, ck_l, cv_l, xk_l, xv_l, xlns, xlnb, xqw, xoutw) = inp
-        xw = {"xlns": xlns, "xlnb": xlnb, "xqw": xqw, "xoutw": xoutw}
-        q, k, v = _attn_proj(layer_p, h, num_heads, num_kv_heads,
-                             pos0=start)
-        ck_l = ck_l.at[page_id, :, page_row, :].set(k.transpose(0, 2, 1, 3))
-        cv_l = cv_l.at[page_id, :, page_row, :].set(v.transpose(0, 2, 1, 3))
-        ctx = reference_attention(q, _gather_pages(ck_l, table),
-                                  _gather_pages(cv_l, table),
-                                  causal=True, q_pos0=start)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, Tc, d)
-        # self-attn residual, then cross block, then FFN
-        h = h + jnp.einsum("btd,de->bte", ctx, layer_p["out_w"])
-        h = _cross_attend(h, xw, xk_l[xslot], xv_l[xslot], src_len,
-                          num_heads)
-        h2 = _ln(h, layer_p["ln2_s"], layer_p["ln2_b"])
-        ff = jax.nn.gelu(jnp.einsum("btd,df->btf", h2, layer_p["ff_w1"])
-                         + layer_p["ff_b1"])
-        h = h + jnp.einsum("btf,fd->btd", ff, layer_p["ff_w2"]) \
-            + layer_p["ff_b2"]
-        return h, (ck_l, cv_l)
-
-    h, (cache_k, cache_v) = jax.lax.scan(
-        layer, x,
-        (params, cache_k, cache_v, cross_k, cross_v,
-         xparams["xlns"], xparams["xlnb"], xparams["xqw"],
-         xparams["xoutw"]))
+    h, cache_k, cache_v = _scan_paged_layers(
+        params, x, cache_k, cache_v, table, page_id, page_row,
+        lambda p, h: _attn_proj(p, h, num_heads, num_kv_heads, pos0=start),
+        dict(causal=True, q_pos0=start),
+        finish=_cross_block(xslot, src_len, num_heads),
+        xs=(cross_k, cross_v, xparams))
     last = h[jnp.arange(b), jnp.clip(lengths, 1, Tc) - 1]
     logits = _logits_fn(ln_s, ln_b, head_w)(last)
     nxt = _pick_rows(attrs, ins, rng, head_w.shape[1], logits)
@@ -247,9 +243,8 @@ def transformer_stack_cross_decode(attrs, ins, rng=None):
     num_heads = attrs["num_heads"]
     num_kv_heads = attrs.get("num_kv_heads") or num_heads
     S = tok.shape[0]
-    ps = cache_k.shape[3]
+    ps = cache_k.shape[2]
     P = table.shape[1]
-    d = params["ln1_s"].shape[1]
     pos = jnp.clip(pos, 0, P * ps - 1)
     x = tok_emb[tok]
     if pos_emb is not None:
@@ -258,34 +253,12 @@ def transformer_stack_cross_decode(attrs, ins, rng=None):
     srange = jnp.arange(S)
     page_id = table[srange, pos // ps]
     page_row = pos % ps
-    from ..kernels.flash_attention import reference_attention
-
-    def layer(h1, inp):
-        (layer_p, ck_l, cv_l, xk_l, xv_l, xlns, xlnb, xqw, xoutw) = inp
-        xw = {"xlns": xlns, "xlnb": xlnb, "xqw": xqw, "xoutw": xoutw}
-        q, k, v = _attn_proj(layer_p, h1, num_heads, num_kv_heads,
-                             pos0=pos)
-        ck_l = ck_l.at[page_id, :, page_row, :].set(k[:, :, 0, :])
-        cv_l = cv_l.at[page_id, :, page_row, :].set(v[:, :, 0, :])
-        ctx = reference_attention(q, _gather_pages(ck_l, table),
-                                  _gather_pages(cv_l, table),
-                                  lengths=pos + 1)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(S, 1, d)
-        h = h1 + jnp.einsum("btd,de->bte", ctx, layer_p["out_w"])
-        h = _cross_attend(h, xw, xk_l[xslot], xv_l[xslot], src_len,
-                          num_heads)
-        h2 = _ln(h, layer_p["ln2_s"], layer_p["ln2_b"])
-        ff = jax.nn.gelu(jnp.einsum("btd,df->btf", h2, layer_p["ff_w1"])
-                         + layer_p["ff_b1"])
-        h = h + jnp.einsum("btf,fd->btd", ff, layer_p["ff_w2"]) \
-            + layer_p["ff_b2"]
-        return h, (ck_l, cv_l)
-
-    h1, (cache_k, cache_v) = jax.lax.scan(
-        layer, h1,
-        (params, cache_k, cache_v, cross_k, cross_v,
-         xparams["xlns"], xparams["xlnb"], xparams["xqw"],
-         xparams["xoutw"]))
+    h1, cache_k, cache_v = _scan_paged_layers(
+        params, h1, cache_k, cache_v, table, page_id, page_row,
+        lambda p, h: _attn_proj(p, h, num_heads, num_kv_heads, pos0=pos),
+        dict(lengths=pos + 1),
+        finish=_cross_block(xslot, src_len, num_heads),
+        xs=(cross_k, cross_v, xparams))
     logits = _logits_fn(ln_s, ln_b, head_w)(h1[:, 0])
     nxt = _pick_rows(attrs, ins, rng, head_w.shape[1], logits)
     outs = out(NextTok=nxt.astype(tok.dtype),

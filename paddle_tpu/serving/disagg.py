@@ -43,8 +43,10 @@ from .errors import BadRequestError, EngineClosedError, QueueFullError
 from .metrics import MetricsRegistry
 from .router import LeastLoadedPolicy, Router
 
-#: serialized-handoff schema version (reject anything else, typed)
-HANDOFF_V = 1
+#: serialized-handoff schema version (reject anything else, typed).
+#: v2: page bytes are [L, n, page_size, Hkv*dh] (one row a token); a v1
+#: blob carried [L, n, Hkv, page_size, dh] and must not be installed.
+HANDOFF_V = 2
 
 
 # ---------------------------------------------------------------------------

@@ -14,7 +14,7 @@ prompt-bucket) pair, all warmed up front.
 Two cache layouts share that loop, selected by ``kv_cache=``:
 
 - ``"paged"`` (default, :class:`PagedGenerationEngine`) — a page pool
-  ``[L, n_pages, Hkv, page_size, dh]`` plus per-slot block tables
+  ``[L, n_pages, page_size, Hkv*dh]`` plus per-slot block tables
   (vLLM's PagedAttention layout): a sequence holds ``ceil(len/page_size)``
   pages instead of a dense ``Tmax`` row, a shared page-aligned prompt
   prefix is stored ONCE (radix-style prefix index, copy-on-write on
@@ -1008,7 +1008,7 @@ class PagedGenerationEngine(GenerationEngine):
     """Continuous batcher over a PAGED KV cache with prefix sharing and
     chunked prefill.
 
-    The cache is a page pool ``[L, n_pages, Hkv, page_size, dh]`` (scope-
+    The cache is a page pool ``[L, n_pages, page_size, Hkv*dh]`` (scope-
     resident, donated in place like the dense table) plus a host-side
     per-slot block table: a sequence holds ``ceil(len/page_size)``
     physical pages, so HBM holds TOKENS IN FLIGHT, not slots x Tmax.
@@ -1133,8 +1133,7 @@ class PagedGenerationEngine(GenerationEngine):
         self._beam_jobs: List[BeamJob] = []
         self._seed_counter = 0    # default per-request seeds (sampled
                                   # requests without an explicit seed)
-        shape = (s.n_layers, self.n_pages, s.kv_heads, self.page_size,
-                 s.head_dim)
+        shape = self._pool_shape()
         if src is None:
             with self.executor.device_ctx():
                 self.scope.set(PAGED_CACHE_K, jnp.zeros(shape, jnp.float32))
@@ -1148,10 +1147,17 @@ class PagedGenerationEngine(GenerationEngine):
                                float(self.slots * self.pmax * 4))
         self._gauges()
 
-    def _cache_vars(self, helper):
+    def _pool_shape(self):
+        """[L, n_pages, page_size, Hkv*dh]: a token's K (or V) of one
+        layer is ONE contiguous row, so a page is contiguous and
+        lane-dense on the device (ops/pipeline_ops.py says why the
+        head-major [.., Hkv, page_size, dh] form was not)."""
         s = self.spec
-        shape = [s.n_layers, self.n_pages, s.kv_heads, self.page_size,
-                 s.head_dim]
+        return (s.n_layers, self.n_pages, self.page_size,
+                s.kv_heads * s.head_dim)
+
+    def _cache_vars(self, helper):
+        shape = list(self._pool_shape())
         ck = helper.create_global_variable(name=PAGED_CACHE_K, shape=shape,
                                            dtype="float32")
         cv = helper.create_global_variable(name=PAGED_CACHE_V, shape=shape,

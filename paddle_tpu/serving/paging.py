@@ -1,6 +1,6 @@
 """Host-side KV page accounting: the allocator and the prefix index.
 
-The device holds one page pool ``[L, n_pages, Hkv, page_size, dh]`` per
+The device holds one page pool ``[L, n_pages, page_size, Hkv*dh]`` per
 K/V (generation.py owns those tensors); THIS module owns the metadata —
 which physical pages are free, how many holders reference each page, and
 which pages cache which prompt prefixes. Everything here is plain Python

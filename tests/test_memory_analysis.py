@@ -481,7 +481,7 @@ class TestBudgetGating:
         eng = GenerationEngine(spec, pt.Scope(), slots=4, mem_budget=1e9)
         gauges = eng.metrics.snapshot()["gauges"]
         # the PAGE POOL is what is resident, not the dense table formula:
-        # [L, n_pages, Hkv, page_size, dh] x 2 (K and V), f32 with
+        # [L, n_pages, page_size, Hkv*dh] x 2 (K and V), f32 with
         # page_size=64 -> pmax=2 -> n_pages = slots*2 + 1 = 9
         assert eng.page_size == 64 and eng.n_pages == 9
         assert gauges["mem/kv_cache_bytes"] == 2 * (2 * 9 * 4 * 64 * 8) * 4

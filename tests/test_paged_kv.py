@@ -11,6 +11,7 @@ from paddle_tpu import layers, models
 from paddle_tpu.serving import (CacheExhaustedError, DynamicBatcher,
                                 GenerationEngine, LMSpec,
                                 PagedGenerationEngine, Request)
+from paddle_tpu.serving.generation import PAGED_CACHE_K, PAGED_CACHE_V
 
 VOCAB, D, L, H, MAXLEN = 32, 16, 2, 2, 64
 
@@ -335,3 +336,109 @@ class TestZeroRecompile:
         assert eng.metrics.counter("prefill_chunks") >= 3
         assert eng.metrics.counter("kv_cow_copies") >= 1
         assert eng.metrics.counter("prefix_hit_tokens") > 0
+
+
+# ---------------------------------------------------------------------------
+# the pools as the layer loop's in-place carry
+# ---------------------------------------------------------------------------
+def _pool_sized_ops(hlo, pool_shape):
+    """``copy`` / ``dynamic-slice`` instructions of the optimized HLO whose
+    result has the pool's or one layer-pool's element count."""
+    import re
+
+    sizes = {int(np.prod(pool_shape)), int(np.prod(pool_shape[1:]))}
+    found = []
+    for m in re.finditer(r"= \w+\[([\d,]+)\]\S* (copy|dynamic-slice)\(",
+                         hlo):
+        if int(np.prod([int(d) for d in m.group(1).split(",")])) in sizes:
+            found.append(m.group(0))
+    return found
+
+
+class TestInPlaceStep:
+    @pytest.mark.parametrize("which", ["decode", "prefill"])
+    def test_step_temporaries_do_not_scale_with_the_pool(self, which):
+        """THE structural pin: with n_pages far above the pages in use,
+        the compiled step's temporaries stay under half of ONE pool (the
+        scanned-over form took more than two: a layer slice, its
+        transposed twin, the restacked output and a whole-pool copy),
+        and no copy / dynamic-slice of pool or layer-pool size is left."""
+        scope, _ = _init_lm_scope(7)
+        eng = GenerationEngine(_spec(), scope, slots=2, page_size=8,
+                               n_pages=512, prefill_chunk=16,
+                               prompt_buckets=(16,),
+                               prefill_batch_buckets=(1,))
+        eng.warmup()
+        prog = (eng._decode_prog if which == "decode"
+                else eng._prefill_prog(16))[0]
+        aots = [c.aot for key, c in eng.executor._cache.items()
+                if key[0] == id(prog)]
+        assert len(aots) == 1
+        pool = eng.scope.get(PAGED_CACHE_K)
+        assert pool.shape[1] == 512
+        stats = aots[0].memory_analysis()
+        if stats is not None and hasattr(stats, "temp_size_in_bytes"):
+            assert stats.temp_size_in_bytes < pool.nbytes / 2, (
+                stats.temp_size_in_bytes, pool.nbytes)
+        assert not _pool_sized_ops(aots[0].as_text(), pool.shape)
+
+    def test_ticks_and_chunk_touch_only_the_written_cells(self):
+        """In-place safety: one prefill chunk and two consecutive decode
+        ticks on pools pre-filled with a seeded pattern change ONLY the
+        (layer, page, row) cells of the tokens they cached — every other
+        element is bitwise the pattern (scrap page 0 apart: vacant slots
+        and pad tokens write there) — the written rows are the dense slot
+        table's K/V rows scattered by the block table, and the tokens are
+        the dense engine's although every page still holds the pattern
+        beyond the rows written."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.serving.generation import CACHE_K, CACHE_V
+
+        scope_d, _ = _init_lm_scope(7)
+        scope_p, _ = _init_lm_scope(7)
+        dense = GenerationEngine(_spec(), scope_d, slots=2,
+                                 kv_cache="dense", prompt_buckets=(16,))
+        paged = GenerationEngine(_spec(), scope_p, slots=2, page_size=8,
+                                 n_pages=40, prefill_chunk=16,
+                                 prompt_buckets=(16,), prefix_sharing=False)
+        rng = np.random.RandomState(25)
+        shape = paged.scope.get(PAGED_CACHE_K).shape
+        assert shape == (L, 40, 8, D)  # [L, N, ps, Hkv*dh]
+        pattern = {n: rng.standard_normal(shape).astype("float32")
+                   for n in (PAGED_CACHE_K, PAGED_CACHE_V)}
+        with paged.executor.device_ctx():
+            for n, v in pattern.items():
+                paged.scope.set(n, jnp.asarray(v))
+        prompt = rng.randint(0, VOCAB, (11,)).astype("int64")
+        stays = []
+        for eng in (dense, paged):
+            req = Request({"prompt": prompt}, {"max_new_tokens": 8}, None)
+            eng.admit([req])  # 11 <= chunk: ONE prefill chunk
+            eng.decode_tick()
+            eng.decode_tick()
+            stays.append(next((i, st) for i, st in enumerate(eng._slots)
+                              if st is not None))
+        (slot_d, st_d), (_, st_p) = stays
+        assert st_p.generated == st_d.generated
+        assert len(st_p.generated) == 3
+        written = len(prompt) + 2  # the prompt + one row per tick
+        pages = list(st_p.pages)
+        assert 0 not in pages and len(pages) >= -(-written // 8)
+        for pname, dname in ((PAGED_CACHE_K, CACHE_K),
+                             (PAGED_CACHE_V, CACHE_V)):
+            got = np.asarray(paged.scope.get(pname))
+            rows = np.asarray(dense.scope.get(dname))[:, slot_d]
+            # [L, Hkv, Tmax, dh] -> one [Hkv*dh] row per position
+            rows = rows.transpose(0, 2, 1, 3).reshape(L, -1, D)
+            want = pattern[pname].copy()
+            mask = np.zeros(shape[:3], bool)
+            for pos in range(written):
+                want[:, pages[pos // 8], pos % 8] = rows[:, pos]
+                mask[:, pages[pos // 8], pos % 8] = True
+            np.testing.assert_allclose(got[mask], want[mask], rtol=1e-5,
+                                       atol=1e-6)
+            untouched = ~mask
+            untouched[:, 0] = False  # the scrap page
+            np.testing.assert_array_equal(got[untouched],
+                                          pattern[pname][untouched])

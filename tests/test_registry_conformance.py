@@ -123,14 +123,14 @@ def test_paged_cache_ops_conform():
     ins.update({
         "Tok": [sds((S,), np.int64)], "Pos": [sds((S,), np.int32)],
         "BlockTable": [sds((S, P), np.int32)],
-        "CacheK": [sds((L, N, Hkv, ps, dh), np.float32)],
-        "CacheV": [sds((L, N, Hkv, ps, dh), np.float32)],
+        "CacheK": [sds((L, N, ps, Hkv * dh), np.float32)],
+        "CacheV": [sds((L, N, ps, Hkv * dh), np.float32)],
     })
     attrs = {"num_heads": 2, "num_kv_heads": Hkv, "page_size": ps}
     outs = registry.infer_outputs("transformer_stack_paged_decode",
                                   attrs, ins)
     assert tuple(outs["NextTok"][0].shape) == (S,)
-    assert tuple(outs["CacheK"][0].shape) == (L, N, Hkv, ps, dh)
+    assert tuple(outs["CacheK"][0].shape) == (L, N, ps, Hkv * dh)
     cost = registry.get_op("transformer_stack_paged_decode").cost_fn(
         attrs, ins, outs)
     assert cost.flops > 0 and cost.bytes > 0
