@@ -15,6 +15,7 @@ from . import (analysis, checkpoint, clip, decoding, evaluator, event,
                optimizer, parallel, profiler, regularizer, resilience,
                serving, trace, trainer, transpiler)
 from . import flags
+from .lm_spec import LMSpec
 from .checkgrad import check_gradients
 from .core.enforce import (EnforceError, enforce, enforce_eq, enforce_ge,
                            enforce_gt, enforce_le, enforce_lt, enforce_ne,

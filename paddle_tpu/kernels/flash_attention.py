@@ -49,10 +49,12 @@ def _out_struct(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
-def rotary(x, pos0=0, base=10000.0):
+def rotary(x, pos0=0, base=10000.0, pairing="interleaved"):
     """Rotary position embedding over [B, H, T, D] heads, positions
-    pos0..pos0+T-1 (RoFormer pairing: (x[2i], x[2i+1]) rotates by
-    pos * base^(-2i/D)). The single source of truth for RoPE math — the
+    pos0..pos0+T-1: pair i rotates by pos * base^(-2i/D). ``pairing``
+    says which two coordinates pair i is: ``"interleaved"`` (RoFormer,
+    (x[2i], x[2i+1])) or ``"half"`` (GPT-NeoX / Llama / OLMoE checkpoints,
+    (x[i], x[i + D/2])). The single source of truth for RoPE math — the
     per-layer encoder op and the stacked/decode path both call it; the
     offset form serves incremental decode. ``pos0`` may be a [B] array
     of PER-ROW offsets (the slot-decode path, where every batch row sits
@@ -67,15 +69,15 @@ def rotary(x, pos0=0, base=10000.0):
         ang = pos[:, :, None] * inv[None, None, :]
         cos = jnp.cos(ang)[:, None].astype(x.dtype)  # [B, 1, T, half]
         sin = jnp.sin(ang)[:, None].astype(x.dtype)
-        x1 = x[..., 0::2]
-        x2 = x[..., 1::2]
-        r1 = x1 * cos - x2 * sin
-        r2 = x1 * sin + x2 * cos
-        return jnp.stack([r1, r2], axis=-1).reshape(x.shape)
-    pos = pos0 + jnp.arange(T, dtype=jnp.float32)
-    ang = pos[:, None] * inv[None, :]  # [T, half]
-    cos = jnp.cos(ang)[None, None].astype(x.dtype)
-    sin = jnp.sin(ang)[None, None].astype(x.dtype)
+    else:
+        pos = pos0 + jnp.arange(T, dtype=jnp.float32)
+        ang = pos[:, None] * inv[None, :]  # [T, half]
+        cos = jnp.cos(ang)[None, None].astype(x.dtype)
+        sin = jnp.sin(ang)[None, None].astype(x.dtype)
+    if pairing == "half":
+        x1, x2 = x[..., :half], x[..., half:]
+        return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                               axis=-1)
     x1 = x[..., 0::2]
     x2 = x[..., 1::2]
     r1 = x1 * cos - x2 * sin
@@ -106,10 +108,12 @@ def reference_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
                              f"{Hkv}")
         rep = H // Hkv
         qg = q.reshape(q.shape[0], Hkv, rep, q.shape[2], q.shape[3])
-        s = jnp.einsum("bgrqd,bgkd->bgrqk", qg, k).astype(jnp.float32)             * sm_scale
+        s = jnp.einsum("bgrqd,bgkd->bgrqk", qg, k,
+                       preferred_element_type=jnp.float32) * sm_scale
         s = s.reshape(q.shape[0], H, q.shape[2], k.shape[2])
     else:
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32)             * sm_scale
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                       preferred_element_type=jnp.float32) * sm_scale
     T = q.shape[2], k.shape[2]
     if causal:
         p0 = jnp.asarray(q_pos0)

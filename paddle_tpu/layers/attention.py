@@ -147,63 +147,60 @@ def transformer_encoder_layer(x, num_heads, d_ff, causal=False,
     return o
 
 
-def make_stack_params(helper, base, L, d_model, d_ff, dtype="float32",
-                      num_heads=None, num_kv_heads=None, param_attr=None):
-    """Create (or rejoin by name) the stacked [L, ...] block weights for
-    ``pipelined_transformer_stack`` / ``transformer_stack_generate``:
-    returns the op-input dict keyed by slot name. Names follow
-    ``{base}.stack_{suffix}`` so sharding plans and sibling programs
-    (training vs generation) address the same tensors."""
+def make_stack_params(helper, base, spec, param_attr=None,
+                      stored_dtype=True):
+    """Create (or rejoin by name) the stacked [L, ...] block weights of
+    ``spec`` (an ``LMSpec``) for ``pipelined_transformer_stack`` and the
+    decode ops: returns the op-input dict keyed by slot name. Names
+    follow ``{base}.stack_{key}`` so sharding plans and sibling programs
+    (training vs generation) address the same tensors; planes, shapes and
+    dtype all come from ``spec.stack_planes()`` / ``spec.param_dtype``
+    (``stored_dtype=False``: the dtype was inherited from an activation,
+    so AMP's float32-master rule of ``create_parameter`` applies)."""
+    import copy
+
     from ..initializer import ConstantInitializer
     from ..param_attr import ParamAttr
 
-    def mk(suffix, shape, bias=False, fan=None, init=None):
-        import copy
-
+    def mk(key, shape, fan):
         attr = (ParamAttr.to_attr(param_attr) if param_attr is not None
                 else ParamAttr())
         attr = copy.copy(attr)
-        attr.name = f"{base}.stack_{suffix}"
-        if init is None and not bias:
+        attr.name = f"{base}.stack_{key}"
+        if fan is not None:
             init = XavierInitializer(fan_in=fan[0], fan_out=fan[1])
+        else:   # vectors: norm scales start at 1, biases at 0
+            init = (ConstantInitializer(1.0) if key.endswith("_s")
+                    else None)
         return helper.create_parameter(
-            attr, shape=shape, dtype=dtype, is_bias=bias,
-            default_initializer=init)
+            attr, shape=[spec.n_layers] + shape, dtype=spec.param_dtype,
+            is_bias=fan is None, default_initializer=init,
+            stored_dtype=stored_dtype)
 
-    one = ConstantInitializer(1.0)
-    # GQA: KV planes carry num_kv_heads < num_heads head groups
-    d_kv = (d_model if not (num_heads and num_kv_heads)
-            else d_model // num_heads * num_kv_heads)
-    qkv_width = d_model + 2 * d_kv
-    return {
-        "Ln1S": [mk("ln1_s", [L, d_model], bias=True, init=one)],
-        "Ln1B": [mk("ln1_b", [L, d_model], bias=True)],
-        "QkvW": [mk("qkv_w", [L, d_model, qkv_width],
-                    fan=(d_model, qkv_width))],
-        "OutW": [mk("out_w", [L, d_model, d_model],
-                    fan=(d_model, d_model))],
-        "Ln2S": [mk("ln2_s", [L, d_model], bias=True, init=one)],
-        "Ln2B": [mk("ln2_b", [L, d_model], bias=True)],
-        "FfW1": [mk("ff_w1", [L, d_model, d_ff], fan=(d_model, d_ff))],
-        "FfB1": [mk("ff_b1", [L, d_ff], bias=True)],
-        "FfW2": [mk("ff_w2", [L, d_ff, d_model], fan=(d_ff, d_model))],
-        "FfB2": [mk("ff_b2", [L, d_model], bias=True)],
-    }
+    return {slot: [mk(key, shape, fan)]
+            for slot, key, shape, fan in spec.stack_planes()}
 
 
-def pipelined_transformer_stack(x, n_layers, num_heads, d_ff=None,
+def pipelined_transformer_stack(x, n_layers=None, num_heads=None, d_ff=None,
                                 num_kv_heads=None, use_rope=False,
                                 causal=True, n_microbatches=None,
                                 pipe_axis="pp", data_axis="dp", remat=False,
-                                param_attr=None, main_program=None,
-                                startup_program=None):
-    """L pre-LN transformer blocks with stacked [L, ...] weights — the
+                                param_attr=None, spec=None,
+                                main_program=None, startup_program=None):
+    """L transformer blocks with stacked [L, ...] weights — the
     scan-over-layers form of ``transformer_encoder_layer``. One compiled
     block body regardless of depth, and the layer axis doubles as the
     pipeline-stage axis: under a mesh with a ``pp`` axis (see
     ``parallel.pipeline_plan``) the stack runs the GPipe microbatch
     schedule across stages. Names carry a ``.stack_`` marker so the plan
-    can shard every stacked tensor's leading dim on ``pp``."""
+    can shard every stacked tensor's leading dim on ``pp``.
+
+    The block comes from ``spec`` (an ``LMSpec``); without one the size
+    keywords describe the GPT-2 block (pre-LN LayerNorm, GELU 4x FFN). A
+    ``swiglu_moe`` spec returns ``(out, aux_loss)``: the load-balance
+    loss summed over the layers (add ``spec.router_aux_loss_coef *
+    aux_loss`` to the objective)."""
+    from ..lm_spec import LMSpec
     from ..param_attr import ParamAttr
 
     if get_seq_len(x) is not None:
@@ -215,32 +212,34 @@ def pipelined_transformer_stack(x, n_layers, num_heads, d_ff=None,
                          main_program=main_program,
                          startup_program=startup_program)
     d_model = x.shape[-1]
-    if d_model % num_heads:
-        raise ValueError(f"d_model {d_model} not divisible by heads "
-                         f"{num_heads}")
-    d_ff = d_ff or 4 * d_model
-    L = n_layers
-    from ..param_attr import ParamAttr as _PA
+    stated = spec is not None
+    if spec is None:
+        if d_model % num_heads:
+            raise ValueError(f"d_model {d_model} not divisible by heads "
+                             f"{num_heads}")
+        if num_kv_heads and num_heads % num_kv_heads:
+            raise ValueError(f"num_heads {num_heads} not a multiple of "
+                             f"num_kv_heads {num_kv_heads}")
+        spec = LMSpec(vocab_size=0, d_model=d_model, n_layers=n_layers,
+                      num_heads=num_heads, num_kv_heads=num_kv_heads,
+                      use_rope=use_rope, d_ff=d_ff, param_dtype=x.dtype)
+    elif spec.d_model != d_model:
+        raise ValueError(f"x is {d_model} wide, the spec {spec.d_model}")
 
-    _given = _PA.to_attr(param_attr)
+    _given = ParamAttr.to_attr(param_attr)
     base = (_given.name if _given is not None and _given.name
             else helper.main_program.unique_name("pipe"))
-
-    if num_kv_heads and num_heads % num_kv_heads:
-        raise ValueError(f"num_heads {num_heads} not a multiple of "
-                         f"num_kv_heads {num_kv_heads}")
     ins = {"X": [x]}
-    ins.update(make_stack_params(helper, base, L, d_model, d_ff,
-                                 dtype=x.dtype, num_heads=num_heads,
-                                 num_kv_heads=num_kv_heads,
-                                 param_attr=param_attr))
-    o = helper.simple_op(
-        "pipelined_transformer_stack", ins,
-        {"num_heads": num_heads, "num_kv_heads": num_kv_heads,
-         "use_rope": use_rope, "causal": causal,
-         "n_microbatches": n_microbatches, "pipe_axis": pipe_axis,
-         "data_axis": data_axis, "remat": remat})
-    return o
+    ins.update(make_stack_params(helper, base, spec, param_attr=param_attr,
+                                 stored_dtype=stated))
+    attrs = {**spec.block.attrs(), "causal": causal,
+             "n_microbatches": n_microbatches, "pipe_axis": pipe_axis,
+             "data_axis": data_axis, "remat": remat}
+    if spec.block.is_moe:
+        outs, _ = helper.append_op("pipelined_transformer_stack", ins,
+                                   ["Out", "AuxLoss"], attrs)
+        return outs["Out"][0], outs["AuxLoss"][0]
+    return helper.simple_op("pipelined_transformer_stack", ins, attrs)
 
 
 def switch_moe(x, num_experts, d_ff=None, capacity_factor=1.25,

@@ -56,7 +56,11 @@ class LayerHelper:
         dtype,
         is_bias: bool = False,
         default_initializer=None,
+        stored_dtype: bool = False,
     ):
+        """``stored_dtype``: ``dtype`` is the dtype a model spec STATES for
+        the stored parameter (a bf16 checkpoint served as bf16), not one
+        inherited from an input's dtype — it is kept under AMP too."""
         attr = ParamAttr.to_attr(attr)
         if attr is None:
             return None
@@ -69,7 +73,8 @@ class LayerHelper:
             return block.vars[name]
         from ..ops.common import amp_enabled
 
-        if amp_enabled() and to_dtype(dtype) == to_dtype("bfloat16"):
+        if (not stored_dtype and amp_enabled()
+                and to_dtype(dtype) == to_dtype("bfloat16")):
             # AMP is bf16 COMPUTE over f32 MASTER weights: a layer that
             # sizes its weight by ``input.dtype`` sees the bf16 an
             # upstream activation carries, not a dtype the user chose.

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 from ..initializer import ConstantInitializer
 from ..layers.layer_helper import LayerHelper
+from ..lm_spec import LMSpec
 from ..param_attr import ParamAttr
 from .transformer import _shared_lm_params
 
@@ -57,9 +58,9 @@ def _encoder_params(helper, src_vocab_size, d_model, d_ff, max_src_len,
             ParamAttr(name="enc_ln.bias"), shape=[d_model],
             dtype="float32", is_bias=True)],
     }
-    enc = make_stack_params(helper, "enc_stack", n_layers, d_model, d_ff,
-                            num_heads=num_heads,
-                            num_kv_heads=num_kv_heads)
+    enc = make_stack_params(helper, "enc_stack", LMSpec(
+        vocab_size=src_vocab_size, d_model=d_model, n_layers=n_layers,
+        num_heads=num_heads, num_kv_heads=num_kv_heads, d_ff=d_ff))
     ins.update({f"Enc{slot}": v for slot, v in enc.items()})
     return ins
 
@@ -71,9 +72,10 @@ def shared_nmt_params(helper, src_vocab_size, tgt_vocab_size, d_model,
     (or rejoin by name) in any program that needs the model."""
     d_kv = (d_model if not (num_heads and num_kv_heads)
             else d_model // num_heads * num_kv_heads)
-    ins = _shared_lm_params(helper, tgt_vocab_size, d_model, d_ff,
-                            max_tgt_len, n_layers, num_heads,
-                            num_kv_heads)
+    ins = _shared_lm_params(helper, LMSpec(
+        vocab_size=tgt_vocab_size, d_model=d_model, n_layers=n_layers,
+        num_heads=num_heads, num_kv_heads=num_kv_heads, max_len=max_tgt_len,
+        d_ff=d_ff))
     ins.update(_cross_params(helper, n_layers, d_model, d_kv))
     ins.update(_encoder_params(helper, src_vocab_size, d_model, d_ff,
                                max_src_len, n_layers, num_heads,

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
-from .common import amp_cast, mxu_precision, out, single
+from .common import amp_cast, amp_enabled, mxu_precision, out, single
 
 
 @register_op("switch_moe", optional_inputs=("GateBias",))
@@ -74,3 +74,71 @@ def switch_moe(attrs, ins):
     aux = E * jnp.sum(frac_tokens * frac_probs)
     return out(Out=y.reshape(b, T, d).astype(x.dtype),
                AuxLoss=aux.reshape(1))
+
+
+def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
+             layer=None):
+    """Dropless token-choice top-``k`` SwiGLU experts — the expert layer
+    of the ``swiglu_moe`` block (ops/pipeline_ops.py calls it from the
+    block's FFN half; it is not a program op of its own).
+
+    x [N, d] (a float32 residual-stream row per token), router_w [d, E],
+    gate_w / up_w [E, d, f], down_w [E, f, d] ->
+    (y [N, d] in x.dtype, counts [E] int32 rows sent to each expert,
+    prob_mean [E] float32 mean router probability).
+
+    Router logits, softmax and top-k are float32. The N*k (token, choice)
+    assignments are sorted by expert (stable), the sorted rows go through
+    three grouped matmuls (``jax.lax.ragged_dot``: the TPU compiler turns
+    it into one grouped-matmul custom call over the rows, so the cost
+    grows with assignments, never with E x capacity), and the weighted
+    rows are gathered back by the inverse permutation and summed over k.
+    There is no capacity and no drop; every shape is static (always N*k
+    rows); ``jax.grad`` goes through (the sort indices are constants of
+    the backward pass). Under AMP the grouped matmuls take bf16 operands
+    and accumulate in float32; weights stored in bf16 are used as they
+    are, never upcast on the device.
+
+    ``layer`` (a traced index): gate_w / up_w / down_w are then the WHOLE
+    stacks [L, E, ...] and the call uses layer ``layer``'s experts —
+    addressed as groups layer*E .. layer*E+E-1 of L*E (every other group
+    empty), so no layer of the stack is sliced out. A scan that hands the
+    per-layer slice [E, d, f] to the grouped-matmul custom call makes XLA
+    COPY it first: 3 x 268 MB a layer at OLMoE's widths, 19.6 of an 82 ms
+    decode tick (my chip run, PR 26). The serving ops pass ``layer``; the
+    train op scans slices (its weight gradient must be one layer's).
+    """
+    N, d = x.shape
+    E = router_w.shape[-1]
+    x32 = x.astype(jnp.float32)
+    logits = jnp.dot(x32, router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)                   # [N, E]
+    top_p, top_e = jax.lax.top_k(probs, k)                    # [N, k]
+    if norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    flat_e = top_e.reshape(N * k)
+    order = jnp.argsort(flat_e, stable=True)                  # by expert
+    counts = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
+    sizes = counts
+    if layer is not None:
+        n_layers = gate_w.shape[0]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_layers * E,), jnp.int32), counts, (layer * E,))
+        gate_w, up_w, down_w = (w.reshape((n_layers * E,) + w.shape[2:])
+                                for w in (gate_w, up_w, down_w))
+    rows = x32[order // k]                                    # [N*k, d]
+    if amp_enabled():
+        rows = rows.astype(jnp.bfloat16)
+
+    def grouped(a, w):
+        if w.dtype != a.dtype:      # bf16 weights under float32 compute
+            w = w.astype(a.dtype)
+        return jax.lax.ragged_dot(a, w, sizes, precision=mxu_precision(),
+                                  preferred_element_type=jnp.float32)
+
+    h = jax.nn.silu(grouped(rows, gate_w)) * grouped(rows, up_w)
+    o = grouped(h.astype(rows.dtype), down_w)                 # [N*k, d] f32
+    inv = jnp.argsort(order)                                  # unsort
+    y = jnp.sum(o[inv].reshape(N, k, d) * top_p[..., None], axis=1)
+    return y.astype(x.dtype), counts, jnp.mean(probs, axis=0)

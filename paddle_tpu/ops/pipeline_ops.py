@@ -18,62 +18,103 @@ import jax.numpy as jnp
 
 from ..core.registry import register_op
 from ..kernels.flash_attention import flash_attention, rotary
+from ..lm_spec import OPTIONAL_STACK_SLOTS, Block, BlockNotSupportedError
 from .common import amp_cast, maybe, mxu_precision, out, single
+from .moe_ops import moe_topk
 
 _EPS = 1e-5
+_LM_OPTIONAL = ("PosEmb", "FinalLnB") + OPTIONAL_STACK_SLOTS
 
 
-def _ln(x, scale, bias):
+def _ln(x, scale, bias, eps=_EPS):
     mu = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.var(x, axis=-1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + _EPS) * scale + bias
+    return (x - mu) * jax.lax.rsqrt(var + eps) * scale + bias
 
 
-def _block(p, x, num_heads, causal, num_kv_heads=None, use_rope=False):
-    """One pre-LN transformer block; p holds per-layer (no leading dim)
-    weights: ln1_s, ln1_b, qkv_w, out_w, ln2_s, ln2_b, ff_w1, ff_b1,
-    ff_w2, ff_b2."""
+def _rms(x, scale, eps):
+    """RMSNorm over the last axis, float32 whatever the stored dtype of
+    its weight: u * rsqrt(mean(u^2) + eps) * w."""
+    u = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(u), axis=-1, keepdims=True)
+    return (u * jax.lax.rsqrt(ms + eps)
+            * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def _norm(blk, x, scale, bias=None):
+    """The spec's norm over the last axis."""
+    if blk.norm == "rms_norm":
+        return _rms(x, scale, blk.norm_eps)
+    if bias is None:
+        bias = jnp.zeros((), x.dtype)
+    return _ln(x, scale, bias, blk.norm_eps)
+
+
+def _mm(eq, a, w):
+    """``einsum(eq, a, w)`` back in a.dtype (the float32 residual
+    stream). float32 weights: bf16 operands under AMP, as the GPT-2 block
+    always did. Weights STORED in bf16 are used as they are with float32
+    accumulation (no float32 copy of a weight is ever made on the
+    device; without AMP jnp promotes inside the contraction)."""
+    a_c, w_c = amp_cast(a, w)
+    pref = jnp.float32 if w.dtype == jnp.bfloat16 else None
+    return jnp.einsum(eq, a_c, w_c, precision=mxu_precision(),
+                      preferred_element_type=pref).astype(a.dtype)
+
+
+def _stack_params(blk, ins):
+    """The block's stacked weights by key, from the op's input slots."""
+    # slots: "Ln1S" "Ln1B" "QkvW" "QNormS" "KNormS" "OutW" "Ln2S" "Ln2B"
+    # "FfW1" "FfB1" "FfW2" "FfB2" "RouterW" "MoeGateW" "MoeUpW" "MoeDownW"
+    return {key: single(ins, slot)
+            for slot, key in blk.stack_slots().items()}
+
+
+def _block(blk, p, x, causal):
+    """One block of the spec; p holds per-layer (no leading dim) weights
+    under the keys of ``blk.stack_slots()``. -> (x, stats): stats is None
+    for a dense FFN, (counts [E], router prob mean [E]) for experts."""
     b, T, d = x.shape
     from jax.ad_checkpoint import checkpoint_name
 
-    q, k, v = _attn_proj(p, x, num_heads, num_kv_heads, use_rope)
-    k, v = _expand_kv(k, v, num_heads)
+    q, k, v = _attn_proj(blk, p, x)
+    k, v = _expand_kv(k, v, blk.num_heads)
     ctx = flash_attention(q, k, v, causal=causal)
     ctx = checkpoint_name(ctx.transpose(0, 2, 1, 3).reshape(b, T, d),
                           "attn_ctx")
-    return _attn_out_ffn(p, x, ctx)
+    return _attn_out_ffn(blk, p, x, ctx)
 
 
-def _attn_proj(p, h, num_heads, num_kv_heads=None, use_rope=False,
-               pos0=0):
-    """LN1 + qkv projection -> q [b, H, t, dh], k/v [b, Hkv, t, dh].
+def _attn_proj(blk, p, h, pos0=0):
+    """norm 1 + qkv projection -> q [b, H, t, dh], k/v [b, Hkv, t, dh].
     Hkv < H is grouped-query attention: the stacked qkv weight is
     [L, d, d + 2*Hkv*dh] and the KV planes (and decode caches) shrink by
-    H/Hkv. ``use_rope`` rotates q/k at absolute positions pos0..pos0+t-1
+    H/Hkv. ``qk_norm``: RMSNorm over the WHOLE q and k vectors before the
+    head split. RoPE rotates q/k at absolute positions pos0..pos0+t-1
     (rotated keys enter the decode cache, so cached rows never re-rotate)."""
-    num_kv_heads = num_kv_heads or num_heads
+    num_heads, num_kv_heads = blk.num_heads, blk.kv_heads
     b, t, d = h.shape
     head_d = d // num_heads
     d_kv = head_d * num_kv_heads
     from jax.ad_checkpoint import checkpoint_name
 
-    hn = _ln(h, p["ln1_s"], p["ln1_b"])
-    hn_c, qkv_c = amp_cast(hn, p["qkv_w"])
-    qkv = jnp.einsum("btd,de->bte", hn_c, qkv_c,
-                     precision=mxu_precision()).astype(h.dtype)
-    qkv = checkpoint_name(qkv, "qkv_proj")
+    hn = _norm(blk, h, p["ln1_s"], p.get("ln1_b"))
+    qkv = checkpoint_name(_mm("btd,de->bte", hn, p["qkv_w"]), "qkv_proj")
     q = qkv[..., :d]
     k = qkv[..., d:d + d_kv]
     v = qkv[..., d + d_kv:]
+    if blk.qk_norm:
+        q = _rms(q, p["q_norm_s"], blk.norm_eps)
+        k = _rms(k, p["k_norm_s"], blk.norm_eps)
 
     def heads(a, n):
         return a.reshape(b, t, n, head_d).transpose(0, 2, 1, 3)
 
     q, k, v = (heads(q, num_heads), heads(k, num_kv_heads),
                heads(v, num_kv_heads))
-    if use_rope:
-        q = rotary(q, pos0)
-        k = rotary(k, pos0)
+    if blk.use_rope:
+        q = rotary(q, pos0, blk.rope_theta, blk.rope_pairing)
+        k = rotary(k, pos0, blk.rope_theta, blk.rope_pairing)
     return q, k, v
 
 
@@ -86,58 +127,74 @@ def _expand_kv(k, v, num_heads):
     return k, v
 
 
-def _attn_out_ffn(p, x, ctx):
-    """Out-projection + residual + FFN half of a block; ctx [b, t, d]."""
+def _attn_out_ffn(blk, p, x, ctx):
+    """Out-projection + residual + FFN half of a block; ctx [b, t, d].
+    -> (x, stats), stats as ``_block`` says."""
     from jax.ad_checkpoint import checkpoint_name
 
-    ctx_c, ow_c = amp_cast(ctx, p["out_w"])
-    attn = jnp.einsum("btd,de->bte", ctx_c, ow_c,
-                      precision=mxu_precision()).astype(x.dtype)
-    attn = checkpoint_name(attn, "attn_out")
-    x = x + attn
-    h2 = _ln(x, p["ln2_s"], p["ln2_b"])
-    h2_c, w1_c = amp_cast(h2, p["ff_w1"])
-    ff = jax.nn.gelu(
-        jnp.einsum("btd,df->btf", h2_c, w1_c,
-                   precision=mxu_precision()).astype(x.dtype) + p["ff_b1"])
-    ff = checkpoint_name(ff, "ffn_hidden")
-    ff_c, w2_c = amp_cast(ff, p["ff_w2"])
-    ff = jnp.einsum("btf,fd->btd", ff_c, w2_c,
-                    precision=mxu_precision()).astype(x.dtype) + p["ff_b2"]
-    return x + ff
+    x = x + checkpoint_name(_mm("btd,de->bte", ctx.astype(x.dtype),
+                                p["out_w"]), "attn_out")
+    h2 = _norm(blk, x, p["ln2_s"], p.get("ln2_b"))
+    if blk.is_moe:
+        b, t, d = x.shape
+        y, counts, prob_mean = moe_topk(
+            h2.reshape(b * t, d), p["router_w"], p["moe_gate_w"],
+            p["moe_up_w"], p["moe_down_w"], blk.experts_per_tok,
+            blk.norm_topk_prob, layer=p.get("layer"))
+        return x + y.reshape(b, t, d), (counts, prob_mean)
+    ff = _mm("btd,df->btf", h2, p["ff_w1"])
+    if blk.bias:
+        ff = ff + p["ff_b1"]
+    ff = checkpoint_name(jax.nn.gelu(ff), "ffn_hidden")
+    ff = _mm("btf,fd->btd", ff, p["ff_w2"])
+    if blk.bias:
+        ff = ff + p["ff_b2"]
+    return x + ff, None
 
 
-_STACK_SLOTS = {
-    "Ln1S": "ln1_s", "Ln1B": "ln1_b", "QkvW": "qkv_w", "OutW": "out_w",
-    "Ln2S": "ln2_s", "Ln2B": "ln2_b", "FfW1": "ff_w1", "FfB1": "ff_b1",
-    "FfW2": "ff_w2", "FfB2": "ff_b2",
-}
+# the GPT-2 block's ten planes (the seq2seq family's encoder / decoder
+# stacks are this block under their own slot prefixes)
+_STACK_SLOTS = Block(num_heads=1).stack_slots()
 
 
-@register_op("pipelined_transformer_stack")
+def _aux_loss(blk, stats, n_experts):
+    """Load-balance loss of the scanned layers' (counts [L, E], router
+    prob mean [L, E]): sum over layers of E * sum_e f_e * P_e, f_e the
+    share of the layer's assignments sent to expert e (a constant of the
+    backward pass), P_e the mean router probability."""
+    counts, prob_mean = stats
+    f = counts.astype(jnp.float32) / jnp.sum(
+        counts, axis=-1, keepdims=True).astype(jnp.float32)
+    return n_experts * jnp.sum(jax.lax.stop_gradient(f) * prob_mean)
+
+
+@register_op("pipelined_transformer_stack",
+             optional_inputs=OPTIONAL_STACK_SLOTS)
 def pipelined_transformer_stack(attrs, ins):
-    """X [b, T, d] + stacked block weights (leading dim L) -> Out [b, T, d].
+    """X [b, T, d] + stacked block weights (leading dim L) -> Out [b, T, d]
+    (and, for a ``swiglu_moe`` block, AuxLoss [1]: the load-balance loss
+    summed over layers, see ``_aux_loss``).
 
-    attrs: num_heads, causal, n_microbatches. With a ``pp`` mesh axis the
-    stack runs the GPipe schedule (layer axis sharded into stages, each
-    stage scanning its local L/S layers); otherwise one scan over all L.
+    attrs: the block (``lm_spec.Block.attrs()``), causal, n_microbatches,
+    remat. With a ``pp`` mesh axis the stack runs the GPipe schedule
+    (layer axis sharded into stages, each stage scanning its local L/S
+    layers); otherwise one scan over all L.
     """
     from ..parallel.context import current_mesh, mesh_axis
 
     x = single(ins, "X")
-    params = {key: single(ins, slot)
-              for slot, key in _STACK_SLOTS.items()}
-    num_heads = attrs["num_heads"]
-    num_kv_heads = attrs.get("num_kv_heads") or num_heads
-    use_rope = attrs.get("use_rope", False)
+    blk = Block.from_attrs(attrs)
+    # optional stack slots (a block leaves out what it has no use for),
+    # read via _stack_params: "Ln1B" "Ln2B" "QNormS" "KNormS" "FfW1"
+    # "FfB1" "FfW2" "FfB2" "RouterW" "MoeGateW" "MoeUpW" "MoeDownW"
+    params = _stack_params(blk, ins)
     causal = attrs.get("causal", True)
 
     remat = attrs.get("remat", False)
 
-    def scan_layers(p, h):
+    def scan_stats(p, h):
         def body(carry, layer_p):
-            return _block(layer_p, carry, num_heads, causal,
-                          num_kv_heads, use_rope), None
+            return _block(blk, layer_p, carry, causal)
 
         if remat == "dots":
             # Selective policy: keep each layer's big GEMM outputs
@@ -150,8 +207,10 @@ def pipelined_transformer_stack(attrs, ins):
                     "qkv_proj", "attn_ctx", "attn_out", "ffn_hidden"))
         elif remat:
             body = jax.checkpoint(body)
-        h, _ = jax.lax.scan(body, h, p)
-        return h
+        return jax.lax.scan(body, h, p)
+
+    def scan_layers(p, h):
+        return scan_stats(p, h)[0]
 
     pipe_axis = attrs.get("pipe_axis") or "pp"
     pp = mesh_axis(pipe_axis)
@@ -159,6 +218,10 @@ def pipelined_transformer_stack(attrs, ins):
     if pp > 1:
         from ..parallel.pipeline import gpipe
 
+        if blk.is_moe:
+            raise BlockNotSupportedError(
+                "a swiglu_moe stack under a pp mesh axis: the GPipe "
+                "schedule carries no per-layer router statistics")
         if L % pp:
             raise ValueError(
                 f"{L} layers not divisible by pipeline size {pp}")
@@ -170,60 +233,64 @@ def pipelined_transformer_stack(attrs, ins):
                   n_microbatches=attrs.get("n_microbatches") or pp,
                   data_axis=data_axis)
         return out(Out=y)
-    return out(Out=scan_layers(params, x))
+    y, stats = scan_stats(params, x)
+    if blk.is_moe:
+        aux = _aux_loss(blk, stats, params["router_w"].shape[-1])
+        return out(Out=y, AuxLoss=aux.reshape(1))
+    return out(Out=y)
 
 
-
-def _unpack_lm_ins(ins):
+def _unpack_lm_ins(blk, ins):
     """Shared input unpacking for the decode ops: (prompt, embeddings,
-    final-LN, head, stacked block params). PosEmb is absent under RoPE
-    (rotation replaces the learned table)."""
+    final norm, head, stacked block params). PosEmb is absent under RoPE
+    (rotation replaces the learned table), FinalLnB under RMSNorm."""
     return (single(ins, "Prompt"), single(ins, "TokEmb"),
             maybe(ins, "PosEmb"), single(ins, "FinalLnS"),
-            single(ins, "FinalLnB"), single(ins, "HeadW"),
-            {key: single(ins, slot) for slot, key in _STACK_SLOTS.items()})
+            maybe(ins, "FinalLnB"), single(ins, "HeadW"),
+            _stack_params(blk, ins))
+
+
+def _embed_rows(tok_emb, ids):
+    """Embedding rows as the float32 residual stream (a table stored in
+    bf16 is read as it is; only the gathered rows are upcast)."""
+    return tok_emb[ids].astype(jnp.float32)
 
 
 def _embed_fn(tok_emb, pos_emb):
     def embed(ids, pos0):
         if pos_emb is None:  # RoPE: positions live in the attention rotation
-            return tok_emb[ids]
+            return _embed_rows(tok_emb, ids)
         t = ids.shape[1]
-        return (tok_emb[ids]
+        return (_embed_rows(tok_emb, ids)
                 + jax.lax.dynamic_slice_in_dim(pos_emb, pos0, t, 0)[None])
 
     return embed
 
 
-def _logits_fn(ln_s, ln_b, head_w):
+def _logits_fn(ln_s, ln_b, head_w, blk=Block(num_heads=1)):
     def logits_of(h_last):
-        hn = _ln(h_last, ln_s, ln_b)
-        hn_c, hw_c = amp_cast(hn, head_w)
-        return jnp.einsum("bd,dv->bv", hn_c, hw_c,
-                          precision=mxu_precision()).astype(jnp.float32)
+        hn = _norm(blk, h_last, ln_s, ln_b)
+        return _mm("bd,dv->bv", hn, head_w).astype(jnp.float32)
 
     return logits_of
 
 
-def _prefill(params, x, num_heads, b, Tp, num_kv_heads=None,
-             use_rope=False):
+def _prefill(blk, params, x, b, Tp):
     """Run the stack over the prompt capturing every layer's K/V:
     returns (hidden [b, Tp, d], ks, vs [L, b, Hkv, Tp, dh]) — the caches
     hold KV heads only (the GQA memory win). Under RoPE the cached keys
     are already rotated at their absolute positions."""
     def prefill_body(h, layer_p):
-        q, k, v = _attn_proj(layer_p, h, num_heads, num_kv_heads,
-                             use_rope)
-        kx, vx = _expand_kv(k, v, num_heads)
+        q, k, v = _attn_proj(blk, layer_p, h)
+        kx, vx = _expand_kv(k, v, blk.num_heads)
         ctx = flash_attention(q, kx, vx, causal=True)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(b, Tp, x.shape[-1])
-        return _attn_out_ffn(layer_p, h, ctx), (k, v)
+        return _attn_out_ffn(blk, layer_p, h, ctx)[0], (k, v)
 
     return jax.lax.scan(prefill_body, x, params)
 
 
-def _decode_layer_fn(params, num_heads, d, num_kv_heads=None,
-                     use_rope=False):
+def _decode_layer_fn(blk, params, d):
     """One-token decode through all layers against the cache; returns a
     fn(h1, (layer_p, ck_l, cv_l), pos) suitable for lax.scan over layers
     (pos = the query's position; cache rows < pos+1 are visible). Caches
@@ -232,8 +299,7 @@ def _decode_layer_fn(params, num_heads, d, num_kv_heads=None,
 
     def layer(h1, inp, pos):
         layer_p, ck_l, cv_l = inp
-        q, k, v = _attn_proj(layer_p, h1, num_heads, num_kv_heads,
-                             use_rope, pos0=pos)
+        q, k, v = _attn_proj(blk, layer_p, h1, pos0=pos)
         ck_l = jax.lax.dynamic_update_slice_in_dim(ck_l, k, pos, 2)
         cv_l = jax.lax.dynamic_update_slice_in_dim(cv_l, v, pos, 2)
         # reference_attention reads the Hkv cache natively (grouped
@@ -241,7 +307,7 @@ def _decode_layer_fn(params, num_heads, d, num_kv_heads=None,
         ctx = reference_attention(
             q, ck_l, cv_l, lengths=jnp.full((h1.shape[0],), pos + 1))
         ctx = ctx.transpose(0, 2, 1, 3).reshape(h1.shape[0], 1, d)
-        return _attn_out_ffn(layer_p, h1, ctx), (ck_l, cv_l)
+        return _attn_out_ffn(blk, layer_p, h1, ctx)[0], (ck_l, cv_l)
 
     return layer
 
@@ -267,7 +333,7 @@ def _make_pick(temperature, top_k, vocab, rng):
     return pick
 
 
-@register_op("transformer_stack_generate", optional_inputs=("PosEmb",),
+@register_op("transformer_stack_generate", optional_inputs=_LM_OPTIONAL,
              needs_rng=lambda attrs: (attrs.get("temperature") or 0) > 0)
 def transformer_stack_generate(attrs, ins, rng):
     """Incremental decoding with a per-layer KV cache.
@@ -285,11 +351,13 @@ def transformer_stack_generate(attrs, ins, rng):
     re-forwarding; everything static-shaped for XLA (the cache is
     preallocated at Tp + N).
     """
+    blk = Block.from_attrs(attrs)
+    # optional stack slots (a block leaves out what it has no use for),
+    # read via _stack_params: "Ln1B" "Ln2B" "QNormS" "KNormS" "FfW1"
+    # "FfB1" "FfW2" "FfB2" "RouterW" "MoeGateW" "MoeUpW" "MoeDownW"
+    # and "PosEmb" "FinalLnB" via _unpack_lm_ins
     (prompt, tok_emb, pos_emb, ln_s, ln_b, head_w,
-     params) = _unpack_lm_ins(ins)
-    num_heads = attrs["num_heads"]
-    num_kv_heads = attrs.get("num_kv_heads") or num_heads
-    use_rope = attrs.get("use_rope", False)
+     params) = _unpack_lm_ins(blk, ins)
     N = attrs["max_new_tokens"]
     temperature = attrs.get("temperature") or 0.0
     top_k = attrs.get("top_k") or 0
@@ -301,20 +369,18 @@ def transformer_stack_generate(attrs, ins, rng):
             f"prompt {Tp} + {N} new tokens exceeds max_len "
             f"{pos_emb.shape[0]}")
     embed = _embed_fn(tok_emb, pos_emb)
-    logits_of = _logits_fn(ln_s, ln_b, head_w)
+    logits_of = _logits_fn(ln_s, ln_b, head_w, blk)
     vocab = head_w.shape[1]
     pick = _make_pick(temperature, top_k, vocab, rng)
 
     # ---- prefill: run the stack over the prompt, capturing K/V -------
-    h, (ks, vs) = _prefill(params, embed(prompt, 0), num_heads, b, Tp,
-                           num_kv_heads, use_rope)
+    h, (ks, vs) = _prefill(blk, params, embed(prompt, 0), b, Tp)
     pad = [(0, 0)] * 5
     pad[3] = (0, N)  # [L, b, Hkv, Tp, dh] -> [L, b, Hkv, Ttot, dh]
     cache_k = jnp.pad(ks, pad)
     cache_v = jnp.pad(vs, pad)
     next_tok = pick(logits_of(h[:, -1]), 0)  # [b]
-    decode_layer = _decode_layer_fn(params, num_heads, d, num_kv_heads,
-                                    use_rope)
+    decode_layer = _decode_layer_fn(blk, params, d)
 
     # ---- decode: one token at a time against the cache ---------------
     def step(carry, n):
@@ -355,11 +421,10 @@ def transformer_stack_beam_search(attrs, ins):
     reference's beam_search op family shuffling LoD rows
     (/root/reference/paddle/operators/beam_search_op.cc).
     """
+    blk = Block.from_attrs(attrs)
+    blk.require_gpt2("transformer_stack_beam_search")
     (prompt, tok_emb, pos_emb, ln_s, ln_b, head_w,
-     params) = _unpack_lm_ins(ins)
-    num_heads = attrs["num_heads"]
-    num_kv_heads = attrs.get("num_kv_heads") or num_heads
-    use_rope = attrs.get("use_rope", False)
+     params) = _unpack_lm_ins(blk, ins)
     N = attrs["max_new_tokens"]
     K = attrs.get("beam_size", 4)
     alpha = attrs.get("length_penalty") or 0.0
@@ -379,11 +444,10 @@ def transformer_stack_beam_search(attrs, ins):
     if not 0 < K <= V:
         raise ValueError(f"beam_size {K} outside [1, vocab {V}]")
     embed = _embed_fn(tok_emb, pos_emb)
-    logits_of = _logits_fn(ln_s, ln_b, head_w)
+    logits_of = _logits_fn(ln_s, ln_b, head_w, blk)
 
     # ---- prefill over the bare batch, then tile to beams --------------
-    h, (ks, vs) = _prefill(params, embed(prompt, 0), num_heads, b, Tp,
-                           num_kv_heads, use_rope)
+    h, (ks, vs) = _prefill(blk, params, embed(prompt, 0), b, Tp)
     pad = [(0, 0)] * 5
     pad[3] = (0, N)
     cache_k = jnp.repeat(jnp.pad(ks, pad), K, axis=1)  # [L, b*K, Hkv, T, dh]
@@ -396,8 +460,7 @@ def transformer_stack_beam_search(attrs, ins):
                       dtype=prompt.dtype)
     tokens = tokens.at[:, :, 0].set(tok0.astype(prompt.dtype))
     alive = (tok0 != eos_id) if eos_id >= 0 else jnp.ones((b, K), bool)
-    decode_layer = _decode_layer_fn(params, num_heads, d, num_kv_heads,
-                                    use_rope)
+    decode_layer = _decode_layer_fn(blk, params, d)
 
     def step(carry, n):
         tokens, scores, alive, ck, cv = carry
@@ -459,8 +522,7 @@ def transformer_stack_beam_search(attrs, ins):
                Scores=scores)
 
 
-def _window_verify_fn(params, num_heads, d, num_kv_heads=None,
-                      use_rope=False):
+def _window_verify_fn(blk, params, d):
     """Forward a w-token window through ALL layers against the cache
     (block-causal: window token i attends cache rows <= pos0 + i), writing
     the window's K/V at rows pos0..pos0+w-1. Returns fn(xw, ck, cv, pos0)
@@ -471,15 +533,14 @@ def _window_verify_fn(params, num_heads, d, num_kv_heads=None,
     def run(xw, ck, cv, pos0):
         def layer(hw, inp):
             layer_p, ck_l, cv_l = inp
-            q, k, v = _attn_proj(layer_p, hw, num_heads, num_kv_heads,
-                                 use_rope, pos0=pos0)
+            q, k, v = _attn_proj(blk, layer_p, hw, pos0=pos0)
             ck_l = jax.lax.dynamic_update_slice_in_dim(ck_l, k, pos0, 2)
             cv_l = jax.lax.dynamic_update_slice_in_dim(cv_l, v, pos0, 2)
             ctx = reference_attention(q, ck_l, cv_l, causal=True,
                                       q_pos0=pos0)
             ctx = ctx.transpose(0, 2, 1, 3).reshape(
                 hw.shape[0], hw.shape[1], d)
-            return _attn_out_ffn(layer_p, hw, ctx), (ck_l, cv_l)
+            return _attn_out_ffn(blk, layer_p, hw, ctx)[0], (ck_l, cv_l)
 
         return jax.lax.scan(layer, xw, (params, ck, cv))
 
@@ -509,14 +570,13 @@ def transformer_stack_speculative_generate(attrs, ins):
     Out [b, Tp + N] int; Rounds [1] int32 (verify rounds taken — the
     speedup diagnostic: plain decode would take N).
     """
+    blk = Block.from_attrs(attrs)
+    blk.require_gpt2("transformer_stack_speculative_generate")
     (prompt, tok_emb, pos_emb, ln_s, ln_b, head_w,
-     params) = _unpack_lm_ins(ins)
+     params) = _unpack_lm_ins(blk, ins)
     d_ln_s = single(ins, "DraftLnS")
     d_ln_b = single(ins, "DraftLnB")
     d_head_w = single(ins, "DraftHeadW")
-    num_heads = attrs["num_heads"]
-    num_kv_heads = attrs.get("num_kv_heads") or num_heads
-    use_rope = attrs.get("use_rope", False)
     N = attrs["max_new_tokens"]
     k_layers = attrs["draft_layers"]
     gamma = attrs.get("gamma", 4)
@@ -533,17 +593,14 @@ def transformer_stack_speculative_generate(attrs, ins):
             f"prompt {Tp} + {N} new tokens (+{gamma + 1} speculative "
             f"slack) exceeds max_len {pos_emb.shape[0]}")
     embed = _embed_fn(tok_emb, pos_emb)
-    logits_of = _logits_fn(ln_s, ln_b, head_w)
+    logits_of = _logits_fn(ln_s, ln_b, head_w, blk)
     draft_logits_of = _logits_fn(d_ln_s, d_ln_b, d_head_w)
     draft_params = {key: p[:k_layers] for key, p in params.items()}
-    draft_layer = _decode_layer_fn(draft_params, num_heads, d,
-                                   num_kv_heads, use_rope)
-    verify = _window_verify_fn(params, num_heads, d, num_kv_heads,
-                               use_rope)
+    draft_layer = _decode_layer_fn(blk, draft_params, d)
+    verify = _window_verify_fn(blk, params, d)
 
     # ---- prefill: the full stack over the prompt -----------------------
-    h, (ks, vs) = _prefill(params, embed(prompt, 0), num_heads, b, Tp,
-                           num_kv_heads, use_rope)
+    h, (ks, vs) = _prefill(blk, params, embed(prompt, 0), b, Tp)
     pad = [(0, 0)] * 5
     pad[3] = (0, Ttot - Tp)
     cache_k = jnp.pad(ks, pad)
@@ -645,6 +702,8 @@ def transformer_stack_slot_prefill(attrs, ins, rng=None):
     length mask stops at the current position) and progressively
     overwrites.
     """
+    blk = Block.from_attrs(attrs)
+    blk.require_gpt2("transformer_stack_slot_prefill")
     prompt = single(ins, "Prompt")
     slot_ids = single(ins, "SlotIds").astype(jnp.int32)
     lengths = single(ins, "Lengths").astype(jnp.int32)
@@ -654,10 +713,7 @@ def transformer_stack_slot_prefill(attrs, ins, rng=None):
     pos_emb = maybe(ins, "PosEmb")
     ln_s, ln_b = single(ins, "FinalLnS"), single(ins, "FinalLnB")
     head_w = single(ins, "HeadW")
-    params = {key: single(ins, slot) for slot, key in _STACK_SLOTS.items()}
-    num_heads = attrs["num_heads"]
-    num_kv_heads = attrs.get("num_kv_heads") or num_heads
-    use_rope = attrs.get("use_rope", False)
+    params = _stack_params(blk, ins)
     b, Tp = prompt.shape
     Tmax = cache_k.shape[3]
     if Tp > Tmax:
@@ -668,10 +724,9 @@ def transformer_stack_slot_prefill(attrs, ins, rng=None):
     embed = _embed_fn(tok_emb, pos_emb)
     pick = _make_pick(attrs.get("temperature") or 0.0,
                       attrs.get("top_k") or 0, head_w.shape[1], rng)
-    h, (ks, vs) = _prefill(params, embed(prompt, 0), num_heads, b, Tp,
-                           num_kv_heads, use_rope)
+    h, (ks, vs) = _prefill(blk, params, embed(prompt, 0), b, Tp)
     last = h[jnp.arange(b), jnp.clip(lengths, 1, Tp) - 1]  # [b, d]
-    next_tok = pick(_logits_fn(ln_s, ln_b, head_w)(last), 0)
+    next_tok = pick(_logits_fn(ln_s, ln_b, head_w, blk)(last), 0)
     # ks/vs [L, b, Hkv, Tp, dh] -> scatter each row into its slot's rows
     # 0..Tp-1 (one advanced index: the batch axis maps onto slot ids)
     cache_k = cache_k.at[:, slot_ids, :, :Tp, :].set(ks)
@@ -699,6 +754,8 @@ def transformer_stack_slot_decode(attrs, ins, rng=None):
     per-row lengths plane, so stale rows beyond a slot's position are
     never visible.
     """
+    blk = Block.from_attrs(attrs)
+    blk.require_gpt2("transformer_stack_slot_decode")
     tok = single(ins, "Tok")
     pos = single(ins, "Pos").astype(jnp.int32)
     cache_k = single(ins, "CacheK")
@@ -707,10 +764,7 @@ def transformer_stack_slot_decode(attrs, ins, rng=None):
     pos_emb = maybe(ins, "PosEmb")
     ln_s, ln_b = single(ins, "FinalLnS"), single(ins, "FinalLnB")
     head_w = single(ins, "HeadW")
-    params = {key: single(ins, slot) for slot, key in _STACK_SLOTS.items()}
-    num_heads = attrs["num_heads"]
-    num_kv_heads = attrs.get("num_kv_heads") or num_heads
-    use_rope = attrs.get("use_rope", False)
+    params = _stack_params(blk, ins)
     S = tok.shape[0]
     if S != cache_k.shape[1]:
         raise ValueError(f"Tok has {S} slots but the cache holds "
@@ -728,8 +782,7 @@ def transformer_stack_slot_decode(attrs, ins, rng=None):
 
     def layer(h1, inp):
         layer_p, ck_l, cv_l = inp  # caches [S, Hkv, Tmax, dh]
-        q, k, v = _attn_proj(layer_p, h1, num_heads, num_kv_heads,
-                             use_rope, pos0=pos)
+        q, k, v = _attn_proj(blk, layer_p, h1, pos0=pos)
         Hkv = k.shape[1]
         ix = (srange[:, None], jnp.arange(Hkv)[None, :], pos[:, None])
         ck_l = ck_l.at[ix].set(k[:, :, 0, :])
@@ -738,11 +791,11 @@ def transformer_stack_slot_decode(attrs, ins, rng=None):
 
         ctx = reference_attention(q, ck_l, cv_l, lengths=pos + 1)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(S, 1, d)
-        return _attn_out_ffn(layer_p, h1, ctx), (ck_l, cv_l)
+        return _attn_out_ffn(blk, layer_p, h1, ctx)[0], (ck_l, cv_l)
 
     h1, (cache_k, cache_v) = jax.lax.scan(layer, h1,
                                           (params, cache_k, cache_v))
-    nxt = pick(_logits_fn(ln_s, ln_b, head_w)(h1[:, 0]), 0)
+    nxt = pick(_logits_fn(ln_s, ln_b, head_w, blk)(h1[:, 0]), 0)
     return out(NextTok=nxt.astype(tok.dtype),
                CacheK=cache_k, CacheV=cache_v)
 
@@ -836,14 +889,13 @@ def _gather_pages(pool, layer, table, num_kv_heads):
     return ctx.transpose(0, 2, 1, 3)
 
 
-def _finish_ffn(layer_p, h, ctx, _x_l):
-    """The plain LM block's ``finish``: out-projection + FFN."""
-    return _attn_out_ffn(layer_p, h, ctx)
+#: planes the paged layer loop hands to the block WHOLE (with the layer's
+#: index under "layer") instead of slicing layer l out: see ``moe_topk``
+_RESIDENT_PLANES = ("moe_gate_w", "moe_up_w", "moe_down_w")
 
 
 def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
-                       page_row, project, mask, finish=_finish_ffn,
-                       xs=None):
+                       page_row, project, mask, finish, xs=None):
     """The layer loop of every paged op: h [b, t, d] through the L
     stacked blocks with the page pools [L, N, ps, Hkv*dh] as the scan's
     CARRY, updated in place.
@@ -855,8 +907,14 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
     the update window is a token's whole [Hkv*dh] row, contiguous in the
     pool; the context is gathered at (l, table) and attended with
     ``reference_attention(**mask)``; ``finish(layer_p, h, ctx, x_l)``
-    closes the block, x_l being layer l's slice of the optional
-    scanned-over ``xs``. Returns (h, cache_k, cache_v).
+    -> (h, stats) closes the block, x_l being layer l's slice of the
+    optional scanned-over ``xs`` and stats what the layer reports (None,
+    or an expert layer's (counts, router prob mean)). Returns (h,
+    cache_k, cache_v, stats stacked over layers).
+
+    Pages hold K/V in the POOL's dtype (the spec's ``page_dtype``): new
+    rows are cast on the way in, and queries are cast to it so the
+    attention contractions read the pages as stored.
 
     The pools are never scanned-over inputs or stacked outputs: that form
     sliced layer l's pool out, re-laid it out round the scatter, restacked
@@ -868,6 +926,8 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
 
     b, t, d = h.shape
     n_layers = cache_k.shape[0]
+    whole = {k: params[k] for k in _RESIDENT_PLANES if k in params}
+    params = {k: v for k, v in params.items() if k not in whole}
     ix_page = page_id.reshape(b, t)
     ix_row = page_row.reshape(b, t)
 
@@ -877,23 +937,36 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
     def layer(carry, inp):
         h, ck, cv = carry
         layer_p, l, x_l = inp
+        if whole:
+            layer_p = {**layer_p, **whole, "layer": l}
         q, k, v = project(layer_p, h)
         hkv = k.shape[1]
-        ck = ck.at[l, ix_page, ix_row].set(token_rows(k))
-        cv = cv.at[l, ix_page, ix_row].set(token_rows(v))
-        ctx = reference_attention(q, _gather_pages(ck, l, table, hkv),
+        ck = ck.at[l, ix_page, ix_row].set(token_rows(k).astype(ck.dtype))
+        cv = cv.at[l, ix_page, ix_row].set(token_rows(v).astype(cv.dtype))
+        ctx = reference_attention(q.astype(ck.dtype),
+                                  _gather_pages(ck, l, table, hkv),
                                   _gather_pages(cv, l, table, hkv), **mask)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(b, t, d)
-        return (finish(layer_p, h, ctx, x_l), ck, cv), None
+        h, stats = finish(layer_p, h, ctx, x_l)
+        return (h, ck, cv), stats
 
-    (h, cache_k, cache_v), _ = jax.lax.scan(
+    (h, cache_k, cache_v), stats = jax.lax.scan(
         layer, (h, cache_k, cache_v),
         (params, jnp.arange(n_layers, dtype=jnp.int32), xs))
-    return h, cache_k, cache_v
+    return h, cache_k, cache_v, stats
+
+
+def _paged_outs(blk, stats, **outs):
+    """The paged ops' outputs; an expert block adds ExpertCounts [L, E]
+    int32 (rows each expert took in each layer of THIS call) so the
+    engine's counters ride the tick's existing fetch."""
+    if blk.is_moe:
+        outs["ExpertCounts"] = stats[0]
+    return out(**outs)
 
 
 @register_op("transformer_stack_paged_prefill",
-             optional_inputs=("PosEmb",) + _SAMPLING_SLOTS,
+             optional_inputs=_LM_OPTIONAL + _SAMPLING_SLOTS,
              needs_rng=lambda attrs: (attrs.get("temperature") or 0) > 0)
 def transformer_stack_paged_prefill(attrs, ins, rng=None):
     """Prefill ONE CHUNK of each row's prompt into its block-table pages.
@@ -942,12 +1015,14 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     cache_v = single(ins, "CacheV")
     tok_emb = single(ins, "TokEmb")
     pos_emb = maybe(ins, "PosEmb")
-    ln_s, ln_b = single(ins, "FinalLnS"), single(ins, "FinalLnB")
+    ln_s, ln_b = single(ins, "FinalLnS"), maybe(ins, "FinalLnB")
     head_w = single(ins, "HeadW")
-    params = {key: single(ins, slot) for slot, key in _STACK_SLOTS.items()}
-    num_heads = attrs["num_heads"]
-    num_kv_heads = attrs.get("num_kv_heads") or num_heads
-    use_rope = attrs.get("use_rope", False)
+    blk = Block.from_attrs(attrs)
+    # optional stack slots (a block leaves out what it has no use for),
+    # read via _stack_params: "Ln1B" "Ln2B" "QNormS" "KNormS" "FfW1"
+    # "FfB1" "FfW2" "FfB2" "RouterW" "MoeGateW" "MoeUpW" "MoeDownW"
+    # and "FinalLnB"
+    params = _stack_params(blk, ins)
     b, Tc = chunk.shape
     ps = cache_k.shape[2]
     P = table.shape[1]
@@ -958,24 +1033,24 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     page_id = jnp.where(
         valid, jnp.take_along_axis(table, entry, axis=1), 0)
     page_row = jnp.where(valid, pos % ps, 0)
-    x = tok_emb[chunk]
+    x = _embed_rows(tok_emb, chunk)
     if pos_emb is not None:
         x = x + pos_emb[jnp.clip(pos, 0, pos_emb.shape[0] - 1)]
-    h, cache_k, cache_v = _scan_paged_layers(
+    h, cache_k, cache_v, stats = _scan_paged_layers(
         params, x, cache_k, cache_v, table, page_id, page_row,
-        lambda p, h: _attn_proj(p, h, num_heads, num_kv_heads, use_rope,
-                                pos0=start),
-        dict(causal=True, q_pos0=start))
+        lambda p, h: _attn_proj(blk, p, h, pos0=start),
+        dict(causal=True, q_pos0=start),
+        lambda p, h, ctx, _x_l: _attn_out_ffn(blk, p, h, ctx))
     last = h[jnp.arange(b), jnp.clip(lengths, 1, Tc) - 1]  # [b, d]
-    logits = _logits_fn(ln_s, ln_b, head_w)(last)
+    logits = _logits_fn(ln_s, ln_b, head_w, blk)(last)
     nxt = _pick_rows(attrs, ins, rng, head_w.shape[1], logits)
-    outs = out(NextTok=nxt.astype(chunk.dtype),
-               CacheK=cache_k, CacheV=cache_v)
+    outs = _paged_outs(blk, stats, NextTok=nxt.astype(chunk.dtype),
+                       CacheK=cache_k, CacheV=cache_v)
     return _maybe_topk(attrs, ins, logits, outs)
 
 
 @register_op("transformer_stack_paged_decode",
-             optional_inputs=("PosEmb",) + _SAMPLING_SLOTS,
+             optional_inputs=_LM_OPTIONAL + _SAMPLING_SLOTS,
              needs_rng=lambda attrs: (attrs.get("temperature") or 0) > 0)
 def transformer_stack_paged_decode(attrs, ins, rng=None):
     """One decode step over every slot's paged context.
@@ -1016,12 +1091,14 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     cache_v = single(ins, "CacheV")
     tok_emb = single(ins, "TokEmb")
     pos_emb = maybe(ins, "PosEmb")
-    ln_s, ln_b = single(ins, "FinalLnS"), single(ins, "FinalLnB")
+    ln_s, ln_b = single(ins, "FinalLnS"), maybe(ins, "FinalLnB")
     head_w = single(ins, "HeadW")
-    params = {key: single(ins, slot) for slot, key in _STACK_SLOTS.items()}
-    num_heads = attrs["num_heads"]
-    num_kv_heads = attrs.get("num_kv_heads") or num_heads
-    use_rope = attrs.get("use_rope", False)
+    blk = Block.from_attrs(attrs)
+    # optional stack slots (a block leaves out what it has no use for),
+    # read via _stack_params: "Ln1B" "Ln2B" "QNormS" "KNormS" "FfW1"
+    # "FfB1" "FfW2" "FfB2" "RouterW" "MoeGateW" "MoeUpW" "MoeDownW"
+    # and "FinalLnB"
+    params = _stack_params(blk, ins)
     S = tok.shape[0]
     if S != table.shape[0]:
         raise ValueError(f"Tok has {S} slots but the block table holds "
@@ -1029,22 +1106,22 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     ps = cache_k.shape[2]
     P = table.shape[1]
     pos = jnp.clip(pos, 0, P * ps - 1)
-    x = tok_emb[tok]
+    x = _embed_rows(tok_emb, tok)
     if pos_emb is not None:
         x = x + pos_emb[jnp.clip(pos, 0, pos_emb.shape[0] - 1)]
     h1 = x[:, None, :]  # [S, 1, d]
     srange = jnp.arange(S)
     page_id = table[srange, pos // ps]  # [S]
     page_row = pos % ps
-    h1, cache_k, cache_v = _scan_paged_layers(
+    h1, cache_k, cache_v, stats = _scan_paged_layers(
         params, h1, cache_k, cache_v, table, page_id, page_row,
-        lambda p, h: _attn_proj(p, h, num_heads, num_kv_heads, use_rope,
-                                pos0=pos),
-        dict(lengths=pos + 1))
-    logits = _logits_fn(ln_s, ln_b, head_w)(h1[:, 0])
+        lambda p, h: _attn_proj(blk, p, h, pos0=pos),
+        dict(lengths=pos + 1),
+        lambda p, h, ctx, _x_l: _attn_out_ffn(blk, p, h, ctx))
+    logits = _logits_fn(ln_s, ln_b, head_w, blk)(h1[:, 0])
     nxt = _pick_rows(attrs, ins, rng, head_w.shape[1], logits)
-    outs = out(NextTok=nxt.astype(tok.dtype),
-               CacheK=cache_k, CacheV=cache_v)
+    outs = _paged_outs(blk, stats, NextTok=nxt.astype(tok.dtype),
+                       CacheK=cache_k, CacheV=cache_v)
     return _maybe_topk(attrs, ins, logits, outs)
 
 

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
+from ..lm_spec import Block
 from .common import maybe, out, single
 from .pipeline_ops import (_SAMPLING_SLOTS, _STACK_SLOTS, _attn_out_ffn,
                            _attn_proj, _expand_kv, _logits_fn, _ln,
@@ -72,7 +73,7 @@ def _cross_block(xslot, src_len, num_heads):
         ff = jax.nn.gelu(jnp.einsum("btd,df->btf", h2, layer_p["ff_w1"])
                          + layer_p["ff_b1"])
         return h + jnp.einsum("btf,fd->btd", ff, layer_p["ff_w2"]) \
-            + layer_p["ff_b2"]
+            + layer_p["ff_b2"], None
 
     return finish
 
@@ -87,6 +88,7 @@ def _encode_memory(ins, attrs, src, src_len):
     pos_emb = maybe(ins, "SrcPosEmb")
     num_heads = attrs["num_heads"]
     num_kv_heads = attrs.get("num_kv_heads") or num_heads
+    blk = Block(num_heads=num_heads, num_kv_heads=num_kv_heads)
     b, Ts = src.shape
     d = params["ln1_s"].shape[1]
     x = tok_emb[src]
@@ -94,11 +96,11 @@ def _encode_memory(ins, attrs, src, src_len):
         x = x + pos_emb[None, :Ts]
 
     def block(h, layer_p):
-        q, k, v = _attn_proj(layer_p, h, num_heads, num_kv_heads)
+        q, k, v = _attn_proj(blk, layer_p, h)
         kx, vx = _expand_kv(k, v, num_heads)
         ctx = reference_attention(q, kx, vx, lengths=src_len)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(b, Ts, d)
-        return _attn_out_ffn(layer_p, h, ctx), None
+        return _attn_out_ffn(blk, layer_p, h, ctx)[0], None
 
     h, _ = jax.lax.scan(block, x, params)
     return _ln(h, single(ins, "EncLnS"), single(ins, "EncLnB"))
@@ -186,6 +188,7 @@ def transformer_stack_cross_prefill(attrs, ins, rng=None):
     xparams = _unpack_cross(ins)
     num_heads = attrs["num_heads"]
     num_kv_heads = attrs.get("num_kv_heads") or num_heads
+    blk = Block(num_heads=num_heads, num_kv_heads=num_kv_heads)
     b, Tc = chunk.shape
     ps = cache_k.shape[2]
     P = table.shape[1]
@@ -198,9 +201,9 @@ def transformer_stack_cross_prefill(attrs, ins, rng=None):
     x = tok_emb[chunk]
     if pos_emb is not None:
         x = x + pos_emb[jnp.clip(pos, 0, pos_emb.shape[0] - 1)]
-    h, cache_k, cache_v = _scan_paged_layers(
+    h, cache_k, cache_v, _ = _scan_paged_layers(
         params, x, cache_k, cache_v, table, page_id, page_row,
-        lambda p, h: _attn_proj(p, h, num_heads, num_kv_heads, pos0=start),
+        lambda p, h: _attn_proj(blk, p, h, pos0=start),
         dict(causal=True, q_pos0=start),
         finish=_cross_block(xslot, src_len, num_heads),
         xs=(cross_k, cross_v, xparams))
@@ -242,6 +245,7 @@ def transformer_stack_cross_decode(attrs, ins, rng=None):
     xparams = _unpack_cross(ins)
     num_heads = attrs["num_heads"]
     num_kv_heads = attrs.get("num_kv_heads") or num_heads
+    blk = Block(num_heads=num_heads, num_kv_heads=num_kv_heads)
     S = tok.shape[0]
     ps = cache_k.shape[2]
     P = table.shape[1]
@@ -253,9 +257,9 @@ def transformer_stack_cross_decode(attrs, ins, rng=None):
     srange = jnp.arange(S)
     page_id = table[srange, pos // ps]
     page_row = pos % ps
-    h1, cache_k, cache_v = _scan_paged_layers(
+    h1, cache_k, cache_v, _ = _scan_paged_layers(
         params, h1, cache_k, cache_v, table, page_id, page_row,
-        lambda p, h: _attn_proj(p, h, num_heads, num_kv_heads, pos0=pos),
+        lambda p, h: _attn_proj(blk, p, h, pos0=pos),
         dict(lengths=pos + 1),
         finish=_cross_block(xslot, src_len, num_heads),
         xs=(cross_k, cross_v, xparams))
@@ -293,6 +297,7 @@ def transformer_encdec_teacher(attrs, ins):
     xparams = _unpack_cross(ins)
     num_heads = attrs["num_heads"]
     num_kv_heads = attrs.get("num_kv_heads") or num_heads
+    blk = Block(num_heads=num_heads, num_kv_heads=num_kv_heads)
     b, Tt = tgt_in.shape
     d = params["ln1_s"].shape[1]
     memory = _encode_memory(ins, attrs, src, src_len)
@@ -305,7 +310,7 @@ def transformer_encdec_teacher(attrs, ins):
     def layer(h, inp):
         (layer_p, xk_l, xv_l, xlns, xlnb, xqw, xoutw) = inp
         xw = {"xlns": xlns, "xlnb": xlnb, "xqw": xqw, "xoutw": xoutw}
-        q, k, v = _attn_proj(layer_p, h, num_heads, num_kv_heads)
+        q, k, v = _attn_proj(blk, layer_p, h)
         kx, vx = _expand_kv(k, v, num_heads)
         ctx = flash_attention(q, kx, vx, causal=True)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(b, Tt, d)

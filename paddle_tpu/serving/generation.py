@@ -46,6 +46,7 @@ from ..decoding.params import BeamParams, SamplingParams
 from ..decoding.stops import StopMatcher
 from ..layers import data as data_layer
 from ..layers.layer_helper import LayerHelper
+from ..lm_spec import Block, LMSpec
 from .batcher import Request
 from .errors import BadRequestError
 from .metrics import MetricsRegistry
@@ -65,35 +66,12 @@ _DECODE_OPS = ("transformer_stack_generate", "transformer_stack_beam_search",
                "transformer_stack_paged_decode")
 
 
-@dataclasses.dataclass
-class LMSpec:
-    """Hyperparameters of a stacked transformer LM — everything the slot
-    programs need to rebuild the shared-by-name weights
-    (``transformer_lm(pipeline_stack=True)`` contract)."""
-    vocab_size: int
-    d_model: int
-    n_layers: int
-    num_heads: int
-    num_kv_heads: Optional[int] = None
-    use_rope: bool = False
-    max_len: int = 2048
-    d_ff: Optional[int] = None
-
-    @property
-    def kv_heads(self) -> int:
-        return self.num_kv_heads or self.num_heads
-
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.num_heads
-
-
 def spec_from_program_dict(pd: dict,
                            max_len: Optional[int] = None) -> LMSpec:
-    """Derive an LMSpec from a saved generation program's dict (the
-    ``io.read_inference_model_meta``/``program_to_dict`` payload): decode
-    hyperparameters come from the decode op's attrs, sizes from the
-    shared parameter shapes."""
+    """Rebuild the LMSpec of a saved generation program (the
+    ``io.read_inference_model_meta``/``program_to_dict`` payload): the
+    block from the decode op's attrs (``lm_spec.Block.attrs()``), sizes
+    and the stored dtype from the shared parameters."""
     block = pd["blocks"][0]
     op = next((o for o in block["ops"] if o["type"] in _DECODE_OPS), None)
     if op is None:
@@ -101,25 +79,28 @@ def spec_from_program_dict(pd: dict,
             "no stacked-LM decode op in the saved program — save an "
             "inference model built from transformer_lm_generate (or "
             "another transformer_stack_* decode program)")
-    attrs = op["attrs"]
-    shapes = {v["name"]: v["shape"] for v in block["vars"]}
-    if "tok_emb" not in shapes or "lm_stack.stack_qkv_w" not in shapes:
+    blk = Block.from_attrs(op["attrs"])
+    var = {v["name"]: v for v in block["vars"]}
+    if "tok_emb" not in var or "lm_stack.stack_qkv_w" not in var:
         raise ValueError("saved program lacks the shared LM parameters "
                          "(tok_emb / lm_stack.*)")
-    vocab, d_model = shapes["tok_emb"]
-    n_layers = shapes["lm_stack.stack_qkv_w"][0]
-    d_ff = shapes["lm_stack.stack_ff_w1"][2]
-    use_rope = bool(attrs.get("use_rope", False))
+    vocab, d_model = var["tok_emb"]["shape"]
+    sizes = {}
+    if blk.is_moe:
+        _, sizes["num_experts"], _, sizes["d_expert"] = \
+            var["lm_stack.stack_moe_gate_w"]["shape"]
+    else:
+        sizes["d_ff"] = var["lm_stack.stack_ff_w1"]["shape"][2]
     if max_len is None:
-        if "pos_emb" in shapes:
-            max_len = shapes["pos_emb"][0]
+        if "pos_emb" in var:
+            max_len = var["pos_emb"]["shape"][0]
         else:
             raise ValueError("RoPE model has no pos_emb table to bound "
                              "sequence length — pass max_len explicitly")
-    return LMSpec(vocab_size=vocab, d_model=d_model, n_layers=n_layers,
-                  num_heads=attrs["num_heads"],
-                  num_kv_heads=attrs.get("num_kv_heads"),
-                  use_rope=use_rope, max_len=max_len, d_ff=d_ff)
+    return LMSpec(vocab_size=vocab, d_model=d_model,
+                  n_layers=var["lm_stack.stack_qkv_w"]["shape"][0],
+                  max_len=max_len, param_dtype=str(var["tok_emb"]["dtype"]),
+                  **sizes, **dataclasses.asdict(blk))
 
 
 def _default_prompt_buckets(tmax: int) -> List[int]:
@@ -252,6 +233,9 @@ class GenerationEngine:
                              f"got {kv_cache!r}")
         if slots < 1:
             raise ValueError("need at least one decode slot")
+        if not isinstance(self, PagedGenerationEngine):
+            spec.block.require_gpt2("the dense slot engine "
+                                    "(kv_cache='dense')")
         self.spec = spec
         self.scope = scope or Scope()
         self.slots = int(slots)
@@ -329,20 +313,42 @@ class GenerationEngine:
         scope = kw.pop("scope", None) or Scope()
         eng = cls(spec, scope, max_seq_len=max_seq_len, **kw)
         load_inference_model(model_dir, eng.executor, scope=scope)
+        eng._adopt_scope()
         eng.model_dir = model_dir  # manifest home for warm_start
         return eng
 
     def _adopt_scope(self):
-        """Move weights handed over in ``scope`` (trained elsewhere, then
-        copied per engine) onto this engine's device, once: the executor
-        refuses state that lives on another chip rather than copying it
-        across on every tick."""
+        """Cast-and-place: weights handed over in ``scope`` (trained
+        elsewhere, then copied per engine, or just loaded from a saved
+        model) go onto this engine's device in the spec's stored dtype,
+        once. The executor refuses state that lives on another chip
+        rather than copying it across on every tick; one of the
+        spec's weights in another dtype than ``spec.param_dtype`` is cast
+        one tensor at a time (the whole model never sits on the device twice)."""
         import jax
+        import jax.numpy as jnp
+
+        from ..core.types import to_dtype
 
         dev = self.executor.device()
+        want = jnp.dtype(to_dtype(self.spec.param_dtype))
+        weights = set(self.spec.param_names())
+        todo = []
         for name in list(self.scope.keys()):
             val = self.scope.get(name)
-            if isinstance(val, jax.Array) and val.devices() != {dev}:
+            cast = name in weights and val.dtype != want
+            if cast or (isinstance(val, jax.Array)
+                        and val.devices() != {dev}):
+                todo.append((name, val, cast))
+        if not todo:
+            return
+        with trace.span("serving/load_weights", dtype=self.spec.param_dtype,
+                        bytes=sum(int(v.size) * (want.itemsize if c else
+                                                 v.dtype.itemsize)
+                                  for _, v, c in todo)):
+            for name, val, cast in todo:
+                if cast:
+                    val = val.astype(want)
                 self.scope.set(name, jax.device_put(val, dev))
 
     def _init_cache(self):
@@ -368,16 +374,10 @@ class GenerationEngine:
     def _lm_ins(self, helper):
         from ..models.transformer import _shared_lm_params
 
-        s = self.spec
-        return _shared_lm_params(helper, s.vocab_size, s.d_model,
-                                 s.d_ff or 4 * s.d_model, s.max_len,
-                                 s.n_layers, s.num_heads, s.num_kv_heads,
-                                 s.use_rope)
+        return _shared_lm_params(helper, self.spec)
 
     def _decode_attrs(self):
-        return {"num_heads": self.spec.num_heads,
-                "num_kv_heads": self.spec.num_kv_heads,
-                "use_rope": self.spec.use_rope,
+        return {**self.spec.block.attrs(),
                 "temperature": self.temperature, "top_k": self.top_k}
 
     def _build_prefill(self, tp: int):
@@ -1077,6 +1077,7 @@ class PagedGenerationEngine(GenerationEngine):
     def _init_cache(self):
         import jax.numpy as jnp
 
+        from ..core.types import to_dtype
         from .paging import PagePool, PrefixIndex
 
         s = self.spec
@@ -1134,24 +1135,27 @@ class PagedGenerationEngine(GenerationEngine):
         self._seed_counter = 0    # default per-request seeds (sampled
                                   # requests without an explicit seed)
         shape = self._pool_shape()
+        page_dtype = jnp.dtype(to_dtype(s.page_dtype))
         if src is None:
             with self.executor.device_ctx():
-                self.scope.set(PAGED_CACHE_K, jnp.zeros(shape, jnp.float32))
-                self.scope.set(PAGED_CACHE_V, jnp.zeros(shape, jnp.float32))
+                self.scope.set(PAGED_CACHE_K, jnp.zeros(shape, page_dtype))
+                self.scope.set(PAGED_CACHE_V, jnp.zeros(shape, page_dtype))
         # shared-pool engines never re-zero: the scope tensors already
         # hold the source pool's live pages
         self._page_copy_prog_cache = None
-        self.metrics.set_gauge("mem/kv_cache_bytes",
-                               2.0 * float(np.prod(shape)) * 4)
+        self.metrics.set_gauge(
+            "mem/kv_cache_bytes",
+            2.0 * float(np.prod(shape)) * page_dtype.itemsize)
         self.metrics.set_gauge("mem/kv_block_table_bytes",
                                float(self.slots * self.pmax * 4))
         self._gauges()
 
     def _pool_shape(self):
-        """[L, n_pages, page_size, Hkv*dh]: a token's K (or V) of one
-        layer is ONE contiguous row, so a page is contiguous and
-        lane-dense on the device (ops/pipeline_ops.py says why the
-        head-major [.., Hkv, page_size, dh] form was not)."""
+        """[L, n_pages, page_size, Hkv*dh] in the spec's ``page_dtype``:
+        a token's K (or V) of one layer is ONE contiguous row, so a page
+        is contiguous and lane-dense on the device (ops/pipeline_ops.py
+        says why the head-major [.., Hkv, page_size, dh] form was
+        not)."""
         s = self.spec
         return (s.n_layers, self.n_pages, self.page_size,
                 s.kv_heads * s.head_dim)
@@ -1159,9 +1163,9 @@ class PagedGenerationEngine(GenerationEngine):
     def _cache_vars(self, helper):
         shape = list(self._pool_shape())
         ck = helper.create_global_variable(name=PAGED_CACHE_K, shape=shape,
-                                           dtype="float32")
+                                           dtype=self.spec.page_dtype)
         cv = helper.create_global_variable(name=PAGED_CACHE_V, shape=shape,
-                                           dtype="float32")
+                                           dtype=self.spec.page_dtype)
         return ck, cv
 
     def _decode_attrs(self):
@@ -1224,6 +1228,42 @@ class PagedGenerationEngine(GenerationEngine):
             ins["Mask"] = [mask]
         return ins
 
+    def _expert_out_vars(self, helper):
+        """ExpertCounts [L, E] int32 of an expert block: the rows each
+        expert took in each layer of the call, fetched beside the
+        tokens (no extra ``Executor.run``)."""
+        if not self.spec.block.is_moe:
+            return {}
+        counts = helper.block.create_var(
+            name="serving.expert_counts",
+            shape=[self.spec.n_layers, self.spec.num_experts],
+            dtype="int32", stop_gradient=True)
+        return {"ExpertCounts": [counts]}
+
+    def _count_experts(self, res, rows: int) -> None:
+        """Fold a call's ExpertCounts (the last fetch) into the engine
+        counters: ``moe_assignments`` (rows the experts took, over
+        layers), ``moe_hot_expert_rows`` (the busiest expert's rows,
+        summed over layers), ``moe_touched_experts`` (experts that took
+        at least one row, summed over the call's ``moe_layer_calls``
+        layers: their weights are what the grouped matmuls must read)
+        and ``moe_dropped_tokens`` (the ``rows`` token rows of the call x
+        top-k x layers, minus what the experts took: dropless routing
+        keeps it 0). Every row of the static batch is routed, vacant
+        slots and padding included — that is the work the device does."""
+        if not self.spec.block.is_moe:
+            return
+        counts = np.asarray(res[-1])                  # [L, E]
+        took = int(counts.sum())
+        self.metrics.inc("moe_assignments", took)
+        self.metrics.inc("moe_hot_expert_rows",
+                         int(counts.max(axis=1).sum()))
+        self.metrics.inc("moe_touched_experts", int((counts > 0).sum()))
+        self.metrics.inc("moe_layer_calls", int(counts.shape[0]))
+        self.metrics.inc("moe_dropped_tokens",
+                         rows * self.spec.experts_per_tok
+                         * self.spec.n_layers - took)
+
     def _beam_out_vars(self, helper, rows: int, prefix: str):
         """TopV/TopI output vars when the beam plane is on."""
         if not self.beam_width:
@@ -1257,10 +1297,11 @@ class PagedGenerationEngine(GenerationEngine):
             ins.update(self._lm_ins(helper))
             outs = {"NextTok": [nxt], "CacheK": [ck], "CacheV": [cv]}
             outs.update(self._beam_out_vars(helper, 0, "serving.pf"))
+            outs.update(self._expert_out_vars(helper))
             helper.append_op("transformer_stack_paged_prefill", ins,
                              outs, self._decode_attrs())
         fetches = [nxt.name] + [v[0].name for k, v in sorted(outs.items())
-                                if k in ("TopV", "TopI")]
+                                if k in ("TopV", "TopI", "ExpertCounts")]
         self._transpile(prog, list(self._prefill_feed_names), fetches,
                         f"transpile/prefill{tc}/")
         return prog, outs
@@ -1288,10 +1329,11 @@ class PagedGenerationEngine(GenerationEngine):
             outs = {"NextTok": [nxt], "CacheK": [ck], "CacheV": [cv]}
             outs.update(self._beam_out_vars(helper, self._nslots,
                                             "serving.dec"))
+            outs.update(self._expert_out_vars(helper))
             helper.append_op("transformer_stack_paged_decode", ins,
                              outs, self._decode_attrs())
         fetches = [nxt.name] + [v[0].name for k, v in sorted(outs.items())
-                                if k in ("TopV", "TopI")]
+                                if k in ("TopV", "TopI", "ExpertCounts")]
         self._transpile(prog, list(self._decode_feed_names), fetches,
                         "transpile/decode/")
         return prog, outs
@@ -1346,6 +1388,8 @@ class PagedGenerationEngine(GenerationEngine):
         fetches = [outs["NextTok"][0]]
         if self.beam_width:
             fetches += [outs["TopV"][0], outs["TopI"][0]]
+        if "ExpertCounts" in outs:      # last: _count_experts reads res[-1]
+            fetches.append(outs["ExpertCounts"][0])
         return fetches
 
     def _neutral_sampling_feed(self, rows: int) -> Dict[str, np.ndarray]:
@@ -1819,6 +1863,7 @@ class PagedGenerationEngine(GenerationEngine):
                                     fetch_list=self._fetches(outs),
                                     scope=self.scope)
         t1 = time.perf_counter()
+        self._count_experts(res, bucket * tc)
         first = np.asarray(res[0])
         topv, topi = ((np.asarray(res[1]), np.asarray(res[2]))
                       if self.beam_width else (None, None))
@@ -1945,6 +1990,7 @@ class PagedGenerationEngine(GenerationEngine):
                                     fetch_list=self._fetches(outs),
                                     scope=self.scope)
         t1 = time.perf_counter()
+        self._count_experts(res, bucket * tc)
         self.metrics.observe_latency(t1 - t0, name="prefill_chunk")
         self.metrics.inc("prefill_chunks")
         st.timeline.chunk(t0, t1, k)
@@ -1991,6 +2037,7 @@ class PagedGenerationEngine(GenerationEngine):
         res = self.executor.run(prog, feed=feed,
                                 fetch_list=self._fetches(outs),
                                 scope=self.scope)
+        self._count_experts(res, self._nslots)
         if self.beam_width:
             return (np.asarray(res[0]), np.asarray(res[1]),
                     np.asarray(res[2]))

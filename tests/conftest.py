@@ -49,7 +49,16 @@ def fresh_programs():
     # FLAGS.seed, changing a LATER test's parameter init and its
     # convergence) — every test starts from registered defaults
     pt.flags.reset_flags()
+    # set_amp / set_mxu_precision PIN the policy over the flag (the
+    # tri-state in ops/common.py): a test that ends with set_amp(False)
+    # would make a later test on the same worker blind to --use_amp.
+    # Which test runs before which is the scheduler's choice
+    # (--dist loadfile), so every test hands back what it found.
+    from paddle_tpu.ops import common
+
+    pinned = common._AMP, common._MXU_PRECISION
     yield
+    common._AMP, common._MXU_PRECISION = pinned
 
 
 # ---------------------------------------------------------------------------

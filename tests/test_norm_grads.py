@@ -370,16 +370,30 @@ def test_transformer_rms_norm_trains():
     assert not ln_ops
 
 
-def test_rms_norm_rejected_on_stacked_path():
-    main, startup = pt.Program(), pt.Program()
+def test_rms_norm_on_the_stacked_path_comes_from_the_spec():
+    """``norm_type`` is the per-layer path's keyword; the stacked path
+    takes its norm from ``spec=`` (PR 26: the old raise pointed nowhere).
+    The keyword still raises, naming the spec, and leaves no orphan
+    parameters; the spec builds RMSNorm planes with no bias."""
     from paddle_tpu import models
+
+    main, startup = pt.Program(), pt.Program()
     with pt.program_guard(main, startup):
         ids = layers.data("ids", shape=[8], dtype="int64")
-        with pytest.raises(ValueError, match="layer_norm"):
+        with pytest.raises(ValueError, match="spec="):
             models.transformer_lm(ids, vocab_size=32, d_model=16,
                                   n_layers=1, num_heads=1, max_len=8,
                                   norm_type="rms_norm",
                                   pipeline_stack=True)
+        assert not main.global_block.all_parameters()
+        spec = pt.LMSpec(vocab_size=32, d_model=16, n_layers=1, num_heads=1,
+                         max_len=8, norm="rms_norm")
+        models.transformer_lm(ids, spec=spec, pipeline_stack=True)
+    names = {p.name for p in main.global_block.all_parameters()}
+    assert "lm_stack.stack_ln1_s" in names and "final_ln.scale" in names
+    assert not {n for n in names if n.endswith(("ln1_b", "ln2_b"))}
+    assert "final_ln.bias" not in names
+    assert any(op.type == "rms_norm" for op in main.global_block.ops)
 
 
 def _stat_output_net(kind):
