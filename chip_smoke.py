@@ -204,8 +204,8 @@ def phase_train(cfg):
     kernels = collections.Counter(re.findall(
         r'kernel_name\s*=\s*"([^"]+)"', tr.lowered_step_text()))
     if device_info()["platform"] == "tpu":
-        for name in ("_flash_kernel", "_flash_dq_kernel",
-                     "_flash_dkv_kernel"):
+        # the names kernels/flash_attention.py gives its pallas_calls
+        for name in ("flash_fwd", "flash_dq", "flash_dkv"):
             check(kernels[name] >= 1, f"no Mosaic call {name} in the "
                   f"lowered train step ({dict(kernels)}): the flash kernel "
                   "gave way to the jnp reference")

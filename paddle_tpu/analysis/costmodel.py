@@ -529,21 +529,30 @@ def _encdec_cost(attrs, ins, outs):
 
 def _paged_cache_cost(attrs, ins, outs):
     """transformer_stack_paged_prefill/decode: the slot-cache cost plus
-    the per-row gathered context — every row streams its table-width
-    [P*ps, Hkv*dh] K/V block per layer (x2 for K and V), which is the
-    decode plane's dominant HBM term and what the dense path reads as
-    contiguous slot rows."""
+    the K/V context the attention reads per layer (x2 for K and V).
+
+    Prefill (``Chunk``): every row gathers its table-width [P*ps, Hkv*dh]
+    block — the chunk path keeps the gather. Decode (``Tok``/``Pos``): the
+    paged-attention kernel walks only the pages a row HOLDS, ``Pos // ps +
+    1`` of them (a vacant slot reads the scrap page once) — counted from
+    ``Pos`` where it carries values (a concrete array); where only its
+    shape is known, rows x P pages: the table width, an UPPER bound, and
+    what the gathered fallback (no TPU, grouped-query heads, a row not
+    lane-aligned) really moves."""
     base = _slot_cache_cost(attrs, ins, outs)
     table = _first(ins, "BlockTable")
     pool = _first(ins, "CacheK")
-    gathered = 0.0
+    context = 0.0
     if table is not None and pool is not None and len(pool.shape) == 4:
         L, _, ps, width = pool.shape  # width = Hkv*dh
         rows, P = table.shape
-        itemsize = np.dtype(pool.dtype).itemsize
-        gathered = 2.0 * float(L) * float(rows) * float(P) * float(ps) \
-            * float(width) * itemsize
-    return OpCost(flops=base.flops, bytes=base.bytes + gathered)
+        pages = float(rows) * float(P)
+        pos = _first(ins, "Pos")
+        if _first(ins, "Chunk") is None and isinstance(pos, np.ndarray):
+            pages = float(np.sum(np.clip(pos // ps, 0, P - 1) + 1))
+        context = 2.0 * float(L) * pages * float(ps) * float(width) \
+            * np.dtype(pool.dtype).itemsize
+    return OpCost(flops=base.flops, bytes=base.bytes + context)
 
 
 # --------------------------------------------------------------------------

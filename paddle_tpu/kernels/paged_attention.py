@@ -1,0 +1,217 @@
+"""Paged decode attention: one Pallas TPU kernel that walks the block table.
+
+The decode tick of the paged serving step attends ONE new token per batch
+row to that row's cached context, which lives as pages of a pool
+[L, N, ps, Hkv*dh] addressed through a block table [b, P]. The gathered
+form (``ops/pipeline_ops._gather_pages`` + ``reference_attention``) moves
+b * P pages a layer and pool whatever the rows hold, re-lays the gathered
+context out so that d_head is minor, and passes over it twice. This kernel
+reads only the pages a row HOLDS, straight from the whole pool:
+
+* the block table, the lengths and the layer index are scalar-prefetch
+  operands; the pools stay in HBM (``memory_space=ANY``) and are never
+  sliced or copied — row s's page i is DMA'd from ``pool[layer,
+  table[s, i]]`` as one contiguous, lane-dense [ps, Hkv*dh] tile, double
+  buffered, ``ceil(length / ps)`` of them (at least one: a vacant slot
+  reads the scrap page once). The walk is one chain over (row, page) in
+  table order: the last page of a row prefetches the first of the next.
+* heads are split in VMEM, not in HBM: the page tile is multiplied by a
+  block-diagonal query Q_bd [H, H*dh] (row h holds q_h in columns
+  h*dh..h*dh+dh-1, zeros elsewhere), so scores = Q_bd @ K_page^T is
+  [H, ps] with no per-head lane slicing (d_head 64 is half a lane row);
+  P @ V_page is [H, H*dh], of which row h's own dh columns are its
+  context; the masked reduction over rows leaves [H*dh] — the context row
+  as the out-projection reads it. The extra MXU work (H x) hides under
+  the page's bytes.
+* the mathematics are ``reference_attention``'s: queries in the pool's
+  dtype, float32 scores and softmax (online over pages: running max and
+  denominator), 1/sqrt(dh) scale, probabilities cast to the page dtype
+  before P @ V, float32 accumulation, keys j < length only. A row's result
+  depends on that row's pages alone, walked in table order.
+
+The jnp gather + ``reference_attention`` stays the semantic ground truth
+and the path of every other shape (t > 1, CPU, GQA, unaligned widths):
+``supported`` is the whole dispatch rule, read off the operands.
+"""
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+
+#: Q_bd's rows are padded to this many (zero rows score 0 against every
+#: key and are masked out of the result): one sublane tile of bf16, two of
+#: float32, so the two matmuls never see a ragged M
+_ROW_TILE = 16
+
+
+def _sublane_tile(dtype) -> int:
+    """Rows of one (sublane x 128-lane) tile of ``dtype`` on the TPU."""
+    return 8 * max(4 // jnp.dtype(dtype).itemsize, 1)
+
+
+def supported(q_width: int, pool, t: int) -> bool:
+    """Whether the decode kernel can take this call: one query token a
+    row, a TPU backend, as many query heads as cached heads (a query row
+    of ``q_width`` = H*dh floats against a pool [L, N, ps, Hkv*dh] of the
+    same width), a lane-aligned row and a page of whole sublane tiles.
+    Everything here is a shape, a dtype or the backend: nothing names a
+    model."""
+    ps, width = pool.shape[2:]
+    return (t == 1 and jax.default_backend() == "tpu"
+            and q_width == width and width % 128 == 0
+            and ps % _sublane_tile(pool.dtype) == 0)
+
+
+def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   kbuf, vbuf, sems, cur_ref, m_ref, l_ref, acc_ref, *,
+                   d_head, pmax):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s, rows = pl.program_id(0), pl.num_programs(0)
+    ps, width = kbuf.shape[1:]
+    sm_scale = 1.0 / math.sqrt(d_head)
+    # indices clamped as the gather clamps them: a DMA outside the pool
+    # would fault the chip where XLA reads the nearest page
+    layer = jnp.clip(layer_ref[0], 0, k_hbm.shape[0] - 1)
+    length = len_ref[s]
+    n_pages = jnp.clip((length + ps - 1) // ps, 1, pmax)
+
+    def page_copies(buf, row, i):
+        page = jnp.clip(table_ref[row * pmax + i], 0, k_hbm.shape[1] - 1)
+        return (pltpu.make_async_copy(k_hbm.at[layer, page], kbuf.at[buf],
+                                      sems.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[layer, page], vbuf.at[buf],
+                                      sems.at[1, buf]))
+
+    def start(buf, row, i):
+        for c in page_copies(buf, row, i):
+            c.start()
+
+    @pl.when(s == 0)
+    def _():
+        cur_ref[0] = 0
+        start(0, 0, 0)
+
+    buf0 = cur_ref[0]
+    # float32 pages are multiplied as float32 (the MXU's default is one
+    # bf16 pass, ~1e-2 off on the chip where the gathered reference is
+    # exact to 1e-6); bf16 products are exact in the float32 accumulator
+    precision = (jax.lax.Precision.HIGHEST if kbuf.dtype == jnp.float32
+                 else None)
+    # the block-diagonal query: row h keeps columns h*dh..h*dh+dh-1 (the
+    # padding rows past H own no column). Selected in float32: Mosaic does
+    # not re-tile the mask for bf16
+    shape = acc_ref.shape                                       # [hp, width]
+    row_id = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col_id = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    own = (col_id >= row_id * d_head) & (col_id < (row_id + 1) * d_head)
+    q_bd = jnp.where(own, q_ref[0].astype(jnp.float32), 0.0).astype(
+        q_ref.dtype)
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def page(i, _):
+        buf = (buf0 + i) % 2
+
+        @pl.when(i + 1 < n_pages)
+        def _():
+            start(1 - buf, s, i + 1)
+
+        @pl.when((i + 1 == n_pages) & (s + 1 < rows))
+        def _():
+            start(1 - buf, s + 1, 0)
+
+        for c in page_copies(buf, s, i):
+            c.wait()
+        k, v = kbuf[buf], vbuf[buf]
+        sc = jax.lax.dot_general(
+            q_bd, k, dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=precision,
+            preferred_element_type=jnp.float32) * sm_scale      # [hp, ps]
+        key = i * ps + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        sc = jnp.where(key < length, sc, -jnp.inf)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+        # a row with no key yet (length 0) keeps exp() off inf - inf
+        m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        p = jnp.exp(sc - m_safe)
+        alpha = jnp.exp(m - m_safe)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            p.astype(v.dtype), v,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=precision,
+            preferred_element_type=jnp.float32)                 # [hp, width]
+        m_ref[...] = m_new
+        return 0
+
+    jax.lax.fori_loop(0, n_pages, page, 0)
+    cur_ref[0] = (buf0 + n_pages) % 2
+    # each row's own dh columns, normalised; a row without a key has
+    # accumulated nothing and stays zero
+    l = l_ref[...]
+    ctx = jnp.where(own, acc_ref[...] / jnp.where(l > 0, l, 1.0), 0.0)
+    o_ref[0] = jnp.sum(ctx, axis=0, keepdims=True).astype(o_ref.dtype)
+
+
+def paged_attention_decode(q, cache_k, cache_v, layer, table, lengths,
+                           interpret=False):
+    """Attention of one query token a row over the pages the row holds.
+
+    q [b, H, dh] (cast to the pools' dtype), cache_k / cache_v the WHOLE
+    pools [L, N, ps, H*dh], layer a scalar int32, table [b, P] int32,
+    lengths [b] int32 (keys j < length attend; 0 gives a zero row) -> the
+    context [b, H*dh] in the pools' dtype, a token's heads side by side
+    as the out-projection reads them."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if q.ndim != 3:
+        raise ValueError(f"q must be [b, H, dh], got {q.shape}")
+    b, heads, d_head = q.shape
+    ps, width = cache_k.shape[2:]
+    if heads * d_head != width or cache_v.shape != cache_k.shape:
+        raise ValueError(f"q {q.shape} does not match the pools "
+                         f"{cache_k.shape} / {cache_v.shape}")
+    pmax = table.shape[1]
+    hp = -(-heads // _ROW_TILE) * _ROW_TILE
+    dtype = cache_k.dtype
+    kernel = functools.partial(_decode_kernel, d_head=d_head, pmax=pmax)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,  # layer, the flattened table, lengths
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, 1, width), lambda s, *_: (s, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, 1, width), lambda s, *_: (s, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, ps, width), dtype),      # K pages, two in flight
+            pltpu.VMEM((2, ps, width), dtype),      # V pages
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),            # buffer of the next page
+            pltpu.VMEM((hp, 1), jnp.float32),       # running max
+            pltpu.VMEM((hp, 1), jnp.float32),       # running denominator
+            pltpu.VMEM((hp, width), jnp.float32),   # un-normalised P @ V
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, width), dtype),
+        # the (row, page) chain carries its DMA from one grid step to the
+        # next: the steps run in order on one core
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_attention_decode",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      table.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
+      q.reshape(b, 1, width).astype(dtype), cache_k, cache_v)
+    return out.reshape(b, width)

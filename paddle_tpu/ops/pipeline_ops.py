@@ -825,11 +825,17 @@ def transformer_stack_slot_decode(attrs, ins, rng=None):
 #
 # What a step moves now (_scan_paged_layers): per layer and pool, one
 # in-place scatter of the new tokens' rows (t * Hkv*dh floats a batch
-# row) and one gather of each row's table-width context [P*ps, Hkv*dh],
-# which reference_attention then reads — proportional to rows x table
-# width, not to N. It no longer slices, transposes, restacks or copies
-# the pool. The table-width gather is what is left for a Pallas
-# paged-attention kernel that reads only the pages a row holds.
+# row), then the context. A DECODE tick on a chip (t == 1) hands the WHOLE
+# pools, the layer index, the block table and the lengths to one Pallas
+# kernel (kernels/paged_attention.py) that DMAs the pages each row HOLDS
+# — ceil(length / ps) tiles of [ps, Hkv*dh] per row and pool, one for a
+# vacant slot — and splits the heads in VMEM: a tick moves the weights
+# plus the K/V of the tokens in flight, not slots x table width. Every
+# other call (a prefill chunk or group: t > 1; no TPU; grouped-query
+# heads; a row that is not lane-aligned) gathers each row's table-width
+# context [P*ps, Hkv*dh] and hands it to reference_attention, which stays
+# the semantic ground truth. Neither path slices, transposes, restacks or
+# copies the pool.
 # ---------------------------------------------------------------------------
 
 _SAMPLING_SLOTS = ("Temperature", "TopK", "TopP", "Seed", "Step", "Mask")
@@ -905,8 +911,12 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
     ONE scatter per pool at (l, page_id, page_row) — page_id / page_row
     are [b, t] (decode: t == 1), the three index arrays are adjacent and
     the update window is a token's whole [Hkv*dh] row, contiguous in the
-    pool; the context is gathered at (l, table) and attended with
-    ``reference_attention(**mask)``; ``finish(layer_p, h, ctx, x_l)``
+    pool; the context is attended either by the paged decode kernel, which
+    reads the pages each row holds straight from the whole pool (a decode
+    step on a chip: ``paged_attention.supported`` and a ``lengths``-only
+    ``mask``), or gathered at (l, table) and attended with
+    ``reference_attention(**mask)`` (everything else: the ground truth);
+    ``finish(layer_p, h, ctx, x_l)``
     -> (h, stats) closes the block, x_l being layer l's slice of the
     optional scanned-over ``xs`` and stats what the layer reports (None,
     or an expert layer's (counts, router prob mean)). Returns (h,
@@ -921,11 +931,17 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
     it and copied the whole pool once per call — all proportional to N,
     none to the tokens in flight. As carry the donated buffers ARE the
     loop state and the only pool-shaped ops are the two in-place
-    scatters."""
+    scatters; the decode kernel takes the carry whole and addresses it at
+    (l, page), so it adds none."""
+    from ..kernels import paged_attention
     from ..kernels.flash_attention import reference_attention
 
     b, t, d = h.shape
     n_layers = cache_k.shape[0]
+    # a decode step (one query token a row, keys j < lengths) on a chip
+    # walks the block table in one kernel; every other call gathers
+    walk_pages = set(mask) == {"lengths"} and paged_attention.supported(
+        d, cache_k, t)
     whole = {k: params[k] for k in _RESIDENT_PLANES if k in params}
     params = {k: v for k, v in params.items() if k not in whole}
     ix_page = page_id.reshape(b, t)
@@ -943,10 +959,15 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
         hkv = k.shape[1]
         ck = ck.at[l, ix_page, ix_row].set(token_rows(k).astype(ck.dtype))
         cv = cv.at[l, ix_page, ix_row].set(token_rows(v).astype(cv.dtype))
-        ctx = reference_attention(q.astype(ck.dtype),
-                                  _gather_pages(ck, l, table, hkv),
-                                  _gather_pages(cv, l, table, hkv), **mask)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, t, d)
+        if walk_pages:
+            ctx = paged_attention.paged_attention_decode(
+                q[:, :, 0], ck, cv, l, table, mask["lengths"])[:, None]
+        else:
+            ctx = reference_attention(q.astype(ck.dtype),
+                                      _gather_pages(ck, l, table, hkv),
+                                      _gather_pages(cv, l, table, hkv),
+                                      **mask)
+            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, t, d)
         h, stats = finish(layer_p, h, ctx, x_l)
         return (h, ck, cv), stats
 
@@ -1065,9 +1086,12 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
 
     The pools ride the layer loop as its in-place carry
     (``_scan_paged_layers``): a tick writes S token rows per layer and
-    pool and gathers S table-width contexts [P*ps, Hkv*dh] — the weights
-    plus that gather are what a tick moves; no layer of the pool is
-    sliced, re-laid out, restacked or copied.
+    pool and, on a chip, reads the pages each slot HOLDS (``Pos // ps +
+    1``; the scrap page for a vacant slot) in one paged-attention kernel
+    a layer — the weights plus the K/V of the tokens in flight are what
+    a tick moves. Elsewhere (CPU, grouped-query heads, a row not
+    lane-aligned) it gathers S table-width contexts [P*ps, Hkv*dh]. No
+    layer of the pool is sliced, re-laid out, restacked or copied.
 
     The slot axis is the batch axis and the table width is static, so the
     compiled shape never depends on occupancy or sequence lengths — the

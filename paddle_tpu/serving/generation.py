@@ -2033,6 +2033,12 @@ class PagedGenerationEngine(GenerationEngine):
                                          step=len(st.generated))
         feed.update({"serving.tok": tok, "serving.pos": pos,
                      "serving.block_table": table})
+        # the pages the decode attention walks this tick (one per slot at
+        # least: a vacant slot reads the scrap page) against the table it
+        # would gather whole (kernels/paged_attention.py)
+        self.metrics.inc("paged_attn_pages_read",
+                         int((pos // self.page_size + 1).sum()))
+        self.metrics.inc("paged_attn_table_pages", table.size)
         prog, outs = self._decode_prog
         res = self.executor.run(prog, feed=feed,
                                 fetch_list=self._fetches(outs),

@@ -1,0 +1,349 @@
+"""The paged decode-attention kernel (kernels/paged_attention.py) against
+the semantic ground truth — ``reference_attention`` over
+``_gather_pages`` — in Pallas interpret mode on the CPU mesh, and the
+rule that chooses between them in ``_scan_paged_layers``."""
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.kernels import paged_attention
+from paddle_tpu.kernels.flash_attention import reference_attention
+from paddle_tpu.kernels.paged_attention import paged_attention_decode
+from paddle_tpu.ops.pipeline_ops import _gather_pages, _scan_paged_layers
+
+L, N, PS, P = 2, 24, 16, 4          # layers, pages, page size, table width
+#: (page dtype, heads, d_head): the two serving cells' rows, 16x64 float32
+#: and 16x128 bf16
+WIDTHS = [pytest.param(jnp.float32, 16, 64, id="f32-16x64"),
+          pytest.param(jnp.bfloat16, 16, 128, id="bf16-16x128")]
+
+
+def _pools(dtype, width, seed=0):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.standard_normal((L, N, PS, width)), dtype)
+                 for _ in range(2))
+
+
+def _queries(dtype, b, heads, d_head, seed=1):
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(2 * rng.standard_normal((b, heads, d_head)), dtype)
+
+
+def _reference(q, ck, cv, layer, table, lengths):
+    heads = q.shape[1]
+    ctx = reference_attention(
+        q[:, :, None, :], _gather_pages(ck, layer, table, heads),
+        _gather_pages(cv, layer, table, heads), lengths=lengths)
+    return ctx.transpose(0, 2, 1, 3).reshape(q.shape[0], -1)
+
+
+def _table(rows):
+    """rows: a list of page lists -> [b, P] int32, padded with the scrap
+    page 0 as the engine pads it."""
+    out = np.zeros((len(rows), P), np.int32)
+    for s, pages in enumerate(rows):
+        out[s, :len(pages)] = pages
+    return out
+
+
+#: name -> (table rows, lengths)
+CONTEXTS = {
+    # every slot vacant: Pos 0, table all zeros, one key on the scrap page
+    "vacant": ([[], [], []], [1, 1, 1]),
+    "page_boundary": ([[3], [5, 9], [7, 2, 11]], [PS, 2 * PS, 2 * PS + 1]),
+    "full_table": ([[4, 8, 15, 16], [23, 1, 2, 3]], [P * PS, P * PS]),
+    # two requests behind one shared prefix (pages 6, 7), diverging after
+    "shared_pages": ([[6, 7, 12], [6, 7, 13], [6, 7]],
+                     [2 * PS + 5, 2 * PS + 9, 2 * PS]),
+    "permuted_table": ([[21, 3, 17, 2], [9, 20, 1]],
+                       [3 * PS + 7, 2 * PS + 2]),
+    "ragged": ([[], [10], [11, 12], [13, 14, 18, 19]],
+               [1, PS - 1, PS + 1, 4 * PS - 3]),
+    # reference_attention gives a fully masked row zeros, not NaN
+    "no_key": ([[5], [6]], [0, 3]),
+}
+
+
+def _tolerance(dtype, got_ref, truth):
+    """float32: 1e-5. bf16: the reference's OWN distance from the float32
+    answer on the same bf16 operands (it rounds p and the result to bf16
+    as the kernel does), doubled, and never under one bf16 ulp of the
+    largest value."""
+    if dtype == jnp.float32:
+        return 1e-5
+    ulp = 2.0 ** -8 * float(np.abs(truth).max())
+    return max(2 * float(np.abs(got_ref - truth).max()), ulp)
+
+
+@pytest.mark.parametrize("context", sorted(CONTEXTS))
+@pytest.mark.parametrize("dtype,heads,d_head", WIDTHS)
+def test_kernel_is_reference_attention_over_the_gathered_pages(
+        dtype, heads, d_head, context):
+    rows, lengths = CONTEXTS[context]
+    table, lengths = jnp.asarray(_table(rows)), jnp.asarray(lengths,
+                                                            jnp.int32)
+    ck, cv = _pools(dtype, heads * d_head)
+    q = _queries(dtype, len(rows), heads, d_head)
+    layer = jnp.int32(1)
+    got = paged_attention_decode(q, ck, cv, layer, table, lengths,
+                                 interpret=True)
+    assert got.shape == (len(rows), heads * d_head) and got.dtype == dtype
+    want = _reference(q, ck, cv, layer, table, lengths)
+    f32 = [a.astype(jnp.float32) for a in (q, ck, cv)]
+    truth = np.asarray(_reference(*f32, layer, table, lengths))
+    got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
+    tol = _tolerance(dtype, want, truth)
+    np.testing.assert_allclose(got, truth, atol=tol, rtol=0)
+    np.testing.assert_allclose(got, want, atol=2 * tol, rtol=0)
+    if context == "no_key":
+        assert not got[0].any()
+
+
+@pytest.mark.parametrize("dtype,heads,d_head", WIDTHS)
+def test_only_the_pages_a_row_holds_are_read(dtype, heads, d_head):
+    """Every page no row holds — the other layer whole, the pages past a
+    row's length that its table still names — is NaN: the result is finite
+    and bitwise what the clean pools give. (The gathered reference reads
+    them all: 0 x NaN.)"""
+    rows = [[3, 4, 23], [], [5, 22, 21]]
+    lengths = [2 * PS, 1, PS + 2]       # rows 0 and 2 name pages unheld
+    held = [0, 3, 4, 5, 22]             # 0: the vacant row's scrap page
+    table, lens = jnp.asarray(_table(rows)), jnp.asarray(lengths, jnp.int32)
+    ck, cv = _pools(dtype, heads * d_head, seed=3)
+    q = _queries(dtype, 3, heads, d_head)
+    layer = jnp.int32(0)
+    clean = paged_attention_decode(q, ck, cv, layer, table, lens,
+                                   interpret=True)
+    poison = np.ones((L, N), bool)
+    poison[0, held] = False
+    mask = jnp.asarray(poison)[:, :, None, None]
+    ck_p, cv_p = (jnp.where(mask, jnp.nan, a) for a in (ck, cv))
+    assert bool(jnp.isnan(ck_p[0, 23]).all() & jnp.isnan(ck_p[1]).all())
+    got = paged_attention_decode(q, ck_p, cv_p, layer, table, lens,
+                                 interpret=True)
+    got, clean = (np.asarray(a.astype(jnp.float32)) for a in (got, clean))
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, clean)
+    assert np.isnan(np.asarray(_reference(
+        q, ck_p, cv_p, layer, table, lens).astype(jnp.float32))).any()
+
+
+def test_a_row_depends_on_its_own_context_alone():
+    """Batch invariance: a row's context is the same whatever the other
+    rows hold and wherever it sits in the batch."""
+    dtype, heads, d_head = jnp.float32, 16, 64
+    ck, cv = _pools(dtype, heads * d_head, seed=5)
+    q = _queries(dtype, 3, heads, d_head)
+    layer = jnp.int32(1)
+    rows, lengths = [[9, 2, 14], [], [7, 8, 1, 20]], [2 * PS + 4, 1, 4 * PS]
+    full = paged_attention_decode(
+        q, ck, cv, layer, jnp.asarray(_table(rows)),
+        jnp.asarray(lengths, jnp.int32), interpret=True)
+    alone = paged_attention_decode(
+        q[:1], ck, cv, layer, jnp.asarray(_table(rows[:1])),
+        jnp.asarray(lengths[:1], jnp.int32), interpret=True)
+    swapped = paged_attention_decode(
+        q[::-1], ck, cv, layer, jnp.asarray(_table(rows[::-1])),
+        jnp.asarray(lengths[::-1], jnp.int32), interpret=True)
+    np.testing.assert_array_equal(np.asarray(full[0]), np.asarray(alone[0]))
+    np.testing.assert_array_equal(np.asarray(full), np.asarray(swapped[::-1]))
+
+
+# ---------------------------------------------------------------------------
+# Mosaic takes the kernel at the two serving cells' shapes: interpret mode
+# cannot see tiling, VMEM or DMA-slice refusals; the chip's compiler is
+# installed here and compiles for a chip that is described, not attached
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 - no compiler here: no test
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("dtype,layers,pages,heads,d_head,table_width", [
+    pytest.param(jnp.float32, 24, 400, 16, 64, 16, id="gpt2m-serve-chat"),
+    pytest.param(jnp.bfloat16, 8, 1024, 16, 128, 32, id="olmoe-serve-chat"),
+])
+def test_kernel_compiles_for_the_v5e_with_the_pool_whole(
+        one_chip, dtype, layers, pages, heads, d_head, table_width):
+    """The whole pool enters the custom call as it lies in HBM: no copy,
+    slice or re-layout of anything pool- or layer-sized is compiled in."""
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    slots, ps, width = 32, 64, heads * d_head
+    pool = arg((layers, pages, ps, width), dtype)
+    text = jax.jit(paged_attention_decode).lower(
+        arg((slots, heads, d_head), dtype), pool, pool, arg((), jnp.int32),
+        arg((slots, table_width), jnp.int32),
+        arg((slots,), jnp.int32)).compile().as_text()
+    call = [ln for ln in text.splitlines()
+            if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(call) == 1 and "%paged_attention_decode" in call[0]
+    ops = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(",
+                     re.sub(r"\{[^{}]*\}", "", text), re.M)
+    assert ops
+    assert {op for shape, op in ops
+            if f"{pages},{ps},{width}]" in shape} == {"parameter"}
+
+
+def test_decode_step_on_the_v5e_keeps_the_pool_the_scan_carry(one_chip,
+                                                              monkeypatch):
+    """The whole decode op compiled for the chip: the kernel sits in the
+    layer loop, the pools are donated and updated in place, and the only
+    pool-shaped ops left are the two scatters — handing the carry to the
+    custom call whole costs no copy, dynamic-slice or re-layout."""
+    from paddle_tpu.lm_spec import LMSpec
+    from paddle_tpu.ops.pipeline_ops import transformer_stack_paged_decode
+
+    spec = LMSpec(vocab_size=512, d_model=256, n_layers=3, num_heads=4,
+                  max_len=256)
+    slots, pages, ps, table_width = 8, 96, 32, 8
+    shapes = {
+        "Tok": ((slots,), "int32"), "Pos": ((slots,), "int32"),
+        "BlockTable": ((slots, table_width), "int32"),
+        "CacheK": ((spec.n_layers, pages, ps, spec.d_model), "float32"),
+        "CacheV": ((spec.n_layers, pages, ps, spec.d_model), "float32"),
+        "TokEmb": ((spec.vocab_size, spec.d_model), "float32"),
+        "PosEmb": ((spec.max_len, spec.d_model), "float32"),
+        "FinalLnS": ((spec.d_model,), "float32"),
+        "FinalLnB": ((spec.d_model,), "float32"),
+        "HeadW": ((spec.d_model, spec.vocab_size), "float32")}
+    for slot, _key, shape, _fan in spec.stack_planes():
+        shapes[slot] = ((spec.n_layers, *shape), "float32")
+    names = sorted(shapes)
+    attrs = dict(spec.block.attrs(), page_size=ps)
+
+    def step(*args):
+        outs = transformer_stack_paged_decode(
+            attrs, {k: [a] for k, a in zip(names, args)})
+        return {k: v[0] for k, v in outs.items()}
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    donate = tuple(names.index(n) for n in ("CacheK", "CacheV"))
+    text = jax.jit(step, donate_argnums=donate).lower(*[
+        jax.ShapeDtypeStruct(shapes[n][0], shapes[n][1], sharding=one_chip)
+        for n in names]).compile().as_text()
+    assert "%paged_attention_decode" in text
+    ops = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(",
+                     re.sub(r"\{[^{}]*\}", "", text), re.M)
+    pool = f"[{spec.n_layers},{pages},{ps},{spec.d_model}]"
+    layer = f"[{pages},{ps},{spec.d_model}]"
+    gathered = f"[{slots * table_width},{ps},{spec.d_model}]"
+    moved = {op for shape, op in ops
+             if shape.endswith((pool, layer, gathered))
+             and not shape.startswith("(")}
+    assert moved <= {"parameter", "get-tuple-element", "scatter", "fusion",
+                     "bitcast"}, moved
+    assert not [shape for shape, op in ops if gathered in shape]
+    # one in-place scatter fusion a pool, nothing else writes a pool
+    assert sorted(op for shape, op in ops if shape.endswith(pool)
+                  and op in ("fusion", "scatter")) == [
+        "fusion", "fusion", "scatter", "scatter"]
+
+
+# ---------------------------------------------------------------------------
+# the dispatch rule of _scan_paged_layers
+# ---------------------------------------------------------------------------
+def _run_layers(monkeypatch, backend, dtype=jnp.float32, heads=4, kv_heads=4,
+                d_head=32, t=1, causal=False, ps=PS):
+    """One pass of ``_scan_paged_layers`` over toy projections with the
+    backend reported as ``backend``; the kernel, where chosen, runs in
+    interpret mode. -> (h, how often the kernel was traced)."""
+    calls = []
+
+    def spy(*args, **kwargs):
+        calls.append(args[0].shape)
+        return paged_attention_decode(*args, interpret=True, **kwargs)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(paged_attention, "paged_attention_decode", spy)
+    b, d = 3, heads * d_head
+    width = kv_heads * d_head
+    rng = np.random.default_rng(0)
+    ck, cv = (jnp.asarray(rng.standard_normal((L, N, ps, width)), dtype)
+              for _ in range(2))
+    h = jnp.asarray(rng.standard_normal((b, t, d)), jnp.float32)
+    table = jnp.asarray(_table([[3, 4], [5], [6, 7, 8]]))
+    pos = jnp.asarray([ps + 2, 0, 2 * ps + 5], jnp.int32)
+    at = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+    page_id = jnp.take_along_axis(table, at // ps, axis=1)
+
+    def project(p, x):
+        q = x.reshape(b, t, heads, d_head).transpose(0, 2, 1, 3)
+        kv = (x * p["w"]).reshape(b, t, heads, d_head)[:, :, :kv_heads]
+        kv = kv.transpose(0, 2, 1, 3)
+        return q, kv, 0.5 * kv
+
+    mask = (dict(causal=True, q_pos0=pos) if causal
+            else dict(lengths=pos + t))
+    out, ck2, cv2, _ = _scan_paged_layers(
+        {"w": jnp.linspace(0.5, 1.5, L)[:, None]}, h, ck, cv, table,
+        page_id, at % ps, project, mask,
+        lambda p, x, ctx, _x: (x + ctx.astype(x.dtype), None))
+    return np.asarray(out), len(calls)
+
+
+def test_a_decode_step_on_a_chip_takes_the_kernel(monkeypatch):
+    """t == 1 + TPU + a lane-aligned row + lengths-only mask: the kernel,
+    once in the scanned layer body, and the same h as the gathered path."""
+    got, traced = _run_layers(monkeypatch, "tpu")
+    want, none = _run_layers(monkeypatch, "cpu")
+    assert (traced, none) == (1, 0)
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("why,kwargs", [
+    ("not a TPU", dict(backend="cpu")),
+    ("not a TPU", dict(backend="gpu")),
+    ("a prefill chunk: t > 1", dict(backend="tpu", t=4, causal=True)),
+    ("block-causal mask", dict(backend="tpu", causal=True)),
+    ("row narrower than the lanes", dict(backend="tpu", heads=2, kv_heads=2)),
+    ("grouped-query: Hkv < H", dict(backend="tpu", heads=8, kv_heads=4)),
+    ("page of half a bf16 tile", dict(backend="tpu", dtype=jnp.bfloat16,
+                                      ps=8)),
+])
+def test_everything_else_keeps_the_gathered_reference(monkeypatch, why,
+                                                      kwargs):
+    got, traced = _run_layers(monkeypatch, **kwargs)
+    assert traced == 0, why
+    assert np.isfinite(got).all()
+
+
+@pytest.mark.parametrize("dtype,ps,ok", [
+    (jnp.float32, 8, True), (jnp.float32, 4, False),
+    (jnp.bfloat16, 16, True), (jnp.bfloat16, 8, False)])
+def test_supported_reads_shapes_dtype_and_backend_only(monkeypatch, dtype,
+                                                       ps, ok):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pool = jax.ShapeDtypeStruct((2, 8, ps, 256), dtype)
+    assert paged_attention.supported(256, pool, 1) is ok
+    assert not paged_attention.supported(256, pool, 2)
+    assert not paged_attention.supported(512, pool, 1)      # Hkv < H
+    narrow = jax.ShapeDtypeStruct((2, 8, ps, 64), dtype)
+    assert not paged_attention.supported(64, narrow, 1)
+
+
+def test_wrapper_refuses_mismatched_operands():
+    ck, cv = _pools(jnp.float32, 256)
+    table = jnp.zeros((2, P), jnp.int32)
+    lengths = jnp.ones((2,), jnp.int32)
+    with pytest.raises(ValueError, match="does not match the pools"):
+        paged_attention_decode(jnp.zeros((2, 4, 32)), ck, cv, 0, table,
+                               lengths, interpret=True)
+    with pytest.raises(ValueError, match=r"\[b, H, dh\]"):
+        paged_attention_decode(jnp.zeros((2, 256)), ck, cv, 0, table,
+                               lengths, interpret=True)

@@ -134,6 +134,14 @@ def test_paged_cache_ops_conform():
     cost = registry.get_op("transformer_stack_paged_decode").cost_fn(
         attrs, ins, outs)
     assert cost.flops > 0 and cost.bytes > 0
+    # the decode op's K/V term is the pages the rows HOLD where Pos
+    # carries values, else rows x table width as an upper bound
+    page = 2 * L * ps * Hkv * dh * 4                  # K and V, all layers
+    cost_fn = registry.get_op("transformer_stack_paged_decode").cost_fn
+    held = dict(ins, Pos=[np.array([0, 2 * ps + 1], np.int32)])
+    assert cost.bytes - cost_fn(attrs, held, outs).bytes == (S * P - 4) * page
+    full = dict(ins, Pos=[np.array([P * ps - 1, P * ps + 7], np.int32)])
+    assert cost_fn(attrs, full, outs).bytes == cost.bytes
 
 
 def test_audit_accepts_cost_exempt_marker():

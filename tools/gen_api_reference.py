@@ -64,6 +64,8 @@ MODULES = [
     ("paddle_tpu.v2.layer", "v2 keyword layer namespace"),
     ("paddle_tpu.v2.networks", "v2 composite networks"),
     ("paddle_tpu.kernels.flash_attention", "Pallas flash attention"),
+    ("paddle_tpu.kernels.paged_attention",
+     "Pallas paged decode attention: walks the block table"),
 ]
 
 
