@@ -1323,127 +1323,6 @@ def bench_feedback_loop(jax, pt, layers, vocab=512, n_requests=192,
     }
 
 
-def bench_paged_kv(jax, pt, layers, models, tmax=2048, page_size=64,
-                   dense_slots=4, prompt_len=48, max_new=8,
-                   n_requests=24, d=32, L=2, H=4, vocab=128,
-                   shared_prefix=64):
-    """Dense-vs-paged KV cache A/B at EQUAL HBM budget.
-
-    Both engines get byte-identical KV allocations: the dense slot table
-    [L, slots+1, Hkv, Tmax, dh] x2 vs a page pool holding exactly the
-    same bytes ((slots+1) * Tmax/page_size pages). With short prompts the
-    paged engine admits every request CONCURRENTLY (a sequence holds
-    ceil(len/ps) pages, not a Tmax row) while the dense engine is capped
-    at its slot count — the capacity acceptance is concurrency_ratio
-    >= 2. A third leg serves three waves sharing a one-page system
-    prompt to price prefix sharing (hit tokens + pool high-water vs the
-    no-sharing pool). Host-side scheduling + cache-layout bench.
-    """
-    from paddle_tpu.serving import GenerationEngine, LMSpec, Request
-
-    spec = LMSpec(vocab_size=vocab, d_model=d, n_layers=L, num_heads=H,
-                  max_len=tmax)
-
-    def lm_scope(seed=7):
-        scope = pt.Scope()
-        exe = pt.Executor(pt.TPUPlace())
-        prog, startup = pt.Program(), pt.Program()
-        with pt.program_guard(prog, startup):
-            p = layers.data("p_init", shape=[8], dtype="int64")
-            models.transformer_lm_generate(
-                p, vocab_size=vocab, d_model=d, n_layers=L, num_heads=H,
-                max_len=tmax, max_new_tokens=1)
-        startup.random_seed = seed
-        exe.run(startup, scope=scope)
-        return scope
-
-    dense_kv_bytes = 2 * L * (dense_slots + 1) * H * tmax * (d // H) * 4
-    n_pages = (dense_slots + 1) * tmax // page_size  # same bytes as dense
-    rng = np.random.RandomState(0)
-    prompts = [rng.randint(0, vocab, (prompt_len,)).astype("int64")
-               for _ in range(n_requests)]
-
-    def serve(eng, reqs_prompts):
-        """Drive the engine by hand, tracking the concurrency high-water
-        (generate_all hides it)."""
-        reqs = [Request({"prompt": p}, {"max_new_tokens": max_new}, None)
-                for p in reqs_prompts]
-        pending = list(reqs)
-        prefill_tick = getattr(eng, "prefill_tick", lambda: False)
-        admit_deferred = getattr(eng, "_admit_deferred", lambda: 0)
-        deferred = getattr(eng, "_deferred", ())
-        hwm, ticks = 0, 0
-        t0 = time.perf_counter()
-        while pending or eng.active or deferred:
-            if pending and eng.free_slots and not deferred:
-                k = min(len(pending), eng.free_slots)
-                eng.admit(pending[:k])
-                pending = pending[k:]
-            admit_deferred()
-            prefill_tick()
-            hwm = max(hwm, eng.active)
-            if eng.decode_tick():
-                ticks += 1
-        wall = time.perf_counter() - t0
-        toks = sum(len(np.asarray(r.future.result(timeout=1)))
-                   for r in reqs) - sum(len(p) for p in reqs_prompts)
-        return {"wall_s": round(wall, 3),
-                "tokens_per_sec": round(toks / wall, 1),
-                "concurrent_hwm": hwm, "decode_ticks": ticks}
-
-    # leg A: dense slot table at the budget
-    dense = GenerationEngine(spec, lm_scope(), kv_cache="dense",
-                             slots=dense_slots, max_seq_len=tmax,
-                             prompt_buckets=(page_size,))
-    dense_leg = serve(dense, prompts)
-    dense_leg["kv_bytes"] = dense_kv_bytes
-
-    # leg B: paged pool, SAME bytes, every request in flight at once
-    paged = GenerationEngine(spec, lm_scope(), slots=n_requests,
-                             max_seq_len=tmax, page_size=page_size,
-                             n_pages=n_pages, prefix_sharing=False,
-                             prompt_buckets=(page_size,))
-    paged_leg = serve(paged, prompts)
-    paged_leg["kv_bytes"] = int(
-        paged.metrics.snapshot()["gauges"]["mem/kv_cache_bytes"])
-    paged_leg["pages"] = n_pages
-    pages_per_seq = -(-(prompt_len + max_new) // page_size)
-    paged_leg["capacity_sequences"] = (n_pages - 1) // pages_per_seq
-
-    # leg C: prefix sharing across three waves of a shared system prompt
-    sysp = rng.randint(0, vocab, (shared_prefix,)).astype("int64")
-    shared_prompts = [np.concatenate(
-        [sysp, rng.randint(0, vocab, (prompt_len - shared_prefix,))
-         .astype("int64")]) if prompt_len > shared_prefix else sysp.copy()
-        for _ in range(n_requests)]
-    shared_eng = GenerationEngine(spec, lm_scope(), slots=n_requests // 3,
-                                  max_seq_len=tmax, page_size=page_size,
-                                  n_pages=n_pages,
-                                  prompt_buckets=(page_size,))
-    shared_leg = serve(shared_eng, shared_prompts)
-    snap = shared_eng.metrics.snapshot()
-    shared_leg["prefix_hit_tokens"] = snap["counters"].get(
-        "prefix_hit_tokens", 0)
-    shared_leg["prefix_hits"] = snap["counters"].get("prefix_hits", 0)
-    shared_leg["pages_retained"] = shared_eng.pool.pages_in_use()
-
-    return {
-        "config": {"tmax": tmax, "page_size": page_size,
-                   "prompt_len": prompt_len, "max_new": max_new,
-                   "n_requests": n_requests,
-                   "model": f"d{d} L{L} h{H} V{vocab}"},
-        "dense": dense_leg,
-        "paged": paged_leg,
-        "paged_shared_prefix": shared_leg,
-        "concurrency_ratio": round(
-            paged_leg["concurrent_hwm"]
-            / max(1, dense_leg["concurrent_hwm"]), 2),
-        "throughput_ratio": round(
-            paged_leg["tokens_per_sec"]
-            / max(1e-9, dense_leg["tokens_per_sec"]), 2),
-    }
-
-
 def bench_decode_platform(jax, pt, layers, models, tmax=512, page_size=16,
                           slots=8, prompt_len=24, max_new=16,
                           n_requests=16, d=32, L=2, H=4, vocab=128,
@@ -1717,8 +1596,7 @@ def bench_multi_tenant(jax, pt, layers, models, vocab=32, d=16, L=2, H=2,
 
     def engine(seed):
         eng = GenerationEngine(spec, scope_for(seed), slots=slots,
-                               page_size=page_size, kv_cache="paged",
-                               prompt_buckets=(8,),
+                               page_size=page_size, prompt_buckets=(8,),
                                prefill_batch_buckets=(1, 2, 4))
         eng.warmup()
         return eng
@@ -1861,7 +1739,7 @@ def bench_disagg(jax, pt, layers, models, vocab=64, d=32, L=2, H=4,
               for _ in range(n_short)]
 
     # -- correctness gate: handoff byte-identical, zero prefill recompute
-    uni_ref = GenerationEngine(spec, scope(), kv_cache="paged", **kw)
+    uni_ref = GenerationEngine(spec, scope(), **kw)
     want = uni_ref.generate_all([p.tolist() for p in shorts[:4]],
                                 max_new_tokens=short_new)
     dis_ref = DisaggEngine.build(spec, prefill_replicas=1,
@@ -1899,8 +1777,8 @@ def bench_disagg(jax, pt, layers, models, vocab=64, d=32, L=2, H=4,
             engines = eng.engines
             served = [eng]
         else:
-            engines = [GenerationEngine(spec, scope(), kv_cache="paged",
-                                        **kw) for _ in range(2)]
+            engines = [GenerationEngine(spec, scope(), **kw)
+                       for _ in range(2)]
             served = engines
         for e in engines:
             e.warmup()
@@ -2022,7 +1900,7 @@ def bench_recovery(jax, pt, layers, models, vocab=32, d=16, L=2, H=2,
 
     def build_leg():
         engines = [GenerationEngine(spec, scope(), slots=slots,
-                                    page_size=page_size, kv_cache="paged")
+                                    page_size=page_size)
                    for _ in range(2)]
         for e in engines:
             e.warmup()
@@ -2356,7 +2234,6 @@ def assemble(rows):
         "checkpoint": res("checkpoint"),
         "memplan": res("memplan"),
         "fleet": res("fleet"),
-        "paged_kv": res("paged_kv"),
         "failed": {s: r["error"] for s, r in rows.items()
                    if "error" in r} or None,
         "image_zoo_train_bs128": zoo or None,
@@ -2470,7 +2347,6 @@ def run_bench(jax, dev):
         # control loops): each is a count-asserting A/B at toy width that
         # ROADMAP D2 moves into tests/ or replaces with a cell
         row("fleet", bench_fleet, jax, pt, layers),
-        row("paged_kv", bench_paged_kv, jax, pt, layers, models),
         row("obs_overhead", bench_obs_overhead, jax, pt, layers, models),
         row("goodput_overhead", bench_goodput, jax, pt, layers, batch=batch,
             dim=1024, steps=30),

@@ -492,9 +492,10 @@ def _stack_cost(attrs, ins, outs):
                   residual_bytes=residual)
 
 
-def _slot_cache_cost(attrs, ins, outs):
-    """transformer_stack_slot_prefill/decode: stacked-weight pass over the
-    slot KV cache; decode is pure HBM streaming of the cache planes."""
+def _stacked_pass_cost(attrs, ins, outs):
+    """One stacked-weight pass of a decode op over its tokens: FLOPs from
+    every [L, in, out] weight plane, bytes from the full I/O stream (the
+    cache planes included)."""
     x = (_first(ins, "Prompt") or _first(ins, "Tok")
          or _first(ins, "X") or _first(ins, "Ids"))
     toks = float(np.prod(x.shape)) if x is not None else 1.0
@@ -528,7 +529,7 @@ def _encdec_cost(attrs, ins, outs):
 
 
 def _paged_cache_cost(attrs, ins, outs):
-    """transformer_stack_paged_prefill/decode: the slot-cache cost plus
+    """transformer_stack_paged_prefill/decode: the stacked pass plus
     the K/V context the attention reads per layer (x2 for K and V).
 
     Prefill (``Chunk``): every row gathers its table-width [P*ps, Hkv*dh]
@@ -539,7 +540,7 @@ def _paged_cache_cost(attrs, ins, outs):
     shape is known, rows x P pages: the table width, an UPPER bound, and
     what the gathered fallback (no TPU, grouped-query heads, a row not
     lane-aligned) really moves."""
-    base = _slot_cache_cost(attrs, ins, outs)
+    base = _stacked_pass_cost(attrs, ins, outs)
     table = _first(ins, "BlockTable")
     pool = _first(ins, "CacheK")
     context = 0.0
@@ -651,7 +652,6 @@ _MEMORY_BOUND = (
 _EXEMPT = (
     "feed", "fetch", "while", "cond", "static_rnn", "beam_search_decoder",
     "transformer_stack_generate", "transformer_stack_beam_search",
-    "transformer_stack_speculative_generate",
 )
 
 
@@ -686,8 +686,6 @@ def _register_all() -> None:
     reg(("seg_fwd",), _seg_fwd_cost)
     reg(("grad_seg",), _grad_seg_cost)
     reg(("pipelined_transformer_stack",), _stack_cost)
-    reg(("transformer_stack_slot_prefill", "transformer_stack_slot_decode"),
-        _slot_cache_cost)
     reg(("transformer_stack_paged_prefill", "transformer_stack_paged_decode"),
         _paged_cache_cost)
     reg(("transformer_encdec_encode", "transformer_encdec_teacher",

@@ -38,8 +38,8 @@ from ..layers import data as data_layer
 from ..layers.layer_helper import LayerHelper
 from ..serving.batcher import Request
 from ..serving.errors import BadRequestError
-from ..serving.generation import (LMSpec, PAGED_CACHE_K, PAGED_CACHE_V,
-                                  PagedGenerationEngine)
+from ..serving.generation import (GenerationEngine, LMSpec, PAGED_CACHE_K,
+                                  PAGED_CACHE_V)
 
 CROSS_K = "serving.cross_k"
 CROSS_V = "serving.cross_v"
@@ -87,7 +87,7 @@ def _default_src_buckets(tsmax: int) -> List[int]:
     return sorted(set(buckets))
 
 
-class Seq2SeqGenerationEngine(PagedGenerationEngine):
+class Seq2SeqGenerationEngine(GenerationEngine):
     """Continuous batching for encoder-decoder generation; see the
     module docstring. Payloads are ``{"src": [ids]}`` with an optional
     ``"prompt"`` target prefix (default ``[bos_id]``); everything else —
@@ -236,12 +236,12 @@ class Seq2SeqGenerationEngine(PagedGenerationEngine):
     def _build_decode(self):
         prog, startup = Program(), Program()
         with program_guard(prog, startup):
-            tok = data_layer("serving.tok", shape=[self._nslots],
+            tok = data_layer("serving.tok", shape=[self.slots],
                              dtype="int64", append_batch_size=False)
-            pos = data_layer("serving.pos", shape=[self._nslots],
+            pos = data_layer("serving.pos", shape=[self.slots],
                              dtype="int32", append_batch_size=False)
             table = data_layer("serving.block_table",
-                               shape=[self._nslots, self.pmax],
+                               shape=[self.slots, self.pmax],
                                dtype="int32", append_batch_size=False)
             helper = LayerHelper("serving_cross_decode",
                                  main_program=prog,
@@ -250,15 +250,15 @@ class Seq2SeqGenerationEngine(PagedGenerationEngine):
             xk, xv = self._cross_cache_vars(helper)
             nxt = helper.block.create_var(
                 name="serving.next_tok",
-                shape=[self._nslots], dtype="int64", stop_gradient=True)
+                shape=[self.slots], dtype="int64", stop_gradient=True)
             ins = {"Tok": [tok], "Pos": [pos], "BlockTable": [table],
                    "CacheK": [ck], "CacheV": [cv],
                    "CrossK": [xk], "CrossV": [xv]}
-            ins.update(self._sampling_vars(self._nslots))
+            ins.update(self._sampling_vars(self.slots))
             ins.update(self._lm_ins(helper))
             ins.update(self._cross_weight_ins(helper))
             outs = {"NextTok": [nxt], "CacheK": [ck], "CacheV": [cv]}
-            outs.update(self._beam_out_vars(helper, self._nslots,
+            outs.update(self._beam_out_vars(helper, self.slots,
                                             "serving.dec"))
             helper.append_op("transformer_stack_cross_decode", ins,
                              outs, self._decode_attrs())

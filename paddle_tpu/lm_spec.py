@@ -31,8 +31,8 @@ ROPE_PAIRINGS = ("interleaved", "half")
 
 class BlockNotSupportedError(NotImplementedError):
     """An op or engine that still hard-codes the GPT-2 block was handed
-    another spec (dense slot engine, beam search, speculative decode,
-    the seq2seq family, a ``pp`` pipeline over MoE layers)."""
+    another spec (beam search, the seq2seq family, a ``pp`` pipeline over
+    MoE layers)."""
 
 
 @dataclasses.dataclass(frozen=True)

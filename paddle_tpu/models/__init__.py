@@ -19,13 +19,12 @@ from .mobilenet import mobilenet
 from .smallnet import smallnet_mnist_cifar
 from .seq2seq import shared_nmt_params, transformer_nmt_teacher
 from .transformer import (transformer_lm, transformer_lm_beam_search,
-                          transformer_lm_generate,
-                          transformer_lm_speculative_generate)
+                          transformer_lm_generate)
 from .wide_deep import wide_deep, wide_deep_loss
 
 __all__ = [
     "transformer_lm", "transformer_lm_beam_search", "transformer_lm_generate",
-    "transformer_lm_speculative_generate", "wide_deep", "wide_deep_loss",
+    "wide_deep", "wide_deep_loss",
     "shared_nmt_params", "transformer_nmt_teacher",
     "lenet5", "alexnet", "vgg", "resnet_imagenet", "resnet_cifar10",
     "googlenet", "mobilenet", "smallnet_mnist_cifar",

@@ -270,57 +270,3 @@ def transformer_lm_beam_search(prompt, vocab_size, d_model=256, n_layers=4,
     ids.stop_gradient = True
     scores.stop_gradient = True
     return ids, scores
-
-
-def transformer_lm_speculative_generate(prompt, vocab_size, d_model=256,
-                                        n_layers=4, num_heads=8, d_ff=None,
-                                        num_kv_heads=None, use_rope=False,
-                                        max_len=2048, max_new_tokens=32,
-                                        draft_layers=None, gamma=4,
-                                        main_program=None,
-                                        startup_program=None):
-    """Self-speculative greedy decoding for a
-    ``transformer_lm(pipeline_stack=True)`` model: the first
-    ``draft_layers`` of the SAME stack plus a small draft head
-    (draft_ln.*, draft_head.w — train it separately, e.g. on the frozen
-    stack) propose ``gamma`` tokens per round, and the full stack verifies
-    them in one block-causal pass. Output is EXACTLY the plain greedy
-    decode (acceptance keeps only tokens the full stack argmaxes); the
-    draft only buys fewer full-stack passes. Returns (ids [b, Tp+N],
-    rounds [1] — plain decode would take N).
-
-    EXPERIMENTAL (status, PERF.md "speculative decoding"): correctness is
-    pinned (tests/test_generate.py) and a trained draft head cuts verify
-    rounds well below N on the CPU mesh, but the only wall-clock A/B on
-    record (r3 chip, UNtrained model — zero acceptance) was a 2.4x
-    slowdown. Until a trained-model A/B on the chip records a speedup
-    > 1 (ROADMAP D4), prefer plain ``transformer_lm_generate`` in
-    production."""
-    from ..initializer import ConstantInitializer
-
-    kw = dict(main_program=main_program, startup_program=startup_program)
-    draft_layers = draft_layers or max(1, n_layers // 2)
-    helper = LayerHelper("transformer_lm_speculative_generate", **kw)
-    ins = {"Prompt": [prompt]}
-    ins.update(_shared_lm_params(helper, _legacy_spec(
-        None, vocab_size, d_model, n_layers, num_heads, d_ff, num_kv_heads,
-        use_rope, max_len)))
-    ins["DraftLnS"] = [helper.create_parameter(
-        ParamAttr(name="draft_ln.scale"), shape=[d_model],
-        dtype="float32", default_initializer=ConstantInitializer(1.0))]
-    ins["DraftLnB"] = [helper.create_parameter(
-        ParamAttr(name="draft_ln.bias"), shape=[d_model], dtype="float32",
-        is_bias=True)]
-    ins["DraftHeadW"] = [helper.create_parameter(
-        ParamAttr(name="draft_head.w"), shape=[d_model, vocab_size],
-        dtype="float32")]
-    outs, _ = helper.append_op(
-        "transformer_stack_speculative_generate", ins, ["Out", "Rounds"],
-        {"num_heads": num_heads, "num_kv_heads": num_kv_heads,
-         "use_rope": use_rope, "max_new_tokens": max_new_tokens,
-         "draft_layers": int(draft_layers), "gamma": int(gamma)})
-    ids = outs["Out"][0]
-    rounds = outs["Rounds"][0]
-    ids.stop_gradient = True
-    rounds.stop_gradient = True
-    return ids, rounds

@@ -522,296 +522,19 @@ def transformer_stack_beam_search(attrs, ins):
                Scores=scores)
 
 
-def _window_verify_fn(blk, params, d):
-    """Forward a w-token window through ALL layers against the cache
-    (block-causal: window token i attends cache rows <= pos0 + i), writing
-    the window's K/V at rows pos0..pos0+w-1. Returns fn(xw, ck, cv, pos0)
-    -> (hidden [b, w, d], ck, cv) — the verify pass of speculative
-    decoding, and exactly a prefill when the cache is empty."""
-    from ..kernels.flash_attention import reference_attention
-
-    def run(xw, ck, cv, pos0):
-        def layer(hw, inp):
-            layer_p, ck_l, cv_l = inp
-            q, k, v = _attn_proj(blk, layer_p, hw, pos0=pos0)
-            ck_l = jax.lax.dynamic_update_slice_in_dim(ck_l, k, pos0, 2)
-            cv_l = jax.lax.dynamic_update_slice_in_dim(cv_l, v, pos0, 2)
-            ctx = reference_attention(q, ck_l, cv_l, causal=True,
-                                      q_pos0=pos0)
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(
-                hw.shape[0], hw.shape[1], d)
-            return _attn_out_ffn(blk, layer_p, hw, ctx)[0], (ck_l, cv_l)
-
-        return jax.lax.scan(layer, xw, (params, ck, cv))
-
-    return run
-
-
-@register_op("transformer_stack_speculative_generate",
-             optional_inputs=("PosEmb",))
-def transformer_stack_speculative_generate(attrs, ins):
-    """Self-speculative greedy decoding: an early-exit draft proposes,
-    the full stack verifies.
-
-    Same inputs as transformer_stack_generate plus a draft head
-    (DraftLnS/DraftLnB [d], DraftHeadW [d, V]); attrs: num_heads,
-    max_new_tokens, draft_layers (k < L), gamma (proposals per round).
-
-    Each round the DRAFT — the first k layers of the SAME stack plus its
-    own head — decodes gamma tokens through the shared cache's first k
-    layer planes; the full L-layer stack then scores the whole window in
-    ONE block-causal pass, the longest agreeing prefix is accepted (plus
-    the target's correction/bonus token), and the loop advances. Because
-    acceptance only keeps tokens the full stack itself argmaxes, the
-    output is EXACTLY the plain greedy decode — the draft controls speed,
-    never content (verified by test). Batch rows advance in lockstep at
-    the batch-min acceptance, keeping every cache update uniform.
-
-    Out [b, Tp + N] int; Rounds [1] int32 (verify rounds taken — the
-    speedup diagnostic: plain decode would take N).
-    """
-    blk = Block.from_attrs(attrs)
-    blk.require_gpt2("transformer_stack_speculative_generate")
-    (prompt, tok_emb, pos_emb, ln_s, ln_b, head_w,
-     params) = _unpack_lm_ins(blk, ins)
-    d_ln_s = single(ins, "DraftLnS")
-    d_ln_b = single(ins, "DraftLnB")
-    d_head_w = single(ins, "DraftHeadW")
-    N = attrs["max_new_tokens"]
-    k_layers = attrs["draft_layers"]
-    gamma = attrs.get("gamma", 4)
-    b, Tp = prompt.shape
-    L, d = params["ln1_s"].shape
-    if not 0 < k_layers < L:
-        raise ValueError(f"draft_layers {k_layers} outside [1, {L - 1}]")
-    if N < 1 or gamma < 1:
-        raise ValueError("max_new_tokens and gamma must be >= 1")
-    # cache slack: a round may write gamma + 1 rows past the last emit
-    Ttot = Tp + N + gamma + 1
-    if pos_emb is not None and Ttot > pos_emb.shape[0]:
-        raise ValueError(
-            f"prompt {Tp} + {N} new tokens (+{gamma + 1} speculative "
-            f"slack) exceeds max_len {pos_emb.shape[0]}")
-    embed = _embed_fn(tok_emb, pos_emb)
-    logits_of = _logits_fn(ln_s, ln_b, head_w, blk)
-    draft_logits_of = _logits_fn(d_ln_s, d_ln_b, d_head_w)
-    draft_params = {key: p[:k_layers] for key, p in params.items()}
-    draft_layer = _decode_layer_fn(blk, draft_params, d)
-    verify = _window_verify_fn(blk, params, d)
-
-    # ---- prefill: the full stack over the prompt -----------------------
-    h, (ks, vs) = _prefill(blk, params, embed(prompt, 0), b, Tp)
-    pad = [(0, 0)] * 5
-    pad[3] = (0, Ttot - Tp)
-    cache_k = jnp.pad(ks, pad)
-    cache_v = jnp.pad(vs, pad)
-    cur = jnp.argmax(logits_of(h[:, -1]), axis=-1)  # token at pos Tp
-
-    tokens = jnp.zeros((b, N + gamma + 1), prompt.dtype)
-    tokens = tokens.at[:, 0].set(cur.astype(prompt.dtype))
-
-    def round_body(carry):
-        tokens, n, cur, pos, rounds, ck, cv = carry
-        # pos = cache rows filled (cur sits at position pos, unprocessed)
-
-        # 1. draft chain: k-layer incremental decode of gamma proposals.
-        # Only the first k_layers cache planes thread through the scan —
-        # carrying the full L-layer cache would rewrite it per proposal.
-        def draft_step(dcarry, i):
-            dtok, dck, dcv = dcarry
-            x1 = embed(dtok[:, None], pos + i)
-            h1, (dck, dcv) = jax.lax.scan(
-                lambda h1, inp: draft_layer(h1, inp, pos + i),
-                x1, (draft_params, dck, dcv))
-            nxt = jnp.argmax(draft_logits_of(h1[:, 0]), axis=-1)
-            return (nxt.astype(dtok.dtype), dck, dcv), nxt
-
-        (_, dck, dcv), dtoks = jax.lax.scan(
-            draft_step, (cur, ck[:k_layers], cv[:k_layers]),
-            jnp.arange(gamma))
-        ck = jax.lax.dynamic_update_slice_in_dim(ck, dck, 0, 0)
-        cv = jax.lax.dynamic_update_slice_in_dim(cv, dcv, 0, 0)
-        dtoks = jnp.moveaxis(dtoks, 0, 1)  # [b, gamma]
-
-        # 2. verify: full stack over [cur, d_0..d_{gamma-1}] in one pass
-        window = jnp.concatenate(
-            [cur[:, None], dtoks.astype(cur.dtype)], axis=1)
-        xw = embed(window, pos)
-        hw, (ck, cv) = verify(xw, ck, cv, pos)
-        t = jnp.argmax(logits_of(
-            hw.reshape(b * (gamma + 1), d)), axis=-1).reshape(
-            b, gamma + 1)  # target tokens for positions pos+1..pos+g+1
-
-        # 3. lockstep acceptance: batch-min longest agreeing prefix
-        agree = (t[:, :gamma] == dtoks)  # [b, gamma]
-        acc_rows = jnp.sum(jnp.cumprod(agree.astype(jnp.int32), axis=1),
-                           axis=1)  # per-row accepted count
-        a = jnp.min(acc_rows)  # lockstep
-        # emit t_0..t_a (a+1 tokens: accepted + correction/bonus)
-        for i in range(gamma + 1):
-            tokens = jnp.where(
-                i <= a,
-                jax.lax.dynamic_update_index_in_dim(
-                    tokens, t[:, i].astype(tokens.dtype), n + 1 + i, 1),
-                tokens)
-        cur = jax.lax.dynamic_index_in_dim(t, a, 1, keepdims=False)
-        return (tokens, n + 1 + a, cur.astype(tokens.dtype),
-                pos + 1 + a, rounds + 1, ck, cv)
-
-    def cond(carry):
-        # tokens[0] is pre-emitted by the prefill; indices 0..n are
-        # filled, so N emissions means n >= N - 1
-        return carry[1] < N - 1
-
-    init_n = jnp.asarray(0, jnp.int32)
-    tokens, n, cur, pos, rounds, cache_k, cache_v = jax.lax.while_loop(
-        cond, round_body,
-        (tokens, init_n, cur.astype(tokens.dtype),
-         jnp.asarray(Tp, jnp.int32), jnp.asarray(0, jnp.int32),
-         cache_k, cache_v))
-    out_ids = jnp.concatenate(
-        [prompt, tokens[:, :N].astype(prompt.dtype)], axis=1)
-    return out(Out=out_ids, Rounds=rounds.reshape(1))
-
-
 # ---------------------------------------------------------------------------
-# Slot-cache decode ops: the continuous-batching serving path
-# (paddle_tpu/serving/generation.py). The KV cache is a SLOT TABLE
-# [L, S, Hkv, Tmax, dh] living in the scope as persistable state: requests
-# claim a slot, prefill scatters their prompt K/V into it, and every decode
-# step advances ALL slots one token (each at its own position) — finished
-# sequences vacate their slot and new requests join mid-flight. Both ops
-# read AND write the cache variables, so the executor threads them as
-# donated read-write state (in-place buffer update, no cache copy per step).
-# ---------------------------------------------------------------------------
-
-@register_op("transformer_stack_slot_prefill", optional_inputs=("PosEmb",),
-             needs_rng=lambda attrs: (attrs.get("temperature") or 0) > 0)
-def transformer_stack_slot_prefill(attrs, ins, rng=None):
-    """Prefill a batch of prompts into their cache slots.
-
-    Prompt [b, Tp] int (right-padded to the bucket width), SlotIds [b]
-    int32 (target slot per row; duplicate ids are only legal for a scrap
-    slot), Lengths [b] int32 (true prompt lengths, 1..Tp), CacheK/CacheV
-    [L, S, Hkv, Tmax, dh], plus the shared LM weights
-    (transformer_stack_generate's contract). Returns NextTok [b] — the
-    first generated token per row, from the hidden state at each row's
-    true last prompt position — and the caches with rows 0..Tp-1 of each
-    target slot overwritten. Pad rows beyond a row's length write pad K/V
-    into rows length..Tp-1, which decode never attends (its per-slot
-    length mask stops at the current position) and progressively
-    overwrites.
-    """
-    blk = Block.from_attrs(attrs)
-    blk.require_gpt2("transformer_stack_slot_prefill")
-    prompt = single(ins, "Prompt")
-    slot_ids = single(ins, "SlotIds").astype(jnp.int32)
-    lengths = single(ins, "Lengths").astype(jnp.int32)
-    cache_k = single(ins, "CacheK")
-    cache_v = single(ins, "CacheV")
-    tok_emb = single(ins, "TokEmb")
-    pos_emb = maybe(ins, "PosEmb")
-    ln_s, ln_b = single(ins, "FinalLnS"), single(ins, "FinalLnB")
-    head_w = single(ins, "HeadW")
-    params = _stack_params(blk, ins)
-    b, Tp = prompt.shape
-    Tmax = cache_k.shape[3]
-    if Tp > Tmax:
-        raise ValueError(f"prompt bucket {Tp} exceeds cache length {Tmax}")
-    if pos_emb is not None and Tp > pos_emb.shape[0]:
-        raise ValueError(f"prompt bucket {Tp} exceeds max_len "
-                         f"{pos_emb.shape[0]}")
-    embed = _embed_fn(tok_emb, pos_emb)
-    pick = _make_pick(attrs.get("temperature") or 0.0,
-                      attrs.get("top_k") or 0, head_w.shape[1], rng)
-    h, (ks, vs) = _prefill(blk, params, embed(prompt, 0), b, Tp)
-    last = h[jnp.arange(b), jnp.clip(lengths, 1, Tp) - 1]  # [b, d]
-    next_tok = pick(_logits_fn(ln_s, ln_b, head_w, blk)(last), 0)
-    # ks/vs [L, b, Hkv, Tp, dh] -> scatter each row into its slot's rows
-    # 0..Tp-1 (one advanced index: the batch axis maps onto slot ids)
-    cache_k = cache_k.at[:, slot_ids, :, :Tp, :].set(ks)
-    cache_v = cache_v.at[:, slot_ids, :, :Tp, :].set(vs)
-    return out(NextTok=next_tok.astype(prompt.dtype),
-               CacheK=cache_k, CacheV=cache_v)
-
-
-@register_op("transformer_stack_slot_decode", optional_inputs=("PosEmb",),
-             needs_rng=lambda attrs: (attrs.get("temperature") or 0) > 0)
-def transformer_stack_slot_decode(attrs, ins, rng=None):
-    """One decode step over EVERY cache slot, each at its own position.
-
-    Tok [S] int (the pending token per slot — its K/V is not yet in the
-    cache), Pos [S] int32 (that token's sequence position == cache rows
-    already filled for the slot), CacheK/CacheV [L, S, Hkv, Tmax, dh],
-    plus the shared LM weights. Returns NextTok [S] and the caches with
-    row Pos[s] of every slot s overwritten by Tok's K/V.
-
-    The slot axis IS the batch axis, so the compiled shape never depends
-    on which slots are occupied — the one-compile steady state of
-    continuous batching (vacant slots compute a garbage token the host
-    ignores; their row-Pos write lands in a region the next prefill
-    overwrites). Attention masks each slot to rows <= Pos[s] via the
-    per-row lengths plane, so stale rows beyond a slot's position are
-    never visible.
-    """
-    blk = Block.from_attrs(attrs)
-    blk.require_gpt2("transformer_stack_slot_decode")
-    tok = single(ins, "Tok")
-    pos = single(ins, "Pos").astype(jnp.int32)
-    cache_k = single(ins, "CacheK")
-    cache_v = single(ins, "CacheV")
-    tok_emb = single(ins, "TokEmb")
-    pos_emb = maybe(ins, "PosEmb")
-    ln_s, ln_b = single(ins, "FinalLnS"), single(ins, "FinalLnB")
-    head_w = single(ins, "HeadW")
-    params = _stack_params(blk, ins)
-    S = tok.shape[0]
-    if S != cache_k.shape[1]:
-        raise ValueError(f"Tok has {S} slots but the cache holds "
-                         f"{cache_k.shape[1]}")
-    L, d = params["ln1_s"].shape
-    Tmax = cache_k.shape[3]
-    pos = jnp.clip(pos, 0, Tmax - 1)
-    x = tok_emb[tok]
-    if pos_emb is not None:
-        x = x + pos_emb[jnp.clip(pos, 0, pos_emb.shape[0] - 1)]
-    h1 = x[:, None, :]  # [S, 1, d]
-    pick = _make_pick(attrs.get("temperature") or 0.0,
-                      attrs.get("top_k") or 0, head_w.shape[1], rng)
-    srange = jnp.arange(S)
-
-    def layer(h1, inp):
-        layer_p, ck_l, cv_l = inp  # caches [S, Hkv, Tmax, dh]
-        q, k, v = _attn_proj(blk, layer_p, h1, pos0=pos)
-        Hkv = k.shape[1]
-        ix = (srange[:, None], jnp.arange(Hkv)[None, :], pos[:, None])
-        ck_l = ck_l.at[ix].set(k[:, :, 0, :])
-        cv_l = cv_l.at[ix].set(v[:, :, 0, :])
-        from ..kernels.flash_attention import reference_attention
-
-        ctx = reference_attention(q, ck_l, cv_l, lengths=pos + 1)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(S, 1, d)
-        return _attn_out_ffn(blk, layer_p, h1, ctx)[0], (ck_l, cv_l)
-
-    h1, (cache_k, cache_v) = jax.lax.scan(layer, h1,
-                                          (params, cache_k, cache_v))
-    nxt = pick(_logits_fn(ln_s, ln_b, head_w, blk)(h1[:, 0]), 0)
-    return out(NextTok=nxt.astype(tok.dtype),
-               CacheK=cache_k, CacheV=cache_v)
-
-
-# ---------------------------------------------------------------------------
-# Paged-cache decode ops: the block-table serving path (vLLM's
-# PagedAttention layout on the slot-op machinery). The KV cache is a PAGE
-# POOL [L, N, page_size, Hkv*dh] living in the scope; a per-row int32
-# block table maps logical positions to physical pages, so a sequence
-# holds exactly ceil(len / page_size) pages instead of a dense Tmax row —
+# Paged-cache decode ops: the continuous-batching serving path
+# (paddle_tpu/serving/generation.py; vLLM's PagedAttention layout). The KV
+# cache is a PAGE POOL [L, N, page_size, Hkv*dh] living in the scope as
+# persistable state; a per-row int32 block table maps logical positions to
+# physical pages, so a sequence holds exactly ceil(len / page_size) pages —
 # and a page shared by several sequences (a common system prompt) is
 # stored ONCE, each sharer's table pointing at the same physical page.
 # Page 0 is the scrap page: padding rows and vacant decode slots write
 # there and nothing ever attends to it. Both ops read AND write the pool,
-# so the executor threads it as donated read-write state exactly like the
-# dense slot table.
+# so the executor threads it as donated read-write state (in-place buffer
+# update, no cache copy per step); ``_scan_paged_layers`` says what a step
+# moves.
 #
 # Why a token's K/V is ONE row of Hkv*dh floats: the TPU runtime derives
 # an array's device layout from its shape alone, and puts the LARGEST
@@ -822,20 +545,6 @@ def transformer_stack_slot_decode(attrs, ins, rng=None):
 # layer's pool round the scatter and the gather and copied the whole pool
 # once (~95 of a 162 ms tick, all proportional to N). With Hkv*dh last a
 # page is contiguous and lane-dense on the device as it is in this shape.
-#
-# What a step moves now (_scan_paged_layers): per layer and pool, one
-# in-place scatter of the new tokens' rows (t * Hkv*dh floats a batch
-# row), then the context. A DECODE tick on a chip (t == 1) hands the WHOLE
-# pools, the layer index, the block table and the lengths to one Pallas
-# kernel (kernels/paged_attention.py) that DMAs the pages each row HOLDS
-# — ceil(length / ps) tiles of [ps, Hkv*dh] per row and pool, one for a
-# vacant slot — and splits the heads in VMEM: a tick moves the weights
-# plus the K/V of the tokens in flight, not slots x table width. Every
-# other call (a prefill chunk or group: t > 1; no TPU; grouped-query
-# heads; a row that is not lane-aligned) gathers each row's table-width
-# context [P*ps, Hkv*dh] and hands it to reference_attention, which stays
-# the semantic ground truth. Neither path slices, transposes, restacks or
-# copies the pool.
 # ---------------------------------------------------------------------------
 
 _SAMPLING_SLOTS = ("Temperature", "TopK", "TopP", "Seed", "Step", "Mask")
@@ -1095,8 +804,8 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
 
     The slot axis is the batch axis and the table width is static, so the
     compiled shape never depends on occupancy or sequence lengths — the
-    same one-compile steady state as the dense slot decode, over a pool
-    sized by TOKENS IN FLIGHT instead of slots*Tmax.
+    one-compile steady state of continuous batching, over a pool sized by
+    TOKENS IN FLIGHT.
 
     Optional per-row sampling plane (Temperature/TopK/TopP/Seed/Step [S]
     + Mask [S, V]): per-REQUEST decode policy inside the one compiled

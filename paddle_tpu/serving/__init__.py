@@ -51,8 +51,8 @@ from .errors import (BadRequestError, CacheExhaustedError,
                      QueueFullError, ReplicaUnavailableError,
                      RequestTimeoutError, ServingError)
 from .fleet import Fleet, HttpReplica, LocalReplica, Replica
-from .generation import (GenerationEngine, LMSpec, PagedGenerationEngine,
-                         RequestTimeline, spec_from_program_dict)
+from .generation import (GenerationEngine, LMSpec, RequestTimeline,
+                         spec_from_program_dict)
 from .metrics import MetricsRegistry
 from .paging import PagePool, PrefixIndex
 from .recovery import LineageRecord, LineageStore
@@ -63,7 +63,7 @@ from .tenancy import ModelRegistry, MultiTenantServer, Tenant
 
 __all__ = [
     "DynamicBatcher", "Future", "Request",
-    "InferenceEngine", "GenerationEngine", "PagedGenerationEngine",
+    "InferenceEngine", "GenerationEngine",
     "LMSpec", "RequestTimeline", "spec_from_program_dict",
     "MetricsRegistry", "Server",
     "PagePool", "PrefixIndex",

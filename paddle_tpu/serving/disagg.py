@@ -58,7 +58,7 @@ def _b64(arr: np.ndarray) -> str:
 
 
 def serialize_handoff(engine, handoff: dict, release: bool = True) -> dict:
-    """Turn an :meth:`~.generation.PagedGenerationEngine.export_slot`
+    """Turn an :meth:`~.generation.GenerationEngine.export_slot`
     handoff into a JSON-safe migration payload: the slot's page byte
     ranges (gathered from the paged K/V tensors by block-table order),
     the decode cursor, and the request's decode policy. ``release=True``
@@ -146,7 +146,7 @@ def install_handoff(engine, blob: dict, request) -> bool:
             pids = engine.pool.alloc_many(n)
         except RuntimeError:
             return False
-    from .generation import (PAGED_CACHE_K, PAGED_CACHE_V, _PagedSlot)
+    from .generation import PAGED_CACHE_K, PAGED_CACHE_V, _Slot
     from ..decoding import SamplingParams
 
     shape = tuple(blob["shape"])
@@ -162,8 +162,8 @@ def install_handoff(engine, blob: dict, request) -> bool:
         temperature=s["temperature"], top_k=s["top_k"],
         top_p=s["top_p"], seed=s["seed"], max_tokens=s["max_tokens"],
         stop=tuple(tuple(x) for x in s["stop"]))
-    st = _PagedSlot(request, prompt, int(blob["max_new"]),
-                    blob["eos_id"], sampling)
+    st = _Slot(request, prompt, int(blob["max_new"]), blob["eos_id"],
+               sampling)
     st.pages = pids
     st.prefill_done = prompt.size
     st.state = "decode"
@@ -424,14 +424,12 @@ class DisaggEngine:
         deployment where migration is a pure refcount transfer."""
         from .generation import GenerationEngine
 
-        engine_kw.pop("kv_cache", None)
-        first = GenerationEngine(spec, scope=scope, kv_cache="paged",
-                                 **engine_kw)
+        first = GenerationEngine(spec, scope=scope, **engine_kw)
         engines = [first]
         for _ in range(prefill_replicas + decode_replicas - 1):
             engines.append(GenerationEngine(
-                spec, scope=first.scope, kv_cache="paged",
-                share_cache_with=first, **engine_kw))
+                spec, scope=first.scope, share_cache_with=first,
+                **engine_kw))
         return cls(PrefillPool(engines[:prefill_replicas]),
                    DecodePool(engines[prefill_replicas:]))
 

@@ -9,23 +9,18 @@ advances every occupied slot each tick — finished sequences vacate
 between ticks and queued requests join mid-flight. The decode step's
 shape depends only on the slot count, so the steady state is a single
 compile-cache entry; prefill compiles once per (batch-bucket,
-prompt-bucket) pair, all warmed up front.
+chunk-width) pair, all warmed up front.
 
-Two cache layouts share that loop, selected by ``kv_cache=``:
-
-- ``"paged"`` (default, :class:`PagedGenerationEngine`) — a page pool
-  ``[L, n_pages, page_size, Hkv*dh]`` plus per-slot block tables
-  (vLLM's PagedAttention layout): a sequence holds ``ceil(len/page_size)``
-  pages instead of a dense ``Tmax`` row, a shared page-aligned prompt
-  prefix is stored ONCE (radix-style prefix index, copy-on-write on
-  divergence), and long prompts stream in page-budgeted chunks
-  interleaved with decode ticks (Sarathi-style chunked prefill) so a
-  ``Tmax`` admission never stalls the decode plane.
-- ``"dense"`` — the original slot table ``[L, slots+1, Hkv, Tmax, dh]``;
-  every slot pays ``Tmax`` rows regardless of true length. The extra
-  slot (index ``slots``) is a scrap slot: padding rows of a partially
-  filled prefill bucket scatter their K/V there, keeping every compiled
-  shape independent of how many requests actually arrived.
+The cache is a page pool ``[L, n_pages, page_size, Hkv*dh]`` plus
+per-slot block tables (vLLM's PagedAttention layout): a sequence holds
+``ceil(len/page_size)`` pages, a shared page-aligned prompt prefix is
+stored ONCE (radix-style prefix index, copy-on-write on divergence), and
+long prompts stream in page-budgeted chunks interleaved with decode
+ticks (Sarathi-style chunked prefill) so a ``Tmax`` admission never
+stalls the decode plane. Page 0 is the scrap page: padding rows of a
+partially filled prefill bucket and vacant decode slots write there,
+keeping every compiled shape independent of how many requests actually
+arrived.
 """
 from __future__ import annotations
 
@@ -50,18 +45,14 @@ from ..lm_spec import Block, LMSpec
 from .batcher import Request
 from .errors import BadRequestError
 from .metrics import MetricsRegistry
+from .paging import PagePool, PrefixIndex
 
-CACHE_K = "serving.cache_k"
-CACHE_V = "serving.cache_v"
 
 PAGED_CACHE_K = "serving.paged_cache_k"
 PAGED_CACHE_V = "serving.paged_cache_v"
 
 # decode-family op types whose attrs + shared weights describe a stacked LM
 _DECODE_OPS = ("transformer_stack_generate", "transformer_stack_beam_search",
-               "transformer_stack_speculative_generate",
-               "transformer_stack_slot_prefill",
-               "transformer_stack_slot_decode",
                "transformer_stack_paged_prefill",
                "transformer_stack_paged_decode")
 
@@ -184,10 +175,14 @@ class RequestTimeline:
 
 class _Slot:
     __slots__ = ("request", "generated", "max_new", "eos_id", "prompt",
-                 "timeline", "truncate_to")
+                 "timeline", "truncate_to", "pages", "shared_tokens",
+                 "cow_reserve", "prefill_done", "state", "sampling",
+                 "stop_matcher", "mask_proc", "beam_job", "role", "xrow",
+                 "resumed")
 
     def __init__(self, request: Request, prompt: np.ndarray,
-                 max_new: int, eos_id: Optional[int]):
+                 max_new: int, eos_id: Optional[int],
+                 sampling: Optional[SamplingParams] = None):
         self.request = request
         self.prompt = prompt
         self.generated: List[int] = []
@@ -197,24 +192,53 @@ class _Slot:
         # set by a stop-sequence match: keep only this many generated
         # tokens in the returned ids (the stop itself is dropped)
         self.truncate_to: Optional[int] = None
+        self.pages: List[int] = []       # physical page per table entry
+        self.shared_tokens = 0           # prefix-cache hit length
+        self.cow_reserve = 0             # pages held for copy-on-write
+        self.prefill_done = 0            # prompt tokens whose K/V is cached
+        self.state = "decode"            # "prefill" while chunks stream in
+                                         # ("hold"/"beam_wait" for beams)
+        self.sampling = sampling or SamplingParams()
+        self.stop_matcher = StopMatcher(self.sampling.stop)
+        self.mask_proc = self.sampling.logits_processor
+        self.beam_job = None             # set for beam-owned slots
+        self.role = "normal"             # beam_parent | beam | hold
+        self.xrow = None                 # seq2seq: cross-KV cache row
+        self.resumed = 0                 # recovery: emitted tokens that
+                                         # re-entered as prefill context
 
 
 class GenerationEngine:
-    """Continuous batcher over the stacked-LM decode ops.
+    """Continuous batcher over a PAGED KV cache with prefix sharing and
+    chunked prefill.
 
-    ``kv_cache="paged"`` (the default) constructs a
-    :class:`PagedGenerationEngine`; ``kv_cache="dense"`` keeps the
-    original contiguous slot table. Both serve the same API.
+    The cache is a page pool ``[L, n_pages, page_size, Hkv*dh]`` (scope-
+    resident, donated in place) plus a host-side per-slot block table: a
+    sequence holds ``ceil(len/page_size)`` physical pages, so HBM holds
+    TOKENS IN FLIGHT, not slots x Tmax. Three levers ride on the
+    allocator:
+
+    - **Prefix sharing** (``prefix_sharing=True``): a radix-style index
+      over page-aligned prompt prefixes maps a shared system prompt to
+      refcounted pages stored once; admission of a request whose prefix
+      is cached skips that prefill entirely (``prefix_hit_tokens``
+      counts the skipped tokens). A shared page about to be written
+      (full-prompt hit diverging into generation) is copied first —
+      copy-on-write via ``kv_cache_page_copy``, one page reserved at
+      admission so decode never allocates.
+    - **Chunked prefill**: a prompt longer than ``prefill_chunk`` tokens
+      streams in page-budgeted chunks, one chunk per engine tick,
+      INTERLEAVED with decode ticks — a Tmax admission no longer stalls
+      every in-flight stream (Sarathi-style stall-free batching).
+    - **Typed backpressure**: a request whose prompt + max_new_tokens can
+      NEVER fit the pool fails with
+      :class:`~paddle_tpu.serving.errors.CacheExhaustedError`; transient
+      pressure defers admission (the batcher queue backs up and sheds)
+      instead of failing mid-decode.
     """
 
     # scope tensors swap_params must never clobber (live decode state)
-    _cache_names = (CACHE_K, CACHE_V)
-
-    def __new__(cls, *args, **kw):
-        if cls is GenerationEngine and \
-                (kw.get("kv_cache") or "paged") == "paged":
-            cls = PagedGenerationEngine
-        return object.__new__(cls)
+    _cache_names = (PAGED_CACHE_K, PAGED_CACHE_V)
 
     def __init__(self, spec: LMSpec, scope: Optional[Scope] = None, *,
                  slots: int = 8, max_seq_len: Optional[int] = None,
@@ -227,15 +251,18 @@ class GenerationEngine:
                  place=None, metrics: Optional[MetricsRegistry] = None,
                  mem_budget: Optional[float] = None,
                  namespace: str = "",
-                 kv_cache: Optional[str] = None):
-        if kv_cache not in (None, "dense", "paged"):
-            raise ValueError(f"kv_cache must be 'paged' or 'dense', "
-                             f"got {kv_cache!r}")
+                 page_size: Optional[int] = None,
+                 n_pages: Optional[int] = None,
+                 prefill_chunk: Optional[int] = None,
+                 prefix_sharing: bool = True,
+                 beam_width: int = 0, mask_plane: bool = True,
+                 share_cache_with: Optional["GenerationEngine"] = None):
         if slots < 1:
             raise ValueError("need at least one decode slot")
-        if not isinstance(self, PagedGenerationEngine):
-            spec.block.require_gpt2("the dense slot engine "
-                                    "(kv_cache='dense')")
+        if page_size is not None and page_size < 1:
+            raise ValueError("page_size must be >= 1")
+        if beam_width < 0:
+            raise ValueError("beam_width must be >= 0")
         self.spec = spec
         self.scope = scope or Scope()
         self.slots = int(slots)
@@ -256,6 +283,14 @@ class GenerationEngine:
         self.default_max_new_tokens = int(default_max_new_tokens)
         self.eos_id = eos_id
         self.pad_id = int(pad_id)
+        # beam_width > 0 compiles the TopV/TopI (emit_topk) plane into
+        # the decode/prefill programs; beam requests up to this width
+        # then ride the one steady-state compile
+        self.beam_width = int(beam_width)
+        # mask_plane=False drops the [slots, vocab] Mask feed from the
+        # programs (per-tick host->device bytes scale with vocab; turn
+        # it off for large-V deployments that never constrain decoding)
+        self.mask_plane = bool(mask_plane)
         # compile-cache/manifest namespace: a registry hosting several
         # resident models against ONE artifact directory keeps each
         # tenant's warmup manifest under its own filename
@@ -280,6 +315,65 @@ class GenerationEngine:
                 b *= 2
             nb.append(self.slots)
         self.prefill_batch_buckets = sorted(set(int(b) for b in nb))
+
+        # -- page geometry ---------------------------------------------
+        # disaggregation: a decode-pool engine built on the PREFILL
+        # engine's scope adopts its page pool/prefix index — a KV
+        # handoff between the two is then a pure slot-table transfer
+        src = share_cache_with
+        if src is not None:
+            if self.scope is not src.scope:
+                raise ValueError(
+                    "share_cache_with requires constructing this engine "
+                    "on the source engine's scope — the page tensors "
+                    "live there")
+            if spec != src.spec or self.tmax != src.tmax:
+                raise ValueError(
+                    "share_cache_with requires an identical LMSpec and "
+                    "max_seq_len — the page geometry and weight contract "
+                    "must match for a block table to transfer")
+            self.page_size = src.page_size
+        else:
+            self.page_size = int(page_size or min(64, self.tmax))
+        # table width: enough entries for a full-context sequence
+        self.pmax = -(-self.tmax // self.page_size)
+        # beam engines default to a bigger pool: K fully-diverged
+        # hypotheses can each hold a full table plus a COW spare
+        beam_extra = (self.slots + 2 * self.beam_width
+                      if self.beam_width else 0)
+        self.n_pages = (src.n_pages if src is not None
+                        else int(n_pages or self.slots * self.pmax + 1
+                                 + beam_extra))
+        if self.n_pages < 2:
+            raise ValueError("need at least 2 pages (one is scrap)")
+        if prefill_chunk is None:
+            prefill_chunk = min(self.prompt_buckets[-1],
+                                max(2 * self.page_size, 128))
+        self.prefill_chunk = max(1, min(int(prefill_chunk), self.tmax))
+        self._chunk_widths = sorted(
+            {b for b in self.prompt_buckets if b <= self.prefill_chunk}
+            | {self.prefill_chunk})
+
+        # -- pool and slot table ----------------------------------------
+        self._prefix_sharing = bool(prefix_sharing)
+        self._owns_pool = src is None
+        if src is not None:
+            self.pool = src.pool
+            self.prefix_index = src.prefix_index
+        else:
+            self.pool = PagePool(self.n_pages, self.page_size)
+            self.prefix_index = (PrefixIndex(self.pool)
+                                 if self._prefix_sharing else None)
+        # no scrap SLOT — padding/vacant rows write the scrap PAGE, so
+        # the decode batch is exactly the slot count
+        self._slots: List[Optional[_Slot]] = [None] * self.slots
+        self._tok = np.zeros(self.slots, np.int64)
+        self._pos = np.zeros(self.slots, np.int32)
+        self._deferred = deque()  # pool-blocked validated admissions
+        self._pf_cursor = 0       # round-robin over prefilling slots
+        self._beam_jobs: List[BeamJob] = []
+        self._seed_counter = 0    # default per-request seeds (sampled
+                                  # requests without an explicit seed)
         # last-N completed request timelines — the flight recorder's
         # per-engine "what was in flight when it fell over" ring
         self._recent: "deque" = deque(maxlen=64)
@@ -288,13 +382,11 @@ class GenerationEngine:
         # revive(); _emitted_total arms the replica_kill fault threshold
         self._killed = False
         self._emitted_total = 0
-        # slot table: index `slots` is the scrap slot (prefill padding)
-        self._nslots = self.slots + 1
-        self._slots: List[Optional[_Slot]] = [None] * self.slots
-        self._tok = np.zeros(self._nslots, np.int64)
-        self._pos = np.zeros(self._nslots, np.int32)
         self._init_cache()
+
+        # -- programs ----------------------------------------------------
         self._prefill_progs: Dict[int, tuple] = {}
+        self._page_copy_prog_cache = None
         self._decode_prog = self._build_decode()
         if mem_budget is not None:
             self._check_mem_budget(mem_budget)
@@ -351,798 +443,21 @@ class GenerationEngine:
                     val = val.astype(want)
                 self.scope.set(name, jax.device_put(val, dev))
 
-    def _init_cache(self):
-        import jax.numpy as jnp
-
-        s = self.spec
-        shape = (s.n_layers, self._nslots, s.kv_heads, self.tmax,
-                 s.head_dim)
-        with self.executor.device_ctx():
-            self.scope.set(CACHE_K, jnp.zeros(shape, jnp.float32))
-            self.scope.set(CACHE_V, jnp.zeros(shape, jnp.float32))
-
-    def _cache_vars(self, helper):
-        s = self.spec
-        shape = [s.n_layers, self._nslots, s.kv_heads, self.tmax,
-                 s.head_dim]
-        ck = helper.create_global_variable(name=CACHE_K, shape=shape,
-                                           dtype="float32")
-        cv = helper.create_global_variable(name=CACHE_V, shape=shape,
-                                           dtype="float32")
-        return ck, cv
-
-    def _lm_ins(self, helper):
-        from ..models.transformer import _shared_lm_params
-
-        return _shared_lm_params(helper, self.spec)
-
-    def _decode_attrs(self):
-        return {**self.spec.block.attrs(),
-                "temperature": self.temperature, "top_k": self.top_k}
-
-    def _build_prefill(self, tp: int):
-        prog, startup = Program(), Program()
-        with program_guard(prog, startup):
-            prompt = data_layer("serving.prompt", shape=[tp],
-                                dtype="int64")
-            slot_ids = data_layer("serving.slot_ids", shape=[],
-                                  dtype="int32")
-            lengths = data_layer("serving.lengths", shape=[],
-                                 dtype="int32")
-            helper = LayerHelper("serving_prefill", main_program=prog,
-                                 startup_program=startup)
-            ck, cv = self._cache_vars(helper)
-            # fixed name (not unique_name): the serving programs must be
-            # bit-identical across boots so warmup-manifest digests match
-            nxt = helper.block.create_var(
-                name="serving.next_tok", shape=[-1],
-                dtype="int64", stop_gradient=True)
-            ins = {"Prompt": [prompt], "SlotIds": [slot_ids],
-                   "Lengths": [lengths], "CacheK": [ck], "CacheV": [cv]}
-            ins.update(self._lm_ins(helper))
-            helper.append_op(
-                "transformer_stack_slot_prefill", ins,
-                {"NextTok": [nxt], "CacheK": [ck], "CacheV": [cv]},
-                self._decode_attrs())
-        self._transpile(prog, ["serving.prompt", "serving.slot_ids",
-                               "serving.lengths"], [nxt.name],
-                        f"transpile/prefill{tp}/")
-        return prog, nxt
-
-    def _build_decode(self):
-        prog, startup = Program(), Program()
-        with program_guard(prog, startup):
-            tok = data_layer("serving.tok", shape=[self._nslots],
-                             dtype="int64", append_batch_size=False)
-            pos = data_layer("serving.pos", shape=[self._nslots],
-                             dtype="int32", append_batch_size=False)
-            helper = LayerHelper("serving_decode", main_program=prog,
-                                 startup_program=startup)
-            ck, cv = self._cache_vars(helper)
-            nxt = helper.block.create_var(
-                name="serving.next_tok",
-                shape=[self._nslots], dtype="int64", stop_gradient=True)
-            ins = {"Tok": [tok], "Pos": [pos], "CacheK": [ck],
-                   "CacheV": [cv]}
-            ins.update(self._lm_ins(helper))
-            helper.append_op(
-                "transformer_stack_slot_decode", ins,
-                {"NextTok": [nxt], "CacheK": [ck], "CacheV": [cv]},
-                self._decode_attrs())
-        self._transpile(prog, ["serving.tok", "serving.pos"], [nxt.name],
-                        "transpile/decode/")
-        return prog, nxt
-
-    def _transpile(self, prog, feed_names, fetch_names, metric_prefix):
-        """Run the inference pipeline over a freshly-built serving program
-        before it is ever compiled (the decode/prefill ops are already
-        maximally fused, so this is usually a fast no-op — but custom or
-        saved-program variants get the full rewrite set) and publish the
-        per-pass stats into the MetricsRegistry.
-        ``preserve_state_writes`` keeps the KV-cache update ops alive even
-        though nothing fetches them."""
-        from ..transpiler import inference_pipeline
-
-        pm = inference_pipeline()
-        pm.run(prog, feed_names, fetch_names, scope=self.scope,
-               preserve_state_writes=True)
-        for k, v in pm.metrics_dict(prefix=metric_prefix).items():
-            self.metrics.set_gauge(k, v)
-
-    def _prefill_prog(self, tp: int):
-        if tp not in self._prefill_progs:
-            self._prefill_progs[tp] = self._build_prefill(tp)
-        return self._prefill_progs[tp]
-
-    def _check_mem_budget(self, budget: float) -> None:
-        """Build-time budget gate over the decode step AND the largest
-        prefill bucket. The KV-cache slot table ([L, slots+1, Hkv, Tmax,
-        dh] x2, scope-resident since _init_cache) is counted as resident
-        state, so an over-provisioned slot/Tmax configuration raises a
-        located MemoryBudgetError before warmup compiles anything."""
-        from .. import analysis
-
-        prog, nxt = self._decode_prog
-        mem = analysis.check_memory_budget(
-            prog, ["serving.tok", "serving.pos"], [nxt.name], budget,
-            scope=self.scope, batch_size=self._nslots,
-            what=f"GenerationEngine decode step (slots={self.slots}, "
-                 f"tmax={self.tmax})")
-        tp = self.prompt_buckets[-1]
-        pprog, pnxt = self._prefill_prog(tp)
-        pmem = analysis.check_memory_budget(
-            pprog, ["serving.prompt", "serving.slot_ids",
-                    "serving.lengths"], [pnxt.name], budget,
-            scope=self.scope,
-            batch_size=self.prefill_batch_buckets[-1],
-            what=f"GenerationEngine prefill (bucket {tp})")
-        self.metrics.set_gauge("mem/static_peak_bytes",
-                               max(mem.peak_bytes, pmem.peak_bytes))
-        self.metrics.set_gauge("mem/kv_cache_bytes", 2.0 * float(
-            np.prod([self.spec.n_layers, self._nslots,
-                     self.spec.kv_heads, self.tmax,
-                     self.spec.head_dim])) * 4)
-
-    # -- bucket helpers -------------------------------------------------
-    def prompt_bucket_for(self, n: int) -> int:
-        for b in self.prompt_buckets:
-            if n <= b:
-                return b
-        raise BadRequestError(
-            f"prompt length {n} exceeds the largest prompt bucket "
-            f"{self.prompt_buckets[-1]}")
-
-    def _batch_bucket_for(self, n: int) -> int:
-        for b in self.prefill_batch_buckets:
-            if n <= b:
-                return b
-        return self.prefill_batch_buckets[-1]
-
-    # -- slot accounting ------------------------------------------------
-    @property
-    def active(self) -> int:
-        return sum(1 for s in self._slots if s is not None)
-
-    @property
-    def free_slots(self) -> int:
-        return self.slots - self.active
-
-    def _needs_scope_rng(self) -> bool:
-        """Does the decode family draw from the SCOPE RNG plane? Only
-        the dense engine's legacy attrs-based sampling does; the paged
-        engine's per-request plane carries seeds as inputs."""
-        return self.temperature > 0
-
-    # -- serving ---------------------------------------------------------
-    def warmup(self) -> int:
-        """Compile every prefill (batch-bucket x prompt-bucket) pair and
-        the decode step before traffic arrives. All warmup rows target
-        the scrap slot, so live slots are never polluted. Returns the
-        number of shapes compiled."""
-        combos = 0
-        if self._needs_scope_rng():
-            # sampled serving threads the scope RNG plane: seed it BEFORE
-            # warmup so the scope key set (part of the compile-cache key)
-            # is identical between warmup and live traffic
-            self.executor._rng_state(self._decode_prog[0], self.scope)
-        for tp in self.prompt_buckets:
-            prog, nxt = self._prefill_prog(tp)
-            for b in self.prefill_batch_buckets:
-                feed = {
-                    "serving.prompt": np.full((b, tp), self.pad_id,
-                                              np.int64),
-                    "serving.slot_ids": np.full(b, self.slots, np.int32),
-                    "serving.lengths": np.ones(b, np.int32),
-                }
-                self.executor.run(prog, feed=feed, fetch_list=[nxt],
-                                  scope=self.scope)
-                combos += 1
-        self._run_decode()
-        combos += 1
-        self.metrics.inc("warmup_compiles", combos)
-        self.save_manifest()
-        return combos
-
-    # -- cold-start plane -------------------------------------------------
-    def _warm_programs(self):
-        """Every program this engine compiles: the decode step plus one
-        prefill program per prompt bucket (built on demand — program
-        construction is cheap; compilation is what the manifest saves)."""
-        progs = [self._decode_prog[0]]
-        progs.extend(self._prefill_prog(tp)[0] for tp in self.prompt_buckets)
-        return progs
-
-    @property
-    def manifest_name(self) -> str:
-        """Warmup-manifest filename, namespaced per tenant: several
-        resident models sharing one artifact directory each persist
-        their own signature set instead of clobbering a global file."""
-        from ..core.manifest import MANIFEST_NAME
-
-        if not self.namespace:
-            return MANIFEST_NAME
-        stem, dot, ext = MANIFEST_NAME.rpartition(".")
-        if not dot:
-            return f"{MANIFEST_NAME}.{self.namespace}"
-        return f"{stem}.{self.namespace}.{ext}"
-
-    def save_manifest(self, dirname: Optional[str] = None) -> Optional[str]:
-        """Persist the compiled (prefill x batch bucket, decode)
-        signature set next to the saved model for AOT replay on the next
-        boot. No-op without a model directory."""
-        dirname = dirname or self.model_dir
-        if dirname is None or len(self.executor.manifest) == 0:
-            return None
-        try:
-            return self.executor.manifest.save(dirname,
-                                               name=self.manifest_name)
-        except OSError:  # read-only artifact volume: serving still works
-            return None
-
-    def warm_from_manifest(self,
-                           dirname: Optional[str] = None) -> Optional[int]:
-        """AOT-replay the saved warmup manifest against the engine-built
-        decode/prefill programs (concurrent ``.lower().compile()``, no
-        execution, live slots untouched). Returns signatures warm, or
-        None when no manifest exists."""
-        from ..core import manifest as manifest_mod
-
-        dirname = dirname or self.model_dir
-        if dirname is None:
-            return None
-        manifest = manifest_mod.try_load(dirname, name=self.manifest_name)
-        if manifest is None:
-            return None
-        if self._needs_scope_rng():
-            # same contract as warmup(): seed the RNG plane first so the
-            # scope key set matches live traffic
-            self.executor._rng_state(self._decode_prog[0], self.scope)
-        stats = manifest_mod.replay(
-            self.executor, self._warm_programs(), scope=self.scope,
-            manifest=manifest)
-        self.metrics.inc("warmup_replayed", stats["compiled"])
-        if stats["skipped"]:
-            self.metrics.inc("warmup_manifest_skipped", stats["skipped"])
-        return stats["compiled"] + stats["already"]
-
-    def warm_start(self) -> int:
-        """Boot path: manifest replay when available, else execute-based
-        :meth:`warmup`; re-persists the manifest either way."""
-        import warnings as warnings_mod
-
-        from ..core.manifest import ManifestError
-
-        warmed = None
-        try:
-            warmed = self.warm_from_manifest()
-        except ManifestError as exc:
-            warnings_mod.warn(f"ignoring warmup manifest: {exc}",
-                              RuntimeWarning, stacklevel=2)
-        if warmed is None:
-            warmed = self.warmup()
-        self.save_manifest()
-        return warmed
-
-    def _validate(self, req: Request):
-        try:
-            raw = (req.payload["prompt"] if isinstance(req.payload, dict)
-                   else req.payload)
-            prompt = np.asarray(raw, dtype=np.int64).reshape(-1)
-        except (KeyError, TypeError, ValueError) as exc:
-            raise BadRequestError(f"bad prompt payload: {exc}")
-        if prompt.size < 1:
-            raise BadRequestError("empty prompt")
-        max_new = int(req.meta.get("max_new_tokens")
-                      or self.default_max_new_tokens)
-        if max_new < 1:
-            raise BadRequestError("max_new_tokens must be >= 1")
-        if prompt.size + max_new > self.tmax:
-            raise BadRequestError(
-                f"prompt ({prompt.size}) + max_new_tokens ({max_new}) "
-                f"exceeds the serving context ({self.tmax})")
-        self._check_prompt_fits(prompt)
-        eos = req.meta.get("eos_id")
-        return prompt, max_new, self.eos_id if eos is None else eos
-
-    def _check_prompt_fits(self, prompt: np.ndarray) -> None:
-        """Layout-specific admission bound: the dense table serves a
-        prompt only if a single prefill bucket covers it; the paged
-        engine overrides this (chunked prefill takes any length the
-        context allows)."""
-        self.prompt_bucket_for(prompt.size)  # raises when over-long
-
-    def admit(self, requests: List[Request]) -> int:
-        """Prefill a group of requests into free slots (one bucketed
-        batch). Returns the number admitted; invalid requests fail their
-        future and consume no slot."""
-        todo = []
-        for req in requests:
-            try:
-                todo.append((req, *self._validate(req)))
-            except BadRequestError as exc:
-                self.metrics.inc("bad_requests")
-                req.end_trace(status="bad_request")
-                req.future.set_exception(exc)
-        if not todo:
-            return 0
-        free = [i for i in range(self.slots) if self._slots[i] is None]
-        if len(todo) > len(free):
-            raise RuntimeError(f"admit() got {len(todo)} requests for "
-                               f"{len(free)} free slots")
-        tp = self.prompt_bucket_for(max(p.size for _, p, _, _ in todo))
-        bucket = self._batch_bucket_for(len(todo))
-        prompt = np.full((bucket, tp), self.pad_id, np.int64)
-        slot_ids = np.full(bucket, self.slots, np.int32)  # scrap default
-        lengths = np.ones(bucket, np.int32)
-        for row, (req, p, max_new, eos) in enumerate(todo):
-            slot = free[row]
-            prompt[row, :p.size] = p
-            slot_ids[row] = slot
-            lengths[row] = p.size
-        prog, nxt = self._prefill_prog(tp)
-        t0 = time.perf_counter()
-        with trace.span("serving/prefill_group", rows=len(todo),
-                        bucket=bucket, tokens=tp):
-            first, = self.executor.run(
-                prog, feed={"serving.prompt": prompt,
-                            "serving.slot_ids": slot_ids,
-                            "serving.lengths": lengths},
-                fetch_list=[nxt], scope=self.scope)
-        t1 = time.perf_counter()
-        self.metrics.observe_latency(t1 - t0, name="prefill")
-        self.metrics.inc("prefills")
-        self.metrics.set_gauge("prefill_occupancy", len(todo) / bucket)
-        first = np.asarray(first)
-        for row, (req, p, max_new, eos) in enumerate(todo):
-            slot = free[row]
-            if req.span is not None:  # keep per-request sampling
-                trace.record("serving/execute", t0, t1, parent=req.span,
-                             phase="prefill", slot=slot,
-                             prompt_len=int(p.size), prompt_bucket=tp,
-                             batch_bucket=bucket)
-                req.span.set_attrs(slot=slot, prompt_len=int(p.size))
-            st = _Slot(req, p, max_new, eos)
-            st.timeline.chunk(t0, t1, int(p.size))
-            self.metrics.observe_hist("queue_wait",
-                                      st.timeline.queue_wait_s)
-            self._slots[slot] = st
-            self._tok[slot] = first[row]
-            self._pos[slot] = p.size
-            self._emit(slot, int(first[row]))
-        self._gauges()
-        return len(todo)
-
-    def _emit(self, slot: int, token: int) -> None:
-        st = self._slots[slot]
-        delta = st.timeline.mark_token(time.monotonic())
-        if delta is None:  # first token: the TTFT sample
-            self.metrics.observe_hist("ttft", st.timeline.ttft_s)
-        else:              # every later token: one TPOT sample
-            self.metrics.observe_hist("tpot", delta)
-        st.generated.append(token)
-        self._emitted_total += 1
-        cb = (st.request.meta or {}).get("on_token")
-        if cb is not None:
-            # progress streaming for the lineage plane: position, token.
-            # Never let an observer kill the decode loop.
-            try:
-                cb(len(st.generated) - 1, token)
-            except Exception:
-                self.metrics.inc("progress_callback_errors")
-        stop = getattr(st, "stop_matcher", None)
-        if stop:
-            keep = stop.match(st.generated)
-            if keep is not None:
-                # the stop sequence ends here (anywhere — including
-                # mid-page on the paged cache): truncate before the
-                # match and finish; the already-written K/V rows past
-                # the cut are released with the request's pages
-                st.truncate_to = keep
-                self.metrics.inc("stop_sequence_hits")
-                self._finish(slot)
-                return
-        if (len(st.generated) >= st.max_new
-                or (st.eos_id is not None and token == st.eos_id)):
-            self._finish(slot)
-
-    def _finish(self, slot: int) -> None:
-        st = self._slots[slot]
-        self._slots[slot] = None
-        gen = (st.generated if st.truncate_to is None
-               else st.generated[:st.truncate_to])
-        # a RESUMED slot's prompt is original-prompt + already-emitted
-        # context while ``generated`` also starts with those emitted
-        # tokens — strip the overlap so the result ids match an
-        # uninterrupted run exactly
-        resumed = getattr(st, "resumed", 0)
-        prompt = st.prompt[:-resumed] if resumed else st.prompt
-        ids = np.concatenate([prompt, np.asarray(gen, np.int64)])
-        latency = time.monotonic() - st.request.enqueue_t
-        tl = st.timeline
-        if st.request.span is not None and tl.n_tokens > 1:
-            # decode residency as ONE span per request (token-level cost
-            # rides the timeline, not 1 span/token)
-            trace.record("serving/decode", tl.first_token_t,
-                         tl.last_token_t, parent=st.request.span,
-                         tokens=tl.n_tokens,
-                         tpot_ms=round((tl.tpot_s or 0.0) * 1e3, 3))
-        self._recent.append(dict(tl.to_dict(), status="ok",
-                                 latency_s=round(latency, 6),
-                                 resumed=bool(resumed)))
-        st.request.future.set_result(ids)
-        st.request.end_trace(status="ok",
-                             tokens_generated=len(st.generated),
-                             latency_s=round(latency, 6))
-        self.metrics.inc("completed")
-        self.metrics.observe_latency(latency)
-
-    def _run_decode(self):
-        prog, nxt = self._decode_prog
-        res, = self.executor.run(
-            prog, feed={"serving.tok": self._tok.copy(),
-                        "serving.pos": self._pos.copy()},
-            fetch_list=[nxt], scope=self.scope)
-        return np.asarray(res)
-
-    def decode_tick(self) -> bool:
-        """Advance every occupied slot one token (one compiled step).
-        Returns True when any slot was active."""
-        if self.active == 0:
-            return False
-        t0 = time.perf_counter()
-        with trace.span("serving/decode_step", active=self.active):
-            nxt = self._run_decode()
-        self.metrics.observe_latency(time.perf_counter() - t0,
-                                     name="decode_step")
-        self.metrics.inc("decode_steps")
-        # per-TOKEN decode work (decode_steps is per tick) — the pin a
-        # recovery run is judged by: resumed context re-enters via
-        # prefill, so total decode_tokens stays below an uninterrupted
-        # run's, never above
-        self.metrics.inc("decode_tokens", self.active)
-        self.metrics.set_gauge("batch_occupancy", self.active / self.slots)
-        for slot in range(self.slots):
-            if self._slots[slot] is None:
-                continue
-            self._pos[slot] += 1
-            self._tok[slot] = nxt[slot]
-            self._emit(slot, int(nxt[slot]))
-        self._maybe_replica_kill()
-        self._gauges()
-        return True
-
-    def _gauges(self):
-        self.metrics.set_gauge("active_slots", self.active)
-        # throttled time-series sampling: the flight bundle's metric
-        # ring sees occupancy/pages/prefix counters EVOLVE, not just
-        # their value at dump time
-        self._flight.maybe_sample(self.metrics)
-
-    def flight_state(self) -> dict:
-        """Live engine state for the flight recorder: per-slot decode
-        progress plus the last-N completed request timelines."""
-        slots = []
-        for i, st in enumerate(self._slots):
-            if st is None:
-                continue
-            slots.append({
-                "slot": i,
-                "state": getattr(st, "state", "decode"),
-                "prompt_len": int(st.prompt.size),
-                "generated": len(st.generated),
-                "max_new": st.max_new,
-                "pos": int(self._pos[i]),
-            })
-        return {
-            "engine": type(self).__name__,
-            "slots_total": self.slots,
-            "killed": self._killed,
-            "slots": slots,
-            "recent_requests": list(self._recent),
-        }
-
-    def cache_stats(self) -> dict:
-        return self.executor.cache_stats()
-
-    # -- mid-stream chaos: hard engine death ------------------------------
-    def _abort_slot_resources(self, st) -> None:
-        """Layout hook: release whatever a killed slot held (the paged
-        engine returns its pages to the pool)."""
-
-    def kill(self, reason: str = "chaos") -> int:
-        """Hard-kill the engine mid-stream (the ``replica_kill`` chaos
-        path): every in-flight generation fails with ``ConnectionError``
-        — RETRYABLE, so a fleet's lineage plane resumes the survivors on
-        a healthy replica — resources are released, and the engine
-        refuses traffic (serve_step drains the queue the same way) until
-        :meth:`revive`. Returns the number of futures failed."""
-        exc = ConnectionError(
-            f"replica killed mid-stream ({reason}); in-flight "
-            "generations are resumable from their lineage")
-        failed = 0
-        for slot in range(self.slots):
-            st = self._slots[slot]
-            if st is None:
-                continue
-            self._slots[slot] = None
-            self._abort_slot_resources(st)
-            st.request.end_trace(status="killed")
-            if not st.request.future.done():
-                st.request.future.set_exception(exc)
-                failed += 1
-        self._killed = True
-        self.metrics.inc("replica_kills")
-        self.metrics.inc("killed_in_flight", failed)
-        self._gauges()
-        return failed
-
-    def revive(self) -> None:
-        """Bring a killed engine back (slots are empty; the KV pages a
-        kill released are reusable immediately). The emit counter
-        restarts: ``after_tokens`` thresholds are per-incarnation."""
-        self._killed = False
-        self._emitted_total = 0
-
-    def _maybe_replica_kill(self) -> None:
-        """Fire an armed ``replica_kill`` fault once the engine has
-        emitted ``after_tokens`` tokens (default 1) across all streams —
-        the deterministic stand-in for a process dying mid-decode."""
-        from ..resilience import faults
-
-        plan = faults.active_plan()
-        if plan is None or self._killed:
-            return
-        params = plan.peek("replica_kill")
-        if params is None:
-            return
-        if self._emitted_total < int(params.get("after_tokens", 1)):
-            return
-        # fire() is the atomic claim: two engines can both pass the
-        # peek, but only the one that consumes the entry dies
-        if plan.fire("replica_kill") is None:
-            return
-        self.kill(reason="fault-plan replica_kill")
-
-    def _drain_killed(self, batcher) -> bool:
-        """A killed engine's serve loop: fail everything the batcher
-        hands it, retryable, so the fleet routes around the corpse."""
-        reqs = batcher.next_batch(max_n=max(self.slots, 1), wait_s=0)
-        if not reqs:
-            return False
-        exc = ConnectionError("replica is down (killed mid-stream)")
-        for req in reqs:
-            req.end_trace(status="killed")
-            if not req.future.done():
-                req.future.set_exception(exc)
-        return True
-
-    def swap_params(self, source, *, strict: bool = True):
-        """Zero-recompile param hot-swap for rolling weight updates:
-        replace the LM weights in place from a trainer checkpoint dir /
-        saved-model dir / Scope / dict. The slot KV cache and the RNG
-        stream are never touched (a checkpoint taken from another
-        serving scope must not clobber live decode state) — call at a
-        drained point so already-admitted requests finish on consistent
-        weights."""
-        from .engine import swap_scope_params
-
-        return swap_scope_params(self.scope, source,
-                                 skip=self._cache_names, strict=strict,
-                                 device_ctx=self.executor.device_ctx,
-                                 metrics=self.metrics)
-
-    # -- server-driver interface -----------------------------------------
-    def serve_step(self, batcher, idle_wait_s: Optional[float] = None) -> bool:
-        """One engine tick: admit queued requests into free slots (a
-        non-blocking grab while decoding, a coalescing wait when idle),
-        then advance the decode loop one step."""
-        if self._killed:
-            return self._drain_killed(batcher)
-        reqs = None
-        if not self.active:
-            # idle: the coalescing wait is no part of a pass
-            reqs = batcher.next_batch(max_n=self.free_slots,
-                                      wait_s=idle_wait_s)
-            if not reqs:
-                return False
-        with trace.span("serving/pass", active=self.active):
-            free = self.free_slots
-            if reqs is None and free:
-                reqs = batcher.next_batch(max_n=free, wait_s=0)
-            did = False
-            if reqs:
-                with trace.span("serving/admit", requests=len(reqs)):
-                    did = self.admit(reqs) > 0
-            return self.decode_tick() or did
-
-    # -- synchronous convenience ------------------------------------------
-    def generate_all(self, prompts: Sequence[Sequence[int]],
-                     max_new_tokens: Optional[int] = None,
-                     eos_id: Optional[int] = None) -> List[np.ndarray]:
-        """Drive the continuous batcher to completion over a request list
-        (no server thread): requests stream into slots as they free up —
-        the in-process analogue of a loaded server."""
-        max_new = max_new_tokens or self.default_max_new_tokens
-        reqs = [Request({"prompt": p},
-                        {"max_new_tokens": max_new, "eos_id": eos_id},
-                        None)
-                for p in prompts]
-        pending = list(reqs)
-        while pending or self.active:
-            if pending and self.free_slots:
-                k = min(len(pending), self.free_slots)
-                self.admit(pending[:k])
-                pending = pending[k:]
-            self.decode_tick()
-        return [r.future.result(timeout=0.1) for r in reqs]
-
-
-# ---------------------------------------------------------------------------
-# Paged KV cache: block-table slots over a shared page pool
-# ---------------------------------------------------------------------------
-class _PagedSlot(_Slot):
-    __slots__ = ("pages", "shared_tokens", "cow_reserve", "prefill_done",
-                 "state", "sampling", "stop_matcher", "mask_proc",
-                 "beam_job", "role", "xrow", "resumed")
-
-    def __init__(self, request, prompt, max_new, eos_id,
-                 sampling: Optional[SamplingParams] = None):
-        super().__init__(request, prompt, max_new, eos_id)
-        self.pages: List[int] = []       # physical page per table entry
-        self.shared_tokens = 0           # prefix-cache hit length
-        self.cow_reserve = 0             # pages held for copy-on-write
-        self.prefill_done = 0            # prompt tokens whose K/V is cached
-        self.state = "decode"            # "prefill" while chunks stream in
-                                         # ("hold"/"beam_wait" for beams)
-        self.sampling = sampling or SamplingParams()
-        self.stop_matcher = StopMatcher(self.sampling.stop)
-        self.mask_proc = self.sampling.logits_processor
-        self.beam_job = None             # set for beam-owned slots
-        self.role = "normal"             # beam_parent | beam | hold
-        self.xrow = None                 # seq2seq: cross-KV cache row
-        self.resumed = 0                 # recovery: emitted tokens that
-                                         # re-entered as prefill context
-
-
-class PagedGenerationEngine(GenerationEngine):
-    """Continuous batcher over a PAGED KV cache with prefix sharing and
-    chunked prefill.
-
-    The cache is a page pool ``[L, n_pages, page_size, Hkv*dh]`` (scope-
-    resident, donated in place like the dense table) plus a host-side
-    per-slot block table: a sequence holds ``ceil(len/page_size)``
-    physical pages, so HBM holds TOKENS IN FLIGHT, not slots x Tmax.
-    Three levers ride on the allocator:
-
-    - **Prefix sharing** (``prefix_sharing=True``): a radix-style index
-      over page-aligned prompt prefixes maps a shared system prompt to
-      refcounted pages stored once; admission of a request whose prefix
-      is cached skips that prefill entirely (``prefix_hit_tokens``
-      counts the skipped tokens). A shared page about to be written
-      (full-prompt hit diverging into generation) is copied first —
-      copy-on-write via ``kv_cache_page_copy``, one page reserved at
-      admission so decode never allocates.
-    - **Chunked prefill**: a prompt longer than ``prefill_chunk`` tokens
-      streams in page-budgeted chunks, one chunk per engine tick,
-      INTERLEAVED with decode ticks — a Tmax admission no longer stalls
-      every in-flight stream (Sarathi-style stall-free batching).
-    - **Typed backpressure**: a request whose prompt + max_new_tokens can
-      NEVER fit the pool fails with
-      :class:`~paddle_tpu.serving.errors.CacheExhaustedError`; transient
-      pressure defers admission (the batcher queue backs up and sheds)
-      instead of failing mid-decode.
-
-    Everything else — warmup manifests, ``swap_params`` rolling updates,
-    drain, fleet membership, metrics names — is inherited unchanged.
-    """
-
-    _cache_names = (PAGED_CACHE_K, PAGED_CACHE_V)
-
-    def __init__(self, spec: LMSpec, scope: Optional[Scope] = None, *,
-                 page_size: Optional[int] = None,
-                 n_pages: Optional[int] = None,
-                 prefill_chunk: Optional[int] = None,
-                 prefix_sharing: bool = True,
-                 beam_width: int = 0, mask_plane: bool = True,
-                 share_cache_with: Optional["PagedGenerationEngine"] = None,
-                 kv_cache: Optional[str] = None, **kw):
-        if kv_cache not in (None, "paged"):
-            raise ValueError(
-                f"PagedGenerationEngine is kv_cache='paged' (got "
-                f"{kv_cache!r}); use GenerationEngine(kv_cache='dense') "
-                "for the dense slot table")
-        if page_size is not None and page_size < 1:
-            raise ValueError("page_size must be >= 1")
-        if beam_width < 0:
-            raise ValueError("beam_width must be >= 0")
-        self._page_size_arg = page_size
-        self._n_pages_arg = n_pages
-        self._prefill_chunk_arg = prefill_chunk
-        self._prefix_sharing = bool(prefix_sharing)
-        # disaggregation: a decode-pool engine built on the PREFILL
-        # engine's scope adopts its page pool/prefix index — a KV
-        # handoff between the two is then a pure slot-table transfer
-        self._share_cache_src = share_cache_with
-        # beam_width > 0 compiles the TopV/TopI (emit_topk) plane into
-        # the decode/prefill programs; beam requests up to this width
-        # then ride the one steady-state compile
-        self.beam_width = int(beam_width)
-        # mask_plane=False drops the [slots, vocab] Mask feed from the
-        # programs (per-tick host->device bytes scale with vocab; turn
-        # it off for large-V deployments that never constrain decoding)
-        self.mask_plane = bool(mask_plane)
-        super().__init__(spec, scope, **kw)
-
     # -- cache / program construction -----------------------------------
     def _init_cache(self):
+        """Put the page pools into the scope and publish their size. A
+        shared-pool engine never re-zeroes: the scope tensors already
+        hold the source pool's live pages."""
         import jax.numpy as jnp
 
         from ..core.types import to_dtype
-        from .paging import PagePool, PrefixIndex
 
-        s = self.spec
-        src = self._share_cache_src
-        if src is not None:
-            if self.scope is not src.scope:
-                raise ValueError(
-                    "share_cache_with requires constructing this engine "
-                    "on the source engine's scope — the page tensors "
-                    "live there")
-            if s != src.spec or self.tmax != src.tmax:
-                raise ValueError(
-                    "share_cache_with requires an identical LMSpec and "
-                    "max_seq_len — the page geometry and weight contract "
-                    "must match for a block table to transfer")
-            self.page_size = src.page_size
-        else:
-            self.page_size = int(self._page_size_arg
-                                 or min(64, self.tmax))
-        # table width: enough entries for a full-context sequence
-        self.pmax = -(-self.tmax // self.page_size)
-        # beam engines default to a bigger pool: K fully-diverged
-        # hypotheses can each hold a full table plus a COW spare
-        beam_extra = (self.slots + 2 * self.beam_width
-                      if getattr(self, "beam_width", 0) else 0)
-        self.n_pages = (src.n_pages if src is not None
-                        else int(self._n_pages_arg
-                                 or self.slots * self.pmax + 1
-                                 + beam_extra))
-        if self.n_pages < 2:
-            raise ValueError("need at least 2 pages (one is scrap)")
-        chunk = self._prefill_chunk_arg
-        if chunk is None:
-            chunk = min(self.prompt_buckets[-1],
-                        max(2 * self.page_size, 128))
-        self.prefill_chunk = max(1, min(int(chunk), self.tmax))
-        self._chunk_widths = sorted(
-            {b for b in self.prompt_buckets if b <= self.prefill_chunk}
-            | {self.prefill_chunk})
-        if src is not None:
-            self.pool = src.pool
-            self.prefix_index = src.prefix_index
-        else:
-            self.pool = PagePool(self.n_pages, self.page_size)
-            self.prefix_index = (PrefixIndex(self.pool)
-                                 if self._prefix_sharing else None)
-        # no scrap SLOT here — padding/vacant rows write the scrap PAGE,
-        # so the decode batch is exactly the slot count
-        self._nslots = self.slots
-        self._tok = np.zeros(self._nslots, np.int64)
-        self._pos = np.zeros(self._nslots, np.int32)
-        self._deferred = deque()  # pool-blocked validated admissions
-        self._pf_cursor = 0       # round-robin over prefilling slots
-        self._beam_jobs: List[BeamJob] = []
-        self._seed_counter = 0    # default per-request seeds (sampled
-                                  # requests without an explicit seed)
         shape = self._pool_shape()
-        page_dtype = jnp.dtype(to_dtype(s.page_dtype))
-        if src is None:
+        page_dtype = jnp.dtype(to_dtype(self.spec.page_dtype))
+        if self._owns_pool:
             with self.executor.device_ctx():
                 self.scope.set(PAGED_CACHE_K, jnp.zeros(shape, page_dtype))
                 self.scope.set(PAGED_CACHE_V, jnp.zeros(shape, page_dtype))
-        # shared-pool engines never re-zero: the scope tensors already
-        # hold the source pool's live pages
-        self._page_copy_prog_cache = None
         self.metrics.set_gauge(
             "mem/kv_cache_bytes",
             2.0 * float(np.prod(shape)) * page_dtype.itemsize)
@@ -1168,20 +483,20 @@ class PagedGenerationEngine(GenerationEngine):
                                            dtype=self.spec.page_dtype)
         return ck, cv
 
+    def _lm_ins(self, helper):
+        from ..models.transformer import _shared_lm_params
+
+        return _shared_lm_params(helper, self.spec)
+
     def _decode_attrs(self):
         # per-request sampling rides the input plane, never the attrs
         # (and never the scope RNG) — attrs stay policy-free so every
         # request shape shares one compile-cache entry
-        attrs = super()._decode_attrs()
-        attrs["temperature"] = 0.0
-        attrs["top_k"] = 0
-        attrs["page_size"] = self.page_size
+        attrs = {**self.spec.block.attrs(), "temperature": 0.0, "top_k": 0,
+                 "page_size": self.page_size}
         if self.beam_width:
             attrs["emit_topk"] = self.beam_width
         return attrs
-
-    def _needs_scope_rng(self) -> bool:
-        return False  # seeds are inputs: the scope RNG is never drawn
 
     _SAMPLING_FEEDS = ("serving.temp", "serving.topk", "serving.topp",
                        "serving.seed", "serving.step")
@@ -1309,25 +624,25 @@ class PagedGenerationEngine(GenerationEngine):
     def _build_decode(self):
         prog, startup = Program(), Program()
         with program_guard(prog, startup):
-            tok = data_layer("serving.tok", shape=[self._nslots],
+            tok = data_layer("serving.tok", shape=[self.slots],
                              dtype="int64", append_batch_size=False)
-            pos = data_layer("serving.pos", shape=[self._nslots],
+            pos = data_layer("serving.pos", shape=[self.slots],
                              dtype="int32", append_batch_size=False)
             table = data_layer("serving.block_table",
-                               shape=[self._nslots, self.pmax],
+                               shape=[self.slots, self.pmax],
                                dtype="int32", append_batch_size=False)
             helper = LayerHelper("serving_paged_decode", main_program=prog,
                                  startup_program=startup)
             ck, cv = self._cache_vars(helper)
             nxt = helper.block.create_var(
                 name="serving.next_tok",
-                shape=[self._nslots], dtype="int64", stop_gradient=True)
+                shape=[self.slots], dtype="int64", stop_gradient=True)
             ins = {"Tok": [tok], "Pos": [pos], "BlockTable": [table],
                    "CacheK": [ck], "CacheV": [cv]}
-            ins.update(self._sampling_vars(self._nslots))
+            ins.update(self._sampling_vars(self.slots))
             ins.update(self._lm_ins(helper))
             outs = {"NextTok": [nxt], "CacheK": [ck], "CacheV": [cv]}
-            outs.update(self._beam_out_vars(helper, self._nslots,
+            outs.update(self._beam_out_vars(helper, self.slots,
                                             "serving.dec"))
             outs.update(self._expert_out_vars(helper))
             helper.append_op("transformer_stack_paged_decode", ins,
@@ -1364,11 +679,57 @@ class PagedGenerationEngine(GenerationEngine):
             self._page_copy_prog_cache = (prog, ok)
         return self._page_copy_prog_cache
 
-    # -- admission bounds ------------------------------------------------
-    def _check_prompt_fits(self, prompt: np.ndarray) -> None:
-        # chunked prefill serves ANY prompt the context admits — the
-        # prompt + max_new_tokens <= tmax check already ran
-        pass
+    def _transpile(self, prog, feed_names, fetch_names, metric_prefix):
+        """Run the inference pipeline over a freshly-built serving program
+        before it is ever compiled (the decode/prefill ops are already
+        maximally fused, so this is usually a fast no-op — but custom or
+        saved-program variants get the full rewrite set) and publish the
+        per-pass stats into the MetricsRegistry.
+        ``preserve_state_writes`` keeps the KV-cache update ops alive even
+        though nothing fetches them."""
+        from ..transpiler import inference_pipeline
+
+        pm = inference_pipeline()
+        pm.run(prog, feed_names, fetch_names, scope=self.scope,
+               preserve_state_writes=True)
+        for k, v in pm.metrics_dict(prefix=metric_prefix).items():
+            self.metrics.set_gauge(k, v)
+
+    def _prefill_prog(self, tp: int):
+        if tp not in self._prefill_progs:
+            self._prefill_progs[tp] = self._build_prefill(tp)
+        return self._prefill_progs[tp]
+
+    def _check_mem_budget(self, budget: float) -> None:
+        """Budget gate with the PAGE POOL (+ block tables) counted as the
+        resident KV state — the pool lives in the scope, so the analyzer
+        prices what is actually allocated, not a slots x Tmax formula."""
+        from .. import analysis
+
+        prog, outs = self._decode_prog
+        mem = analysis.check_memory_budget(
+            prog, list(self._decode_feed_names),
+            [v.name for v in self._fetches(outs)], budget,
+            scope=self.scope, batch_size=self.slots,
+            what=f"GenerationEngine decode step (slots={self.slots}, "
+                 f"pages={self.n_pages}x{self.page_size})")
+        tc = self._chunk_widths[-1]
+        pprog, pouts = self._prefill_prog(tc)
+        pmem = analysis.check_memory_budget(
+            pprog, list(self._prefill_feed_names),
+            [v.name for v in self._fetches(pouts)], budget,
+            scope=self.scope,
+            batch_size=self.prefill_batch_buckets[-1],
+            what=f"GenerationEngine prefill (chunk {tc})")
+        self.metrics.set_gauge("mem/static_peak_bytes",
+                               max(mem.peak_bytes, pmem.peak_bytes))
+
+    # -- bucket helpers -------------------------------------------------
+    def _batch_bucket_for(self, n: int) -> int:
+        for b in self.prefill_batch_buckets:
+            if n <= b:
+                return b
+        return self.prefill_batch_buckets[-1]
 
     def _chunk_bucket_for(self, n: int) -> int:
         for b in self._chunk_widths:
@@ -1378,6 +739,15 @@ class PagedGenerationEngine(GenerationEngine):
 
     def _entries_for(self, n_tokens: int) -> int:
         return -(-n_tokens // self.page_size)
+
+    # -- slot accounting ------------------------------------------------
+    @property
+    def active(self) -> int:
+        return sum(1 for s in self._slots if s is not None)
+
+    @property
+    def free_slots(self) -> int:
+        return self.slots - self.active
 
     # -- program plumbing --------------------------------------------------
     def _fetches(self, outs) -> list:
@@ -1467,30 +837,73 @@ class PagedGenerationEngine(GenerationEngine):
                      for tc in self._chunk_widths)
         return progs
 
-    def _check_mem_budget(self, budget: float) -> None:
-        """Budget gate with the PAGE POOL (+ block tables) counted as the
-        resident KV state — the pool lives in the scope, so the analyzer
-        prices what is actually allocated, not the dense slots x Tmax
-        formula."""
-        from .. import analysis
+    # -- cold-start plane -------------------------------------------------
+    @property
+    def manifest_name(self) -> str:
+        """Warmup-manifest filename, namespaced per tenant: several
+        resident models sharing one artifact directory each persist
+        their own signature set instead of clobbering a global file."""
+        from ..core.manifest import MANIFEST_NAME
 
-        prog, outs = self._decode_prog
-        mem = analysis.check_memory_budget(
-            prog, list(self._decode_feed_names),
-            [v.name for v in self._fetches(outs)], budget,
-            scope=self.scope, batch_size=self._nslots,
-            what=f"PagedGenerationEngine decode step (slots={self.slots}, "
-                 f"pages={self.n_pages}x{self.page_size})")
-        tc = self._chunk_widths[-1]
-        pprog, pouts = self._prefill_prog(tc)
-        pmem = analysis.check_memory_budget(
-            pprog, list(self._prefill_feed_names),
-            [v.name for v in self._fetches(pouts)], budget,
-            scope=self.scope,
-            batch_size=self.prefill_batch_buckets[-1],
-            what=f"PagedGenerationEngine prefill (chunk {tc})")
-        self.metrics.set_gauge("mem/static_peak_bytes",
-                               max(mem.peak_bytes, pmem.peak_bytes))
+        if not self.namespace:
+            return MANIFEST_NAME
+        stem, dot, ext = MANIFEST_NAME.rpartition(".")
+        if not dot:
+            return f"{MANIFEST_NAME}.{self.namespace}"
+        return f"{stem}.{self.namespace}.{ext}"
+
+    def save_manifest(self, dirname: Optional[str] = None) -> Optional[str]:
+        """Persist the compiled (prefill x batch bucket, decode)
+        signature set next to the saved model for AOT replay on the next
+        boot. No-op without a model directory."""
+        dirname = dirname or self.model_dir
+        if dirname is None or len(self.executor.manifest) == 0:
+            return None
+        try:
+            return self.executor.manifest.save(dirname,
+                                               name=self.manifest_name)
+        except OSError:  # read-only artifact volume: serving still works
+            return None
+
+    def warm_from_manifest(self,
+                           dirname: Optional[str] = None) -> Optional[int]:
+        """AOT-replay the saved warmup manifest against the engine-built
+        decode/prefill programs (concurrent ``.lower().compile()``, no
+        execution, live slots untouched). Returns signatures warm, or
+        None when no manifest exists."""
+        from ..core import manifest as manifest_mod
+
+        dirname = dirname or self.model_dir
+        if dirname is None:
+            return None
+        manifest = manifest_mod.try_load(dirname, name=self.manifest_name)
+        if manifest is None:
+            return None
+        stats = manifest_mod.replay(
+            self.executor, self._warm_programs(), scope=self.scope,
+            manifest=manifest)
+        self.metrics.inc("warmup_replayed", stats["compiled"])
+        if stats["skipped"]:
+            self.metrics.inc("warmup_manifest_skipped", stats["skipped"])
+        return stats["compiled"] + stats["already"]
+
+    def warm_start(self) -> int:
+        """Boot path: manifest replay when available, else execute-based
+        :meth:`warmup`; re-persists the manifest either way."""
+        import warnings as warnings_mod
+
+        from ..core.manifest import ManifestError
+
+        warmed = None
+        try:
+            warmed = self.warm_from_manifest()
+        except ManifestError as exc:
+            warnings_mod.warn(f"ignoring warmup manifest: {exc}",
+                              RuntimeWarning, stacklevel=2)
+        if warmed is None:
+            warmed = self.warmup()
+        self.save_manifest()
+        return warmed
 
     # -- page bookkeeping -------------------------------------------------
     def _run_page_copy(self, src: int, dst: int) -> None:
@@ -1525,7 +938,7 @@ class PagedGenerationEngine(GenerationEngine):
             st.pages[entry] = new
             self.metrics.inc("kv_cow_copies")
 
-    def _register_prefix(self, st: _PagedSlot,
+    def _register_prefix(self, st: _Slot,
                          include_tail: bool = False) -> None:
         """Publish the slot's fully-written prompt pages into the prefix
         index (idempotent: existing keys no-op). Full pages register once
@@ -1545,7 +958,7 @@ class PagedGenerationEngine(GenerationEngine):
         if include_tail and tail.size:
             self.prefix_index.insert(key, tail, st.pages[n_full])
 
-    def _release_pages(self, st: _PagedSlot) -> None:
+    def _release_pages(self, st: _Slot) -> None:
         if self._prefix_sharing:
             self._register_prefix(st, include_tail=True)
         for pid in st.pages:
@@ -1555,19 +968,34 @@ class PagedGenerationEngine(GenerationEngine):
             self.pool.release_reservation(st.cow_reserve)
             st.cow_reserve = 0
 
-    def _finish(self, slot: int) -> None:
-        self._release_pages(self._slots[slot])
-        super()._finish(slot)
-
     # -- admission ---------------------------------------------------------
     def _validate(self, req: Request):
-        """Base validation plus the per-request decode policy: a
-        SamplingParams merged request-over-engine-default (request wins
-        field by field — the compat contract for the deprecated
-        engine-wide ``temperature=``/``top_k=``), and BeamParams when
-        the request asks for beam search."""
-        prompt, max_new, eos = super()._validate(req)
+        """Parse one request: the prompt and its bounds, plus the
+        per-request decode policy: a SamplingParams merged
+        request-over-engine-default (request wins field by field — the
+        compat contract for the deprecated engine-wide
+        ``temperature=``/``top_k=``), and BeamParams when the request
+        asks for beam search. Chunked prefill serves ANY prompt the
+        context admits."""
         meta = req.meta or {}
+        try:
+            raw = (req.payload["prompt"] if isinstance(req.payload, dict)
+                   else req.payload)
+            prompt = np.asarray(raw, dtype=np.int64).reshape(-1)
+        except (KeyError, TypeError, ValueError) as exc:
+            raise BadRequestError(f"bad prompt payload: {exc}")
+        if prompt.size < 1:
+            raise BadRequestError("empty prompt")
+        max_new = int(meta.get("max_new_tokens")
+                      or self.default_max_new_tokens)
+        if max_new < 1:
+            raise BadRequestError("max_new_tokens must be >= 1")
+        if prompt.size + max_new > self.tmax:
+            raise BadRequestError(
+                f"prompt ({prompt.size}) + max_new_tokens ({max_new}) "
+                f"exceeds the serving context ({self.tmax})")
+        eos = meta.get("eos_id")
+        eos = self.eos_id if eos is None else eos
         sp = meta.get("sampling_params")
         try:
             sampling = (sp if isinstance(sp, SamplingParams)
@@ -1746,7 +1174,7 @@ class PagedGenerationEngine(GenerationEngine):
         if cow:
             self.pool.reserve(cow)
         slot = self._slots.index(None)
-        st = _PagedSlot(req, prompt, max_new, eos, sampling)
+        st = _Slot(req, prompt, max_new, eos, sampling)
         st.pages = list(spages) + owned
         st.shared_tokens = shared
         st.cow_reserve = cow
@@ -1763,7 +1191,7 @@ class PagedGenerationEngine(GenerationEngine):
             holds = []
             for _ in range(beam.beam_size - 1):
                 h = self._slots.index(None)
-                hs = _PagedSlot(req, prompt, max_new, eos, sampling)
+                hs = _Slot(req, prompt, max_new, eos, sampling)
                 hs.state = "hold"
                 hs.role = "hold"
                 self._slots[h] = hs
@@ -1806,7 +1234,7 @@ class PagedGenerationEngine(GenerationEngine):
             st.state = "prefill"  # streams via prefill_tick
         return "ok"
 
-    def _install_resume(self, st: _PagedSlot, resume: List[int]) -> None:
+    def _install_resume(self, st: _Slot, resume: List[int]) -> None:
         """Seed a re-admitted slot with the tokens its interrupted
         predecessor already emitted: they live in ``generated`` (so the
         decode step counter, stop matching, and max_new accounting all
@@ -1944,6 +1372,71 @@ class PagedGenerationEngine(GenerationEngine):
             self._gauges()
         return admitted
 
+    def _emit(self, slot: int, token: int) -> None:
+        st = self._slots[slot]
+        delta = st.timeline.mark_token(time.monotonic())
+        if delta is None:  # first token: the TTFT sample
+            self.metrics.observe_hist("ttft", st.timeline.ttft_s)
+        else:              # every later token: one TPOT sample
+            self.metrics.observe_hist("tpot", delta)
+        st.generated.append(token)
+        self._emitted_total += 1
+        cb = (st.request.meta or {}).get("on_token")
+        if cb is not None:
+            # progress streaming for the lineage plane: position, token.
+            # Never let an observer kill the decode loop.
+            try:
+                cb(len(st.generated) - 1, token)
+            except Exception:
+                self.metrics.inc("progress_callback_errors")
+        stop = st.stop_matcher
+        if stop:
+            keep = stop.match(st.generated)
+            if keep is not None:
+                # the stop sequence ends here (anywhere — including
+                # mid-page on the paged cache): truncate before the
+                # match and finish; the already-written K/V rows past
+                # the cut are released with the request's pages
+                st.truncate_to = keep
+                self.metrics.inc("stop_sequence_hits")
+                self._finish(slot)
+                return
+        if (len(st.generated) >= st.max_new
+                or (st.eos_id is not None and token == st.eos_id)):
+            self._finish(slot)
+
+    def _finish(self, slot: int) -> None:
+        st = self._slots[slot]
+        self._release_pages(st)
+        self._slots[slot] = None
+        gen = (st.generated if st.truncate_to is None
+               else st.generated[:st.truncate_to])
+        # a RESUMED slot's prompt is original-prompt + already-emitted
+        # context while ``generated`` also starts with those emitted
+        # tokens — strip the overlap so the result ids match an
+        # uninterrupted run exactly
+        resumed = st.resumed
+        prompt = st.prompt[:-resumed] if resumed else st.prompt
+        ids = np.concatenate([prompt, np.asarray(gen, np.int64)])
+        latency = time.monotonic() - st.request.enqueue_t
+        tl = st.timeline
+        if st.request.span is not None and tl.n_tokens > 1:
+            # decode residency as ONE span per request (token-level cost
+            # rides the timeline, not 1 span/token)
+            trace.record("serving/decode", tl.first_token_t,
+                         tl.last_token_t, parent=st.request.span,
+                         tokens=tl.n_tokens,
+                         tpot_ms=round((tl.tpot_s or 0.0) * 1e3, 3))
+        self._recent.append(dict(tl.to_dict(), status="ok",
+                                 latency_s=round(latency, 6),
+                                 resumed=bool(resumed)))
+        st.request.future.set_result(ids)
+        st.request.end_trace(status="ok",
+                             tokens_generated=len(st.generated),
+                             latency_s=round(latency, 6))
+        self.metrics.inc("completed")
+        self.metrics.observe_latency(latency)
+
     # -- the tick loop ----------------------------------------------------
     @property
     def prefilling(self) -> int:
@@ -2017,10 +1510,10 @@ class PagedGenerationEngine(GenerationEngine):
         return True
 
     def _run_decode(self):
-        table = np.zeros((self._nslots, self.pmax), np.int32)
-        tok = np.zeros(self._nslots, np.int64)
-        pos = np.zeros(self._nslots, np.int32)
-        feed = self._neutral_sampling_feed(self._nslots)
+        table = np.zeros((self.slots, self.pmax), np.int32)
+        tok = np.zeros(self.slots, np.int64)
+        pos = np.zeros(self.slots, np.int32)
+        feed = self._neutral_sampling_feed(self.slots)
         for s in range(self.slots):
             st = self._slots[s]
             if st is not None and st.state == "decode":
@@ -2043,7 +1536,7 @@ class PagedGenerationEngine(GenerationEngine):
         res = self.executor.run(prog, feed=feed,
                                 fetch_list=self._fetches(outs),
                                 scope=self.scope)
-        self._count_experts(res, self._nslots)
+        self._count_experts(res, self.slots)
         if self.beam_width:
             return (np.asarray(res[0]), np.asarray(res[1]),
                     np.asarray(res[2]))
@@ -2278,7 +1771,7 @@ class PagedGenerationEngine(GenerationEngine):
         return req.future.result(timeout=0.1)
 
     def _gauges(self):
-        super()._gauges()
+        self.metrics.set_gauge("active_slots", self.active)
         self.metrics.set_gauge("mem/kv_pages_in_use",
                                self.pool.pages_in_use())
         self.metrics.set_gauge("mem/kv_pages_free",
@@ -2287,20 +1780,45 @@ class PagedGenerationEngine(GenerationEngine):
         if self.prefix_index is not None:
             self.metrics.set_gauge("kv_prefix_entries",
                                    len(self.prefix_index))
+        # throttled time-series sampling: the flight bundle's metric
+        # ring sees occupancy/pages/prefix counters EVOLVE, not just
+        # their value at dump time
+        self._flight.maybe_sample(self.metrics)
 
     def flight_state(self) -> dict:
-        state = super().flight_state()
-        state["pool"] = self.pool.stats()
-        state["deferred"] = len(self._deferred)
+        """Live engine state for the flight recorder: per-slot decode
+        progress, the pool, plus the last-N completed request
+        timelines."""
+        slots = []
+        for i, st in enumerate(self._slots):
+            if st is None:
+                continue
+            slots.append({
+                "slot": i,
+                "state": st.state,
+                "prompt_len": int(st.prompt.size),
+                "generated": len(st.generated),
+                "max_new": st.max_new,
+                "pos": int(self._pos[i]),
+            })
+        state = {
+            "engine": type(self).__name__,
+            "slots_total": self.slots,
+            "killed": self._killed,
+            "slots": slots,
+            "recent_requests": list(self._recent),
+            "pool": self.pool.stats(),
+            "deferred": len(self._deferred),
+        }
         if self.prefix_index is not None:
             state["prefix_index"] = self.prefix_index.stats()
         return state
 
     def cache_stats(self) -> dict:
-        """Compile-cache counters (base contract) plus the page pool and
-        prefix index, flattened to numbers so the server can export
-        every key as a gauge."""
-        stats = dict(super().cache_stats())
+        """Compile-cache counters plus the page pool and prefix index,
+        flattened to numbers so the server can export every key as a
+        gauge."""
+        stats = dict(self.executor.cache_stats())
         for k, v in self.pool.stats().items():
             stats[f"kv_pages_{k}"] = v
         if self.prefix_index is not None:
@@ -2308,29 +1826,15 @@ class PagedGenerationEngine(GenerationEngine):
                 stats[f"kv_prefix_{k}"] = v
         return stats
 
-    def swap_params(self, source, *, strict: bool = True):
-        """Rolling weight update (see the base contract) PLUS prefix-
-        cache invalidation: cached prefix pages hold K/V computed with
-        the OLD weights — serving them after a swap would be silently
-        stale, so every index entry is dropped (pages still referenced
-        by in-flight slots stay resident until those requests finish)."""
-        stats = super().swap_params(source, strict=strict)
-        if self.prefix_index is not None:
-            dropped = self.prefix_index.clear()
-            if dropped:
-                self.metrics.inc("prefix_entries_invalidated", dropped)
-            self._gauges()
-        return stats
-
-    # -- mid-stream chaos --------------------------------------------------
-    def _abort_slot_resources(self, st) -> None:
-        if st.pages:
-            self._release_pages(st)
-
+    # -- mid-stream chaos: hard engine death ------------------------------
     def kill(self, reason: str = "chaos") -> int:
-        """Paged kill: beam jobs and the deferred queue die with the
-        slots (every future fails retryable), pages go back to the
-        pool."""
+        """Hard-kill the engine mid-stream (the ``replica_kill`` chaos
+        path): every in-flight generation fails with ``ConnectionError``
+        — RETRYABLE, so a fleet's lineage plane resumes the survivors on
+        a healthy replica — beam jobs and the deferred queue die with
+        the slots, pages go back to the pool, and the engine refuses
+        traffic (serve_step drains the queue the same way) until
+        :meth:`revive`. Returns the number of futures failed."""
         exc = ConnectionError(
             f"replica killed mid-stream ({reason}); in-flight "
             "generations are resumable from their lineage")
@@ -2343,7 +1847,23 @@ class PagedGenerationEngine(GenerationEngine):
             if not job.request.future.done():
                 job.request.future.set_exception(exc)
                 failed += 1
-        failed += super().kill(reason)
+        in_flight = 0
+        for slot in range(self.slots):
+            st = self._slots[slot]
+            if st is None:
+                continue
+            self._slots[slot] = None
+            if st.pages:
+                self._release_pages(st)
+            st.request.end_trace(status="killed")
+            if not st.request.future.done():
+                st.request.future.set_exception(exc)
+                in_flight += 1
+        self._killed = True
+        self.metrics.inc("replica_kills")
+        self.metrics.inc("killed_in_flight", in_flight)
+        self._gauges()
+        failed += in_flight
         while self._deferred:
             req = self._deferred.popleft()[0]
             req.end_trace(status="killed")
@@ -2351,6 +1871,71 @@ class PagedGenerationEngine(GenerationEngine):
                 req.future.set_exception(exc)
                 failed += 1
         return failed
+
+    def revive(self) -> None:
+        """Bring a killed engine back (slots are empty; the KV pages a
+        kill released are reusable immediately). The emit counter
+        restarts: ``after_tokens`` thresholds are per-incarnation."""
+        self._killed = False
+        self._emitted_total = 0
+
+    def _maybe_replica_kill(self) -> None:
+        """Fire an armed ``replica_kill`` fault once the engine has
+        emitted ``after_tokens`` tokens (default 1) across all streams —
+        the deterministic stand-in for a process dying mid-decode."""
+        from ..resilience import faults
+
+        plan = faults.active_plan()
+        if plan is None or self._killed:
+            return
+        params = plan.peek("replica_kill")
+        if params is None:
+            return
+        if self._emitted_total < int(params.get("after_tokens", 1)):
+            return
+        # fire() is the atomic claim: two engines can both pass the
+        # peek, but only the one that consumes the entry dies
+        if plan.fire("replica_kill") is None:
+            return
+        self.kill(reason="fault-plan replica_kill")
+
+    def _drain_killed(self, batcher) -> bool:
+        """A killed engine's serve loop: fail everything the batcher
+        hands it, retryable, so the fleet routes around the corpse."""
+        reqs = batcher.next_batch(max_n=max(self.slots, 1), wait_s=0)
+        if not reqs:
+            return False
+        exc = ConnectionError("replica is down (killed mid-stream)")
+        for req in reqs:
+            req.end_trace(status="killed")
+            if not req.future.done():
+                req.future.set_exception(exc)
+        return True
+
+    def swap_params(self, source, *, strict: bool = True):
+        """Zero-recompile param hot-swap for rolling weight updates:
+        replace the LM weights in place from a trainer checkpoint dir /
+        saved-model dir / Scope / dict. The page pools and the RNG
+        stream are never touched (a checkpoint taken from another
+        serving scope must not clobber live decode state) — call at a
+        drained point so already-admitted requests finish on consistent
+        weights. The prefix cache is invalidated: cached prefix pages
+        hold K/V computed with the OLD weights — serving them after a
+        swap would be silently stale, so every index entry is dropped
+        (pages still referenced by in-flight slots stay resident until
+        those requests finish)."""
+        from .engine import swap_scope_params
+
+        stats = swap_scope_params(self.scope, source,
+                                  skip=self._cache_names, strict=strict,
+                                  device_ctx=self.executor.device_ctx,
+                                  metrics=self.metrics)
+        if self.prefix_index is not None:
+            dropped = self.prefix_index.clear()
+            if dropped:
+                self.metrics.inc("prefix_entries_invalidated", dropped)
+            self._gauges()
+        return stats
 
     # -- prefill/decode disaggregation: KV handoff -------------------------
     def handoff_ready(self) -> List[int]:
@@ -2363,7 +1948,7 @@ class PagedGenerationEngine(GenerationEngine):
             st = self._slots[i]
             if st is not None and st.state == "decode" \
                     and st.role == "normal" and st.beam_job is None \
-                    and getattr(st, "xrow", None) is None:
+                    and st.xrow is None:
                 out.append(i)
         return out
 
@@ -2377,7 +1962,7 @@ class PagedGenerationEngine(GenerationEngine):
         — never a prefill recompute."""
         st = self._slots[slot]
         if st is None or st.state != "decode" or st.beam_job is not None \
-                or getattr(st, "xrow", None) is not None:
+                or st.xrow is not None:
             raise ValueError(f"slot {slot} is not handoff-eligible")
         self._slots[slot] = None
         self.metrics.inc("kv_handoffs_out")

@@ -5,7 +5,7 @@ K/V (generation.py owns those tensors); THIS module owns the metadata —
 which physical pages are free, how many holders reference each page, and
 which pages cache which prompt prefixes. Everything here is plain Python
 over numpy ints: no device traffic, no locks (the engine is single-
-threaded per tick, like the slot table before it).
+threaded per tick).
 
 Two invariants the engine relies on:
 

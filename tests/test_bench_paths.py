@@ -246,27 +246,6 @@ def test_transpiler_bench_path_runs():
     assert res["pass_stats"], "per-pass stats must be recorded"
 
 
-@pytest.mark.slow
-def test_paged_kv_bench_path_runs():
-    import jax
-
-    import paddle_tpu as pt
-    from paddle_tpu import layers, models
-
-    res = _bench().bench_paged_kv(jax, pt, layers, models, tmax=64,
-                                  page_size=16, dense_slots=2,
-                                  prompt_len=12, max_new=4, n_requests=6,
-                                  d=16, L=2, H=2, vocab=32,
-                                  shared_prefix=16)
-    assert res["dense"]["concurrent_hwm"] == 2
-    assert res["paged"]["concurrent_hwm"] == 6
-    # THE capacity acceptance: same KV bytes, >=2x concurrent sequences
-    assert res["paged"]["kv_bytes"] == res["dense"]["kv_bytes"]
-    assert res["concurrency_ratio"] >= 2
-    assert res["paged_shared_prefix"]["prefix_hit_tokens"] > 0
-    assert res["paged"]["tokens_per_sec"] > 0
-
-
 def test_checkpoint_bench_path_runs():
     import jax
 

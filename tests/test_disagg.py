@@ -67,7 +67,7 @@ def _spec():
 
 def _engine(**kw):
     return GenerationEngine(_spec(), _lm_scope(), slots=4, page_size=8,
-                            kv_cache="paged", **kw)
+                            **kw)
 
 
 def _reqs():

@@ -474,33 +474,19 @@ class TestBudgetGating:
 
         spec = LMSpec(vocab_size=64, d_model=32, n_layers=2, num_heads=4,
                       max_len=128)
-        # tiny budget: the KV cache alone blows it (paged default)
+        # tiny budget: the KV cache alone blows it
         with pytest.raises(analysis.MemoryBudgetError) as ei:
             GenerationEngine(spec, pt.Scope(), slots=4, mem_budget=4096)
         assert "GenerationEngine" in str(ei.value)
         eng = GenerationEngine(spec, pt.Scope(), slots=4, mem_budget=1e9)
         gauges = eng.metrics.snapshot()["gauges"]
-        # the PAGE POOL is what is resident, not the dense table formula:
+        # the PAGE POOL is what is resident, not a slots x Tmax formula:
         # [L, n_pages, page_size, Hkv*dh] x 2 (K and V), f32 with
         # page_size=64 -> pmax=2 -> n_pages = slots*2 + 1 = 9
         assert eng.page_size == 64 and eng.n_pages == 9
         assert gauges["mem/kv_cache_bytes"] == 2 * (2 * 9 * 4 * 64 * 8) * 4
         assert gauges["mem/kv_block_table_bytes"] == 4 * 2 * 4
         assert gauges["mem/kv_pages_in_use"] == 0
-
-    def test_dense_generation_engine_budget_counts_slot_table(self):
-        from paddle_tpu.serving.generation import GenerationEngine, LMSpec
-
-        spec = LMSpec(vocab_size=64, d_model=32, n_layers=2, num_heads=4,
-                      max_len=128)
-        with pytest.raises(analysis.MemoryBudgetError):
-            GenerationEngine(spec, pt.Scope(), slots=4, mem_budget=4096,
-                             kv_cache="dense")
-        eng = GenerationEngine(spec, pt.Scope(), slots=4, mem_budget=1e9,
-                               kv_cache="dense")
-        kv = eng.metrics.snapshot()["gauges"]["mem/kv_cache_bytes"]
-        # [L, slots+1, Hkv, Tmax, dh] x 2 (K and V), f32
-        assert kv == 2 * 2 * 5 * 4 * 128 * 8 * 4
 
 
 # ==========================================================================

@@ -436,7 +436,7 @@ class TestFlightRecorder:
             assert "serving/decode_step" in span_names
             engine_states = [v for v in bundle["state"].values()
                              if isinstance(v, dict)
-                             and v.get("engine") == "PagedGenerationEngine"]
+                             and v.get("engine") == "GenerationEngine"]
             assert engine_states, bundle["state"].keys()
             mine = [s for s in engine_states
                     if s.get("recent_requests")]

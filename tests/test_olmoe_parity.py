@@ -2,7 +2,7 @@
 top-k SwiGLU experts) built from ``LMSpec``, against the plain float32
 reference in ``benchmark/families/moe_lm.py`` at a tiny size on the CPU:
 logits, loss, every gradient, and chunked prefill + decode through
-``PagedGenerationEngine`` — all through the normal path
+``GenerationEngine`` — all through the normal path
 (``transformer_lm(spec=)`` / ``GenerationEngine(spec, ...)``).
 
 Tolerances. Program and reference run the SAME float32 arithmetic in a
@@ -350,20 +350,12 @@ _OLMOE_ATTRS = Block(num_heads=4, norm="rms_norm", qk_norm=True,
                      experts_per_tok=2, bias=False).attrs()
 
 
-@pytest.mark.parametrize("op", ["transformer_stack_beam_search",
-                                "transformer_stack_speculative_generate",
-                                "transformer_stack_slot_prefill",
-                                "transformer_stack_slot_decode"])
+@pytest.mark.parametrize("op", ["transformer_stack_beam_search"])
 def test_gpt2_only_ops_refuse_another_spec_by_name(op):
     from paddle_tpu.core.registry import get_op
 
     with pytest.raises(BlockNotSupportedError, match=op):
         get_op(op).fn(dict(_OLMOE_ATTRS, max_new_tokens=1), {})
-
-
-def test_dense_slot_engine_refuses_another_spec():
-    with pytest.raises(BlockNotSupportedError, match="dense slot engine"):
-        GenerationEngine(moe_lm.spec_of(tiny_config()), kv_cache="dense")
 
 
 def test_gpt2_spec_is_the_default_block():
@@ -376,3 +368,37 @@ def test_gpt2_spec_is_the_default_block():
         "ln1_s", "ln1_b", "qkv_w", "out_w", "ln2_s", "ln2_b",
         "ff_w1", "ff_b1", "ff_w2", "ff_b2"]
     assert Block.from_attrs(_OLMOE_ATTRS).attrs() == _OLMOE_ATTRS
+
+
+# ---------------------------------------------------------------------------
+# the engine's programs are pinned: warm-up manifests and the persistent
+# compile cache key on them, so a refactor of the engine must not move them
+# ---------------------------------------------------------------------------
+_ENGINE_KW = dict(slots=2, page_size=8, prompt_buckets=(8, 16),
+                  prefill_chunk=16)
+
+
+@pytest.mark.parametrize("spec,kw,want", [
+    (LMSpec(vocab_size=32, d_model=16, n_layers=2, num_heads=2, max_len=64),
+     {},
+     {"decode": "e85b615f27ebecde", "prefill16": "c65f3065494e5d19",
+      "prefill8": "a1a8b6beee06c68f", "page_copy": "d5270f0b76e90d8b"}),
+    (moe_lm.spec_of(tiny_config()),
+     dict(max_seq_len=64, prefill_batch_buckets=(1,), eos_id=None),
+     {"decode": "7a69e82d28de54fd", "prefill16": "de122b6b33ea0455",
+      "prefill8": "4e95b6fb631f8d41", "page_copy": "38351444fa9c0df6"}),
+], ids=["gpt2", "olmoe"])
+def test_engine_programs_are_bit_identical_to_the_recorded_ones(
+        spec, kw, want):
+    """``program_digest`` (the ``program_to_dict`` JSON, call sites
+    stripped) of the decode step, every prefill chunk width and the page
+    copy, recorded from the tree before PR 28 merged the two engine
+    classes. A change that means to move a program re-records them."""
+    from paddle_tpu.core.manifest import program_digest
+
+    eng = GenerationEngine(spec, **_ENGINE_KW, **kw)
+    got = {"decode": program_digest(eng._decode_prog[0]),
+           "page_copy": program_digest(eng._page_copy_prog[0])}
+    for tc in eng._chunk_widths:
+        got[f"prefill{tc}"] = program_digest(eng._prefill_prog(tc)[0])
+    assert got == want

@@ -1,4 +1,4 @@
-"""Paged KV cache pins: token-exactness vs the dense slot table on mixed
+"""Paged KV cache pins: token-exactness vs the one-shot decode op on mixed
 greedy batches, prefix sharing (stored-once pages, copy-on-write on
 divergence, refcounted release), Sarathi-style chunked-prefill fairness,
 typed pool backpressure, and the zero-recompile steady state over the
@@ -9,8 +9,7 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu import layers, models
 from paddle_tpu.serving import (CacheExhaustedError, DynamicBatcher,
-                                GenerationEngine, LMSpec,
-                                PagedGenerationEngine, Request)
+                                GenerationEngine, LMSpec, Request)
 from paddle_tpu.serving.generation import PAGED_CACHE_K, PAGED_CACHE_V
 
 VOCAB, D, L, H, MAXLEN = 32, 16, 2, 2, 64
@@ -61,58 +60,84 @@ def _spec(**kw):
                   max_len=MAXLEN, **kw)
 
 
+def _reference_each(scope, exe, prompts, max_new, **lm_kwargs):
+    """The one-shot op's greedy decode of each prompt at its own length
+    (the op has no lengths plane: one program per prompt length)."""
+    return [_reference_decode(scope, exe, p[None], max_new, **lm_kwargs)[0]
+            for p in prompts]
+
+
 # ---------------------------------------------------------------------------
-# token-exactness vs the dense slot table
+# token-exactness vs the one-shot decode op
 # ---------------------------------------------------------------------------
 class TestPagedParity:
-    def test_paged_vs_dense_mixed_length_greedy_batch(self):
-        """THE tentpole acceptance pin: a bs>=8 mixed-length greedy
-        workload through the paged engine emits exactly the dense slot
-        table's tokens (same weights, same prompts, same horizons)."""
-        scope_d, exe = _init_lm_scope(7)
-        scope_p, _ = _init_lm_scope(7)
+    _LENS = [3, 5, 8, 11, 6, 14, 2, 16]  # mixed lengths, bs=8
+
+    @pytest.fixture(scope="class")
+    def mixed(self):
+        scope, exe = _init_lm_scope(7)
         rng = np.random.RandomState(0)
-        lens = [3, 5, 8, 11, 6, 14, 2, 16]  # mixed lengths, bs=8
         prompts = [rng.randint(0, VOCAB, (n,)).astype("int64")
-                   for n in lens]
-        dense = GenerationEngine(_spec(), scope_d, slots=8,
-                                 kv_cache="dense",
-                                 prompt_buckets=(4, 8, 16))
-        paged = GenerationEngine(_spec(), scope_p, slots=8, page_size=8,
-                                 prompt_buckets=(4, 8, 16))
-        assert isinstance(paged, PagedGenerationEngine)
-        assert not isinstance(dense, PagedGenerationEngine)
-        got_d = dense.generate_all(prompts, max_new_tokens=5)
-        got_p = paged.generate_all(prompts, max_new_tokens=5)
-        # the dense leg is itself pinned one-shot-exact in
-        # tests/test_serving.py, so dense equality IS ground truth
-        for a, b in zip(got_d, got_p):
+                   for n in self._LENS]
+        return prompts, _reference_each(scope, exe, prompts, 5)
+
+    @pytest.mark.parametrize("page_size", [4, 8, 16])
+    def test_mixed_length_greedy_batch_matches_one_shot_decode(
+            self, mixed, page_size):
+        """THE acceptance pin: a bs>=8 mixed-length greedy workload
+        through the engine emits exactly the one-shot
+        ``transformer_stack_generate`` tokens (same weights, same
+        prompts, same horizons), wherever the page boundaries fall:
+        inside most prompts (4), inside the generation (8: 3+5, 6+5),
+        hardly anywhere (16)."""
+        prompts, want = mixed
+        scope, _ = _init_lm_scope(7)
+        eng = GenerationEngine(_spec(), scope, slots=8,
+                               page_size=page_size,
+                               prompt_buckets=(4, 8, 16))
+        got = eng.generate_all(prompts, max_new_tokens=5)
+        for a, b in zip(want, got):
             np.testing.assert_array_equal(a, b)
-        assert paged.metrics.counter("completed") == len(lens)
+        assert eng.metrics.counter("completed") == len(prompts)
         # every page released on finish (sharing retains prefix pages)
-        assert paged.pool.pages_in_use() == len(paged.prefix_index)
+        assert eng.pool.pages_in_use() == len(eng.prefix_index)
 
     @pytest.mark.slow
     def test_gqa_rope_paged_parity(self):
         """Per-row rotary offsets in the paged chunk prefill (each batch
-        row resumes at its own absolute position) vs the dense path."""
-        scope_d, _ = _init_lm_scope(5, use_rope=True, num_kv_heads=1)
-        scope_p, _ = _init_lm_scope(5, use_rope=True, num_kv_heads=1)
+        row resumes at its own absolute position) vs the one-shot op."""
+        kw = dict(use_rope=True, num_kv_heads=1)
+        scope_r, exe = _init_lm_scope(5, **kw)
+        scope_p, _ = _init_lm_scope(5, **kw)
         rng = np.random.RandomState(2)
         prompts = [rng.randint(0, VOCAB, (n,)).astype("int64")
                    for n in (5, 12)]
-        dense = GenerationEngine(_spec(use_rope=True, num_kv_heads=1),
-                                 scope_d, slots=2, kv_cache="dense",
-                                 prompt_buckets=(16,),
-                                 prefill_batch_buckets=(2,))
-        paged = GenerationEngine(_spec(use_rope=True, num_kv_heads=1),
-                                 scope_p, slots=2, page_size=4,
-                                 prompt_buckets=(16,),
-                                 prefill_batch_buckets=(2,))
-        got_d = dense.generate_all(prompts, max_new_tokens=4)
-        got_p = paged.generate_all(prompts, max_new_tokens=4)
-        for a, b in zip(got_d, got_p):
+        eng = GenerationEngine(_spec(**kw), scope_p, slots=2, page_size=4,
+                               prompt_buckets=(16,),
+                               prefill_batch_buckets=(2,))
+        got = eng.generate_all(prompts, max_new_tokens=4)
+        for a, b in zip(_reference_each(scope_r, exe, prompts, 4, **kw),
+                        got):
             np.testing.assert_array_equal(a, b)
+
+
+def test_default_pool_holds_every_slot_at_full_context_plus_beam_spares():
+    """n_pages when none is given: a full table a slot plus the scrap
+    page; a beam engine adds a copy-on-write spare a slot and two a
+    hypothesis."""
+    s, k = 3, 4
+    pmax = -(-MAXLEN // 16)
+    eng = GenerationEngine(_spec(), slots=s, page_size=16)
+    assert eng.pmax == pmax and eng.n_pages == s * pmax + 1
+    beam = GenerationEngine(_spec(), slots=s, page_size=16, beam_width=k)
+    assert beam.n_pages == s * pmax + 1 + s + 2 * k
+    assert GenerationEngine(_spec(), slots=s, page_size=16, beam_width=k,
+                            n_pages=7).n_pages == 7
+
+
+def test_there_is_one_cache_layout_and_no_switch_for_it():
+    with pytest.raises(TypeError, match="kv_cache"):
+        GenerationEngine(_spec(), slots=2, **{"kv_cache": "paged"})
 
 
 # ---------------------------------------------------------------------------
@@ -382,25 +407,26 @@ class TestInPlaceStep:
                 stats.temp_size_in_bytes, pool.nbytes)
         assert not _pool_sized_ops(aots[0].as_text(), pool.shape)
 
-    def test_ticks_and_chunk_touch_only_the_written_cells(self):
-        """In-place safety: one prefill chunk and two consecutive decode
-        ticks on pools pre-filled with a seeded pattern change ONLY the
-        (layer, page, row) cells of the tokens they cached — every other
-        element is bitwise the pattern (scrap page 0 apart: vacant slots
-        and pad tokens write there) — the written rows are the dense slot
-        table's K/V rows scattered by the block table, and the tokens are
-        the dense engine's although every page still holds the pattern
-        beyond the rows written."""
+    @pytest.mark.parametrize("prefill_chunk", [16, 8])
+    def test_ticks_and_chunk_touch_only_the_written_cells(
+            self, prefill_chunk):
+        """In-place safety: the prefill of an 11-token prompt (one chunk
+        of 16, or two of 8) and two consecutive decode ticks on pools
+        pre-filled with a seeded pattern change ONLY the (layer, page,
+        row) cells of the tokens they cached — every other element is
+        bitwise the pattern (scrap page 0 apart: vacant slots and pad
+        tokens write there) — the written rows are the K/V the one-shot
+        family's prefill captures for every layer, scattered by the
+        block table, and the tokens are the one-shot op's although every
+        page still holds the pattern beyond the rows written."""
         import jax.numpy as jnp
 
-        from paddle_tpu.serving.generation import CACHE_K, CACHE_V
+        from paddle_tpu.ops import pipeline_ops
 
-        scope_d, _ = _init_lm_scope(7)
+        scope_r, exe = _init_lm_scope(7)
         scope_p, _ = _init_lm_scope(7)
-        dense = GenerationEngine(_spec(), scope_d, slots=2,
-                                 kv_cache="dense", prompt_buckets=(16,))
         paged = GenerationEngine(_spec(), scope_p, slots=2, page_size=8,
-                                 n_pages=40, prefill_chunk=16,
+                                 n_pages=40, prefill_chunk=prefill_chunk,
                                  prompt_buckets=(16,), prefix_sharing=False)
         rng = np.random.RandomState(25)
         shape = paged.scope.get(PAGED_CACHE_K).shape
@@ -411,26 +437,33 @@ class TestInPlaceStep:
             for n, v in pattern.items():
                 paged.scope.set(n, jnp.asarray(v))
         prompt = rng.randint(0, VOCAB, (11,)).astype("int64")
-        stays = []
-        for eng in (dense, paged):
-            req = Request({"prompt": prompt}, {"max_new_tokens": 8}, None)
-            eng.admit([req])  # 11 <= chunk: ONE prefill chunk
-            eng.decode_tick()
-            eng.decode_tick()
-            stays.append(next((i, st) for i, st in enumerate(eng._slots)
-                              if st is not None))
-        (slot_d, st_d), (_, st_p) = stays
-        assert st_p.generated == st_d.generated
-        assert len(st_p.generated) == 3
+        req = Request({"prompt": prompt}, {"max_new_tokens": 8}, None)
+        paged.admit([req])  # 11 <= 16: ONE prefill chunk; > 8: it streams
+        chunks = 0
+        while paged.prefilling:
+            chunks += paged.prefill_tick()
+        assert chunks == (0 if prefill_chunk == 16 else 2)
+        paged.decode_tick()
+        paged.decode_tick()
+        st_p = next(st for st in paged._slots if st is not None)
+        want_ids = _reference_decode(scope_r, exe, prompt[None], 3)[0]
+        assert st_p.generated == want_ids[len(prompt):].tolist()
         written = len(prompt) + 2  # the prompt + one row per tick
         pages = list(st_p.pages)
         assert 0 not in pages and len(pages) >= -(-written // 8)
-        for pname, dname in ((PAGED_CACHE_K, CACHE_K),
-                             (PAGED_CACHE_V, CACHE_V)):
+        # every layer's K/V over prompt + the two decoded tokens, from
+        # the one-shot family's prefill: [L, 1, Hkv, T, dh]
+        blk = _spec().block
+        params = {key: jnp.asarray(scope_r.get(f"lm_stack.stack_{key}"))
+                  for key in blk.stack_slots().values()}
+        x = (jnp.asarray(scope_r.get("tok_emb"))[want_ids[:written]]
+             + jnp.asarray(scope_r.get("pos_emb"))[:written])[None]
+        _, (ks, vs) = pipeline_ops._prefill(blk, params, x, 1, written)
+        for pname, kv in ((PAGED_CACHE_K, ks), (PAGED_CACHE_V, vs)):
             got = np.asarray(paged.scope.get(pname))
-            rows = np.asarray(dense.scope.get(dname))[:, slot_d]
-            # [L, Hkv, Tmax, dh] -> one [Hkv*dh] row per position
-            rows = rows.transpose(0, 2, 1, 3).reshape(L, -1, D)
+            # [L, Hkv, T, dh] -> one [Hkv*dh] row per position
+            rows = np.asarray(kv)[:, 0].transpose(0, 2, 1, 3).reshape(
+                L, -1, D)
             want = pattern[pname].copy()
             mask = np.zeros(shape[:3], bool)
             for pos in range(written):

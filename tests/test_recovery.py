@@ -80,8 +80,7 @@ def _spec():
 
 def _engine(**kw):
     kw.setdefault("slots", 4)
-    return GenerationEngine(_spec(), _lm_scope(), page_size=8,
-                            kv_cache="paged", **kw)
+    return GenerationEngine(_spec(), _lm_scope(), page_size=8, **kw)
 
 
 def _counters(obj) -> dict:

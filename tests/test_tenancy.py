@@ -77,8 +77,7 @@ def _engine(seed, **kw):
     # narrow bucket grids so warmup() covers every steady-state shape
     # with a handful of compiles (tier-1 budget)
     return GenerationEngine(_spec(), _lm_scope(seed), slots=4,
-                            page_size=8, kv_cache="paged",
-                            prompt_buckets=(8,),
+                            page_size=8, prompt_buckets=(8,),
                             prefill_batch_buckets=(1, 2, 4), **kw)
 
 
