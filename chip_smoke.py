@@ -166,23 +166,29 @@ class LMTrainer:
                        event_handler=handler, async_depth=async_depth)
         return losses, order
 
-    def lowered_step_text(self):
-        """StableHLO of the whole train step (no compile)."""
-        import jax
 
-        batch = next(lm_reader(self.cfg, 1, 0)())
-        feed = self.sgd.feeder.feed(batch)
-        fn, args = self.sgd.exe.as_function(
-            self.main, feed, [self.loss], scope=self.scope)
-        with self.sgd.exe.device_ctx(self.main):
-            return jax.jit(fn).lower(*args).as_text()
+def compiled_step_loops_and_kernels(tr):
+    """(``while`` instructions, Counter of Mosaic call instructions by
+    kernel) of the COMPILED train step: what the device runs, under the
+    names its trace shows (``%flash_fwd.16 = ... tpu_custom_call``)."""
+    loops, kernels = 0, collections.Counter()
+    for c in tr.sgd.exe._cache.values():
+        text = c.aot.as_text()
+        loops += len(re.findall(r"= .* while\(", text))
+        kernels.update(re.findall(
+            r'%(\w+?)\.\d+ = [^\n]*custom_call_target="tpu_custom_call"',
+            text))
+    return loops, kernels
 
 
 def phase_train(cfg):
     """Sync then async_depth=2 on one trainer (one compiled step). Pass:
     every loss finite, the last below the first by ``loss_margin``,
-    EndIteration in batch order, and on a TPU the lowered step carries
-    the Mosaic flash kernels, forward and backward."""
+    EndIteration in batch order, the stack is traced once for forward
+    and backward (core/backward.py), and on a TPU the compiled step holds
+    TWO loops (forward scan, backward scan) running the Mosaic flash
+    kernels: two ``flash_fwd`` a layer (forward, remat recompute), one
+    ``flash_dq``, one ``flash_dkv``."""
     t0 = time.perf_counter()
     tr = LMTrainer(cfg)
     sync_losses, sync_order = tr.train(cfg["sync_steps"], seed=SEED)
@@ -201,18 +207,26 @@ def phase_train(cfg):
     check(losses[-1] < losses[0] - cfg["loss_margin"],
           f"loss did not fall by {cfg['loss_margin']}: "
           f"{losses[0]:.4f} -> {losses[-1]:.4f}")
-    kernels = collections.Counter(re.findall(
-        r'kernel_name\s*=\s*"([^"]+)"', tr.lowered_step_text()))
+    loops, kernels = compiled_step_loops_and_kernels(tr)
+    check(tr.sgd.exe.cache_stats()["paired_vjp_ops"] == 1,
+          "the stack op was not paired with its grad op: the train step "
+          "traces its forward scan twice")
     if device_info()["platform"] == "tpu":
-        # the names kernels/flash_attention.py gives its pallas_calls
-        for name in ("flash_fwd", "flash_dq", "flash_dkv"):
-            check(kernels[name] >= 1, f"no Mosaic call {name} in the "
-                  f"lowered train step ({dict(kernels)}): the flash kernel "
-                  "gave way to the jnp reference")
+        # (the CPU backend expands scatters and sorts into loops of its own)
+        check(loops == 2, f"{loops} while loops in the compiled train "
+              "step: one forward + one backward scan expected")
+        # the names kernels/flash_attention.py gives its pallas_calls;
+        # none at all means the kernels gave way to the jnp reference
+        check(dict(kernels) == {"flash_fwd": 2, "flash_dq": 1,
+                                "flash_dkv": 1},
+              f"Mosaic calls of the compiled train step {dict(kernels)}: "
+              "expected two flash_fwd a layer (forward scan, remat "
+              "recompute), one flash_dq, one flash_dkv")
     emit("lm_train", t0, steps=len(losses),
          first_loss=round(losses[0], 4), last_loss=round(losses[-1], 4),
          loss_margin=cfg["loss_margin"],
-         mosaic_kernels_in_lowered_step=dict(kernels),
+         while_loops_in_compiled_step=loops,
+         mosaic_calls_in_compiled_step=dict(kernels),
          same_losses_as_previous_run=same_as_previous_run(cfg, losses),
          **tr.sgd.exe.cache_stats())
     return tr, losses[0]

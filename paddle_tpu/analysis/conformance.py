@@ -92,6 +92,14 @@ def audit_op(op_type: str) -> List[LintIssue]:
             "asserts vjp-of-forward is still valid ALONGSIDE a custom "
             "grad — with no grad_fn it is meaningless)"))
 
+    if opdef.has_loop and (opdef.grad_fn is not None or opdef.special
+                           or opdef.needs_rng):
+        issues.append(_op_issue(
+            op_type, ERROR,
+            "has_loop=True pairs the op with the generic grad op (traced "
+            "once under jax.vjp): an op with a grad_fn, a special op or "
+            "one that draws randomness never gets that grad op"))
+
     # cost-model coverage contract (applies to special ops too): every
     # op carries an analytical cost handler (costmodel.register_cost) or
     # an explicit cost_exempt marker — the roofline/memory plane must

@@ -297,14 +297,19 @@ def _segment_residual_bytes(op: Operator, env: Dict[str, object]) -> float:
 
 def _paired_grad_index(block: Block, i: int, op: Operator) -> Optional[int]:
     """Index of the grad op that consumes op's forward residuals: for
-    seg_fwd the grad_seg sharing its vjp_key; for plain ops the first
-    later grad/grad_custom with fwd_type == op.type reading one of op's
-    outputs (or their @PRE snapshots)."""
-    if op.type == "seg_fwd":
-        key = op.attrs.get("vjp_key")
+    seg_fwd the grad_seg sharing its vjp_key; for a loop-bearing op
+    traced once (backward.traced_once) the grad op sharing its pair key;
+    for other plain ops the first later grad/grad_custom with fwd_type ==
+    op.type reading one of op's outputs (or their @PRE snapshots)."""
+    from ..core.backward import VJP_KEY_ATTR
+
+    if op.type == "seg_fwd" or VJP_KEY_ATTR in op.attrs:
+        kind, attr = (("grad_seg", "vjp_key") if op.type == "seg_fwd"
+                      else ("grad", VJP_KEY_ATTR))
+        key = op.attrs.get(attr)
         for j in range(i + 1, len(block.ops)):
             o = block.ops[j]
-            if o.type == "grad_seg" and o.attrs.get("vjp_key") == key:
+            if o.type == kind and o.attrs.get(attr) == key:
                 return j
         return None
     out_names = set(op.output_names())

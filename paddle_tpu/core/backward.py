@@ -9,11 +9,20 @@ op, and sum-accumulates fan-out gradients, naming grad variables
 
 Where the reference needs a hand-written GradOpDescMaker + grad kernel per op
 (grad_op_desc_maker.h), we emit a generic ``grad`` op whose kernel computes
-``jax.vjp`` of the registered forward function. The recomputed forward
-subexpressions are CSE'd by XLA inside the single fused block computation, so
-this is free at run time and guarantees analytically-consistent gradients for
-every op. Ops with randomness or custom sparse grads register an explicit
-``grad_fn`` and get a ``grad_custom`` op instead.
+``jax.vjp`` of the registered forward. For a straight-line kernel the forward
+subexpressions the vjp traces again are CSE'd by XLA inside the single fused
+block computation, so this is free at run time and guarantees
+analytically-consistent gradients for every op. XLA does not merge two
+loops: the forward op's ``while`` (no residuals) and the vjp's forward
+``while`` (stacks residuals) would both run. So a forward op whose definition
+says ``has_loop`` shares a ``__vjp_key__`` with its ``grad`` op, and the
+executor traces it once (``traced_once``: ``jax.vjp``, closure kept in the
+trace environment) and has the grad op apply the kept closure (``grad_kept``)
+— the pairing ``seg_fwd``/``grad_seg`` have, without their checkpoint. A
+program that holds the forward op and not its grad op (inference clones,
+pruned exports, serving) runs the plain kernel. Ops with randomness or custom
+sparse grads register an explicit ``grad_fn`` and get a ``grad_custom`` op
+instead.
 """
 from __future__ import annotations
 
@@ -53,22 +62,11 @@ def _rebuild_ins(attrs, ins):
     return {slot: ins["I:" + slot] for slot in attrs["in_slots"] if "I:" + slot in ins}
 
 
-@register_op("grad")
-def generic_grad(attrs, ins):
-    """vjp-of-forward gradient kernel.
-
-    attrs:
-      fwd_type, fwd_attrs — the forward op
-      in_slots  — {slot: n_inputs} of the forward op
-      out_slots — [slot, ...] deterministic output slot order
-      og        — {slot: [bool per output]} which outputs have incoming grads
-      diff      — {slot: [bool per input]} which inputs need gradients
-    """
-    opdef = get_op(attrs["fwd_type"])
-    fwd_attrs = attrs["fwd_attrs"]
-    primal = _rebuild_ins(attrs, ins)
-    diff_mask: Dict[str, List[bool]] = attrs["diff"]
-
+def _vjp_of_forward(opdef, fwd_attrs, primal, diff_mask, out_slots):
+    """ONE trace of a forward op under ``jax.vjp`` w.r.t. the inputs
+    ``diff_mask`` marks -> (outs, float_pos, leaves, vjp): the op's whole
+    output dict, the (slot, index) of each floating output among
+    ``out_slots``, those outputs' values, and the closure over them."""
     # Split inputs into differentiated leaves and fixed leaves.
     diff_ins = {
         slot: [a for a, d in zip(primal[slot], diff_mask[slot]) if d]
@@ -87,37 +85,105 @@ def generic_grad(attrs, ins):
             full[slot] = [next(it) if d else a for a, d in zip(arrs, mask)]
         return full
 
-    # Discover float output leaf positions by abstract evaluation.
-    probe = jax.eval_shape(lambda p: opdef.fn(fwd_attrs, p), primal)
-    float_pos = [
-        (slot, i)
-        for slot in attrs["out_slots"]
-        for i in range(len(probe.get(slot, [])))
-        if is_floating(probe[slot][i].dtype)
-    ]
+    float_pos: List[Tuple[str, int]] = []
 
     def f(d_ins):
         o = opdef.fn(fwd_attrs, merge(d_ins))
-        return [o[s][i] for (s, i) in float_pos]
+        float_pos[:] = [
+            (slot, i)
+            for slot in out_slots
+            for i in range(len(o.get(slot, [])))
+            if is_floating(o[slot][i].dtype)
+        ]
+        return [o[s][i] for (s, i) in float_pos], o
 
-    outs, vjp = jax.vjp(f, diff_ins)
+    leaves, vjp, outs = jax.vjp(f, diff_ins, has_aux=True)
+    return outs, float_pos, leaves, vjp
 
+
+def _input_grads(attrs, ins, float_pos, leaves, vjp):
+    """Apply ``vjp`` to the grad op's OG: inputs -> its IG: outputs."""
     # Build cotangents aligned with float_pos; missing grads are zeros.
-    og_mask = attrs["og"]
     og_arrays: Dict[str, List] = {}
-    for slot, mask in og_mask.items():
+    for slot, mask in attrs["og"].items():
         arrs = iter(ins.get("OG:" + slot, []))
         og_arrays[slot] = [next(arrs) if m else None for m in mask]
     cts = []
-    for (slot, i), leaf in zip(float_pos, outs):
+    for (slot, i), leaf in zip(float_pos, leaves):
         g = og_arrays.get(slot, [None] * (i + 1))[i] if slot in og_arrays else None
         cts.append(g.astype(leaf.dtype) if g is not None else jnp.zeros_like(leaf))
     (gins,) = vjp(cts)
+    return {"IG:" + slot: list(arrs) for slot, arrs in gins.items()}
 
-    result = {}
-    for slot, arrs in gins.items():
-        result["IG:" + slot] = list(arrs)
-    return result
+
+@register_op("grad")
+def generic_grad(attrs, ins):
+    """vjp-of-forward gradient kernel.
+
+    attrs:
+      fwd_type, fwd_attrs — the forward op
+      in_slots  — {slot: n_inputs} of the forward op
+      out_slots — [slot, ...] deterministic output slot order
+      og        — {slot: [bool per output]} which outputs have incoming grads
+      diff      — {slot: [bool per input]} which inputs need gradients
+    """
+    _, float_pos, leaves, vjp = _vjp_of_forward(
+        get_op(attrs["fwd_type"]), attrs["fwd_attrs"],
+        _rebuild_ins(attrs, ins), attrs["diff"], attrs["out_slots"])
+    return _input_grads(attrs, ins, float_pos, leaves, vjp)
+
+
+# A forward op whose kernel holds a loop (OpDef.has_loop) and the ``grad``
+# op append_backward emitted for it carry the same value under this attr.
+VJP_KEY_ATTR = "__vjp_key__"
+_OP_VJP_PREFIX = "@OPVJP@"
+
+
+def vjp_pairs(ops) -> Dict[str, dict]:
+    """{pair key: the grad op's attrs} for every forward op of ``ops``
+    that is followed, in ``ops``, by the ``grad`` op sharing its key —
+    the ops the executor traces once. A forward op whose grad op is not
+    in the block (an inference clone, a pruned export) is not in it."""
+    fwd_type: Dict[str, str] = {}
+    pairs: Dict[str, dict] = {}
+    for op in ops:
+        key = op.attrs.get(VJP_KEY_ATTR)
+        if key is None:
+            continue
+        if op.type != "grad":
+            fwd_type[key] = op.type
+        elif fwd_type.get(key) == op.attrs["fwd_type"]:
+            pairs[key] = op.attrs
+    return pairs
+
+
+def drop_unpaired_keys(ops) -> bool:
+    """Take the pair key off every op of ``ops`` whose partner is not in
+    it (what a prune leaves of a train program) -> whether any went."""
+    pairs = vjp_pairs(ops)
+    dangling = [op for op in ops if VJP_KEY_ATTR in op.attrs
+                and op.attrs[VJP_KEY_ATTR] not in pairs]
+    for op in dangling:
+        op.attrs = {k: v for k, v in op.attrs.items() if k != VJP_KEY_ATTR}
+    return bool(dangling)
+
+
+def traced_once(op, ins, grad_attrs, env):
+    """Run a paired forward op as the primal half of ``jax.vjp`` — the
+    same kernel on the same inputs, so the same outputs — and keep the
+    closure in the trace environment for ``grad_kept``. The op's own
+    attrs (a stack's ``remat``) still decide what its loop saves."""
+    outs, float_pos, leaves, vjp = _vjp_of_forward(
+        get_op(op.type), op.attrs, ins, grad_attrs["diff"],
+        grad_attrs["out_slots"])
+    env[_OP_VJP_PREFIX + op.attrs[VJP_KEY_ATTR]] = (float_pos, leaves, vjp)
+    return outs
+
+
+def grad_kept(attrs, ins, env):
+    """The paired ``grad`` op: applies the closure ``traced_once`` kept
+    instead of tracing the forward (and its loop) a second time."""
+    return _input_grads(attrs, ins, *env[_OP_VJP_PREFIX + attrs[VJP_KEY_ATTR]])
 
 
 # Outputs of these op types are saved across forward->backward inside a
@@ -573,18 +639,28 @@ def append_backward(
                 add_contribution(name, gvar)
             grad_outputs["IG:" + slot] = slot_outs
 
+        grad_attrs = {
+            "fwd_type": op.type,
+            "fwd_attrs": dict(op.attrs),
+            "in_slots": {slot: len(names) for slot, names in op.inputs.items()},
+            "out_slots": out_slots,
+            "og": og_mask,
+            "diff": diff_mask,
+        }
+        if (opdef.has_loop and not use_custom
+                and VJP_KEY_ATTR not in op.attrs):
+            # XLA would run the forward's loop and the vjp's forward loop
+            # both: pair the two ops so the executor traces the forward
+            # once (traced_once / grad_kept). A second loss over the same
+            # forward op keeps the generic grad.
+            key = program.unique_name("vjp")
+            op.attrs = dict(op.attrs, **{VJP_KEY_ATTR: key})
+            grad_attrs[VJP_KEY_ATTR] = key
         block.append_op(
             "grad_custom" if use_custom else "grad",
             inputs=grad_inputs,
             outputs=grad_outputs,
-            attrs={
-                "fwd_type": op.type,
-                "fwd_attrs": dict(op.attrs),
-                "in_slots": {slot: len(names) for slot, names in op.inputs.items()},
-                "out_slots": out_slots,
-                "og": og_mask,
-                "diff": diff_mask,
-            },
+            attrs=grad_attrs,
         )
         i -= 1
 

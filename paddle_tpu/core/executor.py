@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import backward
 from . import program as prog_mod
 from .enforce import EnforceError, op_error
 from .program import Program, RNG_VAR
@@ -247,11 +248,11 @@ class _Compiled:
     __slots__ = ("fn", "raw_fn", "feed_names", "ro_state_names",
                  "rw_state_names", "out_state_names", "uses_rng",
                  "feed_shardings", "ro_shardings", "rw_shardings",
-                 "aot", "source")
+                 "paired_vjp_ops", "aot", "source")
 
     def __init__(self, fn, raw_fn, feed_names, ro_state_names, rw_state_names,
                  out_state_names, uses_rng, feed_shardings=None,
-                 ro_shardings=None, rw_shardings=None):
+                 ro_shardings=None, rw_shardings=None, paired_vjp_ops=0):
         self.fn = fn
         self.raw_fn = raw_fn
         self.feed_names = feed_names
@@ -262,6 +263,7 @@ class _Compiled:
         self.feed_shardings = feed_shardings
         self.ro_shardings = ro_shardings
         self.rw_shardings = rw_shardings
+        self.paired_vjp_ops = paired_vjp_ops
         self.aot = None
         self.source = None
 
@@ -386,14 +388,19 @@ class Executor:
 
     def cache_stats(self) -> Dict[str, int]:
         """{'hits', 'misses', 'entries', 'persistent_hits',
-        'fresh_compiles'} of the (program, shapes) -> compiled-executable
-        cache. ``misses`` split into disk restores (persistent_hits) and
-        real compiles (fresh_compiles); a manifest+cache-warm boot shows
-        fresh_compiles == 0."""
+        'fresh_compiles', 'paired_vjp_ops'} of the (program, shapes) ->
+        compiled-executable cache. ``misses`` split into disk restores
+        (persistent_hits) and real compiles (fresh_compiles); a
+        manifest+cache-warm boot shows fresh_compiles == 0.
+        ``paired_vjp_ops`` counts, over the cached blocks, the
+        loop-bearing forward ops traced once for forward and backward
+        (backward.traced_once)."""
         return {"hits": self.cache_hits, "misses": self.cache_misses,
                 "entries": len(self._cache),
                 "persistent_hits": self.persistent_hits,
-                "fresh_compiles": self.fresh_compiles}
+                "fresh_compiles": self.fresh_compiles,
+                "paired_vjp_ops": sum(c.paired_vjp_ops
+                                      for c in self._cache.values())}
 
     def device(self):
         """The device this executor computes on when no mesh is in play
@@ -661,7 +668,8 @@ class Executor:
             compiled.aot, how = self._aot_compile(compiled.fn, args)
         compiled.source = "persistent" if how["restored"] else "fresh"
         if span is not None:
-            span.set_attrs(source=compiled.source, **how)
+            span.set_attrs(source=compiled.source,
+                           paired_vjp_ops=compiled.paired_vjp_ops, **how)
 
     def _check_state_device(self, compiled: "_Compiled",
                             scope: Scope) -> None:
@@ -750,6 +758,26 @@ class Executor:
             return [self._fetch_numpy(densify(v)) for v in fetches]
         return list(fetches)
 
+    def _call_op(self, op, opdef, ins, env, rng, vjp_pairs,
+                 program: Program, scope: Scope):
+        """One op of a block, traced or eager -> (outs, rng). A forward op
+        paired with a grad op in this block (``vjp_pairs``) runs once under
+        ``jax.vjp`` and the grad op applies the closure kept in ``env``."""
+        pair = vjp_pairs.get(op.attrs.get(backward.VJP_KEY_ATTR))
+        if pair is not None:
+            if op.type == "grad":
+                return backward.grad_kept(op.attrs, ins, env), rng
+            return backward.traced_once(op, ins, pair, env), rng
+        if opdef.special:
+            return opdef.fn(op.attrs, ins, executor=self, env=env, op=op,
+                            program=program, scope=scope), rng
+        if op_uses_rng(opdef, op.attrs):
+            rng, sub = jax.random.split(rng)
+            return opdef.fn(op.attrs, ins, rng=sub), rng
+        if callable(opdef.needs_rng):
+            return opdef.fn(op.attrs, ins, rng=None), rng
+        return opdef.fn(op.attrs, ins), rng
+
     # ------------------------------------------------------------------
     def _run_interpreted(self, program: Program, feed_vals, fetch_names,
                          scope: Scope, return_numpy: bool = True):
@@ -764,6 +792,7 @@ class Executor:
         compiled path; never use it for serving traffic."""
         block = program.global_block
         ops = list(block.ops)
+        vjp_pairs = backward.vjp_pairs(ops)
         env: Dict[str, Any] = dict(feed_vals)
         state_read: set = set()
         rng = None
@@ -771,7 +800,8 @@ class Executor:
         if uses_rng:
             rng = self._rng_state(program, scope)
         with trace.span("executor/interpret", ops=len(ops),
-                        feeds=len(feed_vals), fetches=len(fetch_names)):
+                        feeds=len(feed_vals), fetches=len(fetch_names),
+                        paired_vjp_ops=len(vjp_pairs)):
             for op_index, op in enumerate(ops):
                 opdef = get_op(op.type)
                 ins = {}
@@ -798,17 +828,8 @@ class Executor:
                     ins[slot] = vals
                 t0 = time.perf_counter()
                 try:
-                    if opdef.special:
-                        outs = opdef.fn(op.attrs, ins, executor=self,
-                                        env=env, op=op, program=program,
-                                        scope=scope)
-                    elif op_uses_rng(opdef, op.attrs):
-                        rng, sub = jax.random.split(rng)
-                        outs = opdef.fn(op.attrs, ins, rng=sub)
-                    elif callable(opdef.needs_rng):
-                        outs = opdef.fn(op.attrs, ins, rng=None)
-                    else:
-                        outs = opdef.fn(op.attrs, ins)
+                    outs, rng = self._call_op(op, opdef, ins, env, rng,
+                                              vjp_pairs, program, scope)
                 except EnforceError:
                     raise
                 except Exception as exc:
@@ -1073,6 +1094,7 @@ class Executor:
         ro_state = [n for n in state_names if n not in written_set]
 
         ops = list(block.ops)
+        vjp_pairs = backward.vjp_pairs(ops)
         mesh, plan = self._mesh_plan_for(program)
 
         def run_traced(feed_args, ro_args, rw_args, rng=None):
@@ -1094,16 +1116,8 @@ class Executor:
                     if names
                 }
                 try:
-                    if opdef.special:
-                        outs = opdef.fn(op.attrs, ins, executor=self, env=env,
-                                        op=op, program=program, scope=scope)
-                    elif op_uses_rng(opdef, op.attrs):
-                        rng, sub = jax.random.split(rng)
-                        outs = opdef.fn(op.attrs, ins, rng=sub)
-                    elif callable(opdef.needs_rng):
-                        outs = opdef.fn(op.attrs, ins, rng=None)
-                    else:
-                        outs = opdef.fn(op.attrs, ins)
+                    outs, rng = self._call_op(op, opdef, ins, env, rng,
+                                              vjp_pairs, program, scope)
                 except EnforceError:
                     raise  # already carries op context (nested blocks)
                 except Exception as exc:
@@ -1197,7 +1211,8 @@ class Executor:
             len(ops), len(feed_names), len(state_names), len(fetch_names),
         )
         return _Compiled(jitted, run_traced, feed_names, ro_state, rw_state,
-                         written_persist, uses_rng, feed_sh, ro_sh, rw_sh)
+                         written_persist, uses_rng, feed_sh, ro_sh, rw_sh,
+                         paired_vjp_ops=len(vjp_pairs))
 
     def close(self):
         self._cache.clear()

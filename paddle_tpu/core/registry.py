@@ -11,10 +11,13 @@ from the kernel itself via ``jax.eval_shape`` — one source of truth.
 
 Gradients: ops normally do NOT register hand-written grad kernels. The
 backward pass (core/backward.py) emits generic ``grad`` ops whose kernel
-computes ``jax.vjp`` of the registered forward. Recomputed forward
-subexpressions are CSE'd by XLA inside the single fused computation, so this
-costs nothing relative to hand-written grad ops. Ops may still register a
-custom ``grad_fn`` when vjp-of-forward is wrong or wasteful (e.g. ops with
+computes ``jax.vjp`` of the registered forward. For a straight-line kernel
+the forward subexpressions that the vjp traces again are CSE'd by XLA inside
+the single fused computation, so this costs nothing relative to hand-written
+grad ops. XLA does not merge two loops, so an op whose kernel holds one
+registers ``has_loop=True``: the executor then traces it once, under
+``jax.vjp``, and its grad op applies the kept closure. Ops may still register
+a custom ``grad_fn`` when vjp-of-forward is wrong or wasteful (e.g. ops with
 integer inputs that need SelectedRows-style sparse grads).
 """
 from __future__ import annotations
@@ -49,6 +52,11 @@ class OpDef:
     # Ops whose semantics are stateful/structural and are handled specially by
     # the executor trace (feed/fetch/control-flow) rather than called as fns.
     special: bool = False
+    # The kernel holds a lax.scan / while_loop / fori_loop. XLA cannot CSE
+    # a second trace of a loop, so when append_backward pairs this op with
+    # a generic ``grad`` op the executor traces it once under jax.vjp and
+    # the grad op applies the kept closure (backward.traced_once).
+    has_loop: bool = False
     # Input slots that may legally be absent/empty (e.g. optional Bias).
     optional_inputs: tuple = ()
     # If set, only these input slots get gradients even if others are float.
@@ -74,6 +82,7 @@ def register_op(
     grad_fn: Callable = None,
     grad_fn_is_optimization: bool = False,
     special: bool = False,
+    has_loop: bool = False,
     optional_inputs=(),
     stop_gradient_inputs=(),
 ):
@@ -89,6 +98,7 @@ def register_op(
             grad_fn=grad_fn,
             grad_fn_is_optimization=grad_fn_is_optimization,
             special=special,
+            has_loop=has_loop,
             optional_inputs=tuple(optional_inputs),
             stop_gradient_inputs=tuple(stop_gradient_inputs),
         )

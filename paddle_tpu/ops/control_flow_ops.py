@@ -52,7 +52,7 @@ def run_body(body_ops: List[dict], env: Dict[str, jax.Array]) -> Dict:
     return env
 
 
-@register_op("static_rnn",
+@register_op("static_rnn", has_loop=True,
              optional_inputs=("X", "MemInit", "Param", "Length"))
 def static_rnn(attrs, ins):
     """User-defined recurrence over the time axis (recurrent_op.cc:222).
@@ -116,7 +116,7 @@ def static_rnn(attrs, ins):
     return {"Out": outputs, "LastMem": list(carry)}
 
 
-@register_op("while", optional_inputs=("Param",))
+@register_op("while", has_loop=True, optional_inputs=("Param",))
 def while_op(attrs, ins):
     """Functional while (while_op.cc): body runs until the carried cond var
     is false. Carried vars are the loop state; the body must reassign each

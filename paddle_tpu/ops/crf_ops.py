@@ -31,7 +31,8 @@ def _split_transition(trans):
     return trans[0], trans[1], trans[2:]  # start [T], end [T], w [T, T]
 
 
-@register_op("linear_chain_crf", optional_inputs=("Length",))
+@register_op("linear_chain_crf", has_loop=True,
+             optional_inputs=("Length",))
 def linear_chain_crf(attrs, ins):
     """Negative log-likelihood of tag paths under a linear-chain CRF.
 

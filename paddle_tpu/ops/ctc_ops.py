@@ -29,7 +29,8 @@ def _log_softmax(x):
     return x - jax.scipy.special.logsumexp(x, axis=-1, keepdims=True)
 
 
-@register_op("warpctc", optional_inputs=("LogitsLength", "LabelLength"))
+@register_op("warpctc", has_loop=True,
+             optional_inputs=("LogitsLength", "LabelLength"))
 def warpctc(attrs, ins):
     """CTC loss per sequence.
 

@@ -168,7 +168,7 @@ def _aux_loss(blk, stats, n_experts):
     return n_experts * jnp.sum(jax.lax.stop_gradient(f) * prob_mean)
 
 
-@register_op("pipelined_transformer_stack",
+@register_op("pipelined_transformer_stack", has_loop=True,
              optional_inputs=OPTIONAL_STACK_SLOTS)
 def pipelined_transformer_stack(attrs, ins):
     """X [b, T, d] + stacked block weights (leading dim L) -> Out [b, T, d]
@@ -201,7 +201,10 @@ def pipelined_transformer_stack(attrs, ins):
             # (qkv/attn-out/ctx/ffn-hidden) resident and recompute only
             # the cheap elementwise/LN work in the backward — the
             # all-or-nothing form re-runs every forward matmul per layer
-            # (measured 30.0% vs 48.1% per-layer MFU at d1024, PERF.md).
+            # (one stack forward of the step's four, PERF.md section 5;
+            # the "30.0% vs 48.1% per-layer MFU" once quoted here came
+            # from before the benchmark, off a step that also ran the
+            # forward scan twice). Never run on a chip: ROADMAP S5.
             body = jax.checkpoint(
                 body, policy=jax.checkpoint_policies.save_only_these_names(
                     "qkv_proj", "attn_ctx", "attn_out", "ffn_hidden"))

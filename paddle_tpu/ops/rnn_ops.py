@@ -64,7 +64,8 @@ def _lstm_step(h, c, gates, bias, peep, act_g, act_cand, act_cell):
     return h_new, c_new
 
 
-@register_op("lstm", optional_inputs=("Bias", "H0", "C0", "Length"))
+@register_op("lstm", has_loop=True,
+             optional_inputs=("Bias", "H0", "C0", "Length"))
 def lstm(attrs, ins):
     """Full LSTM scan (reference lstm_op.cc `dynamic_lstm`).
 
@@ -123,7 +124,8 @@ def lstm(attrs, ins):
     return out(Hidden=hidden, Cell=cell, LastH=h, LastC=c)
 
 
-@register_op("gru", optional_inputs=("Bias", "H0", "Length"))
+@register_op("gru", has_loop=True,
+             optional_inputs=("Bias", "H0", "Length"))
 def gru(attrs, ins):
     """Full GRU scan (reference gru_op.cc `dynamic_gru`).
 
@@ -175,7 +177,8 @@ def gru(attrs, ins):
     return out(Hidden=jnp.swapaxes(ys, 0, 1), LastH=h)
 
 
-@register_op("simple_rnn", optional_inputs=("Bias", "H0", "Length"))
+@register_op("simple_rnn", has_loop=True,
+             optional_inputs=("Bias", "H0", "Length"))
 def simple_rnn(attrs, ins):
     """Plain recurrent layer (reference gserver RecurrentLayer.cpp, the v1
     ``recurrent_layer``): out_t = act(in_t + out_{t-1} @ W + b). ``Input``

@@ -270,7 +270,7 @@ def transformer_stack_cross_decode(attrs, ins, rng=None):
     return _maybe_topk(attrs, ins, logits, outs)
 
 
-@register_op("transformer_encdec_teacher",
+@register_op("transformer_encdec_teacher", has_loop=True,
              optional_inputs=("SrcPosEmb", "PosEmb"))
 def transformer_encdec_teacher(attrs, ins):
     """Teacher-forced encoder-decoder forward: the NMT TRAINING (and

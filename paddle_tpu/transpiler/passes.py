@@ -38,6 +38,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..core.backward import drop_unpaired_keys
 from ..core.program import Block, Operator, Program
 from ..core.registry import get_op, has_op, op_uses_rng
 from .framework import Pass, PassContext, register_pass
@@ -175,6 +176,10 @@ class DeadOpElimination(Pass):
         keep.reverse()
         if len(keep) != len(block.ops):
             block.ops = keep
+            program._bump()
+        # a forward op whose paired grad op went (a train program pruned
+        # to its forward) carries no dangling pair key into the artifact
+        if drop_unpaired_keys(block.ops):
             program._bump()
         _drop_unused_vars(program, ctx)
 

@@ -255,6 +255,56 @@ def test_decode_step_on_the_v5e_keeps_the_pool_the_scan_carry(one_chip,
         "fusion", "fusion", "scatter", "scatter"]
 
 
+def test_stacked_train_step_on_the_v5e_runs_its_forward_scan_once(
+        one_chip, monkeypatch):
+    """The stacked LM's whole train step (GPT-2 medium's width, heads and
+    context; two layers) compiled for the chip through the Executor: ONE
+    forward scan and one backward scan, and a layer's Mosaic calls are two
+    ``flash_fwd`` (forward, remat recompute), one ``flash_dq``, one
+    ``flash_dkv``. With the generic grad op tracing the stack a second
+    time (before core/backward.py paired them) the chip's compiler kept
+    three loops and three ``flash_fwd``: XLA does not merge two loops.
+    (Lives here because one file a worker may describe the topology.)"""
+    import paddle_tpu as pt
+    from paddle_tpu import layers, models
+
+    T, V, B = 1024, 512, 2
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        ids = layers.data("ids", shape=[T], dtype="int64")
+        tgt = layers.data("tgt", shape=[T], dtype="int64")
+        logits = models.transformer_lm(
+            ids, vocab_size=V, d_model=1024, n_layers=2, num_heads=16,
+            max_len=T, pipeline_stack=True, remat=True)
+        loss = layers.mean(layers.softmax_with_cross_entropy(
+            layers.reshape(logits, shape=[-1, V]),
+            layers.reshape(tgt, shape=[-1, 1])))
+        pt.optimizer.AdamOptimizer(learning_rate=1e-4).minimize(
+            loss, startup_program=startup)
+    scope = pt.Scope()
+    for name, v in main.global_block.vars.items():
+        if v.persistable:
+            scope.set(name, jax.ShapeDtypeStruct(
+                tuple(v.shape), np.dtype(str(v.dtype)), sharding=one_chip))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # ... which would also switch the TPU's on-disk compile cache on for
+    # the rest of this worker's tests
+    monkeypatch.setattr("paddle_tpu.core.executor."
+                        "_maybe_enable_compilation_cache", lambda: None)
+    exe = pt.Executor(pt.TPUPlace())
+    assert exe.warm_signature(main, {"ids": ((B, T), "int64"),
+                                     "tgt": ((B, T), "int64")},
+                              [loss.name], scope=scope)
+    (compiled,) = exe._cache.values()
+    text = compiled.aot.as_text()
+    assert len(re.findall(r"= .* while\(", text)) == 2
+    calls = re.findall(
+        r'%(\w+?)\.\d+ = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert sorted(calls) == ["flash_dkv", "flash_dq", "flash_fwd",
+                             "flash_fwd"]
+    assert exe.cache_stats()["paired_vjp_ops"] == 1
+
+
 # ---------------------------------------------------------------------------
 # the dispatch rule of _scan_paged_layers
 # ---------------------------------------------------------------------------
