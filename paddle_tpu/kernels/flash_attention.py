@@ -86,7 +86,7 @@ def rotary(x, pos0=0, base=10000.0, pairing="interleaved"):
 
 
 def reference_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
-                        q_pos0=0):
+                        q_pos0=0, k_pos0=None, window=None):
     """Pure-jnp attention over [B, H, T, D]; the semantic ground truth.
 
     K/V may carry Hkv < H head planes (grouped-query attention, query
@@ -98,7 +98,13 @@ def reference_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
     a window of w queries starting at cache position p attends key j iff
     j <= p + i (the block-causal mask incremental verify needs). It may
     be a [B] array of PER-ROW offsets (the paged chunked-prefill path,
-    where every batch row resumes at its own context length)."""
+    where every batch row resumes at its own context length).
+
+    ``k_pos0`` [B]: key j of row b sits at GLOBAL position k_pos0[b] + j
+    (the keys are a slice of the context: a window layer's gathered
+    pages). ``window``: a query at position i sees key position j only
+    while i - j < window; under ``lengths`` the query is the row's last
+    position, lengths - 1."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     H, Hkv = q.shape[1], k.shape[1]
@@ -115,6 +121,24 @@ def reference_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
         s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                        preferred_element_type=jnp.float32) * sm_scale
     T = q.shape[2], k.shape[2]
+    if k_pos0 is not None or window is not None:
+        B = q.shape[0]
+        kj = jnp.arange(T[1])[None, :] + (
+            0 if k_pos0 is None else jnp.asarray(k_pos0).reshape(-1, 1))
+        kj = jnp.broadcast_to(kj, (B, T[1]))[:, None, None, :]
+        keep = jnp.ones((B, 1, T[0], T[1]), bool)
+        if causal:
+            qi = (jnp.asarray(q_pos0).reshape(-1, 1)
+                  + jnp.arange(T[0])[None, :])[:, None, :, None]
+            keep = keep & (qi >= kj)
+            if window is not None:
+                keep = keep & (qi - kj < window)
+        if lengths is not None:
+            keep = keep & (kj < lengths[:, None, None, None])
+            if window is not None:
+                keep = keep & (kj >= lengths[:, None, None, None] - window)
+        s = jnp.where(keep, s, -jnp.inf)
+        causal, lengths = False, None
     if causal:
         p0 = jnp.asarray(q_pos0)
         if p0.ndim:  # per-row offsets: [B] -> mask [B, 1, Tq, Tk]

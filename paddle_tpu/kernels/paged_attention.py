@@ -29,8 +29,21 @@ reads only the pages a row HOLDS, straight from the whole pool:
   before P @ V, float32 accumulation, keys j < length only. A row's result
   depends on that row's pages alone, walked in table order.
 
+* grouped queries (H = G * Hkv query heads over Hkv cached heads): the
+  page tile stays [ps, Hkv*dh] — G times narrower than the queries — and
+  the block-diagonal query is [H_pad, Hkv*dh] with row n in the columns of
+  KV head n // G, handed in already expanded (a [b, H_pad, Hkv*dh] operand,
+  a few KB a row beside the pages). Row n's context sits in acc[n, (n //
+  G)*dh ..]; G rows share a column block, so the result leaves as [G,
+  Hkv*dh] — row g holding heads g, G+g, 2G+g, .. side by side, selected by
+  one 0/1 matmul — and the wrapper puts the heads back in order.
+* a window (``window=w``, static): a row of ``length`` keys attends keys
+  ``length - w <= j < length`` only; its page walk STARTS at page
+  ``max(0, length - w) // ps`` (pages behind it may be gone from the
+  table) and masks inside that first page.
+
 The jnp gather + ``reference_attention`` stays the semantic ground truth
-and the path of every other shape (t > 1, CPU, GQA, unaligned widths):
+and the path of every other shape (t > 1, CPU, unaligned widths):
 ``supported`` is the whole dispatch rule, read off the operands.
 """
 from __future__ import annotations
@@ -54,20 +67,20 @@ def _sublane_tile(dtype) -> int:
 
 def supported(q_width: int, pool, t: int) -> bool:
     """Whether the decode kernel can take this call: one query token a
-    row, a TPU backend, as many query heads as cached heads (a query row
-    of ``q_width`` = H*dh floats against a pool [L, N, ps, Hkv*dh] of the
-    same width), a lane-aligned row and a page of whole sublane tiles.
-    Everything here is a shape, a dtype or the backend: nothing names a
-    model."""
+    row, a TPU backend, whole groups of query heads over the cached heads
+    (a query row of ``q_width`` = H*dh floats against a pool [L, N, ps,
+    Hkv*dh]: H a multiple of Hkv), a lane-aligned row and a page of whole
+    sublane tiles. Everything here is a shape, a dtype or the backend:
+    nothing names a model."""
     ps, width = pool.shape[2:]
     return (t == 1 and jax.default_backend() == "tpu"
-            and q_width == width and width % 128 == 0
+            and q_width % width == 0 and width % 128 == 0
             and ps % _sublane_tile(pool.dtype) == 0)
 
 
 def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                    kbuf, vbuf, sems, cur_ref, m_ref, l_ref, acc_ref, *,
-                   d_head, pmax):
+                   d_head, pmax, group=1, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -79,6 +92,14 @@ def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     layer = jnp.clip(layer_ref[0], 0, k_hbm.shape[0] - 1)
     length = len_ref[s]
     n_pages = jnp.clip((length + ps - 1) // ps, 1, pmax)
+
+    def first_page(row):
+        """Where row ``row``'s walk starts: 0, or the window's page."""
+        if window is None:
+            return 0
+        return jnp.maximum(len_ref[row] - window, 0) // ps
+
+    page0 = first_page(s)
 
     def page_copies(buf, row, i):
         page = jnp.clip(table_ref[row * pmax + i], 0, k_hbm.shape[1] - 1)
@@ -94,7 +115,7 @@ def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     @pl.when(s == 0)
     def _():
         cur_ref[0] = 0
-        start(0, 0, 0)
+        start(0, 0, page0)
 
     buf0 = cur_ref[0]
     # float32 pages are multiplied as float32 (the MXU's default is one
@@ -108,15 +129,23 @@ def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     shape = acc_ref.shape                                       # [hp, width]
     row_id = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     col_id = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    own = (col_id >= row_id * d_head) & (col_id < (row_id + 1) * d_head)
-    q_bd = jnp.where(own, q_ref[0].astype(jnp.float32), 0.0).astype(
-        q_ref.dtype)
+    # the cached head a query row reads: n // group, by comparisons (a
+    # padding row past H lands past the last head or on zero queries)
+    kv_id = row_id if group == 1 else sum(
+        (row_id >= j * group).astype(jnp.int32)
+        for j in range(1, width // d_head))
+    own = (col_id >= kv_id * d_head) & (col_id < (kv_id + 1) * d_head)
+    if group == 1:
+        q_bd = jnp.where(own, q_ref[0].astype(jnp.float32), 0.0).astype(
+            q_ref.dtype)
+    else:
+        q_bd = q_ref[0]                     # expanded by the wrapper
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def page(i, _):
-        buf = (buf0 + i) % 2
+        buf = (buf0 + i - page0) % 2
 
         @pl.when(i + 1 < n_pages)
         def _():
@@ -124,7 +153,7 @@ def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         @pl.when((i + 1 == n_pages) & (s + 1 < rows))
         def _():
-            start(1 - buf, s + 1, 0)
+            start(1 - buf, s + 1, first_page(jnp.minimum(s + 1, rows - 1)))
 
         for c in page_copies(buf, s, i):
             c.wait()
@@ -134,7 +163,10 @@ def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
             precision=precision,
             preferred_element_type=jnp.float32) * sm_scale      # [hp, ps]
         key = i * ps + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        sc = jnp.where(key < length, sc, -jnp.inf)
+        seen = key < length
+        if window is not None:
+            seen = seen & (key >= length - window)
+        sc = jnp.where(seen, sc, -jnp.inf)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
         # a row with no key yet (length 0) keeps exp() off inf - inf
@@ -150,22 +182,40 @@ def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         m_ref[...] = m_new
         return 0
 
-    jax.lax.fori_loop(0, n_pages, page, 0)
-    cur_ref[0] = (buf0 + n_pages) % 2
+    jax.lax.fori_loop(page0, n_pages, page, 0)
+    cur_ref[0] = (buf0 + n_pages - page0) % 2
     # each row's own dh columns, normalised; a row without a key has
     # accumulated nothing and stays zero
     l = l_ref[...]
     ctx = jnp.where(own, acc_ref[...] / jnp.where(l > 0, l, 1.0), 0.0)
-    o_ref[0] = jnp.sum(ctx, axis=0, keepdims=True).astype(o_ref.dtype)
+    if group == 1:
+        o_ref[0] = jnp.sum(ctx, axis=0, keepdims=True).astype(o_ref.dtype)
+    else:
+        # G rows share a column block: out[g] = the sum of rows g, G+g,
+        # 2G+g, .. (one per cached head), a 0/1 selection done as an exact
+        # float32 matmul [G_pad, hp] x [hp, width]
+        gp, hp = o_ref.shape[1], shape[0]
+        g_id = jax.lax.broadcasted_iota(jnp.int32, (gp, hp), 0)
+        r_id = jax.lax.broadcasted_iota(jnp.int32, (gp, hp), 1)
+        sel = (g_id < group) & functools.reduce(
+            jnp.logical_or, [r_id == j * group + g_id
+                             for j in range(width // d_head)])
+        o_ref[0] = jax.lax.dot_general(
+            sel.astype(jnp.float32), ctx,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def paged_attention_decode(q, cache_k, cache_v, layer, table, lengths,
-                           interpret=False):
+                           interpret=False, window=None):
     """Attention of one query token a row over the pages the row holds.
 
     q [b, H, dh] (cast to the pools' dtype), cache_k / cache_v the WHOLE
-    pools [L, N, ps, H*dh], layer a scalar int32, table [b, P] int32,
-    lengths [b] int32 (keys j < length attend; 0 gives a zero row) -> the
+    pools [L, N, ps, Hkv*dh] (H a multiple of Hkv: query head n reads
+    cached head n // (H/Hkv)), layer a scalar int32, table [b, P] int32,
+    lengths [b] int32 (keys j < length attend; 0 gives a zero row),
+    ``window`` (static) keeps keys ``j >= length - window`` only -> the
     context [b, H*dh] in the pools' dtype, a token's heads side by side
     as the out-projection reads them."""
     from jax.experimental import pallas as pl
@@ -175,22 +225,38 @@ def paged_attention_decode(q, cache_k, cache_v, layer, table, lengths,
         raise ValueError(f"q must be [b, H, dh], got {q.shape}")
     b, heads, d_head = q.shape
     ps, width = cache_k.shape[2:]
-    if heads * d_head != width or cache_v.shape != cache_k.shape:
+    if (heads * d_head) % width or width % d_head \
+            or cache_v.shape != cache_k.shape:
         raise ValueError(f"q {q.shape} does not match the pools "
                          f"{cache_k.shape} / {cache_v.shape}")
+    kv_heads = width // d_head
+    group = heads // kv_heads
     pmax = table.shape[1]
     hp = -(-heads // _ROW_TILE) * _ROW_TILE
     dtype = cache_k.dtype
-    kernel = functools.partial(_decode_kernel, d_head=d_head, pmax=pmax)
+    if group == 1:
+        q_in, q_rows, out_rows = q.reshape(b, 1, width).astype(dtype), 1, 1
+    else:
+        # the block-diagonal query [b, H_pad, Hkv*dh]: row n = q_n in the
+        # columns of cached head n // group, zeros elsewhere
+        own = (jnp.arange(heads)[:, None] // group
+               == jnp.arange(kv_heads)[None, :])
+        q_in = jnp.where(own[None, :, :, None], q.astype(dtype)[:, :, None],
+                         jnp.zeros((), dtype)).reshape(b, heads, width)
+        q_in = jnp.pad(q_in, ((0, 0), (0, hp - heads), (0, 0)))
+        q_rows, out_rows = hp, -(-group // 8) * 8
+    kernel = functools.partial(_decode_kernel, d_head=d_head, pmax=pmax,
+                               group=group, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # layer, the flattened table, lengths
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, 1, width), lambda s, *_: (s, 0, 0)),
+            pl.BlockSpec((1, q_rows, width), lambda s, *_: (s, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, width), lambda s, *_: (s, 0, 0)),
+        out_specs=pl.BlockSpec((1, out_rows, width),
+                               lambda s, *_: (s, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, ps, width), dtype),      # K pages, two in flight
             pltpu.VMEM((2, ps, width), dtype),      # V pages
@@ -204,7 +270,7 @@ def paged_attention_decode(q, cache_k, cache_v, layer, table, lengths,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, width), dtype),
+        out_shape=jax.ShapeDtypeStruct((b, out_rows, width), dtype),
         # the (row, page) chain carries its DMA from one grid step to the
         # next: the steps run in order on one core
         compiler_params=pltpu.CompilerParams(
@@ -213,5 +279,9 @@ def paged_attention_decode(q, cache_k, cache_v, layer, table, lengths,
         name="paged_attention_decode",
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
       table.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
-      q.reshape(b, 1, width).astype(dtype), cache_k, cache_v)
-    return out.reshape(b, width)
+      q_in, cache_k, cache_v)
+    if group == 1:
+        return out.reshape(b, width)
+    # out[b, g, j*dh..] is head j*group + g: back to head order
+    return out[:, :group].reshape(b, group, kv_heads, d_head).transpose(
+        0, 2, 1, 3).reshape(b, heads * d_head)

@@ -16,6 +16,13 @@ Two levels:
   program it was.
 - :class:`LMSpec` — a Block plus the sizes and the parameter dtype.
 
+Layers of one stack may differ in KIND (``layer_pattern``): one period of
+the pattern names, position by position, the attention each layer runs
+(``full`` | ``window`` of ``window`` keys) and whether it rotates q / k
+(``rope`` | ``nope``); the stack repeats the period. The serving cache is
+then held BY KIND (serving/generation.py): full-attention layers keep every
+token, window layers the pages the window can still reach.
+
 Selection between blocks is made from the spec and nothing else: no flag,
 no environment variable.
 """
@@ -27,12 +34,17 @@ from typing import Dict, List, Optional, Tuple
 NORMS = ("layer_norm", "rms_norm")
 FFNS = ("gelu_mlp", "swiglu_moe")
 ROPE_PAIRINGS = ("interleaved", "half")
+#: what one position of a ``layer_pattern`` period may say
+LAYER_KINDS = ("full+rope", "full+nope", "window+rope", "window+nope")
+EXPERT_ACTS = ("silu", "relu")          # SwiGLU | ReGLU
+ROUTER_INPUTS = ("post_attn_norm", "attn_input")
 
 
 class BlockNotSupportedError(NotImplementedError):
     """An op or engine that still hard-codes the GPT-2 block was handed
     another spec (beam search, the seq2seq family, a ``pp`` pipeline over
-    MoE layers)."""
+    MoE layers), or one that knows a single layer kind was handed a
+    ``layer_pattern`` (training beyond the window, the slot handoff)."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,8 +63,38 @@ class Block:
     norm_topk_prob: bool = False
     bias: bool = True                   # norm and FFN biases
     page_dtype: str = "float32"
+    head_dim: Optional[int] = None      # None: d_model // num_heads
+    # one period of layer kinds (LAYER_KINDS), repeated down the stack;
+    # None: every layer full attention, positions as ``use_rope`` says
+    layer_pattern: Optional[Tuple[str, ...]] = None
+    window: int = 0                     # keys a window layer sees: 0 <= i - j < window
+    expert_act: str = "silu"            # act(x W_gate) * (x W_up)
+    router_input: str = "post_attn_norm"  # | "attn_input": norm 1's output
 
     def __post_init__(self):
+        if self.layer_pattern is not None:
+            # a saved program hands the pattern back as a list
+            object.__setattr__(self, "layer_pattern",
+                               tuple(self.layer_pattern))
+            bad = [k for k in self.layer_pattern if k not in LAYER_KINDS]
+            if bad or not self.layer_pattern:
+                raise ValueError(f"layer_pattern {self.layer_pattern!r}: "
+                                 f"each entry is one of {LAYER_KINDS}")
+            if not self.use_rope:
+                raise ValueError("a layer_pattern names each layer's "
+                                 "positions (rope | nope): there is no "
+                                 "learned table, pass use_rope=True")
+            if self.has_window and self.window < 1:
+                raise ValueError("a window layer needs window >= 1")
+            if not any(k.startswith("full") for k in self.layer_pattern):
+                raise ValueError("a layer_pattern needs a full-attention "
+                                 "layer (the cache's first kind)")
+        if self.expert_act not in EXPERT_ACTS:
+            raise ValueError(f"expert_act {self.expert_act!r} not in "
+                             f"{EXPERT_ACTS}")
+        if self.router_input not in ROUTER_INPUTS:
+            raise ValueError(f"router_input {self.router_input!r} not in "
+                             f"{ROUTER_INPUTS}")
         if self.norm not in NORMS:
             raise ValueError(f"norm {self.norm!r} not in {NORMS}")
         if self.ffn not in FFNS:
@@ -73,7 +115,8 @@ class Block:
         for f in dataclasses.fields(self):
             if f.name not in self._LEGACY and \
                     getattr(self, f.name) != f.default:
-                out[f.name] = getattr(self, f.name)
+                v = getattr(self, f.name)
+                out[f.name] = list(v) if isinstance(v, tuple) else v
         return out
 
     @classmethod
@@ -90,6 +133,32 @@ class Block:
     def is_moe(self) -> bool:
         return self.ffn == "swiglu_moe"
 
+    def dh(self, d_model: int) -> int:
+        """Width of one head for a ``d_model``-wide stream."""
+        return self.head_dim or d_model // self.num_heads
+
+    # -- layer kinds -------------------------------------------------------
+    @property
+    def kinds(self) -> Optional[Tuple[Tuple[bool, bool], ...]]:
+        """(windowed, rotates) of every position of a period; None for a
+        stack of one kind."""
+        if self.layer_pattern is None:
+            return None
+        return tuple((k.startswith("window"), k.endswith("+rope"))
+                     for k in self.layer_pattern)
+
+    @property
+    def has_window(self) -> bool:
+        return any(k.startswith("window") for k in self.layer_pattern or ())
+
+    def require_one_kind(self, who: str) -> None:
+        if self.layer_pattern is not None:
+            raise BlockNotSupportedError(
+                f"{who} knows one kind of layer and one page table; this "
+                f"spec's layers differ ({list(self.layer_pattern)}, window "
+                f"{self.window}): the train op (T <= window), the one-shot "
+                "generate op and the paged prefill / decode ops run it")
+
     @property
     def is_gpt2(self) -> bool:
         """The block the not-yet-converted ops hard-code."""
@@ -97,7 +166,8 @@ class Block:
                 and self.bias and not self.qk_norm
                 and self.rope_pairing == "interleaved"
                 and self.rope_theta == 10000.0
-                and self.page_dtype == "float32")
+                and self.page_dtype == "float32"
+                and self.head_dim is None and self.layer_pattern is None)
 
     def require_gpt2(self, who: str) -> None:
         if not self.is_gpt2:
@@ -173,11 +243,25 @@ class LMSpec:
     bias: bool = True
     param_dtype: str = "float32"
     page_dtype: str = "float32"
+    head_dim: Optional[int] = None      # None: d_model // num_heads
+    layer_pattern: Optional[Tuple[str, ...]] = None
+    window: int = 0
+    expert_act: str = "silu"
+    router_input: str = "post_attn_norm"
 
     def __post_init__(self):
-        if self.d_model % self.num_heads:
-            raise ValueError(f"d_model {self.d_model} not divisible by "
-                             f"heads {self.num_heads}")
+        if self.head_dim is None:
+            if self.d_model % self.num_heads:
+                raise ValueError(
+                    f"d_model {self.d_model} not divisible by heads "
+                    f"{self.num_heads}: pass head_dim")
+            self.head_dim = self.d_model // self.num_heads
+        if self.layer_pattern is not None:
+            self.layer_pattern = tuple(self.layer_pattern)
+            if self.n_layers % len(self.layer_pattern):
+                raise ValueError(
+                    f"{self.n_layers} layers are not whole periods of "
+                    f"{len(self.layer_pattern)}")
         if self.num_kv_heads and self.num_heads % self.num_kv_heads:
             raise ValueError(f"num_heads {self.num_heads} not a multiple "
                              f"of num_kv_heads {self.num_kv_heads}")
@@ -193,15 +277,22 @@ class LMSpec:
     @property
     def block(self) -> Block:
         names = {f.name for f in dataclasses.fields(Block)}
-        return Block(**{k: getattr(self, k) for k in names})
+        kw = {k: getattr(self, k) for k in names}
+        if self.head_dim * self.num_heads == self.d_model:
+            kw["head_dim"] = None       # the attrs a program always had
+        return Block(**kw)
 
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
 
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.num_heads
+    def layers_of(self, windowed: bool) -> int:
+        """How many of the stack's layers are window (or full) layers."""
+        kinds = self.block.kinds
+        if kinds is None:
+            return 0 if windowed else self.n_layers
+        return (self.n_layers // len(kinds)
+                * sum(1 for w, _ in kinds if w == windowed))
 
     @property
     def ffn_width(self) -> int:
@@ -212,13 +303,13 @@ class LMSpec:
         plane; fan is (fan_in, fan_out) for a matrix (Xavier), None for a
         vector (norm scales start at 1, biases at 0)."""
         d, dh = self.d_model, self.head_dim
-        d_kv = dh * self.kv_heads
+        d_q, d_kv = dh * self.num_heads, dh * self.kv_heads
         E, f = self.num_experts, self.d_expert
         shapes = {
             "ln1_s": ([d], None), "ln1_b": ([d], None),
-            "qkv_w": ([d, d + 2 * d_kv], (d, d + 2 * d_kv)),
-            "q_norm_s": ([d], None), "k_norm_s": ([d_kv], None),
-            "out_w": ([d, d], (d, d)),
+            "qkv_w": ([d, d_q + 2 * d_kv], (d, d_q + 2 * d_kv)),
+            "q_norm_s": ([d_q], None), "k_norm_s": ([d_kv], None),
+            "out_w": ([d_q, d], (d_q, d)),
             "ln2_s": ([d], None), "ln2_b": ([d], None),
             "ff_w1": ([d, self.ffn_width], (d, self.ffn_width)),
             "ff_b1": ([self.ffn_width], None),

@@ -76,9 +76,12 @@ def switch_moe(attrs, ins):
                AuxLoss=aux.reshape(1))
 
 
+_EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
-             layer=None):
-    """Dropless token-choice top-``k`` SwiGLU experts — the expert layer
+             layer=None, act="silu", router_x=None):
+    """Dropless token-choice top-``k`` gated experts — the expert layer
     of the ``swiglu_moe`` block (ops/pipeline_ops.py calls it from the
     block's FFN half; it is not a program op of its own).
 
@@ -107,11 +110,17 @@ def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
     COPY it first: 3 x 268 MB a layer at OLMoE's widths, 19.6 of an 82 ms
     decode tick (my chip run, PR 26). The serving ops pass ``layer``; the
     train op scans slices (its weight gradient must be one layer's).
+
+    ``act``: the gate's activation (``silu``: SwiGLU, ``relu``: ReGLU).
+    ``router_x`` [N, d]: what the router reads when that is not ``x`` (a
+    block whose router sees the attention's input routes from norm 1's
+    output while the experts take norm 2's).
     """
     N, d = x.shape
     E = router_w.shape[-1]
     x32 = x.astype(jnp.float32)
-    logits = jnp.dot(x32, router_w.astype(jnp.float32),
+    r32 = x32 if router_x is None else router_x.astype(jnp.float32)
+    logits = jnp.dot(r32, router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)                   # [N, E]
     top_p, top_e = jax.lax.top_k(probs, k)                    # [N, k]
@@ -137,7 +146,7 @@ def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
         return jax.lax.ragged_dot(a, w, sizes, precision=mxu_precision(),
                                   preferred_element_type=jnp.float32)
 
-    h = jax.nn.silu(grouped(rows, gate_w)) * grouped(rows, up_w)
+    h = _EXPERT_ACTS[act](grouped(rows, gate_w)) * grouped(rows, up_w)
     o = grouped(h.astype(rows.dtype), down_w)                 # [N*k, d] f32
     inv = jnp.argsort(order)                                  # unsort
     y = jnp.sum(o[inv].reshape(N, k, d) * top_p[..., None], axis=1)

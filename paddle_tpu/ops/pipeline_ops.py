@@ -70,39 +70,81 @@ def _stack_params(blk, ins):
             for slot, key in blk.stack_slots().items()}
 
 
-def _block(blk, p, x, causal):
+def _block(blk, p, x, causal, rope=None):
     """One block of the spec; p holds per-layer (no leading dim) weights
     under the keys of ``blk.stack_slots()``. -> (x, stats): stats is None
-    for a dense FFN, (counts [E], router prob mean [E]) for experts."""
-    b, T, d = x.shape
+    for a dense FFN, (counts [E], router prob mean [E]) for experts.
+    ``rope``: whether THIS layer rotates q / k (a ``layer_pattern``;
+    None: as ``blk.use_rope`` says). A window layer is the causal block
+    here: the callers hold T to the window."""
+    b, T, _ = x.shape
     from jax.ad_checkpoint import checkpoint_name
 
-    q, k, v = _attn_proj(blk, p, x)
+    q, k, v = _attn_proj(blk, p, x, rope=rope)
     k, v = _expand_kv(k, v, blk.num_heads)
     ctx = flash_attention(q, k, v, causal=causal)
-    ctx = checkpoint_name(ctx.transpose(0, 2, 1, 3).reshape(b, T, d),
+    ctx = checkpoint_name(ctx.transpose(0, 2, 1, 3).reshape(b, T, -1),
                           "attn_ctx")
     return _attn_out_ffn(blk, p, x, ctx)
 
 
-def _attn_proj(blk, p, h, pos0=0):
+def _scan_stack(kinds, body, carry, xs):
+    """``lax.scan`` of ``body(carry, x_l, kind) -> (carry, y_l)`` over the
+    stack's layers (every leaf of ``xs`` leads with L); ``kind`` is the
+    layer's (windowed, rotates). A stack of one kind is one scan over L
+    with ``kind`` None. Under a ``layer_pattern``
+    the scan runs over PERIODS with the period's layers unrolled in its
+    body (stacks viewed [L/p, p, ...]), so each position of the period is
+    traced with its own kind and nothing is unrolled L times; the ys come
+    back stacked [L, ...] either way. ``kinds``: the block's (``None``:
+    one kind)."""
+    if kinds is None:
+        return jax.lax.scan(lambda c, x_l: body(c, x_l, None), carry, xs)
+    p = len(kinds)
+    tmap = jax.tree_util.tree_map
+
+    def period(c, x_p):
+        ys = []
+        for j, kind in enumerate(kinds):
+            c, y = body(c, tmap(lambda a: a[j], x_p), kind)
+            ys.append(y)
+        return c, tmap(lambda *a: jnp.stack(a), *ys)
+
+    carry, ys = jax.lax.scan(period, carry, tmap(
+        lambda a: a.reshape((a.shape[0] // p, p) + a.shape[1:]), xs))
+    return carry, tmap(lambda a: a.reshape((-1,) + a.shape[2:]), ys)
+
+
+def _hold_to_window(blk, T, who):
+    """The dense attention paths run a window layer as a causal one."""
+    if blk.has_window and T > blk.window:
+        raise BlockNotSupportedError(
+            f"{who} over {T} tokens with window layers of {blk.window}: "
+            "its dense attention has no window mask; the paged prefill / "
+            "decode ops serve longer contexts")
+
+
+def _attn_proj(blk, p, h, pos0=0, rope=None):
     """norm 1 + qkv projection -> q [b, H, t, dh], k/v [b, Hkv, t, dh].
     Hkv < H is grouped-query attention: the stacked qkv weight is
     [L, d, d + 2*Hkv*dh] and the KV planes (and decode caches) shrink by
     H/Hkv. ``qk_norm``: RMSNorm over the WHOLE q and k vectors before the
     head split. RoPE rotates q/k at absolute positions pos0..pos0+t-1
-    (rotated keys enter the decode cache, so cached rows never re-rotate)."""
+    (rotated keys enter the decode cache, so cached rows never re-rotate);
+    ``rope`` overrides ``blk.use_rope`` for one layer of a pattern. The
+    head width is ``blk.head_dim`` when the spec states one (H*dh need
+    not be d: qkv_w is [d, H*dh + 2*Hkv*dh])."""
     num_heads, num_kv_heads = blk.num_heads, blk.kv_heads
     b, t, d = h.shape
-    head_d = d // num_heads
-    d_kv = head_d * num_kv_heads
+    head_d = blk.dh(d)
+    d_q, d_kv = head_d * num_heads, head_d * num_kv_heads
     from jax.ad_checkpoint import checkpoint_name
 
     hn = _norm(blk, h, p["ln1_s"], p.get("ln1_b"))
     qkv = checkpoint_name(_mm("btd,de->bte", hn, p["qkv_w"]), "qkv_proj")
-    q = qkv[..., :d]
-    k = qkv[..., d:d + d_kv]
-    v = qkv[..., d + d_kv:]
+    q = qkv[..., :d_q]
+    k = qkv[..., d_q:d_q + d_kv]
+    v = qkv[..., d_q + d_kv:]
     if blk.qk_norm:
         q = _rms(q, p["q_norm_s"], blk.norm_eps)
         k = _rms(k, p["k_norm_s"], blk.norm_eps)
@@ -112,7 +154,7 @@ def _attn_proj(blk, p, h, pos0=0):
 
     q, k, v = (heads(q, num_heads), heads(k, num_kv_heads),
                heads(v, num_kv_heads))
-    if blk.use_rope:
+    if blk.use_rope if rope is None else rope:
         q = rotary(q, pos0, blk.rope_theta, blk.rope_pairing)
         k = rotary(k, pos0, blk.rope_theta, blk.rope_pairing)
     return q, k, v
@@ -128,19 +170,29 @@ def _expand_kv(k, v, num_heads):
 
 
 def _attn_out_ffn(blk, p, x, ctx):
-    """Out-projection + residual + FFN half of a block; ctx [b, t, d].
-    -> (x, stats), stats as ``_block`` says."""
+    """Out-projection + residual + FFN half of a block; x [b, t, d] the
+    block's input, ctx [b, t, H*dh]. -> (x, stats), stats as ``_block``
+    says. A block whose router reads the attention's input
+    (``router_input="attn_input"``) routes from norm 1 of ``x``: the same
+    expression the attention half computed, which XLA shares."""
     from jax.ad_checkpoint import checkpoint_name
 
+    early = blk.is_moe and blk.router_input == "attn_input"
+    router_x = _norm(blk, x, p["ln1_s"], p.get("ln1_b")) if early else None
     x = x + checkpoint_name(_mm("btd,de->bte", ctx.astype(x.dtype),
                                 p["out_w"]), "attn_out")
     h2 = _norm(blk, x, p["ln2_s"], p.get("ln2_b"))
     if blk.is_moe:
         b, t, d = x.shape
+        more = {}
+        if early:
+            more["router_x"] = router_x.reshape(b * t, d)
+        if blk.expert_act != "silu":
+            more["act"] = blk.expert_act
         y, counts, prob_mean = moe_topk(
             h2.reshape(b * t, d), p["router_w"], p["moe_gate_w"],
             p["moe_up_w"], p["moe_down_w"], blk.experts_per_tok,
-            blk.norm_topk_prob, layer=p.get("layer"))
+            blk.norm_topk_prob, layer=p.get("layer"), **more)
         return x + y.reshape(b, t, d), (counts, prob_mean)
     ff = _mm("btd,df->btf", h2, p["ff_w1"])
     if blk.bias:
@@ -192,25 +244,28 @@ def pipelined_transformer_stack(attrs, ins):
 
     remat = attrs.get("remat", False)
 
-    def scan_stats(p, h):
-        def body(carry, layer_p):
-            return _block(blk, layer_p, carry, causal)
+    _hold_to_window(blk, x.shape[1], "pipelined_transformer_stack")
 
-        if remat == "dots":
-            # Selective policy: keep each layer's big GEMM outputs
-            # (qkv/attn-out/ctx/ffn-hidden) resident and recompute only
-            # the cheap elementwise/LN work in the backward — the
-            # all-or-nothing form re-runs every forward matmul per layer
-            # (one stack forward of the step's four, PERF.md section 5;
-            # the "30.0% vs 48.1% per-layer MFU" once quoted here came
-            # from before the benchmark, off a step that also ran the
-            # forward scan twice). Never run on a chip: ROADMAP S5.
-            body = jax.checkpoint(
-                body, policy=jax.checkpoint_policies.save_only_these_names(
-                    "qkv_proj", "attn_ctx", "attn_out", "ffn_hidden"))
-        elif remat:
-            body = jax.checkpoint(body)
-        return jax.lax.scan(body, h, p)
+    def scan_stats(p, h):
+        def wrap(body, **kw):
+            if remat == "dots":
+                # Selective policy: keep each layer's big GEMM outputs
+                # (qkv/attn-out/ctx/ffn-hidden) resident and recompute only
+                # the cheap elementwise/LN work in the backward — the
+                # all-or-nothing form re-runs every forward matmul per
+                # layer (one stack forward of the step's four, PERF.md
+                # section 5). Never run on a chip: ROADMAP S5.
+                return jax.checkpoint(
+                    body,
+                    policy=jax.checkpoint_policies.save_only_these_names(
+                        "qkv_proj", "attn_ctx", "attn_out", "ffn_hidden"),
+                    **kw)
+            return jax.checkpoint(body, **kw) if remat else body
+
+        return _scan_stack(
+            blk.kinds, wrap(lambda carry, layer_p, kind: _block(
+                blk, layer_p, carry, causal, kind and kind[1]),
+                static_argnums=(2,)), h, p)
 
     def scan_layers(p, h):
         return scan_stats(p, h)[0]
@@ -225,6 +280,7 @@ def pipelined_transformer_stack(attrs, ins):
             raise BlockNotSupportedError(
                 "a swiglu_moe stack under a pp mesh axis: the GPipe "
                 "schedule carries no per-layer router statistics")
+        blk.require_one_kind("a pp pipeline (stages of whole layers)")
         if L % pp:
             raise ValueError(
                 f"{L} layers not divisible by pipeline size {pp}")
@@ -283,33 +339,37 @@ def _prefill(blk, params, x, b, Tp):
     returns (hidden [b, Tp, d], ks, vs [L, b, Hkv, Tp, dh]) — the caches
     hold KV heads only (the GQA memory win). Under RoPE the cached keys
     are already rotated at their absolute positions."""
-    def prefill_body(h, layer_p):
-        q, k, v = _attn_proj(blk, layer_p, h)
+    def prefill_body(h, layer_p, kind=None):
+        q, k, v = _attn_proj(blk, layer_p, h, rope=kind and kind[1])
         kx, vx = _expand_kv(k, v, blk.num_heads)
         ctx = flash_attention(q, kx, vx, causal=True)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, Tp, x.shape[-1])
+        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, Tp, -1)
         return _attn_out_ffn(blk, layer_p, h, ctx)[0], (k, v)
 
-    return jax.lax.scan(prefill_body, x, params)
+    return _scan_stack(blk.kinds, prefill_body, x, params)
 
 
 def _decode_layer_fn(blk, params, d):
     """One-token decode through all layers against the cache; returns a
-    fn(h1, (layer_p, ck_l, cv_l), pos) suitable for lax.scan over layers
-    (pos = the query's position; cache rows < pos+1 are visible). Caches
-    store Hkv heads; queries expand to their groups at attention time."""
+    fn(h1, (layer_p, ck_l, cv_l), pos, kind) for ``_scan_stack`` (pos =
+    the query's position; cache rows < pos+1 are visible, on a window
+    layer the last ``blk.window`` of them). Caches store Hkv heads;
+    queries expand to their groups at attention time."""
     from ..kernels.flash_attention import reference_attention
 
-    def layer(h1, inp, pos):
+    def layer(h1, inp, pos, kind=None):
         layer_p, ck_l, cv_l = inp
-        q, k, v = _attn_proj(blk, layer_p, h1, pos0=pos)
+        windowed, rope = kind or (False, None)
+        more = dict(window=blk.window) if windowed else {}
+        q, k, v = _attn_proj(blk, layer_p, h1, pos0=pos, rope=rope)
         ck_l = jax.lax.dynamic_update_slice_in_dim(ck_l, k, pos, 2)
         cv_l = jax.lax.dynamic_update_slice_in_dim(cv_l, v, pos, 2)
         # reference_attention reads the Hkv cache natively (grouped
         # einsum) — no [b, H, T, dh] expansion on the decode hot path
         ctx = reference_attention(
-            q, ck_l, cv_l, lengths=jnp.full((h1.shape[0],), pos + 1))
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(h1.shape[0], 1, d)
+            q, ck_l, cv_l, lengths=jnp.full((h1.shape[0],), pos + 1),
+            **more)
+        ctx = ctx.transpose(0, 2, 1, 3).reshape(h1.shape[0], 1, -1)
         return _attn_out_ffn(blk, layer_p, h1, ctx)[0], (ck_l, cv_l)
 
     return layer
@@ -371,6 +431,7 @@ def transformer_stack_generate(attrs, ins, rng):
         raise ValueError(
             f"prompt {Tp} + {N} new tokens exceeds max_len "
             f"{pos_emb.shape[0]}")
+    _hold_to_window(blk, Tp, "transformer_stack_generate's prefill")
     embed = _embed_fn(tok_emb, pos_emb)
     logits_of = _logits_fn(ln_s, ln_b, head_w, blk)
     vocab = head_w.shape[1]
@@ -390,8 +451,8 @@ def transformer_stack_generate(attrs, ins, rng):
         tok, ck, cv = carry
         pos = Tp + n
         x1 = embed(tok[:, None], pos)  # [b, 1, d]
-        h1, (ck, cv) = jax.lax.scan(
-            lambda h1, inp: decode_layer(h1, inp, pos),
+        h1, (ck, cv) = _scan_stack(
+            blk.kinds, lambda h1, inp, kind: decode_layer(h1, inp, pos, kind),
             x1, (params, ck, cv))
         nxt = pick(logits_of(h1[:, 0]), n + 1)
         return (nxt, ck, cv), nxt
@@ -612,8 +673,27 @@ def _gather_pages(pool, layer, table, num_kv_heads):
 _RESIDENT_PLANES = ("moe_gate_w", "moe_up_w", "moe_down_w")
 
 
+def _window_span(table, mask, t, ps, window):
+    """The entries of ``table`` [b, P] a window layer can still reach:
+    -> (span [b, n] page ids, k_pos0 [b] the position of the span's first
+    row). n = the pages ``window + t - 1`` consecutive keys can touch,
+    never the table width; entries past the table repeat its last page at
+    positions no query reaches."""
+    P = table.shape[1]
+    if "lengths" in mask:
+        first_key = jnp.maximum(mask["lengths"] - window, 0)
+    else:
+        first_key = jnp.maximum(mask["q_pos0"] - window + 1, 0)
+    first = (first_key // ps).astype(jnp.int32)
+    n = min(P, (window + t + ps - 3) // ps + 1)
+    entries = first[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]
+    span = jnp.take_along_axis(table, jnp.minimum(entries, P - 1), axis=1)
+    return span, first * ps
+
+
 def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
-                       page_row, project, mask, finish, xs=None):
+                       page_row, project, mask, finish, xs=None, blk=None,
+                       win=None):
     """The layer loop of every paged op: h [b, t, d] through the L
     stacked blocks with the page pools [L, N, ps, Hkv*dh] as the scan's
     CARRY, updated in place.
@@ -632,7 +712,22 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
     -> (h, stats) closes the block, x_l being layer l's slice of the
     optional scanned-over ``xs`` and stats what the layer reports (None,
     or an expert layer's (counts, router prob mean)). Returns (h,
-    cache_k, cache_v, stats stacked over layers).
+    cache_k, cache_v, stats stacked over layers, the window kind's pools
+    or None).
+
+    A stack whose layers differ in kind (``blk.kinds``, a
+    ``layer_pattern``) holds its cache BY KIND: ``cache_k`` / ``cache_v``
+    / ``table`` / ``page_id`` / ``page_row`` are then the FULL-attention
+    layers' pools [Lg, Ng, ..] and table, ``win`` = (cache_kw, cache_vw,
+    table_w, page_id_w, page_row_w) the window layers' [Lw, Nw, ..]
+    (None: no window layer). The same loop runs it (``_scan_stack``
+    unrolls a period in the scan's body): each layer writes and reads its
+    own kind's pool at its index WITHIN the kind, and ``project(layer_p,
+    h, rope)`` takes the layer's positions. A window layer attends keys
+    ``0 <= i - j < blk.window``: the kernel starts its page walk at the
+    window's first page, the gathered form gathers the window's span of
+    the table (``_window_span``), not its width. A stack of one kind is
+    the period of one layer, no window and ``project``'s own positions.
 
     Pages hold K/V in the POOL's dtype (the spec's ``page_dtype``): new
     rows are cast on the way in, and queries are cast to it so the
@@ -645,61 +740,121 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
     loop state and the only pool-shaped ops are the two in-place
     scatters; the decode kernel takes the carry whole and addresses it at
     (l, page), so it adds none."""
-    from ..kernels import paged_attention
-    from ..kernels.flash_attention import reference_attention
-
-    b, t, d = h.shape
-    n_layers = cache_k.shape[0]
-    # a decode step (one query token a row, keys j < lengths) on a chip
-    # walks the block table in one kernel; every other call gathers
-    walk_pages = set(mask) == {"lengths"} and paged_attention.supported(
-        d, cache_k, t)
+    b, t, _ = h.shape
     whole = {k: params[k] for k in _RESIDENT_PLANES if k in params}
     params = {k: v for k, v in params.items() if k not in whole}
-    ix_page = page_id.reshape(b, t)
-    ix_row = page_row.reshape(b, t)
+    attend = _paged_layer_step(b, t, cache_k.shape[2], project, mask, finish)
+    ix = (page_id.reshape(b, t), page_row.reshape(b, t))
+    kinds = blk.kinds if blk is not None else None
+    ckw, cvw, table_w, page_id_w, page_row_w = win or (None,) * 5
+    ix_w = (None if win is None
+            else (page_id_w.reshape(b, t), page_row_w.reshape(b, t)))
+    n_layers = jax.tree_util.tree_leaves(params)[0].shape[0]
+    within = None       # a layer's index within its kind: l itself for one
+    if kinds is not None:
+        seen = {False: 0, True: 0}
+        within = []
+        for l in range(n_layers):
+            windowed = kinds[l % len(kinds)][0]
+            within.append(seen[windowed])
+            seen[windowed] += 1
+        within = jnp.asarray(within, jnp.int32)
+
+    def layer(carry, inp, kind):
+        h, ck, cv, ckw, cvw = carry
+        layer_p, l, l_kind, x_l = inp
+        if whole:
+            layer_p = {**layer_p, **whole, "layer": l}
+        windowed, rope = kind or (False, None)
+        if l_kind is None:
+            l_kind = l
+        if windowed:
+            h, ckw, cvw, stats = attend(h, ckw, cvw, l_kind, layer_p, x_l,
+                                        table_w, *ix_w, window=blk.window,
+                                        rope=rope)
+        else:
+            h, ck, cv, stats = attend(h, ck, cv, l_kind, layer_p, x_l,
+                                      table, *ix, rope=rope)
+        return (h, ck, cv, ckw, cvw), stats
+
+    (h, cache_k, cache_v, ckw, cvw), stats = _scan_stack(
+        kinds, layer, (h, cache_k, cache_v, ckw, cvw),
+        (params, jnp.arange(n_layers, dtype=jnp.int32), within, xs))
+    return h, cache_k, cache_v, stats, (None if win is None else (ckw, cvw))
+
+
+def _paged_layer_step(b, t, ps, project, mask, finish):
+    """The per-layer step of the paged loop (``_scan_paged_layers`` says
+    what it does): ``attend(h, ck, cv, l, layer_p, x_l, table, ix_page,
+    ix_row, window=None, rope=None)`` -> (h, ck, cv, stats) against the
+    pools (ck, cv) of the layer's KIND at its index l within the kind."""
+    from ..kernels import paged_attention
+    from ..kernels.flash_attention import reference_attention
 
     def token_rows(a):  # [b, Hkv, t, dh] -> [b, t, Hkv*dh]
         return a.transpose(0, 2, 1, 3).reshape(b, t, -1)
 
-    def layer(carry, inp):
-        h, ck, cv = carry
-        layer_p, l, x_l = inp
-        if whole:
-            layer_p = {**layer_p, **whole, "layer": l}
-        q, k, v = project(layer_p, h)
+    def attend(h, ck, cv, l, layer_p, x_l, tbl, ix_page, ix_row,
+               window=None, rope=None):
+        """One layer against ITS kind's pools (ck, cv) at index l."""
+        q, k, v = (project(layer_p, h) if rope is None
+                   else project(layer_p, h, rope))
         hkv = k.shape[1]
         ck = ck.at[l, ix_page, ix_row].set(token_rows(k).astype(ck.dtype))
         cv = cv.at[l, ix_page, ix_row].set(token_rows(v).astype(cv.dtype))
-        if walk_pages:
+        # a decode step (one query token a row, keys j < lengths) on a
+        # chip walks the block table in one kernel; every other call
+        # gathers
+        if set(mask) == {"lengths"} and paged_attention.supported(
+                q.shape[1] * q.shape[3], ck, t):
             ctx = paged_attention.paged_attention_decode(
-                q[:, :, 0], ck, cv, l, table, mask["lengths"])[:, None]
+                q[:, :, 0], ck, cv, l, tbl, mask["lengths"],
+                window=window)[:, None]
         else:
+            m = mask
+            if window is not None:
+                tbl, k_pos0 = _window_span(tbl, mask, t, ps, window)
+                m = dict(mask, k_pos0=k_pos0, window=window)
             ctx = reference_attention(q.astype(ck.dtype),
-                                      _gather_pages(ck, l, table, hkv),
-                                      _gather_pages(cv, l, table, hkv),
-                                      **mask)
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, t, d)
+                                      _gather_pages(ck, l, tbl, hkv),
+                                      _gather_pages(cv, l, tbl, hkv), **m)
+            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, t, -1)
         h, stats = finish(layer_p, h, ctx, x_l)
-        return (h, ck, cv), stats
+        return h, ck, cv, stats
 
-    (h, cache_k, cache_v), stats = jax.lax.scan(
-        layer, (h, cache_k, cache_v),
-        (params, jnp.arange(n_layers, dtype=jnp.int32), xs))
-    return h, cache_k, cache_v, stats
+    return attend
 
 
-def _paged_outs(blk, stats, **outs):
+def _paged_outs(blk, stats, win, **outs):
     """The paged ops' outputs; an expert block adds ExpertCounts [L, E]
     int32 (rows each expert took in each layer of THIS call) so the
-    engine's counters ride the tick's existing fetch."""
+    engine's counters ride the tick's existing fetch; a spec with window
+    layers adds their pools."""
     if blk.is_moe:
         outs["ExpertCounts"] = stats[0]
+    if win is not None:
+        outs["CacheKW"], outs["CacheVW"] = win
     return out(**outs)
 
 
+#: the window kind's pools and table, beside CacheK / CacheV / BlockTable
+#: (which a ``layer_pattern`` spec reads as its full-attention kind's)
+_WINDOW_SLOTS = ("CacheKW", "CacheVW", "BlockTableW")
+
+
+def _window_ins(blk, ins, targets):
+    """``win`` of ``_scan_paged_layers`` for a spec with window layers:
+    the kind's pools, its table and the (page, row) targets of the call's
+    tokens under that table (``targets(table)``); None otherwise."""
+    if not blk.has_window:
+        return None
+    table_w = single(ins, "BlockTableW").astype(jnp.int32)
+    return (single(ins, "CacheKW"), single(ins, "CacheVW"), table_w,
+            *targets(table_w))
+
+
 @register_op("transformer_stack_paged_prefill",
-             optional_inputs=_LM_OPTIONAL + _SAMPLING_SLOTS,
+             optional_inputs=_LM_OPTIONAL + _SAMPLING_SLOTS + _WINDOW_SLOTS,
              needs_rng=lambda attrs: (attrs.get("temperature") or 0) > 0)
 def transformer_stack_paged_prefill(attrs, ins, rng=None):
     """Prefill ONE CHUNK of each row's prompt into its block-table pages.
@@ -763,27 +918,32 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     pos = start[:, None] + jnp.arange(Tc, dtype=jnp.int32)[None, :]
     valid = jnp.arange(Tc, dtype=jnp.int32)[None, :] < lengths[:, None]
     entry = jnp.clip(pos // ps, 0, P - 1)
-    page_id = jnp.where(
-        valid, jnp.take_along_axis(table, entry, axis=1), 0)
+
+    def page_of(table):
+        return jnp.where(valid, jnp.take_along_axis(table, entry, axis=1), 0)
+
+    page_id = page_of(table)
     page_row = jnp.where(valid, pos % ps, 0)
     x = _embed_rows(tok_emb, chunk)
     if pos_emb is not None:
         x = x + pos_emb[jnp.clip(pos, 0, pos_emb.shape[0] - 1)]
-    h, cache_k, cache_v, stats = _scan_paged_layers(
+    # "CacheKW" "CacheVW" "BlockTableW": the window kind (_window_ins)
+    h, cache_k, cache_v, stats, win = _scan_paged_layers(
         params, x, cache_k, cache_v, table, page_id, page_row,
-        lambda p, h: _attn_proj(blk, p, h, pos0=start),
+        lambda p, h, rope=None: _attn_proj(blk, p, h, pos0=start, rope=rope),
         dict(causal=True, q_pos0=start),
-        lambda p, h, ctx, _x_l: _attn_out_ffn(blk, p, h, ctx))
+        lambda p, h, ctx, _x_l: _attn_out_ffn(blk, p, h, ctx), blk=blk,
+        win=_window_ins(blk, ins, lambda tw: (page_of(tw), page_row)))
     last = h[jnp.arange(b), jnp.clip(lengths, 1, Tc) - 1]  # [b, d]
     logits = _logits_fn(ln_s, ln_b, head_w, blk)(last)
     nxt = _pick_rows(attrs, ins, rng, head_w.shape[1], logits)
-    outs = _paged_outs(blk, stats, NextTok=nxt.astype(chunk.dtype),
+    outs = _paged_outs(blk, stats, win, NextTok=nxt.astype(chunk.dtype),
                        CacheK=cache_k, CacheV=cache_v)
     return _maybe_topk(attrs, ins, logits, outs)
 
 
 @register_op("transformer_stack_paged_decode",
-             optional_inputs=_LM_OPTIONAL + _SAMPLING_SLOTS,
+             optional_inputs=_LM_OPTIONAL + _SAMPLING_SLOTS + _WINDOW_SLOTS,
              needs_rng=lambda attrs: (attrs.get("temperature") or 0) > 0)
 def transformer_stack_paged_decode(attrs, ins, rng=None):
     """One decode step over every slot's paged context.
@@ -849,14 +1009,17 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     srange = jnp.arange(S)
     page_id = table[srange, pos // ps]  # [S]
     page_row = pos % ps
-    h1, cache_k, cache_v, stats = _scan_paged_layers(
+    # "CacheKW" "CacheVW" "BlockTableW": the window kind (_window_ins)
+    h1, cache_k, cache_v, stats, win = _scan_paged_layers(
         params, h1, cache_k, cache_v, table, page_id, page_row,
-        lambda p, h: _attn_proj(blk, p, h, pos0=pos),
+        lambda p, h, rope=None: _attn_proj(blk, p, h, pos0=pos, rope=rope),
         dict(lengths=pos + 1),
-        lambda p, h, ctx, _x_l: _attn_out_ffn(blk, p, h, ctx))
+        lambda p, h, ctx, _x_l: _attn_out_ffn(blk, p, h, ctx), blk=blk,
+        win=_window_ins(blk, ins,
+                        lambda tw: (tw[srange, pos // ps], page_row)))
     logits = _logits_fn(ln_s, ln_b, head_w, blk)(h1[:, 0])
     nxt = _pick_rows(attrs, ins, rng, head_w.shape[1], logits)
-    outs = _paged_outs(blk, stats, NextTok=nxt.astype(tok.dtype),
+    outs = _paged_outs(blk, stats, win, NextTok=nxt.astype(tok.dtype),
                        CacheK=cache_k, CacheV=cache_v)
     return _maybe_topk(attrs, ins, logits, outs)
 

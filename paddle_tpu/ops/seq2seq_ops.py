@@ -201,7 +201,7 @@ def transformer_stack_cross_prefill(attrs, ins, rng=None):
     x = tok_emb[chunk]
     if pos_emb is not None:
         x = x + pos_emb[jnp.clip(pos, 0, pos_emb.shape[0] - 1)]
-    h, cache_k, cache_v, _ = _scan_paged_layers(
+    h, cache_k, cache_v, _, _ = _scan_paged_layers(
         params, x, cache_k, cache_v, table, page_id, page_row,
         lambda p, h: _attn_proj(blk, p, h, pos0=start),
         dict(causal=True, q_pos0=start),
@@ -257,7 +257,7 @@ def transformer_stack_cross_decode(attrs, ins, rng=None):
     srange = jnp.arange(S)
     page_id = table[srange, pos // ps]
     page_row = pos % ps
-    h1, cache_k, cache_v, _ = _scan_paged_layers(
+    h1, cache_k, cache_v, _, _ = _scan_paged_layers(
         params, h1, cache_k, cache_v, table, page_id, page_row,
         lambda p, h: _attn_proj(blk, p, h, pos0=pos),
         dict(lengths=pos + 1),

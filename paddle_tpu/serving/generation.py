@@ -27,7 +27,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -41,15 +41,18 @@ from ..decoding.params import BeamParams, SamplingParams
 from ..decoding.stops import StopMatcher
 from ..layers import data as data_layer
 from ..layers.layer_helper import LayerHelper
-from ..lm_spec import Block, LMSpec
+from ..lm_spec import Block, BlockNotSupportedError, LMSpec
 from .batcher import Request
 from .errors import BadRequestError
 from .metrics import MetricsRegistry
-from .paging import PagePool, PrefixIndex
+from .paging import PagePool, PrefixIndex, chain_key
 
 
 PAGED_CACHE_K = "serving.paged_cache_k"
 PAGED_CACHE_V = "serving.paged_cache_v"
+# the window layers' pools of a spec whose layers differ in kind
+PAGED_CACHE_KW = "serving.paged_cache_kw"
+PAGED_CACHE_VW = "serving.paged_cache_vw"
 
 # decode-family op types whose attrs + shared weights describe a stacked LM
 _DECODE_OPS = ("transformer_stack_generate", "transformer_stack_beam_search",
@@ -178,7 +181,8 @@ class _Slot:
                  "timeline", "truncate_to", "pages", "shared_tokens",
                  "cow_reserve", "prefill_done", "state", "sampling",
                  "stop_matcher", "mask_proc", "beam_job", "role", "xrow",
-                 "resumed")
+                 "resumed", "wpages", "wfirst", "wentries", "wreserve",
+                 "wcow")
 
     def __init__(self, request: Request, prompt: np.ndarray,
                  max_new: int, eos_id: Optional[int],
@@ -206,6 +210,13 @@ class _Slot:
         self.xrow = None                 # seq2seq: cross-KV cache row
         self.resumed = 0                 # recovery: emitted tokens that
                                          # re-entered as prefill context
+        # the window kind's table (a spec with window layers): a page per
+        # logical entry allocated so far, 0 once it lies behind the window
+        self.wpages: List[int] = []
+        self.wfirst = 0                  # first entry that may hold a page
+        self.wentries = 0                # entries the slot will ever touch
+        self.wreserve = 0                # window-pool pages held for them
+        self.wcow = 0                    # and for one copy-on-write
 
 
 class GenerationEngine:
@@ -235,10 +246,31 @@ class GenerationEngine:
       :class:`~paddle_tpu.serving.errors.CacheExhaustedError`; transient
       pressure defers admission (the batcher queue backs up and sheds)
       instead of failing mid-decode.
+
+    **The cache by kind** (a spec with window layers, ``LMSpec(
+    layer_pattern=, window=)``): the full-attention layers' pool ``[Lg,
+    n_pages, ps, Hkv*dh]`` keeps every token of a sequence as above; the
+    window layers' pool ``[Lw, n_pages_window, ps, Hkv*dh]`` has a
+    ``PagePool``, a ``PrefixIndex`` and a per-slot table of its own
+    (``_Slot.wpages``). A slot's window pages are allocated as it
+    advances, out of an admission-time hold, and released (decref) once
+    they lie wholly behind the window — so a long sequence holds
+    ``window/ps + 1`` of them where it holds ``len/ps`` full-attention
+    pages. The hold covers the slot's live window (window + one chunk)
+    plus the prompt pages it will leave to the prefix index, so either
+    pool can defer an admission and neither is allocated from mid-decode.
+    The window index keeps every page of a cached prefix (a partial hit
+    needs the window before ITS end): the two indexes are written in
+    lockstep, page by page as a prompt's chunks complete, and a hit is as
+    long as both agree on. Copy-on-write and write-implies-exclusive hold
+    per kind. Beam requests, ``share_cache_with=`` and the slot handoff
+    (``export_slot`` / ``adopt_slot`` / a serialized handoff) know one
+    table: they raise :class:`~paddle_tpu.lm_spec.BlockNotSupportedError`.
     """
 
     # scope tensors swap_params must never clobber (live decode state)
-    _cache_names = (PAGED_CACHE_K, PAGED_CACHE_V)
+    _cache_names = (PAGED_CACHE_K, PAGED_CACHE_V, PAGED_CACHE_KW,
+                    PAGED_CACHE_VW)
 
     def __init__(self, spec: LMSpec, scope: Optional[Scope] = None, *,
                  slots: int = 8, max_seq_len: Optional[int] = None,
@@ -253,6 +285,7 @@ class GenerationEngine:
                  namespace: str = "",
                  page_size: Optional[int] = None,
                  n_pages: Optional[int] = None,
+                 n_pages_window: Optional[int] = None,
                  prefill_chunk: Optional[int] = None,
                  prefix_sharing: bool = True,
                  beam_width: int = 0, mask_plane: bool = True,
@@ -321,7 +354,10 @@ class GenerationEngine:
         # engine's scope adopts its page pool/prefix index — a KV
         # handoff between the two is then a pure slot-table transfer
         src = share_cache_with
+        self._by_kind = spec.block.has_window
         if src is not None:
+            spec.block.require_one_kind("share_cache_with= (the slot "
+                                        "handoff between engines)")
             if self.scope is not src.scope:
                 raise ValueError(
                     "share_cache_with requires constructing this engine "
@@ -364,6 +400,23 @@ class GenerationEngine:
             self.pool = PagePool(self.n_pages, self.page_size)
             self.prefix_index = (PrefixIndex(self.pool)
                                  if self._prefix_sharing else None)
+        # the window kind: what a slot's window layers can hold at once
+        # (the window, the chunk in flight, one page of slack each way)
+        self.wpool = self.wprefix_index = None
+        self.n_pages_window = 0
+        if self._by_kind:
+            ps = self.page_size
+            self._wlive = (-(-spec.window // ps) + 1
+                           + -(-self.prefill_chunk // ps))
+            self.n_pages_window = int(
+                n_pages_window
+                or self.slots * min(self.pmax, self._wlive) + 1)
+            self.wpool = PagePool(self.n_pages_window, ps)
+            if self._prefix_sharing:
+                self.wprefix_index = PrefixIndex(self.wpool)
+        #: kind -> (the pool's ``changes`` at the count, pages some slot
+        #: holds): the ``kv_pages_held_*`` counters' cache
+        self._held: Dict[str, Tuple[int, int]] = {}
         # no scrap SLOT — padding/vacant rows write the scrap PAGE, so
         # the decode batch is exactly the slot count
         self._slots: List[Optional[_Slot]] = [None] * self.slots
@@ -386,7 +439,7 @@ class GenerationEngine:
 
         # -- programs ----------------------------------------------------
         self._prefill_progs: Dict[int, tuple] = {}
-        self._page_copy_prog_cache = None
+        self._page_copy_prog_cache: Dict[bool, tuple] = {}
         self._decode_prog = self._build_decode()
         if mem_budget is not None:
             self._check_mem_budget(mem_budget)
@@ -454,34 +507,50 @@ class GenerationEngine:
 
         shape = self._pool_shape()
         page_dtype = jnp.dtype(to_dtype(self.spec.page_dtype))
+        pools = {PAGED_CACHE_K: shape, PAGED_CACHE_V: shape}
+        if self._by_kind:
+            wshape = self._pool_shape(window=True)
+            pools.update({PAGED_CACHE_KW: wshape, PAGED_CACHE_VW: wshape})
         if self._owns_pool:
             with self.executor.device_ctx():
-                self.scope.set(PAGED_CACHE_K, jnp.zeros(shape, page_dtype))
-                self.scope.set(PAGED_CACHE_V, jnp.zeros(shape, page_dtype))
+                for name, shp in pools.items():
+                    self.scope.set(name, jnp.zeros(shp, page_dtype))
         self.metrics.set_gauge(
             "mem/kv_cache_bytes",
-            2.0 * float(np.prod(shape)) * page_dtype.itemsize)
+            float(sum(np.prod(shp) for shp in pools.values()))
+            * page_dtype.itemsize)
         self.metrics.set_gauge("mem/kv_block_table_bytes",
                                float(self.slots * self.pmax * 4))
         self._gauges()
 
-    def _pool_shape(self):
+    def _pool_shape(self, window: bool = False):
         """[L, n_pages, page_size, Hkv*dh] in the spec's ``page_dtype``:
         a token's K (or V) of one layer is ONE contiguous row, so a page
         is contiguous and lane-dense on the device (ops/pipeline_ops.py
         says why the head-major [.., Hkv, page_size, dh] form was
-        not)."""
+        not). L counts the layers of the pool's kind: the full-attention
+        layers (every layer of a one-kind spec), or the window layers."""
         s = self.spec
-        return (s.n_layers, self.n_pages, self.page_size,
-                s.kv_heads * s.head_dim)
+        return (s.layers_of(window),
+                self.n_pages_window if window else self.n_pages,
+                self.page_size, s.kv_heads * s.head_dim)
 
-    def _cache_vars(self, helper):
-        shape = list(self._pool_shape())
-        ck = helper.create_global_variable(name=PAGED_CACHE_K, shape=shape,
-                                           dtype=self.spec.page_dtype)
-        cv = helper.create_global_variable(name=PAGED_CACHE_V, shape=shape,
-                                           dtype=self.spec.page_dtype)
-        return ck, cv
+    def _cache_vars(self, helper, window: bool = False):
+        shape = list(self._pool_shape(window))
+        names = ((PAGED_CACHE_KW, PAGED_CACHE_VW) if window
+                 else (PAGED_CACHE_K, PAGED_CACHE_V))
+        return tuple(helper.create_global_variable(
+            name=name, shape=shape, dtype=self.spec.page_dtype)
+            for name in names)
+
+    def _window_io(self, helper, table):
+        """The window kind's op inputs and outputs (its pools, read and
+        written in place, and its table); nothing for a one-kind spec."""
+        if not self._by_kind:
+            return {}, {}
+        ckw, cvw = self._cache_vars(helper, window=True)
+        return ({"CacheKW": [ckw], "CacheVW": [cvw], "BlockTableW": [table]},
+                {"CacheKW": [ckw], "CacheVW": [cvw]})
 
     def _lm_ins(self, helper):
         from ..models.transformer import _shared_lm_params
@@ -505,6 +574,8 @@ class GenerationEngine:
     def _prefill_feed_names(self):
         names = ["serving.chunk", "serving.start", "serving.chunk_len",
                  "serving.block_table", *self._SAMPLING_FEEDS]
+        if self._by_kind:
+            names.append("serving.block_table_w")
         if self.mask_plane:
             names.append("serving.mask")
         return names
@@ -513,6 +584,8 @@ class GenerationEngine:
     def _decode_feed_names(self):
         names = ["serving.tok", "serving.pos", "serving.block_table",
                  *self._SAMPLING_FEEDS]
+        if self._by_kind:
+            names.append("serving.block_table_w")
         if self.mask_plane:
             names.append("serving.mask")
         return names
@@ -611,6 +684,12 @@ class GenerationEngine:
             ins.update(self._sampling_vars(None))
             ins.update(self._lm_ins(helper))
             outs = {"NextTok": [nxt], "CacheK": [ck], "CacheV": [cv]}
+            if self._by_kind:
+                w_ins, w_outs = self._window_io(helper, data_layer(
+                    "serving.block_table_w", shape=[self.pmax],
+                    dtype="int32"))
+                ins.update(w_ins)
+                outs.update(w_outs)
             outs.update(self._beam_out_vars(helper, 0, "serving.pf"))
             outs.update(self._expert_out_vars(helper))
             helper.append_op("transformer_stack_paged_prefill", ins,
@@ -642,6 +721,12 @@ class GenerationEngine:
             ins.update(self._sampling_vars(self.slots))
             ins.update(self._lm_ins(helper))
             outs = {"NextTok": [nxt], "CacheK": [ck], "CacheV": [cv]}
+            if self._by_kind:
+                w_ins, w_outs = self._window_io(helper, data_layer(
+                    "serving.block_table_w", shape=[self.slots, self.pmax],
+                    dtype="int32", append_batch_size=False))
+                ins.update(w_ins)
+                outs.update(w_outs)
             outs.update(self._beam_out_vars(helper, self.slots,
                                             "serving.dec"))
             outs.update(self._expert_out_vars(helper))
@@ -655,7 +740,12 @@ class GenerationEngine:
 
     @property
     def _page_copy_prog(self):
-        if self._page_copy_prog_cache is None:
+        return self._page_copy_prog_of(False)
+
+    def _page_copy_prog_of(self, window: bool):
+        """The copy-on-write program of one kind's pools (built once)."""
+        cache = self._page_copy_prog_cache
+        if window not in cache:
             prog, startup = Program(), Program()
             with program_guard(prog, startup):
                 src = data_layer("serving.cow_src", shape=[1],
@@ -665,7 +755,7 @@ class GenerationEngine:
                 helper = LayerHelper("serving_page_copy",
                                      main_program=prog,
                                      startup_program=startup)
-                ck, cv = self._cache_vars(helper)
+                ck, cv = self._cache_vars(helper, window)
                 ok = helper.block.create_var(
                     name="serving.cow_ok", shape=[1], dtype="int32",
                     stop_gradient=True)
@@ -676,8 +766,8 @@ class GenerationEngine:
                     {"Ok": [ok], "CacheK": [ck], "CacheV": [cv]}, {})
             self._transpile(prog, ["serving.cow_src", "serving.cow_dst"],
                             [ok.name], "transpile/page_copy/")
-            self._page_copy_prog_cache = (prog, ok)
-        return self._page_copy_prog_cache
+            cache[window] = (prog, ok)
+        return cache[window]
 
     def _transpile(self, prog, feed_names, fetch_names, metric_prefix):
         """Run the inference pipeline over a freshly-built serving program
@@ -818,6 +908,9 @@ class GenerationEngine:
                     "serving.block_table": np.zeros((b, self.pmax),
                                                     np.int32),
                 }
+                if self._by_kind:
+                    feed["serving.block_table_w"] = np.zeros(
+                        (b, self.pmax), np.int32)
                 feed.update(self._neutral_sampling_feed(b))
                 self.executor.run(prog, feed=feed,
                                   fetch_list=self._fetches(outs),
@@ -827,6 +920,9 @@ class GenerationEngine:
         combos += 1
         self._run_page_copy(0, 0)  # scrap onto itself: harmless
         combos += 1
+        if self._by_kind:
+            self._run_page_copy(0, 0, window=True)
+            combos += 1
         self.metrics.inc("warmup_compiles", combos)
         self.save_manifest()
         return combos
@@ -906,8 +1002,9 @@ class GenerationEngine:
         return warmed
 
     # -- page bookkeeping -------------------------------------------------
-    def _run_page_copy(self, src: int, dst: int) -> None:
-        prog, ok = self._page_copy_prog
+    def _run_page_copy(self, src: int, dst: int,
+                       window: bool = False) -> None:
+        prog, ok = self._page_copy_prog_of(window)
         self.executor.run(
             prog, feed={"serving.cow_src": np.asarray([src], np.int32),
                         "serving.cow_dst": np.asarray([dst], np.int32)},
@@ -922,21 +1019,76 @@ class GenerationEngine:
         prefix per sequence lifetime."""
         for slot in decoding:
             st = self._slots[slot]
-            entry = int(self._pos[slot]) // self.page_size
-            pid = st.pages[entry]
-            if self.pool.refcount(pid) <= 1:
+            pos = int(self._pos[slot])
+            self._copy_if_shared(st, pos // self.page_size, window=False)
+            if self._by_kind:   # the window kind's page under that position
+                self._window_advance(st, pos, pos)
+                self._copy_if_shared(st, pos // self.page_size, window=True)
+
+    def _copy_if_shared(self, st: _Slot, entry: int, window: bool) -> None:
+        """Copy-on-write of one table entry of one kind's pool."""
+        pool, index, pages, held = (
+            (self.wpool, self.wprefix_index, st.wpages, "wcow") if window
+            else (self.pool, self.prefix_index, st.pages, "cow_reserve"))
+        pid = pages[entry]
+        if pool.refcount(pid) <= 1:
+            return
+        if getattr(st, held) > 0:
+            setattr(st, held, getattr(st, held) - 1)
+            new = pool.alloc(reserved=True)
+        else:  # defensive: never expected, but never corrupt a share
+            if pool.available() < 1 and index:
+                index.evict_until(1)
+            new = pool.alloc()
+        self._run_page_copy(pid, new, window=window)
+        pool.decref(pid)
+        pages[entry] = new
+        self.metrics.inc("kv_cow_copies")
+
+    # -- the window kind's table -------------------------------------------
+    def _window_first(self, pos: int) -> int:
+        """The first table entry a query at position ``pos`` can still
+        reach in a window layer (keys ``pos - window < j <= pos``)."""
+        return max(0, pos - self.spec.window + 1) // self.page_size
+
+    def _window_alloc(self, st: _Slot) -> int:
+        if st.wreserve > 0:
+            st.wreserve -= 1
+            return self.wpool.alloc(reserved=True)
+        # defensive: the admission hold covers every allocation
+        self.metrics.inc("kv_window_unreserved_allocs")
+        if self.wpool.available() < 1 and self.wprefix_index:
+            self.wprefix_index.evict_until(1)
+        return self.wpool.alloc()
+
+    def _window_advance(self, st: _Slot, q_first: int, q_last: int) -> None:
+        """Before a call whose queries for this slot sit at positions
+        ``q_first..q_last``: release the window pages no query of the
+        call (or any later one) can reach — a decref, so a page the
+        prefix index or another slot still holds lives on — and allocate,
+        out of the slot's hold, the entries the call writes. A page that
+        came back to the pool is held again at once while the slot still
+        has entries to come, so the hold never shrinks under it."""
+        keep_from = self._window_first(q_first)
+        for e in range(st.wfirst, min(keep_from, len(st.wpages))):
+            pid = st.wpages[e]
+            if not pid:
                 continue
-            if st.cow_reserve > 0:
-                st.cow_reserve -= 1
-                new = self.pool.alloc(reserved=True)
-            else:  # defensive: never expected, but never corrupt a share
-                if self.pool.available() < 1 and self.prefix_index:
-                    self.prefix_index.evict_until(1)
-                new = self.pool.alloc()
-            self._run_page_copy(pid, new)
-            self.pool.decref(pid)
-            st.pages[entry] = new
-            self.metrics.inc("kv_cow_copies")
+            st.wpages[e] = 0
+            self.metrics.inc("kv_window_pages_released")
+            if self.wpool.decref(pid) \
+                    and st.wreserve < st.wentries - len(st.wpages):
+                self.wpool.reserve(1)
+                st.wreserve += 1
+        st.wfirst = max(st.wfirst, keep_from)
+        while len(st.wpages) <= q_last // self.page_size:
+            st.wpages.append(self._window_alloc(st)
+                             if len(st.wpages) >= st.wfirst else 0)
+
+    def _window_row(self, st: _Slot) -> np.ndarray:
+        row = np.zeros(self.pmax, np.int32)
+        row[:len(st.wpages)] = st.wpages
+        return row
 
     def _register_prefix(self, st: _Slot,
                          include_tail: bool = False) -> None:
@@ -945,18 +1097,29 @@ class GenerationEngine:
         their content is prefilled; the partial tail page only at finish
         (an index reference on a page the request still writes would
         force a pointless self-copy-on-write)."""
-        if self.prefix_index is None or st.prefill_done < st.prompt.size:
+        if self.prefix_index is None:
             return
         ps = self.page_size
         prompt = st.prompt
-        n_full = prompt.size // ps
+        done = st.prefill_done >= prompt.size
+        # a cache held by kind writes both kinds' indexes in lockstep, page
+        # by page as the chunks complete: a window page must be indexed
+        # before the slot moves past it and lets it go
+        if not done and not self._by_kind:
+            return
+
+        def insert(key, toks, i):
+            if i < len(st.wpages) and st.wpages[i]:
+                self.wprefix_index.insert(key, toks, st.wpages[i])
+            return self.prefix_index.insert(key, toks, st.pages[i])
+
+        n_full = min(st.prefill_done, prompt.size) // ps
         key = b""
         for i in range(n_full):
-            key = self.prefix_index.insert(
-                key, prompt[i * ps:(i + 1) * ps], st.pages[i])
+            key = insert(key, prompt[i * ps:(i + 1) * ps], i)
         tail = prompt[n_full * ps:]
-        if include_tail and tail.size:
-            self.prefix_index.insert(key, tail, st.pages[n_full])
+        if include_tail and done and tail.size:
+            insert(key, tail, n_full)
 
     def _release_pages(self, st: _Slot) -> None:
         if self._prefix_sharing:
@@ -967,6 +1130,13 @@ class GenerationEngine:
         if st.cow_reserve:
             self.pool.release_reservation(st.cow_reserve)
             st.cow_reserve = 0
+        for pid in st.wpages:
+            if pid:
+                self.wpool.decref(pid)
+        st.wpages = []
+        if st.wreserve + st.wcow:
+            self.wpool.release_reservation(st.wreserve + st.wcow)
+            st.wreserve = st.wcow = 0
 
     # -- admission ---------------------------------------------------------
     def _validate(self, req: Request):
@@ -1010,6 +1180,10 @@ class GenerationEngine:
         except (ValueError, TypeError) as exc:
             raise BadRequestError(str(exc))
         if beam is not None:
+            if self._by_kind:
+                raise BlockNotSupportedError(
+                    "beam search forks one block table; this engine's "
+                    "cache is held by layer kind")
             if not self.beam_width:
                 raise BadRequestError(
                     "beam request on an engine built without the beam "
@@ -1052,6 +1226,7 @@ class GenerationEngine:
                 and r.payload.get("handoff") is not None]
         adopted = 0
         if hand:
+            self.spec.block.require_one_kind("a serialized KV handoff")
             # cross-process KV migration: the payload carries serialized
             # page ranges + the block table; installation writes the
             # bytes and resumes decode — never a prefill recompute
@@ -1068,7 +1243,7 @@ class GenerationEngine:
         for req in requests:
             try:
                 todo.append((req, *self._validate(req)))
-            except BadRequestError as exc:
+            except (BadRequestError, BlockNotSupportedError) as exc:
                 self.metrics.inc("bad_requests")
                 req.end_trace(status="bad_request")
                 req.future.set_exception(exc)
@@ -1157,25 +1332,76 @@ class GenerationEngine:
         shared, spages = 0, []
         if self.prefix_index is not None:
             shared, spages, _ = self.prefix_index.lookup(prompt)
+        wspages, wkeep, wneed = [], 0, 0
+        if self._by_kind:
+            # a hit is as long as BOTH kinds' indexes hold it
+            if self.wprefix_index is not None:
+                wshared, wspages, _ = self.wprefix_index.lookup(prompt)
+                shared = min(shared, wshared)
+                spages = spages[:self._entries_for(shared)]
+                wspages = wspages[:self._entries_for(shared)]
+            # of the hit the slot holds only what its next query reaches
+            wkeep = self._window_first(shared if shared < plen
+                                       else plen - 1)
         own = entries_total - len(spages)
         cow = 1 if shared == plen else 0  # generation writes a shared page
         need = own + cow
+        if self._by_kind:
+            # the window hold: the live window, plus the full prompt pages
+            # the slot will leave to the prefix index (they stay resident
+            # when it lets them go, so their successors need pages too)
+            donate = (plen - shared) // self.page_size \
+                if self.wprefix_index is not None else 0
+            wneed = min(entries_total - len(wspages),
+                        self._wlive + donate) + cow
+            if wneed > self.wpool.capacity:
+                exc = CacheExhaustedError(
+                    f"prompt ({plen}) + max_new_tokens ({max_new}) needs "
+                    f"{wneed} window-layer pages but that pool holds only "
+                    f"{self.wpool.capacity} — shrink the request or grow "
+                    f"n_pages_window",
+                    pages_needed=wneed, pages_free=self.wpool.capacity)
+                self.metrics.inc("cache_exhausted")
+                req.end_trace(status="cache_exhausted")
+                req.future.set_exception(exc)
+                return "failed"
         for pid in spages:  # hold the prefix before any eviction runs
             self.pool.incref(pid)
+        for pid in wspages[wkeep:]:
+            self.wpool.incref(pid)
+        short = None
         if self.pool.available() < need:
             if self.prefix_index is not None:
                 self.prefix_index.evict_until(need)
             if self.pool.available() < need:
-                for pid in spages:
-                    self.pool.decref(pid)
-                self.metrics.inc("admission_deferred")
-                return "defer"
+                short = "admit_deferred_global"
+        if short is None and self._by_kind \
+                and self.wpool.available() < wneed:
+            if self.wprefix_index is not None:
+                self.wprefix_index.evict_until(wneed)
+            if self.wpool.available() < wneed:
+                short = "admit_deferred_window"
+        if short is not None:
+            for pid in spages:
+                self.pool.decref(pid)
+            for pid in wspages[wkeep:]:
+                self.wpool.decref(pid)
+            self.metrics.inc("admission_deferred")
+            if self._by_kind:
+                self.metrics.inc(short)
+            return "defer"
         owned = [self.pool.alloc() for _ in range(own)]
         if cow:
             self.pool.reserve(cow)
         slot = self._slots.index(None)
         st = _Slot(req, prompt, max_new, eos, sampling)
         st.pages = list(spages) + owned
+        if self._by_kind:
+            self.wpool.reserve(wneed)
+            st.wpages = [0] * min(wkeep, len(wspages)) + wspages[wkeep:]
+            st.wfirst = wkeep
+            st.wentries = entries_total
+            st.wreserve, st.wcow = wneed - cow, cow
         st.shared_tokens = shared
         st.cow_reserve = cow
         st.prefill_done = shared
@@ -1268,6 +1494,7 @@ class GenerationEngine:
         start = np.zeros(bucket, np.int32)
         length = np.zeros(bucket, np.int32)
         table = np.zeros((bucket, self.pmax), np.int32)
+        table_w = np.zeros_like(table)
         feed = self._neutral_sampling_feed(bucket)
         for row, (req, st, slot) in enumerate(group):
             r = rem[row]
@@ -1275,6 +1502,10 @@ class GenerationEngine:
             start[row] = st.prefill_done
             length[row] = r
             table[row, :len(st.pages)] = st.pages
+            if self._by_kind:
+                self._window_advance(st, st.prefill_done,
+                                     st.prompt.size - 1)
+                table_w[row] = self._window_row(st)
             # step = tokens already sampled: 0 for a fresh request; a
             # RESUMED one samples its next token at step len(emitted),
             # keeping (seed, step) aligned with the uninterrupted stream
@@ -1283,6 +1514,8 @@ class GenerationEngine:
         feed.update({"serving.chunk": chunk, "serving.start": start,
                      "serving.chunk_len": length,
                      "serving.block_table": table})
+        if self._by_kind:
+            feed["serving.block_table_w"] = table_w
         prog, outs = self._prefill_prog(tc)
         t0 = time.perf_counter()
         with trace.span("serving/prefill_group", rows=len(group),
@@ -1475,10 +1708,21 @@ class GenerationEngine:
         feed.update({"serving.chunk": chunk, "serving.start": start,
                      "serving.chunk_len": length,
                      "serving.block_table": table})
+        ctx_pages = {}
+        if self._by_kind:
+            last = start0 + k - 1
+            self._window_advance(st, start0, last)
+            table_w = np.zeros((bucket, self.pmax), np.int32)
+            table_w[0] = self._window_row(st)
+            feed["serving.block_table_w"] = table_w
+            # the pages of context each kind's layers attend this chunk
+            ctx_pages = {
+                "ctx_pages_global": last // self.page_size + 1,
+                "ctx_pages_window": last // self.page_size + 1 - st.wfirst}
         prog, outs = self._prefill_prog(tc)
         t0 = time.perf_counter()
         with trace.span("serving/prefill_chunk", slot=slot,
-                        offset=start0, tokens=k):
+                        offset=start0, tokens=k, **ctx_pages):
             res = self.executor.run(prog, feed=feed,
                                     fetch_list=self._fetches(outs),
                                     scope=self.scope)
@@ -1492,6 +1736,8 @@ class GenerationEngine:
                          parent=st.request.span, phase="prefill_chunk",
                          slot=slot, offset=start0, tokens=k)
         st.prefill_done = start0 + k
+        if self._by_kind:
+            self._register_prefix(st)   # page by page, before they go
         if st.prefill_done >= plen:
             self.metrics.inc("prefills")
             first = np.asarray(res[0])
@@ -1511,6 +1757,7 @@ class GenerationEngine:
 
     def _run_decode(self):
         table = np.zeros((self.slots, self.pmax), np.int32)
+        table_w = np.zeros_like(table)
         tok = np.zeros(self.slots, np.int64)
         pos = np.zeros(self.slots, np.int32)
         feed = self._neutral_sampling_feed(self.slots)
@@ -1520,6 +1767,8 @@ class GenerationEngine:
                 tok[s] = self._tok[s]
                 pos[s] = self._pos[s]
                 table[s, :len(st.pages)] = st.pages
+                if self._by_kind:
+                    table_w[s] = self._window_row(st)
                 # step = tokens this request has sampled so far — a pure
                 # function of the request, never of the batch around it
                 self._slot_sampling_feed(s, st, feed,
@@ -1529,9 +1778,35 @@ class GenerationEngine:
         # the pages the decode attention walks this tick (one per slot at
         # least: a vacant slot reads the scrap page) against the table it
         # would gather whole (kernels/paged_attention.py)
-        self.metrics.inc("paged_attn_pages_read",
-                         int((pos // self.page_size + 1).sum()))
-        self.metrics.inc("paged_attn_table_pages", table.size)
+        held = pos // self.page_size + 1
+        if self._by_kind:
+            feed["serving.block_table_w"] = table_w
+            # per layer of each kind: a full-attention layer walks what a
+            # slot holds, a window layer from its window's first page
+            self.metrics.inc("paged_attn_pages_read_global", int(held.sum()))
+            self.metrics.inc("paged_attn_pages_read_window", int(
+                (held - np.maximum(pos + 1 - self.spec.window, 0)
+                 // self.page_size).sum()))
+            # pages some SLOT holds (a page shared by several counts once;
+            # what only a prefix index still caches is evictable and is
+            # left to ``cache_stats``), by kind, against what ONE table for
+            # all layers would hold for the same slots: every page of
+            # every sequence, as the full-attention kind does
+            # (recounted only after a pool changed hands: ``changes``)
+            for name, pool, attr in (("global", self.pool, "pages"),
+                                     ("window", self.wpool, "wpages")):
+                at, n = self._held.get(name, (-1, 0))
+                if at != pool.changes:
+                    n = np.unique(np.fromiter(
+                        (p for st in self._slots if st is not None
+                         for p in getattr(st, attr) if p), np.int64)).size
+                    self._held[name] = (pool.changes, n)
+                self.metrics.inc(f"kv_pages_held_{name}", n)
+                if name == "global":
+                    self.metrics.inc("kv_pages_uniform_equiv", n)
+        else:
+            self.metrics.inc("paged_attn_pages_read", int(held.sum()))
+            self.metrics.inc("paged_attn_table_pages", table.size)
         prog, outs = self._decode_prog
         res = self.executor.run(prog, feed=feed,
                                 fetch_list=self._fetches(outs),
@@ -1777,6 +2052,11 @@ class GenerationEngine:
         self.metrics.set_gauge("mem/kv_pages_free",
                                self.pool.available())
         self.metrics.set_gauge("beam_active_jobs", len(self._beam_jobs))
+        if self._by_kind:
+            self.metrics.set_gauge("mem/kv_window_pages_in_use",
+                                   self.wpool.pages_in_use())
+            self.metrics.set_gauge("mem/kv_window_pages_free",
+                                   self.wpool.available())
         if self.prefix_index is not None:
             self.metrics.set_gauge("kv_prefix_entries",
                                    len(self.prefix_index))
@@ -1812,6 +2092,11 @@ class GenerationEngine:
         }
         if self.prefix_index is not None:
             state["prefix_index"] = self.prefix_index.stats()
+        if self._by_kind:
+            # "pool" is then the full-attention layers' alone
+            state["pool_window"] = self.wpool.stats()
+            if self.wprefix_index is not None:
+                state["prefix_index_window"] = self.wprefix_index.stats()
         return state
 
     def cache_stats(self) -> dict:
@@ -1824,6 +2109,9 @@ class GenerationEngine:
         if self.prefix_index is not None:
             for k, v in self.prefix_index.stats().items():
                 stats[f"kv_prefix_{k}"] = v
+        if self._by_kind:   # kv_pages_* are then the full-attention kind's
+            for k, v in self.wpool.stats().items():
+                stats[f"kv_window_pages_{k}"] = v
         return stats
 
     # -- mid-stream chaos: hard engine death ------------------------------
@@ -1932,6 +2220,8 @@ class GenerationEngine:
                                   metrics=self.metrics)
         if self.prefix_index is not None:
             dropped = self.prefix_index.clear()
+            if self.wprefix_index is not None:
+                self.wprefix_index.clear()
             if dropped:
                 self.metrics.inc("prefix_entries_invalidated", dropped)
             self._gauges()
@@ -1960,6 +2250,7 @@ class GenerationEngine:
         refcount; cross-process: ``disagg.serialize_handoff`` moves the
         page bytes. Either way the migration is the block table + pages
         — never a prefill recompute."""
+        self.spec.block.require_one_kind("export_slot (the KV handoff)")
         st = self._slots[slot]
         if st is None or st.state != "decode" or st.beam_job is not None \
                 or st.xrow is not None:
@@ -1977,6 +2268,7 @@ class GenerationEngine:
         pages' refcounts simply transfer with the block table. Returns
         the slot index; decode resumes on the next tick, bit-identically
         (copy-on-write still guards any page the prefix index shares)."""
+        self.spec.block.require_one_kind("adopt_slot (the KV handoff)")
         if handoff.get("pool") is not self.pool:
             raise ValueError(
                 "same-process adoption needs a shared page pool — build "

@@ -5,7 +5,10 @@ K/V (generation.py owns those tensors); THIS module owns the metadata —
 which physical pages are free, how many holders reference each page, and
 which pages cache which prompt prefixes. Everything here is plain Python
 over numpy ints: no device traffic, no locks (the engine is single-
-threaded per tick).
+threaded per tick). A model whose layers differ in kind (full attention /
+a sliding window) has one such pool, ``PagePool`` and ``PrefixIndex`` PER
+KIND: both invariants below hold for each, and the engine keeps the two
+indexes in lockstep (the same content keys, page by page).
 
 Two invariants the engine relies on:
 
@@ -53,6 +56,10 @@ class PagePool:
         self._ref = np.zeros(self.n_pages, np.int32)
         self._ref[SCRAP_PAGE] = 1  # pinned
         self._reserved = 0
+        #: alloc + incref + decref calls so far: whoever holds a page got
+        #: it, or let it go, through one of them, so a count of pages by
+        #: holder stays valid for as long as this stands still
+        self.changes = 0
 
     # -- accounting --------------------------------------------------------
     @property
@@ -97,6 +104,7 @@ class PagePool:
             raise RuntimeError("page pool exhausted")
         page = self._free.pop()
         self._ref[page] = 1
+        self.changes += 1
         return page
 
     def alloc_many(self, n: int) -> List[int]:
@@ -112,6 +120,7 @@ class PagePool:
         if page == SCRAP_PAGE or self._ref[page] < 1:
             raise RuntimeError(f"incref of unallocated page {page}")
         self._ref[page] += 1
+        self.changes += 1
 
     def decref(self, page: int) -> bool:
         """Drop one reference; returns True when the page was freed."""
@@ -120,6 +129,7 @@ class PagePool:
         if self._ref[page] < 1:
             raise RuntimeError(f"decref of free page {page}")
         self._ref[page] -= 1
+        self.changes += 1
         if self._ref[page] == 0:
             self._free.append(page)
             return True

@@ -255,6 +255,79 @@ def test_decode_step_on_the_v5e_keeps_the_pool_the_scan_carry(one_chip,
         "fusion", "fusion", "scatter", "scatter"]
 
 
+def test_decode_step_with_layer_kinds_on_the_v5e_walks_both_pools(
+        one_chip, monkeypatch):
+    """The decode op of a stack with layer kinds at SmallThinker-21BA3B's
+    published widths (2560; 28 query / 4 KV heads of 128; 64 ReGLU experts
+    of 768, top-6; window 4096; vocabulary 151936) and one period (a
+    global NoPE layer, three window RoPE layers), compiled for the chip:
+    every layer's attention is the kernel — grouped queries, and for the
+    window kind a walk that starts at the window — against its OWN kind's
+    pool, and nothing shaped like a gathered table-width context or a
+    copied pool is compiled in."""
+    from paddle_tpu.lm_spec import LMSpec
+    from paddle_tpu.ops.pipeline_ops import transformer_stack_paged_decode
+
+    spec = LMSpec(
+        vocab_size=151936, d_model=2560, n_layers=4, num_heads=28,
+        num_kv_heads=4, head_dim=128, use_rope=True, max_len=16384,
+        norm="rms_norm", norm_eps=1e-6, rope_theta=1.5e6,
+        rope_pairing="half", ffn="swiglu_moe", num_experts=64,
+        experts_per_tok=6, d_expert=768, norm_topk_prob=True, bias=False,
+        param_dtype="bfloat16", page_dtype="bfloat16",
+        layer_pattern=("full+nope", "window+rope", "window+rope",
+                       "window+rope"), window=4096, expert_act="relu",
+        router_input="attn_input")
+    slots, ps, table_width, pages_g, pages_w = 32, 64, 192, 3072, 1792
+    bf = "bfloat16"
+    shapes = {
+        "Tok": ((slots,), "int32"), "Pos": ((slots,), "int32"),
+        "BlockTable": ((slots, table_width), "int32"),
+        "BlockTableW": ((slots, table_width), "int32"),
+        "CacheK": ((1, pages_g, ps, 512), bf),
+        "CacheV": ((1, pages_g, ps, 512), bf),
+        "CacheKW": ((3, pages_w, ps, 512), bf),
+        "CacheVW": ((3, pages_w, ps, 512), bf),
+        "TokEmb": ((spec.vocab_size, spec.d_model), bf),
+        "FinalLnS": ((spec.d_model,), bf),
+        "HeadW": ((spec.d_model, spec.vocab_size), bf)}
+    for slot, _key, shape, _fan in spec.stack_planes():
+        shapes[slot] = ((spec.n_layers, *shape), bf)
+    names = sorted(shapes)
+    attrs = dict(spec.block.attrs(), page_size=ps)
+
+    def step(*args):
+        outs = transformer_stack_paged_decode(
+            attrs, {k: [a] for k, a in zip(names, args)})
+        return {k: v[0] for k, v in outs.items()}
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    donate = tuple(names.index(n) for n in ("CacheK", "CacheV", "CacheKW",
+                                            "CacheVW"))
+    text = jax.jit(step, donate_argnums=donate).lower(*[
+        jax.ShapeDtypeStruct(shapes[n][0], shapes[n][1], sharding=one_chip)
+        for n in names]).compile().as_text()
+    flat = re.sub(r"\{[^{}]*\}", "", text)
+    calls = [ln for ln in flat.splitlines()
+             if "custom-call(" in ln and "%paged_attention_decode" in ln
+             and "tpu_custom_call" in ln]
+    # one call a layer of the period (the loop has a single trip and is
+    # unrolled): one against the global pool, three against the window's
+    assert len(calls) == 4
+    assert sum(f"bf16[1,{pages_g},{ps},512]" in c for c in calls) == 1
+    assert sum(f"bf16[3,{pages_w},{ps},512]" in c for c in calls) == 3
+    ops = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", flat, re.M)
+    pools = (f"[1,{pages_g},{ps},512]", f"[3,{pages_w},{ps},512]")
+    moved = {op for shape, op in ops if shape.endswith(pools)
+             and not shape.startswith("(")}
+    assert moved <= {"parameter", "get-tuple-element", "scatter", "fusion",
+                     "bitcast"}, moved
+    # no context gathered at the table's width (or the window's span)
+    assert not [shape for shape, _ in ops
+                if f"[{slots * table_width},{ps},512]" in shape
+                or f"[{slots},{table_width * ps}," in shape]
+
+
 def test_stacked_train_step_on_the_v5e_runs_its_forward_scan_once(
         one_chip, monkeypatch):
     """The stacked LM's whole train step (GPT-2 medium's width, heads and
@@ -340,7 +413,7 @@ def _run_layers(monkeypatch, backend, dtype=jnp.float32, heads=4, kv_heads=4,
 
     mask = (dict(causal=True, q_pos0=pos) if causal
             else dict(lengths=pos + t))
-    out, ck2, cv2, _ = _scan_paged_layers(
+    out, ck2, cv2, _, _ = _scan_paged_layers(
         {"w": jnp.linspace(0.5, 1.5, L)[:, None]}, h, ck, cv, table,
         page_id, at % ps, project, mask,
         lambda p, x, ctx, _x: (x + ctx.astype(x.dtype), None))
@@ -362,7 +435,8 @@ def test_a_decode_step_on_a_chip_takes_the_kernel(monkeypatch):
     ("a prefill chunk: t > 1", dict(backend="tpu", t=4, causal=True)),
     ("block-causal mask", dict(backend="tpu", causal=True)),
     ("row narrower than the lanes", dict(backend="tpu", heads=2, kv_heads=2)),
-    ("grouped-query: Hkv < H", dict(backend="tpu", heads=8, kv_heads=4)),
+    ("grouped-query over a row narrower than the lanes",
+     dict(backend="tpu", heads=4, kv_heads=2)),
     ("page of half a bf16 tile", dict(backend="tpu", dtype=jnp.bfloat16,
                                       ps=8)),
 ])
@@ -382,7 +456,8 @@ def test_supported_reads_shapes_dtype_and_backend_only(monkeypatch, dtype,
     pool = jax.ShapeDtypeStruct((2, 8, ps, 256), dtype)
     assert paged_attention.supported(256, pool, 1) is ok
     assert not paged_attention.supported(256, pool, 2)
-    assert not paged_attention.supported(512, pool, 1)      # Hkv < H
+    assert paged_attention.supported(512, pool, 1) is ok    # Hkv = H / 2
+    assert not paged_attention.supported(384, pool, 1)      # no whole groups
     narrow = jax.ShapeDtypeStruct((2, 8, ps, 64), dtype)
     assert not paged_attention.supported(64, narrow, 1)
 

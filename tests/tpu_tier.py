@@ -675,12 +675,14 @@ def fused_head_matches_unfused():
 
 @check
 def paged_attention_decode_matches_reference():
-    """The paged decode kernel at the two serving rows (16 x 64 float32,
-    16 x 128 bf16; pages of 64) against a float64 softmax over each row's
+    """The paged decode kernel at the three serving rows (16 x 64 float32,
+    16 x 128 bf16, and 28 query over 4 KV heads of 128 bf16 under a window
+    of 100 keys; pages of 64) against a float64 softmax over each row's
     own pages: float32 pages as exact as the gathered XLA reference
     (1e-4), bf16 pages no further off than that reference is. Vacant
     slot, page boundary, full table, shared and permuted pages; every
-    page no row holds is NaN."""
+    page no row holds is NaN, and under a window so is every table entry
+    behind the window's first page."""
     import jax.numpy as jnp
     from paddle_tpu.kernels.flash_attention import reference_attention
     from paddle_tpu.kernels.paged_attention import paged_attention_decode
@@ -694,30 +696,41 @@ def paged_attention_decode_matches_reference():
         table[s, :len(pages)] = pages
     held = sorted({0} | {p for r in rows for p in r})
     detail = []
-    for dtype, H, dh in ((jnp.float32, 16, 64), (jnp.bfloat16, 16, 128)):
+    for dtype, H, Hkv, dh, window in ((jnp.float32, 16, 16, 64, None),
+                                      (jnp.bfloat16, 16, 16, 128, None),
+                                      (jnp.bfloat16, 28, 4, 128, 100)):
         rng = np.random.RandomState(11)
-        pools = [jnp.asarray(rng.randn(L, N, ps, H * dh), dtype)
+        G = H // Hkv
+        pools = [jnp.asarray(rng.randn(L, N, ps, Hkv * dh), dtype)
                  for _ in range(2)]
+        first = np.zeros(len(rows), np.int64)
+        walked = table.copy()
+        if window is not None:      # entries behind the window: poisoned
+            first = np.maximum(lengths - window, 0)
+            for s in range(len(rows)):
+                walked[s, :first[s] // ps] = 38
         q = jnp.asarray(2 * rng.randn(len(rows), H, dh), dtype)
         poison = np.ones((L, N, 1, 1), bool)
         poison[layer, held] = False
         ck, cv = (jnp.where(jnp.asarray(poison), jnp.nan, a) for a in pools)
+        kw = {} if window is None else {"window": window}
         got = np.asarray(paged_attention_decode(
-            q, ck, cv, jnp.int32(layer), jnp.asarray(table),
-            jnp.asarray(lengths)).astype(jnp.float32), np.float64)
+            q, ck, cv, jnp.int32(layer), jnp.asarray(walked),
+            jnp.asarray(lengths), **kw).astype(jnp.float32), np.float64)
         xla = reference_attention(
-            q[:, :, None], _gather_pages(pools[0], layer, table, H),
-            _gather_pages(pools[1], layer, table, H),
-            lengths=jnp.asarray(lengths))
+            q[:, :, None], _gather_pages(pools[0], layer, table, Hkv),
+            _gather_pages(pools[1], layer, table, Hkv),
+            lengths=jnp.asarray(lengths), **kw)
         xla = np.asarray(xla.astype(jnp.float32), np.float64).reshape(
             len(rows), -1)
         q64, k64, v64 = (np.asarray(a.astype(jnp.float32), np.float64)
                          for a in (q, *pools))
         want = np.zeros_like(got)
         for s in range(len(rows)):
-            n = int(lengths[s])
-            k = k64[layer, table[s]].reshape(-1, H, dh)[:n]
-            v = v64[layer, table[s]].reshape(-1, H, dh)[:n]
+            n, n0 = int(lengths[s]), int(first[s])
+            k = k64[layer, table[s]].reshape(-1, Hkv, dh)[n0:n]
+            v = v64[layer, table[s]].reshape(-1, Hkv, dh)[n0:n]
+            k, v = (np.repeat(a, G, axis=1) for a in (k, v))
             sc = np.einsum("hd,jhd->hj", q64[s], k) / np.sqrt(dh)
             p = np.exp(sc - sc.max(-1, keepdims=True))
             p /= p.sum(-1, keepdims=True)
@@ -726,8 +739,8 @@ def paged_attention_decode_matches_reference():
         tol = 1e-4 if dtype == jnp.float32 else max(
             2 * ref_err, 2.0 ** -8 * np.abs(want).max())
         assert np.isfinite(got).all() and err <= tol, (str(dtype), err, tol)
-        detail.append(f"{jnp.dtype(dtype).name} err {err:.2e} "
-                      f"(gathered reference {ref_err:.2e})")
+        detail.append(f"{jnp.dtype(dtype).name} {H}/{Hkv}x{dh} err "
+                      f"{err:.2e} (gathered reference {ref_err:.2e})")
     return "; ".join(detail)
 
 
