@@ -1775,6 +1775,15 @@ class GenerationEngine:
                                          step=len(st.generated))
         feed.update({"serving.tok": tok, "serving.pos": pos,
                      "serving.block_table": table})
+        # rows whose top-k / top-p cut-off the sampling plane has to search
+        # for this tick (kernels/sampling.py runs a search only when some
+        # row asks; a vacant slot is fed greedy and never does)
+        topk, topp = feed["serving.topk"], feed["serving.topp"]
+        asking = int(((feed["serving.temp"] > 0) & (
+            ((topk > 0) & (topk < self.spec.vocab_size)) | (topp < 1))
+        ).sum())
+        self.metrics.inc("sample_filter_ticks", int(asking > 0))
+        self.metrics.inc("sample_filter_rows", asking)
         # the pages the decode attention walks this tick (one per slot at
         # least: a vacant slot reads the scrap page) against the table it
         # would gather whole (kernels/paged_attention.py)

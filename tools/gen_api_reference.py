@@ -66,6 +66,10 @@ MODULES = [
     ("paddle_tpu.kernels.flash_attention", "Pallas flash attention"),
     ("paddle_tpu.kernels.paged_attention",
      "Pallas paged decode attention: walks the block table"),
+    ("paddle_tpu.kernels.sampling",
+     "Per-request sampling plane: each decode row's own temperature / "
+     "top-k / top-p / seed; cut-offs by a counted search, run only when "
+     "a live row asks"),
 ]
 
 
