@@ -25,7 +25,8 @@ Mix parameters: see ``benchmark/traffic.py`` for the traffic; ``engine``
 (slots, page_size, n_pages, prompt_buckets, prefill_batch_buckets,
 prefill_chunk), ``server`` (max_wait_ms, max_queue), ``ramp_s``,
 ``drain_s``, ``trace_after_s``, ``trace_slice_s``, ``slo`` (ttft_ms,
-mean_gap_ms), ``check`` (greedy_requests, logit_gap_tol).
+mean_gap_ms and, optionally, ttft_ms_per_prompt_token: see ``slo_met``),
+``check`` (greedy_requests, logit_gap_tol).
 """
 from __future__ import annotations
 
@@ -37,6 +38,8 @@ from typing import List, Optional
 import numpy as np
 
 from benchmark import harness, traffic
+from benchmark.layer_metrics import (gaps_over_p95_mode_pct,
+                                     gaps_over_tick_pct)
 from benchmark.trace_reduce import percentile
 
 
@@ -81,6 +84,43 @@ def _engine_counters(eng) -> dict:
     out["fresh_compiles"] = eng.executor.cache_stats()["fresh_compiles"]
     out["cache_misses"] = eng.cache_stats()["misses"]
     return out
+
+
+def slo_met(slo: dict, prompt_tokens: int, first_ms: float,
+            mean_gap_ms: float) -> bool:
+    """Whether an ANSWERED request met the mix's limits: time to first
+    token within ``ttft_ms`` + ``ttft_ms_per_prompt_token`` (default 0:
+    the flat limit) x its prompt's tokens, and mean gap between its
+    tokens within ``mean_gap_ms``. A limit that grows with the prompt
+    makes the share a reading of the LOAD: under a flat one a prompt
+    with more than that much prefill of its own misses at any rate."""
+    ttft_limit = (slo["ttft_ms"]
+                  + slo.get("ttft_ms_per_prompt_token", 0.0) * prompt_tokens)
+    return first_ms <= ttft_limit and mean_gap_ms <= slo["mean_gap_ms"]
+
+
+#: edges, in medians of the window's gaps, of the notes' histogram
+_EDGES = (0.75, 1.25, 1.5, 2.0, 3.0, 4.0, 6.0, 10.0)
+
+
+def _by_median(gaps: List[float]) -> dict:
+    """Percent of the gaps in each stretch of multiples of their median:
+    where the modes lie, so that a reader of the notes can judge
+    ``gaps_over_tick_pct``'s cut."""
+    if not gaps:
+        return {}
+    med = float(np.median(gaps))
+    counts = np.histogram(np.asarray(gaps) / med,
+                          bins=(0.0,) + _EDGES + (np.inf,))[0]
+    lows = ("0",) + tuple(f"{e:g}" for e in _EDGES)
+    return {f"from_{lo}": 100.0 * int(c) / len(gaps)
+            for lo, c in zip(lows, counts)}
+
+
+def _in_flight(requests: List[Sent], t: float) -> int:
+    """Requests due before ``t`` whose last token had not come by then."""
+    return sum(1 for r in requests if r.due < t
+               and (not r.token_times or r.token_times[-1] >= t))
 
 
 def _sleep_until(t: float) -> None:
@@ -169,8 +209,8 @@ def run(cell, seed: int, seconds: float, traced: bool, devices,
         late.append((r.sent - r.due) * 1e3)
         mean_gap = ((ts[-1] - ts[0]) / (len(ts) - 1) * 1e3
                     if len(ts) > 1 else 0.0)
-        if (r.result is not None and first_ms <= slo["ttft_ms"]
-                and mean_gap <= slo["mean_gap_ms"]):
+        if r.result is not None and slo_met(slo, r.plan.prompt.size,
+                                            first_ms, mean_gap):
             met += 1
     failed = [r for r in due if r.result is None]
 
@@ -186,6 +226,7 @@ def run(cell, seed: int, seconds: float, traced: bool, devices,
                             f"got {r.result.size - p.size}")
         elif not np.array_equal(r.result[:p.size], p):
             problems.append(f"request {r.plan.index}: prompt not echoed")
+    malformed = len(problems)
     check = mix["check"]
     greedy = [r for r in due if r.result is not None
               and r.plan.sampling is None][:check["greedy_requests"]]
@@ -211,12 +252,22 @@ def run(cell, seed: int, seconds: float, traced: bool, devices,
             "decode_step_p50_ms": at_close["decode_step_p50_ms"],
             "window_fresh_compiles": delta["fresh_compiles"],
             "gen_late_ms": late, "ttft_ms": ttft, "slo_met": met,
-            "due": len(due),
+            "due": len(due), "gap_ms": gaps,
         },
         spans=log.spans,
         executors=executors,
         xplane=slice_.xplane if slice_ else None,
         live_peak_bytes=live_peak,
+        checks={
+            # at most the limit (None: no position to compare, which
+            # fails); at least one position; no request lost; none wrong
+            "logit_gap_max": {"value": gap_max if gaps_ref.size else None,
+                              "limit": check["logit_gap_tol"]},
+            "logit_gap_positions": {"value": int(gaps_ref.size),
+                                    "limit": 1},
+            "requests_failed": {"value": len(failed), "limit": 0},
+            "answers_malformed": {"value": malformed, "limit": 0},
+        },
         notes={
             "requests_due": len(due), "requests_failed": len(failed),
             "errors": sorted({r.error for r in failed if r.error})[:5],
@@ -230,6 +281,13 @@ def run(cell, seed: int, seconds: float, traced: bool, devices,
             "ttft_p90_ms": percentile(ttft, 90),
             "tpot_mean_ms": sum(gaps) / max(len(gaps), 1),
             "tpot_p50_ms": percentile(gaps, 50),
+            "tpot_p90_ms": percentile(gaps, 90),
+            "tpot_p99_ms": percentile(gaps, 99),
+            "gaps_over_tick_pct": (gaps_over_tick_pct.share(gaps)
+                                   if gaps else None),
+            "gaps_over_p95_mode_pct": (gaps_over_p95_mode_pct.share(gaps)
+                                       if gaps else None),
+            "gaps_by_median": _by_median(gaps),
             "decode_step_p50_ms": at_close["decode_step_p50_ms"],
             "gen_late_p95_ms": percentile(late, 95),
             "slo_attain_pct": 100.0 * met / max(len(due), 1),
@@ -240,7 +298,8 @@ def run(cell, seed: int, seconds: float, traced: bool, devices,
             "prefix_hit_tokens": delta.get("prefix_hit_tokens", 0),
             "admission_deferred": delta.get("admission_deferred", 0),
             "cache_misses_in_window": delta["cache_misses"],
-            "in_flight_at_close": sum(
-                1 for r in requests if r.due < t_close
-                and (not r.token_times or r.token_times[-1] >= t_close)),
+            "in_flight_at_close": _in_flight(requests, t_close),
+            "in_flight_over_window": [
+                _in_flight(requests, t_open + seconds * i / 8)
+                for i in range(1, 8)],
         })

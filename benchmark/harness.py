@@ -19,6 +19,7 @@ import importlib
 import json
 import os
 import shutil
+import sys
 import time
 from typing import Any, Dict, List, Optional
 
@@ -117,6 +118,9 @@ class Measured:
     xplane: Optional[str] = None            # the traced slice, if any
     live_peak_bytes: Optional[int] = None   # where the driver read it early
     notes: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    #: each number ``correct`` compared, beside its limit:
+    #: {name: {"value": .., "limit": ..}}; printed last, where given
+    checks: Dict[str, dict] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -335,4 +339,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         else:
             extra = []
         line["breakdown"] = trace.breakdown(extra_host_spans=extra)
+    if measured.checks:
+        line["checks"] = measured.checks    # the line's LAST key
+        for name, c in measured.checks.items():
+            print(f"check {name}: {c['value']} (limit {c['limit']})",
+                  file=sys.stderr, flush=True)
     return line

@@ -8,7 +8,9 @@ neither ``JAX_PLATFORMS`` nor a cache directory — the package resolves the
 compile cache: ``$JAX_COMPILATION_CACHE_DIR``, else ``<checkout>/.jax_cache``).
 Earlier lines of stdout are free-form JSON notes; the LAST line is the
 contract's object: ``correct``, ``attempted``, ``failed``, ``metrics``,
-``device`` and, with ``--trace 1``, ``breakdown``.
+``device``, with ``--trace 1`` ``breakdown``, and last ``checks``: each
+number the driver compared beside its limit (also the last lines of
+standard error).
 """
 import time
 

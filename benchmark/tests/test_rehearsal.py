@@ -59,7 +59,10 @@ def run(workload, traced, seconds=2.0, monkeypatch=None):
                                       "tiny-image", "tiny-serve"])
 def test_untraced_line_is_the_contract(workload):
     cell, line, _ = run(workload, traced=False)
-    assert set(line) == LINE_KEYS
+    # a driver that compares numbers prints each beside its limit, last
+    assert set(line) - {"checks"} == LINE_KEYS
+    assert "checks" not in line or list(line)[-1] == "checks"
+    assert ("checks" in line) == (cell.mix["kind"] == "serve")
     assert set(line["device"]) == DEVICE_KEYS
     assert line["device"]["platform"] == "cpu"      # never read as a chip
     assert line["correct"] is True and line["failed"] == 0
@@ -73,7 +76,8 @@ def test_untraced_line_is_the_contract(workload):
 @pytest.mark.parametrize("workload", ["tiny-train", "tiny-serve"])
 def test_traced_line_carries_the_layer_metrics(workload, monkeypatch):
     cell, line, out = run(workload, traced=True, monkeypatch=monkeypatch)
-    assert set(line) == LINE_KEYS | {"breakdown"}
+    assert set(line) - {"checks"} == LINE_KEYS | {"breakdown"}
+    assert "checks" not in line or list(line)[-1] == "checks"
     assert set(line["device"]) == DEVICE_KEYS | {"busy_s", "window_s"}
     assert 0 < line["device"]["busy_s"] < line["device"]["window_s"]
     names = {m["name"] for m in cell.per_layer}
