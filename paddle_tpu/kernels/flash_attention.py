@@ -49,7 +49,33 @@ def _out_struct(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
-def rotary(x, pos0=0, base=10000.0, pairing="interleaved"):
+def yarn_inv_freq(dim, base, scaling):
+    """The ``dim // 2`` rotary frequencies under YaRN (``scaling``: an
+    ``lm_spec.RopeScaling``). Pair i turns ``original_max * theta_i /
+    2 pi`` times in the original context, ``theta_i = base^(-2i/dim)``;
+    the pair index at which that is r turns is ``dim * ln(original_max /
+    (2 pi r)) / (2 ln base)``. Pairs below ``floor`` of it at ``beta_fast``
+    keep ``theta_i``, pairs above ``ceil`` of it at ``beta_slow`` get
+    ``theta_i / factor``, and in between the two are mixed linearly in
+    the pair index (the DeepSeek-V3 / HF ``yarn`` convention, whole-index
+    ends)."""
+    import numpy as np
+
+    half = dim // 2
+    theta = base ** (-np.arange(half, dtype=np.float64) / half)
+
+    def index_at(turns):
+        return (dim * math.log(scaling.original_max / (turns * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(index_at(scaling.beta_fast)), 0)
+    high = min(math.ceil(index_at(scaling.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    return jnp.asarray(theta / scaling.factor * ramp + theta * (1 - ramp),
+                       jnp.float32)
+
+
+def rotary(x, pos0=0, base=10000.0, pairing="interleaved", scaling=None):
     """Rotary position embedding over [B, H, T, D] heads, positions
     pos0..pos0+T-1: pair i rotates by pos * base^(-2i/D). ``pairing``
     says which two coordinates pair i is: ``"interleaved"`` (RoFormer,
@@ -58,11 +84,15 @@ def rotary(x, pos0=0, base=10000.0, pairing="interleaved"):
     per-layer encoder op and the stacked/decode path both call it; the
     offset form serves incremental decode. ``pos0`` may be a [B] array
     of PER-ROW offsets (the slot-decode path, where every batch row sits
-    at its own sequence position)."""
+    at its own sequence position). ``scaling`` (an ``lm_spec.RopeScaling``):
+    YaRN frequencies (``yarn_inv_freq``) and its cos / sin scale."""
     D = x.shape[-1]
     T = x.shape[2]
     half = D // 2
-    inv = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling is None:
+        inv = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        inv = yarn_inv_freq(D, base, scaling)
     pos0 = jnp.asarray(pos0, jnp.float32)
     if pos0.ndim:  # per-row offsets: [B] -> angles [B, T, half]
         pos = pos0[:, None] + jnp.arange(T, dtype=jnp.float32)[None, :]
@@ -74,6 +104,8 @@ def rotary(x, pos0=0, base=10000.0, pairing="interleaved"):
         ang = pos[:, None] * inv[None, :]  # [T, half]
         cos = jnp.cos(ang)[None, None].astype(x.dtype)
         sin = jnp.sin(ang)[None, None].astype(x.dtype)
+    if scaling is not None and scaling.cos_sin_scale != 1.0:
+        cos, sin = cos * scaling.cos_sin_scale, sin * scaling.cos_sin_scale
     if pairing == "half":
         x1, x2 = x[..., :half], x[..., half:]
         return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
@@ -160,7 +192,7 @@ def reference_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
     if H != Hkv:
         pg = p.reshape(p.shape[0], Hkv, rep, p.shape[2], p.shape[3])
         og = jnp.einsum("bgrqk,bgkd->bgrqd", pg, v)
-        return og.reshape(q.shape)
+        return og.reshape(q.shape[:3] + v.shape[-1:])
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 

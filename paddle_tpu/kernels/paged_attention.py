@@ -42,6 +42,15 @@ reads only the pages a row HOLDS, straight from the whole pool:
   ``max(0, length - w) // ps`` (pages behind it may be gone from the
   table) and masks inside that first page.
 
+* latent attention (``cache_v=None``, ``name=MLA_KERNEL``): in these terms
+  ONE cached head of the row's whole width under all H query heads (G = H),
+  the value the SAME tile as the key (the page is read once for both
+  roles: scores Q [H, W] @ tile^T, context P @ tile, of which the caller
+  keeps the latent's columns), and the scale handed in (``sm_scale``; the
+  caller's queries carry their own). The body, the page walk and the
+  online softmax are the ones above; the call has a name of its own
+  because its bytes are counted differently (one pool).
+
 The jnp gather + ``reference_attention`` stays the semantic ground truth
 and the path of every other shape (t > 1, CPU, unaligned widths):
 ``supported`` is the whole dispatch rule, read off the operands.
@@ -53,6 +62,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+#: the call names (``pallas_call(name=...)``): what the benchmark's
+#: roofline readers tell a call by
+KERNEL = "paged_attention_decode"
+MLA_KERNEL = "paged_mla_decode"
 
 #: Q_bd's rows are padded to this many (zero rows score 0 against every
 #: key and are masked out of the result): one sublane tile of bf16, two of
@@ -78,15 +92,22 @@ def supported(q_width: int, pool, t: int) -> bool:
             and ps % _sublane_tile(pool.dtype) == 0)
 
 
-def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                   kbuf, vbuf, sems, cur_ref, m_ref, l_ref, acc_ref, *,
-                   d_head, pmax, group=1, window=None):
+def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, *rest,
+                   d_head, pmax, group=1, window=None, sm_scale=None,
+                   shared_kv=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if shared_kv:       # one pool: the key tile is the value tile
+        o_ref, kbuf, sems, cur_ref, m_ref, l_ref, acc_ref = rest
+        v_hbm = vbuf = None
+    else:
+        (v_hbm, o_ref, kbuf, vbuf, sems, cur_ref, m_ref, l_ref,
+         acc_ref) = rest
     s, rows = pl.program_id(0), pl.num_programs(0)
     ps, width = kbuf.shape[1:]
-    sm_scale = 1.0 / math.sqrt(d_head)
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d_head)
     # indices clamped as the gather clamps them: a DMA outside the pool
     # would fault the chip where XLA reads the nearest page
     layer = jnp.clip(layer_ref[0], 0, k_hbm.shape[0] - 1)
@@ -103,8 +124,11 @@ def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     def page_copies(buf, row, i):
         page = jnp.clip(table_ref[row * pmax + i], 0, k_hbm.shape[1] - 1)
-        return (pltpu.make_async_copy(k_hbm.at[layer, page], kbuf.at[buf],
-                                      sems.at[0, buf]),
+        k_copy = pltpu.make_async_copy(k_hbm.at[layer, page], kbuf.at[buf],
+                                       sems.at[0, buf])
+        if shared_kv:
+            return (k_copy,)
+        return (k_copy,
                 pltpu.make_async_copy(v_hbm.at[layer, page], vbuf.at[buf],
                                       sems.at[1, buf]))
 
@@ -157,7 +181,8 @@ def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         for c in page_copies(buf, s, i):
             c.wait()
-        k, v = kbuf[buf], vbuf[buf]
+        k = kbuf[buf]
+        v = k if shared_kv else vbuf[buf]
         sc = jax.lax.dot_general(
             q_bd, k, dimension_numbers=(((1,), (1,)), ((), ())),
             precision=precision,
@@ -208,7 +233,8 @@ def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 def paged_attention_decode(q, cache_k, cache_v, layer, table, lengths,
-                           interpret=False, window=None):
+                           interpret=False, window=None, sm_scale=None,
+                           name=KERNEL):
     """Attention of one query token a row over the pages the row holds.
 
     q [b, H, dh] (cast to the pools' dtype), cache_k / cache_v the WHOLE
@@ -217,7 +243,12 @@ def paged_attention_decode(q, cache_k, cache_v, layer, table, lengths,
     lengths [b] int32 (keys j < length attend; 0 gives a zero row),
     ``window`` (static) keeps keys ``j >= length - window`` only -> the
     context [b, H*dh] in the pools' dtype, a token's heads side by side
-    as the out-projection reads them."""
+    as the out-projection reads them.
+
+    ``cache_v=None`` (latent attention): q [b, H, W] against the ONE pool
+    [L, N, ps, W], every head reading the row's whole width as key AND
+    value -> [b, H*W] (the caller keeps the latent's columns of each
+    head); ``sm_scale`` replaces 1/sqrt(dh); ``name`` is the call's."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -225,10 +256,12 @@ def paged_attention_decode(q, cache_k, cache_v, layer, table, lengths,
         raise ValueError(f"q must be [b, H, dh], got {q.shape}")
     b, heads, d_head = q.shape
     ps, width = cache_k.shape[2:]
+    shared_kv = cache_v is None
     if (heads * d_head) % width or width % d_head \
-            or cache_v.shape != cache_k.shape:
+            or (not shared_kv and cache_v.shape != cache_k.shape):
         raise ValueError(f"q {q.shape} does not match the pools "
-                         f"{cache_k.shape} / {cache_v.shape}")
+                         f"{cache_k.shape} / "
+                         f"{None if shared_kv else cache_v.shape}")
     kv_heads = width // d_head
     group = heads // kv_heads
     pmax = table.shape[1]
@@ -246,20 +279,20 @@ def paged_attention_decode(q, cache_k, cache_v, layer, table, lengths,
         q_in = jnp.pad(q_in, ((0, 0), (0, hp - heads), (0, 0)))
         q_rows, out_rows = hp, -(-group // 8) * 8
     kernel = functools.partial(_decode_kernel, d_head=d_head, pmax=pmax,
-                               group=group, window=window)
+                               group=group, window=window, sm_scale=sm_scale,
+                               shared_kv=shared_kv)
+    pools = (cache_k,) if shared_kv else (cache_k, cache_v)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # layer, the flattened table, lengths
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, q_rows, width), lambda s, *_: (s, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
+        ] + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
         out_specs=pl.BlockSpec((1, out_rows, width),
                                lambda s, *_: (s, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, ps, width), dtype),      # K pages, two in flight
-            pltpu.VMEM((2, ps, width), dtype),      # V pages
+            # K pages, two in flight (and V pages: not under one pool)
+            pltpu.VMEM((2, ps, width), dtype) for _ in pools] + [
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),            # buffer of the next page
             pltpu.VMEM((hp, 1), jnp.float32),       # running max
@@ -276,10 +309,10 @@ def paged_attention_decode(q, cache_k, cache_v, layer, table, lengths,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="paged_attention_decode",
+        name=name,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
       table.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
-      q_in, cache_k, cache_v)
+      q_in, *pools)
     if group == 1:
         return out.reshape(b, width)
     # out[b, g, j*dh..] is head j*group + g: back to head order

@@ -23,13 +23,23 @@ the pattern names, position by position, the attention each layer runs
 then held BY KIND (serving/generation.py): full-attention layers keep every
 token, window layers the pages the window can still reach.
 
+Attention is ``mha`` (K and V rows of ``kv_heads * head_dim`` in the cache)
+or ``mla`` (latent attention: one row [c_kv | k_rope] a token a layer; the
+paged decode absorbs the up-projections, everything else expands them), and
+an expert layer may hold a SHARE of the router's experts (``experts_held``)
+beside an always-on shared expert: what a cached token costs and which
+planes a layer has are properties of the spec.
+
 Selection between blocks is made from the spec and nothing else: no flag,
 no environment variable.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, List, Optional, Tuple
+
+__all__ = ["Block", "BlockNotSupportedError", "LMSpec", "RopeScaling"]
 
 NORMS = ("layer_norm", "rms_norm")
 FFNS = ("gelu_mlp", "swiglu_moe")
@@ -38,6 +48,7 @@ ROPE_PAIRINGS = ("interleaved", "half")
 LAYER_KINDS = ("full+rope", "full+nope", "window+rope", "window+nope")
 EXPERT_ACTS = ("silu", "relu")          # SwiGLU | ReGLU
 ROUTER_INPUTS = ("post_attn_norm", "attn_input")
+ATTNS = ("mha", "mla")
 
 
 class BlockNotSupportedError(NotImplementedError):
@@ -45,6 +56,47 @@ class BlockNotSupportedError(NotImplementedError):
     another spec (beam search, the seq2seq family, a ``pp`` pipeline over
     MoE layers), or one that knows a single layer kind was handed a
     ``layer_pattern`` (training beyond the window, the slot handoff)."""
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN frequencies and the two temperature terms that ride with them
+    (the ``rope_parameters`` keys of a DeepSeek-V3-style config):
+
+    - pair i of ``dim / 2`` keeps ``theta_i = base^(-2i/dim)`` where it
+      turns more than ``beta_fast`` times in ``original_max`` positions,
+      is divided by ``factor`` where it turns less than ``beta_slow``
+      times, and is a linear ramp between the two over the pair indices in
+      between (``kernels.flash_attention.yarn_inv_freq``);
+    - cos / sin are scaled by ``mscale / mscale_all_dim`` of ``factor``
+      (1 when the two are equal);
+    - the softmax scale is multiplied by ``m^2``, ``m = 0.1 *
+      mscale_all_dim * ln(factor) + 1`` (``softmax_mscale``);
+    - a query at position i is multiplied by ``1 + temp_beta * ln(1 +
+      floor(i / original_max))`` (``llama_4_scaling_beta``; 1 below
+      ``original_max``)."""
+    factor: float = 1.0
+    original_max: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    temp_beta: float = 0.0
+
+    @staticmethod
+    def _m(scale: float, mscale: float) -> float:
+        return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+    @property
+    def cos_sin_scale(self) -> float:
+        return (self._m(self.factor, self.mscale)
+                / self._m(self.factor, self.mscale_all_dim))
+
+    @property
+    def softmax_mscale(self) -> float:
+        """What the attention's 1/sqrt(d) is multiplied by: m^2."""
+        return self._m(self.factor, self.mscale_all_dim) ** 2 \
+            if self.mscale_all_dim else 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,8 +122,46 @@ class Block:
     window: int = 0                     # keys a window layer sees: 0 <= i - j < window
     expert_act: str = "silu"            # act(x W_gate) * (x W_up)
     router_input: str = "post_attn_norm"  # | "attn_input": norm 1's output
+    # latent attention (``attn="mla"``): q through a rank-``q_lora_rank``
+    # bottleneck, keys and values expanded from ONE rank-``kv_lora_rank``
+    # latent a token plus one ``qk_rope_head_dim`` rotary key shared by
+    # all heads; the serving cache holds [latent | rotary key] rows
+    attn: str = "mha"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional[RopeScaling] = None
+    shared_expert: bool = False         # an always-on expert beside the routed
+    # (first, count): the routed experts THIS program holds of the router's
+    # E outputs (expert parallelism's share); None: all of them
+    experts_held: Optional[Tuple[int, int]] = None
+    routed_scale: float = 1.0           # routed_scaling_factor
 
     def __post_init__(self):
+        if isinstance(self.rope_scaling, dict):     # a saved program's attrs
+            object.__setattr__(self, "rope_scaling",
+                               RopeScaling(**self.rope_scaling))
+        if self.experts_held is not None:
+            object.__setattr__(self, "experts_held",
+                               tuple(int(v) for v in self.experts_held))
+        if self.attn not in ATTNS:
+            raise ValueError(f"attn {self.attn!r} not in {ATTNS}")
+        if self.is_mla:
+            widths = (self.q_lora_rank, self.kv_lora_rank,
+                      self.qk_nope_head_dim, self.qk_rope_head_dim,
+                      self.v_head_dim)
+            if min(widths) < 1 or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "attn='mla' needs q_lora_rank, kv_lora_rank, "
+                    "qk_nope_head_dim, an even qk_rope_head_dim and "
+                    f"v_head_dim (got {widths})")
+            if not self.use_rope or self.layer_pattern is not None \
+                    or self.qk_norm or self.num_kv_heads:
+                raise ValueError(
+                    "attn='mla' rotates its rotary dims (use_rope=True) and "
+                    "has one kind of layer, no QK-norm and no KV groups")
         if self.layer_pattern is not None:
             # a saved program hands the pattern back as a list
             object.__setattr__(self, "layer_pattern",
@@ -116,6 +206,8 @@ class Block:
             if f.name not in self._LEGACY and \
                     getattr(self, f.name) != f.default:
                 v = getattr(self, f.name)
+                if isinstance(v, RopeScaling):
+                    v = dataclasses.asdict(v)
                 out[f.name] = list(v) if isinstance(v, tuple) else v
         return out
 
@@ -132,6 +224,30 @@ class Block:
     @property
     def is_moe(self) -> bool:
         return self.ffn == "swiglu_moe"
+
+    @property
+    def is_mla(self) -> bool:
+        return self.attn == "mla"
+
+    def require_mha(self, who: str) -> None:
+        if self.is_mla:
+            raise BlockNotSupportedError(
+                f"{who} keeps K and V pages of kv_heads * head_dim and "
+                "cannot carry a latent cache row (attn='mla'): the train "
+                "op, the one-shot generate op and the paged prefill / "
+                "decode ops run it")
+
+    def cache_row(self, d_model: int) -> Tuple[int, int]:
+        """(pools, width): how many page pools a layer's cache is and the
+        values a token costs a layer in each. K and V rows of ``kv_heads *
+        head_dim``; a latent layer has ONE row a token: [c_kv | k_rope].
+        A row wider than a lane row is held at whole lane rows (320 ->
+        384): the TPU's tiled layout pads it to that anyway, and a
+        page tile the kernel can DMA needs it."""
+        if not self.is_mla:
+            return 2, self.kv_heads * self.dh(d_model)
+        w = self.kv_lora_rank + self.qk_rope_head_dim
+        return 1, w if w < 128 else -(-w // 128) * 128
 
     def dh(self, d_model: int) -> int:
         """Width of one head for a ``d_model``-wide stream."""
@@ -167,7 +283,8 @@ class Block:
                 and self.rope_pairing == "interleaved"
                 and self.rope_theta == 10000.0
                 and self.page_dtype == "float32"
-                and self.head_dim is None and self.layer_pattern is None)
+                and self.head_dim is None and self.layer_pattern is None
+                and not self.is_mla)
 
     def require_gpt2(self, who: str) -> None:
         if not self.is_gpt2:
@@ -185,7 +302,12 @@ class Block:
         slots = {"Ln1S": "ln1_s"}
         if ln:
             slots["Ln1B"] = "ln1_b"
-        slots["QkvW"] = "qkv_w"
+        if self.is_mla:
+            slots.update(QaW="q_a_w", QaNormS="q_a_norm_s", QbW="q_b_w",
+                         KvaW="kv_a_w", KvaNormS="kv_a_norm_s",
+                         KvbW="kv_b_w")
+        else:
+            slots["QkvW"] = "qkv_w"
         if self.qk_norm:
             slots["QNormS"] = "q_norm_s"
             slots["KNormS"] = "k_norm_s"
@@ -196,6 +318,10 @@ class Block:
         if self.is_moe:
             slots.update(RouterW="router_w", MoeGateW="moe_gate_w",
                          MoeUpW="moe_up_w", MoeDownW="moe_down_w")
+            if self.shared_expert:
+                slots.update(SharedGateW="shared_gate_w",
+                             SharedUpW="shared_up_w",
+                             SharedDownW="shared_down_w")
         else:
             slots["FfW1"] = "ff_w1"
             if self.bias:
@@ -210,7 +336,9 @@ class Block:
 #: declare as ``optional_inputs`` (next to PosEmb / FinalLnB)
 OPTIONAL_STACK_SLOTS = ("Ln1B", "Ln2B", "QNormS", "KNormS", "FfW1", "FfB1",
                         "FfW2", "FfB2", "RouterW", "MoeGateW", "MoeUpW",
-                        "MoeDownW")
+                        "MoeDownW", "QkvW", "QaW", "QaNormS", "QbW", "KvaW",
+                        "KvaNormS", "KvbW", "SharedGateW", "SharedUpW",
+                        "SharedDownW")
 
 
 @dataclasses.dataclass
@@ -220,7 +348,18 @@ class LMSpec:
     trains it, ``GenerationEngine(spec, ...)`` serves it, and the saved
     program's attrs and parameter shapes give it back
     (``serving.spec_from_program_dict``). The defaults are the GPT-2 block
-    in float32."""
+    in float32.
+
+    ``attn="mla"`` with ``q_lora_rank`` / ``kv_lora_rank`` /
+    ``qk_nope_head_dim`` / ``qk_rope_head_dim`` / ``v_head_dim``: latent
+    attention (the serving cache is then ONE pool of [c_kv | k_rope] rows:
+    ``cache_pools`` / ``cache_row_width`` / ``cache_bytes_per_token``).
+    ``rope_scaling`` (a ``RopeScaling``): YaRN frequencies, the softmax
+    scale's ``m^2`` and the query's position temperature. ``d_shared``:
+    width of an always-on expert beside the routed ones. ``experts_held``
+    = (first, count): the routed experts this program holds of the
+    router's ``num_experts`` (the expert stacks are [L, count, ..], the
+    router [d, num_experts]). ``routed_scale``: ``routed_scaling_factor``."""
     vocab_size: int
     d_model: int
     n_layers: int
@@ -248,8 +387,30 @@ class LMSpec:
     window: int = 0
     expert_act: str = "silu"
     router_input: str = "post_attn_norm"
+    attn: str = "mha"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional[RopeScaling] = None
+    d_shared: int = 0                   # width of the always-on expert
+    experts_held: Optional[Tuple[int, int]] = None
+    routed_scale: float = 1.0
 
     def __post_init__(self):
+        if isinstance(self.rope_scaling, dict):
+            self.rope_scaling = RopeScaling(**self.rope_scaling)
+        if self.experts_held is not None:
+            first, count = self.experts_held = tuple(self.experts_held)
+            if not (0 <= first and 0 < count
+                    and first + count <= self.num_experts):
+                raise ValueError(
+                    f"experts_held {self.experts_held} outside the "
+                    f"router's {self.num_experts} experts")
+        if self.attn == "mla":
+            # the attrs a program carries: no head_dim for a latent block
+            self.head_dim = self.qk_nope_head_dim + self.qk_rope_head_dim
         if self.head_dim is None:
             if self.d_model % self.num_heads:
                 raise ValueError(
@@ -278,7 +439,8 @@ class LMSpec:
     def block(self) -> Block:
         names = {f.name for f in dataclasses.fields(Block)}
         kw = {k: getattr(self, k) for k in names}
-        if self.head_dim * self.num_heads == self.d_model:
+        if self.head_dim * self.num_heads == self.d_model \
+                or self.attn == "mla":
             kw["head_dim"] = None       # the attrs a program always had
         return Block(**kw)
 
@@ -298,6 +460,35 @@ class LMSpec:
     def ffn_width(self) -> int:
         return self.d_ff or 4 * self.d_model
 
+    @property
+    def shared_expert(self) -> bool:
+        return self.d_shared > 0
+
+    @property
+    def experts_here(self) -> int:
+        """Routed experts whose weights this program holds."""
+        return self.experts_held[1] if self.experts_held else self.num_experts
+
+    @property
+    def cache_pools(self) -> int:
+        """Page pools a layer's cache is: K and V, or one latent pool."""
+        return self.block.cache_row(self.d_model)[0]
+
+    @property
+    def cache_row_width(self) -> int:
+        """Values a cached token costs a layer in each pool."""
+        return self.block.cache_row(self.d_model)[1]
+
+    @property
+    def cache_bytes_per_token(self) -> int:
+        """Bytes a cached token costs over all layers as the pools hold
+        it, full-attention kind (a window layer's pages are released)."""
+        from .core.types import to_dtype
+        import numpy as np
+
+        return (self.n_layers * self.cache_pools * self.cache_row_width
+                * np.dtype(to_dtype(self.page_dtype)).itemsize)
+
     def stack_planes(self) -> List[Tuple[str, str, list, Optional[tuple]]]:
         """(slot, key, shape without the layer axis, fan) of every stacked
         plane; fan is (fan_in, fan_out) for a matrix (Xavier), None for a
@@ -305,6 +496,13 @@ class LMSpec:
         d, dh = self.d_model, self.head_dim
         d_q, d_kv = dh * self.num_heads, dh * self.kv_heads
         E, f = self.num_experts, self.d_expert
+        Eh, fs = self.experts_here, self.d_shared
+        rq, rkv = self.q_lora_rank, self.kv_lora_rank
+        nope, rope, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                          self.v_head_dim)
+        H = self.num_heads
+        if self.attn == "mla":
+            d_q = H * dv                # what the out-projection reads
         shapes = {
             "ln1_s": ([d], None), "ln1_b": ([d], None),
             "qkv_w": ([d, d_q + 2 * d_kv], (d, d_q + 2 * d_kv)),
@@ -316,9 +514,17 @@ class LMSpec:
             "ff_w2": ([self.ffn_width, d], (self.ffn_width, d)),
             "ff_b2": ([d], None),
             "router_w": ([d, E], (d, E)),
-            "moe_gate_w": ([E, d, f], (d, f)),
-            "moe_up_w": ([E, d, f], (d, f)),
-            "moe_down_w": ([E, f, d], (f, d)),
+            "moe_gate_w": ([Eh, d, f], (d, f)),
+            "moe_up_w": ([Eh, d, f], (d, f)),
+            "moe_down_w": ([Eh, f, d], (f, d)),
+            "shared_gate_w": ([d, fs], (d, fs)),
+            "shared_up_w": ([d, fs], (d, fs)),
+            "shared_down_w": ([fs, d], (fs, d)),
+            "q_a_w": ([d, rq], (d, rq)), "q_a_norm_s": ([rq], None),
+            "q_b_w": ([rq, H * (nope + rope)], (rq, H * (nope + rope))),
+            "kv_a_w": ([d, rkv + rope], (d, rkv + rope)),
+            "kv_a_norm_s": ([rkv], None),
+            "kv_b_w": ([rkv, H * (nope + dv)], (rkv, H * (nope + dv))),
         }
         return [(slot, key, *shapes[key])
                 for slot, key in self.block.stack_slots().items()]
@@ -335,8 +541,6 @@ class LMSpec:
     def n_params(self) -> int:
         """Parameters of the whole model (embedding, position table,
         stack, final norm, untied head)."""
-        import math
-
         per_layer = sum(math.prod(shape)
                         for _, _, shape, _ in self.stack_planes())
         emb = 2 * self.vocab_size * self.d_model

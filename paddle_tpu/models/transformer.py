@@ -141,7 +141,7 @@ def _stacked_lm(helper, ids, spec, n_microbatches, remat, kw):
     the trained weights by name."""
     # one stacked LM per program — the fixed names would otherwise
     # silently alias
-    if "lm_stack.stack_qkv_w" in helper.main_program.global_block.vars:
+    if "lm_stack.stack_ln1_s" in helper.main_program.global_block.vars:
         raise ValueError(
             "transformer_lm(pipeline_stack=True) may be built only "
             "once per program: its parameter names (lm_stack.*, "

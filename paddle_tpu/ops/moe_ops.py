@@ -23,6 +23,9 @@ from ..core.registry import register_op
 from .common import amp_cast, amp_enabled, mxu_precision, out, single
 
 
+__all__ = ["moe_topk", "switch_moe"]
+
+
 @register_op("switch_moe", optional_inputs=("GateBias",))
 def switch_moe(attrs, ins):
     """X [b, T, d]; Gate [d, E]; W1 [E, d, ff]; B1 [E, ff]; W2 [E, ff, d];
@@ -80,7 +83,8 @@ _EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
-             layer=None, act="silu", router_x=None):
+             layer=None, act="silu", router_x=None, shared=None, held=None,
+             routed_scale=1.0):
     """Dropless token-choice top-``k`` gated experts — the expert layer
     of the ``swiglu_moe`` block (ops/pipeline_ops.py calls it from the
     block's FFN half; it is not a program op of its own).
@@ -115,6 +119,21 @@ def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
     ``router_x`` [N, d]: what the router reads when that is not ``x`` (a
     block whose router sees the attention's input routes from norm 1's
     output while the experts take norm 2's).
+
+    ``held`` = (first, count): gate_w / up_w / down_w hold experts ``first
+    .. first + count - 1`` of the router's E only ([count, d, f], or
+    [L, count, ..] under ``layer``): expert parallelism's share of the
+    layer, computed without its exchange. The router still scores all E
+    and picks its top-k among them; an assignment to an absent expert
+    sorts behind the held ones, belongs to no group of the grouped matmul
+    and adds EXACTLY zero (its rows are masked after the matmuls, whatever
+    the custom call leaves beyond its groups); ``counts`` stays [E] wide,
+    so the router's statistics and the engine's ``moe_dropped_tokens`` (an
+    absent expert is not a drop) read as before. Nothing stands in for
+    the absent chips. ``held=None`` is the call it always was.
+    ``shared`` = (gate_w [d, fs], up_w [d, fs], down_w [fs, d]): an
+    always-on expert of the same activation, added to every row once.
+    ``routed_scale`` multiplies the routed sum (``routed_scaling_factor``).
     """
     N, d = x.shape
     E = router_w.shape[-1]
@@ -127,14 +146,21 @@ def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
     if norm_topk_prob:
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     flat_e = top_e.reshape(N * k)
-    order = jnp.argsort(flat_e, stable=True)                  # by expert
     counts = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
-    sizes = counts
+    sizes, n_here, present = counts, E, None
+    if held is not None:
+        first, n_here = held
+        present = (flat_e >= first) & (flat_e < first + n_here)
+        # absent experts sort behind every held group
+        flat_e = jnp.where(present, flat_e - first, n_here)
+        sizes = jax.lax.dynamic_slice(counts, (first,), (n_here,))
+    order = jnp.argsort(flat_e, stable=True)                  # by expert
     if layer is not None:
         n_layers = gate_w.shape[0]
         sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((n_layers * E,), jnp.int32), counts, (layer * E,))
-        gate_w, up_w, down_w = (w.reshape((n_layers * E,) + w.shape[2:])
+            jnp.zeros((n_layers * n_here,), jnp.int32), sizes,
+            (layer * n_here,))
+        gate_w, up_w, down_w = (w.reshape((n_layers * n_here,) + w.shape[2:])
                                 for w in (gate_w, up_w, down_w))
     rows = x32[order // k]                                    # [N*k, d]
     if amp_enabled():
@@ -148,6 +174,22 @@ def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
 
     h = _EXPERT_ACTS[act](grouped(rows, gate_w)) * grouped(rows, up_w)
     o = grouped(h.astype(rows.dtype), down_w)                 # [N*k, d] f32
+    if present is not None:
+        o = jnp.where(present[order][:, None], o, 0.0)
     inv = jnp.argsort(order)                                  # unsort
     y = jnp.sum(o[inv].reshape(N, k, d) * top_p[..., None], axis=1)
+    if routed_scale != 1.0:
+        y = y * routed_scale
+    if shared is not None:
+        xs = x32.astype(jnp.bfloat16) if amp_enabled() else x32
+
+        def dense(a, w):
+            if w.dtype != a.dtype:
+                w = w.astype(a.dtype)
+            return jnp.dot(a, w, precision=mxu_precision(),
+                           preferred_element_type=jnp.float32)
+
+        s_gate, s_up, s_down = shared
+        hs = _EXPERT_ACTS[act](dense(xs, s_gate)) * dense(xs, s_up)
+        y = y + dense(hs.astype(xs.dtype), s_down)
     return y.astype(x.dtype), counts, jnp.mean(probs, axis=0)

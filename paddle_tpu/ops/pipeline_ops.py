@@ -82,7 +82,7 @@ def _block(blk, p, x, causal, rope=None):
 
     q, k, v = _attn_proj(blk, p, x, rope=rope)
     k, v = _expand_kv(k, v, blk.num_heads)
-    ctx = flash_attention(q, k, v, causal=causal)
+    ctx = flash_attention(q, k, v, causal=causal, sm_scale=_sm_scale(blk))
     ctx = checkpoint_name(ctx.transpose(0, 2, 1, 3).reshape(b, T, -1),
                           "attn_ctx")
     return _attn_out_ffn(blk, p, x, ctx)
@@ -134,6 +134,10 @@ def _attn_proj(blk, p, h, pos0=0, rope=None):
     ``rope`` overrides ``blk.use_rope`` for one layer of a pattern. The
     head width is ``blk.head_dim`` when the spec states one (H*dh need
     not be d: qkv_w is [d, H*dh + 2*Hkv*dh])."""
+    if blk.is_mla:
+        q_nope, q_rope, c_kv, k_rope = _mla_latent(blk, p, h, pos0)
+        k, v = _mla_expand(blk, p, c_kv, k_rope)
+        return jnp.concatenate([q_nope, q_rope], axis=-1), k, v
     num_heads, num_kv_heads = blk.num_heads, blk.kv_heads
     b, t, d = h.shape
     head_d = blk.dh(d)
@@ -158,6 +162,70 @@ def _attn_proj(blk, p, h, pos0=0, rope=None):
         q = rotary(q, pos0, blk.rope_theta, blk.rope_pairing)
         k = rotary(k, pos0, blk.rope_theta, blk.rope_pairing)
     return q, k, v
+
+
+def _sm_scale(blk):
+    """The attention's softmax scale where it is not 1/sqrt(head width):
+    a latent block's ``(nope + rope)^-0.5 * m^2`` (``RopeScaling.
+    softmax_mscale``); None: the default."""
+    if not blk.is_mla:
+        return None
+    scale = (blk.qk_nope_head_dim + blk.qk_rope_head_dim) ** -0.5
+    if blk.rope_scaling is not None:
+        scale *= blk.rope_scaling.softmax_mscale
+    return scale
+
+
+def _mla_latent(blk, p, h, pos0=0):
+    """The latent block's projections of h [b, t, d] at positions pos0 ..
+    (a scalar or [b]) -> q_nope [b, H, t, nope], q_rope [b, H, t, rope]
+    (rotated), c_kv [b, t, r] (after its norm) and k_rope [b, t, rope]
+    (rotated: ONE rotary key a token, shared by all heads) — [c_kv |
+    k_rope] is the token's cache row. Both query parts carry the
+    position's temperature ``a(i) = 1 + temp_beta * ln(1 + floor(i /
+    original_max))`` (1 below ``original_max``)."""
+    b, t, _ = h.shape
+    H, nope, rope = blk.num_heads, blk.qk_nope_head_dim, blk.qk_rope_head_dim
+    r, sc = blk.kv_lora_rank, blk.rope_scaling
+    hn = _norm(blk, h, p["ln1_s"], p.get("ln1_b"))
+    c_q = _rms(_mm("btd,dr->btr", hn, p["q_a_w"]), p["q_a_norm_s"],
+               blk.norm_eps)
+    q = _mm("btr,re->bte", c_q, p["q_b_w"]).reshape(
+        b, t, H, nope + rope).transpose(0, 2, 1, 3)
+    kv_a = _mm("btd,de->bte", hn, p["kv_a_w"])
+    c_kv = _rms(kv_a[..., :r], p["kv_a_norm_s"], blk.norm_eps)
+
+    def rot(a):
+        return rotary(a, pos0, blk.rope_theta, blk.rope_pairing, scaling=sc)
+
+    q_nope, q_rope = q[..., :nope], rot(q[..., nope:])
+    k_rope = rot(kv_a[:, None, :, r:])[:, 0]
+    if sc is not None and sc.temp_beta:
+        pos = (jnp.asarray(pos0, jnp.int32).reshape(-1, 1)
+               + jnp.arange(t, dtype=jnp.int32)[None, :])       # [b | 1, t]
+        a = 1.0 + sc.temp_beta * jnp.log1p(
+            (pos // sc.original_max).astype(jnp.float32))
+        q_nope, q_rope = (q_nope * a[:, None, :, None],
+                          q_rope * a[:, None, :, None])
+    return q_nope, q_rope, c_kv, k_rope
+
+
+def _mla_up(blk, p):
+    """kv_b_w [r, H * (nope + dv)] as (W_UK [r, H, nope], W_UV
+    [r, H, dv])."""
+    w = p["kv_b_w"].reshape(blk.kv_lora_rank, blk.num_heads, -1)
+    return w[..., :blk.qk_nope_head_dim], w[..., blk.qk_nope_head_dim:]
+
+
+def _mla_expand(blk, p, c_kv, k_rope):
+    """Keys and values of every head from the latent: c_kv [b, T, r],
+    k_rope [b, T, rope] -> k [b, H, T, nope + rope], v [b, H, T, dv]."""
+    w_uk, w_uv = _mla_up(blk, p)
+    k_nope = _mm("btr,rhn->bhtn", c_kv, w_uk)
+    v = _mm("btr,rhv->bhtv", c_kv, w_uv)
+    k_r = jnp.broadcast_to(k_rope[:, None].astype(k_nope.dtype),
+                           k_nope.shape[:3] + k_rope.shape[-1:])
+    return jnp.concatenate([k_nope, k_r], axis=-1), v
 
 
 def _expand_kv(k, v, num_heads):
@@ -189,6 +257,13 @@ def _attn_out_ffn(blk, p, x, ctx):
             more["router_x"] = router_x.reshape(b * t, d)
         if blk.expert_act != "silu":
             more["act"] = blk.expert_act
+        if blk.shared_expert:
+            more["shared"] = (p["shared_gate_w"], p["shared_up_w"],
+                              p["shared_down_w"])
+        if blk.experts_held is not None:
+            more["held"] = blk.experts_held
+        if blk.routed_scale != 1.0:
+            more["routed_scale"] = blk.routed_scale
         y, counts, prob_mean = moe_topk(
             h2.reshape(b * t, d), p["router_w"], p["moe_gate_w"],
             p["moe_up_w"], p["moe_down_w"], blk.experts_per_tok,
@@ -239,10 +314,18 @@ def pipelined_transformer_stack(attrs, ins):
     # optional stack slots (a block leaves out what it has no use for),
     # read via _stack_params: "Ln1B" "Ln2B" "QNormS" "KNormS" "FfW1"
     # "FfB1" "FfW2" "FfB2" "RouterW" "MoeGateW" "MoeUpW" "MoeDownW"
+    # "QkvW" | "QaW" "QaNormS" "QbW" "KvaW" "KvaNormS" "KvbW";
+    # "SharedGateW" "SharedUpW" "SharedDownW"
     params = _stack_params(blk, ins)
     causal = attrs.get("causal", True)
 
     remat = attrs.get("remat", False)
+    if blk.is_mla or blk.experts_held is not None:
+        raise BlockNotSupportedError(
+            "pipelined_transformer_stack (training) was never held to a "
+            "reference for latent attention or a held share of the experts "
+            "(no gradient test exists for either): the one-shot generate op "
+            "and the paged prefill / decode ops run this spec")
 
     _hold_to_window(blk, x.shape[1], "pipelined_transformer_stack")
 
@@ -272,7 +355,7 @@ def pipelined_transformer_stack(attrs, ins):
 
     pipe_axis = attrs.get("pipe_axis") or "pp"
     pp = mesh_axis(pipe_axis)
-    L = params["qkv_w"].shape[0]
+    L = params["ln1_s"].shape[0]
     if pp > 1:
         from ..parallel.pipeline import gpipe
 
@@ -342,7 +425,8 @@ def _prefill(blk, params, x, b, Tp):
     def prefill_body(h, layer_p, kind=None):
         q, k, v = _attn_proj(blk, layer_p, h, rope=kind and kind[1])
         kx, vx = _expand_kv(k, v, blk.num_heads)
-        ctx = flash_attention(q, kx, vx, causal=True)
+        ctx = flash_attention(q, kx, vx, causal=True,
+                              sm_scale=_sm_scale(blk))
         ctx = ctx.transpose(0, 2, 1, 3).reshape(b, Tp, -1)
         return _attn_out_ffn(blk, layer_p, h, ctx)[0], (k, v)
 
@@ -368,7 +452,7 @@ def _decode_layer_fn(blk, params, d):
         # einsum) — no [b, H, T, dh] expansion on the decode hot path
         ctx = reference_attention(
             q, ck_l, cv_l, lengths=jnp.full((h1.shape[0],), pos + 1),
-            **more)
+            sm_scale=_sm_scale(blk), **more)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(h1.shape[0], 1, -1)
         return _attn_out_ffn(blk, layer_p, h1, ctx)[0], (ck_l, cv_l)
 
@@ -418,6 +502,8 @@ def transformer_stack_generate(attrs, ins, rng):
     # optional stack slots (a block leaves out what it has no use for),
     # read via _stack_params: "Ln1B" "Ln2B" "QNormS" "KNormS" "FfW1"
     # "FfB1" "FfW2" "FfB2" "RouterW" "MoeGateW" "MoeUpW" "MoeDownW"
+    # "QkvW" | "QaW" "QaNormS" "QbW" "KvaW" "KvaNormS" "KvbW";
+    # "SharedGateW" "SharedUpW" "SharedDownW"
     # and "PosEmb" "FinalLnB" via _unpack_lm_ins
     (prompt, tok_emb, pos_emb, ln_s, ln_b, head_w,
      params) = _unpack_lm_ins(blk, ins)
@@ -743,7 +829,9 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
     b, t, _ = h.shape
     whole = {k: params[k] for k in _RESIDENT_PLANES if k in params}
     params = {k: v for k, v in params.items() if k not in whole}
-    attend = _paged_layer_step(b, t, cache_k.shape[2], project, mask, finish)
+    attend = _paged_layer_step(b, t, cache_k.shape[2], project, mask, finish,
+                               blk if blk is not None and blk.is_mla
+                               else None)
     ix = (page_id.reshape(b, t), page_row.reshape(b, t))
     kinds = blk.kinds if blk is not None else None
     ckw, cvw, table_w, page_id_w, page_row_w = win or (None,) * 5
@@ -783,13 +871,63 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
     return h, cache_k, cache_v, stats, (None if win is None else (ckw, cvw))
 
 
-def _paged_layer_step(b, t, ps, project, mask, finish):
+def _mla_paged_step(blk, b, t, project, mask, finish):
+    """``_paged_layer_step`` for a latent block: ONE pool ``ck`` [L, N,
+    ps, W] whose row is a token's [c_kv | k_rope | zero pad to W];
+    ``project(layer_p, h)`` is ``_mla_latent``. Absorbed attention never
+    expands the cache: q~[h] = q_nope[h] W_UK[h]^T scores the latent
+    directly, every head reads ONE shared key row whose first r columns
+    are also the value, and o[h] = (sum_j p_j c_kv[j]) W_UV[h]. The softmax
+    scale rides the queries (sm_scale 1 in the attention). A decode tick
+    on a chip walks the pages in the kernel; a prefill chunk (and the CPU)
+    gathers them and attends absorbed too: rebuilding every head's keys
+    and values from the gathered rows was 25.5 ms a 256-token unit over a
+    16k context where this is 20.7 (my chip run, PR 35, PERF.md section
+    6)."""
+    from ..kernels import paged_attention
+    from ..kernels.flash_attention import reference_attention
+
+    r, rope_d = blk.kv_lora_rank, blk.qk_rope_head_dim
+    scale = _sm_scale(blk)
+
+    def attend(h, ck, cv, l, layer_p, x_l, tbl, ix_page, ix_row, **_kw):
+        q_nope, q_rope, c_kv, k_rope = project(layer_p, h)
+        W = ck.shape[-1]
+        row = jnp.concatenate([c_kv, k_rope], axis=-1)
+        row = jnp.pad(row, ((0, 0), (0, 0), (0, W - row.shape[-1])))
+        ck = ck.at[l, ix_page, ix_row].set(row.astype(ck.dtype))
+        w_uk, w_uv = _mla_up(blk, layer_p)
+        q_abs = _mm("bhtn,rhn->bhtr", q_nope, w_uk)
+        q_lat = jnp.concatenate([q_abs, q_rope], axis=-1) * scale
+        q_lat = jnp.pad(q_lat, ((0, 0),) * 3 + ((0, W - r - rope_d),))
+        if set(mask) == {"lengths"} and paged_attention.supported(W, ck, t):
+            o_lat = paged_attention.paged_attention_decode(
+                q_lat[:, :, 0], ck, None, l, tbl, mask["lengths"],
+                sm_scale=1.0, name=paged_attention.MLA_KERNEL)
+            o_lat = o_lat.reshape(b, -1, 1, W)
+        else:
+            lat = ck[l, tbl].reshape(b, 1, tbl.shape[1] * ck.shape[2], W)
+            o_lat = reference_attention(q_lat.astype(ck.dtype), lat,
+                                        lat[..., :r], sm_scale=1.0, **mask)
+        ctx = _mm("bhtr,rhv->bthv", o_lat[..., :r].astype(h.dtype),
+                  w_uv).reshape(b, t, -1)
+        h, stats = finish(layer_p, h, ctx, x_l)
+        return h, ck, cv, stats
+
+    return attend
+
+
+def _paged_layer_step(b, t, ps, project, mask, finish, mla=None):
     """The per-layer step of the paged loop (``_scan_paged_layers`` says
     what it does): ``attend(h, ck, cv, l, layer_p, x_l, table, ix_page,
     ix_row, window=None, rope=None)`` -> (h, ck, cv, stats) against the
-    pools (ck, cv) of the layer's KIND at its index l within the kind."""
+    pools (ck, cv) of the layer's KIND at its index l within the kind.
+    ``mla``: a latent block (``_mla_paged_step``: one pool, cv None)."""
     from ..kernels import paged_attention
     from ..kernels.flash_attention import reference_attention
+
+    if mla is not None:
+        return _mla_paged_step(mla, b, t, project, mask, finish)
 
     def token_rows(a):  # [b, Hkv, t, dh] -> [b, t, Hkv*dh]
         return a.transpose(0, 2, 1, 3).reshape(b, t, -1)
@@ -834,12 +972,24 @@ def _paged_outs(blk, stats, win, **outs):
         outs["ExpertCounts"] = stats[0]
     if win is not None:
         outs["CacheKW"], outs["CacheVW"] = win
+    if outs.get("CacheV", 0) is None:       # a latent block's one pool
+        del outs["CacheV"]
     return out(**outs)
 
 
 #: the window kind's pools and table, beside CacheK / CacheV / BlockTable
 #: (which a ``layer_pattern`` spec reads as its full-attention kind's)
 _WINDOW_SLOTS = ("CacheKW", "CacheVW", "BlockTableW")
+#: absent for a latent block, whose cache is the one pool under CacheK
+_POOL_SLOTS = ("CacheV",)
+
+
+def _paged_project(blk, pos0):
+    """``project`` of ``_scan_paged_layers`` for the spec's attention."""
+    if blk.is_mla:
+        return lambda p, h, rope=None: _mla_latent(blk, p, h, pos0)
+    return lambda p, h, rope=None: _attn_proj(blk, p, h, pos0=pos0,
+                                              rope=rope)
 
 
 def _window_ins(blk, ins, targets):
@@ -854,7 +1004,8 @@ def _window_ins(blk, ins, targets):
 
 
 @register_op("transformer_stack_paged_prefill",
-             optional_inputs=_LM_OPTIONAL + _SAMPLING_SLOTS + _WINDOW_SLOTS,
+             optional_inputs=(_LM_OPTIONAL + _SAMPLING_SLOTS + _WINDOW_SLOTS
+                              + _POOL_SLOTS),
              needs_rng=lambda attrs: (attrs.get("temperature") or 0) > 0)
 def transformer_stack_paged_prefill(attrs, ins, rng=None):
     """Prefill ONE CHUNK of each row's prompt into its block-table pages.
@@ -900,7 +1051,7 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     lengths = single(ins, "Lengths").astype(jnp.int32)
     table = single(ins, "BlockTable").astype(jnp.int32)
     cache_k = single(ins, "CacheK")
-    cache_v = single(ins, "CacheV")
+    cache_v = maybe(ins, "CacheV")      # None: a latent block's one pool
     tok_emb = single(ins, "TokEmb")
     pos_emb = maybe(ins, "PosEmb")
     ln_s, ln_b = single(ins, "FinalLnS"), maybe(ins, "FinalLnB")
@@ -909,7 +1060,8 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     # optional stack slots (a block leaves out what it has no use for),
     # read via _stack_params: "Ln1B" "Ln2B" "QNormS" "KNormS" "FfW1"
     # "FfB1" "FfW2" "FfB2" "RouterW" "MoeGateW" "MoeUpW" "MoeDownW"
-    # and "FinalLnB"
+    # "QkvW" | "QaW" "QaNormS" "QbW" "KvaW" "KvaNormS" "KvbW";
+    # "SharedGateW" "SharedUpW" "SharedDownW" and "FinalLnB"
     params = _stack_params(blk, ins)
     b, Tc = chunk.shape
     ps = cache_k.shape[2]
@@ -930,8 +1082,7 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     # "CacheKW" "CacheVW" "BlockTableW": the window kind (_window_ins)
     h, cache_k, cache_v, stats, win = _scan_paged_layers(
         params, x, cache_k, cache_v, table, page_id, page_row,
-        lambda p, h, rope=None: _attn_proj(blk, p, h, pos0=start, rope=rope),
-        dict(causal=True, q_pos0=start),
+        _paged_project(blk, start), dict(causal=True, q_pos0=start),
         lambda p, h, ctx, _x_l: _attn_out_ffn(blk, p, h, ctx), blk=blk,
         win=_window_ins(blk, ins, lambda tw: (page_of(tw), page_row)))
     last = h[jnp.arange(b), jnp.clip(lengths, 1, Tc) - 1]  # [b, d]
@@ -943,7 +1094,8 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
 
 
 @register_op("transformer_stack_paged_decode",
-             optional_inputs=_LM_OPTIONAL + _SAMPLING_SLOTS + _WINDOW_SLOTS,
+             optional_inputs=(_LM_OPTIONAL + _SAMPLING_SLOTS + _WINDOW_SLOTS
+                              + _POOL_SLOTS),
              needs_rng=lambda attrs: (attrs.get("temperature") or 0) > 0)
 def transformer_stack_paged_decode(attrs, ins, rng=None):
     """One decode step over every slot's paged context.
@@ -984,7 +1136,7 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     pos = single(ins, "Pos").astype(jnp.int32)
     table = single(ins, "BlockTable").astype(jnp.int32)
     cache_k = single(ins, "CacheK")
-    cache_v = single(ins, "CacheV")
+    cache_v = maybe(ins, "CacheV")      # None: a latent block's one pool
     tok_emb = single(ins, "TokEmb")
     pos_emb = maybe(ins, "PosEmb")
     ln_s, ln_b = single(ins, "FinalLnS"), maybe(ins, "FinalLnB")
@@ -993,7 +1145,8 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     # optional stack slots (a block leaves out what it has no use for),
     # read via _stack_params: "Ln1B" "Ln2B" "QNormS" "KNormS" "FfW1"
     # "FfB1" "FfW2" "FfB2" "RouterW" "MoeGateW" "MoeUpW" "MoeDownW"
-    # and "FinalLnB"
+    # "QkvW" | "QaW" "QaNormS" "QbW" "KvaW" "KvaNormS" "KvbW";
+    # "SharedGateW" "SharedUpW" "SharedDownW" and "FinalLnB"
     params = _stack_params(blk, ins)
     S = tok.shape[0]
     if S != table.shape[0]:
@@ -1012,8 +1165,7 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     # "CacheKW" "CacheVW" "BlockTableW": the window kind (_window_ins)
     h1, cache_k, cache_v, stats, win = _scan_paged_layers(
         params, h1, cache_k, cache_v, table, page_id, page_row,
-        lambda p, h, rope=None: _attn_proj(blk, p, h, pos0=pos, rope=rope),
-        dict(lengths=pos + 1),
+        _paged_project(blk, pos), dict(lengths=pos + 1),
         lambda p, h, ctx, _x_l: _attn_out_ffn(blk, p, h, ctx), blk=blk,
         win=_window_ins(blk, ins,
                         lambda tw: (tw[srange, pos // ps], page_row)))
@@ -1024,7 +1176,7 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     return _maybe_topk(attrs, ins, logits, outs)
 
 
-@register_op("kv_cache_page_copy")
+@register_op("kv_cache_page_copy", optional_inputs=_POOL_SLOTS)
 def kv_cache_page_copy(attrs, ins):
     """Copy whole KV pages inside the pools: the copy-on-write step.
 
@@ -1037,7 +1189,9 @@ def kv_cache_page_copy(attrs, ins):
     src = single(ins, "Src").astype(jnp.int32)
     dst = single(ins, "Dst").astype(jnp.int32)
     cache_k = single(ins, "CacheK")
-    cache_v = single(ins, "CacheV")
+    cache_v = maybe(ins, "CacheV")      # None: a latent block's one pool
     cache_k = cache_k.at[:, dst].set(cache_k[:, src])
+    if cache_v is None:
+        return out(Ok=dst, CacheK=cache_k)
     cache_v = cache_v.at[:, dst].set(cache_v[:, src])
     return out(Ok=dst, CacheK=cache_k, CacheV=cache_v)

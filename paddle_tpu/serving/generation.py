@@ -75,14 +75,19 @@ def spec_from_program_dict(pd: dict,
             "another transformer_stack_* decode program)")
     blk = Block.from_attrs(op["attrs"])
     var = {v["name"]: v for v in block["vars"]}
-    if "tok_emb" not in var or "lm_stack.stack_qkv_w" not in var:
+    if "tok_emb" not in var or "lm_stack.stack_ln1_s" not in var:
         raise ValueError("saved program lacks the shared LM parameters "
                          "(tok_emb / lm_stack.*)")
     vocab, d_model = var["tok_emb"]["shape"]
     sizes = {}
     if blk.is_moe:
-        _, sizes["num_experts"], _, sizes["d_expert"] = \
-            var["lm_stack.stack_moe_gate_w"]["shape"]
+        # the router's width is the model's expert count; the stacks hold
+        # the experts this program has (``experts_held``)
+        sizes["num_experts"] = var["lm_stack.stack_router_w"]["shape"][2]
+        sizes["d_expert"] = var["lm_stack.stack_moe_gate_w"]["shape"][3]
+        if blk.shared_expert:
+            sizes["d_shared"] = \
+                var["lm_stack.stack_shared_gate_w"]["shape"][2]
     else:
         sizes["d_ff"] = var["lm_stack.stack_ff_w1"]["shape"][2]
     if max_len is None:
@@ -91,10 +96,12 @@ def spec_from_program_dict(pd: dict,
         else:
             raise ValueError("RoPE model has no pos_emb table to bound "
                              "sequence length — pass max_len explicitly")
+    block_kw = dataclasses.asdict(blk)
+    block_kw.pop("shared_expert")       # the spec's d_shared says it
     return LMSpec(vocab_size=vocab, d_model=d_model,
-                  n_layers=var["lm_stack.stack_qkv_w"]["shape"][0],
+                  n_layers=var["lm_stack.stack_ln1_s"]["shape"][0],
                   max_len=max_len, param_dtype=str(var["tok_emb"]["dtype"]),
-                  **sizes, **dataclasses.asdict(blk))
+                  **sizes, **block_kw)
 
 
 def _default_prompt_buckets(tmax: int) -> List[int]:
@@ -182,7 +189,7 @@ class _Slot:
                  "cow_reserve", "prefill_done", "state", "sampling",
                  "stop_matcher", "mask_proc", "beam_job", "role", "xrow",
                  "resumed", "wpages", "wfirst", "wentries", "wreserve",
-                 "wcow")
+                 "wcow", "prefix_key")
 
     def __init__(self, request: Request, prompt: np.ndarray,
                  max_new: int, eos_id: Optional[int],
@@ -200,6 +207,7 @@ class _Slot:
         self.shared_tokens = 0           # prefix-cache hit length
         self.cow_reserve = 0             # pages held for copy-on-write
         self.prefill_done = 0            # prompt tokens whose K/V is cached
+        self.prefix_key = b""            # chain key of the FULL pages of them
         self.state = "decode"            # "prefill" while chunks stream in
                                          # ("hold"/"beam_wait" for beams)
         self.sampling = sampling or SamplingParams()
@@ -358,6 +366,8 @@ class GenerationEngine:
         if src is not None:
             spec.block.require_one_kind("share_cache_with= (the slot "
                                         "handoff between engines)")
+            spec.block.require_mha("share_cache_with= (the slot handoff "
+                                   "between engines)")
             if self.scope is not src.scope:
                 raise ValueError(
                     "share_cache_with requires constructing this engine "
@@ -507,7 +517,8 @@ class GenerationEngine:
 
         shape = self._pool_shape()
         page_dtype = jnp.dtype(to_dtype(self.spec.page_dtype))
-        pools = {PAGED_CACHE_K: shape, PAGED_CACHE_V: shape}
+        pools = {name: shape for name in
+                 (PAGED_CACHE_K, PAGED_CACHE_V)[:self.spec.cache_pools]}
         if self._by_kind:
             wshape = self._pool_shape(window=True)
             pools.update({PAGED_CACHE_KW: wshape, PAGED_CACHE_VW: wshape})
@@ -521,19 +532,25 @@ class GenerationEngine:
             * page_dtype.itemsize)
         self.metrics.set_gauge("mem/kv_block_table_bytes",
                                float(self.slots * self.pmax * 4))
+        # what a cached token costs over the stack, as the pools hold it
+        # (a latent row of 640 B a layer where K and V rows are 16 KB)
+        self.metrics.set_gauge("mem/kv_bytes_per_token",
+                               float(self.spec.cache_bytes_per_token))
         self._gauges()
 
     def _pool_shape(self, window: bool = False):
-        """[L, n_pages, page_size, Hkv*dh] in the spec's ``page_dtype``:
-        a token's K (or V) of one layer is ONE contiguous row, so a page
-        is contiguous and lane-dense on the device (ops/pipeline_ops.py
-        says why the head-major [.., Hkv, page_size, dh] form was
-        not). L counts the layers of the pool's kind: the full-attention
-        layers (every layer of a one-kind spec), or the window layers."""
+        """[L, n_pages, page_size, row] in the spec's ``page_dtype``, row
+        = ``spec.cache_row_width`` (Hkv*dh for K and V pools, the latent
+        row for a latent block's one pool): a token's row of one layer is
+        contiguous, so a page is contiguous and lane-dense on the device
+        (ops/pipeline_ops.py says why the head-major [.., Hkv, page_size,
+        dh] form was not). L counts the layers of the pool's kind: the
+        full-attention layers (every layer of a one-kind spec), or the
+        window layers."""
         s = self.spec
         return (s.layers_of(window),
                 self.n_pages_window if window else self.n_pages,
-                self.page_size, s.kv_heads * s.head_dim)
+                self.page_size, s.cache_row_width)
 
     def _cache_vars(self, helper, window: bool = False):
         shape = list(self._pool_shape(window))
@@ -541,7 +558,13 @@ class GenerationEngine:
                  else (PAGED_CACHE_K, PAGED_CACHE_V))
         return tuple(helper.create_global_variable(
             name=name, shape=shape, dtype=self.spec.page_dtype)
-            for name in names)
+            for name in names[:self.spec.cache_pools])
+
+    @staticmethod
+    def _pool_io(pools):
+        """The op's pool slots for ``_cache_vars``' pools: CacheK (and
+        CacheV: a latent block's cache is the one pool)."""
+        return {slot: [v] for slot, v in zip(("CacheK", "CacheV"), pools)}
 
     def _window_io(self, helper, table):
         """The window kind's op inputs and outputs (its pools, read and
@@ -646,7 +669,16 @@ class GenerationEngine:
         self.metrics.inc("moe_assignments", took)
         self.metrics.inc("moe_hot_expert_rows",
                          int(counts.max(axis=1).sum()))
-        self.metrics.inc("moe_touched_experts", int((counts > 0).sum()))
+        here = counts
+        if self.spec.experts_held is not None:
+            # a held share of the router's experts: only their weights
+            # are read, only their rows computed (ops/moe_ops.moe_topk)
+            first, n = self.spec.experts_held
+            here = counts[:, first:first + n]
+            held = int(here.sum())
+            self.metrics.inc("moe_held_assignments", held)
+            self.metrics.inc("moe_absent_assignments", took - held)
+        self.metrics.inc("moe_touched_experts", int((here > 0).sum()))
         self.metrics.inc("moe_layer_calls", int(counts.shape[0]))
         self.metrics.inc("moe_dropped_tokens",
                          rows * self.spec.experts_per_tok
@@ -674,16 +706,15 @@ class GenerationEngine:
                                dtype="int32")
             helper = LayerHelper("serving_paged_prefill", main_program=prog,
                                  startup_program=startup)
-            ck, cv = self._cache_vars(helper)
+            pools = self._pool_io(self._cache_vars(helper))
             nxt = helper.block.create_var(
                 name="serving.next_tok", shape=[-1],
                 dtype="int64", stop_gradient=True)
             ins = {"Chunk": [chunk], "StartPos": [start],
-                   "Lengths": [length], "BlockTable": [table],
-                   "CacheK": [ck], "CacheV": [cv]}
+                   "Lengths": [length], "BlockTable": [table], **pools}
             ins.update(self._sampling_vars(None))
             ins.update(self._lm_ins(helper))
-            outs = {"NextTok": [nxt], "CacheK": [ck], "CacheV": [cv]}
+            outs = {"NextTok": [nxt], **pools}
             if self._by_kind:
                 w_ins, w_outs = self._window_io(helper, data_layer(
                     "serving.block_table_w", shape=[self.pmax],
@@ -712,15 +743,15 @@ class GenerationEngine:
                                dtype="int32", append_batch_size=False)
             helper = LayerHelper("serving_paged_decode", main_program=prog,
                                  startup_program=startup)
-            ck, cv = self._cache_vars(helper)
+            pools = self._pool_io(self._cache_vars(helper))
             nxt = helper.block.create_var(
                 name="serving.next_tok",
                 shape=[self.slots], dtype="int64", stop_gradient=True)
             ins = {"Tok": [tok], "Pos": [pos], "BlockTable": [table],
-                   "CacheK": [ck], "CacheV": [cv]}
+                   **pools}
             ins.update(self._sampling_vars(self.slots))
             ins.update(self._lm_ins(helper))
-            outs = {"NextTok": [nxt], "CacheK": [ck], "CacheV": [cv]}
+            outs = {"NextTok": [nxt], **pools}
             if self._by_kind:
                 w_ins, w_outs = self._window_io(helper, data_layer(
                     "serving.block_table_w", shape=[self.slots, self.pmax],
@@ -755,15 +786,14 @@ class GenerationEngine:
                 helper = LayerHelper("serving_page_copy",
                                      main_program=prog,
                                      startup_program=startup)
-                ck, cv = self._cache_vars(helper, window)
+                pools = self._pool_io(self._cache_vars(helper, window))
                 ok = helper.block.create_var(
                     name="serving.cow_ok", shape=[1], dtype="int32",
                     stop_gradient=True)
                 helper.append_op(
                     "kv_cache_page_copy",
-                    {"Src": [src], "Dst": [dst],
-                     "CacheK": [ck], "CacheV": [cv]},
-                    {"Ok": [ok], "CacheK": [ck], "CacheV": [cv]}, {})
+                    {"Src": [src], "Dst": [dst], **pools},
+                    {"Ok": [ok], **pools}, {})
             self._transpile(prog, ["serving.cow_src", "serving.cow_dst"],
                             [ok.name], "transpile/page_copy/")
             cache[window] = (prog, ok)
@@ -1093,8 +1123,12 @@ class GenerationEngine:
     def _register_prefix(self, st: _Slot,
                          include_tail: bool = False) -> None:
         """Publish the slot's fully-written prompt pages into the prefix
-        index (idempotent: existing keys no-op). Full pages register once
-        their content is prefilled; the partial tail page only at finish
+        index (idempotent: existing keys no-op). Full pages register as
+        the chunks that fill them complete, so a request that arrives
+        while a long shared prompt is still prefilling hits what is
+        written already (and a window page is indexed before the slot
+        moves past it and lets it go: a cache held by kind writes both
+        kinds' indexes in lockstep); the partial tail page only at finish
         (an index reference on a page the request still writes would
         force a pointless self-copy-on-write)."""
         if self.prefix_index is None:
@@ -1102,11 +1136,6 @@ class GenerationEngine:
         ps = self.page_size
         prompt = st.prompt
         done = st.prefill_done >= prompt.size
-        # a cache held by kind writes both kinds' indexes in lockstep, page
-        # by page as the chunks complete: a window page must be indexed
-        # before the slot moves past it and lets it go
-        if not done and not self._by_kind:
-            return
 
         def insert(key, toks, i):
             if i < len(st.wpages) and st.wpages[i]:
@@ -1117,9 +1146,68 @@ class GenerationEngine:
         key = b""
         for i in range(n_full):
             key = insert(key, prompt[i * ps:(i + 1) * ps], i)
+        st.prefix_key = key
         tail = prompt[n_full * ps:]
         if include_tail and done and tail.size:
             insert(key, tail, n_full)
+
+    def _pages_in_flight(self, prompt: np.ndarray, shared: int) -> List[int]:
+        """The pages some PREFILLING slot holds for full pages of
+        ``prompt`` beyond its ``shared``-token hit in the index (the
+        longest such run): a request that arrives while a long shared
+        prompt is cold takes the SAME pages into its table instead of a
+        set of its own, so n requests over one cold document hold it once
+        (with a set each, a burst of arrivals fills the pool, every
+        admission evicts the index to no avail and nothing cached
+        survives: PERF.md section 6, PR 35). The page of the prompt's
+        last token is never taken: its chunk is the slot's own."""
+        ps = self.page_size
+        cap = (int(prompt.size) - 1) // ps * ps
+        best: List[int] = []
+        for st in self._slots:
+            if st is None or st.state != "prefill":
+                continue
+            n = min(cap, int(st.prompt.size) // ps * ps)
+            if n <= shared + len(best) * ps:
+                continue
+            differ = np.flatnonzero(st.prompt[:n] != prompt[:n])
+            common = (int(differ[0]) if differ.size else n) // ps * ps
+            if common > shared + len(best) * ps:
+                best = st.pages[shared // ps:common // ps]
+        return best
+
+    def _adopt_prefilled(self, st: _Slot) -> None:
+        """Before a prefilling slot's next chunk: move past the full
+        prompt pages that ANOTHER slot has prefilled and registered since
+        this one was admitted (into pages the two share since admission,
+        ``_pages_in_flight``, or into its own, which then replace this
+        slot's). Requests over one long document that arrive while it is
+        cold then prefill it ONCE between them, each chunk by whichever
+        slot's turn comes first, instead of once each side by side
+        (PERF.md section 6, PR 35: 16384-token documents under open-loop
+        load never warmed). The page that holds the prompt's last token
+        stays the slot's own: its chunk yields the first token. One-kind
+        caches only (a window kind's pages are let go behind the slot as
+        it advances)."""
+        ps, start = self.page_size, st.prefill_done
+        if self.prefix_index is None or self._by_kind or start % ps:
+            return
+        i, last = start // ps, (int(st.prompt.size) - 1) // ps
+        while i < last:
+            hit = self.prefix_index.page_after(
+                st.prefix_key, st.prompt[i * ps:(i + 1) * ps])
+            if hit is None:
+                break
+            st.prefix_key, page = hit
+            self.pool.incref(page)
+            self.pool.decref(st.pages[i])
+            st.pages[i] = page
+            i += 1
+        if i * ps > start:
+            st.prefill_done = i * ps
+            st.shared_tokens += i * ps - start
+            st.timeline.prefix_hit_tokens = st.shared_tokens
+            self.metrics.inc("prefix_hit_tokens", i * ps - start)
 
     def _release_pages(self, st: _Slot) -> None:
         if self._prefix_sharing:
@@ -1184,6 +1272,8 @@ class GenerationEngine:
                 raise BlockNotSupportedError(
                     "beam search forks one block table; this engine's "
                     "cache is held by layer kind")
+            self.spec.block.require_mha("beam search (its page forks were "
+                                        "never run over latent pages)")
             if not self.beam_width:
                 raise BadRequestError(
                     "beam request on an engine built without the beam "
@@ -1227,6 +1317,7 @@ class GenerationEngine:
         adopted = 0
         if hand:
             self.spec.block.require_one_kind("a serialized KV handoff")
+            self.spec.block.require_mha("a serialized KV handoff")
             # cross-process KV migration: the payload carries serialized
             # page ranges + the block table; installation writes the
             # bytes and resumes decode — never a prefill recompute
@@ -1329,9 +1420,14 @@ class GenerationEngine:
             req.end_trace(status="cache_exhausted")
             req.future.set_exception(exc)
             return "failed"
-        shared, spages = 0, []
+        shared, spages, key = 0, [], b""
         if self.prefix_index is not None:
-            shared, spages, _ = self.prefix_index.lookup(prompt)
+            shared, spages, key = self.prefix_index.lookup(prompt)
+            if not self._by_kind:
+                # ... and the pages a slot is still prefilling for the
+                # same tokens: held from now on, written by whichever of
+                # the two comes to a chunk first (``_adopt_prefilled``)
+                spages = spages + self._pages_in_flight(prompt, shared)
         wspages, wkeep, wneed = [], 0, 0
         if self._by_kind:
             # a hit is as long as BOTH kinds' indexes hold it
@@ -1405,6 +1501,7 @@ class GenerationEngine:
         st.shared_tokens = shared
         st.cow_reserve = cow
         st.prefill_done = shared
+        st.prefix_key = key     # (a cache by kind may hold less: unused)
         st.timeline.prefix_hit_tokens = shared
         if resumed_k:
             self._install_resume(st, resume)
@@ -1690,6 +1787,7 @@ class GenerationEngine:
         self._pf_cursor = (slot + 1) % self.slots
         st = self._slots[slot]
         plen = int(st.prompt.size)
+        self._adopt_prefilled(st)
         start0 = st.prefill_done
         k = min(self.prefill_chunk, plen - start0)
         tc = self._chunk_bucket_for(k)
@@ -1736,12 +1834,10 @@ class GenerationEngine:
                          parent=st.request.span, phase="prefill_chunk",
                          slot=slot, offset=start0, tokens=k)
         st.prefill_done = start0 + k
-        if self._by_kind:
-            self._register_prefix(st)   # page by page, before they go
+        self._register_prefix(st)       # page by page, as they fill
         if st.prefill_done >= plen:
             self.metrics.inc("prefills")
             first = np.asarray(res[0])
-            self._register_prefix(st)
             if st.role == "beam_parent":
                 st.state = "decode"
                 st.role = "beam"
@@ -1814,6 +1910,8 @@ class GenerationEngine:
                 if name == "global":
                     self.metrics.inc("kv_pages_uniform_equiv", n)
         else:
+            # (a latent spec's pages too: ONE pool row a token, read once
+            # for key and value, ``paged_mla_decode``)
             self.metrics.inc("paged_attn_pages_read", int(held.sum()))
             self.metrics.inc("paged_attn_table_pages", table.size)
         prog, outs = self._decode_prog
@@ -2260,6 +2358,7 @@ class GenerationEngine:
         page bytes. Either way the migration is the block table + pages
         — never a prefill recompute."""
         self.spec.block.require_one_kind("export_slot (the KV handoff)")
+        self.spec.block.require_mha("export_slot (the KV handoff)")
         st = self._slots[slot]
         if st is None or st.state != "decode" or st.beam_job is not None \
                 or st.xrow is not None:
@@ -2278,6 +2377,7 @@ class GenerationEngine:
         the slot index; decode resumes on the next tick, bit-identically
         (copy-on-write still guards any page the prefix index shares)."""
         self.spec.block.require_one_kind("adopt_slot (the KV handoff)")
+        self.spec.block.require_mha("adopt_slot (the KV handoff)")
         if handoff.get("pool") is not self.pool:
             raise ValueError(
                 "same-process adoption needs a shared page pool — build "

@@ -216,6 +216,15 @@ class PrefixIndex:
                 self.misses += 1
         return shared, pages, key or b""
 
+    def page_after(self, parent_key: bytes, tokens: Sequence[int]
+                   ) -> Optional[Tuple[bytes, int]]:
+        """The cached page that continues ``parent_key`` (b"" for the
+        first page) by the full page ``tokens``, with its key; None when
+        it is not cached. No statistics, no reference taken."""
+        k = chain_key(parent_key or None, tokens)
+        page = self._entries.get(k)
+        return None if page is None else (k, page)
+
     def insert(self, parent_key: bytes, tokens: Sequence[int],
                page: int) -> bytes:
         """Cache ``page`` as the prefix continuation ``tokens`` of

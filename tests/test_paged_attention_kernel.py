@@ -200,6 +200,37 @@ def test_kernel_compiles_for_the_v5e_with_the_pool_whole(
             if f"{pages},{ps},{width}]" in shape} == {"parameter"}
 
 
+def test_latent_kernel_compiles_for_the_v5e_with_the_one_pool_whole(one_chip):
+    """Latent decode at the ``mistral4-serve-longdoc`` cell's shapes: 32
+    query heads over ONE 384-wide pool row (320 values at whole lane
+    rows), 256-token pages, the key tile also the value: one custom call
+    under its own name, one pool operand, nothing pool-sized moved."""
+    from paddle_tpu.kernels.paged_attention import MLA_KERNEL
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    slots, layers, pages, ps, width, heads = 64, 6, 1536, 256, 384, 32
+
+    def call(q, pool, layer, table, lengths):
+        return paged_attention_decode(q, pool, None, layer, table, lengths,
+                                      sm_scale=1.0, name=MLA_KERNEL)
+
+    text = jax.jit(call).lower(
+        arg((slots, heads, width), jnp.bfloat16),
+        arg((layers, pages, ps, width), jnp.bfloat16), arg((), jnp.int32),
+        arg((slots, 80), jnp.int32),
+        arg((slots,), jnp.int32)).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "%paged_mla_decode" in calls[0]
+    assert calls[0].count(f"[{layers},{pages},{ps},{width}]") == 1
+    ops = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(",
+                     re.sub(r"\{[^{}]*\}", "", text), re.M)
+    assert {op for shape, op in ops
+            if f"{pages},{ps},{width}]" in shape} == {"parameter"}
+
+
 def test_decode_step_on_the_v5e_keeps_the_pool_the_scan_carry(one_chip,
                                                               monkeypatch):
     """The whole decode op compiled for the chip: the kernel sits in the

@@ -185,6 +185,46 @@ class TestPrefixSharing:
         assert shared.pool.pages_in_use() == len(shared.prefix_index)
         assert shared.pool.stats()["shared"] == 0  # no live sharers left
 
+    @pytest.mark.parametrize("first_new", [1, 9],
+                             ids=["holder leaves first", "holder stays"])
+    def test_a_cold_shared_prompt_is_held_and_prefilled_once(self, first_new):
+        """Three requests over one COLD 5-page system prompt admitted
+        together (chunk = 2 pages, so it streams through ``prefill_tick``):
+        the later two take the first one's pages into their tables, the
+        chunks are run by whichever slot's turn comes (pages register as
+        they fill and the others move past them), and every answer is the
+        sharing-disabled engine's, also when the first holder finishes
+        and lets go while the others still prefill."""
+        scope_a, _ = _init_lm_scope(7)
+        scope_b, _ = _init_lm_scope(7)
+        rng = np.random.RandomState(5)
+        ps = 4
+        sys_prompt = rng.randint(0, VOCAB, (5 * ps,)).astype("int64")
+        prompts = [np.concatenate(
+            [sys_prompt, rng.randint(0, VOCAB, (n,)).astype("int64")])
+            for n in (2, 9, 13)]
+        new = [first_new, 6, 6]
+        kw = dict(slots=4, page_size=ps, prefill_chunk=2 * ps,
+                  prompt_buckets=(4, 8))
+        plain = GenerationEngine(_spec(), scope_a, prefix_sharing=False,
+                                 **kw)
+        ref = [plain.generate_all([p], max_new_tokens=n)[0]
+               for p, n in zip(prompts, new)]
+        eng = GenerationEngine(_spec(), scope_b, **kw)
+        reqs = [Request({"prompt": p}, {"max_new_tokens": n}, None)
+                for p, n in zip(prompts, new)]
+        eng.admit(reqs)
+        assert eng.active == 3
+        tables = [eng._slots[i].pages for i in range(3)]
+        assert tables[0][:5] == tables[1][:5] == tables[2][:5]
+        eng._drive([])
+        for req, want in zip(reqs, ref):
+            np.testing.assert_array_equal(req.future.result(timeout=1), want)
+        chunks = eng.metrics.counter("prefill_chunks")
+        assert chunks <= plain.metrics.counter("prefill_chunks") - 4
+        assert eng.metrics.counter("prefix_hit_tokens") >= 2 * 5 * ps - 2 * ps
+        assert eng.pool.pages_in_use() == len(eng.prefix_index)
+
     def test_full_prompt_hit_takes_copy_on_write(self):
         """A repeated IDENTICAL prompt full-hits the prefix cache: zero
         prefill tokens, identical output, and the first generated token

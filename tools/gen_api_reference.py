@@ -63,6 +63,13 @@ MODULES = [
     ("paddle_tpu.v2", "The v2 user API facade"),
     ("paddle_tpu.v2.layer", "v2 keyword layer namespace"),
     ("paddle_tpu.v2.networks", "v2 composite networks"),
+    ("paddle_tpu.lm_spec",
+     "The stacked LM's model spec: Block (what a block computes), LMSpec "
+     "(plus sizes), RopeScaling; attention mha | mla, a shared expert, a "
+     "held share of the router's experts"),
+    ("paddle_tpu.ops.moe_ops",
+     "The expert layer: moe_topk (dropless top-k; shared=, held=, "
+     "routed_scale=) and the Switch op"),
     ("paddle_tpu.kernels.flash_attention", "Pallas flash attention"),
     ("paddle_tpu.kernels.paged_attention",
      "Pallas paged decode attention: walks the block table"),

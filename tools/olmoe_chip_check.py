@@ -9,13 +9,16 @@ family are used (default ``olmoe-serve-chat``):
     python tools/olmoe_chip_check.py train      # 5(c): train -> save -> serve
     python tools/olmoe_chip_check.py sweep 0.5 1 1.5 ...   # 5(e): the knee
     python tools/olmoe_chip_check.py --cell smallthinker-serve-mixed serve
+    python tools/olmoe_chip_check.py --cell mistral4-serve-longdoc serve
 
 A family with ``VARIANTS`` (``window_moe_lm``: one deliberately wrong
 model a fault — no window, RoPE on the global layers, silu for relu, the
 router after the attention, KV head n % Hkv, bfloat16 where float32 is
 stated) is also held to each of them: the served log-probs must lie
 within the tolerance of the right reference and beyond it from every
-wrong one (the 90th percentile of the error over the sample).
+wrong one (a percentile of the error over the sample: the 90th, or the
+family's ``CHECK_LOGPROB_QUANTILE``), but for those the family lists as
+``CHECK_UNSEEN``; every position's error is kept in the output.
 
 ``serve``: the benchmark's own engine (``moe_lm.build_engine``, 8 layers,
 bf16 weights and pages) with the beam plane on; a seeded sample of
@@ -230,8 +233,15 @@ def _serve_kinds(eng, family, config, w, rng):
     import jax
 
     worst = {"": 0.0, **{v: 0.0 for v in family.VARIANTS}}
-    worst_gap, p90 = dict(worst), {v: [] for v in worst}
-    for n_prompt, n_new in KIND_SEQUENCES:
+    worst_gap, errs = dict(worst), {v: [] for v in worst}
+    # a family may bring its own sample, quantile and tolerance
+    # (``mla_moe_lm``: a sequence beyond its 16384-token documents and
+    # YaRN's original_max; the 95th percentile) and name the wrong models
+    # no statistic of the sample can see (``CHECK_UNSEEN``)
+    tol = getattr(family, "CHECK_LOGPROB_TOL", KIND_LOGPROB_P90_TOL)
+    unseen = getattr(family, "CHECK_UNSEEN", {})
+    for n_prompt, n_new in getattr(family, "CHECK_SEQUENCES",
+                                   KIND_SEQUENCES):
         prompt = family.draw_prompt_ids(rng, n_prompt, config)
         before = eng.metrics.snapshot()["counters"]
         calls, out = family.served_logprobs(eng, prompt, n_new)
@@ -245,7 +255,7 @@ def _serve_kinds(eng, family, config, w, rng):
             e = np.array([np.abs(v - ref[j][i]).max()
                           for j, (_, v, i) in enumerate(calls)])
             worst[variant] = max(worst[variant], float(e.max()))
-            p90[variant].extend(e.tolist())
+            errs[variant].extend(e.tolist())
             # the serve cell's own statistic under this model: how far
             # below the row's best token the EMITTED one lies
             gap = np.array([ref[j].max() - ref[j][out[p + 1]]
@@ -265,16 +275,22 @@ def _serve_kinds(eng, family, config, w, rng):
              context_end=int(out.size),
              window_pages_released=after.get("kv_window_pages_released", 0)
              - before.get("kv_window_pages_released", 0), **row)
-    p90 = {v: float(np.percentile(e, 90)) for v, e in p90.items()}
-    ok = p90[""] <= KIND_LOGPROB_P90_TOL
-    caught = {v: p90[v] > KIND_LOGPROB_P90_TOL for v in family.VARIANTS}
-    note(logprob_p90_tol=KIND_LOGPROB_P90_TOL, reference_err_p90=p90[""],
-         wrong_model_err_p90={v: p90[v] for v in family.VARIANTS},
+    # the family's quantile of the pooled errors (90 where it names none)
+    q = getattr(family, "CHECK_LOGPROB_QUANTILE", 90)
+    held = {v: float(np.percentile(e, q)) for v, e in errs.items()}
+    caught = {v: held[v] > tol for v in family.VARIANTS}
+    note(logprob_quantile=q, logprob_tol=tol, reference_err=held[""],
+         wrong_model_err={v: held[v] for v in family.VARIANTS},
          reference_err_max=worst[""],
          wrong_model_err_max={v: worst[v] for v in family.VARIANTS},
          emitted_gap_max={v or "reference": g for v, g in worst_gap.items()},
-         wrong_models_caught=caught,
-         within_tolerance=bool(ok and all(caught.values())))
+         wrong_models_caught=caught, unseen=sorted(unseen),
+         within_tolerance=bool(held[""] <= tol and all(
+             c for v, c in caught.items() if v not in unseen)))
+    # every position's error, so that another statistic can be weighed
+    # without another run
+    note(errors={v or "reference": [round(x, 6) for x in e]
+                 for v, e in errs.items()})
 
 
 def _serve_router_sets(eng, moe_lm, config, w, rng):
@@ -582,7 +598,8 @@ def main(argv):
         return 2
     os.makedirs(OUT, exist_ok=True)
     name = "olmoe" if CELL == "olmoe-serve-chat" else CELL
-    tag = "".join(f"_{k}{v:g}" for k, v in OPTIONS.items() if v is not None)
+    tag = "".join(f"_{k}{v:.10g}" for k, v in OPTIONS.items()
+                  if v is not None)
     if phase == "sweep":
         tag += "_" + "_".join(argv[1:])
     with open(os.path.join(OUT, f"{name}_{phase}{tag}.json"), "w") as f:
