@@ -37,8 +37,17 @@ class _Error:
 def background_stage(source, depth: int, transform: Callable = None):
     """Run ``source()`` (and optionally ``transform`` per item) on a
     background thread, staying up to ``depth`` items ahead of the
-    consumer — the generic pipeline stage under ``buffered`` and
-    ``device_prefetch``.
+    consumer — the generic pipeline stage under ``buffered``,
+    ``device_prefetch`` and ``SGD.train(async_depth=N)``'s feed stage.
+
+    ``source`` and ``transform`` run on ONE thread, one after the other:
+    the stage's period is their SUM, and chaining two stages makes it the
+    longer of the two. The trainer's stage (stack a batch, then
+    ``device_put`` it) stays one: with its host buffers reused the two
+    are 13 + 1 ms of a 100 ms device step (PERF.md section 6, PR 36). An
+    item may lend out memory that its producer writes again later (the
+    trainer's buffer ring): the stage only hands items over, in order,
+    and whoever lends decides when a buffer is safe to write.
 
     Leak-safe: an abandoned consumer (early ``break``, GC of the
     generator) closes the stage — a stop flag is set and the queue

@@ -25,6 +25,56 @@ from .core.scope import Scope, global_scope
 from .data_feeder import DataFeeder
 
 
+#: sets in the feed ring at most, whatever ``async_depth``: a set holds a
+#: batch's host bytes, and until its turn a batch's feed on the device
+#: (154 MB each in ``resnet50-train``), and a transfer (38 ms there) is
+#: long over when its set comes round two or three steps later
+_RING_SETS = 3
+
+
+class _FeedRing:
+    """The host buffers that the async feed stage stacks dense columns
+    into: ``size`` sets (``DataFeeder.feed``'s ``out`` dicts), lent oldest
+    first.
+
+    **The recycle rule.** ``jax.device_put`` returns before its transfer
+    has read the host array, and on some backends the device array may BE
+    the host array: the CPU client takes a 64-byte-aligned host buffer
+    zero-copy and aliases it for the array's whole life. So a set is
+    written again only when every device array that was made from it has
+    completed its transfer (``is_ready``) AND does not alias the host
+    array. A set that fails either test when its turn comes is never
+    written again: the ring forgets it and lends an empty one, which the
+    feeder fills with fresh arrays. The stage therefore never WAITS for a
+    transfer (no span would name such a wait); what a late transfer costs
+    is a fresh allocation, and ``trainer/feed_stack`` shows it as
+    ``reused=False`` beside a longer span. That a device array has been
+    consumed, or dropped by everyone else, proves neither test (a step is
+    dispatched before its feed has arrived), so the ring holds the arrays
+    themselves until it has asked them: a batch's feed stays alive on the
+    device until its set comes round, at most ``size`` batches later."""
+
+    def __init__(self, size: int):
+        self._size = size
+        self._lent = []     # (set, [(host array, device array)])
+
+    def take(self) -> Dict[str, np.ndarray]:
+        """A set that is safe to write; never waits."""
+        if len(self._lent) < self._size:
+            return {}
+        bufs, made = self._lent.pop(0)
+        for host, arr in made:
+            if (not arr.is_ready()
+                    or arr.unsafe_buffer_pointer() == host.ctypes.data):
+                return {}
+        return bufs
+
+    def lend(self, bufs: Dict[str, np.ndarray], made) -> None:
+        """``made``: (host array of ``bufs``, device array put from it)
+        pairs; the set comes back through :meth:`take`."""
+        self._lent.append((bufs, made))
+
+
 class SGD:
     """``SGD(cost, optimizer, feed_list).train(reader, ...)``.
 
@@ -537,8 +587,17 @@ class SGD:
         ``trainer/resolve`` phases carrying a ``queue_depth`` attr, so
         tools/trace_summary.py --pipeline shows host gap vs device
         time; the feed thread's ``trainer/feed_stack`` (rows -> one host
-        array per feed) and ``trainer/feed_put`` (the ``device_put``
-        calls) say what a ``data_wait`` waited for."""
+        array per feed; attrs ``bytes`` written by the feeder's dense
+        row copies, ``fast_cols`` of ``cols`` columns that took them,
+        ``reused``: into buffers the ring already held) and
+        ``trainer/feed_put`` (the ``device_put`` calls) say what a
+        ``data_wait`` waited for.
+
+        The feed stage stacks into a :class:`_FeedRing` that this pass
+        owns: ``depth`` buffer sets, ``_RING_SETS`` at most (its bytes
+        are the dense columns'; the recycle rule is stated there). A
+        fresh 154 MB array a step costs ten times more in page faults
+        than its copy does."""
         import time as time_mod
         from collections import deque
 
@@ -550,6 +609,10 @@ class SGD:
         feeder = self.feeder
         dev = None if self.exe.mesh is not None \
             else self.exe.place.device()
+        # mesh runs keep fresh arrays: the executor shards the feed itself
+        # on the dispatch thread (``executor/shard_feed``), so this stage
+        # never sees the device arrays whose transfers gate a reuse
+        ring = None if dev is None else _FeedRing(min(depth, _RING_SETS))
 
         def feed_source():
             for batch_id, batch in enumerate(reader()):
@@ -559,21 +622,37 @@ class SGD:
                     bs = len(batch)
                 except TypeError:
                     bs = None
-                with trace.span("trainer/feed_stack", batch_id=batch_id):
-                    feed = feeder.feed(batch)
-                yield batch_id, bs, feed
+                bufs = ring.take() if ring is not None else {}
+                lent = dict(bufs)
+                with trace.span("trainer/feed_stack",
+                                batch_id=batch_id) as sp:
+                    feed = feeder.feed(batch, out=bufs)
+                    if sp is not None:
+                        # a feed that IS its entry of the set was copied
+                        # row by row: into the lent array, or a fresh one
+                        fast = [k for k, v in feed.items()
+                                if v is bufs.get(k)]
+                        sp.set_attrs(
+                            bytes=sum(feed[k].nbytes for k in fast),
+                            cols=len(feeder.feed_vars),
+                            fast_cols=len(fast),
+                            reused=bool(fast) and all(
+                                feed[k] is lent.get(k) for k in fast))
+                yield batch_id, bs, feed, bufs
 
         def to_device(item):
-            batch_id, bs, feed = item
+            batch_id, bs, feed, bufs = item
             if dev is None:  # mesh runs: the executor shards feeds itself
                 return batch_id, bs, feed
             # the span times the host call: the transfer is the device
             # trace's, and nothing here waits for it
             with trace.span("trainer/feed_put", batch_id=batch_id):
-                feed = {k: (jax.device_put(v, dev)
-                            if not isinstance(v, jax.Array) else v)
-                        for k, v in feed.items()}
-            return batch_id, bs, feed
+                put = {k: (jax.device_put(v, dev)
+                           if not isinstance(v, jax.Array) else v)
+                       for k, v in feed.items()}
+            ring.lend(bufs, [(v, put[k]) for k, v in feed.items()
+                             if v is bufs.get(k)])
+            return batch_id, bs, put
 
         m = meter
         perf = time_mod.perf_counter

@@ -28,7 +28,7 @@ class V1DataFeeder(DataFeeder):
     rectangularized to [T, Kmax] with -1 padding before the base feeder
     pads the time axis."""
 
-    def feed(self, data):
+    def feed(self, data, out=None):
         names = [v.name for v in self.feed_vars]
         rows = [[row[n] for n in names] if isinstance(row, dict) else row
                 for row in data]
@@ -47,7 +47,7 @@ class V1DataFeeder(DataFeeder):
             rows = [list(r) for r in rows]
             for r, arr in zip(rows, fixed):
                 r[i] = arr
-        return super().feed(rows)
+        return super().feed(rows, out=out)
 
 
 def make_reader(parsed: ParsedConfig, split: str = "train"):

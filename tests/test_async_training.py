@@ -13,6 +13,7 @@ import threading
 import time
 
 import numpy as np
+import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import event, layers, reader as reader_mod
@@ -132,6 +133,174 @@ def test_async_emits_dispatch_and_resolve_spans():
     assert all("queue_depth" in s.attrs for s in dispatch + resolve)
     # the window is bounded: never more than async_depth in flight
     assert max(s.attrs["queue_depth"] for s in dispatch) < 4
+
+
+# ---------------------------------------------------------------------------
+# the feed ring: dense columns stacked into reused host buffers
+# ---------------------------------------------------------------------------
+
+_WIDE = 150_000     # 8 rows x 150,000 float32 = 4.8 MB a batch
+
+
+def _wide_batches(n_batches=7, batch=8, seed=5):
+    rng = np.random.RandomState(seed)
+    return [[(rng.standard_normal(_WIDE).astype("float32"),
+              rng.randint(0, 3, size=(1,)).astype("int64"))
+             for _ in range(batch)] for _ in range(n_batches)]
+
+
+def _train_wide(batches, async_depth):
+    _fresh_programs()
+    x = layers.data("x", shape=[_WIDE])
+    y = layers.data("y", shape=[1], dtype="int64")
+    logits = layers.fc(x, size=3)
+    cost = layers.mean(layers.softmax_with_cross_entropy(logits, y))
+    trainer = SGD(cost=cost,
+                  optimizer=pt.optimizer.SGDOptimizer(learning_rate=0.01),
+                  feed_list=[x, y], place=pt.CPUPlace(), scope=pt.Scope())
+    events = []
+    trainer.train(lambda: iter(batches), num_passes=1,
+                  event_handler=events.append, async_depth=async_depth)
+    return [e.cost for e in events if isinstance(e, event.EndIteration)]
+
+
+class _AlignedNumpy:
+    """numpy, but ``empty`` returns 64-byte-aligned arrays: what the CPU
+    client takes zero-copy, so every device array IS its host buffer."""
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    @staticmethod
+    def empty(shape, dtype):
+        nbytes = int(np.prod(shape)) * np.dtype(dtype).itemsize
+        raw = np.empty(nbytes + 64, np.uint8)
+        off = (-raw.ctypes.data) % 64
+        return raw[off:off + nbytes].view(dtype).reshape(shape)
+
+
+@pytest.mark.parametrize("aligned", [False, True],
+                         ids=["copied", "aliased"])
+def test_async_feed_ring_recycles_without_changing_a_step(aligned,
+                                                          monkeypatch):
+    """More distinct batches than the ring holds (2 sets at depth 2), a
+    column of megabytes: a buffer written before the
+    transfer that reads it completed, or while a device array aliases it
+    (a step reads an aliased feed when it RUNS, after the loop has let go
+    of the array), changes some step's cost."""
+    from paddle_tpu import data_feeder, trace
+
+    if aligned:
+        monkeypatch.setattr(data_feeder, "np", _AlignedNumpy())
+    batches = _wide_batches()
+    assert len(batches) > 2
+    sync_costs = _train_wide(batches, async_depth=1)
+    tracer = trace.get_tracer()
+    prev = tracer.level
+    trace.enable(level=1)
+    tracer.clear()
+    try:
+        async_costs = _train_wide(batches, async_depth=2)
+    finally:
+        tracer.configure(level=prev)
+    assert len(sync_costs) == len(batches)
+    assert async_costs == sync_costs            # bitwise, step by step
+    stacks = [s for s in tracer.spans() if s.name == "trainer/feed_stack"]
+    assert len(stacks) == len(batches)
+    for s in stacks:
+        assert s.attrs["bytes"] == 8 * _WIDE * 4 + 8 * 8
+        assert s.attrs["fast_cols"] == s.attrs["cols"] == 2
+        assert isinstance(s.attrs["reused"], bool)
+    # the first lap of the ring allocates; an aliased set never returns
+    assert not stacks[0].attrs["reused"] and not stacks[1].attrs["reused"]
+    if aligned:
+        assert not any(s.attrs["reused"] for s in stacks)
+
+
+def _host_array(aligned: bool, n=4096):
+    """float32[n] whose data pointer is (not) 64-byte aligned: the CPU
+    client takes an aligned host buffer zero-copy."""
+    raw = np.zeros(4 * n + 128, np.uint8)
+    off = (-raw.ctypes.data) % 64 + (0 if aligned else 16)
+    return raw[off:off + 4 * n].view(np.float32)
+
+
+@pytest.mark.parametrize("aligned", [False, True],
+                         ids=["copied", "aliased"])
+def test_feed_ring_recycle_rule(aligned):
+    import jax
+
+    from paddle_tpu.trainer import _FeedRing
+
+    dev = jax.devices("cpu")[0]
+    ring = _FeedRing(1)
+    first = ring.take()
+    host = first["x"] = _host_array(aligned)
+    host[:] = 1.0
+    arr = jax.device_put(host, dev)
+    arr.block_until_ready()
+    assert (arr.unsafe_buffer_pointer() == host.ctypes.data) is aligned
+    ring.lend(first, [(host, arr)])
+    second = ring.take()
+    # a device array that IS the host buffer: the set is forgotten
+    assert (second is first) is (not aligned)
+    if aligned:
+        assert not second
+    else:
+        host[:] = 2.0       # the transfer completed: the copy is its own
+        assert float(arr[0]) == 1.0
+
+
+@pytest.mark.parametrize("ready", [True, False], ids=["arrived", "in_flight"])
+def test_feed_ring_asks_a_transfer_nobody_else_holds_and_never_waits(ready):
+    """A device array that was consumed and dropped has not thereby
+    arrived: the ring asks the array itself before it lends the set, and
+    one still in flight costs the set, not a wait."""
+    from paddle_tpu.trainer import _FeedRing
+
+    asked = []
+
+    class Put:
+        def is_ready(self):
+            asked.append(self)
+            return ready
+
+        def unsafe_buffer_pointer(self):
+            return 0
+
+    ring = _FeedRing(1)
+    first = ring.take()
+    host = first["x"] = _host_array(False)
+    ring.lend(first, [(host, Put())])           # the ring's is the only ref
+    gc.collect()
+    again = ring.take()
+    assert len(asked) == 1 and (again is first) is ready
+    assert ready or not again                   # forgotten: an empty set
+    assert ring.take() == {}                    # nothing lent: a new set
+
+
+def test_feed_ring_holds_three_sets_at_most_whatever_the_depth():
+    """``async_depth`` 8 over a dozen batches: the ring's sets, and the
+    feeds they keep alive on the device, stay at ``_RING_SETS``."""
+    from paddle_tpu import trainer as trainer_mod
+
+    rings = []
+
+    class Ring(trainer_mod._FeedRing):
+        def __init__(self, size):
+            super().__init__(size)
+            rings.append(self)
+
+    real = trainer_mod._FeedRing
+    trainer_mod._FeedRing = Ring
+    try:
+        batches = _wide_batches(n_batches=12, batch=2)
+        assert _train_wide(batches, async_depth=8) == _train_wide(
+            batches, async_depth=1)
+    finally:
+        trainer_mod._FeedRing = real
+    assert [r._size for r in rings] == [trainer_mod._RING_SETS]
+    assert len(rings[0]._lent) == trainer_mod._RING_SETS == 3
 
 
 # ---------------------------------------------------------------------------

@@ -817,7 +817,9 @@ class TestSpansOnTheProfilerClock:
     def test_feed_put_span_waits_for_no_transfer(self, monkeypatch):
         """No span site syncs: ``trainer/feed_put`` closes on the feed
         thread while what ``device_put`` returned is still in flight, and
-        nothing ever asks it to be ready."""
+        nothing ever waits for it: the feed ring polls ``is_ready`` and,
+        told no, has the batch stacked into fresh arrays, which the
+        ``trainer/feed_stack`` span says (``reused`` false)."""
         import jax
 
         waited = []
@@ -826,6 +828,9 @@ class TestSpansOnTheProfilerClock:
             def block_until_ready(self):
                 waited.append(threading.get_ident())
                 return self
+
+            def is_ready(self):
+                return False
 
         def device_put(value, device=None, **kw):
             return np.asarray(value).view(InFlight)
@@ -842,3 +847,4 @@ class TestSpansOnTheProfilerClock:
         assert {s.thread for s in puts} == {s.thread for s in stacks}
         assert threading.get_ident() not in {s.thread for s in puts}
         assert waited == []
+        assert [s.attrs["reused"] for s in stacks] == [False] * 4
