@@ -446,17 +446,15 @@ class Executor:
         fetch_names = [f.name if hasattr(f, "name") else str(f) for f in fetch_list]
         block = program.global_block
 
-        feed_vals = self._normalize_feeds(block, feed)
-
         level = trace.active_level() if trace_level is None else trace_level
-        if level >= 2 and self._mesh_plan_for(program)[0] is None:
+        interpret = level >= 2 and self._mesh_plan_for(program)[0] is None
+        feed_vals, key, compiled = self._feed_and_lookup(
+            program, feed, fetch_names, scope, interpret)
+        if interpret:
             with self.device_ctx(program):
                 return self._run_interpreted(program, feed_vals,
                                              fetch_names, scope,
                                              return_numpy)
-
-        key = self._cache_key(program, feed_vals, fetch_names, scope)
-        compiled = self._cache.get(key)
         cache_hit = compiled is not None
         if compiled is None:
             self.cache_misses += 1
@@ -506,19 +504,18 @@ class Executor:
         fetch_names = [f.name if hasattr(f, "name") else str(f)
                        for f in fetch_list]
         block = program.global_block
-        feed_vals = self._normalize_feeds(block, feed)
 
         level = trace.active_level() if trace_level is None else trace_level
-        if level >= 2 and self._mesh_plan_for(program)[0] is None:
+        interpret = level >= 2 and self._mesh_plan_for(program)[0] is None
+        feed_vals, key, compiled = self._feed_and_lookup(
+            program, feed, fetch_names, scope, interpret)
+        if interpret:
             with self.device_ctx(program):
                 outs = self._run_interpreted(program, feed_vals,
                                              fetch_names, scope,
                                              return_numpy=False)
             return RunHandle(outs, fetch_names,
                              check_nan_inf=self.check_nan_inf)
-
-        key = self._cache_key(program, feed_vals, fetch_names, scope)
-        compiled = self._cache.get(key)
         cache_hit = compiled is not None
         if compiled is None:
             self.cache_misses += 1
@@ -557,10 +554,32 @@ class Executor:
         return RunHandle(fetches, fetch_names, state_checks=checks,
                          check_nan_inf=self.check_nan_inf)
 
+    def _feed_and_lookup(self, program: Program, feed, fetch_names,
+                         scope: Scope, interpret: bool):
+        """``(feed_vals, key, compiled)`` of one call under ONE
+        ``executor/feed`` span: the executor's host work before it
+        touches the device. ``compiled`` is None on a miss; an
+        interpreted run (``interpret``) needs the feeds alone."""
+        with trace.span("executor/feed"):
+            feed_vals = self._normalize_feeds(program.global_block, feed)
+            if interpret:
+                return feed_vals, None, None
+            key = self._cache_key(program, feed_vals, fetch_names, scope)
+            return feed_vals, key, self._cache.get(key)
+
     def _call_compiled(self, compiled: "_Compiled", feed_vals,
                        scope: Scope, program: Program):
         """Invoke the compiled executable (pure dispatch, no scope
-        writes). Returns ``(fetches, new_states, new_rng_or_None)``."""
+        writes). Returns ``(fetches, new_states, new_rng_or_None)``.
+        One ``executor/launch`` span: the state lookups, the
+        host-to-device copy of the host feeds and the enqueue, as far as
+        the call blocks for them."""
+        with trace.span("executor/launch"):
+            out = self._launch(compiled, feed_vals, scope, program)
+        return self._unpack(compiled, out)
+
+    def _launch(self, compiled: "_Compiled", feed_vals, scope: Scope,
+                program: Program):
         feed_args = [feed_vals[n] for n in compiled.feed_names]
         ro_args = [scope.get(n) for n in compiled.ro_state_names]
         rw_args = [scope.get(n) for n in compiled.rw_state_names]
@@ -587,8 +606,7 @@ class Executor:
                 # entry compiled lazily (as_function path)
                 self._finish_compile(compiled, feed_vals, scope, program)
             tail = (rng,) if rng is not None else ()
-            out = compiled.aot(feed_args, ro_args, rw_args, *tail)
-        return self._unpack(compiled, out)
+            return compiled.aot(feed_args, ro_args, rw_args, *tail)
 
     @staticmethod
     def _unpack(compiled: "_Compiled", out):
@@ -754,9 +772,11 @@ class Executor:
         if self.check_nan_inf:
             for name, val in zip(fetch_names, fetches):
                 _check_nan_inf(name, val)
-        if return_numpy:
+        if not return_numpy:
+            return list(fetches)
+        # the wait for the device and the copy back
+        with trace.span("executor/fetch"):
             return [self._fetch_numpy(densify(v)) for v in fetches]
-        return list(fetches)
 
     def _call_op(self, op, opdef, ins, env, rng, vjp_pairs,
                  program: Program, scope: Scope):

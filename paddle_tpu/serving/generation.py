@@ -1584,35 +1584,37 @@ class GenerationEngine:
             for i in range(0, len(group), cap):
                 self._run_prefill_group(group[i:i + cap])
             return
-        rem = [st.prompt.size - st.prefill_done for _, st, _ in group]
-        tc = self._chunk_bucket_for(max(rem))
-        bucket = self._batch_bucket_for(len(group))
-        chunk = np.full((bucket, tc), self.pad_id, np.int64)
-        start = np.zeros(bucket, np.int32)
-        length = np.zeros(bucket, np.int32)
-        table = np.zeros((bucket, self.pmax), np.int32)
-        table_w = np.zeros_like(table)
-        feed = self._neutral_sampling_feed(bucket)
-        for row, (req, st, slot) in enumerate(group):
-            r = rem[row]
-            chunk[row, :r] = st.prompt[st.prefill_done:]
-            start[row] = st.prefill_done
-            length[row] = r
-            table[row, :len(st.pages)] = st.pages
+        with trace.span("serving/build_feed", phase="prefill_group"):
+            rem = [st.prompt.size - st.prefill_done for _, st, _ in group]
+            tc = self._chunk_bucket_for(max(rem))
+            bucket = self._batch_bucket_for(len(group))
+            chunk = np.full((bucket, tc), self.pad_id, np.int64)
+            start = np.zeros(bucket, np.int32)
+            length = np.zeros(bucket, np.int32)
+            table = np.zeros((bucket, self.pmax), np.int32)
+            table_w = np.zeros_like(table)
+            feed = self._neutral_sampling_feed(bucket)
+            for row, (req, st, slot) in enumerate(group):
+                r = rem[row]
+                chunk[row, :r] = st.prompt[st.prefill_done:]
+                start[row] = st.prefill_done
+                length[row] = r
+                table[row, :len(st.pages)] = st.pages
+                if self._by_kind:
+                    self._window_advance(st, st.prefill_done,
+                                         st.prompt.size - 1)
+                    table_w[row] = self._window_row(st)
+                # step = tokens already sampled: 0 for a fresh request; a
+                # RESUMED one samples its next token at step len(emitted),
+                # keeping (seed, step) aligned with the uninterrupted
+                # stream
+                self._slot_sampling_feed(row, st, feed,
+                                         step=len(st.generated))
+            feed.update({"serving.chunk": chunk, "serving.start": start,
+                         "serving.chunk_len": length,
+                         "serving.block_table": table})
             if self._by_kind:
-                self._window_advance(st, st.prefill_done,
-                                     st.prompt.size - 1)
-                table_w[row] = self._window_row(st)
-            # step = tokens already sampled: 0 for a fresh request; a
-            # RESUMED one samples its next token at step len(emitted),
-            # keeping (seed, step) aligned with the uninterrupted stream
-            self._slot_sampling_feed(row, st, feed,
-                                     step=len(st.generated))
-        feed.update({"serving.chunk": chunk, "serving.start": start,
-                     "serving.chunk_len": length,
-                     "serving.block_table": table})
-        if self._by_kind:
-            feed["serving.block_table_w"] = table_w
+                feed["serving.block_table_w"] = table_w
         prog, outs = self._prefill_prog(tc)
         t0 = time.perf_counter()
         with trace.span("serving/prefill_group", rows=len(group),
@@ -1791,36 +1793,31 @@ class GenerationEngine:
         start0 = st.prefill_done
         k = min(self.prefill_chunk, plen - start0)
         tc = self._chunk_bucket_for(k)
-        bucket = self._batch_bucket_for(1)
-        chunk = np.full((bucket, tc), self.pad_id, np.int64)
-        start = np.zeros(bucket, np.int32)
-        length = np.zeros(bucket, np.int32)
-        table = np.zeros((bucket, self.pmax), np.int32)
-        chunk[0, :k] = st.prompt[start0:start0 + k]
-        start[0] = start0
-        length[0] = k
-        table[0, :len(st.pages)] = st.pages
-        feed = self._neutral_sampling_feed(bucket)
-        # same step contract as the group path: 0 unless resumed
-        self._slot_sampling_feed(0, st, feed, step=len(st.generated))
-        feed.update({"serving.chunk": chunk, "serving.start": start,
-                     "serving.chunk_len": length,
-                     "serving.block_table": table})
-        ctx_pages = {}
-        if self._by_kind:
-            last = start0 + k - 1
-            self._window_advance(st, start0, last)
-            table_w = np.zeros((bucket, self.pmax), np.int32)
-            table_w[0] = self._window_row(st)
-            feed["serving.block_table_w"] = table_w
-            # the pages of context each kind's layers attend this chunk
-            ctx_pages = {
-                "ctx_pages_global": last // self.page_size + 1,
-                "ctx_pages_window": last // self.page_size + 1 - st.wfirst}
+        with trace.span("serving/build_feed", phase="prefill_chunk"):
+            bucket = self._batch_bucket_for(1)
+            chunk = np.full((bucket, tc), self.pad_id, np.int64)
+            start = np.zeros(bucket, np.int32)
+            length = np.zeros(bucket, np.int32)
+            table = np.zeros((bucket, self.pmax), np.int32)
+            chunk[0, :k] = st.prompt[start0:start0 + k]
+            start[0] = start0
+            length[0] = k
+            table[0, :len(st.pages)] = st.pages
+            feed = self._neutral_sampling_feed(bucket)
+            # same step contract as the group path: 0 unless resumed
+            self._slot_sampling_feed(0, st, feed, step=len(st.generated))
+            feed.update({"serving.chunk": chunk, "serving.start": start,
+                         "serving.chunk_len": length,
+                         "serving.block_table": table})
+            if self._by_kind:
+                self._window_advance(st, start0, start0 + k - 1)
+                table_w = np.zeros((bucket, self.pmax), np.int32)
+                table_w[0] = self._window_row(st)
+                feed["serving.block_table_w"] = table_w
         prog, outs = self._prefill_prog(tc)
         t0 = time.perf_counter()
         with trace.span("serving/prefill_chunk", slot=slot,
-                        offset=start0, tokens=k, **ctx_pages):
+                        offset=start0, tokens=k):
             res = self.executor.run(prog, feed=feed,
                                     fetch_list=self._fetches(outs),
                                     scope=self.scope)
@@ -1851,7 +1848,11 @@ class GenerationEngine:
             self._gauges()
         return True
 
-    def _run_decode(self):
+    def _decode_feed(self) -> Dict[str, np.ndarray]:
+        """The feeds of one decode tick, counted as they are built:
+        every slot's row of the token, position, table and sampling
+        planes (a vacant slot rides along greedy on the scrap
+        page)."""
         table = np.zeros((self.slots, self.pmax), np.int32)
         table_w = np.zeros_like(table)
         tok = np.zeros(self.slots, np.int64)
@@ -1914,6 +1915,15 @@ class GenerationEngine:
             # for key and value, ``paged_mla_decode``)
             self.metrics.inc("paged_attn_pages_read", int(held.sum()))
             self.metrics.inc("paged_attn_table_pages", table.size)
+        # what ``Executor.run`` has to copy to the device before it can
+        # enqueue the tick: the host-resident feeds
+        self.metrics.inc("decode_feed_host_bytes", sum(
+            v.nbytes for v in feed.values() if isinstance(v, np.ndarray)))
+        return feed
+
+    def _run_decode(self):
+        with trace.span("serving/build_feed", phase="decode"):
+            feed = self._decode_feed()
         prog, outs = self._decode_prog
         res = self.executor.run(prog, feed=feed,
                                 fetch_list=self._fetches(outs),
