@@ -167,18 +167,42 @@ class LMTrainer:
         return losses, order
 
 
+_MOSAIC_CALL = re.compile(
+    r'%(\w+?)\.\d+ = [^\n]*?custom_call_target="tpu_custom_call", '
+    r'operand_layout_constraints=\{([^\n]*?)\}\}')
+
+
+def mosaic_calls(hlo_text):
+    """[(kernel, [operand type, ...])] of a compiled module's Mosaic call
+    instructions, under the names its trace shows: ``%flash_fwd.16 = ...
+    custom-call(...), custom_call_target="tpu_custom_call",
+    operand_layout_constraints={s32[128]{0}, bf16[128,1024,64]{2,1,0},
+    ...}`` -> ("flash_fwd", ["s32[128]", "bf16[128,1024,64]", ...])."""
+    return [(name, re.findall(r"(\w+\[[\d,]*\])", operands))
+            for name, operands in _MOSAIC_CALL.findall(hlo_text)]
+
+
 def compiled_step_loops_and_kernels(tr):
-    """(``while`` instructions, Counter of Mosaic call instructions by
-    kernel) of the COMPILED train step: what the device runs, under the
-    names its trace shows (``%flash_fwd.16 = ... tpu_custom_call``)."""
-    loops, kernels = 0, collections.Counter()
+    """(``while`` instructions, the Mosaic call instructions as
+    ``mosaic_calls`` gives them) of the COMPILED train step: what the
+    device runs."""
+    loops, calls = 0, []
     for c in tr.sgd.exe._cache.values():
         text = c.aot.as_text()
         loops += len(re.findall(r"= .* while\(", text))
-        kernels.update(re.findall(
-            r'%(\w+?)\.\d+ = [^\n]*custom_call_target="tpu_custom_call"',
-            text))
-    return loops, kernels
+        calls += mosaic_calls(text)
+    return loops, calls
+
+
+def flash_operands_not_bf16(calls, rows, seq, d_head):
+    """The flash calls among ``calls`` (``mosaic_calls``) whose
+    ``[rows, seq, d_head]`` operands (q, k, v and, in the backward, dO) are
+    not all bf16, or are not all there: what AMP must leave empty."""
+    want = f"bf16[{rows},{seq},{d_head}]"
+    return [(name, types) for name, types in calls
+            if name.startswith("flash_")
+            and [t for t in types if t.endswith(f"[{rows},{seq},{d_head}]")]
+            != [want] * (3 if name == "flash_fwd" else 4)]
 
 
 def phase_train(cfg):
@@ -188,7 +212,7 @@ def phase_train(cfg):
     and backward (core/backward.py), and on a TPU the compiled step holds
     TWO loops (forward scan, backward scan) running the Mosaic flash
     kernels: two ``flash_fwd`` a layer (forward, remat recompute), one
-    ``flash_dq``, one ``flash_dkv``."""
+    ``flash_dq``, one ``flash_dkv``, each on bf16 operands (AMP is on)."""
     t0 = time.perf_counter()
     tr = LMTrainer(cfg)
     sync_losses, sync_order = tr.train(cfg["sync_steps"], seed=SEED)
@@ -207,7 +231,8 @@ def phase_train(cfg):
     check(losses[-1] < losses[0] - cfg["loss_margin"],
           f"loss did not fall by {cfg['loss_margin']}: "
           f"{losses[0]:.4f} -> {losses[-1]:.4f}")
-    loops, kernels = compiled_step_loops_and_kernels(tr)
+    loops, calls = compiled_step_loops_and_kernels(tr)
+    kernels = collections.Counter(name for name, _ in calls)
     check(tr.sgd.exe.cache_stats()["paired_vjp_ops"] == 1,
           "the stack op was not paired with its grad op: the train step "
           "traces its forward scan twice")
@@ -222,6 +247,11 @@ def phase_train(cfg):
               f"Mosaic calls of the compiled train step {dict(kernels)}: "
               "expected two flash_fwd a layer (forward scan, remat "
               "recompute), one flash_dq, one flash_dkv")
+        f32_fed = flash_operands_not_bf16(
+            calls, cfg["batch"] * cfg["heads"], cfg["seq"],
+            cfg["d_model"] // cfg["heads"])
+        check(not f32_fed, "under AMP every flash call takes bf16 "
+              f"[rows, T, d_head] operands; these do not: {f32_fed}")
     emit("lm_train", t0, steps=len(losses),
          first_loss=round(losses[0], 4), last_loss=round(losses[-1], 4),
          loss_margin=cfg["loss_margin"],

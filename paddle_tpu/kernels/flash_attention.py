@@ -26,11 +26,36 @@ import math
 import jax
 import jax.numpy as jnp
 
-# Large blocks amortise the per-iteration VPU work (masking, exp, online
-# rescale) over more MXU work — the d=64 head dim makes the matmuls thin,
-# so the block sizes carry the efficiency. Device-traced sweep at
-# bs8/h16/T2048/d64 fwd+bwd: 512x512 7.9 ms, 256x512 9.0, 512x256 10.5,
-# 256x256 12.1 (PERF.md).
+# Blocks: 512 x 512 for the three kernels, whatever the operand type.
+# `tools/flash_block_sweep.py` on a v5e under JAX 0.9.0 (PR 41), causal,
+# bf16 operands, ms a call by block_q x block_k (a chain of 24 dependent
+# calls by the host clock: 0.1-0.2 ms of every reading is the chain's
+# carry, the same along a row; a traced train step has the kernels of
+# the first shape at 0.70 / 0.78 (two forwards), 0.68, 0.75 ms):
+#
+#   [rows, T, d_head] kernel 256x256 128x512 256x512 512x256 512x512 512x1024
+#   [128, 1024, 64]   fwd     1.104   0.870   0.857   1.099   0.842   0.971
+#   (the LM train     dq      0.921   0.988   0.805   0.864   0.751   0.856
+#   cells)            dkv     1.122   1.238   1.065   0.890   0.866   1.009
+#   [128, 2048, 64]   fwd     3.290   2.628   2.289   3.101   2.262   2.517
+#                     dq      2.886   2.840   2.354   2.531   2.165   2.351
+#                     dkv     3.894   3.935   3.423   2.909   2.734   3.094
+#   [64, 2048, 128]   fwd     1.612   1.275   1.107   1.500   1.076   1.202
+#                     dq      1.402   1.370   1.134   1.234   1.054   1.144
+#                     dkv     1.842   1.875   1.628   1.352   1.265   1.457
+#
+# (128 x 128: 1.8-2.0, 6.3-7.1 and 3.1-3.5.)
+# float32 operands at [128, 1024, 64] pick the same pair (0.955 / 0.857 /
+# 1.323 against 0.976 / 0.979 / 1.392 at 256 x 512). The kernels are
+# bound by the float32 passes over the [block_q, block_k] score tile and
+# by what a loop turn costs whatever its width (two lane reductions, the
+# [block_q, 1] statistics, the accumulator's rescale), not by their dots:
+# at d_head 64 a dot fills half the MXU, and bf16 operands moved the
+# kernels by under 10%. So a smaller block's larger skip under the causal
+# mask (10/16 of the square at 256 x 256 and T 1024, against 3/4) loses
+# to its extra turns, a block_k beyond 512 to the skip it gives up, and a
+# second, mask-free loop for the blocks below the diagonal was slower in
+# 106 of 108 readings, by up to 9% (measured, then removed).
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 
@@ -196,6 +221,28 @@ def reference_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
+def _live_blocks(qb, block_q, block_k, n_blocks, causal):
+    """How many k-blocks q-block ``qb`` walks: under the causal mask the
+    blocks wholly above the diagonal contribute nothing and are skipped."""
+    if not causal:
+        return n_blocks
+    last = (qb + 1) * block_q  # exclusive bound on visible columns
+    return jnp.minimum(n_blocks, (last + block_k - 1) // block_k)
+
+
+def _column_bound(qb, block_q, length, causal):
+    """Row i of q-block ``qb`` sees the key columns below this bound:
+    ``min(i + 1, length)`` under the causal mask ([bq, 1]), ``length``
+    without. With it a block's whole mask is ONE compare a score against
+    a column vector moved by the block's offset (the kernels are bound by
+    the float32 passes over the score tile, not by their dots)."""
+    if not causal:
+        return length
+    row = qb * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, 1), 0)
+    return jnp.minimum(row + 1, length)
+
+
 def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
                   causal, sm_scale, kv_len):
     from jax.experimental import pallas as pl
@@ -210,17 +257,9 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
     m0 = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
     acc0 = jnp.zeros((block_q, d), jnp.float32)
-    n_blocks = kv_len // block_k
-    if causal:
-        # blocks fully above the diagonal contribute nothing — skip them
-        last = (qb + 1) * block_q  # exclusive bound on visible columns
-        n_live = (last + block_k - 1) // block_k
-        ub = jnp.minimum(n_blocks, n_live)
-    else:
-        ub = n_blocks
-
-    q_pos = qb * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
+    ub = _live_blocks(qb, block_q, block_k, kv_len // block_k, causal)
+    bound = _column_bound(qb, block_q, length, causal)
+    col = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
 
     def body(j, carry):
         m, l, acc = carry
@@ -229,19 +268,12 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
         s = jax.lax.dot_general(
             q, k, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # [bq, bk]
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = k_pos < length
-        if causal:
-            mask &= q_pos >= k_pos
-        s = jnp.where(mask, s, -jnp.inf)
+        s = jnp.where(col < bound - j * block_k, s, -jnp.inf)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         # keep -inf rows stable (fully masked so far)
         m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - m_safe)
-        p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(jnp.where(jnp.isfinite(m), m - m_safe, -jnp.inf))
-        alpha = jnp.where(jnp.isfinite(m), alpha, 0.0)
+        p = jnp.exp(s - m_safe)  # a masked score: exp(-inf) = 0 exactly
+        alpha = jnp.exp(m - m_safe)  # m = -inf (nothing seen yet): 0
         l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc = acc * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v,
@@ -316,14 +348,9 @@ def _flash_dq_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
     dd = dd_ref[0, 0][:, None]                # [bq, 1] rowsum(dO * O)
     length = len_ref[pl.program_id(0)]
 
-    n_blocks = kv_len // block_k
-    if causal:
-        last = (qb + 1) * block_q
-        ub = jnp.minimum(n_blocks, (last + block_k - 1) // block_k)
-    else:
-        ub = n_blocks
-    q_pos = qb * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
+    ub = _live_blocks(qb, block_q, block_k, kv_len // block_k, causal)
+    bound = _column_bound(qb, block_q, length, causal)
+    col = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
 
     def body(j, acc):
         k = k_ref[0, pl.ds(j * block_k, block_k), :]
@@ -331,12 +358,8 @@ def _flash_dq_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         s = jax.lax.dot_general(
             q, k, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = k_pos < length
-        if causal:
-            mask &= q_pos >= k_pos
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)     # [bq, bk]
+        p = jnp.where(col < bound - j * block_k,
+                      jnp.exp(s - lse), 0.0)           # [bq, bk]
         dp = jax.lax.dot_general(
             do, v, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)        # [bq, bk]
@@ -352,6 +375,11 @@ def _flash_dq_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
 
 def _flash_dkv_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                       dk_ref, dv_ref, *, block_q, causal, sm_scale, q_len):
+    """dk, dv of one k-block. The score tile is held TRANSPOSED, [bk, bq]:
+    ``K Q^T`` and ``V dO^T`` contract the operands' last axes, ``P^T dO``
+    and ``dS^T Q`` are plain products, so no [bq, bk] tile is transposed
+    on its way into a dot, and logsumexp / delta are read as the [1, bq]
+    rows they are stored as."""
     from jax.experimental import pallas as pl
 
     kb = pl.program_id(1)
@@ -362,36 +390,34 @@ def _flash_dkv_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
 
     n_blocks = q_len // block_q
     lb = (kb * block_k) // block_q if causal else 0
-    k_pos = kb * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+    # the first query that sees key row r: the key's own position under
+    # the causal mask, 0 without, none (q_len) for a key beyond ``length``
+    k_row = kb * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_k, 1), 0)
+    first = jnp.where(k_row < length, k_row if causal else 0, q_len)
+    col = jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
+    a_bt = (((1,), (1,)), ((), ()))
+    a_b = (((1,), (0,)), ((), ()))
 
     def body(i, carry):
         dk_acc, dv_acc = carry
         q = q_ref[0, pl.ds(i * block_q, block_q), :]
         do = do_ref[0, pl.ds(i * block_q, block_q), :]
-        lse = lse_ref[0, 0, pl.ds(i * block_q, block_q)][:, None]
-        dd = dd_ref[0, 0, pl.ds(i * block_q, block_q)][:, None]
+        lse = lse_ref[0, :, pl.ds(i * block_q, block_q)]        # [1, bq]
+        dd = dd_ref[0, :, pl.ds(i * block_q, block_q)]          # [1, bq]
         s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [bq, bk]
-        mask = k_pos < length
-        if causal:
-            q_pos = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask &= q_pos >= k_pos
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)          # [bq, bk]
+            k, q, a_bt, preferred_element_type=jnp.float32) * sm_scale
+        p = jnp.where(col >= first - i * block_q,
+                      jnp.exp(s - lse), 0.0)                    # [bk, bq]
         dv_acc = dv_acc + jax.lax.dot_general(
-            p.astype(do.dtype), do,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [bk, d]
+            p.astype(do.dtype), do, a_b,
+            preferred_element_type=jnp.float32)                 # [bk, d]
         dp = jax.lax.dot_general(
-            do, v, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [bq, bk]
+            v, do, a_bt, preferred_element_type=jnp.float32)    # [bk, bq]
         ds = p * (dp - dd)
         dk_acc = dk_acc + jax.lax.dot_general(
-            ds.astype(q.dtype), q,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [bk, d]
+            ds.astype(q.dtype), q, a_b,
+            preferred_element_type=jnp.float32)                 # [bk, d]
         return dk_acc, dv_acc
 
     z = jnp.zeros((block_k, d), jnp.float32)
@@ -577,15 +603,27 @@ def flash_attention(q, k, v, lengths=None, causal=False, sm_scale=None):
 
     Pallas flash kernel on TPU, jnp reference elsewhere; differentiable via
     recompute. ``lengths`` [B] masks K/V padding columns.
+
+    Under AMP float32 q / k / v are matmul operands like any other
+    (``ops.common.amp_cast``): the kernels, their saved residuals and the
+    cotangent they are handed are bf16 (accumulation, softmax statistics
+    and logsumexp stay float32), and the result and the gradients come
+    back in the caller's dtype. Without AMP, or for operands already
+    bf16, nothing is cast.
     """
+    from ..ops.common import amp_cast
     from ..parallel.context import current_mesh
 
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    out_dtype = q.dtype
+    q, k, v = amp_cast(q, k, v)
     mesh = current_mesh()
     if (jax.default_backend() == "tpu" and mesh is not None
             and mesh.size > 1
             and not jax.sharding.get_abstract_mesh().manual_axes):
         # (already-manual callers — the GPipe stages — hold local shards)
-        return _batch_local(mesh, q, k, v, lengths, causal, float(sm_scale))
-    return _attention(q, k, v, lengths, causal, float(sm_scale))
+        out = _batch_local(mesh, q, k, v, lengths, causal, float(sm_scale))
+    else:
+        out = _attention(q, k, v, lengths, causal, float(sm_scale))
+    return out.astype(out_dtype)

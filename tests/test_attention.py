@@ -110,6 +110,223 @@ class TestFlashAttention:
                                        rtol=1e-4, atol=1e-5)
 
 
+# ---------------------------------------------------------------------------
+# bf16 operands under AMP, on blocks chosen from (T, d_head, operand width)
+# ---------------------------------------------------------------------------
+# bf16 keeps 8 significant bits: q, k, v, p, dS and dO are each rounded to
+# half an ulp (2^-9 relative) before a dot that accumulates in float32, and
+# o / dq / dk / dv once more on the way out. Stated tolerance against the
+# float32 reference on the SAME float32 inputs: 2^-6 of the tensor's
+# largest magnitude (eight roundings' worth; measured 0.2-0.5 of it).
+BF16_TOL = 2.0 ** -6
+
+
+@pytest.fixture
+def pallas_path(monkeypatch):
+    """``flash_attention``'s TPU branch on the CPU: the backend reads
+    "tpu" and the three kernels run in interpret mode. -> the inner calls
+    made, as (pass, operand dtypes, preferred blocks)."""
+    calls = []
+    forward, backward = fa._flash_forward, fa._flash_backward
+
+    def fwd(q, k, v, lengths, causal, sm_scale, block_q, block_k,
+            interpret):
+        calls.append(("fwd", {a.dtype for a in (q, k, v)},
+                      (block_q, block_k)))
+        return forward(q, k, v, lengths, causal, sm_scale, block_q, block_k,
+                       interpret=True)
+
+    def bwd(q, k, v, o, lse, lengths, g, causal, sm_scale, block_q,
+            block_k, interpret):
+        calls.append(("bwd", {a.dtype for a in (q, k, v, o, g)},
+                      (block_q, block_k)))
+        return backward(q, k, v, o, lse, lengths, g, causal, sm_scale,
+                        block_q, block_k, interpret=True)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "_flash_forward", fwd)
+    monkeypatch.setattr(fa, "_flash_backward", bwd)
+    return calls
+
+
+def _qkvg(T, D=64, B=2, H=1, seed=0):
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.randn(B, H, T, D).astype(np.float32))
+                 for _ in range(4))
+
+
+def _out_and_grads(fn, q, k, v, g):
+    out, vjp = jax.vjp(fn, q, k, v)
+    return (out,) + vjp(g)
+
+
+class TestFlashBf16:
+    # 1024: the LM train cells' context; 2048: chip_smoke's; 640: only 128
+    # divides it; 200: not a lane multiple (padded to 256 on the way in)
+    @pytest.mark.parametrize("T", [1024, 2048, 640, 200])
+    @pytest.mark.parametrize("masking", ["causal", "lengths",
+                                         "causal+lengths"])
+    def test_kernels_on_bf16_match_float32_reference(self, pallas_path, T,
+                                                     masking):
+        """The three Pallas kernels (interpret mode) as AMP runs them —
+        bf16 operands, at the blocks ``_pick_block`` returns for the
+        shape — against the float32 reference: o, dq, dk, dv."""
+        q, k, v, g = _qkvg(T, seed=T)
+        causal = "causal" in masking
+        lengths = (jnp.asarray([T, T - T // 3], jnp.int32)
+                   if "lengths" in masking else None)
+        pt.set_amp(True)    # (conftest's autouse fixture puts it back)
+        got = _out_and_grads(
+            lambda q, k, v: fa.flash_attention(q, k, v, lengths=lengths,
+                                               causal=causal), q, k, v, g)
+        pt.set_amp(False)
+        ref = _out_and_grads(
+            lambda q, k, v: fa.reference_attention(q, k, v, lengths=lengths,
+                                                   causal=causal),
+            q, k, v, g)
+        for name, a, b in zip(("o", "dq", "dk", "dv"), got, ref):
+            assert a.dtype == jnp.float32 and a.shape == b.shape, name
+            a, b = np.asarray(a), np.asarray(b)
+            np.testing.assert_allclose(a, b, rtol=0, err_msg=name,
+                                       atol=BF16_TOL * np.abs(b).max())
+        assert pallas_path == [
+            (which, {jnp.dtype(jnp.bfloat16)},
+             (fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K))
+            for which in ("fwd", "bwd")]
+
+    def test_the_bf16_tolerance_tells_a_missing_mask(self, pallas_path):
+        """The tolerance tells a wrong kernel: with the causal mask left
+        off the same comparison fails."""
+        q, k, v, g = _qkvg(256, seed=3)
+        pt.set_amp(True)
+        got = np.asarray(fa.flash_attention(q, k, v, causal=False))
+        ref = np.asarray(fa.reference_attention(q, k, v, causal=True))
+        assert np.abs(got - ref).max() > 4 * BF16_TOL * np.abs(ref).max()
+
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_amp_hands_the_inner_call_bf16_and_returns_the_callers_dtype(
+            self, causal):
+        """Read off the jaxpr: under AMP the custom-VJP call takes bf16
+        q / k / v and returns bf16, the result and the three gradients
+        are float32, and the backward is handed a bf16 cotangent."""
+        q, k, v, g = _qkvg(32, D=8)
+        pt.set_amp(True)
+
+        def f(q, k, v):
+            return fa.flash_attention(q, k, v, causal=causal)
+
+        jaxpr = jax.make_jaxpr(f)(q, k, v)
+        (call,) = [e for e in jaxpr.eqns
+                   if e.primitive.name == "custom_vjp_call"]
+        assert [x.aval.dtype for x in call.invars] == [jnp.bfloat16] * 3
+        assert [x.aval.dtype for x in call.outvars] == [jnp.bfloat16]
+        assert [a.dtype for a in jaxpr.out_avals] == [jnp.float32]
+        out, dq, dk, dv = _out_and_grads(f, q, k, v, g)
+        assert {a.dtype for a in (out, dq, dk, dv)} == {
+            jnp.dtype(jnp.float32)}
+        # every value the kernels return IS a bf16 value
+        for a in (out, dq, dk, dv):
+            assert jnp.array_equal(a, a.astype(jnp.bfloat16)
+                                   .astype(jnp.float32))
+        seen = []
+        bwd = fa._attention.bwd     # what defvjp registered
+        try:
+            fa._attention.bwd = lambda c, s, res, g: (
+                seen.append(g.dtype), bwd(c, s, res, g))[1]
+            jax.vjp(f, q, k, v)[1](g)
+        finally:
+            fa._attention.bwd = bwd
+        assert seen == [jnp.dtype(jnp.bfloat16)]
+
+    def test_bf16_operands_pass_through_whatever_amp_says(self):
+        """Operands already bf16 (the MoE specs' stream) are not touched:
+        no convert in the jaxpr, bf16 out, with AMP on or off."""
+        q, k, v, _ = (a.astype(jnp.bfloat16) for a in _qkvg(32, D=8))
+        for amp in (True, False):
+            pt.set_amp(amp)
+            jaxpr = jax.make_jaxpr(
+                lambda q, k, v: fa.flash_attention(q, k, v, causal=True))(
+                    q, k, v)
+            assert [e.primitive.name for e in jaxpr.eqns] == [
+                "custom_vjp_call"]
+            assert jaxpr.out_avals[0].dtype == jnp.bfloat16
+
+    @pytest.mark.parametrize("masking", ["causal", "lengths", "plain"])
+    def test_without_amp_nothing_changes_bitwise(self, masking):
+        """AMP off: ``flash_attention`` is the parent's — the one
+        custom-VJP call on the caller's float32 arrays and nothing else
+        — so output and gradients are its bits."""
+        q, k, v, g = _qkvg(48, D=8, seed=5)
+        causal = masking == "causal"
+        lengths = (jnp.asarray([48, 17], jnp.int32)
+                   if masking == "lengths" else None)
+        sm = 1.0 / math.sqrt(8)
+        pt.set_amp(False)
+
+        def f(q, k, v):
+            return fa.flash_attention(q, k, v, lengths=lengths,
+                                      causal=causal)
+
+        def parent(q, k, v):    # the body of flash_attention at PR 40
+            return fa._attention(q, k, v, lengths, causal, float(sm))
+
+        jaxpr = jax.make_jaxpr(f)(q, k, v)
+        assert [e.primitive.name for e in jaxpr.eqns] == ["custom_vjp_call"]
+        assert str(jaxpr) == str(jax.make_jaxpr(parent)(q, k, v))
+        for a, b in zip(_out_and_grads(f, q, k, v, g),
+                        _out_and_grads(parent, q, k, v, g)):
+            assert a.dtype == jnp.float32
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _executed_share(T, block_q, block_k):
+    """The share of the causal [T, T] score square the kernels execute at
+    these blocks: k-block j is live for q-block i while j * bk < (i + 1)
+    * bq (the same bound for the q-blocks a k-block walks)."""
+    live = sum(1 for i in range(T // block_q) for j in range(T // block_k)
+               if j * block_k < (i + 1) * block_q)
+    return live * block_q * block_k / (T * T)
+
+
+class TestBlockChoice:
+    """``_pick_block`` is a pure function of the sequence length: the
+    preferred 512 (every chip sweep's answer, for bf16 and float32
+    operands, d_head 64 and 128: kernels/flash_attention.py has the
+    times) where it divides T, else its largest power-of-two fraction
+    that does."""
+
+    @pytest.mark.parametrize("T,block,share", [
+        (1024, 512, 3 / 4),     # the LM train cells
+        (2048, 512, 10 / 16),   # chip_smoke's context
+        (640, 128, 15 / 25),    # five lane tiles: only 128 divides
+        (96, 96, 1.0),          # shorter than any block: one block
+    ])
+    def test_table(self, T, block, share):
+        """The block a kernel gets for T, and the share of the causal
+        score square the walk executes at it."""
+        assert fa._pick_block(T, fa.DEFAULT_BLOCK_Q) == block
+        assert fa._pick_block(T, fa.DEFAULT_BLOCK_K) == block
+        assert _executed_share(T, block, block) == pytest.approx(share)
+
+    @pytest.mark.parametrize("T,blocks,share", [
+        (1024, (256, 256), 10 / 16), (1024, (128, 128), 36 / 64),
+        (1024, (256, 512), 3 / 4), (2048, (512, 1024), 3 / 4)])
+    def test_executed_share_of_smaller_and_unequal_blocks(self, T, blocks,
+                                                          share):
+        """What the sweep weighed against the loop turns: smaller blocks
+        skip more of the square (and lost on the chip all the same)."""
+        assert _executed_share(T, *blocks) == pytest.approx(share)
+
+    @pytest.mark.parametrize("preferred", [128, 256, 512])
+    def test_block_divides_every_length(self, preferred):
+        """Never larger than T, always a divisor of it: every T the pad
+        to 128 lanes can hand the kernels, and the ragged ones a test
+        hands them directly."""
+        for t in list(range(128, 4097, 128)) + [96, 200, 8, 1]:
+            b = fa._pick_block(t, preferred)
+            assert 1 <= b <= min(t, preferred) and t % b == 0, (t, b)
+
+
 class TestRingAttention:
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_full_attention(self, causal):

@@ -368,11 +368,16 @@ def test_stacked_train_step_on_the_v5e_runs_its_forward_scan_once(
     ``flash_dkv``. With the generic grad op tracing the stack a second
     time (before core/backward.py paired them) the chip's compiler kept
     three loops and three ``flash_fwd``: XLA does not merge two loops.
+    Under AMP, as the train cells run it: every flash call takes bf16
+    ``[rows, T, d_head]`` operands at the blocks the kernel file picks
+    for them, so a block shape Mosaic refuses for bf16 fails HERE.
     (Lives here because one file a worker may describe the topology.)"""
+    import chip_smoke
     import paddle_tpu as pt
     from paddle_tpu import layers, models
 
     T, V, B = 1024, 512, 2
+    pt.set_amp(True)    # (conftest's autouse fixture puts the policy back)
     main, startup = pt.Program(), pt.Program()
     with pt.program_guard(main, startup):
         ids = layers.data("ids", shape=[T], dtype="int64")
@@ -402,10 +407,13 @@ def test_stacked_train_step_on_the_v5e_runs_its_forward_scan_once(
     (compiled,) = exe._cache.values()
     text = compiled.aot.as_text()
     assert len(re.findall(r"= .* while\(", text)) == 2
-    calls = re.findall(
-        r'%(\w+?)\.\d+ = [^\n]*custom_call_target="tpu_custom_call"', text)
-    assert sorted(calls) == ["flash_dkv", "flash_dq", "flash_fwd",
-                             "flash_fwd"]
+    calls = chip_smoke.mosaic_calls(text)
+    assert sorted(name for name, _ in calls) == [
+        "flash_dkv", "flash_dq", "flash_fwd", "flash_fwd"]
+    assert not chip_smoke.flash_operands_not_bf16(calls, B * 16, T, 64)
+    # the check can fail: it tells a float32-fed call
+    assert chip_smoke.flash_operands_not_bf16(
+        [("flash_fwd", ["s32[32]"] + ["f32[32,1024,64]"] * 3)], 32, T, 64)
     assert exe.cache_stats()["paired_vjp_ops"] == 1
 
 

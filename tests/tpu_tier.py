@@ -469,10 +469,10 @@ def flash_attention_d128_matches_reference():
 def flash_attention_full_width_shapes():
     """The shapes the full-width LM hands the kernels — (8, 8, 2048, 128)
     and (8, 16, 2048, 64), causal — forward and both backward kernels, in
-    bf16 AND f32 (the stacked block casts q/k/v back to the f32 residual
-    dtype, so the dkv kernel's full-T q/dO blocks are 1 MB each before
-    double-buffering against the 16 MB scoped-VMEM limit): Mosaic must
-    compile them and they must match the jnp reference."""
+    bf16 AND f32 (without AMP the stacked block hands the kernels its
+    float32 stream, so the dkv kernel's full-T q/dO blocks are 1 MB each
+    before double-buffering against the 16 MB scoped-VMEM limit): Mosaic
+    must compile them and they must match the jnp reference."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.kernels.flash_attention import (flash_attention,
@@ -509,6 +509,47 @@ def flash_attention_full_width_shapes():
             msgs.append(f"h{H}d{D} {jnp.dtype(dt).name}: "
                         f"fwd {err_f:.1e} bwd {worst:.1e}")
     return "; ".join(msgs)
+
+
+@check
+def flash_attention_under_amp_runs_bf16_kernels():
+    """What the LM train cells run since PR 41: float32 q / k / v
+    ``[8, 16, 1024, 64]`` under AMP go through the three kernels as bf16
+    and come back float32; o, dq, dk, dv
+    against the float32 reference (AMP off, highest precision) within
+    2^-6 of each tensor's largest magnitude, causal + a ragged length."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu as pt
+    from paddle_tpu.kernels.flash_attention import (flash_attention,
+                                                    reference_attention)
+
+    rng = np.random.RandomState(41)
+    B, H, T, D = 8, 16, 1024, 64
+    q, k, v, g = (jnp.asarray(rng.randn(B, H, T, D).astype(np.float32))
+                  for _ in range(4))
+    lengths = jnp.asarray(np.array([T] * 7 + [700], np.int32))
+
+    def out_and_grads(attn):
+        out, vjp = jax.vjp(
+            lambda q, k, v: attn(q, k, v, lengths=lengths, causal=True),
+            q, k, v)
+        return (out,) + vjp(g)
+
+    pt.set_amp(True)
+    try:
+        got = jax.jit(lambda: out_and_grads(flash_attention))()
+    finally:
+        pt.set_amp(False)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(lambda: out_and_grads(reference_attention))()
+    msgs = []
+    for name, a, b in zip(("o", "dq", "dk", "dv"), got, ref):
+        assert a.dtype == jnp.float32, (name, a.dtype)
+        err = float(jnp.abs(a - b).max() / jnp.abs(b).max())
+        assert err < 2.0 ** -6, (name, err)
+        msgs.append(f"{name} {err:.1e}")
+    return "rel to max: " + " ".join(msgs)
 
 
 @check
