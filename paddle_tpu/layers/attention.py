@@ -173,7 +173,8 @@ def make_stack_params(helper, base, spec, param_attr=None,
             init = (ConstantInitializer(1.0) if key.endswith("_s")
                     else None)
         return helper.create_parameter(
-            attr, shape=[spec.n_layers] + shape, dtype=spec.param_dtype,
+            attr, shape=[spec.plane_layers(key)] + shape,
+            dtype=spec.param_dtype,
             is_bias=fan is None, default_initializer=init,
             stored_dtype=stored_dtype)
 

@@ -30,6 +30,25 @@ an expert layer may hold a SHARE of the router's experts (``experts_held``)
 beside an always-on shared expert: what a cached token costs and which
 planes a layer has are properties of the spec.
 
+A pattern may instead name, position by position, one of two attention
+KINDS that share no plane: ``kda`` (Kimi Delta Attention, arXiv:2510.26692:
+the gated delta rule with a per-channel decay, fed through a short causal
+convolution; linear in the context, so what a sequence carries from token
+to token is a FIXED-SIZE recurrent state and no cache row) and ``mla`` (the
+spec's latent attention, then one kind of the pattern; ``q_lora_rank`` 0 is
+a full-rank query, ``attn_gate="head"`` a head-wise sigmoid gate on its
+output). The stack's weights are then held BY KIND (``stack_slots()`` /
+``LMSpec.plane_layers``: a KDA plane leads with the number of KDA layers,
+the latent planes with the number of latent layers), the page pool holds
+the latent layers only, and ``slot_state`` lists what a serving SLOT holds
+beside its pages that does not grow with tokens — (name, per-slot shape,
+dtype[, layers]); empty for every other spec. ``first_dense`` leading
+layers of an expert stack run a dense SwiGLU FFN of ``d_ff`` instead, and
+the router may score with a sigmoid, select on score + a correction bias
+and limit its choice to the best ``topk_group`` of ``n_group`` groups
+(``router_score`` / ``router_bias`` / ``n_group`` / ``topk_group``:
+DeepSeek-V3's ``noaux_tc``).
+
 Selection between blocks is made from the spec and nothing else: no flag,
 no environment variable.
 """
@@ -46,6 +65,11 @@ FFNS = ("gelu_mlp", "swiglu_moe")
 ROPE_PAIRINGS = ("interleaved", "half")
 #: what one position of a ``layer_pattern`` period may say
 LAYER_KINDS = ("full+rope", "full+nope", "window+rope", "window+nope")
+#: ... or, for a stack whose layers differ in attention KIND (planes held by
+#: kind, a recurrent state beside the pages): every entry one of these
+ATTN_KINDS = ("kda", "mla")
+ROUTER_SCORES = ("softmax", "sigmoid")
+ATTN_GATES = ("none", "head")
 EXPERT_ACTS = ("silu", "relu")          # SwiGLU | ReGLU
 ROUTER_INPUTS = ("post_attn_norm", "attn_input")
 ATTNS = ("mha", "mla")
@@ -54,8 +78,10 @@ ATTNS = ("mha", "mla")
 class BlockNotSupportedError(NotImplementedError):
     """An op or engine that still hard-codes the GPT-2 block was handed
     another spec (beam search, the seq2seq family, a ``pp`` pipeline over
-    MoE layers), or one that knows a single layer kind was handed a
-    ``layer_pattern`` (training beyond the window, the slot handoff)."""
+    MoE layers), one that knows a single layer kind was handed a
+    ``layer_pattern`` (training beyond the window, the slot handoff), or
+    one that moves or shares cached tokens was handed a spec whose slots
+    carry a recurrent state (``require_stateless``)."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,6 +164,24 @@ class Block:
     # E outputs (expert parallelism's share); None: all of them
     experts_held: Optional[Tuple[int, int]] = None
     routed_scale: float = 1.0           # routed_scaling_factor
+    # the router (``ops/moe_ops.moe_topk``): scores softmax | sigmoid over
+    # all E; ``router_bias``: selection on score + a per-expert bias (the
+    # weights stay the bare scores); ``n_group`` > 1: only the experts of
+    # the ``topk_group`` best groups (a group's score = the sum of its two
+    # largest biased scores) can be chosen
+    router_score: str = "softmax"
+    router_bias: bool = False
+    n_group: int = 1
+    topk_group: int = 1
+    first_dense: int = 0                # leading layers with a dense SwiGLU
+    attn_gate: str = "none"             # "head": sigmoid(x w_h) on head h's output
+    # Kimi Delta Attention (a ``kda`` entry of the pattern): ``num_heads``
+    # heads of ``kda_head_dim`` keys and values, a causal depthwise
+    # convolution of ``kda_conv`` taps before q / k / v, log-decay in
+    # (``kda_lower_bound``, 0)
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_lower_bound: float = -5.0
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):     # a saved program's attrs
@@ -149,36 +193,65 @@ class Block:
         if self.attn not in ATTNS:
             raise ValueError(f"attn {self.attn!r} not in {ATTNS}")
         if self.is_mla:
-            widths = (self.q_lora_rank, self.kv_lora_rank,
-                      self.qk_nope_head_dim, self.qk_rope_head_dim,
-                      self.v_head_dim)
-            if min(widths) < 1 or self.qk_rope_head_dim % 2:
+            widths = (self.kv_lora_rank, self.qk_nope_head_dim,
+                      self.qk_rope_head_dim, self.v_head_dim)
+            if min(widths) < 1 or self.qk_rope_head_dim % 2 \
+                    or self.q_lora_rank < 0:
                 raise ValueError(
-                    "attn='mla' needs q_lora_rank, kv_lora_rank, "
-                    "qk_nope_head_dim, an even qk_rope_head_dim and "
-                    f"v_head_dim (got {widths})")
-            if not self.use_rope or self.layer_pattern is not None \
-                    or self.qk_norm or self.num_kv_heads:
+                    "attn='mla' needs kv_lora_rank, qk_nope_head_dim, an "
+                    "even qk_rope_head_dim and v_head_dim (got "
+                    f"{widths}); q_lora_rank 0 is a full-rank query")
+            if not self.use_rope or self.qk_norm or self.num_kv_heads:
                 raise ValueError(
-                    "attn='mla' rotates its rotary dims (use_rope=True) and "
-                    "has one kind of layer, no QK-norm and no KV groups")
+                    "attn='mla' rotates its rotary dims (use_rope=True) "
+                    "and has no QK-norm and no KV groups")
         if self.layer_pattern is not None:
             # a saved program hands the pattern back as a list
             object.__setattr__(self, "layer_pattern",
                                tuple(self.layer_pattern))
-            bad = [k for k in self.layer_pattern if k not in LAYER_KINDS]
-            if bad or not self.layer_pattern:
-                raise ValueError(f"layer_pattern {self.layer_pattern!r}: "
-                                 f"each entry is one of {LAYER_KINDS}")
+            pattern = self.layer_pattern
+            by_attn = bool(pattern) and all(k in ATTN_KINDS for k in pattern)
+            if not pattern or not (by_attn or all(k in LAYER_KINDS
+                                                  for k in pattern)):
+                raise ValueError(
+                    f"layer_pattern {pattern!r}: every entry is one of "
+                    f"{LAYER_KINDS}, or every entry one of {ATTN_KINDS}")
+            if self.is_mla != ("mla" in pattern) or (
+                    self.is_mla and not by_attn):
+                raise ValueError(
+                    "a latent block (attn='mla') is one kind of layer "
+                    "(no layer_pattern) or the 'mla' entries of a pattern "
+                    f"over {ATTN_KINDS}; got attn={self.attn!r} with "
+                    f"{pattern!r}")
+            if "kda" in pattern and (self.kda_head_dim < 1
+                                     or self.kda_conv < 2
+                                     or self.kda_lower_bound >= 0):
+                raise ValueError(
+                    "a 'kda' layer needs kda_head_dim >= 1, kda_conv >= 2 "
+                    "taps and a negative kda_lower_bound")
             if not self.use_rope:
                 raise ValueError("a layer_pattern names each layer's "
                                  "positions (rope | nope): there is no "
                                  "learned table, pass use_rope=True")
             if self.has_window and self.window < 1:
                 raise ValueError("a window layer needs window >= 1")
-            if not any(k.startswith("full") for k in self.layer_pattern):
+            if not by_attn and not any(k.startswith("full")
+                                       for k in pattern):
                 raise ValueError("a layer_pattern needs a full-attention "
                                  "layer (the cache's first kind)")
+        if self.attn_gate not in ATTN_GATES:
+            raise ValueError(f"attn_gate {self.attn_gate!r} not in "
+                             f"{ATTN_GATES}")
+        if self.router_score not in ROUTER_SCORES:
+            raise ValueError(f"router_score {self.router_score!r} not in "
+                             f"{ROUTER_SCORES}")
+        if self.n_group < 1 or not 1 <= self.topk_group <= self.n_group:
+            raise ValueError(f"topk_group {self.topk_group} outside "
+                             f"[1, n_group {self.n_group}]")
+        if self.first_dense and not (self.ffn == "swiglu_moe"
+                                     and self.attn_kinds):
+            raise ValueError("first_dense: the leading dense layers of an "
+                             "expert stack whose layers are held by kind")
         if self.expert_act not in EXPERT_ACTS:
             raise ValueError(f"expert_act {self.expert_act!r} not in "
                              f"{EXPERT_ACTS}")
@@ -233,17 +306,20 @@ class Block:
         if self.is_mla:
             raise BlockNotSupportedError(
                 f"{who} keeps K and V pages of kv_heads * head_dim and "
-                "cannot carry a latent cache row (attn='mla'): the train "
-                "op, the one-shot generate op and the paged prefill / "
-                "decode ops run it")
+                "cannot carry a latent cache row (attn='mla', alone or as "
+                "one kind of a layer_pattern): the paged prefill / decode "
+                "ops run it (and, for a stack of latent layers only, the "
+                "one-shot generate op)")
 
     def cache_row(self, d_model: int) -> Tuple[int, int]:
         """(pools, width): how many page pools a layer's cache is and the
         values a token costs a layer in each. K and V rows of ``kv_heads *
         head_dim``; a latent layer has ONE row a token: [c_kv | k_rope].
         A row wider than a lane row is held at whole lane rows (320 ->
-        384): the TPU's tiled layout pads it to that anyway, and a
-        page tile the kernel can DMA needs it."""
+        384, 576 -> 640): the TPU's tiled layout pads it to that anyway,
+        and a page tile the kernel can DMA needs it. Under a pattern over
+        ``ATTN_KINDS`` it is the latent layers' row: a ``kda`` layer
+        caches no token (``slot_state``)."""
         if not self.is_mla:
             return 2, self.kv_heads * self.dh(d_model)
         w = self.kv_lora_rank + self.qk_rope_head_dim
@@ -258,10 +334,40 @@ class Block:
     def kinds(self) -> Optional[Tuple[Tuple[bool, bool], ...]]:
         """(windowed, rotates) of every position of a period; None for a
         stack of one kind."""
-        if self.layer_pattern is None:
+        if self.layer_pattern is None or self.attn_kinds:
             return None
         return tuple((k.startswith("window"), k.endswith("+rope"))
                      for k in self.layer_pattern)
+
+    @property
+    def attn_kinds(self) -> Optional[Tuple[str, ...]]:
+        """The period of a stack whose layers differ in attention KIND
+        (every entry of ``ATTN_KINDS``); None for every other spec."""
+        p = self.layer_pattern
+        return p if p and p[0] in ATTN_KINDS else None
+
+    def slot_state(self, d_model: int) -> List[Tuple[str, tuple, str]]:
+        """What a serving SLOT holds, a layer that has it, beside its
+        pages and whatever its length: (name, per-slot shape, dtype). The
+        name is the paged ops' slot. A ``kda`` layer: the recurrent state
+        S [H, K, V] in float32 and the last ``kda_conv - 1`` inputs of the
+        q | k | v convolutions in the page dtype. Empty for a spec without
+        such a layer."""
+        if "kda" not in (self.attn_kinds or ()):
+            return []
+        H, K = self.num_heads, self.kda_head_dim
+        return [("KdaState", (H, K, K), "float32"),
+                ("KdaConv", (self.kda_conv - 1, 3 * H * K), self.page_dtype)]
+
+    def require_stateless(self, who: str) -> None:
+        if self.slot_state(0):
+            raise BlockNotSupportedError(
+                f"{who} moves, shares or re-enters cached TOKENS; this "
+                "spec's slots also carry a recurrent state "
+                f"({[n for n, _, _ in self.slot_state(0)]}) that is held "
+                "at the slot's last token only, and no snapshot of it at "
+                "another position exists: the paged prefill / decode ops "
+                "behind GenerationEngine / Server run it from position 0")
 
     @property
     def has_window(self) -> bool:
@@ -272,8 +378,9 @@ class Block:
             raise BlockNotSupportedError(
                 f"{who} knows one kind of layer and one page table; this "
                 f"spec's layers differ ({list(self.layer_pattern)}, window "
-                f"{self.window}): the train op (T <= window), the one-shot "
-                "generate op and the paged prefill / decode ops run it")
+                f"{self.window}): the paged prefill / decode ops run it "
+                "(and, for full / window kinds, the train op with T <= "
+                "window and the one-shot generate op)")
 
     @property
     def is_gpt2(self) -> bool:
@@ -298,19 +405,22 @@ class Block:
     def stack_slots(self) -> Dict[str, str]:
         """Op input slot -> per-layer weight key, in the fixed order the
         layout names them (``<base>.stack_<key>``)."""
+        if self.attn_kinds:
+            return self._slots_by_kind()
         ln = self.norm == "layer_norm" and self.bias
         slots = {"Ln1S": "ln1_s"}
         if ln:
             slots["Ln1B"] = "ln1_b"
         if self.is_mla:
-            slots.update(QaW="q_a_w", QaNormS="q_a_norm_s", QbW="q_b_w",
-                         KvaW="kv_a_w", KvaNormS="kv_a_norm_s",
-                         KvbW="kv_b_w")
+            slots.update({k: v for k, v in self._mla_slots().items()
+                          if k not in ("AttnGateW", "OutW")})
         else:
             slots["QkvW"] = "qkv_w"
         if self.qk_norm:
             slots["QNormS"] = "q_norm_s"
             slots["KNormS"] = "k_norm_s"
+        if self.is_mla and self.attn_gate == "head":
+            slots["AttnGateW"] = "attn_gate_w"
         slots["OutW"] = "out_w"
         slots["Ln2S"] = "ln2_s"
         if ln:
@@ -331,6 +441,76 @@ class Block:
                 slots["FfB2"] = "ff_b2"
         return slots
 
+    def _mla_slots(self) -> Dict[str, str]:
+        q = (dict(QW="q_w") if not self.q_lora_rank
+             else dict(QaW="q_a_w", QaNormS="q_a_norm_s", QbW="q_b_w"))
+        gate = dict(AttnGateW="attn_gate_w") if self.attn_gate == "head" \
+            else {}
+        return dict(**q, KvaW="kv_a_w", KvaNormS="kv_a_norm_s",
+                    KvbW="kv_b_w", **gate, OutW="out_w")
+
+    def _slots_by_kind(self) -> Dict[str, str]:
+        """The planes of a stack held BY KIND (``plane_group`` says which
+        layers own a key): the two norms of every layer, then each
+        attention kind's, then each FFN kind's."""
+        if self.norm != "rms_norm" or self.bias or not self.is_moe:
+            raise ValueError("a layer_pattern over attention kinds is an "
+                             "RMSNorm, bias-free expert stack")
+        slots = {"Ln1S": "ln1_s", "Ln2S": "ln2_s"}
+        if "kda" in self.attn_kinds:
+            slots.update(KdaQkvW="kda_qkv_w", KdaConvW="kda_conv_w",
+                         KdaAW="kda_a_w", KdaDtBias="kda_dt_bias",
+                         KdaALog="kda_a_log", KdaBetaW="kda_beta_w",
+                         KdaGateW="kda_gate_w", KdaNormS="kda_norm_s",
+                         KdaOutW="kda_out_w")
+        if "mla" in self.attn_kinds:
+            slots.update(self._mla_slots())
+        if self.first_dense:
+            slots.update(DenseGateW="dense_gate_w", DenseUpW="dense_up_w",
+                         DenseDownW="dense_down_w")
+        slots["RouterW"] = "router_w"
+        if self.router_bias:
+            slots["RouterB"] = "router_b"
+        slots.update(MoeGateW="moe_gate_w", MoeUpW="moe_up_w",
+                     MoeDownW="moe_down_w")
+        if self.shared_expert:
+            slots.update(SharedGateW="shared_gate_w",
+                         SharedUpW="shared_up_w",
+                         SharedDownW="shared_down_w")
+        return slots
+
+    @staticmethod
+    def plane_group(key: str) -> str:
+        """Which layers of a by-kind stack own plane ``key``: ``all`` |
+        ``kda`` | ``mla`` | ``dense`` | ``experts``."""
+        if key in ("ln1_s", "ln2_s"):
+            return "all"
+        if key.startswith("kda_"):
+            return "kda"
+        if key.startswith("dense_"):
+            return "dense"
+        if key.startswith(("router_", "moe_", "shared_")):
+            return "experts"
+        return "mla"
+
+    def group_index(self, n_layers: int) -> Dict[str, List[Optional[int]]]:
+        """group -> for each of the stack's ``n_layers`` layers its index
+        WITHIN the group's planes (None: the layer has none)."""
+        kinds = self.attn_kinds
+        of = {"all": lambda l: True,
+              "kda": lambda l: kinds[l % len(kinds)] == "kda",
+              "mla": lambda l: kinds[l % len(kinds)] == "mla",
+              "dense": lambda l: l < self.first_dense,
+              "experts": lambda l: l >= self.first_dense}
+        out = {}
+        for group, has in of.items():
+            n, ix = 0, []
+            for l in range(n_layers):
+                ix.append(n if has(l) else None)
+                n += bool(has(l))
+            out[group] = ix
+        return out
+
 
 #: every stack slot some block leaves out — what the spec-built ops
 #: declare as ``optional_inputs`` (next to PosEmb / FinalLnB)
@@ -338,7 +518,13 @@ OPTIONAL_STACK_SLOTS = ("Ln1B", "Ln2B", "QNormS", "KNormS", "FfW1", "FfB1",
                         "FfW2", "FfB2", "RouterW", "MoeGateW", "MoeUpW",
                         "MoeDownW", "QkvW", "QaW", "QaNormS", "QbW", "KvaW",
                         "KvaNormS", "KvbW", "SharedGateW", "SharedUpW",
-                        "SharedDownW")
+                        "SharedDownW", "OutW", "QW", "AttnGateW", "RouterB",
+                        "KdaQkvW", "KdaConvW", "KdaAW", "KdaDtBias",
+                        "KdaALog", "KdaBetaW", "KdaGateW", "KdaNormS",
+                        "KdaOutW", "DenseGateW", "DenseUpW", "DenseDownW")
+#: what ``Block.slot_state`` may list: the paged ops' state slots (inputs,
+#: and outputs updated in place)
+STATE_SLOTS = ("KdaState", "KdaConv")
 
 
 @dataclasses.dataclass
@@ -359,7 +545,16 @@ class LMSpec:
     width of an always-on expert beside the routed ones. ``experts_held``
     = (first, count): the routed experts this program holds of the
     router's ``num_experts`` (the expert stacks are [L, count, ..], the
-    router [d, num_experts]). ``routed_scale``: ``routed_scaling_factor``."""
+    router [d, num_experts]). ``routed_scale``: ``routed_scaling_factor``.
+
+    ``layer_pattern`` over ``("kda", "mla")`` with ``kda_head_dim`` /
+    ``kda_conv`` / ``kda_lower_bound``: linear-attention layers beside
+    latent ones, planes held by kind (``plane_layers``), the page pool the
+    latent layers' alone (``layers_of(False)``) and ``slot_state()`` what a
+    slot carries besides. ``first_dense`` leading layers run a dense SwiGLU
+    of ``d_ff``; ``router_score`` / ``router_bias`` / ``n_group`` /
+    ``topk_group``: the router; ``attn_gate="head"``: the latent
+    attention's head-wise output gate."""
     vocab_size: int
     d_model: int
     n_layers: int
@@ -397,10 +592,25 @@ class LMSpec:
     d_shared: int = 0                   # width of the always-on expert
     experts_held: Optional[Tuple[int, int]] = None
     routed_scale: float = 1.0
+    router_score: str = "softmax"
+    router_bias: bool = False
+    n_group: int = 1
+    topk_group: int = 1
+    first_dense: int = 0
+    attn_gate: str = "none"
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_lower_bound: float = -5.0
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
             self.rope_scaling = RopeScaling(**self.rope_scaling)
+        if self.n_group > 1 and self.num_experts % self.n_group:
+            raise ValueError(f"{self.num_experts} experts are not "
+                             f"{self.n_group} equal groups")
+        if not 0 <= self.first_dense <= self.n_layers:
+            raise ValueError(f"first_dense {self.first_dense} outside the "
+                             f"stack's {self.n_layers} layers")
         if self.experts_held is not None:
             first, count = self.experts_held = tuple(self.experts_held)
             if not (0 <= first and 0 < count
@@ -449,7 +659,12 @@ class LMSpec:
         return self.num_kv_heads or self.num_heads
 
     def layers_of(self, windowed: bool) -> int:
-        """How many of the stack's layers are window (or full) layers."""
+        """How many of the stack's layers are window (or full) layers:
+        the layers of each kind's page pool. Under a pattern over
+        attention kinds the latent layers are the full kind (a ``kda``
+        layer has no pages) and there is no window kind."""
+        if self.block.attn_kinds:
+            return 0 if windowed else self.plane_layers("kv_a_w")
         kinds = self.block.kinds
         if kinds is None:
             return 0 if windowed else self.n_layers
@@ -486,8 +701,35 @@ class LMSpec:
         from .core.types import to_dtype
         import numpy as np
 
-        return (self.n_layers * self.cache_pools * self.cache_row_width
+        layers = (self.layers_of(False) if self.block.attn_kinds
+                  else self.n_layers)
+        return (layers * self.cache_pools * self.cache_row_width
                 * np.dtype(to_dtype(self.page_dtype)).itemsize)
+
+    def slot_state(self) -> List[Tuple[str, tuple, str, int]]:
+        """(name, per-slot shape, dtype, layers) of everything a serving
+        slot holds that does not grow with its tokens
+        (``Block.slot_state``); the engine keeps one array [layers, slots,
+        *shape] of each. Empty for a spec without a recurrent layer."""
+        return [(name, shape, dtype, self.plane_layers("kda_qkv_w"))
+                for name, shape, dtype in self.block.slot_state(self.d_model)]
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        from .core.types import to_dtype
+        import numpy as np
+
+        return sum(layers * math.prod(shape)
+                   * np.dtype(to_dtype(dtype)).itemsize
+                   for _, shape, dtype, layers in self.slot_state())
+
+    def plane_layers(self, key: str) -> int:
+        """The leading (layer) axis of stacked plane ``key``: every layer,
+        or, for a stack held by kind, the layers of the key's group."""
+        if not self.block.attn_kinds:
+            return self.n_layers
+        ix = self.block.group_index(self.n_layers)[Block.plane_group(key)]
+        return sum(1 for i in ix if i is not None)
 
     def stack_planes(self) -> List[Tuple[str, str, list, Optional[tuple]]]:
         """(slot, key, shape without the layer axis, fan) of every stacked
@@ -503,7 +745,22 @@ class LMSpec:
         H = self.num_heads
         if self.attn == "mla":
             d_q = H * dv                # what the out-projection reads
+        Kd, taps = self.kda_head_dim, self.kda_conv
+        dK, ff = H * Kd, self.ffn_width
         shapes = {
+            "q_w": ([d, H * (nope + rope)], (d, H * (nope + rope))),
+            "attn_gate_w": ([d, H], (d, H)),
+            "router_b": ([E], None),
+            "kda_qkv_w": ([d, 3 * dK], (d, 3 * dK)),
+            # the taps of each channel's causal convolution, oldest first
+            "kda_conv_w": ([taps, 3 * dK], (taps, 1)),
+            "kda_a_w": ([d, dK], (d, dK)), "kda_dt_bias": ([dK], None),
+            "kda_a_log": ([H], None), "kda_beta_w": ([d, H], (d, H)),
+            "kda_gate_w": ([d, dK], (d, dK)), "kda_norm_s": ([Kd], None),
+            "kda_out_w": ([dK, d], (dK, d)),
+            "dense_gate_w": ([d, ff], (d, ff)),
+            "dense_up_w": ([d, ff], (d, ff)),
+            "dense_down_w": ([ff, d], (ff, d)),
             "ln1_s": ([d], None), "ln1_b": ([d], None),
             "qkv_w": ([d, d_q + 2 * d_kv], (d, d_q + 2 * d_kv)),
             "q_norm_s": ([d_q], None), "k_norm_s": ([d_kv], None),
@@ -541,10 +798,10 @@ class LMSpec:
     def n_params(self) -> int:
         """Parameters of the whole model (embedding, position table,
         stack, final norm, untied head)."""
-        per_layer = sum(math.prod(shape)
-                        for _, _, shape, _ in self.stack_planes())
+        stack = sum(self.plane_layers(key) * math.prod(shape)
+                    for _, key, shape, _ in self.stack_planes())
         emb = 2 * self.vocab_size * self.d_model
         pos = 0 if self.use_rope else self.max_len * self.d_model
         final = self.d_model * (2 if self.block.norm == "layer_norm"
                                 and self.bias else 1)
-        return self.n_layers * per_layer + emb + pos + final
+        return stack + emb + pos + final

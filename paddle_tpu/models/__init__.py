@@ -18,11 +18,13 @@ from .googlenet import googlenet
 from .mobilenet import mobilenet
 from .smallnet import smallnet_mnist_cifar
 from .seq2seq import shared_nmt_params, transformer_nmt_teacher
-from .transformer import (transformer_lm, transformer_lm_beam_search,
+from .transformer import (lm_parameters, transformer_lm,
+                          transformer_lm_beam_search,
                           transformer_lm_generate)
 from .wide_deep import wide_deep, wide_deep_loss
 
 __all__ = [
+    "lm_parameters",
     "transformer_lm", "transformer_lm_beam_search", "transformer_lm_generate",
     "wide_deep", "wide_deep_loss",
     "shared_nmt_params", "transformer_nmt_teacher",

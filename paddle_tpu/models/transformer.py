@@ -208,6 +208,18 @@ def _shared_lm_params(helper, spec):
     return ins
 
 
+def lm_parameters(spec, main_program=None, startup_program=None):
+    """Declare the parameters of ``spec``'s stacked LM under their fixed
+    names (tok_emb, final_ln.*, lm_head.w, lm_stack.stack_*) and nothing
+    else: running the startup program then initialises a scope that
+    ``GenerationEngine(spec, scope)`` serves. For a spec only the paged ops
+    run (a stack held by attention kind), whose weights no train or
+    one-shot generation program can declare. Returns the op-input dict."""
+    helper = LayerHelper("lm_parameters", main_program=main_program,
+                         startup_program=startup_program)
+    return _shared_lm_params(helper, spec)
+
+
 def transformer_lm_generate(prompt, vocab_size=None, d_model=256,
                             n_layers=4, num_heads=8, d_ff=None,
                             num_kv_heads=None, use_rope=False, max_len=2048,

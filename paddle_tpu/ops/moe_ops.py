@@ -84,7 +84,8 @@ _EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
              layer=None, act="silu", router_x=None, shared=None, held=None,
-             routed_scale=1.0):
+             routed_scale=1.0, score="softmax", bias=None, n_group=1,
+             topk_group=1):
     """Dropless token-choice top-``k`` gated experts — the expert layer
     of the ``swiglu_moe`` block (ops/pipeline_ops.py calls it from the
     block's FFN half; it is not a program op of its own).
@@ -134,6 +135,14 @@ def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
     ``shared`` = (gate_w [d, fs], up_w [d, fs], down_w [fs, d]): an
     always-on expert of the same activation, added to every row once.
     ``routed_scale`` multiplies the routed sum (``routed_scaling_factor``).
+
+    ``score``: ``softmax`` over the E logits, or ``sigmoid`` of each
+    (DeepSeek-V3's ``noaux_tc``). ``bias`` [E] float32: the top-k is taken
+    on score + bias while the weights stay the bare scores of the chosen.
+    ``n_group`` > 1: the E experts are ``n_group`` equal consecutive
+    groups, a group's score the sum of its two largest (biased) scores;
+    only the experts of the ``topk_group`` best groups can be chosen. The
+    defaults are the call it always was, bit for bit.
     """
     N, d = x.shape
     E = router_w.shape[-1]
@@ -141,8 +150,22 @@ def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
     r32 = x32 if router_x is None else router_x.astype(jnp.float32)
     logits = jnp.dot(r32, router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)                   # [N, E]
-    top_p, top_e = jax.lax.top_k(probs, k)                    # [N, k]
+    if score == "sigmoid":
+        probs = jax.nn.sigmoid(logits)                        # [N, E]
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+    if bias is None and n_group == 1:
+        top_p, top_e = jax.lax.top_k(probs, k)                # [N, k]
+    else:
+        choice = probs if bias is None else probs + bias.astype(jnp.float32)
+        if n_group > 1:
+            per = choice.reshape(N, n_group, E // n_group)
+            group_score = jnp.sum(jax.lax.top_k(per, 2)[0], axis=-1)
+            kth = jax.lax.top_k(group_score, topk_group)[0][:, -1:]
+            choice = jnp.where((group_score >= kth)[..., None], per,
+                               -jnp.inf).reshape(N, E)
+        top_e = jax.lax.top_k(choice, k)[1]
+        top_p = jnp.take_along_axis(probs, top_e, axis=-1)
     if norm_topk_prob:
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     flat_e = top_e.reshape(N * k)

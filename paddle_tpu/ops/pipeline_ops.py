@@ -18,7 +18,8 @@ import jax.numpy as jnp
 
 from ..core.registry import register_op
 from ..kernels.flash_attention import flash_attention, rotary
-from ..lm_spec import OPTIONAL_STACK_SLOTS, Block, BlockNotSupportedError
+from ..lm_spec import (OPTIONAL_STACK_SLOTS, STATE_SLOTS, Block,
+                       BlockNotSupportedError)
 from .common import amp_cast, maybe, mxu_precision, out, single
 from .moe_ops import moe_topk
 
@@ -188,10 +189,13 @@ def _mla_latent(blk, p, h, pos0=0):
     H, nope, rope = blk.num_heads, blk.qk_nope_head_dim, blk.qk_rope_head_dim
     r, sc = blk.kv_lora_rank, blk.rope_scaling
     hn = _norm(blk, h, p["ln1_s"], p.get("ln1_b"))
-    c_q = _rms(_mm("btd,dr->btr", hn, p["q_a_w"]), p["q_a_norm_s"],
-               blk.norm_eps)
-    q = _mm("btr,re->bte", c_q, p["q_b_w"]).reshape(
-        b, t, H, nope + rope).transpose(0, 2, 1, 3)
+    if blk.q_lora_rank:
+        c_q = _rms(_mm("btd,dr->btr", hn, p["q_a_w"]), p["q_a_norm_s"],
+                   blk.norm_eps)
+        q = _mm("btr,re->bte", c_q, p["q_b_w"])
+    else:                               # a full-rank query: no bottleneck
+        q = _mm("btd,de->bte", hn, p["q_w"])
+    q = q.reshape(b, t, H, nope + rope).transpose(0, 2, 1, 3)
     kv_a = _mm("btd,de->bte", hn, p["kv_a_w"])
     c_kv = _rms(kv_a[..., :r], p["kv_a_norm_s"], blk.norm_eps)
 
@@ -237,19 +241,25 @@ def _expand_kv(k, v, num_heads):
     return k, v
 
 
-def _attn_out_ffn(blk, p, x, ctx):
+def _attn_out_ffn(blk, p, x, ctx, out_key="out_w", dense=False):
     """Out-projection + residual + FFN half of a block; x [b, t, d] the
     block's input, ctx [b, t, H*dh]. -> (x, stats), stats as ``_block``
     says. A block whose router reads the attention's input
     (``router_input="attn_input"``) routes from norm 1 of ``x``: the same
-    expression the attention half computed, which XLA shares."""
+    expression the attention half computed, which XLA shares. A stack held
+    by kind names the layer's out-projection (``out_key``) and whether it
+    is one of the leading ``dense`` SwiGLU layers."""
     from jax.ad_checkpoint import checkpoint_name
 
     early = blk.is_moe and blk.router_input == "attn_input"
     router_x = _norm(blk, x, p["ln1_s"], p.get("ln1_b")) if early else None
     x = x + checkpoint_name(_mm("btd,de->bte", ctx.astype(x.dtype),
-                                p["out_w"]), "attn_out")
+                                p[out_key]), "attn_out")
     h2 = _norm(blk, x, p["ln2_s"], p.get("ln2_b"))
+    if dense:
+        ff = jax.nn.silu(_mm("btd,df->btf", h2, p["dense_gate_w"])) \
+            * _mm("btd,df->btf", h2, p["dense_up_w"])
+        return x + _mm("btf,fd->btd", ff, p["dense_down_w"]), None
     if blk.is_moe:
         b, t, d = x.shape
         more = {}
@@ -264,6 +274,12 @@ def _attn_out_ffn(blk, p, x, ctx):
             more["held"] = blk.experts_held
         if blk.routed_scale != 1.0:
             more["routed_scale"] = blk.routed_scale
+        if blk.router_score != "softmax":
+            more["score"] = blk.router_score
+        if blk.router_bias:
+            more["bias"] = p["router_b"]
+        if blk.n_group > 1:
+            more.update(n_group=blk.n_group, topk_group=blk.topk_group)
         y, counts, prob_mean = moe_topk(
             h2.reshape(b * t, d), p["router_w"], p["moe_gate_w"],
             p["moe_up_w"], p["moe_down_w"], blk.experts_per_tok,
@@ -316,16 +332,21 @@ def pipelined_transformer_stack(attrs, ins):
     # "FfB1" "FfW2" "FfB2" "RouterW" "MoeGateW" "MoeUpW" "MoeDownW"
     # "QkvW" | "QaW" "QaNormS" "QbW" "KvaW" "KvaNormS" "KvbW";
     # "SharedGateW" "SharedUpW" "SharedDownW"
+    # a stack held by attention kind (``Block._slots_by_kind``): "OutW"
+    # "QW" "AttnGateW" "RouterB" "KdaQkvW" "KdaConvW" "KdaAW" "KdaDtBias"
+    # "KdaALog" "KdaBetaW" "KdaGateW" "KdaNormS" "KdaOutW" "DenseGateW"
+    # "DenseUpW" "DenseDownW"
     params = _stack_params(blk, ins)
     causal = attrs.get("causal", True)
 
     remat = attrs.get("remat", False)
-    if blk.is_mla or blk.experts_held is not None:
+    if blk.is_mla or blk.experts_held is not None or blk.attn_kinds:
         raise BlockNotSupportedError(
             "pipelined_transformer_stack (training) was never held to a "
-            "reference for latent attention or a held share of the experts "
-            "(no gradient test exists for either): the one-shot generate op "
-            "and the paged prefill / decode ops run this spec")
+            "reference for latent attention, a held share of the experts "
+            "or a stack held by attention kind with a recurrent state (no "
+            "gradient test exists for any): the paged prefill / decode ops "
+            "run this spec")
 
     _hold_to_window(blk, x.shape[1], "pipelined_transformer_stack")
 
@@ -505,8 +526,18 @@ def transformer_stack_generate(attrs, ins, rng):
     # "QkvW" | "QaW" "QaNormS" "QbW" "KvaW" "KvaNormS" "KvbW";
     # "SharedGateW" "SharedUpW" "SharedDownW"
     # and "PosEmb" "FinalLnB" via _unpack_lm_ins
+    # a stack held by attention kind (``Block._slots_by_kind``): "OutW"
+    # "QW" "AttnGateW" "RouterB" "KdaQkvW" "KdaConvW" "KdaAW" "KdaDtBias"
+    # "KdaALog" "KdaBetaW" "KdaGateW" "KdaNormS" "KdaOutW" "DenseGateW"
+    # "DenseUpW" "DenseDownW"
     (prompt, tok_emb, pos_emb, ln_s, ln_b, head_w,
      params) = _unpack_lm_ins(blk, ins)
+    if blk.attn_kinds:
+        raise BlockNotSupportedError(
+            "transformer_stack_generate keeps one dense K/V cache a layer "
+            "and cannot hold a stack by attention kind "
+            f"({list(blk.attn_kinds)}): the paged prefill / decode ops "
+            "behind GenerationEngine run it")
     N = attrs["max_new_tokens"]
     temperature = attrs.get("temperature") or 0.0
     top_k = attrs.get("top_k") or 0
@@ -963,6 +994,197 @@ def _paged_layer_step(b, t, ps, project, mask, finish, mla=None):
     return attend
 
 
+# ---------------------------------------------------------------------------
+# A stack held BY ATTENTION KIND (``Block.attn_kinds``): ``kda`` layers, whose
+# memory of the sequence is a fixed-size recurrent state a SLOT (no pages),
+# beside ``mla`` layers over the latent page pool; the leading
+# ``first_dense`` layers a dense SwiGLU, the rest experts. Every group of
+# planes leads with the number of ITS layers (``Block.group_index``).
+# ---------------------------------------------------------------------------
+_L2_EPS = 1e-6
+
+
+def _l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + _L2_EPS)
+
+
+def _kda_project(blk, p, hn, conv, n_valid):
+    """The inputs of the delta rule from the normed stream hn [b, t, d]
+    and the row's convolution history conv [b, taps - 1, 3HK] (the q | k |
+    v projections of its last tokens): -> q, k, v, g [b, t, H, K] float32
+    (q, k L2-normalised, q scaled by K^-1/2; g the log-decay in
+    (lower_bound, 0)), beta [b, t, H], and the history after the row's
+    ``n_valid`` [b] tokens of this call. Tokens beyond ``n_valid`` get g
+    = 0 and beta = 0: they leave the state as it is."""
+    b, t, _ = hn.shape
+    H, K, taps = blk.num_heads, blk.kda_head_dim, blk.kda_conv
+    f32 = jnp.float32
+    qkv = _mm("btd,de->bte", hn, p["kda_qkv_w"]).astype(f32)
+    full = jnp.concatenate([conv.astype(f32), qkv], axis=1)
+    w = p["kda_conv_w"].astype(f32)                     # [taps, 3HK]
+    y = sum(full[:, i:i + t] * w[i] for i in range(taps))
+    at = n_valid[:, None] + jnp.arange(taps - 1, dtype=jnp.int32)[None, :]
+    conv = jnp.take_along_axis(full, at[..., None], axis=1).astype(conv.dtype)
+    y = jax.nn.silu(y)
+    q, k, v = (y[..., i * H * K:(i + 1) * H * K].reshape(b, t, H, K)
+               for i in range(3))
+    q, k = _l2norm(q) * K ** -0.5, _l2norm(k)
+    a = _mm("btd,de->bte", hn, p["kda_a_w"]).astype(f32) \
+        + p["kda_dt_bias"].astype(f32)
+    rate = jnp.exp(p["kda_a_log"].astype(f32))[:, None]  # [H, 1]
+    g = blk.kda_lower_bound * jax.nn.sigmoid(a.reshape(b, t, H, K) * rate)
+    beta = jax.nn.sigmoid(_mm("btd,dh->bth", hn, p["kda_beta_w"]).astype(f32))
+    valid = jnp.arange(t, dtype=jnp.int32)[None, :] < n_valid[:, None]
+    g = jnp.where(valid[..., None, None], g, 0.0)
+    beta = jnp.where(valid[..., None], beta, 0.0)
+    return q, k, v, g, beta, conv
+
+
+def _kda_layer(blk, p, h, state, conv, l, rows):
+    """One ``kda`` layer's attention half against the state arrays, in
+    place: h [b, t, d], ``state`` [Lk, slots, H, K, V] float32, ``conv``
+    [Lk, slots, taps - 1, 3HK], l the layer's index among the kda layers,
+    ``rows`` = (slot [b] or None: row i IS slot i, start [b], n_valid
+    [b]). -> (ctx [b, t, H*V]: RMSNorm of each head's read-out times the
+    sigmoid output gate, state, conv). A row whose call starts at position
+    0 reads a ZERO state and history whatever its slot held; a row with no
+    valid token (a vacant or prefilling slot of a decode tick, a padding
+    row) leaves both as they were. t == 1 is the recurrent step (on a chip
+    the ``kda_decode_step`` kernel over the whole state array), a chunk
+    the chunked form."""
+    from ..kernels import kda
+
+    slot, start, n_valid = rows
+    b, t, _ = h.shape
+    H, K = blk.num_heads, blk.kda_head_dim
+    live = n_valid > 0
+    fresh = live & (start == 0)
+    hn = _norm(blk, h, p["ln1_s"])
+    ix = jnp.arange(b) if slot is None else slot
+    conv0 = jnp.where(fresh[:, None, None], 0, conv[l, ix])
+    q, k, v, g, beta, conv1 = _kda_project(blk, p, hn, conv0, n_valid)
+    conv = conv.at[l, ix].set(jnp.where(live[:, None, None], conv1, conv0),
+                              mode="drop")
+    if slot is None and kda.supported(state, t):
+        # (a live decode row never sits at position 0: nothing is fresh)
+        o, state = kda.kda_decode_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                       beta[:, 0], state, l, live)
+        o = o[:, None]
+    else:
+        s_old = state[l, ix]
+        s0 = jnp.where(fresh[:, None, None, None], 0.0, s_old)
+        o, s1 = (kda.kda_recurrent if t == 1 else kda.kda_chunked)(
+            q, k, v, g, beta, s0)
+        state = state.at[l, ix].set(
+            jnp.where(live[:, None, None, None], s1, s_old), mode="drop")
+    o = _rms(o, p["kda_norm_s"], blk.norm_eps).reshape(b, t, H * K)
+    gate = jax.nn.sigmoid(_mm("btd,de->bte", hn, p["kda_gate_w"]))
+    return (o * gate).astype(h.dtype), state, conv
+
+
+def _scan_kind_layers(blk, params, h, pool, table, page_id, page_row, pos0,
+                      mask, states, rows):
+    """``_scan_paged_layers`` for a stack held by attention kind: h [b, t,
+    d] through the layers with the latent page pool [Lmla, N, ps, W] AND
+    the slot-state arrays (``states``: name -> [Lk, slots, ..]) as the
+    loop's in-place carry. Layer l runs its kind's attention half (``kda``:
+    ``_kda_layer`` against the state; ``mla``: ``_mla_paged_step`` against
+    the pool) and its FFN kind (dense SwiGLU below ``first_dense``,
+    experts after), each on the planes of ITS group at the layer's index
+    within the group. The periods that hold a dense layer are unrolled (a
+    prologue: their positions differ from the later periods'), the whole
+    periods after them run under ONE ``lax.scan`` with the period's
+    positions unrolled in its body. -> (h, pool, states, (counts [Lexp,
+    E], router prob mean [Lexp, E]))."""
+    b, t, _ = h.shape
+    kinds = blk.attn_kinds
+    P = len(kinds)
+    n_layers = params["ln1_s"].shape[0]
+    index = blk.group_index(n_layers)
+    group_of = {key: Block.plane_group(key) for key in params}
+    whole = {k: params[k] for k in _RESIDENT_PLANES if k in params}
+    mla = _mla_paged_step(
+        blk, b, t, lambda p, hh: _mla_latent(blk, p, hh, pos0), mask,
+        lambda p, hh, ctx, dense: _attn_out_ffn(
+            blk, p, hh, _head_gate(blk, p, hh, ctx), dense=dense))
+    ix = (page_id.reshape(b, t), page_row.reshape(b, t))
+
+    def layer(carry, p, kind, dense, at):
+        """One layer; ``at``: group -> the layer's index in the group."""
+        hh, pool, st = carry
+        if whole and not dense:
+            p = {**p, **whole, "layer": at["experts"]}
+        if kind == "kda":
+            ctx, s_new, c_new = _kda_layer(blk, p, hh, st["KdaState"],
+                                           st["KdaConv"], at["kda"], rows)
+            st = {**st, "KdaState": s_new, "KdaConv": c_new}
+            hh, stats = _attn_out_ffn(blk, p, hh, ctx, out_key="kda_out_w",
+                                      dense=dense)
+        else:
+            hh, pool, _, stats = mla(hh, pool, None, at["mla"], p, dense,
+                                     table, *ix)
+        return (hh, pool, st), stats
+
+    def planes(l):      # layer l's own planes (python l)
+        return {k: v[index[group_of[k]][l]] for k, v in params.items()
+                if k not in whole and index[group_of[k]][l] is not None}
+
+    head = min(-(-blk.first_dense // P) * P, n_layers)
+    carry, stats = (h, pool, states), []
+    for l in range(head):
+        carry, st_l = layer(carry, planes(l), kinds[l % P],
+                            l < blk.first_dense,
+                            {g: index[g][l] for g in index})
+        if st_l is not None:
+            stats.append(st_l)
+    stats = [tuple(jnp.stack(a) for a in zip(*stats))] if stats else []
+    periods = (n_layers - head) // P
+    if periods:
+        # each group's planes past the prologue, viewed [periods, a
+        # period's layers of the group, ..]
+        first = {g: next((i for i in index[g][head:] if i is not None), 0)
+                 for g in index}
+        per = {g: sum(1 for i in index[g][head:head + P] if i is not None)
+               for g in index}
+        xs = {k: v[first[group_of[k]]:].reshape(
+            (periods, per[group_of[k]]) + v.shape[1:])
+            for k, v in params.items()
+            if k not in whole and per[group_of[k]]}
+
+        def period(c, inp):
+            x_p, n = inp
+            ys = []
+            for j in range(P):
+                at_j = {g: index[g][head + j] for g in index}
+                p_j = {k: v[at_j[group_of[k]] - first[group_of[k]]]
+                       for k, v in x_p.items()
+                       if at_j[group_of[k]] is not None}
+                c, y = layer(c, p_j, kinds[j], False,
+                             {g: None if at_j[g] is None
+                              else at_j[g] + n * per[g] for g in index})
+                ys.append(y)
+            return c, tuple(jnp.stack(a) for a in zip(*ys))
+
+        carry, ys = jax.lax.scan(period, carry, (
+            xs, jnp.arange(periods, dtype=jnp.int32)))
+        stats.append(tuple(a.reshape((-1,) + a.shape[2:]) for a in ys))
+    h, pool, states = carry
+    stats = tuple(jnp.concatenate(a) for a in zip(*stats))
+    return h, pool, states, stats
+
+
+def _head_gate(blk, p, h, ctx):
+    """The latent attention's head-wise output gate: head n's context
+    times sigmoid(norm 1(h) . w_n) (``attn_gate="head"``)."""
+    if blk.attn_gate != "head":
+        return ctx
+    b, t, _ = ctx.shape
+    hn = _norm(blk, h, p["ln1_s"], p.get("ln1_b"))
+    gate = jax.nn.sigmoid(_mm("btd,dh->bth", hn, p["attn_gate_w"]))
+    return (ctx.reshape(b, t, blk.num_heads, -1)
+            * gate[..., None].astype(ctx.dtype)).reshape(b, t, -1)
+
+
 def _paged_outs(blk, stats, win, **outs):
     """The paged ops' outputs; an expert block adds ExpertCounts [L, E]
     int32 (rows each expert took in each layer of THIS call) so the
@@ -975,6 +1197,12 @@ def _paged_outs(blk, stats, win, **outs):
     if outs.get("CacheV", 0) is None:       # a latent block's one pool
         del outs["CacheV"]
     return out(**outs)
+
+
+def _state_ins(blk, ins):
+    """The slot-state arrays the spec lists (``Block.slot_state``), by
+    slot name: "KdaState" "KdaConv"; {} for a spec without any."""
+    return {name: single(ins, name) for name, _, _ in blk.slot_state(0)}
 
 
 #: the window kind's pools and table, beside CacheK / CacheV / BlockTable
@@ -1005,7 +1233,7 @@ def _window_ins(blk, ins, targets):
 
 @register_op("transformer_stack_paged_prefill",
              optional_inputs=(_LM_OPTIONAL + _SAMPLING_SLOTS + _WINDOW_SLOTS
-                              + _POOL_SLOTS),
+                              + _POOL_SLOTS + STATE_SLOTS + ("StateSlot",)),
              needs_rng=lambda attrs: (attrs.get("temperature") or 0) > 0)
 def transformer_stack_paged_prefill(attrs, ins, rng=None):
     """Prefill ONE CHUNK of each row's prompt into its block-table pages.
@@ -1062,6 +1290,12 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     # "FfB1" "FfW2" "FfB2" "RouterW" "MoeGateW" "MoeUpW" "MoeDownW"
     # "QkvW" | "QaW" "QaNormS" "QbW" "KvaW" "KvaNormS" "KvbW";
     # "SharedGateW" "SharedUpW" "SharedDownW" and "FinalLnB"
+    # a stack held by attention kind (``Block._slots_by_kind``): "OutW"
+    # "QW" "AttnGateW" "RouterB" "KdaQkvW" "KdaConvW" "KdaAW" "KdaDtBias"
+    # "KdaALog" "KdaBetaW" "KdaGateW" "KdaNormS" "KdaOutW" "DenseGateW"
+    # "DenseUpW" "DenseDownW"
+    # and its slot-state arrays, via ``_state_ins``: "KdaState" "KdaConv"
+    # (a prefill row's slot: "StateSlot")
     params = _stack_params(blk, ins)
     b, Tc = chunk.shape
     ps = cache_k.shape[2]
@@ -1079,23 +1313,33 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     x = _embed_rows(tok_emb, chunk)
     if pos_emb is not None:
         x = x + pos_emb[jnp.clip(pos, 0, pos_emb.shape[0] - 1)]
-    # "CacheKW" "CacheVW" "BlockTableW": the window kind (_window_ins)
-    h, cache_k, cache_v, stats, win = _scan_paged_layers(
-        params, x, cache_k, cache_v, table, page_id, page_row,
-        _paged_project(blk, start), dict(causal=True, q_pos0=start),
-        lambda p, h, ctx, _x_l: _attn_out_ffn(blk, p, h, ctx), blk=blk,
-        win=_window_ins(blk, ins, lambda tw: (page_of(tw), page_row)))
+    states = {}
+    if blk.attn_kinds:
+        # "StateSlot" [b] int32: the slot whose state each row reads and
+        # leaves advanced (a padding row: any index beyond the slots)
+        h, cache_k, states, stats = _scan_kind_layers(
+            blk, params, x, cache_k, table, page_id, page_row, start,
+            dict(causal=True, q_pos0=start), _state_ins(blk, ins),
+            (single(ins, "StateSlot").astype(jnp.int32), start, lengths))
+        win = None
+    else:
+        # "CacheKW" "CacheVW" "BlockTableW": the window kind (_window_ins)
+        h, cache_k, cache_v, stats, win = _scan_paged_layers(
+            params, x, cache_k, cache_v, table, page_id, page_row,
+            _paged_project(blk, start), dict(causal=True, q_pos0=start),
+            lambda p, h, ctx, _x_l: _attn_out_ffn(blk, p, h, ctx), blk=blk,
+            win=_window_ins(blk, ins, lambda tw: (page_of(tw), page_row)))
     last = h[jnp.arange(b), jnp.clip(lengths, 1, Tc) - 1]  # [b, d]
     logits = _logits_fn(ln_s, ln_b, head_w, blk)(last)
     nxt = _pick_rows(attrs, ins, rng, head_w.shape[1], logits)
     outs = _paged_outs(blk, stats, win, NextTok=nxt.astype(chunk.dtype),
-                       CacheK=cache_k, CacheV=cache_v)
+                       CacheK=cache_k, CacheV=cache_v, **states)
     return _maybe_topk(attrs, ins, logits, outs)
 
 
 @register_op("transformer_stack_paged_decode",
              optional_inputs=(_LM_OPTIONAL + _SAMPLING_SLOTS + _WINDOW_SLOTS
-                              + _POOL_SLOTS),
+                              + _POOL_SLOTS + STATE_SLOTS),
              needs_rng=lambda attrs: (attrs.get("temperature") or 0) > 0)
 def transformer_stack_paged_decode(attrs, ins, rng=None):
     """One decode step over every slot's paged context.
@@ -1147,6 +1391,12 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     # "FfB1" "FfW2" "FfB2" "RouterW" "MoeGateW" "MoeUpW" "MoeDownW"
     # "QkvW" | "QaW" "QaNormS" "QbW" "KvaW" "KvaNormS" "KvbW";
     # "SharedGateW" "SharedUpW" "SharedDownW" and "FinalLnB"
+    # a stack held by attention kind (``Block._slots_by_kind``): "OutW"
+    # "QW" "AttnGateW" "RouterB" "KdaQkvW" "KdaConvW" "KdaAW" "KdaDtBias"
+    # "KdaALog" "KdaBetaW" "KdaGateW" "KdaNormS" "KdaOutW" "DenseGateW"
+    # "DenseUpW" "DenseDownW"
+    # and its slot-state arrays, via ``_state_ins``: "KdaState" "KdaConv"
+    # (a prefill row's slot: "StateSlot")
     params = _stack_params(blk, ins)
     S = tok.shape[0]
     if S != table.shape[0]:
@@ -1162,17 +1412,28 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     srange = jnp.arange(S)
     page_id = table[srange, pos // ps]  # [S]
     page_row = pos % ps
-    # "CacheKW" "CacheVW" "BlockTableW": the window kind (_window_ins)
-    h1, cache_k, cache_v, stats, win = _scan_paged_layers(
-        params, h1, cache_k, cache_v, table, page_id, page_row,
-        _paged_project(blk, pos), dict(lengths=pos + 1),
-        lambda p, h, ctx, _x_l: _attn_out_ffn(blk, p, h, ctx), blk=blk,
-        win=_window_ins(blk, ins,
-                        lambda tw: (tw[srange, pos // ps], page_row)))
+    states = {}
+    if blk.attn_kinds:
+        # row s IS slot s; a live row's token lands in a page of its own
+        # (a vacant or still-prefilling slot rides on the scrap page and
+        # leaves its state alone)
+        h1, cache_k, states, stats = _scan_kind_layers(
+            blk, params, h1, cache_k, table, page_id, page_row, pos,
+            dict(lengths=pos + 1), _state_ins(blk, ins),
+            (None, pos, (page_id != 0).astype(jnp.int32)))
+        win = None
+    else:
+        # "CacheKW" "CacheVW" "BlockTableW": the window kind (_window_ins)
+        h1, cache_k, cache_v, stats, win = _scan_paged_layers(
+            params, h1, cache_k, cache_v, table, page_id, page_row,
+            _paged_project(blk, pos), dict(lengths=pos + 1),
+            lambda p, h, ctx, _x_l: _attn_out_ffn(blk, p, h, ctx), blk=blk,
+            win=_window_ins(blk, ins,
+                            lambda tw: (tw[srange, pos // ps], page_row)))
     logits = _logits_fn(ln_s, ln_b, head_w, blk)(h1[:, 0])
     nxt = _pick_rows(attrs, ins, rng, head_w.shape[1], logits)
     outs = _paged_outs(blk, stats, win, NextTok=nxt.astype(tok.dtype),
-                       CacheK=cache_k, CacheV=cache_v)
+                       CacheK=cache_k, CacheV=cache_v, **states)
     return _maybe_topk(attrs, ins, logits, outs)
 
 

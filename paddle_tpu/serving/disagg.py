@@ -424,6 +424,8 @@ class DisaggEngine:
         deployment where migration is a pure refcount transfer."""
         from .generation import GenerationEngine
 
+        spec.block.require_stateless("DisaggEngine.build (prefill and "
+                                     "decode pools hand slots over)")
         first = GenerationEngine(spec, scope=scope, **engine_kw)
         engines = [first]
         for _ in range(prefill_replicas + decode_replicas - 1):

@@ -53,6 +53,9 @@ PAGED_CACHE_V = "serving.paged_cache_v"
 # the window layers' pools of a spec whose layers differ in kind
 PAGED_CACHE_KW = "serving.paged_cache_kw"
 PAGED_CACHE_VW = "serving.paged_cache_vw"
+# what a slot holds beside its pages (``LMSpec.slot_state``): one array
+# [layers, slots, *shape] a name, "serving.state.<name>"
+SLOT_STATE = "serving.state."
 
 # decode-family op types whose attrs + shared weights describe a stacked LM
 _DECODE_OPS = ("transformer_stack_generate", "transformer_stack_beam_search",
@@ -90,6 +93,8 @@ def spec_from_program_dict(pd: dict,
                 var["lm_stack.stack_shared_gate_w"]["shape"][2]
     else:
         sizes["d_ff"] = var["lm_stack.stack_ff_w1"]["shape"][2]
+    if blk.first_dense:                 # the leading dense layers' width
+        sizes["d_ff"] = var["lm_stack.stack_dense_gate_w"]["shape"][2]
     if max_len is None:
         if "pos_emb" in var:
             max_len = var["pos_emb"]["shape"][0]
@@ -274,6 +279,23 @@ class GenerationEngine:
     per kind. Beam requests, ``share_cache_with=`` and the slot handoff
     (``export_slot`` / ``adopt_slot`` / a serialized handoff) know one
     table: they raise :class:`~paddle_tpu.lm_spec.BlockNotSupportedError`.
+
+    **State a slot** (a spec with recurrent layers: ``LMSpec.slot_state()``
+    lists (name, per-slot shape, dtype, layers); empty for every other
+    spec): the engine keeps one scope array ``[layers, slots, *shape]`` of
+    each, hands them to the prefill and decode ops as it hands the pools
+    (read and written in place; a prefill row names its slot in
+    ``serving.state_slot``), and counts them with the cache
+    (``mem/state_bytes_per_slot``, ``mem/state_bytes_live``,
+    ``cache_stats()``). A slot IS its state: admission allocates nothing,
+    and a row whose first chunk starts at position 0 reads zeros whatever
+    the slot's last tenant left. The state is held at the slot's LAST token
+    only, so everything that enters a sequence elsewhere than position 0
+    is off or raises ``BlockNotSupportedError``
+    (``Block.require_stateless``): the prefix index is not consulted
+    (``state_refused_prefix_lookups`` counts what it would have been
+    asked), beams, resume-from-token, ``share_cache_with=`` and the slot
+    handoff refuse.
     """
 
     # scope tensors swap_params must never clobber (live decode state)
@@ -363,7 +385,20 @@ class GenerationEngine:
         # handoff between the two is then a pure slot-table transfer
         src = share_cache_with
         self._by_kind = spec.block.has_window
+        #: (op slot, scope name, array shape, dtype) of every per-slot
+        #: state array the spec lists; [] for a spec without any
+        self._state = [(name, SLOT_STATE + name,
+                        (layers, self.slots) + tuple(shape), dtype)
+                       for name, shape, dtype, layers in spec.slot_state()]
+        self._cache_names = type(self)._cache_names + tuple(
+            scope_name for _, scope_name, _, _ in self._state)
+        #: layers that carry state: what a call counts as its
+        #: ``kda_layer_calls``
+        self._state_layers = max((shape[0] for _, _, shape, _
+                                  in self._state), default=0)
         if src is not None:
+            spec.block.require_stateless("share_cache_with= (the slot "
+                                         "handoff between engines)")
             spec.block.require_one_kind("share_cache_with= (the slot "
                                         "handoff between engines)")
             spec.block.require_mha("share_cache_with= (the slot handoff "
@@ -401,7 +436,9 @@ class GenerationEngine:
             | {self.prefill_chunk})
 
         # -- pool and slot table ----------------------------------------
-        self._prefix_sharing = bool(prefix_sharing)
+        # a hit on pages without the state at that position would be wrong
+        self._prefix_refused = bool(prefix_sharing) and bool(self._state)
+        self._prefix_sharing = bool(prefix_sharing) and not self._state
         self._owns_pool = src is None
         if src is not None:
             self.pool = src.pool
@@ -526,6 +563,10 @@ class GenerationEngine:
             with self.executor.device_ctx():
                 for name, shp in pools.items():
                     self.scope.set(name, jnp.zeros(shp, page_dtype))
+                for _, name, shp, dtype in self._state:
+                    self.scope.set(name, jnp.zeros(shp, to_dtype(dtype)))
+        self.metrics.set_gauge("mem/state_bytes_per_slot",
+                               float(self.spec.state_bytes_per_slot))
         self.metrics.set_gauge(
             "mem/kv_cache_bytes",
             float(sum(np.prod(shp) for shp in pools.values()))
@@ -566,6 +607,24 @@ class GenerationEngine:
         CacheV: a latent block's cache is the one pool)."""
         return {slot: [v] for slot, v in zip(("CacheK", "CacheV"), pools)}
 
+    def _state_io(self, helper):
+        """The slot-state arrays as op inputs AND outputs (updated in
+        place, like the pools), by the spec's slot names."""
+        return {slot: [helper.create_global_variable(
+            name=name, shape=list(shape), dtype=dtype)]
+            for slot, name, shape, dtype in self._state}
+
+    def _state_rows(self, slots_of_rows, rows: int) -> Dict[str, np.ndarray]:
+        """The prefill feed that names each row's slot, where the spec has
+        state ({} otherwise): a padding row points beyond the slots, so
+        its write is dropped."""
+        if not self._state:
+            return {}
+        ix = np.full(rows, self.slots, np.int32)
+        ix[:len(slots_of_rows)] = slots_of_rows
+        self.metrics.inc("kda_layer_calls", self._state_layers)
+        return {"serving.state_slot": ix}
+
     def _window_io(self, helper, table):
         """The window kind's op inputs and outputs (its pools, read and
         written in place, and its table); nothing for a one-kind spec."""
@@ -597,6 +656,8 @@ class GenerationEngine:
     def _prefill_feed_names(self):
         names = ["serving.chunk", "serving.start", "serving.chunk_len",
                  "serving.block_table", *self._SAMPLING_FEEDS]
+        if self._state:
+            names.append("serving.state_slot")
         if self._by_kind:
             names.append("serving.block_table_w")
         if self.mask_plane:
@@ -647,7 +708,8 @@ class GenerationEngine:
             return {}
         counts = helper.block.create_var(
             name="serving.expert_counts",
-            shape=[self.spec.n_layers, self.spec.num_experts],
+            shape=[self.spec.plane_layers("router_w"),
+                   self.spec.num_experts],
             dtype="int32", stop_gradient=True)
         return {"ExpertCounts": [counts]}
 
@@ -682,7 +744,7 @@ class GenerationEngine:
         self.metrics.inc("moe_layer_calls", int(counts.shape[0]))
         self.metrics.inc("moe_dropped_tokens",
                          rows * self.spec.experts_per_tok
-                         * self.spec.n_layers - took)
+                         * int(counts.shape[0]) - took)
 
     def _beam_out_vars(self, helper, rows: int, prefix: str):
         """TopV/TopI output vars when the beam plane is on."""
@@ -710,11 +772,16 @@ class GenerationEngine:
             nxt = helper.block.create_var(
                 name="serving.next_tok", shape=[-1],
                 dtype="int64", stop_gradient=True)
+            state = self._state_io(helper)
             ins = {"Chunk": [chunk], "StartPos": [start],
-                   "Lengths": [length], "BlockTable": [table], **pools}
+                   "Lengths": [length], "BlockTable": [table], **pools,
+                   **state}
+            if state:
+                ins["StateSlot"] = [data_layer(
+                    "serving.state_slot", shape=[], dtype="int32")]
             ins.update(self._sampling_vars(None))
             ins.update(self._lm_ins(helper))
-            outs = {"NextTok": [nxt], **pools}
+            outs = {"NextTok": [nxt], **pools, **state}
             if self._by_kind:
                 w_ins, w_outs = self._window_io(helper, data_layer(
                     "serving.block_table_w", shape=[self.pmax],
@@ -747,11 +814,12 @@ class GenerationEngine:
             nxt = helper.block.create_var(
                 name="serving.next_tok",
                 shape=[self.slots], dtype="int64", stop_gradient=True)
+            state = self._state_io(helper)
             ins = {"Tok": [tok], "Pos": [pos], "BlockTable": [table],
-                   **pools}
+                   **pools, **state}
             ins.update(self._sampling_vars(self.slots))
             ins.update(self._lm_ins(helper))
-            outs = {"NextTok": [nxt], **pools}
+            outs = {"NextTok": [nxt], **pools, **state}
             if self._by_kind:
                 w_ins, w_outs = self._window_io(helper, data_layer(
                     "serving.block_table_w", shape=[self.slots, self.pmax],
@@ -821,9 +889,10 @@ class GenerationEngine:
         return self._prefill_progs[tp]
 
     def _check_mem_budget(self, budget: float) -> None:
-        """Budget gate with the PAGE POOL (+ block tables) counted as the
-        resident KV state — the pool lives in the scope, so the analyzer
-        prices what is actually allocated, not a slots x Tmax formula."""
+        """Budget gate with the PAGE POOL (+ block tables) and the
+        slot-state arrays counted as the resident cache — both live in the
+        scope, so the analyzer prices what is actually allocated, not a
+        slots x Tmax formula."""
         from .. import analysis
 
         prog, outs = self._decode_prog
@@ -832,7 +901,8 @@ class GenerationEngine:
             [v.name for v in self._fetches(outs)], budget,
             scope=self.scope, batch_size=self.slots,
             what=f"GenerationEngine decode step (slots={self.slots}, "
-                 f"pages={self.n_pages}x{self.page_size})")
+                 f"pages={self.n_pages}x{self.page_size}, state "
+                 f"{self.spec.state_bytes_per_slot} B a slot)")
         tc = self._chunk_widths[-1]
         pprog, pouts = self._prefill_prog(tc)
         pmem = analysis.check_memory_budget(
@@ -941,6 +1011,7 @@ class GenerationEngine:
                 if self._by_kind:
                     feed["serving.block_table_w"] = np.zeros(
                         (b, self.pmax), np.int32)
+                feed.update(self._state_rows([], b))    # no row's slot
                 feed.update(self._neutral_sampling_feed(b))
                 self.executor.run(prog, feed=feed,
                                   fetch_list=self._fetches(outs),
@@ -1267,6 +1338,10 @@ class GenerationEngine:
                 beam.validate(self.spec.vocab_size)
         except (ValueError, TypeError) as exc:
             raise BadRequestError(str(exc))
+        if beam is not None or meta.get("resume_tokens"):
+            self.spec.block.require_stateless(
+                "beam search (a fork shares its parent's pages)"
+                if beam is not None else "resume-from-token")
         if beam is not None:
             if self._by_kind:
                 raise BlockNotSupportedError(
@@ -1318,6 +1393,7 @@ class GenerationEngine:
         if hand:
             self.spec.block.require_one_kind("a serialized KV handoff")
             self.spec.block.require_mha("a serialized KV handoff")
+            self.spec.block.require_stateless("a serialized KV handoff")
             # cross-process KV migration: the payload carries serialized
             # page ranges + the block table; installation writes the
             # bytes and resumes decode — never a prefill recompute
@@ -1421,6 +1497,8 @@ class GenerationEngine:
             req.future.set_exception(exc)
             return "failed"
         shared, spages, key = 0, [], b""
+        self.metrics.inc("state_refused_prefix_lookups",
+                         int(self._prefix_refused))
         if self.prefix_index is not None:
             shared, spages, key = self.prefix_index.lookup(prompt)
             if not self._by_kind:
@@ -1507,6 +1585,8 @@ class GenerationEngine:
             self._install_resume(st, resume)
         self.metrics.observe_hist("queue_wait", st.timeline.queue_wait_s)
         self._slots[slot] = st
+        # (a slot IS its state: its first chunk, at position 0, reads zeros)
+        self.metrics.inc("state_slots_started", int(bool(self._state)))
         if beam is not None:
             # parent + (K-1) parked hold slots the hypotheses fork into;
             # holds occupy the slot table now so later admissions can't
@@ -1613,6 +1693,8 @@ class GenerationEngine:
             feed.update({"serving.chunk": chunk, "serving.start": start,
                          "serving.chunk_len": length,
                          "serving.block_table": table})
+            feed.update(self._state_rows([slot for _, _, slot in group],
+                                         bucket))
             if self._by_kind:
                 feed["serving.block_table_w"] = table_w
         prog, outs = self._prefill_prog(tc)
@@ -1809,6 +1891,7 @@ class GenerationEngine:
             feed.update({"serving.chunk": chunk, "serving.start": start,
                          "serving.chunk_len": length,
                          "serving.block_table": table})
+            feed.update(self._state_rows([slot], bucket))
             if self._by_kind:
                 self._window_advance(st, start0, start0 + k - 1)
                 table_w = np.zeros((bucket, self.pmax), np.int32)
@@ -1915,6 +1998,21 @@ class GenerationEngine:
             # for key and value, ``paged_mla_decode``)
             self.metrics.inc("paged_attn_pages_read", int(held.sum()))
             self.metrics.inc("paged_attn_table_pages", table.size)
+        # the state a tick's recurrent layers read and write: every row of
+        # the static batch, in and out (a vacant row's tiles move too)
+        if self._state:
+            self.metrics.inc("kda_layer_calls", self._state_layers)
+            self.metrics.inc("kda_state_bytes",
+                             2 * self.slots * self.spec.state_bytes_per_slot)
+            # which cache sets the batch: the live slots' state against
+            # the pages they hold, tick by tick (the gauges' summed twins)
+            live = [st for st in self._slots if st is not None]
+            self.metrics.inc("state_bytes_live_ticks",
+                             len(live) * self.spec.state_bytes_per_slot)
+            self.metrics.inc("kv_bytes_held_ticks",
+                             sum(len(st.pages) for st in live)
+                             * self.page_size
+                             * self.spec.cache_bytes_per_token)
         # what ``Executor.run`` has to copy to the device before it can
         # enqueue the tick: the host-resident feeds
         self.metrics.inc("decode_feed_host_bytes", sum(
@@ -2169,6 +2267,9 @@ class GenerationEngine:
         self.metrics.set_gauge("mem/kv_pages_free",
                                self.pool.available())
         self.metrics.set_gauge("beam_active_jobs", len(self._beam_jobs))
+        self.metrics.set_gauge(
+            "mem/state_bytes_live",
+            float(self.active * self.spec.state_bytes_per_slot))
         if self._by_kind:
             self.metrics.set_gauge("mem/kv_window_pages_in_use",
                                    self.wpool.pages_in_use())
@@ -2206,6 +2307,9 @@ class GenerationEngine:
             "recent_requests": list(self._recent),
             "pool": self.pool.stats(),
             "deferred": len(self._deferred),
+            "state_bytes_per_slot": self.spec.state_bytes_per_slot,
+            "state_bytes_live": (self.active
+                                 * self.spec.state_bytes_per_slot),
         }
         if self.prefix_index is not None:
             state["prefix_index"] = self.prefix_index.stats()
@@ -2229,6 +2333,11 @@ class GenerationEngine:
         if self._by_kind:   # kv_pages_* are then the full-attention kind's
             for k, v in self.wpool.stats().items():
                 stats[f"kv_window_pages_{k}"] = v
+        stats["state_bytes_per_slot"] = self.spec.state_bytes_per_slot
+        stats["state_bytes_live"] = (self.active
+                                     * self.spec.state_bytes_per_slot)
+        stats["state_bytes_total"] = (self.slots
+                                      * self.spec.state_bytes_per_slot)
         return stats
 
     # -- mid-stream chaos: hard engine death ------------------------------
@@ -2369,6 +2478,7 @@ class GenerationEngine:
         — never a prefill recompute."""
         self.spec.block.require_one_kind("export_slot (the KV handoff)")
         self.spec.block.require_mha("export_slot (the KV handoff)")
+        self.spec.block.require_stateless("export_slot (the KV handoff)")
         st = self._slots[slot]
         if st is None or st.state != "decode" or st.beam_job is not None \
                 or st.xrow is not None:
@@ -2388,6 +2498,7 @@ class GenerationEngine:
         (copy-on-write still guards any page the prefix index shares)."""
         self.spec.block.require_one_kind("adopt_slot (the KV handoff)")
         self.spec.block.require_mha("adopt_slot (the KV handoff)")
+        self.spec.block.require_stateless("adopt_slot (the KV handoff)")
         if handoff.get("pool") is not self.pool:
             raise ValueError(
                 "same-process adoption needs a shared page pool — build "

@@ -66,13 +66,21 @@ MODULES = [
     ("paddle_tpu.lm_spec",
      "The stacked LM's model spec: Block (what a block computes), LMSpec "
      "(plus sizes), RopeScaling; attention mha | mla, a shared expert, a "
-     "held share of the router's experts"),
+     "held share of the router's experts; a layer_pattern over attention "
+     "kinds (kda | mla: planes by kind, slot_state = what a serving slot "
+     "holds beside its pages), first_dense leading dense layers, the "
+     "router's score / bias / groups"),
     ("paddle_tpu.ops.moe_ops",
      "The expert layer: moe_topk (dropless top-k; shared=, held=, "
-     "routed_scale=) and the Switch op"),
+     "routed_scale=; score= softmax | sigmoid, bias=, n_group=, "
+     "topk_group=) and the Switch op"),
     ("paddle_tpu.kernels.flash_attention", "Pallas flash attention"),
     ("paddle_tpu.kernels.paged_attention",
      "Pallas paged decode attention: walks the block table"),
+    ("paddle_tpu.kernels.kda",
+     "Kimi Delta Attention: the gated delta rule with a per-channel decay "
+     "token by token, chunked (prefill), and the kda_decode_step Pallas "
+     "kernel over the whole slot-state array"),
     ("paddle_tpu.kernels.sampling",
      "Per-request sampling plane: each decode row's own temperature / "
      "top-k / top-p / seed; cut-offs by a counted search, run only when "
