@@ -120,6 +120,13 @@ class Seq2SeqGenerationEngine(GenerationEngine):
             max(1, min(int(b), self.slots))
             for b in (encode_batch_buckets or (1, 2, 4, 8))))
 
+    def _amp_operand_names(self):
+        """The cross-attention decoder ops hand the qkv projection and the
+        head to ``amp_cast`` (``_attn_proj``, ``_logits_fn``); the
+        out-projection and the FFN they multiply in float32
+        (``ops.seq2seq_ops._cross_block``), so those keep no copy."""
+        return ["lm_head.w", "lm_stack.stack_qkv_w"]
+
     # -- cross-KV cache ----------------------------------------------------
     def _init_cache(self):
         import jax.numpy as jnp

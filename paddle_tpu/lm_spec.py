@@ -182,6 +182,12 @@ class Block:
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_lower_bound: float = -5.0
+    # the dtype the weights are STATED in, where the matmul operands a
+    # program hands the op are not the weights themselves: a serving
+    # engine's bf16 AMP operand copies of float32 weights
+    # (``LMSpec.amp_operand_names``). None, in every other program: the
+    # operands are the weights and their own dtype says it
+    param_dtype: Optional[str] = None
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):     # a saved program's attrs
@@ -522,6 +528,9 @@ OPTIONAL_STACK_SLOTS = ("Ln1B", "Ln2B", "QNormS", "KNormS", "FfW1", "FfB1",
                         "KdaQkvW", "KdaConvW", "KdaAW", "KdaDtBias",
                         "KdaALog", "KdaBetaW", "KdaGateW", "KdaNormS",
                         "KdaOutW", "DenseGateW", "DenseUpW", "DenseDownW")
+#: matrix planes the ops read in float32 under AMP too: the router's
+#: logits (``moe_topk``) and the taps of a ``kda`` layer's convolution
+_F32_READ_PLANES = ("router_w", "kda_conv_w")
 #: what ``Block.slot_state`` may list: the paged ops' state slots (inputs,
 #: and outputs updated in place)
 STATE_SLOTS = ("KdaState", "KdaConv")
@@ -652,6 +661,7 @@ class LMSpec:
         if self.head_dim * self.num_heads == self.d_model \
                 or self.attn == "mla":
             kw["head_dim"] = None       # the attrs a program always had
+        kw["param_dtype"] = None        # the operands are the weights
         return Block(**kw)
 
     @property
@@ -785,6 +795,18 @@ class LMSpec:
         }
         return [(slot, key, *shapes[key])
                 for slot, key in self.block.stack_slots().items()]
+
+    def amp_operand_names(self, base: str = "lm_stack") -> List[str]:
+        """The weights the paged ops hand to ``amp_cast`` as a matmul
+        operand and use in no other way: the head and the stack's matrix
+        planes, less those read in float32 whatever AMP says
+        (``_F32_READ_PLANES``). Embedding tables (gathered), norm scales
+        and biases are not among them. Under AMP an engine that serves
+        float32 weights holds the bf16 tensor ``amp_cast`` would make of
+        each (``GenerationEngine._adopt_scope``)."""
+        return ["lm_head.w"] + [
+            f"{base}.stack_{key}" for _, key, _, fan in self.stack_planes()
+            if fan is not None and key not in _F32_READ_PLANES]
 
     def param_names(self, base: str = "lm_stack") -> List[str]:
         """The fixed names of the model's parameters in a scope."""

@@ -51,14 +51,22 @@ def _norm(blk, x, scale, bias=None):
     return _ln(x, scale, bias, blk.norm_eps)
 
 
-def _mm(eq, a, w):
+def _mm(blk, eq, a, w):
     """``einsum(eq, a, w)`` back in a.dtype (the float32 residual
-    stream). float32 weights: bf16 operands under AMP, as the GPT-2 block
+    stream). float32 weights: bf16 operands under AMP and a result rounded
+    through bf16 (``preferred_element_type`` None), as the GPT-2 block
     always did. Weights STORED in bf16 are used as they are with float32
-    accumulation (no float32 copy of a weight is ever made on the
-    device; without AMP jnp promotes inside the contraction)."""
+    accumulation (no float32 copy of a stored-bf16 weight is ever made on
+    the device; without AMP jnp promotes inside the contraction). Which of
+    the two rules holds is decided by the dtype the weight is STATED in,
+    not by the operand's: ``blk.param_dtype`` where the program states
+    one (a serving engine hands the bf16 copy ``amp_cast`` would make of a
+    float32 weight, ``GenerationEngine._adopt_scope``, and the result is
+    the float32 weight's to the last bit), else ``w.dtype`` (the operand
+    IS the weight)."""
     a_c, w_c = amp_cast(a, w)
-    pref = jnp.float32 if w.dtype == jnp.bfloat16 else None
+    stated = jnp.dtype(blk.param_dtype) if blk.param_dtype else w.dtype
+    pref = jnp.float32 if stated == jnp.bfloat16 else None
     return jnp.einsum(eq, a_c, w_c, precision=mxu_precision(),
                       preferred_element_type=pref).astype(a.dtype)
 
@@ -146,7 +154,8 @@ def _attn_proj(blk, p, h, pos0=0, rope=None):
     from jax.ad_checkpoint import checkpoint_name
 
     hn = _norm(blk, h, p["ln1_s"], p.get("ln1_b"))
-    qkv = checkpoint_name(_mm("btd,de->bte", hn, p["qkv_w"]), "qkv_proj")
+    qkv = checkpoint_name(_mm(blk, "btd,de->bte", hn, p["qkv_w"]),
+                          "qkv_proj")
     q = qkv[..., :d_q]
     k = qkv[..., d_q:d_q + d_kv]
     v = qkv[..., d_q + d_kv:]
@@ -190,13 +199,13 @@ def _mla_latent(blk, p, h, pos0=0):
     r, sc = blk.kv_lora_rank, blk.rope_scaling
     hn = _norm(blk, h, p["ln1_s"], p.get("ln1_b"))
     if blk.q_lora_rank:
-        c_q = _rms(_mm("btd,dr->btr", hn, p["q_a_w"]), p["q_a_norm_s"],
+        c_q = _rms(_mm(blk, "btd,dr->btr", hn, p["q_a_w"]), p["q_a_norm_s"],
                    blk.norm_eps)
-        q = _mm("btr,re->bte", c_q, p["q_b_w"])
+        q = _mm(blk, "btr,re->bte", c_q, p["q_b_w"])
     else:                               # a full-rank query: no bottleneck
-        q = _mm("btd,de->bte", hn, p["q_w"])
+        q = _mm(blk, "btd,de->bte", hn, p["q_w"])
     q = q.reshape(b, t, H, nope + rope).transpose(0, 2, 1, 3)
-    kv_a = _mm("btd,de->bte", hn, p["kv_a_w"])
+    kv_a = _mm(blk, "btd,de->bte", hn, p["kv_a_w"])
     c_kv = _rms(kv_a[..., :r], p["kv_a_norm_s"], blk.norm_eps)
 
     def rot(a):
@@ -225,8 +234,8 @@ def _mla_expand(blk, p, c_kv, k_rope):
     """Keys and values of every head from the latent: c_kv [b, T, r],
     k_rope [b, T, rope] -> k [b, H, T, nope + rope], v [b, H, T, dv]."""
     w_uk, w_uv = _mla_up(blk, p)
-    k_nope = _mm("btr,rhn->bhtn", c_kv, w_uk)
-    v = _mm("btr,rhv->bhtv", c_kv, w_uv)
+    k_nope = _mm(blk, "btr,rhn->bhtn", c_kv, w_uk)
+    v = _mm(blk, "btr,rhv->bhtv", c_kv, w_uv)
     k_r = jnp.broadcast_to(k_rope[:, None].astype(k_nope.dtype),
                            k_nope.shape[:3] + k_rope.shape[-1:])
     return jnp.concatenate([k_nope, k_r], axis=-1), v
@@ -253,13 +262,13 @@ def _attn_out_ffn(blk, p, x, ctx, out_key="out_w", dense=False):
 
     early = blk.is_moe and blk.router_input == "attn_input"
     router_x = _norm(blk, x, p["ln1_s"], p.get("ln1_b")) if early else None
-    x = x + checkpoint_name(_mm("btd,de->bte", ctx.astype(x.dtype),
+    x = x + checkpoint_name(_mm(blk, "btd,de->bte", ctx.astype(x.dtype),
                                 p[out_key]), "attn_out")
     h2 = _norm(blk, x, p["ln2_s"], p.get("ln2_b"))
     if dense:
-        ff = jax.nn.silu(_mm("btd,df->btf", h2, p["dense_gate_w"])) \
-            * _mm("btd,df->btf", h2, p["dense_up_w"])
-        return x + _mm("btf,fd->btd", ff, p["dense_down_w"]), None
+        ff = jax.nn.silu(_mm(blk, "btd,df->btf", h2, p["dense_gate_w"])) \
+            * _mm(blk, "btd,df->btf", h2, p["dense_up_w"])
+        return x + _mm(blk, "btf,fd->btd", ff, p["dense_down_w"]), None
     if blk.is_moe:
         b, t, d = x.shape
         more = {}
@@ -285,11 +294,11 @@ def _attn_out_ffn(blk, p, x, ctx, out_key="out_w", dense=False):
             p["moe_up_w"], p["moe_down_w"], blk.experts_per_tok,
             blk.norm_topk_prob, layer=p.get("layer"), **more)
         return x + y.reshape(b, t, d), (counts, prob_mean)
-    ff = _mm("btd,df->btf", h2, p["ff_w1"])
+    ff = _mm(blk, "btd,df->btf", h2, p["ff_w1"])
     if blk.bias:
         ff = ff + p["ff_b1"]
     ff = checkpoint_name(jax.nn.gelu(ff), "ffn_hidden")
-    ff = _mm("btf,fd->btd", ff, p["ff_w2"])
+    ff = _mm(blk, "btf,fd->btd", ff, p["ff_w2"])
     if blk.bias:
         ff = ff + p["ff_b2"]
     return x + ff, None
@@ -433,7 +442,7 @@ def _embed_fn(tok_emb, pos_emb):
 def _logits_fn(ln_s, ln_b, head_w, blk=Block(num_heads=1)):
     def logits_of(h_last):
         hn = _norm(blk, h_last, ln_s, ln_b)
-        return _mm("bd,dv->bv", hn, head_w).astype(jnp.float32)
+        return _mm(blk, "bd,dv->bv", hn, head_w).astype(jnp.float32)
 
     return logits_of
 
@@ -928,7 +937,7 @@ def _mla_paged_step(blk, b, t, project, mask, finish):
         row = jnp.pad(row, ((0, 0), (0, 0), (0, W - row.shape[-1])))
         ck = ck.at[l, ix_page, ix_row].set(row.astype(ck.dtype))
         w_uk, w_uv = _mla_up(blk, layer_p)
-        q_abs = _mm("bhtn,rhn->bhtr", q_nope, w_uk)
+        q_abs = _mm(blk, "bhtn,rhn->bhtr", q_nope, w_uk)
         q_lat = jnp.concatenate([q_abs, q_rope], axis=-1) * scale
         q_lat = jnp.pad(q_lat, ((0, 0),) * 3 + ((0, W - r - rope_d),))
         if set(mask) == {"lengths"} and paged_attention.supported(W, ck, t):
@@ -940,7 +949,7 @@ def _mla_paged_step(blk, b, t, project, mask, finish):
             lat = ck[l, tbl].reshape(b, 1, tbl.shape[1] * ck.shape[2], W)
             o_lat = reference_attention(q_lat.astype(ck.dtype), lat,
                                         lat[..., :r], sm_scale=1.0, **mask)
-        ctx = _mm("bhtr,rhv->bthv", o_lat[..., :r].astype(h.dtype),
+        ctx = _mm(blk, "bhtr,rhv->bthv", o_lat[..., :r].astype(h.dtype),
                   w_uv).reshape(b, t, -1)
         h, stats = finish(layer_p, h, ctx, x_l)
         return h, ck, cv, stats
@@ -1019,7 +1028,7 @@ def _kda_project(blk, p, hn, conv, n_valid):
     b, t, _ = hn.shape
     H, K, taps = blk.num_heads, blk.kda_head_dim, blk.kda_conv
     f32 = jnp.float32
-    qkv = _mm("btd,de->bte", hn, p["kda_qkv_w"]).astype(f32)
+    qkv = _mm(blk, "btd,de->bte", hn, p["kda_qkv_w"]).astype(f32)
     full = jnp.concatenate([conv.astype(f32), qkv], axis=1)
     w = p["kda_conv_w"].astype(f32)                     # [taps, 3HK]
     y = sum(full[:, i:i + t] * w[i] for i in range(taps))
@@ -1029,11 +1038,12 @@ def _kda_project(blk, p, hn, conv, n_valid):
     q, k, v = (y[..., i * H * K:(i + 1) * H * K].reshape(b, t, H, K)
                for i in range(3))
     q, k = _l2norm(q) * K ** -0.5, _l2norm(k)
-    a = _mm("btd,de->bte", hn, p["kda_a_w"]).astype(f32) \
+    a = _mm(blk, "btd,de->bte", hn, p["kda_a_w"]).astype(f32) \
         + p["kda_dt_bias"].astype(f32)
     rate = jnp.exp(p["kda_a_log"].astype(f32))[:, None]  # [H, 1]
     g = blk.kda_lower_bound * jax.nn.sigmoid(a.reshape(b, t, H, K) * rate)
-    beta = jax.nn.sigmoid(_mm("btd,dh->bth", hn, p["kda_beta_w"]).astype(f32))
+    beta = jax.nn.sigmoid(
+        _mm(blk, "btd,dh->bth", hn, p["kda_beta_w"]).astype(f32))
     valid = jnp.arange(t, dtype=jnp.int32)[None, :] < n_valid[:, None]
     g = jnp.where(valid[..., None, None], g, 0.0)
     beta = jnp.where(valid[..., None], beta, 0.0)
@@ -1078,7 +1088,7 @@ def _kda_layer(blk, p, h, state, conv, l, rows):
         state = state.at[l, ix].set(
             jnp.where(live[:, None, None, None], s1, s_old), mode="drop")
     o = _rms(o, p["kda_norm_s"], blk.norm_eps).reshape(b, t, H * K)
-    gate = jax.nn.sigmoid(_mm("btd,de->bte", hn, p["kda_gate_w"]))
+    gate = jax.nn.sigmoid(_mm(blk, "btd,de->bte", hn, p["kda_gate_w"]))
     return (o * gate).astype(h.dtype), state, conv
 
 
@@ -1180,7 +1190,7 @@ def _head_gate(blk, p, h, ctx):
         return ctx
     b, t, _ = ctx.shape
     hn = _norm(blk, h, p["ln1_s"], p.get("ln1_b"))
-    gate = jax.nn.sigmoid(_mm("btd,dh->bth", hn, p["attn_gate_w"]))
+    gate = jax.nn.sigmoid(_mm(blk, "btd,dh->bth", hn, p["attn_gate_w"]))
     return (ctx.reshape(b, t, blk.num_heads, -1)
             * gate[..., None].astype(ctx.dtype)).reshape(b, t, -1)
 

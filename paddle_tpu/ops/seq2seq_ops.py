@@ -188,7 +188,10 @@ def transformer_stack_cross_prefill(attrs, ins, rng=None):
     xparams = _unpack_cross(ins)
     num_heads = attrs["num_heads"]
     num_kv_heads = attrs.get("num_kv_heads") or num_heads
-    blk = Block(num_heads=num_heads, num_kv_heads=num_kv_heads)
+    # (the engine states the weights' dtype where it binds AMP operand
+    # copies of the decoder's: ``ops.pipeline_ops._mm``)
+    blk = Block(num_heads=num_heads, num_kv_heads=num_kv_heads,
+                param_dtype=attrs.get("param_dtype"))
     b, Tc = chunk.shape
     ps = cache_k.shape[2]
     P = table.shape[1]
@@ -208,7 +211,7 @@ def transformer_stack_cross_prefill(attrs, ins, rng=None):
         finish=_cross_block(xslot, src_len, num_heads),
         xs=(cross_k, cross_v, xparams))
     last = h[jnp.arange(b), jnp.clip(lengths, 1, Tc) - 1]
-    logits = _logits_fn(ln_s, ln_b, head_w)(last)
+    logits = _logits_fn(ln_s, ln_b, head_w, blk)(last)
     nxt = _pick_rows(attrs, ins, rng, head_w.shape[1], logits)
     outs = out(NextTok=nxt.astype(chunk.dtype),
                CacheK=cache_k, CacheV=cache_v)
@@ -245,7 +248,10 @@ def transformer_stack_cross_decode(attrs, ins, rng=None):
     xparams = _unpack_cross(ins)
     num_heads = attrs["num_heads"]
     num_kv_heads = attrs.get("num_kv_heads") or num_heads
-    blk = Block(num_heads=num_heads, num_kv_heads=num_kv_heads)
+    # (the engine states the weights' dtype where it binds AMP operand
+    # copies of the decoder's: ``ops.pipeline_ops._mm``)
+    blk = Block(num_heads=num_heads, num_kv_heads=num_kv_heads,
+                param_dtype=attrs.get("param_dtype"))
     S = tok.shape[0]
     ps = cache_k.shape[2]
     P = table.shape[1]
@@ -263,7 +269,7 @@ def transformer_stack_cross_decode(attrs, ins, rng=None):
         dict(lengths=pos + 1),
         finish=_cross_block(xslot, src_len, num_heads),
         xs=(cross_k, cross_v, xparams))
-    logits = _logits_fn(ln_s, ln_b, head_w)(h1[:, 0])
+    logits = _logits_fn(ln_s, ln_b, head_w, blk)(h1[:, 0])
     nxt = _pick_rows(attrs, ins, rng, head_w.shape[1], logits)
     outs = out(NextTok=nxt.astype(tok.dtype),
                CacheK=cache_k, CacheV=cache_v)

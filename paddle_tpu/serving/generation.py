@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+import weakref
 from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -42,6 +43,7 @@ from ..decoding.stops import StopMatcher
 from ..layers import data as data_layer
 from ..layers.layer_helper import LayerHelper
 from ..lm_spec import Block, BlockNotSupportedError, LMSpec
+from ..ops.common import amp_enabled
 from .batcher import Request
 from .errors import BadRequestError
 from .metrics import MetricsRegistry
@@ -56,6 +58,14 @@ PAGED_CACHE_VW = "serving.paged_cache_vw"
 # what a slot holds beside its pages (``LMSpec.slot_state``): one array
 # [layers, slots, *shape] a name, "serving.state.<name>"
 SLOT_STATE = "serving.state."
+# the bf16 tensor ``amp_cast`` would make of a float32 matmul weight, held
+# from load on: "serving.amp_operand.<the weight's name>"
+AMP_OPERAND = "serving.amp_operand."
+#: scope -> {weight name: the array its operand copy was cast from}. The
+#: engines built on one scope share it: a copy is reused while the scope
+#: still holds that very array under the weight's name, and remade once it
+#: holds another
+_OPERAND_SOURCE: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
 # decode-family op types whose attrs + shared weights describe a stacked LM
 _DECODE_OPS = ("transformer_stack_generate", "transformer_stack_beam_search",
@@ -103,6 +113,7 @@ def spec_from_program_dict(pd: dict,
                              "sequence length — pass max_len explicitly")
     block_kw = dataclasses.asdict(blk)
     block_kw.pop("shared_expert")       # the spec's d_shared says it
+    block_kw.pop("param_dtype")         # the stored parameters' own says it
     return LMSpec(vocab_size=vocab, d_model=d_model,
                   n_layers=var["lm_stack.stack_ln1_s"]["shape"][0],
                   max_len=max_len, param_dtype=str(var["tok_emb"]["dtype"]),
@@ -296,6 +307,24 @@ class GenerationEngine:
     (``state_refused_prefix_lookups`` counts what it would have been
     asked), beams, resume-from-token, ``share_cache_with=`` and the slot
     handoff refuse.
+
+    **What stays resident** on the engine's device, all of it in the
+    scope: the weights in the spec's stored dtype, the page pools, the
+    slot-state arrays, and — where float32 weights are served under AMP
+    (``spec.param_dtype`` float32 and ``amp_enabled()`` as the engine is
+    built; nothing else decides it) — the bf16 **AMP operand copy** of
+    every weight its ops hand to ``amp_cast`` and use in no other way
+    (``_amp_operand_names``; scope names ``serving.amp_operand.<weight>``,
+    gauge ``mem/amp_operand_bytes``). The decode and prefill programs bind
+    those slots to the copies, so a call reads the operands it multiplies
+    instead of rewriting every float32 stack as bf16 first (three converts
+    at their byte roofline, 2.1 ms of every call of GPT-2 medium: PERF.md
+    section 6, PR 43). The float32 tensors stay what ``swap_params``, a
+    save, the handoff and a trainer on the same scope see; the copies
+    follow them through ``_adopt_scope`` and ``swap_params``, the two
+    places where an engine's weights change. A spec stored in bf16 and a
+    float32 spec without AMP hold no copy and build the programs they
+    always built.
     """
 
     # scope tensors swap_params must never clobber (live decode state)
@@ -366,6 +395,11 @@ class GenerationEngine:
         self._flight.add_source(type(self).__name__, self.flight_state)
         self.model_dir: Optional[str] = None  # set by from_saved
         self.executor = Executor(place or TPUPlace(0))
+        #: weight name -> scope name of its AMP operand copy; {} for a
+        #: spec stored in bf16 and for float32 weights without AMP
+        self._operands = (
+            {name: AMP_OPERAND + name for name in self._amp_operand_names()}
+            if spec.param_dtype == "float32" and amp_enabled() else {})
         self._adopt_scope()
         self.prompt_buckets = sorted(set(
             min(int(b), self.tmax) for b in
@@ -391,7 +425,8 @@ class GenerationEngine:
                         (layers, self.slots) + tuple(shape), dtype)
                        for name, shape, dtype, layers in spec.slot_state()]
         self._cache_names = type(self)._cache_names + tuple(
-            scope_name for _, scope_name, _, _ in self._state)
+            scope_name for _, scope_name, _, _ in self._state) + tuple(
+            self._operands.values())
         #: layers that carry state: what a call counts as its
         #: ``kda_layer_calls``
         self._state_layers = max((shape[0] for _, _, shape, _
@@ -509,6 +544,12 @@ class GenerationEngine:
         eng.model_dir = model_dir  # manifest home for warm_start
         return eng
 
+    def _amp_operand_names(self) -> List[str]:
+        """The weights this engine's ops hand to ``amp_cast`` as a matmul
+        operand and use in no other way (``LMSpec.amp_operand_names``): an
+        engine that runs other ops over the same weights names its own."""
+        return self.spec.amp_operand_names()
+
     def _adopt_scope(self):
         """Cast-and-place: weights handed over in ``scope`` (trained
         elsewhere, then copied per engine, or just loaded from a saved
@@ -516,7 +557,15 @@ class GenerationEngine:
         once. The executor refuses state that lives on another chip
         rather than copying it across on every tick; one of the
         spec's weights in another dtype than ``spec.param_dtype`` is cast
-        one tensor at a time (the whole model never sits on the device twice)."""
+        one tensor at a time (the whole model never sits on the device
+        twice). Then the AMP operand copies (the class docstring says when
+        an engine holds them): ``w.astype(bfloat16)`` of every operand
+        weight the scope holds, what ``amp_cast`` computes inside a call,
+        made once and placed beside the weight, which stays. Called again
+        (``from_saved`` after its load), it casts only the weights whose
+        copy is missing or was made from another array than the scope
+        holds now; an engine built on a scope another engine serves from
+        finds that engine's copies and makes none."""
         import jax
         import jax.numpy as jnp
 
@@ -532,16 +581,49 @@ class GenerationEngine:
             if cast or (isinstance(val, jax.Array)
                         and val.devices() != {dev}):
                 todo.append((name, val, cast))
-        if not todo:
-            return
-        with trace.span("serving/load_weights", dtype=self.spec.param_dtype,
-                        bytes=sum(int(v.size) * (want.itemsize if c else
-                                                 v.dtype.itemsize)
-                                  for _, v, c in todo)):
-            for name, val, cast in todo:
-                if cast:
-                    val = val.astype(want)
-                self.scope.set(name, jax.device_put(val, dev))
+        # (a weight placed or cast below is another array afterwards)
+        stale = set(self._stale_operands()) | {name for name, _, _ in todo}
+        stale = [name for name in self._operands if name in stale]
+        if todo or stale:
+            with trace.span(
+                    "serving/load_weights", dtype=self.spec.param_dtype,
+                    bytes=sum(int(v.size) * (want.itemsize if c else
+                                             v.dtype.itemsize)
+                              for _, v, c in todo)
+                    + 2 * sum(int(self.scope.get(n).size) for n in stale)):
+                for name, val, cast in todo:
+                    if cast:
+                        val = val.astype(want)
+                    self.scope.set(name, jax.device_put(val, dev))
+                self._cast_operands(stale)
+        self.metrics.set_gauge("mem/amp_operand_bytes", float(sum(
+            self.scope.get(copy).nbytes for copy in self._operands.values()
+            if self.scope.has(copy))))
+
+    def _stale_operands(self) -> List[str]:
+        """The operand weights in the scope whose copy is missing or was
+        cast from another array than the scope holds now."""
+        source = _OPERAND_SOURCE.get(self.scope, {})
+        return [name for name, copy in self._operands.items()
+                if self.scope.has(name) and not (
+                    self.scope.has(copy)
+                    and source.get(name) is self.scope.get(name))]
+
+    def _cast_operands(self, names: Sequence[str]) -> None:
+        """Make the AMP operand copy of each weight of ``names``, one
+        tensor at a time (the float32 model and its copies are what the
+        device holds; never a third)."""
+        import jax
+        import jax.numpy as jnp
+
+        dev = self.executor.device()
+        source = _OPERAND_SOURCE.setdefault(self.scope, {})
+        for name in names:
+            val = self.scope.get(name)
+            with self.executor.device_ctx():
+                copy = jnp.asarray(val).astype(jnp.bfloat16)
+            self.scope.set(self._operands[name], jax.device_put(copy, dev))
+            source[name] = val
 
     # -- cache / program construction -----------------------------------
     def _init_cache(self):
@@ -635,9 +717,18 @@ class GenerationEngine:
                 {"CacheKW": [ckw], "CacheVW": [cvw]})
 
     def _lm_ins(self, helper):
+        """The ops' weight slots; a weight with an AMP operand copy is
+        bound to the copy (the float32 parameter stays declared: it is
+        resident, and the memory analysis prices both)."""
         from ..models.transformer import _shared_lm_params
 
-        return _shared_lm_params(helper, self.spec)
+        ins = _shared_lm_params(helper, self.spec)
+        for slot, (var,) in ins.items():
+            if var.name in self._operands:
+                ins[slot] = [helper.create_global_variable(
+                    name=self._operands[var.name], shape=list(var.shape),
+                    dtype="bfloat16")]
+        return ins
 
     def _decode_attrs(self):
         # per-request sampling rides the input plane, never the attrs
@@ -645,6 +736,10 @@ class GenerationEngine:
         # request shape shares one compile-cache entry
         attrs = {**self.spec.block.attrs(), "temperature": 0.0, "top_k": 0,
                  "page_size": self.page_size}
+        if self._operands:
+            # the bf16 operands are copies: the weights are float32, and
+            # a matmul rounds as theirs does (``ops.pipeline_ops._mm``)
+            attrs["param_dtype"] = self.spec.param_dtype
         if self.beam_width:
             attrs["emit_topk"] = self.beam_width
         return attrs
@@ -892,27 +987,33 @@ class GenerationEngine:
         """Budget gate with the PAGE POOL (+ block tables) and the
         slot-state arrays counted as the resident cache — both live in the
         scope, so the analyzer prices what is actually allocated, not a
-        slots x Tmax formula."""
+        slots x Tmax formula. The AMP operand copies are priced the same
+        way (the programs read them); the float32 weights behind them,
+        which stay on the device and which no op of these programs reads,
+        are taken off the budget beforehand."""
         from .. import analysis
 
+        unread = sum(self.scope.get(name).nbytes for name in self._operands
+                     if self.scope.has(name))
         prog, outs = self._decode_prog
         mem = analysis.check_memory_budget(
             prog, list(self._decode_feed_names),
-            [v.name for v in self._fetches(outs)], budget,
+            [v.name for v in self._fetches(outs)], budget - unread,
             scope=self.scope, batch_size=self.slots,
             what=f"GenerationEngine decode step (slots={self.slots}, "
                  f"pages={self.n_pages}x{self.page_size}, state "
-                 f"{self.spec.state_bytes_per_slot} B a slot)")
+                 f"{self.spec.state_bytes_per_slot} B a slot, {unread} B "
+                 "of float32 weights behind AMP operand copies)")
         tc = self._chunk_widths[-1]
         pprog, pouts = self._prefill_prog(tc)
         pmem = analysis.check_memory_budget(
             pprog, list(self._prefill_feed_names),
-            [v.name for v in self._fetches(pouts)], budget,
+            [v.name for v in self._fetches(pouts)], budget - unread,
             scope=self.scope,
             batch_size=self.prefill_batch_buckets[-1],
             what=f"GenerationEngine prefill (chunk {tc})")
         self.metrics.set_gauge("mem/static_peak_bytes",
-                               max(mem.peak_bytes, pmem.peak_bytes))
+                               max(mem.peak_bytes, pmem.peak_bytes) + unread)
 
     # -- bucket helpers -------------------------------------------------
     def _batch_bucket_for(self, n: int) -> int:
@@ -2437,13 +2538,18 @@ class GenerationEngine:
         hold K/V computed with the OLD weights — serving them after a
         swap would be silently stale, so every index entry is dropped
         (pages still referenced by in-flight slots stay resident until
-        those requests finish)."""
+        those requests finish). The source's float32 weights are what is
+        swapped in; the AMP operand copy of each weight the swap replaced
+        is remade from it before this returns (one tensor at a time), so
+        the next tick multiplies the new weights. A source that is itself
+        a serving scope brings copies of its own: they are skipped."""
         from .engine import swap_scope_params
 
         stats = swap_scope_params(self.scope, source,
                                   skip=self._cache_names, strict=strict,
                                   device_ctx=self.executor.device_ctx,
                                   metrics=self.metrics)
+        self._cast_operands(self._stale_operands())
         if self.prefix_index is not None:
             dropped = self.prefix_index.clear()
             if self.wprefix_index is not None:

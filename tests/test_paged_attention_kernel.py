@@ -359,6 +359,76 @@ def test_decode_step_with_layer_kinds_on_the_v5e_walks_both_pools(
                 or f"[{slots},{table_width * ps}," in shape]
 
 
+@pytest.mark.parametrize("operands", ["copies", "float32_weights"])
+def test_decode_step_of_a_float32_model_under_amp_on_the_v5e_casts_no_stack(
+        one_chip, monkeypatch, operands):
+    """The decode op at GPT-2 medium's widths (1024, 16 heads, FFN 4096,
+    vocabulary 50304; two layers) under AMP, compiled for the chip. Handed
+    the float32 weights, as an engine's programs were before it held AMP
+    operand copies, the call opens by rewriting every matmul stack as bf16
+    (one ``convert`` a stack, hoisted out of the layer loop: 2.1 ms of
+    every call at 24 layers) and the head with them; handed the copies an
+    engine holds now (``LMSpec.amp_operand_names``, the block stating
+    float32), no weight stack is converted and no float32 stack is read.
+    The first form is compiled too so that this fails if the copies stop
+    doing anything."""
+    import paddle_tpu as pt
+    from paddle_tpu.lm_spec import LMSpec
+    from paddle_tpu.ops.pipeline_ops import transformer_stack_paged_decode
+
+    spec = LMSpec(vocab_size=50304, d_model=1024, n_layers=2, num_heads=16,
+                  max_len=1024)
+    slots, pages, ps, table_width = 32, 96, 64, 16
+    copied = {"HeadW"} | {
+        slot for slot, key, _, _ in spec.stack_planes()
+        if f"lm_stack.stack_{key}" in spec.amp_operand_names()}
+    assert copied == {"HeadW", "QkvW", "OutW", "FfW1", "FfW2"}
+    held = copied if operands == "copies" else set()
+    f32 = "float32"
+    shapes = {
+        "Tok": ((slots,), "int32"), "Pos": ((slots,), "int32"),
+        "BlockTable": ((slots, table_width), "int32"),
+        "CacheK": ((spec.n_layers, pages, ps, spec.d_model), f32),
+        "CacheV": ((spec.n_layers, pages, ps, spec.d_model), f32),
+        "TokEmb": ((spec.vocab_size, spec.d_model), f32),
+        "PosEmb": ((spec.max_len, spec.d_model), f32),
+        "FinalLnS": ((spec.d_model,), f32),
+        "FinalLnB": ((spec.d_model,), f32),
+        "HeadW": ((spec.d_model, spec.vocab_size), f32)}
+    for slot, _key, shape, _fan in spec.stack_planes():
+        shapes[slot] = ((spec.n_layers, *shape), f32)
+    shapes = {slot: (shape, "bfloat16" if slot in held else dtype)
+              for slot, (shape, dtype) in shapes.items()}
+    names = sorted(shapes)
+    attrs = dict(spec.block.attrs(), page_size=ps)
+    if held:
+        attrs["param_dtype"] = spec.param_dtype
+
+    def step(*args):
+        outs = transformer_stack_paged_decode(
+            attrs, {k: [a] for k, a in zip(names, args)})
+        return {k: v[0] for k, v in outs.items()}
+
+    pt.set_amp(True)    # (conftest's autouse fixture puts the policy back)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    donate = tuple(names.index(n) for n in ("CacheK", "CacheV"))
+    text = jax.jit(step, donate_argnums=donate).lower(*[
+        jax.ShapeDtypeStruct(shapes[n][0], shapes[n][1], sharding=one_chip)
+        for n in names]).compile().as_text()
+    assert "%paged_attention_decode" in text
+    flat = re.sub(r"\{[^{}]*\}", "", text)
+    stack = rf"\[{spec.n_layers},(?:1024|3072|4096),(?:1024|3072|4096)\]"
+    converted = re.findall(rf"= bf16({stack}) convert\(", flat)
+    read_f32 = re.findall(rf"= f32{stack} parameter\(", flat)
+    head_cast = re.findall(r"= bf16\[1024,50304\] convert\(", flat)
+    if held:
+        assert not converted and not read_f32 and not head_cast
+    else:
+        assert sorted(converted) == ["[2,1024,1024]", "[2,1024,3072]",
+                                     "[2,1024,4096]", "[2,4096,1024]"]
+        assert read_f32 and head_cast
+
+
 def test_stacked_train_step_on_the_v5e_runs_its_forward_scan_once(
         one_chip, monkeypatch):
     """The stacked LM's whole train step (GPT-2 medium's width, heads and
