@@ -30,19 +30,32 @@ an expert layer may hold a SHARE of the router's experts (``experts_held``)
 beside an always-on shared expert: what a cached token costs and which
 planes a layer has are properties of the spec.
 
-A pattern may instead name, position by position, one of two attention
+A pattern may instead name, position by position, one of three attention
 KINDS that share no plane: ``kda`` (Kimi Delta Attention, arXiv:2510.26692:
 the gated delta rule with a per-channel decay, fed through a short causal
 convolution; linear in the context, so what a sequence carries from token
-to token is a FIXED-SIZE recurrent state and no cache row) and ``mla`` (the
+to token is a FIXED-SIZE recurrent state and no cache row), ``mla`` (the
 spec's latent attention, then one kind of the pattern; ``q_lora_rank`` 0 is
 a full-rank query, ``attn_gate="head"`` a head-wise sigmoid gate on its
-output). The stack's weights are then held BY KIND (``stack_slots()`` /
+output) and ``gqa`` (softmax attention of ``num_heads`` query heads over
+``num_kv_heads`` cached heads of ``head_dim`` WITHOUT positions, K and V
+rows of ``kv_heads * head_dim`` in two pools; ``attn_gate="channel"`` an
+elementwise sigmoid gate on its output). A stack holds ``kda`` layers beside
+ONE of the two kinds that cache tokens (``PAGED_KINDS``: one pool layout,
+one table); ``full`` / ``window`` entries cannot stand in such a pattern.
+KDA's variants are spec fields: ``kda_decay`` ("bounded": kda_lower_bound *
+sigmoid(.), or "softplus": -exp(A_log) softplus(.), Kimi Linear's own),
+``kda_neg_eigval`` (beta = 2 sigmoid(.), in (0, 2)) and ``kda_proj_rank``
+(rank of the decay and output-gate projections; 0: full rank). The stack's
+weights are then held BY KIND (``stack_slots()`` /
 ``LMSpec.plane_layers``: a KDA plane leads with the number of KDA layers,
-the latent planes with the number of latent layers), the page pool holds
-the latent layers only, and ``slot_state`` lists what a serving SLOT holds
-beside its pages that does not grow with tokens — (name, per-slot shape,
-dtype[, layers]); empty for every other spec. ``first_dense`` leading
+the latent or ``gqa`` planes with the number of those layers), the page
+pool(s) hold the layers of the paged kind only, and ``slot_state`` lists
+what a serving SLOT holds beside its pages that does not grow with tokens —
+(name, per-slot shape, dtype[, layers]); empty for every other spec. A
+serving engine may keep SNAPSHOT rows of the same shapes (``GenerationEngine(
+snapshot_stride=, n_snapshots=)``), which is what lets its prefix index
+serve a spec with state. ``first_dense`` leading
 layers of an expert stack run a dense SwiGLU FFN of ``d_ff`` instead, and
 the router may score with a sigmoid, select on score + a correction bias
 and limit its choice to the best ``topk_group`` of ``n_group`` groups
@@ -67,9 +80,13 @@ ROPE_PAIRINGS = ("interleaved", "half")
 LAYER_KINDS = ("full+rope", "full+nope", "window+rope", "window+nope")
 #: ... or, for a stack whose layers differ in attention KIND (planes held by
 #: kind, a recurrent state beside the pages): every entry one of these
-ATTN_KINDS = ("kda", "mla")
+ATTN_KINDS = ("kda", "mla", "gqa")
+#: the kinds of ``ATTN_KINDS`` that cache tokens in pages (a stack holds at
+#: most one of them: one page pool layout, one table)
+PAGED_KINDS = ("mla", "gqa")
 ROUTER_SCORES = ("softmax", "sigmoid")
-ATTN_GATES = ("none", "head")
+ATTN_GATES = ("none", "head", "channel")
+KDA_DECAYS = ("bounded", "softplus")
 EXPERT_ACTS = ("silu", "relu")          # SwiGLU | ReGLU
 ROUTER_INPUTS = ("post_attn_norm", "attn_input")
 ATTNS = ("mha", "mla")
@@ -174,14 +191,25 @@ class Block:
     n_group: int = 1
     topk_group: int = 1
     first_dense: int = 0                # leading layers with a dense SwiGLU
-    attn_gate: str = "none"             # "head": sigmoid(x w_h) on head h's output
+    # "head": sigmoid(x w_h) on head h's output (the latent kind);
+    # "channel": sigmoid(x W_g) on every channel of it (the ``gqa`` kind)
+    attn_gate: str = "none"
     # Kimi Delta Attention (a ``kda`` entry of the pattern): ``num_heads``
     # heads of ``kda_head_dim`` keys and values, a causal depthwise
-    # convolution of ``kda_conv`` taps before q / k / v, log-decay in
-    # (``kda_lower_bound``, 0)
+    # convolution of ``kda_conv`` taps before q / k / v. The log-decay is
+    # ``kda_decay`` "bounded": kda_lower_bound * sigmoid(exp(A_log) a), in
+    # (``kda_lower_bound``, 0), or "softplus": -exp(A_log) softplus(a)
+    # (Kimi Linear's own form, unbounded below); ``kda_neg_eigval``: beta =
+    # 2 sigmoid(.), in (0, 2), so that I - beta k k^T has an eigenvalue in
+    # (-1, 1); ``kda_proj_rank`` r > 0: the decay and output-gate
+    # projections are rank-r products [d, r] [r, HK] (the gate's with a
+    # bias), 0: one full [d, HK] matrix each
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_lower_bound: float = -5.0
+    kda_decay: str = "bounded"
+    kda_neg_eigval: bool = False
+    kda_proj_rank: int = 0
     # the dtype the weights are STATED in, where the matmul operands a
     # program hands the op are not the weights themselves: a serving
     # engine's bf16 AMP operand copies of float32 weights
@@ -229,12 +257,25 @@ class Block:
                     "(no layer_pattern) or the 'mla' entries of a pattern "
                     f"over {ATTN_KINDS}; got attn={self.attn!r} with "
                     f"{pattern!r}")
-            if "kda" in pattern and (self.kda_head_dim < 1
-                                     or self.kda_conv < 2
-                                     or self.kda_lower_bound >= 0):
+            if by_attn and sum(k in pattern for k in PAGED_KINDS) != 1:
+                raise ValueError(
+                    f"a pattern over {ATTN_KINDS} holds ONE kind that "
+                    f"caches tokens (one of {PAGED_KINDS}: one pool layout "
+                    f"and one table); got {pattern!r}")
+            if "gqa" in pattern and self.qk_norm:
+                raise ValueError("the 'gqa' kind has no QK-norm")
+            if self.kda_decay not in KDA_DECAYS:
+                raise ValueError(f"kda_decay {self.kda_decay!r} not in "
+                                 f"{KDA_DECAYS}")
+            if "kda" in pattern and (
+                    self.kda_head_dim < 1 or self.kda_conv < 2
+                    or self.kda_proj_rank < 0
+                    or (self.kda_decay == "bounded"
+                        and self.kda_lower_bound >= 0)):
                 raise ValueError(
                     "a 'kda' layer needs kda_head_dim >= 1, kda_conv >= 2 "
-                    "taps and a negative kda_lower_bound")
+                    "taps, kda_proj_rank >= 0 and, for the bounded decay, "
+                    "a negative kda_lower_bound")
             if not self.use_rope:
                 raise ValueError("a layer_pattern names each layer's "
                                  "positions (rope | nope): there is no "
@@ -248,6 +289,10 @@ class Block:
         if self.attn_gate not in ATTN_GATES:
             raise ValueError(f"attn_gate {self.attn_gate!r} not in "
                              f"{ATTN_GATES}")
+        if self.attn_gate == "channel" and "gqa" not in (
+                self.attn_kinds or ()):
+            raise ValueError("attn_gate='channel' gates the 'gqa' kind of a "
+                             f"layer_pattern over {ATTN_KINDS}")
         if self.router_score not in ROUTER_SCORES:
             raise ValueError(f"router_score {self.router_score!r} not in "
                              f"{ROUTER_SCORES}")
@@ -324,8 +369,9 @@ class Block:
         A row wider than a lane row is held at whole lane rows (320 ->
         384, 576 -> 640): the TPU's tiled layout pads it to that anyway,
         and a page tile the kernel can DMA needs it. Under a pattern over
-        ``ATTN_KINDS`` it is the latent layers' row: a ``kda`` layer
-        caches no token (``slot_state``)."""
+        ``ATTN_KINDS`` it is the row of the kind that caches tokens
+        (the latent layers', or the ``gqa`` layers' K and V rows): a
+        ``kda`` layer caches no token (``slot_state``)."""
         if not self.is_mla:
             return 2, self.kv_heads * self.dh(d_model)
         w = self.kv_lora_rank + self.qk_rope_head_dim
@@ -366,14 +412,22 @@ class Block:
                 ("KdaConv", (self.kda_conv - 1, 3 * H * K), self.page_dtype)]
 
     def require_stateless(self, who: str) -> None:
+        """What still refuses a spec with state: beams (a fork shares its
+        parent's pages and would need its state), resume-from-token, the
+        slot handoff (``export_slot`` / ``adopt_slot`` / a serialized
+        handoff) and ``share_cache_with=``. The prefix index does NOT go
+        through here: an engine with a snapshot pool serves hits at
+        snapshot boundaries, one without refuses the index itself."""
         if self.slot_state(0):
             raise BlockNotSupportedError(
                 f"{who} moves, shares or re-enters cached TOKENS; this "
                 "spec's slots also carry a recurrent state "
                 f"({[n for n, _, _ in self.slot_state(0)]}) that is held "
-                "at the slot's last token only, and no snapshot of it at "
-                "another position exists: the paged prefill / decode ops "
-                "behind GenerationEngine / Server run it from position 0")
+                "at the slot's last token (and, in an engine with a "
+                "snapshot pool, at the prompt's snapshot boundaries, for "
+                "the prefix index alone): the paged prefill / decode ops "
+                "behind GenerationEngine / Server run it from position 0 "
+                "or from a snapshot")
 
     @property
     def has_window(self) -> bool:
@@ -464,13 +518,25 @@ class Block:
                              "RMSNorm, bias-free expert stack")
         slots = {"Ln1S": "ln1_s", "Ln2S": "ln2_s"}
         if "kda" in self.attn_kinds:
-            slots.update(KdaQkvW="kda_qkv_w", KdaConvW="kda_conv_w",
-                         KdaAW="kda_a_w", KdaDtBias="kda_dt_bias",
-                         KdaALog="kda_a_log", KdaBetaW="kda_beta_w",
-                         KdaGateW="kda_gate_w", KdaNormS="kda_norm_s",
-                         KdaOutW="kda_out_w")
+            low = self.kda_proj_rank > 0
+            slots.update(KdaQkvW="kda_qkv_w", KdaConvW="kda_conv_w")
+            slots.update(dict(KdaADownW="kda_a_down_w",
+                              KdaAUpW="kda_a_up_w") if low
+                         else dict(KdaAW="kda_a_w"))
+            slots.update(KdaDtBias="kda_dt_bias", KdaALog="kda_a_log",
+                         KdaBetaW="kda_beta_w")
+            slots.update(dict(KdaGateDownW="kda_gate_down_w",
+                              KdaGateUpW="kda_gate_up_w",
+                              KdaGateB="kda_gate_b") if low
+                         else dict(KdaGateW="kda_gate_w"))
+            slots.update(KdaNormS="kda_norm_s", KdaOutW="kda_out_w")
         if "mla" in self.attn_kinds:
             slots.update(self._mla_slots())
+        if "gqa" in self.attn_kinds:
+            slots["GqaQkvW"] = "gqa_qkv_w"
+            if self.attn_gate == "channel":
+                slots["GqaGateW"] = "gqa_gate_w"
+            slots["GqaOutW"] = "gqa_out_w"
         if self.first_dense:
             slots.update(DenseGateW="dense_gate_w", DenseUpW="dense_up_w",
                          DenseDownW="dense_down_w")
@@ -488,11 +554,13 @@ class Block:
     @staticmethod
     def plane_group(key: str) -> str:
         """Which layers of a by-kind stack own plane ``key``: ``all`` |
-        ``kda`` | ``mla`` | ``dense`` | ``experts``."""
+        ``kda`` | ``mla`` | ``gqa`` | ``dense`` | ``experts``."""
         if key in ("ln1_s", "ln2_s"):
             return "all"
         if key.startswith("kda_"):
             return "kda"
+        if key.startswith("gqa_"):
+            return "gqa"
         if key.startswith("dense_"):
             return "dense"
         if key.startswith(("router_", "moe_", "shared_")):
@@ -506,6 +574,7 @@ class Block:
         of = {"all": lambda l: True,
               "kda": lambda l: kinds[l % len(kinds)] == "kda",
               "mla": lambda l: kinds[l % len(kinds)] == "mla",
+              "gqa": lambda l: kinds[l % len(kinds)] == "gqa",
               "dense": lambda l: l < self.first_dense,
               "experts": lambda l: l >= self.first_dense}
         out = {}
@@ -527,13 +596,22 @@ OPTIONAL_STACK_SLOTS = ("Ln1B", "Ln2B", "QNormS", "KNormS", "FfW1", "FfB1",
                         "SharedDownW", "OutW", "QW", "AttnGateW", "RouterB",
                         "KdaQkvW", "KdaConvW", "KdaAW", "KdaDtBias",
                         "KdaALog", "KdaBetaW", "KdaGateW", "KdaNormS",
-                        "KdaOutW", "DenseGateW", "DenseUpW", "DenseDownW")
+                        "KdaOutW", "DenseGateW", "DenseUpW", "DenseDownW",
+                        "KdaADownW", "KdaAUpW", "KdaGateDownW", "KdaGateUpW",
+                        "KdaGateB", "GqaQkvW", "GqaGateW", "GqaOutW")
 #: matrix planes the ops read in float32 under AMP too: the router's
 #: logits (``moe_topk``) and the taps of a ``kda`` layer's convolution
 _F32_READ_PLANES = ("router_w", "kda_conv_w")
 #: what ``Block.slot_state`` may list: the paged ops' state slots (inputs,
 #: and outputs updated in place)
 STATE_SLOTS = ("KdaState", "KdaConv")
+#: ... and, for an engine with a snapshot pool, the snapshot rows of each
+#: ([layers, n_snapshots, *shape], the prefill op's alone) with the two
+#: feeds that name, row by row of a prefill call, the snapshot row a row's
+#: state STARTS from and the one its state is copied into after the chunk
+#: (a value beyond the rows: neither)
+SNAPSHOT_SLOTS = tuple(name + "Snap" for name in STATE_SLOTS) + (
+    "SnapFrom", "SnapTake")
 
 
 @dataclasses.dataclass
@@ -556,11 +634,14 @@ class LMSpec:
     router's ``num_experts`` (the expert stacks are [L, count, ..], the
     router [d, num_experts]). ``routed_scale``: ``routed_scaling_factor``.
 
-    ``layer_pattern`` over ``("kda", "mla")`` with ``kda_head_dim`` /
-    ``kda_conv`` / ``kda_lower_bound``: linear-attention layers beside
-    latent ones, planes held by kind (``plane_layers``), the page pool the
-    latent layers' alone (``layers_of(False)``) and ``slot_state()`` what a
-    slot carries besides. ``first_dense`` leading layers run a dense SwiGLU
+    ``layer_pattern`` over ``("kda", "mla", "gqa")`` with ``kda_head_dim``
+    / ``kda_conv`` / ``kda_lower_bound`` / ``kda_decay`` /
+    ``kda_neg_eigval`` / ``kda_proj_rank``: linear-attention layers beside
+    latent ones OR beside grouped-query K/V layers without positions
+    (``attn_gate="channel"``: their elementwise output gate), planes held
+    by kind (``plane_layers``), the page pool(s) the paged kind's alone
+    (``layers_of(False)``) and ``slot_state()`` what a slot carries
+    besides. ``first_dense`` leading layers run a dense SwiGLU
     of ``d_ff``; ``router_score`` / ``router_bias`` / ``n_group`` /
     ``topk_group``: the router; ``attn_gate="head"``: the latent
     attention's head-wise output gate."""
@@ -610,6 +691,9 @@ class LMSpec:
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_lower_bound: float = -5.0
+    kda_decay: str = "bounded"
+    kda_neg_eigval: bool = False
+    kda_proj_rank: int = 0
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
@@ -671,10 +755,13 @@ class LMSpec:
     def layers_of(self, windowed: bool) -> int:
         """How many of the stack's layers are window (or full) layers:
         the layers of each kind's page pool. Under a pattern over
-        attention kinds the latent layers are the full kind (a ``kda``
-        layer has no pages) and there is no window kind."""
-        if self.block.attn_kinds:
-            return 0 if windowed else self.plane_layers("kv_a_w")
+        attention kinds the layers that cache tokens (latent or ``gqa``)
+        are the full kind (a ``kda`` layer has no pages) and there is no
+        window kind."""
+        kinds = self.block.attn_kinds
+        if kinds:
+            return 0 if windowed else self.plane_layers(
+                "gqa_qkv_w" if "gqa" in kinds else "kv_a_w")
         kinds = self.block.kinds
         if kinds is None:
             return 0 if windowed else self.n_layers
@@ -756,8 +843,16 @@ class LMSpec:
         if self.attn == "mla":
             d_q = H * dv                # what the out-projection reads
         Kd, taps = self.kda_head_dim, self.kda_conv
-        dK, ff = H * Kd, self.ffn_width
+        dK, ff, rk = H * Kd, self.ffn_width, self.kda_proj_rank
         shapes = {
+            "kda_a_down_w": ([d, rk], (d, rk)),
+            "kda_a_up_w": ([rk, dK], (rk, dK)),
+            "kda_gate_down_w": ([d, rk], (d, rk)),
+            "kda_gate_up_w": ([rk, dK], (rk, dK)),
+            "kda_gate_b": ([dK], None),
+            "gqa_qkv_w": ([d, d_q + 2 * d_kv], (d, d_q + 2 * d_kv)),
+            "gqa_gate_w": ([d, d_q], (d, d_q)),
+            "gqa_out_w": ([d_q, d], (d_q, d)),
             "q_w": ([d, H * (nope + rope)], (d, H * (nope + rope))),
             "attn_gate_w": ([d, H], (d, H)),
             "router_b": ([E], None),

@@ -18,8 +18,8 @@ import jax.numpy as jnp
 
 from ..core.registry import register_op
 from ..kernels.flash_attention import flash_attention, rotary
-from ..lm_spec import (OPTIONAL_STACK_SLOTS, STATE_SLOTS, Block,
-                       BlockNotSupportedError)
+from ..lm_spec import (OPTIONAL_STACK_SLOTS, SNAPSHOT_SLOTS, STATE_SLOTS,
+                       Block, BlockNotSupportedError)
 from .common import amp_cast, maybe, mxu_precision, out, single
 from .moe_ops import moe_topk
 
@@ -344,7 +344,8 @@ def pipelined_transformer_stack(attrs, ins):
     # a stack held by attention kind (``Block._slots_by_kind``): "OutW"
     # "QW" "AttnGateW" "RouterB" "KdaQkvW" "KdaConvW" "KdaAW" "KdaDtBias"
     # "KdaALog" "KdaBetaW" "KdaGateW" "KdaNormS" "KdaOutW" "DenseGateW"
-    # "DenseUpW" "DenseDownW"
+    # "DenseUpW" "DenseDownW" "KdaADownW" "KdaAUpW" "KdaGateDownW"
+    # "KdaGateUpW" "KdaGateB" "GqaQkvW" "GqaGateW" "GqaOutW"
     params = _stack_params(blk, ins)
     causal = attrs.get("causal", True)
 
@@ -538,7 +539,8 @@ def transformer_stack_generate(attrs, ins, rng):
     # a stack held by attention kind (``Block._slots_by_kind``): "OutW"
     # "QW" "AttnGateW" "RouterB" "KdaQkvW" "KdaConvW" "KdaAW" "KdaDtBias"
     # "KdaALog" "KdaBetaW" "KdaGateW" "KdaNormS" "KdaOutW" "DenseGateW"
-    # "DenseUpW" "DenseDownW"
+    # "DenseUpW" "DenseDownW" "KdaADownW" "KdaAUpW" "KdaGateDownW"
+    # "KdaGateUpW" "KdaGateB" "GqaQkvW" "GqaGateW" "GqaOutW"
     (prompt, tok_emb, pos_emb, ln_s, ln_b, head_w,
      params) = _unpack_lm_ins(blk, ins)
     if blk.attn_kinds:
@@ -1017,14 +1019,25 @@ def _l2norm(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + _L2_EPS)
 
 
+def _kda_low_rank(blk, p, hn, key):
+    """hn [b, t, d] through the decay's (``kda_a``) or the output gate's
+    (``kda_gate``) projection to [b, t, HK]: one matrix, or the rank-
+    ``kda_proj_rank`` pair ``<key>_down_w`` [d, r], ``<key>_up_w`` [r, HK]."""
+    if not blk.kda_proj_rank:
+        return _mm(blk, "btd,de->bte", hn, p[key + "_w"])
+    low = _mm(blk, "btd,dr->btr", hn, p[key + "_down_w"])
+    return _mm(blk, "btr,re->bte", low, p[key + "_up_w"])
+
+
 def _kda_project(blk, p, hn, conv, n_valid):
     """The inputs of the delta rule from the normed stream hn [b, t, d]
     and the row's convolution history conv [b, taps - 1, 3HK] (the q | k |
     v projections of its last tokens): -> q, k, v, g [b, t, H, K] float32
-    (q, k L2-normalised, q scaled by K^-1/2; g the log-decay in
-    (lower_bound, 0)), beta [b, t, H], and the history after the row's
-    ``n_valid`` [b] tokens of this call. Tokens beyond ``n_valid`` get g
-    = 0 and beta = 0: they leave the state as it is."""
+    (q, k L2-normalised, q scaled by K^-1/2; g the log-decay, in
+    (lower_bound, 0) or, ``kda_decay="softplus"``, -exp(A_log) softplus(a)),
+    beta [b, t, H] (doubled under ``kda_neg_eigval``), and the history
+    after the row's ``n_valid`` [b] tokens of this call. Tokens beyond
+    ``n_valid`` get g = 0 and beta = 0: they leave the state as it is."""
     b, t, _ = hn.shape
     H, K, taps = blk.num_heads, blk.kda_head_dim, blk.kda_conv
     f32 = jnp.float32
@@ -1038,30 +1051,44 @@ def _kda_project(blk, p, hn, conv, n_valid):
     q, k, v = (y[..., i * H * K:(i + 1) * H * K].reshape(b, t, H, K)
                for i in range(3))
     q, k = _l2norm(q) * K ** -0.5, _l2norm(k)
-    a = _mm(blk, "btd,de->bte", hn, p["kda_a_w"]).astype(f32) \
+    a = _kda_low_rank(blk, p, hn, "kda_a").astype(f32) \
         + p["kda_dt_bias"].astype(f32)
     rate = jnp.exp(p["kda_a_log"].astype(f32))[:, None]  # [H, 1]
-    g = blk.kda_lower_bound * jax.nn.sigmoid(a.reshape(b, t, H, K) * rate)
+    a = a.reshape(b, t, H, K)
+    if blk.kda_decay == "softplus":
+        g = -rate * jax.nn.softplus(a)
+    else:
+        g = blk.kda_lower_bound * jax.nn.sigmoid(a * rate)
     beta = jax.nn.sigmoid(
         _mm(blk, "btd,dh->bth", hn, p["kda_beta_w"]).astype(f32))
+    if blk.kda_neg_eigval:
+        beta = 2.0 * beta
     valid = jnp.arange(t, dtype=jnp.int32)[None, :] < n_valid[:, None]
     g = jnp.where(valid[..., None, None], g, 0.0)
     beta = jnp.where(valid[..., None], beta, 0.0)
     return q, k, v, g, beta, conv
 
 
-def _kda_layer(blk, p, h, state, conv, l, rows):
+def _kda_layer(blk, p, h, state, conv, l, rows, snaps=None):
     """One ``kda`` layer's attention half against the state arrays, in
     place: h [b, t, d], ``state`` [Lk, slots, H, K, V] float32, ``conv``
     [Lk, slots, taps - 1, 3HK], l the layer's index among the kda layers,
     ``rows`` = (slot [b] or None: row i IS slot i, start [b], n_valid
     [b]). -> (ctx [b, t, H*V]: RMSNorm of each head's read-out times the
-    sigmoid output gate, state, conv). A row whose call starts at position
-    0 reads a ZERO state and history whatever its slot held; a row with no
-    valid token (a vacant or prefilling slot of a decode tick, a padding
-    row) leaves both as they were. t == 1 is the recurrent step (on a chip
-    the ``kda_decode_step`` kernel over the whole state array), a chunk
-    the chunked form."""
+    sigmoid output gate, state, conv, snaps). A row whose call starts at
+    position 0 reads a ZERO state and history whatever its slot held; a row
+    with no valid token (a vacant or prefilling slot of a decode tick, a
+    padding row) leaves both as they were. t == 1 is the recurrent step (on
+    a chip the ``kda_decode_step`` kernel over the whole state array), a
+    chunk the chunked form.
+
+    ``snaps`` (a prefill call of an engine with a snapshot pool) =
+    (state_snap [Lk, n, H, K, V], conv_snap [Lk, n, taps - 1, 3HK], from
+    [b], take [b]): a row whose ``from`` < n starts from that snapshot row
+    instead of its slot's state, and a row whose ``take`` < n leaves a copy
+    of the state and history it ends the chunk with in that row — bit for
+    bit what its slot holds, so a later row that starts from it computes
+    what this row's next chunk does."""
     from ..kernels import kda
 
     slot, start, n_valid = rows
@@ -1072,9 +1099,15 @@ def _kda_layer(blk, p, h, state, conv, l, rows):
     hn = _norm(blk, h, p["ln1_s"])
     ix = jnp.arange(b) if slot is None else slot
     conv0 = jnp.where(fresh[:, None, None], 0, conv[l, ix])
+    if snaps is not None:
+        s_snap, c_snap, s_from, s_take = snaps
+        n_snap = s_snap.shape[1]
+        restore = live & (s_from < n_snap)
+        at = jnp.minimum(s_from, n_snap - 1)
+        conv0 = jnp.where(restore[:, None, None], c_snap[l, at], conv0)
     q, k, v, g, beta, conv1 = _kda_project(blk, p, hn, conv0, n_valid)
-    conv = conv.at[l, ix].set(jnp.where(live[:, None, None], conv1, conv0),
-                              mode="drop")
+    conv1 = jnp.where(live[:, None, None], conv1, conv0)
+    conv = conv.at[l, ix].set(conv1, mode="drop")
     if slot is None and kda.supported(state, t):
         # (a live decode row never sits at position 0: nothing is fresh)
         o, state = kda.kda_decode_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
@@ -1083,29 +1116,42 @@ def _kda_layer(blk, p, h, state, conv, l, rows):
     else:
         s_old = state[l, ix]
         s0 = jnp.where(fresh[:, None, None, None], 0.0, s_old)
+        if snaps is not None:
+            s0 = jnp.where(restore[:, None, None, None], s_snap[l, at], s0)
         o, s1 = (kda.kda_recurrent if t == 1 else kda.kda_chunked)(
             q, k, v, g, beta, s0)
-        state = state.at[l, ix].set(
-            jnp.where(live[:, None, None, None], s1, s_old), mode="drop")
+        s1 = jnp.where(live[:, None, None, None], s1, s_old)
+        state = state.at[l, ix].set(s1, mode="drop")
+        if snaps is not None:
+            snaps = (s_snap.at[l, s_take].set(s1, mode="drop"),
+                     c_snap.at[l, s_take].set(conv1, mode="drop"),
+                     s_from, s_take)
     o = _rms(o, p["kda_norm_s"], blk.norm_eps).reshape(b, t, H * K)
-    gate = jax.nn.sigmoid(_mm(blk, "btd,de->bte", hn, p["kda_gate_w"]))
-    return (o * gate).astype(h.dtype), state, conv
+    gate = _kda_low_rank(blk, p, hn, "kda_gate")
+    if blk.kda_proj_rank:
+        gate = gate + p["kda_gate_b"].astype(gate.dtype)
+    return (o * jax.nn.sigmoid(gate)).astype(h.dtype), state, conv, snaps
 
 
 def _scan_kind_layers(blk, params, h, pool, table, page_id, page_row, pos0,
-                      mask, states, rows):
+                      mask, states, rows, pool_v=None, snaps=None):
     """``_scan_paged_layers`` for a stack held by attention kind: h [b, t,
-    d] through the layers with the latent page pool [Lmla, N, ps, W] AND
-    the slot-state arrays (``states``: name -> [Lk, slots, ..]) as the
-    loop's in-place carry. Layer l runs its kind's attention half (``kda``:
+    d] through the layers with the page pool(s) of the ONE kind that
+    caches tokens (the latent layers' [Lmla, N, ps, W], or the ``gqa``
+    layers' K and V pools [Lgqa, N, ps, Hkv*dh]: ``pool_v``) AND the
+    slot-state arrays (``states``: name -> [Lk, slots, ..]) as the loop's
+    in-place carry. Layer l runs its kind's attention half (``kda``:
     ``_kda_layer`` against the state; ``mla``: ``_mla_paged_step`` against
-    the pool) and its FFN kind (dense SwiGLU below ``first_dense``,
-    experts after), each on the planes of ITS group at the layer's index
-    within the group. The periods that hold a dense layer are unrolled (a
-    prologue: their positions differ from the later periods'), the whole
-    periods after them run under ONE ``lax.scan`` with the period's
-    positions unrolled in its body. -> (h, pool, states, (counts [Lexp,
-    E], router prob mean [Lexp, E]))."""
+    the pool; ``gqa``: ``_paged_layer_step``, the paged grouped-query step
+    of every K/V stack, without rotation and with the channel gate) and
+    its FFN kind (dense SwiGLU below ``first_dense``, experts after), each
+    on the planes of ITS group at the layer's index within the group. The
+    periods that hold a dense layer are unrolled (a prologue: their
+    positions differ from the later periods'), the whole periods after
+    them run under ONE ``lax.scan`` with the period's positions unrolled
+    in its body. ``snaps``: the snapshot rows of a prefill call
+    (``_kda_layer``), carried like the states. -> (h, pool, pool_v, states,
+    snaps, (counts [Lexp, E], router prob mean [Lexp, E]))."""
     b, t, _ = h.shape
     kinds = blk.attn_kinds
     P = len(kinds)
@@ -1113,34 +1159,45 @@ def _scan_kind_layers(blk, params, h, pool, table, page_id, page_row, pos0,
     index = blk.group_index(n_layers)
     group_of = {key: Block.plane_group(key) for key in params}
     whole = {k: params[k] for k in _RESIDENT_PLANES if k in params}
-    mla = _mla_paged_step(
-        blk, b, t, lambda p, hh: _mla_latent(blk, p, hh, pos0), mask,
-        lambda p, hh, ctx, dense: _attn_out_ffn(
-            blk, p, hh, _head_gate(blk, p, hh, ctx), dense=dense))
+    if "mla" in kinds:
+        paged = _mla_paged_step(
+            blk, b, t, lambda p, hh: _mla_latent(blk, p, hh, pos0), mask,
+            lambda p, hh, ctx, dense: _attn_out_ffn(
+                blk, p, hh, _head_gate(blk, p, hh, ctx), dense=dense))
+    else:
+        paged = _paged_layer_step(
+            b, t, pool.shape[2],
+            lambda p, hh: _attn_proj(blk, {**p, "qkv_w": p["gqa_qkv_w"]},
+                                     hh, pos0=pos0, rope=False),
+            mask, lambda p, hh, ctx, dense: _attn_out_ffn(
+                blk, p, hh, _channel_gate(blk, p, hh, ctx),
+                out_key="gqa_out_w", dense=dense))
+    paged_kind = "mla" if "mla" in kinds else "gqa"
     ix = (page_id.reshape(b, t), page_row.reshape(b, t))
 
     def layer(carry, p, kind, dense, at):
         """One layer; ``at``: group -> the layer's index in the group."""
-        hh, pool, st = carry
+        hh, pool, pool_v, st, sn = carry
         if whole and not dense:
             p = {**p, **whole, "layer": at["experts"]}
         if kind == "kda":
-            ctx, s_new, c_new = _kda_layer(blk, p, hh, st["KdaState"],
-                                           st["KdaConv"], at["kda"], rows)
+            ctx, s_new, c_new, sn = _kda_layer(
+                blk, p, hh, st["KdaState"], st["KdaConv"], at["kda"], rows,
+                sn)
             st = {**st, "KdaState": s_new, "KdaConv": c_new}
             hh, stats = _attn_out_ffn(blk, p, hh, ctx, out_key="kda_out_w",
                                       dense=dense)
         else:
-            hh, pool, _, stats = mla(hh, pool, None, at["mla"], p, dense,
-                                     table, *ix)
-        return (hh, pool, st), stats
+            hh, pool, pool_v, stats = paged(hh, pool, pool_v, at[paged_kind],
+                                            p, dense, table, *ix)
+        return (hh, pool, pool_v, st, sn), stats
 
     def planes(l):      # layer l's own planes (python l)
         return {k: v[index[group_of[k]][l]] for k, v in params.items()
                 if k not in whole and index[group_of[k]][l] is not None}
 
     head = min(-(-blk.first_dense // P) * P, n_layers)
-    carry, stats = (h, pool, states), []
+    carry, stats = (h, pool, pool_v, states, snaps), []
     for l in range(head):
         carry, st_l = layer(carry, planes(l), kinds[l % P],
                             l < blk.first_dense,
@@ -1178,9 +1235,9 @@ def _scan_kind_layers(blk, params, h, pool, table, page_id, page_row, pos0,
         carry, ys = jax.lax.scan(period, carry, (
             xs, jnp.arange(periods, dtype=jnp.int32)))
         stats.append(tuple(a.reshape((-1,) + a.shape[2:]) for a in ys))
-    h, pool, states = carry
+    h, pool, pool_v, states, snaps = carry
     stats = tuple(jnp.concatenate(a) for a in zip(*stats))
-    return h, pool, states, stats
+    return h, pool, pool_v, states, snaps, stats
 
 
 def _head_gate(blk, p, h, ctx):
@@ -1193,6 +1250,16 @@ def _head_gate(blk, p, h, ctx):
     gate = jax.nn.sigmoid(_mm(blk, "btd,dh->bth", hn, p["attn_gate_w"]))
     return (ctx.reshape(b, t, blk.num_heads, -1)
             * gate[..., None].astype(ctx.dtype)).reshape(b, t, -1)
+
+
+def _channel_gate(blk, p, h, ctx):
+    """The ``gqa`` kind's output gate: every channel of the context times
+    sigmoid(norm 1(h) W_g) (``attn_gate="channel"``, arXiv:2505.06708)."""
+    if blk.attn_gate != "channel":
+        return ctx
+    hn = _norm(blk, h, p["ln1_s"], p.get("ln1_b"))
+    gate = jax.nn.sigmoid(_mm(blk, "btd,de->bte", hn, p["gqa_gate_w"]))
+    return ctx * gate.astype(ctx.dtype)
 
 
 def _paged_outs(blk, stats, win, **outs):
@@ -1243,7 +1310,8 @@ def _window_ins(blk, ins, targets):
 
 @register_op("transformer_stack_paged_prefill",
              optional_inputs=(_LM_OPTIONAL + _SAMPLING_SLOTS + _WINDOW_SLOTS
-                              + _POOL_SLOTS + STATE_SLOTS + ("StateSlot",)),
+                              + _POOL_SLOTS + STATE_SLOTS + ("StateSlot",)
+                              + SNAPSHOT_SLOTS),
              needs_rng=lambda attrs: (attrs.get("temperature") or 0) > 0)
 def transformer_stack_paged_prefill(attrs, ins, rng=None):
     """Prefill ONE CHUNK of each row's prompt into its block-table pages.
@@ -1303,7 +1371,8 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     # a stack held by attention kind (``Block._slots_by_kind``): "OutW"
     # "QW" "AttnGateW" "RouterB" "KdaQkvW" "KdaConvW" "KdaAW" "KdaDtBias"
     # "KdaALog" "KdaBetaW" "KdaGateW" "KdaNormS" "KdaOutW" "DenseGateW"
-    # "DenseUpW" "DenseDownW"
+    # "DenseUpW" "DenseDownW" "KdaADownW" "KdaAUpW" "KdaGateDownW"
+    # "KdaGateUpW" "KdaGateB" "GqaQkvW" "GqaGateW" "GqaOutW"
     # and its slot-state arrays, via ``_state_ins``: "KdaState" "KdaConv"
     # (a prefill row's slot: "StateSlot")
     params = _stack_params(blk, ins)
@@ -1327,10 +1396,23 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     if blk.attn_kinds:
         # "StateSlot" [b] int32: the slot whose state each row reads and
         # leaves advanced (a padding row: any index beyond the slots)
-        h, cache_k, states, stats = _scan_kind_layers(
+        # an engine with a snapshot pool: "KdaStateSnap" "KdaConvSnap"
+        # [layers, n_snapshots, ..] and, a row, the snapshot row its state
+        # starts from / is copied into ("SnapFrom" "SnapTake": a value
+        # beyond the rows for neither)
+        snaps = None
+        if maybe(ins, "SnapFrom") is not None:
+            snaps = (single(ins, "KdaStateSnap"), single(ins, "KdaConvSnap"),
+                     single(ins, "SnapFrom").astype(jnp.int32),
+                     single(ins, "SnapTake").astype(jnp.int32))
+        h, cache_k, cache_v, states, snaps, stats = _scan_kind_layers(
             blk, params, x, cache_k, table, page_id, page_row, start,
             dict(causal=True, q_pos0=start), _state_ins(blk, ins),
-            (single(ins, "StateSlot").astype(jnp.int32), start, lengths))
+            (single(ins, "StateSlot").astype(jnp.int32), start, lengths),
+            pool_v=cache_v, snaps=snaps)
+        if snaps is not None:
+            states = {**states, "KdaStateSnap": snaps[0],
+                      "KdaConvSnap": snaps[1]}
         win = None
     else:
         # "CacheKW" "CacheVW" "BlockTableW": the window kind (_window_ins)
@@ -1404,7 +1486,8 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     # a stack held by attention kind (``Block._slots_by_kind``): "OutW"
     # "QW" "AttnGateW" "RouterB" "KdaQkvW" "KdaConvW" "KdaAW" "KdaDtBias"
     # "KdaALog" "KdaBetaW" "KdaGateW" "KdaNormS" "KdaOutW" "DenseGateW"
-    # "DenseUpW" "DenseDownW"
+    # "DenseUpW" "DenseDownW" "KdaADownW" "KdaAUpW" "KdaGateDownW"
+    # "KdaGateUpW" "KdaGateB" "GqaQkvW" "GqaGateW" "GqaOutW"
     # and its slot-state arrays, via ``_state_ins``: "KdaState" "KdaConv"
     # (a prefill row's slot: "StateSlot")
     params = _stack_params(blk, ins)
@@ -1427,10 +1510,10 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
         # row s IS slot s; a live row's token lands in a page of its own
         # (a vacant or still-prefilling slot rides on the scrap page and
         # leaves its state alone)
-        h1, cache_k, states, stats = _scan_kind_layers(
+        h1, cache_k, cache_v, states, _, stats = _scan_kind_layers(
             blk, params, h1, cache_k, table, page_id, page_row, pos,
             dict(lengths=pos + 1), _state_ins(blk, ins),
-            (None, pos, (page_id != 0).astype(jnp.int32)))
+            (None, pos, (page_id != 0).astype(jnp.int32)), pool_v=cache_v)
         win = None
     else:
         # "CacheKW" "CacheVW" "BlockTableW": the window kind (_window_ins)

@@ -58,6 +58,9 @@ PAGED_CACHE_VW = "serving.paged_cache_vw"
 # what a slot holds beside its pages (``LMSpec.slot_state``): one array
 # [layers, slots, *shape] a name, "serving.state.<name>"
 SLOT_STATE = "serving.state."
+# ... and the snapshot rows of each ([layers, n_snapshots, *shape]; an
+# engine with ``n_snapshots``): "serving.snapshot.<name>"
+STATE_SNAPSHOT = "serving.snapshot."
 # the bf16 tensor ``amp_cast`` would make of a float32 matmul weight, held
 # from load on: "serving.amp_operand.<the weight's name>"
 AMP_OPERAND = "serving.amp_operand."
@@ -205,7 +208,7 @@ class _Slot:
                  "cow_reserve", "prefill_done", "state", "sampling",
                  "stop_matcher", "mask_proc", "beam_job", "role", "xrow",
                  "resumed", "wpages", "wfirst", "wentries", "wreserve",
-                 "wcow", "prefix_key")
+                 "wcow", "prefix_key", "snap_from", "waited")
 
     def __init__(self, request: Request, prompt: np.ndarray,
                  max_new: int, eos_id: Optional[int],
@@ -224,6 +227,11 @@ class _Slot:
         self.cow_reserve = 0             # pages held for copy-on-write
         self.prefill_done = 0            # prompt tokens whose K/V is cached
         self.prefix_key = b""            # chain key of the FULL pages of them
+        # a slot with state: the (pinned) snapshot row its next prefill
+        # chunk starts from, and whether it ever sat out a tick behind
+        # another slot's prefill of its prefix
+        self.snap_from: Optional[int] = None
+        self.waited = False
         self.state = "decode"            # "prefill" while chunks stream in
                                          # ("hold"/"beam_wait" for beams)
         self.sampling = sampling or SamplingParams()
@@ -308,6 +316,29 @@ class GenerationEngine:
     asked), beams, resume-from-token, ``share_cache_with=`` and the slot
     handoff refuse.
 
+    **State snapshots** (``n_snapshots`` > 0 with ``snapshot_stride`` in
+    pages, a spec with state): the prefix index is ON, and holds beside
+    its pages ``n_snapshots`` rows of snapshot arrays ``[layers,
+    n_snapshots, *shape]`` (``serving.snapshot.<name>``, gauge
+    ``mem/state_snapshot_bytes``). While a prompt prefills, the chunk that
+    ends ``snapshot_stride`` pages further leaves a bit-for-bit copy of
+    the slot's state in a row (``state_snapshots_taken``); an admission
+    enters at the deepest such boundary that has both its pages and a row
+    (``PrefixIndex.lookup_snapshot``), at least one prompt token before
+    its end, its first chunk starting from the row instead of the slot's
+    state (``state_snapshots_restored``); what the pages matched beyond
+    that boundary is prefilled again (``state_snapshot_cutback_tokens``).
+    Taking and restoring are two feeds of the prefill call, never a call
+    of their own. A row goes with its page when the index evicts it, or
+    alone when rows run out (``state_snapshots_evicted``); a row a slot
+    is about to start from is pinned. A prompt whose next boundary another
+    prefilling slot is ahead of it on WAITS for that slot
+    (``state_prefix_waited``) and enters at the boundary once it is
+    cached (``state_prefix_adopted``), so n arrivals over one cold prefix
+    prefill it once. The stride times the page size is a multiple of the
+    prefill chunk, so a request that enters at a boundary is chunked
+    where the cold one was and serves the same bits.
+
     **What stays resident** on the engine's device, all of it in the
     scope: the weights in the spec's stored dtype, the page pools, the
     slot-state arrays, and — where float32 weights are served under AMP
@@ -348,7 +379,8 @@ class GenerationEngine:
                  prefill_chunk: Optional[int] = None,
                  prefix_sharing: bool = True,
                  beam_width: int = 0, mask_plane: bool = True,
-                 share_cache_with: Optional["GenerationEngine"] = None):
+                 share_cache_with: Optional["GenerationEngine"] = None,
+                 snapshot_stride: int = 0, n_snapshots: int = 0):
         if slots < 1:
             raise ValueError("need at least one decode slot")
         if page_size is not None and page_size < 1:
@@ -424,8 +456,16 @@ class GenerationEngine:
         self._state = [(name, SLOT_STATE + name,
                         (layers, self.slots) + tuple(shape), dtype)
                        for name, shape, dtype, layers in spec.slot_state()]
+        #: the same for the snapshot rows of each state array (op slot
+        #: ``<name>Snap``); [] without a snapshot pool
+        self._snapshots = [
+            (name + "Snap", STATE_SNAPSHOT + name,
+             (layers, int(n_snapshots)) + tuple(shape), dtype)
+            for name, shape, dtype, layers in spec.slot_state()
+        ] if n_snapshots else []
         self._cache_names = type(self)._cache_names + tuple(
-            scope_name for _, scope_name, _, _ in self._state) + tuple(
+            scope_name for _, scope_name, _, _
+            in self._state + self._snapshots) + tuple(
             self._operands.values())
         #: layers that carry state: what a call counts as its
         #: ``kda_layer_calls``
@@ -471,17 +511,35 @@ class GenerationEngine:
             | {self.prefill_chunk})
 
         # -- pool and slot table ----------------------------------------
-        # a hit on pages without the state at that position would be wrong
-        self._prefix_refused = bool(prefix_sharing) and bool(self._state)
-        self._prefix_sharing = bool(prefix_sharing) and not self._state
+        # a hit on pages without the state at that position would be wrong:
+        # a spec with state has a prefix index only with a snapshot pool
+        if n_snapshots and not (self._state and prefix_sharing
+                                and snapshot_stride >= 1
+                                and (snapshot_stride * self.page_size)
+                                % self.prefill_chunk == 0):
+            raise ValueError(
+                "n_snapshots: state snapshots are for a spec whose slots "
+                "carry state (LMSpec.slot_state()), with prefix_sharing "
+                "and a snapshot_stride >= 1 pages whose tokens are whole "
+                f"prefill chunks (got stride {snapshot_stride} x page "
+                f"{self.page_size}, chunk {self.prefill_chunk})")
+        #: tokens between two snapshot boundaries; 0: no snapshot pool
+        self._snap_block = (int(snapshot_stride) * self.page_size
+                            if n_snapshots else 0)
+        self._snap_evicted = 0      # the index's count at the last gauge
+        self._prefix_refused = (bool(prefix_sharing) and bool(self._state)
+                                and not self._snap_block)
+        self._prefix_sharing = bool(prefix_sharing) and (
+            not self._state or bool(self._snap_block))
         self._owns_pool = src is None
         if src is not None:
             self.pool = src.pool
             self.prefix_index = src.prefix_index
         else:
             self.pool = PagePool(self.n_pages, self.page_size)
-            self.prefix_index = (PrefixIndex(self.pool)
-                                 if self._prefix_sharing else None)
+            self.prefix_index = (
+                PrefixIndex(self.pool, int(n_snapshots), int(snapshot_stride))
+                if self._prefix_sharing else None)
         # the window kind: what a slot's window layers can hold at once
         # (the window, the chunk in flight, one page of slack each way)
         self.wpool = self.wprefix_index = None
@@ -645,10 +703,15 @@ class GenerationEngine:
             with self.executor.device_ctx():
                 for name, shp in pools.items():
                     self.scope.set(name, jnp.zeros(shp, page_dtype))
-                for _, name, shp, dtype in self._state:
+                for _, name, shp, dtype in self._state + self._snapshots:
                     self.scope.set(name, jnp.zeros(shp, to_dtype(dtype)))
         self.metrics.set_gauge("mem/state_bytes_per_slot",
                                float(self.spec.state_bytes_per_slot))
+        if self._snapshots:
+            self.metrics.set_gauge(
+                "mem/state_snapshot_bytes",
+                float(self.prefix_index.n_snapshots
+                      * self.spec.state_bytes_per_slot))
         self.metrics.set_gauge(
             "mem/kv_cache_bytes",
             float(sum(np.prod(shp) for shp in pools.values()))
@@ -689,23 +752,37 @@ class GenerationEngine:
         CacheV: a latent block's cache is the one pool)."""
         return {slot: [v] for slot, v in zip(("CacheK", "CacheV"), pools)}
 
-    def _state_io(self, helper):
+    def _state_io(self, helper, snapshots: bool = False):
         """The slot-state arrays as op inputs AND outputs (updated in
-        place, like the pools), by the spec's slot names."""
+        place, like the pools), by the spec's slot names; with
+        ``snapshots`` (the prefill program) the snapshot rows too."""
         return {slot: [helper.create_global_variable(
             name=name, shape=list(shape), dtype=dtype)]
-            for slot, name, shape, dtype in self._state}
+            for slot, name, shape, dtype
+            in self._state + (self._snapshots if snapshots else [])}
 
-    def _state_rows(self, slots_of_rows, rows: int) -> Dict[str, np.ndarray]:
+    def _state_rows(self, slots_of_rows, rows: int,
+                    snaps=()) -> Dict[str, np.ndarray]:
         """The prefill feed that names each row's slot, where the spec has
         state ({} otherwise): a padding row points beyond the slots, so
-        its write is dropped."""
+        its write is dropped. With a snapshot pool also, a row, the
+        snapshot row its state starts from and the one it is copied into
+        after the chunk (``snaps``: (from, take) a row, None for neither:
+        a value beyond the rows)."""
         if not self._state:
             return {}
         ix = np.full(rows, self.slots, np.int32)
         ix[:len(slots_of_rows)] = slots_of_rows
         self.metrics.inc("kda_layer_calls", self._state_layers)
-        return {"serving.state_slot": ix}
+        feed = {"serving.state_slot": ix}
+        if self._snapshots:
+            at = np.full((2, rows), self.prefix_index.n_snapshots, np.int32)
+            for row, pair in enumerate(snaps):
+                for j, v in enumerate(pair):
+                    if v is not None:
+                        at[j, row] = v
+            feed["serving.snap_from"], feed["serving.snap_take"] = at
+        return feed
 
     def _window_io(self, helper, table):
         """The window kind's op inputs and outputs (its pools, read and
@@ -753,6 +830,8 @@ class GenerationEngine:
                  "serving.block_table", *self._SAMPLING_FEEDS]
         if self._state:
             names.append("serving.state_slot")
+        if self._snapshots:
+            names += ["serving.snap_from", "serving.snap_take"]
         if self._by_kind:
             names.append("serving.block_table_w")
         if self.mask_plane:
@@ -867,13 +946,18 @@ class GenerationEngine:
             nxt = helper.block.create_var(
                 name="serving.next_tok", shape=[-1],
                 dtype="int64", stop_gradient=True)
-            state = self._state_io(helper)
+            state = self._state_io(helper, snapshots=True)
             ins = {"Chunk": [chunk], "StartPos": [start],
                    "Lengths": [length], "BlockTable": [table], **pools,
                    **state}
             if state:
                 ins["StateSlot"] = [data_layer(
                     "serving.state_slot", shape=[], dtype="int32")]
+            if self._snapshots:
+                ins["SnapFrom"] = [data_layer(
+                    "serving.snap_from", shape=[], dtype="int32")]
+                ins["SnapTake"] = [data_layer(
+                    "serving.snap_take", shape=[], dtype="int32")]
             ins.update(self._sampling_vars(None))
             ins.update(self._lm_ins(helper))
             outs = {"NextTok": [nxt], **pools, **state}
@@ -1358,32 +1442,112 @@ class GenerationEngine:
         slot's turn comes first, instead of once each side by side
         (PERF.md section 6, PR 35: 16384-token documents under open-loop
         load never warmed). The page that holds the prompt's last token
-        stays the slot's own: its chunk yields the first token. One-kind
-        caches only (a window kind's pages are let go behind the slot as
-        it advances)."""
+        stays the slot's own: its chunk yields the first token. A slot
+        WITH STATE moves only as far as the deepest snapshot boundary among
+        those pages (pages AND a row), whose row its next chunk then starts
+        from. One-kind caches only (a window kind's pages are let go
+        behind the slot as it advances)."""
         ps, start = self.page_size, st.prefill_done
-        if self.prefix_index is None or self._by_kind or start % ps:
+        index = self.prefix_index
+        if index is None or self._by_kind or start % ps:
             return
         i, last = start // ps, (int(st.prompt.size) - 1) // ps
+        key, hits, best = st.prefix_key, [], None
         while i < last:
-            hit = self.prefix_index.page_after(
-                st.prefix_key, st.prompt[i * ps:(i + 1) * ps])
+            hit = index.page_after(key, st.prompt[i * ps:(i + 1) * ps])
             if hit is None:
                 break
-            st.prefix_key, page = hit
-            self.pool.incref(page)
-            self.pool.decref(st.pages[i])
-            st.pages[i] = page
+            key = hit[0]
+            hits.append(hit[1])
             i += 1
-        if i * ps > start:
-            st.prefill_done = i * ps
-            st.shared_tokens += i * ps - start
-            st.timeline.prefix_hit_tokens = st.shared_tokens
-            self.metrics.inc("prefix_hit_tokens", i * ps - start)
+            # a slot with state moves only to where a snapshot row is too
+            row = index.snapshot_row(key) if self._state \
+                and (i * ps) % self._snap_block == 0 else None
+            if row is not None or not self._state:
+                best = (len(hits), key, row)
+        if best is None:
+            return
+        n, key, row = best
+        for j, page in enumerate(hits[:n], start // ps):
+            self.pool.incref(page)
+            self.pool.decref(st.pages[j])
+            st.pages[j] = page
+        if self._state:
+            self._start_from_snapshot(st, row)
+            self.metrics.inc("state_prefix_adopted")
+        st.prefix_key = key
+        st.prefill_done = start + n * ps
+        st.shared_tokens += n * ps
+        st.timeline.prefix_hit_tokens = st.shared_tokens
+        self.metrics.inc("prefix_hit_tokens", n * ps)
+
+    # -- state snapshots ---------------------------------------------------
+    def _start_from_snapshot(self, st: _Slot, row: Optional[int]) -> None:
+        """Pin ``row`` as what the slot's next prefill chunk starts from
+        (None: nothing; whatever it held before is let go)."""
+        if st.snap_from is not None:
+            self.prefix_index.unpin_snapshot(st.snap_from)
+        st.snap_from = row
+        if row is not None:
+            self.prefix_index.pin_snapshot(row)
+
+    def _waits_for_prefix(self, slot: int) -> bool:
+        """Whether prefilling slot ``slot`` sits this tick out: another
+        prefilling slot is AHEAD of it (further, or as far with a lower
+        index) on a prompt that agrees with this one's up to the next
+        snapshot boundary this one could enter at. That slot's chunk
+        there leaves the snapshot (``_adopt_prefilled`` then moves this
+        one to it), so n arrivals over one cold prefix prefill it once.
+        The slot furthest ahead never waits: a unit runs every tick."""
+        st, B = self._slots[slot], self._snap_block
+        nxt = (st.prefill_done // B + 1) * B
+        if nxt > int(st.prompt.size) - 1:
+            return False
+        for j, other in enumerate(self._slots):
+            if other is None or other is st or other.state != "prefill" \
+                    or other.prompt.size < nxt:
+                continue
+            if (other.prefill_done, -j) > (st.prefill_done, -slot) \
+                    and np.array_equal(other.prompt[:nxt], st.prompt[:nxt]):
+                if not st.waited:
+                    st.waited = True
+                    self.metrics.inc("state_prefix_waited")
+                return True
+        return False
+
+    def _snapshot_plan(self, st: _Slot, end: int):
+        """For the chunk that takes the slot's prefill to ``end`` tokens:
+        -> (from, take, key): the snapshot row it starts from (None: the
+        slot's own state), the row its end state is copied into and the
+        chain key that row will be held under (None, None: ``end`` is no
+        snapshot boundary, the boundary has a snapshot already, or every
+        row is pinned)."""
+        take = key = None
+        ps = self.page_size
+        if self._snap_block and end % self._snap_block == 0:
+            key = st.prefix_key
+            for i in range(st.prefill_done // ps, end // ps):
+                key = chain_key(key or None, st.prompt[i * ps:(i + 1) * ps])
+            if self.prefix_index.snapshot_row(key) is None:
+                take = self.prefix_index.alloc_snapshot()
+        return st.snap_from, take, key
+
+    def _snapshot_done(self, st: _Slot, plan) -> None:
+        """After the chunk ran (and its pages are registered): the row it
+        started from is let go, the one it filled goes under its key."""
+        came_from, take, key = plan
+        if came_from is not None:
+            self._start_from_snapshot(st, None)
+            self.metrics.inc("state_snapshots_restored")
+        if take is not None and self.prefix_index.attach_snapshot(key, take):
+            self.metrics.inc("state_snapshots_taken")
 
     def _release_pages(self, st: _Slot) -> None:
+        if st.snap_from is not None:
+            self._start_from_snapshot(st, None)
         if self._prefix_sharing:
-            self._register_prefix(st, include_tail=True)
+            # (a slot with state never enters at a partial page)
+            self._register_prefix(st, include_tail=not self._state)
         for pid in st.pages:
             self.pool.decref(pid)
         st.pages = []
@@ -1600,7 +1764,15 @@ class GenerationEngine:
         shared, spages, key = 0, [], b""
         self.metrics.inc("state_refused_prefix_lookups",
                          int(self._prefix_refused))
-        if self.prefix_index is not None:
+        snap_row = None
+        if self._snap_block:
+            # a slot with state enters where pages AND a snapshot are, one
+            # prompt token at least before the end (that token's chunk
+            # yields the first answer token and advances the state once)
+            matched, shared, spages, key, snap_row = \
+                self.prefix_index.lookup_snapshot(prompt, plen - 1)
+            cutback = matched - shared
+        elif self.prefix_index is not None:
             shared, spages, key = self.prefix_index.lookup(prompt)
             if not self._by_kind:
                 # ... and the pages a slot is still prefilling for the
@@ -1642,6 +1814,8 @@ class GenerationEngine:
                 return "failed"
         for pid in spages:  # hold the prefix before any eviction runs
             self.pool.incref(pid)
+        if snap_row is not None:
+            self.prefix_index.pin_snapshot(snap_row)
         for pid in wspages[wkeep:]:
             self.wpool.incref(pid)
         short = None
@@ -1657,6 +1831,8 @@ class GenerationEngine:
             if self.wpool.available() < wneed:
                 short = "admit_deferred_window"
         if short is not None:
+            if snap_row is not None:
+                self.prefix_index.unpin_snapshot(snap_row)
             for pid in spages:
                 self.pool.decref(pid)
             for pid in wspages[wkeep:]:
@@ -1681,7 +1857,11 @@ class GenerationEngine:
         st.cow_reserve = cow
         st.prefill_done = shared
         st.prefix_key = key     # (a cache by kind may hold less: unused)
+        st.snap_from = snap_row     # pinned above
         st.timeline.prefix_hit_tokens = shared
+        self.metrics.inc("prompt_tokens_admitted", plen)
+        if self._snap_block:
+            self.metrics.inc("state_snapshot_cutback_tokens", cutback)
         if resumed_k:
             self._install_resume(st, resume)
         self.metrics.observe_hist("queue_wait", st.timeline.queue_wait_s)
@@ -1794,8 +1974,10 @@ class GenerationEngine:
             feed.update({"serving.chunk": chunk, "serving.start": start,
                          "serving.chunk_len": length,
                          "serving.block_table": table})
+            plans = [self._snapshot_plan(st, int(st.prompt.size))
+                     for _, st, _ in group]
             feed.update(self._state_rows([slot for _, _, slot in group],
-                                         bucket))
+                                         bucket, [p[:2] for p in plans]))
             if self._by_kind:
                 feed["serving.block_table_w"] = table_w
         prog, outs = self._prefill_prog(tc)
@@ -1822,6 +2004,7 @@ class GenerationEngine:
             st.timeline.chunk(t0, t1, rem[row])
             st.prefill_done = st.prompt.size
             self._register_prefix(st)
+            self._snapshot_done(st, plans[row])
             if st.role == "beam_parent":
                 # the parent's top-K row expands the hypothesis set; the
                 # job takes over the slot bookkeeping from here
@@ -1965,14 +2148,21 @@ class GenerationEngine:
         prefilling slots; returns True when a chunk ran."""
         order = [(self._pf_cursor + i) % self.slots
                  for i in range(self.slots)]
-        slot = next((i for i in order if self._slots[i] is not None
-                     and self._slots[i].state == "prefill"), None)
+        slot = None
+        for i in order:
+            if self._slots[i] is None or self._slots[i].state != "prefill":
+                continue
+            # (a slot with state first moves to whatever boundary another
+            # slot has cached for it, then sits out while one is ahead)
+            self._adopt_prefilled(self._slots[i])
+            if not (self._snap_block and self._waits_for_prefix(i)):
+                slot = i
+                break
         if slot is None:
             return False
         self._pf_cursor = (slot + 1) % self.slots
         st = self._slots[slot]
         plen = int(st.prompt.size)
-        self._adopt_prefilled(st)
         start0 = st.prefill_done
         k = min(self.prefill_chunk, plen - start0)
         tc = self._chunk_bucket_for(k)
@@ -1992,7 +2182,8 @@ class GenerationEngine:
             feed.update({"serving.chunk": chunk, "serving.start": start,
                          "serving.chunk_len": length,
                          "serving.block_table": table})
-            feed.update(self._state_rows([slot], bucket))
+            plan = self._snapshot_plan(st, start0 + k)
+            feed.update(self._state_rows([slot], bucket, [plan[:2]]))
             if self._by_kind:
                 self._window_advance(st, start0, start0 + k - 1)
                 table_w = np.zeros((bucket, self.pmax), np.int32)
@@ -2016,6 +2207,7 @@ class GenerationEngine:
                          slot=slot, offset=start0, tokens=k)
         st.prefill_done = start0 + k
         self._register_prefix(st)       # page by page, as they fill
+        self._snapshot_done(st, plan)
         if st.prefill_done >= plen:
             self.metrics.inc("prefills")
             first = np.asarray(res[0])
@@ -2379,6 +2571,13 @@ class GenerationEngine:
         if self.prefix_index is not None:
             self.metrics.set_gauge("kv_prefix_entries",
                                    len(self.prefix_index))
+        if self._snap_block:
+            self.metrics.set_gauge("mem/state_snapshots_in_use",
+                                   self.prefix_index.snapshots_in_use())
+            n = self.prefix_index.snapshot_evictions
+            self.metrics.inc("state_snapshots_evicted",
+                             n - self._snap_evicted)
+            self._snap_evicted = n
         # throttled time-series sampling: the flight bundle's metric
         # ring sees occupancy/pages/prefix counters EVOLVE, not just
         # their value at dump time

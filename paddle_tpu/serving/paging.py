@@ -168,17 +168,55 @@ class PrefixIndex:
     satisfy an allocation; entries whose page is still held by a live
     request drop only the index's reference (the page stays resident
     under the request and is freed when it finishes).
+
+    **State snapshots** (``n_snapshots`` > 0: an engine whose slots carry a
+    recurrent state beside their pages): the index also owns
+    ``n_snapshots`` rows of the engine's snapshot arrays. A row is a copy
+    of a slot's state at a page boundary ``snapshot_stride`` pages apart,
+    held under the key of the page that ENDS there, so a prefix is a hit
+    only as deep as the deepest such boundary that has both its pages and a
+    row (``lookup_snapshot``). Rows are least-recently-used among
+    themselves (``alloc_snapshot`` evicts the oldest unpinned one when none
+    is free); a row goes with its page when the page's entry is evicted;
+    and a row some slot is about to start from is PINNED: evicted or
+    orphaned, it is not handed out again before ``unpin_snapshot``.
     """
 
-    def __init__(self, pool: PagePool):
+    def __init__(self, pool: PagePool, n_snapshots: int = 0,
+                 snapshot_stride: int = 0):
         self._pool = pool
         self._entries: "OrderedDict[bytes, int]" = OrderedDict()
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+        if n_snapshots and snapshot_stride < 1:
+            raise ValueError("snapshot rows need snapshot_stride >= 1 pages")
+        self.snapshot_stride = int(snapshot_stride)
+        self.n_snapshots = int(n_snapshots)
+        self._snap_free: List[int] = list(range(self.n_snapshots - 1, -1, -1))
+        self._snaps: "OrderedDict[bytes, int]" = OrderedDict()
+        self._snap_pins: Dict[int, int] = {}
+        self._snap_orphans: set = set()     # dropped while pinned
+        self.snapshot_evictions = 0
 
     def __len__(self) -> int:
         return len(self._entries)
+
+    def _walk(self, prompt: np.ndarray, n_full: int):
+        """The chain walk every lookup makes: (key, page) of each cached
+        full page of ``prompt`` among its first ``n_full``, in order, up to
+        the first that is not cached (hits, one miss and recency counted)."""
+        ps = self._pool.page_size
+        key: Optional[bytes] = None
+        for i in range(n_full):
+            key = chain_key(key, prompt[i * ps:(i + 1) * ps])
+            page = self._entries.get(key)
+            if page is None:
+                self.misses += 1
+                return
+            self._entries.move_to_end(key)
+            self.hits += 1
+            yield key, page
 
     def lookup(self, prompt: np.ndarray) -> Tuple[int, List[int], bytes]:
         """Longest cached prefix of ``prompt``: returns
@@ -187,23 +225,14 @@ class PrefixIndex:
         page multiple except on a full-prompt hit. Does NOT take
         references — the caller increfs the pages it decides to use."""
         ps = self._pool.page_size
+        n_full = len(prompt) // ps
         key: Optional[bytes] = None
         pages: List[int] = []
-        shared = 0
-        n_full = len(prompt) // ps
-        for i in range(n_full):
-            k = chain_key(key, prompt[i * ps:(i + 1) * ps])
-            page = self._entries.get(k)
-            if page is None:
-                self.misses += 1
-                return shared, pages, key or b""
-            self._entries.move_to_end(k)
-            self.hits += 1
-            key = k
+        for key, page in self._walk(prompt, n_full):
             pages.append(page)
-            shared += ps
+        shared = len(pages) * ps
         tail = prompt[n_full * ps:]
-        if len(tail):
+        if len(pages) == n_full and len(tail):
             k = chain_key(key, tail)
             page = self._entries.get(k)
             if page is not None:
@@ -239,18 +268,96 @@ class PrefixIndex:
 
     def evict_until(self, pages_needed: int) -> int:
         """Drop LRU entries until ``pool.available() >= pages_needed``
-        (or the index is empty). Returns entries evicted."""
+        (or the index is empty). Returns entries evicted. A snapshot goes
+        with the page that ends at its boundary."""
         n = 0
         while (self._pool.available() < pages_needed and self._entries):
-            _, page = self._entries.popitem(last=False)
+            key, page = self._entries.popitem(last=False)
             self._pool.decref(page)
             self.evictions += 1
+            if key in self._snaps:
+                self._drop_snapshot(key)
             n += 1
         return n
+
+    # -- state snapshots ---------------------------------------------------
+    def lookup_snapshot(self, prompt: np.ndarray, limit: int
+                        ) -> Tuple[int, int, List[int], bytes, Optional[int]]:
+        """The prefix of ``prompt`` a slot WITH STATE can enter at: ->
+        ``(matched, shared, page_ids, key, row)``. ``matched``: tokens of
+        the full pages the index holds for the prompt's first ``limit``
+        tokens; ``shared`` <= matched: the deepest stride boundary among
+        them that also has a snapshot (0: none), ``page_ids`` / ``key`` the
+        pages up to it and the chain key there, ``row`` its snapshot row
+        (None when ``shared`` is 0). No reference is taken and nothing is
+        pinned: the caller increfs the pages and pins the row."""
+        ps, stride = self._pool.page_size, self.snapshot_stride
+        pages: List[int] = []
+        best = (0, b"", None)
+        for k, page in self._walk(prompt, min(limit, len(prompt)) // ps):
+            pages.append(page)
+            if len(pages) % stride == 0 and k in self._snaps:
+                self._snaps.move_to_end(k)
+                best = (len(pages), k, self._snaps[k])
+        n, key_at, row = best
+        return len(pages) * ps, n * ps, pages[:n], key_at, row
+
+    def snapshot_row(self, key: bytes) -> Optional[int]:
+        return self._snaps.get(key)
+
+    def alloc_snapshot(self) -> Optional[int]:
+        """A snapshot row to write: a free one, else the least recently
+        used row no slot is starting from (its key loses it); None when
+        every row is pinned."""
+        if self._snap_free:
+            return self._snap_free.pop()
+        for key, row in self._snaps.items():
+            if not self._snap_pins.get(row):
+                self._drop_snapshot(key)    # the row is free again
+                return self._snap_free.pop()
+        return None
+
+    def attach_snapshot(self, key: bytes, row: int) -> bool:
+        """Hold ``row`` under ``key`` (the chain key of the page that ends
+        at the snapshot's boundary). The page must be cached and have no
+        snapshot yet: else the row goes back to the free list (False)."""
+        if key not in self._entries or key in self._snaps:
+            self._snap_free.append(row)
+            return False
+        self._snaps[key] = row
+        return True
+
+    def pin_snapshot(self, row: int) -> None:
+        self._snap_pins[row] = self._snap_pins.get(row, 0) + 1
+
+    def unpin_snapshot(self, row: int) -> None:
+        left = self._snap_pins.get(row, 0) - 1
+        if left > 0:
+            self._snap_pins[row] = left
+            return
+        self._snap_pins.pop(row, None)
+        if row in self._snap_orphans:
+            self._snap_orphans.discard(row)
+            self._snap_free.append(row)
+
+    def _drop_snapshot(self, key: bytes) -> None:
+        row = self._snaps.pop(key)
+        self.snapshot_evictions += 1
+        if self._snap_pins.get(row):
+            self._snap_orphans.add(row)     # freed by its last unpin
+        else:
+            self._snap_free.append(row)
+
+    def snapshots_in_use(self) -> int:
+        return self.n_snapshots - len(self._snap_free)
 
     def clear(self) -> int:
         return self.evict_until(self._pool.n_pages + 1)
 
     def stats(self) -> dict:
-        return {"entries": len(self._entries), "hits": self.hits,
-                "misses": self.misses, "evictions": self.evictions}
+        out = {"entries": len(self._entries), "hits": self.hits,
+               "misses": self.misses, "evictions": self.evictions}
+        if self.n_snapshots:
+            out.update(snapshots=len(self._snaps),
+                       snapshot_evictions=self.snapshot_evictions)
+        return out

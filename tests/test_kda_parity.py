@@ -199,7 +199,7 @@ def test_a_row_with_no_valid_token_leaves_state_and_history_alone():
     conv = jnp.asarray(rng.normal(size=(4, 3, 3, 96)), jnp.float32)
     h = jnp.asarray(rng.normal(size=(3, 1, 32)), jnp.float32)
     rows = (None, jnp.asarray([7, 0, 3]), jnp.asarray([1, 0, 1]))
-    _, s1, c1 = pipeline_ops._kda_layer(blk, p, h, state, conv, 2, rows)
+    _, s1, c1, _ = pipeline_ops._kda_layer(blk, p, h, state, conv, 2, rows)
     for arr0, arr1 in ((state, s1), (conv, c1)):
         a0, a1 = np.asarray(arr0), np.asarray(arr1)
         assert (a0[[0, 1, 3]] == a1[[0, 1, 3]]).all()     # other layers
@@ -208,7 +208,8 @@ def test_a_row_with_no_valid_token_leaves_state_and_history_alone():
     # prefill rows: row 0 -> slot 1 from position 0, row 1 is padding
     hp = jnp.asarray(rng.normal(size=(2, 8, 32)), jnp.float32)
     rows = (jnp.asarray([1, 3]), jnp.asarray([0, 0]), jnp.asarray([5, 0]))
-    ctx, s2, c2 = pipeline_ops._kda_layer(blk, p, hp, state, conv, 0, rows)
+    ctx, s2, c2, _ = pipeline_ops._kda_layer(blk, p, hp, state, conv, 0,
+                                             rows)
     zero = pipeline_ops._kda_layer(blk, p, hp, jnp.zeros_like(state),
                                    jnp.zeros_like(conv), 0, rows)
     np.testing.assert_array_equal(ctx[0, :5], zero[0][0, :5])

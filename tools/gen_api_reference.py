@@ -47,7 +47,11 @@ MODULES = [
     ("paddle_tpu.dataset", "Datasets"),
     ("paddle_tpu.data_feeder", "Batch -> feed-dict conversion"),
     ("paddle_tpu.master", "Fault-tolerant task dispatch (native)"),
-    ("paddle_tpu.serving", "Model server: dynamic & continuous batching"),
+    ("paddle_tpu.serving",
+     "Model server: dynamic & continuous batching; GenerationEngine(spec, "
+     "scope, slots=, page_size=, n_pages=, prefill_chunk=, ..; "
+     "snapshot_stride=<pages>, n_snapshots=<rows>: state snapshots, the "
+     "prefix index for a spec whose slots carry a recurrent state)"),
     ("paddle_tpu.serving.fleet",
      "Multi-replica fleet: retries/hedging, breakers, load shedding, "
      "rolling weight updates"),
@@ -67,9 +71,15 @@ MODULES = [
      "The stacked LM's model spec: Block (what a block computes), LMSpec "
      "(plus sizes), RopeScaling; attention mha | mla, a shared expert, a "
      "held share of the router's experts; a layer_pattern over attention "
-     "kinds (kda | mla: planes by kind, slot_state = what a serving slot "
-     "holds beside its pages), first_dense leading dense layers, the "
-     "router's score / bias / groups"),
+     "kinds (kda | mla | gqa: planes by kind, kda beside ONE kind that "
+     "caches tokens; gqa = grouped-query K/V pages without positions, "
+     "attn_gate head | channel; kda_decay bounded | softplus, "
+     "kda_neg_eigval, kda_proj_rank; slot_state = what a serving slot "
+     "holds beside its pages, which GenerationEngine(snapshot_stride=, "
+     "n_snapshots=) also keeps as snapshot rows for its prefix index; "
+     "require_stateless still refuses beams, resume, the slot handoff and "
+     "share_cache_with=), first_dense leading dense layers, the router's "
+     "score / bias / groups"),
     ("paddle_tpu.ops.moe_ops",
      "The expert layer: moe_topk (dropless top-k; shared=, held=, "
      "routed_scale=; score= softmax | sigmoid, bias=, n_group=, "
