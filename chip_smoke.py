@@ -211,8 +211,9 @@ def phase_train(cfg):
     EndIteration in batch order, the stack is traced once for forward
     and backward (core/backward.py), and on a TPU the compiled step holds
     TWO loops (forward scan, backward scan) running the Mosaic flash
-    kernels: two ``flash_fwd`` a layer (forward, remat recompute), one
-    ``flash_dq``, one ``flash_dkv``, each on bf16 operands (AMP is on)."""
+    kernels: ONE ``flash_fwd`` a layer (the layer checkpoint saves the
+    call's residuals: no recompute), one ``flash_dq``, one ``flash_dkv``,
+    each on bf16 operands (AMP is on)."""
     t0 = time.perf_counter()
     tr = LMTrainer(cfg)
     sync_losses, sync_order = tr.train(cfg["sync_steps"], seed=SEED)
@@ -242,11 +243,12 @@ def phase_train(cfg):
               "step: one forward + one backward scan expected")
         # the names kernels/flash_attention.py gives its pallas_calls;
         # none at all means the kernels gave way to the jnp reference
-        check(dict(kernels) == {"flash_fwd": 2, "flash_dq": 1,
+        check(dict(kernels) == {"flash_fwd": 1, "flash_dq": 1,
                                 "flash_dkv": 1},
               f"Mosaic calls of the compiled train step {dict(kernels)}: "
-              "expected two flash_fwd a layer (forward scan, remat "
-              "recompute), one flash_dq, one flash_dkv")
+              "expected one flash_fwd a layer (the forward scan's; a "
+              "second is remat running the kernel again), one flash_dq, "
+              "one flash_dkv")
         f32_fed = flash_operands_not_bf16(
             calls, cfg["batch"] * cfg["heads"], cfg["seq"],
             cfg["d_model"] // cfg["heads"])

@@ -474,6 +474,8 @@ def _stack_cost(attrs, ins, outs):
     weight_bytes = 0.0
     L = 1.0
     for slot, arrs in (ins or {}).items():
+        if slot == "X":     # (the activation is [b, T, d], no weight plane)
+            continue
         for w in arrs:
             weight_bytes += _nbytes(w)
             if len(w.shape) == 3:  # [L, in, out] matmul plane
@@ -482,11 +484,22 @@ def _stack_cost(attrs, ins, outs):
     t = float(x.shape[-2]) if len(x.shape) >= 2 else 1.0
     flops += L * 2.0 * b_t * t * d  # attention score+context contractions
     remat = attrs.get("remat", False)
-    # saved per token per layer, in units of d (see ops/pipeline_ops.py):
-    # full save ~14d (every interior), "dots" ~9d (GEMM outputs), remat
-    # all-or-nothing saves only the layer input carry (1d).
-    per_tok_d = 1.0 if remat is True else (9.0 if remat == "dots" else 14.0)
-    residual = L * b_t * per_tok_d * d * itemsize
+    # saved per token per layer (see ops/pipeline_ops.py): "full" the
+    # stream alone (1 d); True the stream and ``_STACK_SAVED``: the flash
+    # call's q, k, v (expanded to the query heads) and result and the
+    # out-projection's result, each in the matmuls' operand dtype (5 d of
+    # bf16 under AMP for the GPT-2 block); False every interior, ~14 d.
+    if remat == "full":
+        per_tok = d * itemsize
+    elif remat:
+        from ..ops.common import amp_enabled
+
+        out_w = _first(ins, "OutW")                         # [L, H*dh, d]
+        planes = 4.0 * out_w.shape[-2] + out_w.shape[-1]
+        per_tok = d * itemsize + planes * (2 if amp_enabled() else itemsize)
+    else:
+        per_tok = 14.0 * d * itemsize
+    residual = L * b_t * per_tok
     return OpCost(flops=flops,
                   bytes=_nbytes(x) + weight_bytes + _slot_bytes(outs),
                   residual_bytes=residual)

@@ -25,6 +25,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 # Blocks: 512 x 512 for the three kernels, whatever the operand type.
 # `tools/flash_block_sweep.py` on a v5e under JAX 0.9.0 (PR 41), causal,
@@ -537,16 +538,40 @@ def _attention(q, k, v, lengths, causal, sm_scale):
     return reference_attention(q, k, v, lengths, causal, sm_scale)
 
 
+#: ``checkpoint_name`` tags of what the backward reads: the operands as
+#: the kernel was handed them, its result and its logsumexp. A
+#: ``jax.checkpoint`` whose policy saves these names (the layer scan of
+#: ``ops/pipeline_ops.py``) keeps the call's OWN residuals, so its backward
+#: runs no second forward; a name on the caller's copy of the result
+#: cannot do that, logsumexp never leaves this file. Outside such a
+#: checkpoint the tags lower to nothing.
+RESIDUAL_NAMES = ("flash_qkv", "flash_out", "flash_lse")
+
+
 def _attention_fwd(q, k, v, lengths, causal, sm_scale):
+    tag_qkv, tag_out, tag_lse = RESIDUAL_NAMES
     if jax.default_backend() == "tpu":
         qp, kp, vp, lens, Tq = _pad_to_lanes(q, k, v, lengths)
         out, lse = _flash_forward(qp, kp, vp, lens, causal, sm_scale,
                                   DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K,
                                   interpret=False)
+        qp, kp, vp = checkpoint_name((qp, kp, vp), tag_qkv)
+        # the result is tagged MERGED, [B, T, H * D] (the merge its caller
+        # makes anyway): a scan that saves [B, H, T, 64] stacks it with
+        # each 64-wide row padded to the 128 lanes, twice the bytes
+        # (0.4 GB and 0.75 points of train_mfu in gpt2m-train, PERF.md)
+        B, H, T, D = out.shape
+        merged = checkpoint_name(
+            out.transpose(0, 2, 1, 3).reshape(B, T, H * D), tag_out)
+        out = merged.reshape(B, T, H, D).transpose(0, 2, 1, 3)
+        lse = checkpoint_name(lse, tag_lse)
         return out[:, :, :Tq], (qp, kp, vp, out, lse, lens,
                                 (Tq, k.shape[2]))
-    return (reference_attention(q, k, v, lengths, causal, sm_scale),
-            (q, k, v, None, None, lengths, None))
+    # (no logsumexp here: the backward differentiates the reference)
+    q, k, v = checkpoint_name((q, k, v), tag_qkv)
+    out = checkpoint_name(
+        reference_attention(q, k, v, lengths, causal, sm_scale), tag_out)
+    return out, (q, k, v, None, None, lengths, None)
 
 
 def _attention_bwd(causal, sm_scale, res, g):

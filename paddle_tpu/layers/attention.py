@@ -200,7 +200,15 @@ def pipelined_transformer_stack(x, n_layers=None, num_heads=None, d_ff=None,
     keywords describe the GPT-2 block (pre-LN LayerNorm, GELU 4x FFN). A
     ``swiglu_moe`` spec returns ``(out, aux_loss)``: the load-balance
     loss summed over the layers (add ``spec.router_aux_loss_coef *
-    aux_loss`` to the objective)."""
+    aux_loss`` to the objective).
+
+    ``remat``: what the layer scan keeps for its backward. ``False``:
+    every interior of every layer; ``True``: the stream, the attention
+    call's own residuals and the out-projection's result, in the matmuls'
+    operand dtype (5 d a token a layer, bf16 under AMP: the backward
+    rebuilds the attention half elementwise and runs the FFN's first
+    matmul, or the expert layer, again); ``"full"``: the stream alone,
+    the backward runs each layer's forward again."""
     from ..lm_spec import LMSpec
     from ..param_attr import ParamAttr
 

@@ -13,17 +13,33 @@ here placement is a sharding spec and movement is an ICI ppermute.
 """
 from __future__ import annotations
 
+import collections
+
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
+from .. import trace
 from ..core.registry import register_op
-from ..kernels.flash_attention import flash_attention, rotary
+from ..kernels.flash_attention import (RESIDUAL_NAMES, flash_attention,
+                                       rotary)
 from ..lm_spec import (OPTIONAL_STACK_SLOTS, SNAPSHOT_SLOTS, STATE_SLOTS,
                        Block, BlockNotSupportedError)
 from .common import amp_cast, maybe, mxu_precision, out, single
 from .moe_ops import moe_topk
 
 _EPS = 1e-5
+#: what the train stack's layer checkpoint saves a layer under
+#: ``remat=True``, beside the stream the scan carries: the flash call's own
+#: residuals (its operands, result and logsumexp) and the out-projection's
+#: result, tagged before its upcast (``_mm``), so under AMP each saved
+#: plane is the bf16 value the step rounds to anyway: 5 d a token. From
+#: these and the stream the backward rebuilds the attention half
+#: elementwise and runs ONE forward matmul again, the FFN's first (its
+#: result before bias and GELU is 4 d a token more and did not fit GPT-2
+#: medium at 8 x 1024 on a v5e beside the rest: PERF.md section 5 has
+#: the table of sets tried). An expert layer is recomputed likewise.
+_STACK_SAVED = RESIDUAL_NAMES + ("attn_out",)
 _LM_OPTIONAL = ("PosEmb", "FinalLnB") + OPTIONAL_STACK_SLOTS
 
 
@@ -51,11 +67,12 @@ def _norm(blk, x, scale, bias=None):
     return _ln(x, scale, bias, blk.norm_eps)
 
 
-def _mm(blk, eq, a, w):
+def _mm(blk, eq, a, w, name=None):
     """``einsum(eq, a, w)`` back in a.dtype (the float32 residual
-    stream). float32 weights: bf16 operands under AMP and a result rounded
-    through bf16 (``preferred_element_type`` None), as the GPT-2 block
-    always did. Weights STORED in bf16 are used as they are with float32
+    stream); ``name``: the ``checkpoint_name`` of the contraction's own
+    result, before that upcast (``_STACK_SAVED``). float32 weights: bf16
+    operands under AMP and a result rounded through bf16
+    (``preferred_element_type`` None), as the GPT-2 block always did. Weights STORED in bf16 are used as they are with float32
     accumulation (no float32 copy of a stored-bf16 weight is ever made on
     the device; without AMP jnp promotes inside the contraction). Which of
     the two rules holds is decided by the dtype the weight is STATED in,
@@ -67,8 +84,11 @@ def _mm(blk, eq, a, w):
     a_c, w_c = amp_cast(a, w)
     stated = jnp.dtype(blk.param_dtype) if blk.param_dtype else w.dtype
     pref = jnp.float32 if stated == jnp.bfloat16 else None
-    return jnp.einsum(eq, a_c, w_c, precision=mxu_precision(),
-                      preferred_element_type=pref).astype(a.dtype)
+    y = jnp.einsum(eq, a_c, w_c, precision=mxu_precision(),
+                   preferred_element_type=pref)
+    if name is not None:
+        y = checkpoint_name(y, name)
+    return y.astype(a.dtype)
 
 
 def _stack_params(blk, ins):
@@ -87,13 +107,10 @@ def _block(blk, p, x, causal, rope=None):
     None: as ``blk.use_rope`` says). A window layer is the causal block
     here: the callers hold T to the window."""
     b, T, _ = x.shape
-    from jax.ad_checkpoint import checkpoint_name
-
     q, k, v = _attn_proj(blk, p, x, rope=rope)
     k, v = _expand_kv(k, v, blk.num_heads)
     ctx = flash_attention(q, k, v, causal=causal, sm_scale=_sm_scale(blk))
-    ctx = checkpoint_name(ctx.transpose(0, 2, 1, 3).reshape(b, T, -1),
-                          "attn_ctx")
+    ctx = ctx.transpose(0, 2, 1, 3).reshape(b, T, -1)
     return _attn_out_ffn(blk, p, x, ctx)
 
 
@@ -151,11 +168,8 @@ def _attn_proj(blk, p, h, pos0=0, rope=None):
     b, t, d = h.shape
     head_d = blk.dh(d)
     d_q, d_kv = head_d * num_heads, head_d * num_kv_heads
-    from jax.ad_checkpoint import checkpoint_name
-
     hn = _norm(blk, h, p["ln1_s"], p.get("ln1_b"))
-    qkv = checkpoint_name(_mm(blk, "btd,de->bte", hn, p["qkv_w"]),
-                          "qkv_proj")
+    qkv = _mm(blk, "btd,de->bte", hn, p["qkv_w"])
     q = qkv[..., :d_q]
     k = qkv[..., d_q:d_q + d_kv]
     v = qkv[..., d_q + d_kv:]
@@ -258,12 +272,10 @@ def _attn_out_ffn(blk, p, x, ctx, out_key="out_w", dense=False):
     expression the attention half computed, which XLA shares. A stack held
     by kind names the layer's out-projection (``out_key``) and whether it
     is one of the leading ``dense`` SwiGLU layers."""
-    from jax.ad_checkpoint import checkpoint_name
-
     early = blk.is_moe and blk.router_input == "attn_input"
     router_x = _norm(blk, x, p["ln1_s"], p.get("ln1_b")) if early else None
-    x = x + checkpoint_name(_mm(blk, "btd,de->bte", ctx.astype(x.dtype),
-                                p[out_key]), "attn_out")
+    x = x + _mm(blk, "btd,de->bte", ctx.astype(x.dtype), p[out_key],
+                name="attn_out")
     h2 = _norm(blk, x, p["ln2_s"], p.get("ln2_b"))
     if dense:
         ff = jax.nn.silu(_mm(blk, "btd,df->btf", h2, p["dense_gate_w"])) \
@@ -297,11 +309,27 @@ def _attn_out_ffn(blk, p, x, ctx, out_key="out_w", dense=False):
     ff = _mm(blk, "btd,df->btf", h2, p["ff_w1"])
     if blk.bias:
         ff = ff + p["ff_b1"]
-    ff = checkpoint_name(jax.nn.gelu(ff), "ffn_hidden")
-    ff = _mm(blk, "btf,fd->btd", ff, p["ff_w2"])
+    ff = _mm(blk, "btf,fd->btd", jax.nn.gelu(ff), p["ff_w2"])
     if blk.bias:
         ff = ff + p["ff_b2"]
     return x + ff, None
+
+
+def _saved_bytes(body, carry, layer_p):
+    """Bytes of the planes one layer's backward holds of its forward
+    beside its arguments (the stream and the layer's weights): what the
+    layer checkpoint saves, as JAX reports it, traced abstractly."""
+    held = jax.eval_shape(lambda c, p: jax.vjp(body, c, p)[1], carry, layer_p)
+    args = collections.Counter(
+        (a.shape, a.dtype)
+        for a in jax.tree_util.tree_leaves((carry, layer_p)))
+    saved = 0
+    for a in jax.tree_util.tree_leaves(held):
+        if args[a.shape, a.dtype]:      # an argument read again
+            args[a.shape, a.dtype] -= 1
+        else:
+            saved += a.size * a.dtype.itemsize
+    return saved
 
 
 # the GPT-2 block's ten planes (the seq2seq family's encoder / decoder
@@ -350,6 +378,10 @@ def pipelined_transformer_stack(attrs, ins):
     causal = attrs.get("causal", True)
 
     remat = attrs.get("remat", False)
+    if remat not in (False, True, "full"):
+        raise ValueError(f"remat {remat!r}: False (save everything), True "
+                         "(the stream and _STACK_SAVED) or 'full' (the "
+                         "stream only)")
     if blk.is_mla or blk.experts_held is not None or blk.attn_kinds:
         raise BlockNotSupportedError(
             "pipelined_transformer_stack (training) was never held to a "
@@ -361,25 +393,31 @@ def pipelined_transformer_stack(attrs, ins):
     _hold_to_window(blk, x.shape[1], "pipelined_transformer_stack")
 
     def scan_stats(p, h):
-        def wrap(body, **kw):
-            if remat == "dots":
-                # Selective policy: keep each layer's big GEMM outputs
-                # (qkv/attn-out/ctx/ffn-hidden) resident and recompute only
-                # the cheap elementwise/LN work in the backward — the
-                # all-or-nothing form re-runs every forward matmul per
-                # layer (one stack forward of the step's four, PERF.md
-                # section 5). Never run on a chip: ROADMAP S5.
-                return jax.checkpoint(
-                    body,
-                    policy=jax.checkpoint_policies.save_only_these_names(
-                        "qkv_proj", "attn_ctx", "attn_out", "ffn_hidden"),
-                    **kw)
-            return jax.checkpoint(body, **kw) if remat else body
+        def body(carry, layer_p, kind):
+            return _block(blk, layer_p, carry, causal, kind and kind[1])
 
-        return _scan_stack(
-            blk.kinds, wrap(lambda carry, layer_p, kind: _block(
-                blk, layer_p, carry, causal, kind and kind[1]),
-                static_argnums=(2,)), h, p)
+        if remat:
+            # "full" (no policy): the stream only, the backward runs the
+            # whole layer forward again (a model whose saved set does not
+            # fit); True: the stream and _STACK_SAVED
+            body = jax.checkpoint(
+                body, static_argnums=(2,), policy=None if remat == "full"
+                else jax.checkpoint_policies.save_only_these_names(
+                    *_STACK_SAVED))
+        scanned = _scan_stack(blk.kinds, body, h, p)
+        span = trace.current_span()
+        if span is not None:
+            # gauge: the saved planes' bytes over the scanned layers. AFTER
+            # the scan: the body's traced form is cached with the source
+            # locations of whoever traces it first, and they reach the
+            # compile cache's key through the Mosaic calls
+            layer_p = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), p)
+            kind = blk.kinds[0] if blk.kinds else None
+            span.set_attr("mem/stack_saved_bytes",
+                          p["ln1_s"].shape[0] * _saved_bytes(
+                              lambda c, lp: body(c, lp, kind)[0], h, layer_p))
+        return scanned
 
     def scan_layers(p, h):
         return scan_stats(p, h)[0]

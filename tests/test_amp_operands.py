@@ -344,12 +344,16 @@ def test_seq2seq_engine_copies_only_what_its_ops_cast():
 # ---------------------------------------------------------------------------
 # the train op: live float32 master weights, whose cast is work
 # ---------------------------------------------------------------------------
-def _parent_mm(blk, eq, a, w):
-    """``_mm`` as it was before a block could state its weights' dtype."""
+def _parent_mm(blk, eq, a, w, name=None):
+    """``_mm`` as it was before a block could state its weights' dtype
+    (``name``: the tag the layer checkpoint saves a result under)."""
     a_c, w_c = pipeline_ops.amp_cast(a, w)
     pref = jnp.float32 if w.dtype == jnp.bfloat16 else None
-    return jnp.einsum(eq, a_c, w_c, precision=pipeline_ops.mxu_precision(),
-                      preferred_element_type=pref).astype(a.dtype)
+    y = jnp.einsum(eq, a_c, w_c, precision=pipeline_ops.mxu_precision(),
+                   preferred_element_type=pref)
+    if name is not None:
+        y = pipeline_ops.checkpoint_name(y, name)
+    return y.astype(a.dtype)
 
 
 def _train_step_text():

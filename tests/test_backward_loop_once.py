@@ -78,7 +78,7 @@ def _moe_spec():
 # --------------------------------------------------------------------------
 # structure
 # --------------------------------------------------------------------------
-@pytest.mark.parametrize("remat", [True, False, "dots"])
+@pytest.mark.parametrize("remat", [True, False, "full"])
 def test_stacked_train_step_holds_two_loops(remat):
     """One forward scan + one backward scan, whatever the remat policy
     (the parent compiled three: the forward op's scan, the generic grad
@@ -267,7 +267,7 @@ def _stack_only(remat=True, spec=None):
 
 
 PARITY = {"remat": dict(remat=True), "plain": dict(remat=False),
-          "dots": dict(remat="dots"), "moe": dict(spec=_moe_spec())}
+          "full": dict(remat="full"), "moe": dict(spec=_moe_spec())}
 
 
 @pytest.mark.parametrize("case", sorted(PARITY))
@@ -299,7 +299,7 @@ def test_three_adam_steps_match_value_and_grad_bitwise(case):
     assert losses[-1] < losses[0]
 
 
-@pytest.mark.parametrize("case", ["remat", "plain", "dots", "moe",
+@pytest.mark.parametrize("case", ["remat", "plain", "full", "moe",
                                   "interpreted"])
 def test_lm_train_steps_match_the_unpaired_program_bitwise(case,
                                                            monkeypatch):
@@ -310,7 +310,7 @@ def test_lm_train_steps_match_the_unpaired_program_bitwise(case,
         monkeypatch.setattr(get_op(STACK), "has_loop", paired)
         main, startup, loss, _ = (
             _lm(True, spec=_moe_spec()) if case == "moe" else
-            _lm({"remat": True, "plain": False, "dots": "dots",
+            _lm({"remat": True, "plain": False, "full": "full",
                  "interpreted": True}[case]))
         assert len(backward.vjp_pairs(main.global_block.ops)) == int(paired)
         level = 2 if case == "interpreted" else None
@@ -319,6 +319,133 @@ def test_lm_train_steps_match_the_unpaired_program_bitwise(case,
 
     for a, b in zip(run(True), run(False)):
         np.testing.assert_array_equal(a, b)
+
+
+# --------------------------------------------------------------------------
+# what the layer checkpoint saves (ops/pipeline_ops._STACK_SAVED)
+# --------------------------------------------------------------------------
+def _count(jaxpr, primitive):
+    """Equations of ``primitive`` in ``jaxpr`` and every jaxpr inside it."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == primitive
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _count(sub, primitive)
+    return n
+
+
+def _stack_grad(remat, spec=None):
+    """-> (grad of a scalar of the stack OP w.r.t. its inputs, as a
+    function of them; the inputs)"""
+    spec = spec or LMSpec(vocab_size=0, d_model=D, n_layers=3, num_heads=H,
+                          d_ff=FF)
+    rng = np.random.RandomState(3)
+    ins = {slot: [jnp.asarray(0.2 * rng.randn(spec.n_layers, *shape)
+                              + (key.endswith("_s")), jnp.float32)]
+           for slot, key, shape, _ in spec.stack_planes()}
+    ins["X"] = [jnp.asarray(rng.randn(B, T, D), jnp.float32)]
+    attrs = dict(spec.block.attrs(), causal=True, remat=remat)
+    kernel = get_op(STACK).fn
+
+    def loss(ins):
+        outs = kernel(attrs, ins)
+        return sum(jnp.sum(jnp.square(v[0])) for v in outs.values())
+
+    return jax.grad(loss), ins
+
+
+@pytest.mark.parametrize("amp", [False, True])
+def test_saved_set_spares_the_backward_the_attention_half(amp):
+    """The gradient's jaxpr of the stack op over a dense block: with
+    nothing rematerialized 20 ``dot_general`` over the scan and the
+    attention forward's ``exp`` twice (the forward's, and the one the CPU
+    reference's backward differentiates); ``remat=True`` runs ONE product
+    more, the FFN's first matmul, and no attention product and no qkv or
+    out projection again; ``"full"`` runs five more and the softmax once
+    more. ``"dots"``, the set this one replaces, also counted 21, but
+    named the kernel's result OUTSIDE the custom VJP: on a chip the flash
+    forward, which alone yields logsumexp, still ran twice."""
+    pt.set_amp(amp)     # (conftest's autouse fixture puts it back)
+    counts = {}
+    for remat in (False, True, "full"):
+        grad, ins = _stack_grad(remat)
+        jaxpr = jax.make_jaxpr(grad)(ins).jaxpr
+        counts[remat] = (_count(jaxpr, "dot_general"), _count(jaxpr, "exp"))
+    assert counts[False] == (20, 2)
+    assert counts[True] == (21, 2)
+    assert counts["full"] == (25, 3)
+
+
+def test_saved_set_of_an_expert_block_is_its_attention_half():
+    """A ``swiglu_moe`` block tags nothing in its expert layer: ``True``
+    spares the backward the attention forward and the out-projection
+    (fewer products than ``"full"``) and recomputes the experts (more
+    than with nothing rematerialized)."""
+    def dots(remat):
+        grad, ins = _stack_grad(remat, _moe_spec())
+        jaxpr = jax.make_jaxpr(grad)(ins).jaxpr
+        return (_count(jaxpr, "dot_general") + _count(jaxpr, "ragged_dot"),
+                _count(jaxpr, "exp"))
+
+    plain, saved, full = dots(False), dots(True), dots("full")
+    assert plain[0] < saved[0] < full[0]
+    assert saved[1] < full[1]
+
+
+@pytest.mark.parametrize("amp", [False, True])
+@pytest.mark.parametrize("block", ["dense", "moe"])
+def test_gradients_agree_across_remat_policies(block, amp):
+    """``True`` and ``"full"`` change what is kept, never the
+    mathematics: every input's gradient equals the unrematerialized
+    one's, bit for bit on the CPU without AMP as in PARITY's cases; under
+    AMP to float32's last bits (XLA fuses a recomputed norm or GELU into
+    other neighbours than the forward's)."""
+    pt.set_amp(amp)     # (conftest's autouse fixture puts it back)
+    spec = _moe_spec() if block == "moe" else None
+    got = {}
+    for remat in (False, True, "full"):
+        grad, ins = _stack_grad(remat, spec)
+        got[remat] = jax.jit(grad)(ins)
+    tol = dict(rtol=1e-5, atol=2e-5) if amp else dict(rtol=0, atol=0)
+    for remat in (True, "full"):
+        for slot, (g,) in got[remat].items():
+            np.testing.assert_allclose(
+                np.asarray(g), np.asarray(got[False][slot][0]),
+                err_msg=f"{remat} {slot}", **tol)
+
+
+def test_compile_span_carries_the_saved_planes_bytes():
+    """``mem/stack_saved_bytes`` on the ``executor/compile`` span of a
+    train step: what the layers' backward holds beside stream and weights.
+    ``True``: 5 d a token a layer (q, k, v, the kernel's result, the
+    out-projection's) in float32 without AMP; ``"full"``: nothing."""
+    from paddle_tpu import trace
+
+    tracer = trace.get_tracer()
+    got = {}
+    for remat in (True, "full"):
+        main, startup, loss, _ = _lm(remat)
+        scope, exe = pt.Scope(), pt.Executor(pt.TPUPlace())
+        exe.run(startup, scope=scope)
+        tracer.clear()
+        trace.enable(level=1)
+        try:
+            exe.run(main, feed=_lm_feed(), fetch_list=[loss], scope=scope)
+            got[remat], = [s.attrs["mem/stack_saved_bytes"]
+                           for s in tracer.spans()
+                           if s.name == "executor/compile"]
+        finally:
+            trace.disable()
+            tracer.clear()
+    assert got == {True: L * B * T * 5 * D * 4, "full": 0}
+
+
+def test_unknown_remat_value_is_refused():
+    """``"dots"`` went with its set: a program that still states it
+    fails where it is traced, it does not fall back to another policy."""
+    grad, ins = _stack_grad("dots")
+    with pytest.raises(ValueError, match="remat 'dots'"):
+        grad(ins)
 
 
 def test_gpipe_branch_paired_matches_unpaired_bitwise(monkeypatch):

@@ -66,7 +66,7 @@ def test_train_phase_counts(smoke):
     # TPU the phase REQUIRES the three flash kernels
     assert rec["mosaic_calls_in_compiled_step"] == {}
     # the stack is traced once for forward and backward (on a TPU the
-    # phase also REQUIRES two loops and two flash_fwd calls a layer; the
+    # phase also REQUIRES two loops and ONE flash_fwd call a layer; the
     # CPU backend adds loops of its own for scatters and sorts)
     assert rec["while_loops_in_compiled_step"] >= 2
     assert rec["paired_vjp_ops"] == 1

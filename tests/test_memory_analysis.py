@@ -158,11 +158,12 @@ class TestSegmentsAndStack:
         kinds = {t.kind for t in mem_g.peak_live}
         assert "residual" in kinds or mem_g.peak_op_index is not None
 
-    @pytest.mark.parametrize("remat,rank", [(False, 2), ("dots", 1),
-                                            (True, 0)])
+    @pytest.mark.parametrize("remat,rank", [(False, 2), (True, 1),
+                                            ("full", 0)])
     def test_stacked_scan_residuals_follow_remat_policy(self, remat, rank):
         """pipelined_transformer_stack sizes its [L, ...] saved planes by
-        the remat attr: full save > "dots" > all-or-nothing remat."""
+        the remat attr: full save > True (the stream and the saved set of
+        ops/pipeline_ops.py) > "full" (the stream alone)."""
         def build():
             ids = layers.data("ids", shape=[32], dtype="int64")
             tgt = layers.data("tgt", shape=[32], dtype="int64")
@@ -182,6 +183,11 @@ class TestSegmentsAndStack:
                        if op.type == "pipelined_transformer_stack")
         cost = mem.op_costs[stack_i]
         assert cost is not None and cost.residual_bytes > 0
+        stream = 2 * 4 * 32 * 32 * 4        # L x tokens x d of float32
+        if remat == "full":
+            assert cost.residual_bytes == stream
+        elif remat is True:                 # + 5 d a token, float32 here
+            assert cost.residual_bytes == stream * 6
         # stash for cross-param comparison via the test cache
         key = "_stack_residuals"
         store = getattr(TestSegmentsAndStack, key, {})
@@ -189,6 +195,49 @@ class TestSegmentsAndStack:
         setattr(TestSegmentsAndStack, key, store)
         if len(store) == 3:
             assert store[0] < store[1] < store[2]
+
+    @pytest.mark.parametrize("amp", [True, False])
+    def test_stack_residuals_of_gpt2_medium_match_the_saved_planes(
+            self, amp):
+        """GPT-2 medium at 8 x 1024 tokens under ``remat=True``: beside
+        the float32 stream the analyzer holds 5 d a token in the matmuls'
+        operand dtype, 2.01 GB of bf16 under AMP over 24 layers (PERF.md
+        section 5: what the chip's compiler stacks) — and the op's own
+        gauge, which asks JAX what a layer's backward holds, reads the
+        same figure."""
+        import jax
+
+        from paddle_tpu.analysis.costmodel import op_cost
+        from paddle_tpu.lm_spec import LMSpec
+        from paddle_tpu.ops import pipeline_ops
+
+        pt.set_amp(amp)     # (conftest's autouse fixture puts it back)
+        L, b, T, d = 24, 8, 1024, 1024
+        spec = LMSpec(vocab_size=0, d_model=d, n_layers=L, num_heads=16,
+                      d_ff=4096)
+        blk = spec.block
+
+        def f32(*shape):
+            return jax.ShapeDtypeStruct(shape, "float32")
+
+        planes = spec.stack_planes()
+        ins = {slot: [f32(L, *shape)] for slot, _, shape, _ in planes}
+        x = f32(b, T, d)
+        cost = op_cost("pipelined_transformer_stack",
+                       dict(blk.attrs(), remat=True), {**ins, "X": [x]},
+                       {"Out": [x]})
+        stream = L * b * T * d * 4
+        saved = cost.residual_bytes - stream
+        assert saved == L * b * T * 5 * d * (2 if amp else 4)
+        if amp:
+            assert abs(saved - 2.01e9) < 0.1 * 2.01e9
+        body = jax.checkpoint(
+            lambda c, p: pipeline_ops._block(blk, p, c, True)[0],
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *pipeline_ops._STACK_SAVED))
+        assert L * pipeline_ops._saved_bytes(
+            body, x, {key: f32(*shape) for _, key, shape, _ in planes}
+        ) == saved
 
 
 # ==========================================================================

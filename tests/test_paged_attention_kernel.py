@@ -429,15 +429,22 @@ def test_decode_step_of_a_float32_model_under_amp_on_the_v5e_casts_no_stack(
         assert read_f32 and head_cast
 
 
+@pytest.mark.parametrize("remat,flash_fwd,matmuls", [(True, 1, 16),
+                                                     ("full", 2, 18)])
 def test_stacked_train_step_on_the_v5e_runs_its_forward_scan_once(
-        one_chip, monkeypatch):
+        one_chip, monkeypatch, remat, flash_fwd, matmuls):
     """The stacked LM's whole train step (GPT-2 medium's width, heads and
     context; two layers) compiled for the chip through the Executor: ONE
-    forward scan and one backward scan, and a layer's Mosaic calls are two
-    ``flash_fwd`` (forward, remat recompute), one ``flash_dq``, one
-    ``flash_dkv``. With the generic grad op tracing the stack a second
-    time (before core/backward.py paired them) the chip's compiler kept
-    three loops and three ``flash_fwd``: XLA does not merge two loops.
+    forward scan and one backward scan, and a layer's Mosaic calls are one
+    ``flash_dq``, one ``flash_dkv`` and ONE ``flash_fwd`` under
+    ``remat=True`` (the layer checkpoint saves the call's own residuals,
+    and of the forward's projections the backward scan's body holds the
+    FFN's first alone: the step's ``convolution`` instructions are the
+    forward's four, the backward's eight, that one and the head's three),
+    two under ``"full"`` (forward, recompute: and the qkv and out
+    projections again, 18). With the generic grad op tracing the stack a
+    second time (before core/backward.py paired them) the chip's compiler
+    kept three loops and three ``flash_fwd``: XLA does not merge two loops.
     Under AMP, as the train cells run it: every flash call takes bf16
     ``[rows, T, d_head]`` operands at the blocks the kernel file picks
     for them, so a block shape Mosaic refuses for bf16 fails HERE.
@@ -454,7 +461,7 @@ def test_stacked_train_step_on_the_v5e_runs_its_forward_scan_once(
         tgt = layers.data("tgt", shape=[T], dtype="int64")
         logits = models.transformer_lm(
             ids, vocab_size=V, d_model=1024, n_layers=2, num_heads=16,
-            max_len=T, pipeline_stack=True, remat=True)
+            max_len=T, pipeline_stack=True, remat=remat)
         loss = layers.mean(layers.softmax_with_cross_entropy(
             layers.reshape(logits, shape=[-1, V]),
             layers.reshape(tgt, shape=[-1, 1])))
@@ -470,21 +477,46 @@ def test_stacked_train_step_on_the_v5e_runs_its_forward_scan_once(
     # the rest of this worker's tests
     monkeypatch.setattr("paddle_tpu.core.executor."
                         "_maybe_enable_compilation_cache", lambda: None)
-    exe = pt.Executor(pt.TPUPlace())
-    assert exe.warm_signature(main, {"ids": ((B, T), "int64"),
-                                     "tgt": ((B, T), "int64")},
-                              [loss.name], scope=scope)
-    (compiled,) = exe._cache.values()
-    text = compiled.aot.as_text()
+    from paddle_tpu import trace
+
+    tracer = trace.get_tracer()
+    texts, gauges = [], []
+    # compiled twice from ONE call site (source locations are part of the
+    # text): as the cells run it, then with tracing on, as a traced run
+    for level in (0, 1) if remat is True else (0,):
+        exe = pt.Executor(pt.TPUPlace())
+        tracer.clear()
+        trace.enable(level=level)
+        try:
+            assert exe.warm_signature(main, {"ids": ((B, T), "int64"),
+                                             "tgt": ((B, T), "int64")},
+                                      [loss.name], scope=scope)
+            gauges += [s.attrs["mem/stack_saved_bytes"]
+                       for s in tracer.spans() if s.name == "executor/compile"]
+        finally:
+            trace.disable()
+            tracer.clear()
+        assert exe.cache_stats()["paired_vjp_ops"] == 1
+        (compiled,) = exe._cache.values()
+        texts.append(compiled.aot.as_text())
+    text = texts[0]
     assert len(re.findall(r"= .* while\(", text)) == 2
     calls = chip_smoke.mosaic_calls(text)
     assert sorted(name for name, _ in calls) == [
-        "flash_dkv", "flash_dq", "flash_fwd", "flash_fwd"]
+        "flash_dkv", "flash_dq"] + ["flash_fwd"] * flash_fwd
+    assert text.count(" convolution(") == matmuls
     assert not chip_smoke.flash_operands_not_bf16(calls, B * 16, T, 64)
     # the check can fail: it tells a float32-fed call
     assert chip_smoke.flash_operands_not_bf16(
         [("flash_fwd", ["s32[32]"] + ["f32[32,1024,64]"] * 3)], 32, T, 64)
-    assert exe.cache_stats()["paired_vjp_ops"] == 1
+    if remat is True:
+        # with tracing on the op also asks JAX what a layer's backward
+        # holds (the gauge on the compile span: 5 d of bf16 a token a
+        # layer + logsumexp), and the step still compiles to the SAME
+        # text, source locations included: they reach the compile cache's
+        # key through the Mosaic calls
+        assert gauges == [2 * (B * T * 5 * 1024 * 2 + B * 16 * T * 4)]
+        assert texts[1] == text
 
 
 # ---------------------------------------------------------------------------
