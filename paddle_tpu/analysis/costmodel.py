@@ -604,7 +604,7 @@ _MOVEMENT = (
     "sequence_expand", "sequence_reshape", "sequence_reverse",
     "sequence_slice", "sequence_enumerate", "sub_nested_seq", "sub_seq",
     "array_read", "array_write", "assign_value", "one_hot",
-    "im2sequence", "unpool", "scale_sub_region", 
+    "im2sequence", "unpool", "scale_sub_region", "unpack_plane",
 )
 _ALIAS = (
     "assign", "reshape", "squeeze", "unsqueeze", "lod_reset",

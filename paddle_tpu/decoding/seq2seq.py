@@ -169,109 +169,58 @@ class Seq2SeqGenerationEngine(GenerationEngine):
         return ins
 
     # -- program construction ---------------------------------------------
-    @property
-    def _prefill_feed_names(self):
-        return super()._prefill_feed_names + ["serving.xslot",
-                                              "serving.src_len"]
+    def _plane_columns(self, tc):
+        """The paged engine's columns and, a row, the cross row it attends
+        and that row's source length (a row no request fills: the scrap
+        cross row, one position deep)."""
+        return super()._plane_columns(tc) + [
+            ("serving.xslot", "XSlot", 0, "int32", self.slots),
+            ("serving.src_len", "SrcLen", 0, "int32", 1)]
 
-    @property
-    def _decode_feed_names(self):
-        return super()._decode_feed_names + ["serving.xslot",
-                                             "serving.src_len"]
-
-    def _sampling_vars(self, rows):
-        ins = super()._sampling_vars(rows)
-        if rows is None:  # prefill: batch-dim scalars
-            xs = data_layer("serving.xslot", shape=[], dtype="int32")
-            sl = data_layer("serving.src_len", shape=[], dtype="int32")
-        else:
-            xs = data_layer("serving.xslot", shape=[rows], dtype="int32",
-                            append_batch_size=False)
-            sl = data_layer("serving.src_len", shape=[rows],
-                            dtype="int32", append_batch_size=False)
-        ins["XSlot"] = [xs]
-        ins["SrcLen"] = [sl]
-        return ins
-
-    def _neutral_sampling_feed(self, rows: int):
-        feed = super()._neutral_sampling_feed(rows)
-        # vacant rows attend the scrap cross row, one position deep
-        feed["serving.xslot"] = np.full(rows, self.slots, np.int32)
-        feed["serving.src_len"] = np.ones(rows, np.int32)
-        return feed
-
-    def _slot_sampling_feed(self, row, st, feed, step):
-        super()._slot_sampling_feed(row, st, feed, step)
+    def _slot_sampling_feed(self, row, st, cols, step):
+        super()._slot_sampling_feed(row, st, cols, step)
         if st.xrow is not None:
-            feed["serving.xslot"][row] = st.xrow
-            feed["serving.src_len"][row] = self._xrow_len[st.xrow]
+            cols["serving.xslot"][row] = st.xrow
+            cols["serving.src_len"][row] = self._xrow_len[st.xrow]
 
-    def _build_prefill(self, tc: int):
+    def _build(self, tc, rows, kind: str):
+        """The prefill program of chunk width ``tc`` or (``tc`` None) the
+        decode tick's: the paged engine's feeds into the cross-attention
+        twin of its op."""
         prog, startup = Program(), Program()
         with program_guard(prog, startup):
-            chunk = data_layer("serving.chunk", shape=[tc], dtype="int64")
-            start = data_layer("serving.start", shape=[], dtype="int32")
-            length = data_layer("serving.chunk_len", shape=[],
-                                dtype="int32")
-            table = data_layer("serving.block_table", shape=[self.pmax],
-                               dtype="int32")
-            helper = LayerHelper("serving_cross_prefill",
+            helper = LayerHelper(f"serving_cross_{kind}",
                                  main_program=prog,
                                  startup_program=startup)
+            ins = self._call_ins(helper, tc)
             ck, cv = self._cache_vars(helper)
             xk, xv = self._cross_cache_vars(helper)
             nxt = helper.block.create_var(
-                name="serving.next_tok", shape=[-1],
+                name="serving.next_tok", shape=[rows],
                 dtype="int64", stop_gradient=True)
-            ins = {"Chunk": [chunk], "StartPos": [start],
-                   "Lengths": [length], "BlockTable": [table],
-                   "CacheK": [ck], "CacheV": [cv],
-                   "CrossK": [xk], "CrossV": [xv]}
-            ins.update(self._sampling_vars(None))
+            ins.update({"CacheK": [ck], "CacheV": [cv],
+                        "CrossK": [xk], "CrossV": [xv]})
             ins.update(self._lm_ins(helper))
             ins.update(self._cross_weight_ins(helper))
             outs = {"NextTok": [nxt], "CacheK": [ck], "CacheV": [cv]}
-            outs.update(self._beam_out_vars(helper, 0, "serving.pf"))
-            helper.append_op("transformer_stack_cross_prefill", ins,
+            outs.update(self._beam_out_vars(
+                helper, rows,
+                "serving.pf" if kind == "prefill" else "serving.dec"))
+            helper.append_op(f"transformer_stack_cross_{kind}", ins,
                              outs, self._decode_attrs())
         fetches = [nxt.name] + [v[0].name for k, v in sorted(outs.items())
                                 if k in ("TopV", "TopI")]
-        self._transpile(prog, list(self._prefill_feed_names), fetches,
+        return prog, outs, fetches
+
+    def _build_prefill(self, tc: int):
+        prog, outs, fetches = self._build(tc, -1, "prefill")
+        self._transpile(prog, self._prefill_feed_names, fetches,
                         f"transpile/prefill{tc}/")
         return prog, outs
 
     def _build_decode(self):
-        prog, startup = Program(), Program()
-        with program_guard(prog, startup):
-            tok = data_layer("serving.tok", shape=[self.slots],
-                             dtype="int64", append_batch_size=False)
-            pos = data_layer("serving.pos", shape=[self.slots],
-                             dtype="int32", append_batch_size=False)
-            table = data_layer("serving.block_table",
-                               shape=[self.slots, self.pmax],
-                               dtype="int32", append_batch_size=False)
-            helper = LayerHelper("serving_cross_decode",
-                                 main_program=prog,
-                                 startup_program=startup)
-            ck, cv = self._cache_vars(helper)
-            xk, xv = self._cross_cache_vars(helper)
-            nxt = helper.block.create_var(
-                name="serving.next_tok",
-                shape=[self.slots], dtype="int64", stop_gradient=True)
-            ins = {"Tok": [tok], "Pos": [pos], "BlockTable": [table],
-                   "CacheK": [ck], "CacheV": [cv],
-                   "CrossK": [xk], "CrossV": [xv]}
-            ins.update(self._sampling_vars(self.slots))
-            ins.update(self._lm_ins(helper))
-            ins.update(self._cross_weight_ins(helper))
-            outs = {"NextTok": [nxt], "CacheK": [ck], "CacheV": [cv]}
-            outs.update(self._beam_out_vars(helper, self.slots,
-                                            "serving.dec"))
-            helper.append_op("transformer_stack_cross_decode", ins,
-                             outs, self._decode_attrs())
-        fetches = [nxt.name] + [v[0].name for k, v in sorted(outs.items())
-                                if k in ("TopV", "TopI")]
-        self._transpile(prog, list(self._decode_feed_names), fetches,
+        prog, outs, fetches = self._build(None, self.slots, "decode")
+        self._transpile(prog, self._decode_feed_names, fetches,
                         "transpile/decode/")
         return prog, outs
 

@@ -142,6 +142,29 @@ def split(attrs, ins):
     return {"Out": list(parts)}
 
 
+@register_op("unpack_plane")
+def unpack_plane(attrs, ins):
+    """Split a packed int32 plane X [rows, W] into its columns, left to
+    right: ``widths[i]`` columns become Out[i] [rows, widths[i]], or [rows]
+    where the width is 0 (one value a row). A column whose ``dtypes[i]``
+    is not int32 carries that type's BITS (the host wrote them with
+    ``.view(np.int32)``) and is bitcast back: exact, nothing is rescaled.
+    What lets a caller with many small feeds hand the executor one buffer
+    (``serving.generation.FeedPlane``)."""
+    x = single(ins, "X")
+    cols, at = [], 0
+    for width, dtype in zip(attrs["widths"], attrs["dtypes"]):
+        col = x[:, at:at + width] if width else x[:, at]
+        at += max(width, 1)
+        if dtype != "int32":
+            col = jax.lax.bitcast_convert_type(col, to_dtype(dtype))
+        cols.append(col)
+    if at != x.shape[1]:
+        raise ValueError(f"unpack_plane: the columns cover {at} of the "
+                         f"plane's {x.shape[1]}")
+    return {"Out": cols}
+
+
 @register_op("slice")
 def slice_op(attrs, ins):
     x = single(ins, "X")

@@ -64,6 +64,9 @@ STATE_SNAPSHOT = "serving.snapshot."
 # the bf16 tensor ``amp_cast`` would make of a float32 matmul weight, held
 # from load on: "serving.amp_operand.<the weight's name>"
 AMP_OPERAND = "serving.amp_operand."
+# the packed int32 feed of a decode tick and of a prefill call (``FeedPlane``)
+TICK_PLANE = "serving.tick"
+PREFILL_PLANE = "serving.prefill"
 #: scope -> {weight name: the array its operand copy was cast from}. The
 #: engines built on one scope share it: a copy is reused while the scope
 #: still holds that very array under the weight's name, and remade once it
@@ -130,6 +133,82 @@ def _default_prompt_buckets(tmax: int) -> List[int]:
         b *= 2
     buckets.append(tmax)
     return sorted(set(buckets))
+
+
+class FeedPlane:
+    """The ONE host buffer a paged call hands ``Executor.run``: an int32
+    plane ``[rows, width]`` whose columns are what the call's small feeds
+    used to be, each under the name it had alone. ``columns``: (name, op
+    slot, width (0: one value a row), dtype, what a row that no request
+    fills reads). A float32 column travels as its BITS and the program
+    bitcasts it back (``unpack_plane``), so a temperature or a top-p
+    arrives exactly. A host-to-device copy costs 0.10-0.15 ms on the
+    engine's thread whatever its size, so a call pays one where it paid
+    eleven (PERF.md section 6, PR 47)."""
+
+    def __init__(self, name: str, columns: Sequence[tuple]):
+        self.name = name
+        self.columns = list(columns)
+        self._at, at = {}, 0
+        for col, _, width, dtype, _ in self.columns:
+            self._at[col] = (at, width, np.dtype(dtype))
+            at += max(width, 1)
+        self.width = at
+        self._blank = np.zeros(at, np.int32)
+        for col, _, _, _, fill in self.columns:
+            self._view(self._blank[None], col)[...] = fill
+
+    def __contains__(self, col: str) -> bool:
+        return col in self._at
+
+    def _view(self, arr: np.ndarray, col: str) -> np.ndarray:
+        at, width, dtype = self._at[col]
+        return (arr[:, at:at + width] if width else arr[:, at]).view(dtype)
+
+    def new(self, rows: int) -> Tuple[np.ndarray, Dict[str, np.ndarray]]:
+        """A plane of ``rows`` unfilled rows and its columns by name
+        (views: what is written to them is written to the plane)."""
+        arr = np.empty((rows, self.width), np.int32)
+        arr[:] = self._blank
+        return arr, {col: self._view(arr, col) for col in self._at}
+
+    def declare(self, helper, rows: Optional[int]) -> Dict[str, list]:
+        """Declare the plane as the program's feed (``rows`` None: the
+        implicit batch axis of a prefill program) and split it into one
+        variable a column, named as the column: {op slot: [variable]}."""
+        batched = rows is None
+        plane = data_layer(self.name, shape=[self.width] if batched
+                           else [rows, self.width], dtype="int32",
+                           append_batch_size=batched)
+        ins = {slot: [helper.block.create_var(
+            name=col, shape=[-1 if batched else rows] + [width] * (width > 0),
+            dtype=dtype, stop_gradient=True)]
+            for col, slot, width, dtype, _ in self.columns}
+        helper.append_op(
+            "unpack_plane", {"X": [plane]},
+            {"Out": [var for (var,) in ins.values()]},
+            {"widths": [c[2] for c in self.columns],
+             "dtypes": [c[3] for c in self.columns]})
+        return ins
+
+
+class CallFeed(dict):
+    """What a paged call hands ``Executor.run``. Its items are the feeds:
+    the packed plane, and the mask where the programs take one. The plane's
+    ``columns`` stay readable under the names they had as feeds of their
+    own (``"serving.pos" in feed``, ``feed["serving.start"]``), for
+    whoever wraps ``Executor.run`` to watch a call's rows: the benchmark's
+    ``served_logprobs`` does."""
+
+    def __init__(self, feeds: dict, columns: Dict[str, np.ndarray]):
+        super().__init__(feeds)
+        self.columns = columns
+
+    def __contains__(self, name) -> bool:
+        return super().__contains__(name) or name in self.columns
+
+    def __missing__(self, name):
+        return self.columns[name]
 
 
 class RequestTimeline:
@@ -303,9 +382,9 @@ class GenerationEngine:
     lists (name, per-slot shape, dtype, layers); empty for every other
     spec): the engine keeps one scope array ``[layers, slots, *shape]`` of
     each, hands them to the prefill and decode ops as it hands the pools
-    (read and written in place; a prefill row names its slot in
-    ``serving.state_slot``), and counts them with the cache
-    (``mem/state_bytes_per_slot``, ``mem/state_bytes_live``,
+    (read and written in place; a prefill row names its slot in the
+    ``serving.state_slot`` column of the call's plane), and counts them
+    with the cache (``mem/state_bytes_per_slot``, ``mem/state_bytes_live``,
     ``cache_stats()``). A slot IS its state: admission allocates nothing,
     and a row whose first chunk starts at position 0 reads zeros whatever
     the slot's last tenant left. The state is held at the slot's LAST token
@@ -328,16 +407,27 @@ class GenerationEngine:
     its end, its first chunk starting from the row instead of the slot's
     state (``state_snapshots_restored``); what the pages matched beyond
     that boundary is prefilled again (``state_snapshot_cutback_tokens``).
-    Taking and restoring are two feeds of the prefill call, never a call
-    of their own. A row goes with its page when the index evicts it, or
-    alone when rows run out (``state_snapshots_evicted``); a row a slot
-    is about to start from is pinned. A prompt whose next boundary another
+    Taking and restoring are two columns of the prefill call's plane,
+    never a call of their own. A row goes with its page when the index
+    evicts it, or alone when rows run out (``state_snapshots_evicted``);
+    a row a slot is about to start from is pinned. A prompt whose next boundary another
     prefilling slot is ahead of it on WAITS for that slot
     (``state_prefix_waited``) and enters at the boundary once it is
     cached (``state_prefix_adopted``), so n arrivals over one cold prefix
     prefill it once. The stride times the page size is a multiple of the
     prefill chunk, so a request that enters at a boundary is chunked
     where the cold one was and serves the same bits.
+
+    **What a call feeds**: ONE host buffer, the packed int32 plane of its
+    rows (``FeedPlane``: token and position or chunk, start and length,
+    the sampling policy, the row's slot and snapshot rows, the block
+    table(s); ``serving.tick`` / ``serving.prefill``), which the program
+    splits back into the paged op's inputs (``unpack_plane``), and, with
+    ``mask_plane``, the ``[rows, vocab]`` mask: ones that live on the
+    device (``_ones``) unless a row of THAT call carries a logits
+    processor, when the host builds the plane for the call
+    (``mask_host_feeds`` counts those; ``decode_feed_host_arrays`` /
+    ``prefill_feed_host_arrays`` the host arrays handed over).
 
     **What stays resident** on the engine's device, all of it in the
     scope: the weights in the spec's stored dtype, the page pools, the
@@ -411,9 +501,10 @@ class GenerationEngine:
         # the decode/prefill programs; beam requests up to this width
         # then ride the one steady-state compile
         self.beam_width = int(beam_width)
-        # mask_plane=False drops the [slots, vocab] Mask feed from the
-        # programs (per-tick host->device bytes scale with vocab; turn
-        # it off for large-V deployments that never constrain decoding)
+        # mask_plane=False drops the [rows, vocab] Mask input from the
+        # programs: no request can then constrain its decoding. A call
+        # without a masked row feeds the device-resident plane of ones
+        # (``_ones``), so all it saves is that plane's device memory
         self.mask_plane = bool(mask_plane)
         # compile-cache/manifest namespace: a registry hosting several
         # resident models against ONE artifact directory keeps each
@@ -578,6 +669,8 @@ class GenerationEngine:
         self._init_cache()
 
         # -- programs ----------------------------------------------------
+        #: chunk width (None: the decode tick) -> its packed feed's layout
+        self._planes: Dict[Optional[int], FeedPlane] = {}
         self._prefill_progs: Dict[int, tuple] = {}
         self._page_copy_prog_cache: Dict[bool, tuple] = {}
         self._decode_prog = self._build_decode()
@@ -705,6 +798,15 @@ class GenerationEngine:
                     self.scope.set(name, jnp.zeros(shp, page_dtype))
                 for _, name, shp, dtype in self._state + self._snapshots:
                     self.scope.set(name, jnp.zeros(shp, to_dtype(dtype)))
+        #: rows -> the mask of a call none of whose rows is constrained:
+        #: ones [rows, vocab], on the device from here on, for the decode
+        #: batch and every prefill bucket ({} without the mask plane)
+        self._ones = {}
+        if self.mask_plane:
+            with self.executor.device_ctx():
+                self._ones = {
+                    rows: jnp.ones((rows, self.spec.vocab_size), jnp.float32)
+                    for rows in {self.slots, *self.prefill_batch_buckets}}
         self.metrics.set_gauge("mem/state_bytes_per_slot",
                                float(self.spec.state_bytes_per_slot))
         if self._snapshots:
@@ -761,37 +863,32 @@ class GenerationEngine:
             for slot, name, shape, dtype
             in self._state + (self._snapshots if snapshots else [])}
 
-    def _state_rows(self, slots_of_rows, rows: int,
-                    snaps=()) -> Dict[str, np.ndarray]:
-        """The prefill feed that names each row's slot, where the spec has
-        state ({} otherwise): a padding row points beyond the slots, so
-        its write is dropped. With a snapshot pool also, a row, the
+    def _state_rows(self, cols, slots_of_rows, snaps=()) -> None:
+        """Name each prefill row's slot in the call's plane, where the
+        spec has state (a padding row keeps a slot beyond the slots, so
+        its write is dropped); with a snapshot pool also, a row, the
         snapshot row its state starts from and the one it is copied into
         after the chunk (``snaps``: (from, take) a row, None for neither:
-        a value beyond the rows)."""
+        the column keeps a value beyond the rows)."""
         if not self._state:
-            return {}
-        ix = np.full(rows, self.slots, np.int32)
-        ix[:len(slots_of_rows)] = slots_of_rows
+            return
+        cols["serving.state_slot"][:len(slots_of_rows)] = slots_of_rows
         self.metrics.inc("kda_layer_calls", self._state_layers)
-        feed = {"serving.state_slot": ix}
         if self._snapshots:
-            at = np.full((2, rows), self.prefix_index.n_snapshots, np.int32)
             for row, pair in enumerate(snaps):
-                for j, v in enumerate(pair):
+                for col, v in zip(("serving.snap_from", "serving.snap_take"),
+                                  pair):
                     if v is not None:
-                        at[j, row] = v
-            feed["serving.snap_from"], feed["serving.snap_take"] = at
-        return feed
+                        cols[col][row] = v
 
-    def _window_io(self, helper, table):
-        """The window kind's op inputs and outputs (its pools, read and
-        written in place, and its table); nothing for a one-kind spec."""
+    def _window_pools(self, helper):
+        """The window kind's pools, op inputs and outputs alike (read and
+        written in place; its table is a column of the call's plane);
+        nothing for a one-kind spec."""
         if not self._by_kind:
-            return {}, {}
-        ckw, cvw = self._cache_vars(helper, window=True)
-        return ({"CacheKW": [ckw], "CacheVW": [cvw], "BlockTableW": [table]},
-                {"CacheKW": [ckw], "CacheVW": [cvw]})
+            return {}
+        return {slot: [v] for slot, v in zip(
+            ("CacheKW", "CacheVW"), self._cache_vars(helper, window=True))}
 
     def _lm_ins(self, helper):
         """The ops' weight slots; a weight with an AMP operand copy is
@@ -821,57 +918,65 @@ class GenerationEngine:
             attrs["emit_topk"] = self.beam_width
         return attrs
 
-    _SAMPLING_FEEDS = ("serving.temp", "serving.topk", "serving.topp",
-                       "serving.seed", "serving.step")
+    def _plane_columns(self, tc: Optional[int]) -> List[tuple]:
+        """The columns of a call's packed feed (``FeedPlane``): the decode
+        tick's (``tc`` None) or those of a prefill of chunk width ``tc``.
+        Its width follows what the engine knows as it builds the program:
+        the table's width, a second table by kind, a row's slot where
+        slots carry state, its snapshot rows where there is a pool."""
+        cols = ([("serving.tok", "Tok", 0, "int32", 0),
+                 ("serving.pos", "Pos", 0, "int32", 0)] if tc is None else
+                [("serving.chunk", "Chunk", tc, "int32", self.pad_id),
+                 ("serving.start", "StartPos", 0, "int32", 0),
+                 ("serving.chunk_len", "Lengths", 0, "int32", 0)])
+        # a row without a policy is greedy (warmup, vacant slots, padding)
+        cols += [("serving.topk", "TopK", 0, "int32", 0),
+                 ("serving.seed", "Seed", 0, "int32", 0),
+                 ("serving.step", "Step", 0, "int32", 0),
+                 ("serving.temp", "Temperature", 0, "float32", 0.0),
+                 ("serving.topp", "TopP", 0, "float32", 1.0)]
+        if tc is not None and self._state:
+            # a padding row points beyond the slots: its write is dropped
+            cols.append(("serving.state_slot", "StateSlot", 0, "int32",
+                         self.slots))
+            if self._snapshots:     # beyond the rows: neither
+                rows = self.prefix_index.n_snapshots
+                cols += [("serving.snap_from", "SnapFrom", 0, "int32", rows),
+                         ("serving.snap_take", "SnapTake", 0, "int32", rows)]
+        cols.append(("serving.block_table", "BlockTable", self.pmax,
+                     "int32", 0))
+        if self._by_kind:
+            cols.append(("serving.block_table_w", "BlockTableW", self.pmax,
+                         "int32", 0))
+        return cols
+
+    def _plane(self, tc: Optional[int]) -> FeedPlane:
+        """The packed feed of the decode tick (``tc`` None) or of the
+        prefill of chunk width ``tc``."""
+        if tc not in self._planes:
+            self._planes[tc] = FeedPlane(
+                TICK_PLANE if tc is None else PREFILL_PLANE,
+                self._plane_columns(tc))
+        return self._planes[tc]
 
     @property
     def _prefill_feed_names(self):
-        names = ["serving.chunk", "serving.start", "serving.chunk_len",
-                 "serving.block_table", *self._SAMPLING_FEEDS]
-        if self._state:
-            names.append("serving.state_slot")
-        if self._snapshots:
-            names += ["serving.snap_from", "serving.snap_take"]
-        if self._by_kind:
-            names.append("serving.block_table_w")
-        if self.mask_plane:
-            names.append("serving.mask")
-        return names
+        return [PREFILL_PLANE] + ["serving.mask"] * self.mask_plane
 
     @property
     def _decode_feed_names(self):
-        names = ["serving.tok", "serving.pos", "serving.block_table",
-                 *self._SAMPLING_FEEDS]
-        if self._by_kind:
-            names.append("serving.block_table_w")
-        if self.mask_plane:
-            names.append("serving.mask")
-        return names
+        return [TICK_PLANE] + ["serving.mask"] * self.mask_plane
 
-    def _sampling_vars(self, rows: Optional[int]):
-        """Declare the per-row sampling-plane feeds. ``rows`` is None for
-        batch-dim programs (prefill: the batch axis is implicit) or the
-        static slot count (decode)."""
-        batched = rows is None
-
-        def vec(name, dtype):
-            if batched:
-                return data_layer(name, shape=[], dtype=dtype)
-            return data_layer(name, shape=[rows], dtype=dtype,
-                              append_batch_size=False)
-
-        ins = {"Temperature": [vec("serving.temp", "float32")],
-               "TopK": [vec("serving.topk", "int32")],
-               "TopP": [vec("serving.topp", "float32")],
-               "Seed": [vec("serving.seed", "int32")],
-               "Step": [vec("serving.step", "int32")]}
+    def _call_ins(self, helper, tc: Optional[int]) -> Dict[str, list]:
+        """Declare a call's feeds: the packed plane, split into the
+        variables the paged ops take, and the [rows, vocab] mask."""
+        rows = self.slots if tc is None else None
+        ins = self._plane(tc).declare(helper, rows)
         if self.mask_plane:
             V = self.spec.vocab_size
-            mask = (data_layer("serving.mask", shape=[V], dtype="float32")
-                    if batched else
-                    data_layer("serving.mask", shape=[rows, V],
-                               dtype="float32", append_batch_size=False))
-            ins["Mask"] = [mask]
+            ins["Mask"] = [data_layer(
+                "serving.mask", shape=[V] if rows is None else [rows, V],
+                dtype="float32", append_batch_size=rows is None)]
         return ins
 
     def _expert_out_vars(self, helper):
@@ -934,77 +1039,41 @@ class GenerationEngine:
     def _build_prefill(self, tc: int):
         prog, startup = Program(), Program()
         with program_guard(prog, startup):
-            chunk = data_layer("serving.chunk", shape=[tc], dtype="int64")
-            start = data_layer("serving.start", shape=[], dtype="int32")
-            length = data_layer("serving.chunk_len", shape=[],
-                                dtype="int32")
-            table = data_layer("serving.block_table", shape=[self.pmax],
-                               dtype="int32")
             helper = LayerHelper("serving_paged_prefill", main_program=prog,
                                  startup_program=startup)
+            ins = self._call_ins(helper, tc)
             pools = self._pool_io(self._cache_vars(helper))
             nxt = helper.block.create_var(
                 name="serving.next_tok", shape=[-1],
                 dtype="int64", stop_gradient=True)
-            state = self._state_io(helper, snapshots=True)
-            ins = {"Chunk": [chunk], "StartPos": [start],
-                   "Lengths": [length], "BlockTable": [table], **pools,
-                   **state}
-            if state:
-                ins["StateSlot"] = [data_layer(
-                    "serving.state_slot", shape=[], dtype="int32")]
-            if self._snapshots:
-                ins["SnapFrom"] = [data_layer(
-                    "serving.snap_from", shape=[], dtype="int32")]
-                ins["SnapTake"] = [data_layer(
-                    "serving.snap_take", shape=[], dtype="int32")]
-            ins.update(self._sampling_vars(None))
-            ins.update(self._lm_ins(helper))
-            outs = {"NextTok": [nxt], **pools, **state}
-            if self._by_kind:
-                w_ins, w_outs = self._window_io(helper, data_layer(
-                    "serving.block_table_w", shape=[self.pmax],
-                    dtype="int32"))
-                ins.update(w_ins)
-                outs.update(w_outs)
+            held = {**pools, **self._window_pools(helper),
+                    **self._state_io(helper, snapshots=True)}
+            ins.update({**held, **self._lm_ins(helper)})
+            outs = {"NextTok": [nxt], **held}
             outs.update(self._beam_out_vars(helper, 0, "serving.pf"))
             outs.update(self._expert_out_vars(helper))
             helper.append_op("transformer_stack_paged_prefill", ins,
                              outs, self._decode_attrs())
         fetches = [nxt.name] + [v[0].name for k, v in sorted(outs.items())
                                 if k in ("TopV", "TopI", "ExpertCounts")]
-        self._transpile(prog, list(self._prefill_feed_names), fetches,
+        self._transpile(prog, self._prefill_feed_names, fetches,
                         f"transpile/prefill{tc}/")
         return prog, outs
 
     def _build_decode(self):
         prog, startup = Program(), Program()
         with program_guard(prog, startup):
-            tok = data_layer("serving.tok", shape=[self.slots],
-                             dtype="int64", append_batch_size=False)
-            pos = data_layer("serving.pos", shape=[self.slots],
-                             dtype="int32", append_batch_size=False)
-            table = data_layer("serving.block_table",
-                               shape=[self.slots, self.pmax],
-                               dtype="int32", append_batch_size=False)
             helper = LayerHelper("serving_paged_decode", main_program=prog,
                                  startup_program=startup)
+            ins = self._call_ins(helper, None)
             pools = self._pool_io(self._cache_vars(helper))
             nxt = helper.block.create_var(
                 name="serving.next_tok",
                 shape=[self.slots], dtype="int64", stop_gradient=True)
-            state = self._state_io(helper)
-            ins = {"Tok": [tok], "Pos": [pos], "BlockTable": [table],
-                   **pools, **state}
-            ins.update(self._sampling_vars(self.slots))
-            ins.update(self._lm_ins(helper))
-            outs = {"NextTok": [nxt], **pools, **state}
-            if self._by_kind:
-                w_ins, w_outs = self._window_io(helper, data_layer(
-                    "serving.block_table_w", shape=[self.slots, self.pmax],
-                    dtype="int32", append_batch_size=False))
-                ins.update(w_ins)
-                outs.update(w_outs)
+            held = {**pools, **self._window_pools(helper),
+                    **self._state_io(helper)}
+            ins.update({**held, **self._lm_ins(helper)})
+            outs = {"NextTok": [nxt], **held}
             outs.update(self._beam_out_vars(helper, self.slots,
                                             "serving.dec"))
             outs.update(self._expert_out_vars(helper))
@@ -1012,7 +1081,7 @@ class GenerationEngine:
                              outs, self._decode_attrs())
         fetches = [nxt.name] + [v[0].name for k, v in sorted(outs.items())
                                 if k in ("TopV", "TopI", "ExpertCounts")]
-        self._transpile(prog, list(self._decode_feed_names), fetches,
+        self._transpile(prog, self._decode_feed_names, fetches,
                         "transpile/decode/")
         return prog, outs
 
@@ -1137,30 +1206,18 @@ class GenerationEngine:
             fetches.append(outs["ExpertCounts"][0])
         return fetches
 
-    def _neutral_sampling_feed(self, rows: int) -> Dict[str, np.ndarray]:
-        """The sampling plane for rows with no live policy (warmup,
-        vacant slots, padding): greedy, mask wide open."""
-        feed = {
-            "serving.temp": np.zeros(rows, np.float32),
-            "serving.topk": np.zeros(rows, np.int32),
-            "serving.topp": np.ones(rows, np.float32),
-            "serving.seed": np.zeros(rows, np.int32),
-            "serving.step": np.zeros(rows, np.int32),
-        }
-        if self.mask_plane:
-            feed["serving.mask"] = np.ones(
-                (rows, self.spec.vocab_size), np.float32)
-        return feed
-
-    def _slot_sampling_feed(self, row: int, st, feed: dict,
+    def _slot_sampling_feed(self, row: int, st, cols: dict,
                             step: int) -> None:
-        """Write one slot's policy into row ``row`` of a sampling feed."""
+        """Write one slot's policy into row ``row`` of a call's columns.
+        The first constrained row of a call makes the call's HOST mask
+        (``cols["serving.mask"]``, ones elsewhere): ``_call_feed`` then
+        feeds that instead of the device's."""
         sp = st.sampling
-        feed["serving.temp"][row] = sp.temperature
-        feed["serving.topk"][row] = sp.top_k
-        feed["serving.topp"][row] = sp.top_p
-        feed["serving.seed"][row] = (sp.seed or 0) & 0x7FFFFFFF
-        feed["serving.step"][row] = step
+        cols["serving.temp"][row] = sp.temperature
+        cols["serving.topk"][row] = sp.top_k
+        cols["serving.topp"][row] = sp.top_p
+        cols["serving.seed"][row] = (sp.seed or 0) & 0x7FFFFFFF
+        cols["serving.step"][row] = step
         if st.mask_proc is not None and self.mask_plane:
             mask = np.asarray(
                 st.mask_proc.mask(step, st.generated), np.float32)
@@ -1171,7 +1228,30 @@ class GenerationEngine:
             if mask.max() <= 0:  # dead end: fail open, count it
                 self.metrics.inc("mask_dead_ends")
             else:
-                feed["serving.mask"][row] = mask
+                if "serving.mask" not in cols:
+                    cols["serving.mask"] = np.ones(
+                        (len(cols["serving.step"]), mask.size), np.float32)
+                cols["serving.mask"][row] = mask
+
+    def _call_feed(self, tc: Optional[int], arr: np.ndarray,
+                   cols: dict) -> CallFeed:
+        """The feed of one call (the decode tick's: ``tc`` None) from its
+        filled plane: beside it the mask, the device's ones unless a row
+        of the call is constrained. Counts the host arrays handed over,
+        each a host-to-device copy on the engine's thread before the
+        call's enqueue."""
+        feed = {self._plane(tc).name: arr}
+        if self.mask_plane:
+            feed["serving.mask"] = cols.get("serving.mask",
+                                            self._ones[len(arr)])
+        on_host = [v for v in feed.values() if isinstance(v, np.ndarray)]
+        what = "decode" if tc is None else "prefill"
+        self.metrics.inc(f"{what}_feed_host_arrays", len(on_host))
+        self.metrics.inc("mask_host_feeds", int("serving.mask" in cols))
+        if tc is None:
+            self.metrics.inc("decode_feed_host_bytes",
+                             sum(v.nbytes for v in on_host))
+        return CallFeed(feed, cols)
 
     # -- warmup / manifests ----------------------------------------------
     def warmup(self) -> int:
@@ -1185,19 +1265,10 @@ class GenerationEngine:
         for tc in self._chunk_widths:
             prog, outs = self._prefill_prog(tc)
             for b in self.prefill_batch_buckets:
-                feed = {
-                    "serving.chunk": np.full((b, tc), self.pad_id,
-                                             np.int64),
-                    "serving.start": np.zeros(b, np.int32),
-                    "serving.chunk_len": np.ones(b, np.int32),
-                    "serving.block_table": np.zeros((b, self.pmax),
-                                                    np.int32),
-                }
-                if self._by_kind:
-                    feed["serving.block_table_w"] = np.zeros(
-                        (b, self.pmax), np.int32)
-                feed.update(self._state_rows([], b))    # no row's slot
-                feed.update(self._neutral_sampling_feed(b))
+                arr, cols = self._plane(tc).new(b)
+                cols["serving.chunk_len"][:] = 1
+                self._state_rows(cols, [])              # no row's slot
+                feed = self._call_feed(tc, arr, cols)
                 self.executor.run(prog, feed=feed,
                                   fetch_list=self._fetches(outs),
                                   scope=self.scope)
@@ -1949,37 +2020,28 @@ class GenerationEngine:
             rem = [st.prompt.size - st.prefill_done for _, st, _ in group]
             tc = self._chunk_bucket_for(max(rem))
             bucket = self._batch_bucket_for(len(group))
-            chunk = np.full((bucket, tc), self.pad_id, np.int64)
-            start = np.zeros(bucket, np.int32)
-            length = np.zeros(bucket, np.int32)
-            table = np.zeros((bucket, self.pmax), np.int32)
-            table_w = np.zeros_like(table)
-            feed = self._neutral_sampling_feed(bucket)
+            arr, cols = self._plane(tc).new(bucket)
             for row, (req, st, slot) in enumerate(group):
                 r = rem[row]
-                chunk[row, :r] = st.prompt[st.prefill_done:]
-                start[row] = st.prefill_done
-                length[row] = r
-                table[row, :len(st.pages)] = st.pages
+                cols["serving.chunk"][row, :r] = st.prompt[st.prefill_done:]
+                cols["serving.start"][row] = st.prefill_done
+                cols["serving.chunk_len"][row] = r
+                cols["serving.block_table"][row, :len(st.pages)] = st.pages
                 if self._by_kind:
                     self._window_advance(st, st.prefill_done,
                                          st.prompt.size - 1)
-                    table_w[row] = self._window_row(st)
+                    cols["serving.block_table_w"][row] = self._window_row(st)
                 # step = tokens already sampled: 0 for a fresh request; a
                 # RESUMED one samples its next token at step len(emitted),
                 # keeping (seed, step) aligned with the uninterrupted
                 # stream
-                self._slot_sampling_feed(row, st, feed,
+                self._slot_sampling_feed(row, st, cols,
                                          step=len(st.generated))
-            feed.update({"serving.chunk": chunk, "serving.start": start,
-                         "serving.chunk_len": length,
-                         "serving.block_table": table})
             plans = [self._snapshot_plan(st, int(st.prompt.size))
                      for _, st, _ in group]
-            feed.update(self._state_rows([slot for _, _, slot in group],
-                                         bucket, [p[:2] for p in plans]))
-            if self._by_kind:
-                feed["serving.block_table_w"] = table_w
+            self._state_rows(cols, [slot for _, _, slot in group],
+                             [p[:2] for p in plans])
+            feed = self._call_feed(tc, arr, cols)
         prog, outs = self._prefill_prog(tc)
         t0 = time.perf_counter()
         with trace.span("serving/prefill_group", rows=len(group),
@@ -2168,27 +2230,19 @@ class GenerationEngine:
         tc = self._chunk_bucket_for(k)
         with trace.span("serving/build_feed", phase="prefill_chunk"):
             bucket = self._batch_bucket_for(1)
-            chunk = np.full((bucket, tc), self.pad_id, np.int64)
-            start = np.zeros(bucket, np.int32)
-            length = np.zeros(bucket, np.int32)
-            table = np.zeros((bucket, self.pmax), np.int32)
-            chunk[0, :k] = st.prompt[start0:start0 + k]
-            start[0] = start0
-            length[0] = k
-            table[0, :len(st.pages)] = st.pages
-            feed = self._neutral_sampling_feed(bucket)
+            arr, cols = self._plane(tc).new(bucket)
+            cols["serving.chunk"][0, :k] = st.prompt[start0:start0 + k]
+            cols["serving.start"][0] = start0
+            cols["serving.chunk_len"][0] = k
+            cols["serving.block_table"][0, :len(st.pages)] = st.pages
             # same step contract as the group path: 0 unless resumed
-            self._slot_sampling_feed(0, st, feed, step=len(st.generated))
-            feed.update({"serving.chunk": chunk, "serving.start": start,
-                         "serving.chunk_len": length,
-                         "serving.block_table": table})
+            self._slot_sampling_feed(0, st, cols, step=len(st.generated))
             plan = self._snapshot_plan(st, start0 + k)
-            feed.update(self._state_rows([slot], bucket, [plan[:2]]))
+            self._state_rows(cols, [slot], [plan[:2]])
             if self._by_kind:
                 self._window_advance(st, start0, start0 + k - 1)
-                table_w = np.zeros((bucket, self.pmax), np.int32)
-                table_w[0] = self._window_row(st)
-                feed["serving.block_table_w"] = table_w
+                cols["serving.block_table_w"][0] = self._window_row(st)
+            feed = self._call_feed(tc, arr, cols)
         prog, outs = self._prefill_prog(tc)
         t0 = time.perf_counter()
         with trace.span("serving/prefill_chunk", slot=slot,
@@ -2224,16 +2278,13 @@ class GenerationEngine:
             self._gauges()
         return True
 
-    def _decode_feed(self) -> Dict[str, np.ndarray]:
-        """The feeds of one decode tick, counted as they are built:
-        every slot's row of the token, position, table and sampling
-        planes (a vacant slot rides along greedy on the scrap
-        page)."""
-        table = np.zeros((self.slots, self.pmax), np.int32)
-        table_w = np.zeros_like(table)
-        tok = np.zeros(self.slots, np.int64)
-        pos = np.zeros(self.slots, np.int32)
-        feed = self._neutral_sampling_feed(self.slots)
+    def _decode_feed(self) -> CallFeed:
+        """The feed of one decode tick, counted as it is built: every
+        slot's row of the tick's plane (token, position, policy, table; a
+        vacant slot rides along greedy on the scrap page) and the mask."""
+        arr, cols = self._plane(None).new(self.slots)
+        tok, pos = cols["serving.tok"], cols["serving.pos"]
+        table = cols["serving.block_table"]
         for s in range(self.slots):
             st = self._slots[s]
             if st is not None and st.state == "decode":
@@ -2241,18 +2292,16 @@ class GenerationEngine:
                 pos[s] = self._pos[s]
                 table[s, :len(st.pages)] = st.pages
                 if self._by_kind:
-                    table_w[s] = self._window_row(st)
+                    cols["serving.block_table_w"][s] = self._window_row(st)
                 # step = tokens this request has sampled so far — a pure
                 # function of the request, never of the batch around it
-                self._slot_sampling_feed(s, st, feed,
+                self._slot_sampling_feed(s, st, cols,
                                          step=len(st.generated))
-        feed.update({"serving.tok": tok, "serving.pos": pos,
-                     "serving.block_table": table})
         # rows whose top-k / top-p cut-off the sampling plane has to search
         # for this tick (kernels/sampling.py runs a search only when some
         # row asks; a vacant slot is fed greedy and never does)
-        topk, topp = feed["serving.topk"], feed["serving.topp"]
-        asking = int(((feed["serving.temp"] > 0) & (
+        topk, topp = cols["serving.topk"], cols["serving.topp"]
+        asking = int(((cols["serving.temp"] > 0) & (
             ((topk > 0) & (topk < self.spec.vocab_size)) | (topp < 1))
         ).sum())
         self.metrics.inc("sample_filter_ticks", int(asking > 0))
@@ -2262,7 +2311,6 @@ class GenerationEngine:
         # would gather whole (kernels/paged_attention.py)
         held = pos // self.page_size + 1
         if self._by_kind:
-            feed["serving.block_table_w"] = table_w
             # per layer of each kind: a full-attention layer walks what a
             # slot holds, a window layer from its window's first page
             self.metrics.inc("paged_attn_pages_read_global", int(held.sum()))
@@ -2306,11 +2354,7 @@ class GenerationEngine:
                              sum(len(st.pages) for st in live)
                              * self.page_size
                              * self.spec.cache_bytes_per_token)
-        # what ``Executor.run`` has to copy to the device before it can
-        # enqueue the tick: the host-resident feeds
-        self.metrics.inc("decode_feed_host_bytes", sum(
-            v.nbytes for v in feed.values() if isinstance(v, np.ndarray)))
-        return feed
+        return self._call_feed(None, arr, cols)
 
     def _run_decode(self):
         with trace.span("serving/build_feed", phase="decode"):

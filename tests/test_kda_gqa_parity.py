@@ -326,7 +326,7 @@ def test_an_engine_without_a_snapshot_pool_is_the_engine_it_was(plain):
     pt.set_amp(False)
     was = _engine(beam=False, n_snapshots=0, snapshot_stride=0)
     assert was.prefix_index is None and not was._snapshots
-    assert "serving.snap_from" not in was._prefill_feed_names
+    assert "serving.snap_from" not in was._plane(was.prefill_chunk)
     p = _prompt(5, 40)
     was.generate_all([p, p], max_new_tokens=2)
     c = _counters(was)

@@ -381,19 +381,21 @@ _ENGINE_KW = dict(slots=2, page_size=8, prompt_buckets=(8, 16),
 @pytest.mark.parametrize("spec,kw,want", [
     (LMSpec(vocab_size=32, d_model=16, n_layers=2, num_heads=2, max_len=64),
      {},
-     {"decode": "e85b615f27ebecde", "prefill16": "c65f3065494e5d19",
-      "prefill8": "a1a8b6beee06c68f", "page_copy": "d5270f0b76e90d8b"}),
+     {"decode": "c393d3da6d29c622", "prefill16": "3f5e83d3a0887552",
+      "prefill8": "ceb639374c3ffa76", "page_copy": "d5270f0b76e90d8b"}),
     (moe_lm.spec_of(tiny_config()),
      dict(max_seq_len=64, prefill_batch_buckets=(1,), eos_id=None),
-     {"decode": "7a69e82d28de54fd", "prefill16": "de122b6b33ea0455",
-      "prefill8": "4e95b6fb631f8d41", "page_copy": "38351444fa9c0df6"}),
+     {"decode": "9828c54cf4fabc59", "prefill16": "134a2be61ec3cc06",
+      "prefill8": "72006d4d2f9df612", "page_copy": "38351444fa9c0df6"}),
 ], ids=["gpt2", "olmoe"])
 def test_engine_programs_are_bit_identical_to_the_recorded_ones(
         spec, kw, want):
     """``program_digest`` (the ``program_to_dict`` JSON, call sites
     stripped) of the decode step, every prefill chunk width and the page
-    copy, recorded from the tree before PR 28 merged the two engine
-    classes. A change that means to move a program re-records them."""
+    copy. A change that means to move a program re-records them: PR 28
+    merged the two engine classes and moved none; PR 47 gave the decode
+    and prefill programs ONE packed feed and the ``unpack_plane`` op that
+    splits it (the page copy is the recorded one still)."""
     from paddle_tpu.core.manifest import program_digest
 
     eng = GenerationEngine(spec, **_ENGINE_KW, **kw)
