@@ -183,26 +183,27 @@ class Seq2SeqGenerationEngine(GenerationEngine):
             cols["serving.xslot"][row] = st.xrow
             cols["serving.src_len"][row] = self._xrow_len[st.xrow]
 
-    def _build(self, tc, rows, kind: str):
+    def _build_paged(self, tc):
         """The prefill program of chunk width ``tc`` or (``tc`` None) the
         decode tick's: the paged engine's feeds into the cross-attention
         twin of its op."""
+        kind, rows = (("decode", self.slots) if tc is None
+                      else ("prefill", -1))
         prog, startup = Program(), Program()
         with program_guard(prog, startup):
             helper = LayerHelper(f"serving_cross_{kind}",
                                  main_program=prog,
                                  startup_program=startup)
             ins = self._call_ins(helper, tc)
-            ck, cv = self._cache_vars(helper)
+            pools = self._pool_io(helper, self._caches)
             xk, xv = self._cross_cache_vars(helper)
             nxt = helper.block.create_var(
                 name="serving.next_tok", shape=[rows],
                 dtype="int64", stop_gradient=True)
-            ins.update({"CacheK": [ck], "CacheV": [cv],
-                        "CrossK": [xk], "CrossV": [xv]})
+            ins.update({**pools, "CrossK": [xk], "CrossV": [xv]})
             ins.update(self._lm_ins(helper))
             ins.update(self._cross_weight_ins(helper))
-            outs = {"NextTok": [nxt], "CacheK": [ck], "CacheV": [cv]}
+            outs = {"NextTok": [nxt], **pools}
             outs.update(self._beam_out_vars(
                 helper, rows,
                 "serving.pf" if kind == "prefill" else "serving.dec"))
@@ -210,18 +211,10 @@ class Seq2SeqGenerationEngine(GenerationEngine):
                              outs, self._decode_attrs())
         fetches = [nxt.name] + [v[0].name for k, v in sorted(outs.items())
                                 if k in ("TopV", "TopI")]
-        return prog, outs, fetches
-
-    def _build_prefill(self, tc: int):
-        prog, outs, fetches = self._build(tc, -1, "prefill")
-        self._transpile(prog, self._prefill_feed_names, fetches,
-                        f"transpile/prefill{tc}/")
-        return prog, outs
-
-    def _build_decode(self):
-        prog, outs, fetches = self._build(None, self.slots, "decode")
-        self._transpile(prog, self._decode_feed_names, fetches,
-                        "transpile/decode/")
+        self._transpile(
+            prog, self._decode_feed_names if tc is None
+            else self._prefill_feed_names, fetches,
+            f"transpile/{kind}{'' if tc is None else tc}/")
         return prog, outs
 
     def _build_encode(self, ts: int):

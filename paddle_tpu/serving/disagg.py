@@ -66,11 +66,10 @@ def serialize_handoff(engine, handoff: dict, release: bool = True) -> dict:
     handoff now); pass False to keep them so a failed install can roll
     back via ``adopt_slot``."""
     st = handoff["st"]
-    pids = list(st.pages)
-    from .generation import PAGED_CACHE_K, PAGED_CACHE_V
-
-    k = np.asarray(engine.scope.get(PAGED_CACHE_K))[:, pids]
-    v = np.asarray(engine.scope.get(PAGED_CACHE_V))[:, pids]
+    (cache,), (held,) = engine._caches, st.held     # one kind, K and V
+    pids = cache.table_of(held)
+    k, v = (np.asarray(engine.scope.get(name))[:, pids]
+            for name in cache.scope_names)
     sp = st.sampling
     blob = {
         "v": HANDOFF_V,
@@ -105,13 +104,7 @@ def release_handoff(engine, handoff: dict) -> None:
     """Drop the exporter's claim on a serialized-away handoff: decref
     every page (shared prefix pages just lose one holder) and release
     the copy-on-write reservation."""
-    st = handoff["st"]
-    for pid in st.pages:
-        engine.pool.decref(pid)
-    st.pages = []
-    if st.cow_reserve:
-        engine.pool.release_reservation(st.cow_reserve)
-        st.cow_reserve = 0
+    engine._caches[0].release(handoff["st"].held[0])
 
 
 def install_handoff(engine, blob: dict, request) -> bool:
@@ -137,26 +130,9 @@ def install_handoff(engine, blob: dict, request) -> bool:
     n = int(blob["shape"][1])
     if engine.free_slots == 0:
         return False
-    try:
-        pids = engine.pool.alloc_many(n)
-    except RuntimeError:
-        if engine.prefix_index is not None:
-            engine.prefix_index.evict_until(n)
-        try:
-            pids = engine.pool.alloc_many(n)
-        except RuntimeError:
-            return False
-    from .generation import PAGED_CACHE_K, PAGED_CACHE_V, _Slot
+    from .generation import _Slot
     from ..decoding import SamplingParams
 
-    shape = tuple(blob["shape"])
-    dtype = np.dtype(blob["dtype"])
-    for name, key in ((PAGED_CACHE_K, "k"), (PAGED_CACHE_V, "v_")):
-        pages = np.frombuffer(base64.b64decode(blob[key]),
-                              dtype).reshape(shape)
-        full = np.array(np.asarray(engine.scope.get(name)))
-        full[:, pids] = pages
-        engine.scope.set(name, full)
     s = blob["sampling"]
     sampling = SamplingParams(
         temperature=s["temperature"], top_k=s["top_k"],
@@ -164,7 +140,21 @@ def install_handoff(engine, blob: dict, request) -> bool:
         stop=tuple(tuple(x) for x in s["stop"]))
     st = _Slot(request, prompt, int(blob["max_new"]), blob["eos_id"],
                sampling)
-    st.pages = pids
+    (cache,), (held,) = engine._caches, st.held     # one kind, K and V
+    # a migrated-in table must never half-land: room for all of it first
+    # (the prefix index is evicted for it), then every page at once
+    if not cache.make_room(n):
+        return False
+    cache.take(held, [], 0, n, n, 0)
+    pids = cache.table_of(held)
+    shape = tuple(blob["shape"])
+    dtype = np.dtype(blob["dtype"])
+    for name, key in zip(cache.scope_names, ("k", "v_")):
+        pages = np.frombuffer(base64.b64decode(blob[key]),
+                              dtype).reshape(shape)
+        full = np.array(np.asarray(engine.scope.get(name)))
+        full[:, pids] = pages
+        engine.scope.set(name, full)
     st.prefill_done = prompt.size
     st.state = "decode"
     st.generated = [int(t) for t in blob["generated"]]

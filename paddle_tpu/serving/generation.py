@@ -47,14 +47,10 @@ from ..ops.common import amp_enabled
 from .batcher import Request
 from .errors import BadRequestError
 from .metrics import MetricsRegistry
-from .paging import PagePool, PrefixIndex, chain_key
+from .paging import (PAGED_CACHE_K, PAGED_CACHE_KW, PAGED_CACHE_V,
+                     PAGED_CACHE_VW, Held, PageCache, PagePool, PrefixIndex,
+                     chain_key)
 
-
-PAGED_CACHE_K = "serving.paged_cache_k"
-PAGED_CACHE_V = "serving.paged_cache_v"
-# the window layers' pools of a spec whose layers differ in kind
-PAGED_CACHE_KW = "serving.paged_cache_kw"
-PAGED_CACHE_VW = "serving.paged_cache_vw"
 # what a slot holds beside its pages (``LMSpec.slot_state``): one array
 # [layers, slots, *shape] a name, "serving.state.<name>"
 SLOT_STATE = "serving.state."
@@ -283,15 +279,14 @@ class RequestTimeline:
 
 class _Slot:
     __slots__ = ("request", "generated", "max_new", "eos_id", "prompt",
-                 "timeline", "truncate_to", "pages", "shared_tokens",
-                 "cow_reserve", "prefill_done", "state", "sampling",
-                 "stop_matcher", "mask_proc", "beam_job", "role", "xrow",
-                 "resumed", "wpages", "wfirst", "wentries", "wreserve",
-                 "wcow", "prefix_key", "snap_from", "waited")
+                 "timeline", "truncate_to", "held", "shared_tokens",
+                 "prefill_done", "state", "sampling", "stop_matcher",
+                 "mask_proc", "beam_job", "role", "xrow", "resumed",
+                 "prefix_key", "snap_from", "waited")
 
     def __init__(self, request: Request, prompt: np.ndarray,
                  max_new: int, eos_id: Optional[int],
-                 sampling: Optional[SamplingParams] = None):
+                 sampling: Optional[SamplingParams] = None, caches: int = 1):
         self.request = request
         self.prompt = prompt
         self.generated: List[int] = []
@@ -301,9 +296,10 @@ class _Slot:
         # set by a stop-sequence match: keep only this many generated
         # tokens in the returned ids (the stop itself is dropped)
         self.truncate_to: Optional[int] = None
-        self.pages: List[int] = []       # physical page per table entry
+        # what the slot holds of each of the engine's page caches: its
+        # table, its admission-time hold and copy-on-write spare
+        self.held = [Held() for _ in range(caches)]
         self.shared_tokens = 0           # prefix-cache hit length
-        self.cow_reserve = 0             # pages held for copy-on-write
         self.prefill_done = 0            # prompt tokens whose K/V is cached
         self.prefix_key = b""            # chain key of the FULL pages of them
         # a slot with state: the (pinned) snapshot row its next prefill
@@ -321,13 +317,6 @@ class _Slot:
         self.xrow = None                 # seq2seq: cross-KV cache row
         self.resumed = 0                 # recovery: emitted tokens that
                                          # re-entered as prefill context
-        # the window kind's table (a spec with window layers): a page per
-        # logical entry allocated so far, 0 once it lies behind the window
-        self.wpages: List[int] = []
-        self.wfirst = 0                  # first entry that may hold a page
-        self.wentries = 0                # entries the slot will ever touch
-        self.wreserve = 0                # window-pool pages held for them
-        self.wcow = 0                    # and for one copy-on-write
 
 
 class GenerationEngine:
@@ -358,18 +347,16 @@ class GenerationEngine:
       pressure defers admission (the batcher queue backs up and sheds)
       instead of failing mid-decode.
 
-    **The cache by kind** (a spec with window layers, ``LMSpec(
-    layer_pattern=, window=)``): the full-attention layers' pool ``[Lg,
-    n_pages, ps, Hkv*dh]`` keeps every token of a sequence as above; the
-    window layers' pool ``[Lw, n_pages_window, ps, Hkv*dh]`` has a
-    ``PagePool``, a ``PrefixIndex`` and a per-slot table of its own
-    (``_Slot.wpages``). A slot's window pages are allocated as it
-    advances, out of an admission-time hold, and released (decref) once
-    they lie wholly behind the window — so a long sequence holds
-    ``window/ps + 1`` of them where it holds ``len/ps`` full-attention
-    pages. The hold covers the slot's live window (window + one chunk)
-    plus the prompt pages it will leave to the prefix index, so either
-    pool can defer an admission and neither is allocated from mid-decode.
+    **The cache by kind**: the engine walks ``_caches``, one
+    :class:`~paddle_tpu.serving.paging.PageCache` a kind of page cache
+    (pools, ``PagePool``, ``PrefixIndex``, table column and the rule by
+    which a slot's pages come and go: that class says it), and a slot
+    holds a ``Held`` of each (``_Slot.held``). A one-kind spec (a latent
+    one too) has the one; a spec with window layers (``LMSpec(
+    layer_pattern=, window=)``) a second, ``[Lw, n_pages_window, ps,
+    Hkv*dh]``, of which a long sequence holds ``window/ps + 1`` pages
+    where it holds ``len/ps`` full-attention ones. Either pool can defer
+    an admission and neither is allocated from mid-decode.
     The window index keeps every page of a cached prefix (a partial hit
     needs the window before ITS end): the two indexes are written in
     lockstep, page by page as a prompt's chunks complete, and a hit is as
@@ -541,7 +528,6 @@ class GenerationEngine:
         # engine's scope adopts its page pool/prefix index — a KV
         # handoff between the two is then a pure slot-table transfer
         src = share_cache_with
-        self._by_kind = spec.block.has_window
         #: (op slot, scope name, array shape, dtype) of every per-slot
         #: state array the spec lists; [] for a spec without any
         self._state = [(name, SLOT_STATE + name,
@@ -563,12 +549,8 @@ class GenerationEngine:
         self._state_layers = max((shape[0] for _, _, shape, _
                                   in self._state), default=0)
         if src is not None:
-            spec.block.require_stateless("share_cache_with= (the slot "
-                                         "handoff between engines)")
-            spec.block.require_one_kind("share_cache_with= (the slot "
-                                        "handoff between engines)")
-            spec.block.require_mha("share_cache_with= (the slot handoff "
-                                   "between engines)")
+            self._require_one_table("share_cache_with= (the slot handoff "
+                                    "between engines)")
             if self.scope is not src.scope:
                 raise ValueError(
                     "share_cache_with requires constructing this engine "
@@ -584,15 +566,6 @@ class GenerationEngine:
             self.page_size = int(page_size or min(64, self.tmax))
         # table width: enough entries for a full-context sequence
         self.pmax = -(-self.tmax // self.page_size)
-        # beam engines default to a bigger pool: K fully-diverged
-        # hypotheses can each hold a full table plus a COW spare
-        beam_extra = (self.slots + 2 * self.beam_width
-                      if self.beam_width else 0)
-        self.n_pages = (src.n_pages if src is not None
-                        else int(n_pages or self.slots * self.pmax + 1
-                                 + beam_extra))
-        if self.n_pages < 2:
-            raise ValueError("need at least 2 pages (one is scrap)")
         if prefill_chunk is None:
             prefill_chunk = min(self.prompt_buckets[-1],
                                 max(2 * self.page_size, 128))
@@ -623,31 +596,36 @@ class GenerationEngine:
         self._prefix_sharing = bool(prefix_sharing) and (
             not self._state or bool(self._snap_block))
         self._owns_pool = src is None
+        ps = self.page_size
         if src is not None:
-            self.pool = src.pool
-            self.prefix_index = src.prefix_index
+            pool, index = src.pool, src.prefix_index
         else:
-            self.pool = PagePool(self.n_pages, self.page_size)
-            self.prefix_index = (
-                PrefixIndex(self.pool, int(n_snapshots), int(snapshot_stride))
-                if self._prefix_sharing else None)
-        # the window kind: what a slot's window layers can hold at once
-        # (the window, the chunk in flight, one page of slack each way)
-        self.wpool = self.wprefix_index = None
-        self.n_pages_window = 0
-        if self._by_kind:
-            ps = self.page_size
-            self._wlive = (-(-spec.window // ps) + 1
-                           + -(-self.prefill_chunk // ps))
-            self.n_pages_window = int(
-                n_pages_window
-                or self.slots * min(self.pmax, self._wlive) + 1)
-            self.wpool = PagePool(self.n_pages_window, ps)
-            if self._prefix_sharing:
-                self.wprefix_index = PrefixIndex(self.wpool)
-        #: kind -> (the pool's ``changes`` at the count, pages some slot
-        #: holds): the ``kv_pages_held_*`` counters' cache
-        self._held: Dict[str, Tuple[int, int]] = {}
+            # beam engines default to a bigger pool: K fully-diverged
+            # hypotheses can each hold a full table plus a COW spare
+            pool = PagePool(int(n_pages or self.slots * self.pmax + 1 + (
+                self.slots + 2 * self.beam_width if self.beam_width else 0)),
+                ps)
+            index = (PrefixIndex(pool, int(n_snapshots), int(snapshot_stride))
+                     if self._prefix_sharing else None)
+        #: one entry a kind of page cache: the full-attention kind (every
+        #: layer of a one-kind spec, a latent one's single pool too), then
+        #: the window kind of a spec that has window layers
+        kw = dict(row_width=spec.cache_row_width, n_pools=spec.cache_pools,
+                  count=self.metrics.inc)
+        self._caches: List[PageCache] = [PageCache(
+            "global", pool, index, layers=spec.layers_of(False), **kw)]
+        if spec.block.has_window:
+            # what a slot's window layers can hold at once (the window,
+            # the chunk in flight, one page of slack each way)
+            live = -(-spec.window // ps) + 1 + -(-self.prefill_chunk // ps)
+            wpool = PagePool(int(n_pages_window
+                                 or self.slots * min(self.pmax, live) + 1),
+                             ps)
+            self._caches.append(PageCache(
+                "window", wpool,
+                PrefixIndex(wpool) if self._prefix_sharing else None,
+                layers=spec.layers_of(True), window=spec.window, live=live,
+                **kw))
         # no scrap SLOT — padding/vacant rows write the scrap PAGE, so
         # the decode batch is exactly the slot count
         self._slots: List[Optional[_Slot]] = [None] * self.slots
@@ -672,10 +650,18 @@ class GenerationEngine:
         #: chunk width (None: the decode tick) -> its packed feed's layout
         self._planes: Dict[Optional[int], FeedPlane] = {}
         self._prefill_progs: Dict[int, tuple] = {}
-        self._page_copy_prog_cache: Dict[bool, tuple] = {}
-        self._decode_prog = self._build_decode()
+        self._page_copy_progs: Dict[str, tuple] = {}    # by cache name
+        self._decode_prog = self._build_paged(None)
         if mem_budget is not None:
             self._check_mem_budget(mem_budget)
+
+    # read-only views of the full-attention kind ALONE (and of the window
+    # kind's size), for whoever knew the engine when it had one cache
+    pool = property(lambda self: self._caches[0].pool)
+    prefix_index = property(lambda self: self._caches[0].index)
+    n_pages = property(lambda self: self._caches[0].pool.n_pages)
+    n_pages_window = property(
+        lambda self: self._caches[1].pool.n_pages if self._caches[1:] else 0)
 
     # -- program/scope construction ------------------------------------
     @classmethod
@@ -785,13 +771,9 @@ class GenerationEngine:
 
         from ..core.types import to_dtype
 
-        shape = self._pool_shape()
         page_dtype = jnp.dtype(to_dtype(self.spec.page_dtype))
-        pools = {name: shape for name in
-                 (PAGED_CACHE_K, PAGED_CACHE_V)[:self.spec.cache_pools]}
-        if self._by_kind:
-            wshape = self._pool_shape(window=True)
-            pools.update({PAGED_CACHE_KW: wshape, PAGED_CACHE_VW: wshape})
+        pools = {name: cache.shape for cache in self._caches
+                 for name in cache.scope_names}
         if self._owns_pool:
             with self.executor.device_ctx():
                 for name, shp in pools.items():
@@ -826,33 +808,16 @@ class GenerationEngine:
                                float(self.spec.cache_bytes_per_token))
         self._gauges()
 
-    def _pool_shape(self, window: bool = False):
-        """[L, n_pages, page_size, row] in the spec's ``page_dtype``, row
-        = ``spec.cache_row_width`` (Hkv*dh for K and V pools, the latent
-        row for a latent block's one pool): a token's row of one layer is
-        contiguous, so a page is contiguous and lane-dense on the device
-        (ops/pipeline_ops.py says why the head-major [.., Hkv, page_size,
-        dh] form was not). L counts the layers of the pool's kind: the
-        full-attention layers (every layer of a one-kind spec), or the
-        window layers."""
-        s = self.spec
-        return (s.layers_of(window),
-                self.n_pages_window if window else self.n_pages,
-                self.page_size, s.cache_row_width)
-
-    def _cache_vars(self, helper, window: bool = False):
-        shape = list(self._pool_shape(window))
-        names = ((PAGED_CACHE_KW, PAGED_CACHE_VW) if window
-                 else (PAGED_CACHE_K, PAGED_CACHE_V))
-        return tuple(helper.create_global_variable(
-            name=name, shape=shape, dtype=self.spec.page_dtype)
-            for name in names[:self.spec.cache_pools])
-
-    @staticmethod
-    def _pool_io(pools):
-        """The op's pool slots for ``_cache_vars``' pools: CacheK (and
-        CacheV: a latent block's cache is the one pool)."""
-        return {slot: [v] for slot, v in zip(("CacheK", "CacheV"), pools)}
+    def _pool_io(self, helper, caches: Sequence[PageCache]):
+        """The pools of ``caches`` as a paged op's slots, inputs and
+        outputs alike (read and written in place): ``[L, n_pages,
+        page_size, row]`` in the spec's ``page_dtype``, L the layers of
+        the cache's kind, row = ``spec.cache_row_width`` (Hkv*dh for K and
+        V pools, the latent row for a latent block's one pool)."""
+        return {slot: [helper.create_global_variable(
+            name=name, shape=list(cache.shape), dtype=self.spec.page_dtype)]
+            for cache in caches
+            for slot, name in zip(cache.op_slots, cache.scope_names)}
 
     def _state_io(self, helper, snapshots: bool = False):
         """The slot-state arrays as op inputs AND outputs (updated in
@@ -880,15 +845,6 @@ class GenerationEngine:
                                   pair):
                     if v is not None:
                         cols[col][row] = v
-
-    def _window_pools(self, helper):
-        """The window kind's pools, op inputs and outputs alike (read and
-        written in place; its table is a column of the call's plane);
-        nothing for a one-kind spec."""
-        if not self._by_kind:
-            return {}
-        return {slot: [v] for slot, v in zip(
-            ("CacheKW", "CacheVW"), self._cache_vars(helper, window=True))}
 
     def _lm_ins(self, helper):
         """The ops' weight slots; a weight with an AMP operand copy is
@@ -922,7 +878,7 @@ class GenerationEngine:
         """The columns of a call's packed feed (``FeedPlane``): the decode
         tick's (``tc`` None) or those of a prefill of chunk width ``tc``.
         Its width follows what the engine knows as it builds the program:
-        the table's width, a second table by kind, a row's slot where
+        the table's width, a table a kind of cache, a row's slot where
         slots carry state, its snapshot rows where there is a pool."""
         cols = ([("serving.tok", "Tok", 0, "int32", 0),
                  ("serving.pos", "Pos", 0, "int32", 0)] if tc is None else
@@ -943,12 +899,8 @@ class GenerationEngine:
                 rows = self.prefix_index.n_snapshots
                 cols += [("serving.snap_from", "SnapFrom", 0, "int32", rows),
                          ("serving.snap_take", "SnapTake", 0, "int32", rows)]
-        cols.append(("serving.block_table", "BlockTable", self.pmax,
-                     "int32", 0))
-        if self._by_kind:
-            cols.append(("serving.block_table_w", "BlockTableW", self.pmax,
-                         "int32", 0))
-        return cols
+        return cols + [(cache.table, cache.table_slot, self.pmax, "int32", 0)
+                       for cache in self._caches]
 
     def _plane(self, tc: Optional[int]) -> FeedPlane:
         """The packed feed of the decode tick (``tc`` None) or of the
@@ -1029,70 +981,48 @@ class GenerationEngine:
         """TopV/TopI output vars when the beam plane is on."""
         if not self.beam_width:
             return {}
-        shape = [rows, self.beam_width] if rows else [-1, self.beam_width]
+        shape = [rows, self.beam_width]
         tv = helper.block.create_var(name=f"{prefix}.topv", shape=shape,
                                      dtype="float32", stop_gradient=True)
         ti = helper.block.create_var(name=f"{prefix}.topi", shape=shape,
                                      dtype="int32", stop_gradient=True)
         return {"TopV": [tv], "TopI": [ti]}
 
-    def _build_prefill(self, tc: int):
+    def _build_paged(self, tc: Optional[int]):
+        """The prefill program of chunk width ``tc`` or (None) the decode
+        tick's: the call's plane and mask, the pools of every kind of
+        cache, the slot state and the weights into ONE paged op."""
+        what, rows = (("decode", self.slots) if tc is None
+                      else ("prefill", -1))
         prog, startup = Program(), Program()
         with program_guard(prog, startup):
-            helper = LayerHelper("serving_paged_prefill", main_program=prog,
+            helper = LayerHelper(f"serving_paged_{what}", main_program=prog,
                                  startup_program=startup)
             ins = self._call_ins(helper, tc)
-            pools = self._pool_io(self._cache_vars(helper))
+            pools = self._pool_io(helper, self._caches[:1])
             nxt = helper.block.create_var(
-                name="serving.next_tok", shape=[-1],
+                name="serving.next_tok", shape=[rows],
                 dtype="int64", stop_gradient=True)
-            held = {**pools, **self._window_pools(helper),
-                    **self._state_io(helper, snapshots=True)}
+            held = {**pools, **self._pool_io(helper, self._caches[1:]),
+                    **self._state_io(helper, snapshots=tc is not None)}
             ins.update({**held, **self._lm_ins(helper)})
             outs = {"NextTok": [nxt], **held}
-            outs.update(self._beam_out_vars(helper, 0, "serving.pf"))
+            outs.update(self._beam_out_vars(
+                helper, rows, "serving.dec" if tc is None else "serving.pf"))
             outs.update(self._expert_out_vars(helper))
-            helper.append_op("transformer_stack_paged_prefill", ins,
+            helper.append_op(f"transformer_stack_paged_{what}", ins,
                              outs, self._decode_attrs())
         fetches = [nxt.name] + [v[0].name for k, v in sorted(outs.items())
                                 if k in ("TopV", "TopI", "ExpertCounts")]
-        self._transpile(prog, self._prefill_feed_names, fetches,
-                        f"transpile/prefill{tc}/")
+        self._transpile(
+            prog, [self._plane(tc).name] + ["serving.mask"] * self.mask_plane,
+            fetches, f"transpile/{what}{'' if tc is None else tc}/")
         return prog, outs
 
-    def _build_decode(self):
-        prog, startup = Program(), Program()
-        with program_guard(prog, startup):
-            helper = LayerHelper("serving_paged_decode", main_program=prog,
-                                 startup_program=startup)
-            ins = self._call_ins(helper, None)
-            pools = self._pool_io(self._cache_vars(helper))
-            nxt = helper.block.create_var(
-                name="serving.next_tok",
-                shape=[self.slots], dtype="int64", stop_gradient=True)
-            held = {**pools, **self._window_pools(helper),
-                    **self._state_io(helper)}
-            ins.update({**held, **self._lm_ins(helper)})
-            outs = {"NextTok": [nxt], **held}
-            outs.update(self._beam_out_vars(helper, self.slots,
-                                            "serving.dec"))
-            outs.update(self._expert_out_vars(helper))
-            helper.append_op("transformer_stack_paged_decode", ins,
-                             outs, self._decode_attrs())
-        fetches = [nxt.name] + [v[0].name for k, v in sorted(outs.items())
-                                if k in ("TopV", "TopI", "ExpertCounts")]
-        self._transpile(prog, self._decode_feed_names, fetches,
-                        "transpile/decode/")
-        return prog, outs
-
-    @property
-    def _page_copy_prog(self):
-        return self._page_copy_prog_of(False)
-
-    def _page_copy_prog_of(self, window: bool):
-        """The copy-on-write program of one kind's pools (built once)."""
-        cache = self._page_copy_prog_cache
-        if window not in cache:
+    def _page_copy_prog_of(self, kind: PageCache):
+        """The copy-on-write program of one cache's pools (built once)."""
+        cache = self._page_copy_progs
+        if kind.name not in cache:
             prog, startup = Program(), Program()
             with program_guard(prog, startup):
                 src = data_layer("serving.cow_src", shape=[1],
@@ -1102,7 +1032,9 @@ class GenerationEngine:
                 helper = LayerHelper("serving_page_copy",
                                      main_program=prog,
                                      startup_program=startup)
-                pools = self._pool_io(self._cache_vars(helper, window))
+                # (the copy op knows one cache: CacheK / CacheV)
+                pools = dict(zip(("CacheK", "CacheV"),
+                                 self._pool_io(helper, [kind]).values()))
                 ok = helper.block.create_var(
                     name="serving.cow_ok", shape=[1], dtype="int32",
                     stop_gradient=True)
@@ -1112,8 +1044,8 @@ class GenerationEngine:
                     {"Ok": [ok], **pools}, {})
             self._transpile(prog, ["serving.cow_src", "serving.cow_dst"],
                             [ok.name], "transpile/page_copy/")
-            cache[window] = (prog, ok)
-        return cache[window]
+            cache[kind.name] = (prog, ok)
+        return cache[kind.name]
 
     def _transpile(self, prog, feed_names, fetch_names, metric_prefix):
         """Run the inference pipeline over a freshly-built serving program
@@ -1133,7 +1065,7 @@ class GenerationEngine:
 
     def _prefill_prog(self, tp: int):
         if tp not in self._prefill_progs:
-            self._prefill_progs[tp] = self._build_prefill(tp)
+            self._prefill_progs[tp] = self._build_paged(tp)
         return self._prefill_progs[tp]
 
     def _check_mem_budget(self, budget: float) -> None:
@@ -1275,17 +1207,16 @@ class GenerationEngine:
                 combos += 1
         self._run_decode()
         combos += 1
-        self._run_page_copy(0, 0)  # scrap onto itself: harmless
-        combos += 1
-        if self._by_kind:
-            self._run_page_copy(0, 0, window=True)
+        for cache in self._caches:
+            self._run_page_copy(cache, 0, 0)  # scrap onto itself: harmless
             combos += 1
         self.metrics.inc("warmup_compiles", combos)
         self.save_manifest()
         return combos
 
     def _warm_programs(self):
-        progs = [self._decode_prog[0], self._page_copy_prog[0]]
+        progs = [self._decode_prog[0],
+                 self._page_copy_prog_of(self._caches[0])[0]]
         progs.extend(self._prefill_prog(tc)[0]
                      for tc in self._chunk_widths)
         return progs
@@ -1359,9 +1290,8 @@ class GenerationEngine:
         return warmed
 
     # -- page bookkeeping -------------------------------------------------
-    def _run_page_copy(self, src: int, dst: int,
-                       window: bool = False) -> None:
-        prog, ok = self._page_copy_prog_of(window)
+    def _run_page_copy(self, cache: PageCache, src: int, dst: int) -> None:
+        prog, ok = self._page_copy_prog_of(cache)
         self.executor.run(
             prog, feed={"serving.cow_src": np.asarray([src], np.int32),
                         "serving.cow_dst": np.asarray([dst], np.int32)},
@@ -1370,82 +1300,24 @@ class GenerationEngine:
     def _cow_guard(self, decoding) -> None:
         """Before a decode tick writes position ``pos`` for each slot,
         any target page still shared (refcount > 1 — a prefix-cache page
-        this sequence is diverging from) is copied to a fresh page from
-        the slot's admission-time reserve and the block table redirected.
-        Runs at page-boundary granularity: at most one copy per shared
-        prefix per sequence lifetime."""
-        for slot in decoding:
-            st = self._slots[slot]
-            pos = int(self._pos[slot])
-            self._copy_if_shared(st, pos // self.page_size, window=False)
-            if self._by_kind:   # the window kind's page under that position
-                self._window_advance(st, pos, pos)
-                self._copy_if_shared(st, pos // self.page_size, window=True)
+        this sequence is diverging from) is copied first, in every kind
+        of cache (``PageCache.before_write``). Runs at page-boundary
+        granularity: at most one copy per shared prefix per sequence
+        lifetime."""
+        copy = self._run_page_copy
+        for i, cache in enumerate(self._caches):
+            for slot in decoding:
+                cache.before_write(self._slots[slot].held[i],
+                                    int(self._pos[slot]), copy)
 
-    def _copy_if_shared(self, st: _Slot, entry: int, window: bool) -> None:
-        """Copy-on-write of one table entry of one kind's pool."""
-        pool, index, pages, held = (
-            (self.wpool, self.wprefix_index, st.wpages, "wcow") if window
-            else (self.pool, self.prefix_index, st.pages, "cow_reserve"))
-        pid = pages[entry]
-        if pool.refcount(pid) <= 1:
-            return
-        if getattr(st, held) > 0:
-            setattr(st, held, getattr(st, held) - 1)
-            new = pool.alloc(reserved=True)
-        else:  # defensive: never expected, but never corrupt a share
-            if pool.available() < 1 and index:
-                index.evict_until(1)
-            new = pool.alloc()
-        self._run_page_copy(pid, new, window=window)
-        pool.decref(pid)
-        pages[entry] = new
-        self.metrics.inc("kv_cow_copies")
-
-    # -- the window kind's table -------------------------------------------
-    def _window_first(self, pos: int) -> int:
-        """The first table entry a query at position ``pos`` can still
-        reach in a window layer (keys ``pos - window < j <= pos``)."""
-        return max(0, pos - self.spec.window + 1) // self.page_size
-
-    def _window_alloc(self, st: _Slot) -> int:
-        if st.wreserve > 0:
-            st.wreserve -= 1
-            return self.wpool.alloc(reserved=True)
-        # defensive: the admission hold covers every allocation
-        self.metrics.inc("kv_window_unreserved_allocs")
-        if self.wpool.available() < 1 and self.wprefix_index:
-            self.wprefix_index.evict_until(1)
-        return self.wpool.alloc()
-
-    def _window_advance(self, st: _Slot, q_first: int, q_last: int) -> None:
-        """Before a call whose queries for this slot sit at positions
-        ``q_first..q_last``: release the window pages no query of the
-        call (or any later one) can reach — a decref, so a page the
-        prefix index or another slot still holds lives on — and allocate,
-        out of the slot's hold, the entries the call writes. A page that
-        came back to the pool is held again at once while the slot still
-        has entries to come, so the hold never shrinks under it."""
-        keep_from = self._window_first(q_first)
-        for e in range(st.wfirst, min(keep_from, len(st.wpages))):
-            pid = st.wpages[e]
-            if not pid:
-                continue
-            st.wpages[e] = 0
-            self.metrics.inc("kv_window_pages_released")
-            if self.wpool.decref(pid) \
-                    and st.wreserve < st.wentries - len(st.wpages):
-                self.wpool.reserve(1)
-                st.wreserve += 1
-        st.wfirst = max(st.wfirst, keep_from)
-        while len(st.wpages) <= q_last // self.page_size:
-            st.wpages.append(self._window_alloc(st)
-                             if len(st.wpages) >= st.wfirst else 0)
-
-    def _window_row(self, st: _Slot) -> np.ndarray:
-        row = np.zeros(self.pmax, np.int32)
-        row[:len(st.wpages)] = st.wpages
-        return row
+    def _table_rows(self, st: _Slot, cols: dict, row: int, q_first: int,
+                    q_last: int) -> None:
+        """Row ``row`` of a prefill call's tables, one a kind of cache,
+        for queries at ``q_first..q_last`` (a window kind lets go of what
+        they cannot reach and allocates what they write)."""
+        for cache, held in zip(self._caches, st.held):
+            cache.advance(held, q_first, q_last)
+            cols[cache.table][row, :len(held.pages)] = held.pages
 
     def _register_prefix(self, st: _Slot,
                          include_tail: bool = False) -> None:
@@ -1454,8 +1326,8 @@ class GenerationEngine:
         the chunks that fill them complete, so a request that arrives
         while a long shared prompt is still prefilling hits what is
         written already (and a window page is indexed before the slot
-        moves past it and lets it go: a cache held by kind writes both
-        kinds' indexes in lockstep); the partial tail page only at finish
+        moves past it and lets it go: every kind's index is written in
+        lockstep); the partial tail page only at finish
         (an index reference on a page the request still writes would
         force a pointless self-copy-on-write)."""
         if self.prefix_index is None:
@@ -1464,10 +1336,15 @@ class GenerationEngine:
         prompt = st.prompt
         done = st.prefill_done >= prompt.size
 
-        def insert(key, toks, i):
-            if i < len(st.wpages) and st.wpages[i]:
-                self.wprefix_index.insert(key, toks, st.wpages[i])
-            return self.prefix_index.insert(key, toks, st.pages[i])
+        pages = st.held[0].pages    # the first kind holds every page
+        others = [(cache.index, held.pages) for cache, held
+                  in zip(self._caches[1:], st.held[1:])]
+
+        def insert(key, toks, i):   # (a long prompt's every page, every
+            for index, own in others:   # chunk: nothing is made a page)
+                if i < len(own) and own[i]:     # still held by the slot
+                    index.insert(key, toks, own[i])
+            return self.prefix_index.insert(key, toks, pages[i])
 
         n_full = min(st.prefill_done, prompt.size) // ps
         key = b""
@@ -1500,7 +1377,7 @@ class GenerationEngine:
             differ = np.flatnonzero(st.prompt[:n] != prompt[:n])
             common = (int(differ[0]) if differ.size else n) // ps * ps
             if common > shared + len(best) * ps:
-                best = st.pages[shared // ps:common // ps]
+                best = st.held[0].pages[shared // ps:common // ps]
         return best
 
     def _adopt_prefilled(self, st: _Slot) -> None:
@@ -1520,7 +1397,7 @@ class GenerationEngine:
         behind the slot as it advances)."""
         ps, start = self.page_size, st.prefill_done
         index = self.prefix_index
-        if index is None or self._by_kind or start % ps:
+        if index is None or len(self._caches) > 1 or start % ps:
             return
         i, last = start // ps, (int(st.prompt.size) - 1) // ps
         key, hits, best = st.prefix_key, [], None
@@ -1540,9 +1417,7 @@ class GenerationEngine:
             return
         n, key, row = best
         for j, page in enumerate(hits[:n], start // ps):
-            self.pool.incref(page)
-            self.pool.decref(st.pages[j])
-            st.pages[j] = page
+            self._caches[0].trade(st.held[0], j, page)
         if self._state:
             self._start_from_snapshot(st, row)
             self.metrics.inc("state_prefix_adopted")
@@ -1619,19 +1494,8 @@ class GenerationEngine:
         if self._prefix_sharing:
             # (a slot with state never enters at a partial page)
             self._register_prefix(st, include_tail=not self._state)
-        for pid in st.pages:
-            self.pool.decref(pid)
-        st.pages = []
-        if st.cow_reserve:
-            self.pool.release_reservation(st.cow_reserve)
-            st.cow_reserve = 0
-        for pid in st.wpages:
-            if pid:
-                self.wpool.decref(pid)
-        st.wpages = []
-        if st.wreserve + st.wcow:
-            self.wpool.release_reservation(st.wreserve + st.wcow)
-            st.wreserve = st.wcow = 0
+        for cache, held in zip(self._caches, st.held):
+            cache.release(held)
 
     # -- admission ---------------------------------------------------------
     def _validate(self, req: Request):
@@ -1679,7 +1543,7 @@ class GenerationEngine:
                 "beam search (a fork shares its parent's pages)"
                 if beam is not None else "resume-from-token")
         if beam is not None:
-            if self._by_kind:
+            if len(self._caches) > 1:
                 raise BlockNotSupportedError(
                     "beam search forks one block table; this engine's "
                     "cache is held by layer kind")
@@ -1727,9 +1591,7 @@ class GenerationEngine:
                 and r.payload.get("handoff") is not None]
         adopted = 0
         if hand:
-            self.spec.block.require_one_kind("a serialized KV handoff")
-            self.spec.block.require_mha("a serialized KV handoff")
-            self.spec.block.require_stateless("a serialized KV handoff")
+            self._require_one_table("a serialized KV handoff")
             # cross-process KV migration: the payload carries serialized
             # page ranges + the block table; installation writes the
             # bytes and resumes decode — never a prefill recompute
@@ -1755,25 +1617,20 @@ class GenerationEngine:
         group: list = []
         admitted = adopted
         for item in todo:
-            if self._is_recovery(item[0]):
-                # PRIORITY admission: a recovery re-admission never
-                # queues behind deferred NEW work — under pool pressure
-                # new requests defer first, and a blocked recovery goes
-                # to the FRONT of the deferred queue
-                r = self._admit_one(*item, group=group)
-                if r == "ok":
-                    admitted += 1
-                elif r == "defer":
-                    self._deferred.appendleft(item)
-                continue
-            if self._deferred:  # keep FIFO order behind blocked work
+            # PRIORITY admission: a recovery re-admission never queues
+            # behind deferred NEW work — under pool pressure new requests
+            # defer first, and a blocked recovery goes to the FRONT of
+            # the deferred queue
+            first = self._is_recovery(item[0])
+            if self._deferred and not first:    # FIFO behind blocked work
                 self._deferred.append(item)
                 continue
             r = self._admit_one(*item, group=group)
             if r == "ok":
                 admitted += 1
             elif r == "defer":
-                self._deferred.append(item)
+                (self._deferred.appendleft if first
+                 else self._deferred.append)(item)
         if group:
             self._run_prefill_group(group)
         self._gauges()
@@ -1790,8 +1647,6 @@ class GenerationEngine:
         (slot taken; short prefills appended to ``group``), "defer"
         (transient pool/slot pressure), or "failed" (future completed
         with CacheExhaustedError — the request can NEVER fit)."""
-        from .errors import CacheExhaustedError
-
         slots_needed = beam.beam_size if beam is not None else 1
         if self.free_slots < slots_needed:
             self.metrics.inc("admission_deferred")
@@ -1816,23 +1671,13 @@ class GenerationEngine:
         # prompt + resumed) plus only the NEW tokens left to decode —
         # identical to the uninterrupted request's bound
         entries_total = self._entries_for(plen + max_new - resumed_k)
-        # worst-case pages: entries_total when unshared; a shared prefix
-        # trades >=1 allocated page for <=1 copy-on-write spare, so the
-        # bound never grows — entries_total > capacity can NEVER fit
-        if entries_total > self.pool.capacity:
-            exc = CacheExhaustedError(
-                f"prompt ({plen}) + max_new_tokens ({max_new}) needs "
-                f"{entries_total} pages but the pool holds only "
-                f"{self.pool.capacity} allocatable pages of "
-                f"{self.page_size} tokens — shrink the request or grow "
-                f"n_pages",
-                pages_needed=entries_total,
-                pages_free=self.pool.capacity)
-            self.metrics.inc("cache_exhausted")
-            req.end_trace(status="cache_exhausted")
-            req.future.set_exception(exc)
-            return "failed"
-        shared, spages, key = 0, [], b""
+        caches = self._caches
+        for cache in caches:
+            if cache.never_fits(entries_total):
+                return self._fail_exhausted(req, cache, plen, max_new,
+                                            entries_total)
+        shared, key = 0, b""
+        hits: List[List[int]] = [[] for _ in caches]
         self.metrics.inc("state_refused_prefix_lookups",
                          int(self._prefix_refused))
         snap_row = None
@@ -1840,92 +1685,49 @@ class GenerationEngine:
             # a slot with state enters where pages AND a snapshot are, one
             # prompt token at least before the end (that token's chunk
             # yields the first answer token and advances the state once)
-            matched, shared, spages, key, snap_row = \
+            matched, shared, hits[0], key, snap_row = \
                 self.prefix_index.lookup_snapshot(prompt, plen - 1)
             cutback = matched - shared
         elif self.prefix_index is not None:
-            shared, spages, key = self.prefix_index.lookup(prompt)
-            if not self._by_kind:
+            # a hit is as long as EVERY kind's index holds it
+            found = [cache.index.lookup(prompt) for cache in caches]
+            shared, key = min(f[0] for f in found), found[0][2]
+            hits = [f[1][:self._entries_for(shared)] for f in found]
+            if len(caches) == 1:
                 # ... and the pages a slot is still prefilling for the
                 # same tokens: held from now on, written by whichever of
                 # the two comes to a chunk first (``_adopt_prefilled``)
-                spages = spages + self._pages_in_flight(prompt, shared)
-        wspages, wkeep, wneed = [], 0, 0
-        if self._by_kind:
-            # a hit is as long as BOTH kinds' indexes hold it
-            if self.wprefix_index is not None:
-                wshared, wspages, _ = self.wprefix_index.lookup(prompt)
-                shared = min(shared, wshared)
-                spages = spages[:self._entries_for(shared)]
-                wspages = wspages[:self._entries_for(shared)]
-            # of the hit the slot holds only what its next query reaches
-            wkeep = self._window_first(shared if shared < plen
-                                       else plen - 1)
-        own = entries_total - len(spages)
+                hits[0] = hits[0] + self._pages_in_flight(prompt, shared)
         cow = 1 if shared == plen else 0  # generation writes a shared page
-        need = own + cow
-        if self._by_kind:
-            # the window hold: the live window, plus the full prompt pages
-            # the slot will leave to the prefix index (they stay resident
-            # when it lets them go, so their successors need pages too)
-            donate = (plen - shared) // self.page_size \
-                if self.wprefix_index is not None else 0
-            wneed = min(entries_total - len(wspages),
-                        self._wlive + donate) + cow
-            if wneed > self.wpool.capacity:
-                exc = CacheExhaustedError(
-                    f"prompt ({plen}) + max_new_tokens ({max_new}) needs "
-                    f"{wneed} window-layer pages but that pool holds only "
-                    f"{self.wpool.capacity} — shrink the request or grow "
-                    f"n_pages_window",
-                    pages_needed=wneed, pages_free=self.wpool.capacity)
-                self.metrics.inc("cache_exhausted")
-                req.end_trace(status="cache_exhausted")
-                req.future.set_exception(exc)
-                return "failed"
-        for pid in spages:  # hold the prefix before any eviction runs
-            self.pool.incref(pid)
+        # of the hit a slot holds what its next query reaches, by kind
+        keeps = [cache.first_entry(shared if shared < plen else plen - 1)
+                 for cache in caches]
+        needs = [cache.need(entries_total, len(hit), plen - shared, cow)
+                 for cache, hit in zip(caches, hits)]
+        for cache, need in zip(caches, needs):
+            if need > cache.pool.capacity:
+                return self._fail_exhausted(req, cache, plen, max_new, need)
+        for cache, hit, keep in zip(caches, hits, keeps):
+            cache.hold(hit[keep:])  # the prefix, before any eviction runs
         if snap_row is not None:
             self.prefix_index.pin_snapshot(snap_row)
-        for pid in wspages[wkeep:]:
-            self.wpool.incref(pid)
-        short = None
-        if self.pool.available() < need:
-            if self.prefix_index is not None:
-                self.prefix_index.evict_until(need)
-            if self.pool.available() < need:
-                short = "admit_deferred_global"
-        if short is None and self._by_kind \
-                and self.wpool.available() < wneed:
-            if self.wprefix_index is not None:
-                self.wprefix_index.evict_until(wneed)
-            if self.wpool.available() < wneed:
-                short = "admit_deferred_window"
+        short = next((cache for cache, need in zip(caches, needs)
+                      if not cache.make_room(need)), None)
         if short is not None:
             if snap_row is not None:
                 self.prefix_index.unpin_snapshot(snap_row)
-            for pid in spages:
-                self.pool.decref(pid)
-            for pid in wspages[wkeep:]:
-                self.wpool.decref(pid)
+            for cache, hit, keep in zip(caches, hits, keeps):
+                cache.unhold(hit[keep:])
             self.metrics.inc("admission_deferred")
-            if self._by_kind:
-                self.metrics.inc(short)
+            if len(caches) > 1:
+                self.metrics.inc(f"admit_deferred_{short.name}")
             return "defer"
-        owned = [self.pool.alloc() for _ in range(own)]
-        if cow:
-            self.pool.reserve(cow)
         slot = self._slots.index(None)
-        st = _Slot(req, prompt, max_new, eos, sampling)
-        st.pages = list(spages) + owned
-        if self._by_kind:
-            self.wpool.reserve(wneed)
-            st.wpages = [0] * min(wkeep, len(wspages)) + wspages[wkeep:]
-            st.wfirst = wkeep
-            st.wentries = entries_total
-            st.wreserve, st.wcow = wneed - cow, cow
+        st = _Slot(req, prompt, max_new, eos, sampling, caches=len(caches))
+        for cache, held, hit, keep, need in zip(caches, st.held, hits, keeps,
+                                                needs):
+            cache.take(held, hit, keep, entries_total, need, cow)
         st.shared_tokens = shared
-        st.cow_reserve = cow
         st.prefill_done = shared
         st.prefix_key = key     # (a cache by kind may hold less: unused)
         st.snap_from = snap_row     # pinned above
@@ -1989,6 +1791,21 @@ class GenerationEngine:
             st.state = "prefill"  # streams via prefill_tick
         return "ok"
 
+    def _fail_exhausted(self, req, cache: PageCache, plen: int,
+                        max_new: int, pages: int) -> str:
+        """Complete ``req`` typed: ``cache`` can NEVER hold its pages."""
+        from .errors import CacheExhaustedError
+
+        self.metrics.inc("cache_exhausted")
+        req.end_trace(status="cache_exhausted")
+        req.future.set_exception(CacheExhaustedError(
+            f"prompt ({plen}) + max_new_tokens ({max_new}) needs {pages} "
+            f"{cache.noun} but that pool holds only {cache.pool.capacity} "
+            f"allocatable pages of {self.page_size} tokens — shrink the "
+            f"request or grow n_pages{cache.suffix}",
+            pages_needed=pages, pages_free=cache.pool.capacity))
+        return "failed"
+
     def _install_resume(self, st: _Slot, resume: List[int]) -> None:
         """Seed a re-admitted slot with the tokens its interrupted
         predecessor already emitted: they live in ``generated`` (so the
@@ -2026,11 +1843,8 @@ class GenerationEngine:
                 cols["serving.chunk"][row, :r] = st.prompt[st.prefill_done:]
                 cols["serving.start"][row] = st.prefill_done
                 cols["serving.chunk_len"][row] = r
-                cols["serving.block_table"][row, :len(st.pages)] = st.pages
-                if self._by_kind:
-                    self._window_advance(st, st.prefill_done,
-                                         st.prompt.size - 1)
-                    cols["serving.block_table_w"][row] = self._window_row(st)
+                self._table_rows(st, cols, row, st.prefill_done,
+                                 st.prompt.size - 1)
                 # step = tokens already sampled: 0 for a fresh request; a
                 # RESUMED one samples its next token at step len(emitted),
                 # keeping (seed, step) aligned with the uninterrupted
@@ -2051,9 +1865,7 @@ class GenerationEngine:
                                     scope=self.scope)
         t1 = time.perf_counter()
         self._count_experts(res, bucket * tc)
-        first = np.asarray(res[0])
-        topv, topi = ((np.asarray(res[1]), np.asarray(res[2]))
-                      if self.beam_width else (None, None))
+        first, topv, topi = self._tokens_of(res)
         self.metrics.observe_latency(t1 - t0, name="prefill")
         self.metrics.inc("prefills")
         self.metrics.set_gauge("prefill_occupancy", len(group) / bucket)
@@ -2067,17 +1879,22 @@ class GenerationEngine:
             st.prefill_done = st.prompt.size
             self._register_prefix(st)
             self._snapshot_done(st, plans[row])
-            if st.role == "beam_parent":
-                # the parent's top-K row expands the hypothesis set; the
-                # job takes over the slot bookkeeping from here
-                st.state = "decode"
-                st.role = "beam"
-                st.beam_job.on_parent_row(topv[row], topi[row])
-                continue
-            st.state = "decode"
-            self._tok[slot] = first[row]
-            self._pos[slot] = st.prompt.size
-            self._emit(slot, int(first[row]))
+            self._prefilled(slot, row, first, topv, topi)
+
+    def _prefilled(self, slot: int, row: int, first, topv, topi) -> None:
+        """The prompt of ``slot`` is cached whole; row ``row`` of the call
+        that ended it holds its first token (a beam parent's top-K)."""
+        st = self._slots[slot]
+        st.state = "decode"
+        if st.role == "beam_parent":
+            # the parent's top-K row expands the hypothesis set; the
+            # job takes over the slot bookkeeping from here
+            st.role = "beam"
+            st.beam_job.on_parent_row(topv[row], topi[row])
+            return
+        self._tok[slot] = first[row]
+        self._pos[slot] = st.prompt.size
+        self._emit(slot, int(first[row]))
 
     def _admit_deferred(self) -> int:
         """Retry pool-blocked admissions in arrival order. Expired ones
@@ -2234,14 +2051,11 @@ class GenerationEngine:
             cols["serving.chunk"][0, :k] = st.prompt[start0:start0 + k]
             cols["serving.start"][0] = start0
             cols["serving.chunk_len"][0] = k
-            cols["serving.block_table"][0, :len(st.pages)] = st.pages
+            self._table_rows(st, cols, 0, start0, start0 + k - 1)
             # same step contract as the group path: 0 unless resumed
             self._slot_sampling_feed(0, st, cols, step=len(st.generated))
             plan = self._snapshot_plan(st, start0 + k)
             self._state_rows(cols, [slot], [plan[:2]])
-            if self._by_kind:
-                self._window_advance(st, start0, start0 + k - 1)
-                cols["serving.block_table_w"][0] = self._window_row(st)
             feed = self._call_feed(tc, arr, cols)
         prog, outs = self._prefill_prog(tc)
         t0 = time.perf_counter()
@@ -2264,17 +2078,7 @@ class GenerationEngine:
         self._snapshot_done(st, plan)
         if st.prefill_done >= plen:
             self.metrics.inc("prefills")
-            first = np.asarray(res[0])
-            if st.role == "beam_parent":
-                st.state = "decode"
-                st.role = "beam"
-                st.beam_job.on_parent_row(np.asarray(res[1])[0],
-                                          np.asarray(res[2])[0])
-            else:
-                st.state = "decode"
-                self._tok[slot] = first[0]
-                self._pos[slot] = plen
-                self._emit(slot, int(first[0]))
+            self._prefilled(slot, 0, *self._tokens_of(res))
             self._gauges()
         return True
 
@@ -2284,19 +2088,22 @@ class GenerationEngine:
         vacant slot rides along greedy on the scrap page) and the mask."""
         arr, cols = self._plane(None).new(self.slots)
         tok, pos = cols["serving.tok"], cols["serving.pos"]
-        table = cols["serving.block_table"]
-        for s in range(self.slots):
-            st = self._slots[s]
-            if st is not None and st.state == "decode":
-                tok[s] = self._tok[s]
-                pos[s] = self._pos[s]
-                table[s, :len(st.pages)] = st.pages
-                if self._by_kind:
-                    cols["serving.block_table_w"][s] = self._window_row(st)
-                # step = tokens this request has sampled so far — a pure
-                # function of the request, never of the batch around it
-                self._slot_sampling_feed(s, st, cols,
-                                         step=len(st.generated))
+        caches, slots = self._caches, self._slots
+        rows = [s for s in range(self.slots)
+                if slots[s] is not None and slots[s].state == "decode"]
+        for s in rows:
+            st = slots[s]
+            tok[s] = self._tok[s]
+            pos[s] = self._pos[s]
+            # step = tokens this request has sampled so far — a pure
+            # function of the request, never of the batch around it
+            self._slot_sampling_feed(s, st, cols, step=len(st.generated))
+        # a live slot's tables: one slice assignment a kind of cache
+        tables = [cols[cache.table] for cache in caches]
+        for i, table in enumerate(tables):
+            for s in rows:
+                pages = slots[s].held[i].pages
+                table[s, :len(pages)] = pages
         # rows whose top-k / top-p cut-off the sampling plane has to search
         # for this tick (kernels/sampling.py runs a search only when some
         # row asks; a vacant slot is fed greedy and never does)
@@ -2309,36 +2116,26 @@ class GenerationEngine:
         # the pages the decode attention walks this tick (one per slot at
         # least: a vacant slot reads the scrap page) against the table it
         # would gather whole (kernels/paged_attention.py)
-        held = pos // self.page_size + 1
-        if self._by_kind:
-            # per layer of each kind: a full-attention layer walks what a
-            # slot holds, a window layer from its window's first page
-            self.metrics.inc("paged_attn_pages_read_global", int(held.sum()))
-            self.metrics.inc("paged_attn_pages_read_window", int(
-                (held - np.maximum(pos + 1 - self.spec.window, 0)
-                 // self.page_size).sum()))
-            # pages some SLOT holds (a page shared by several counts once;
-            # what only a prefix index still caches is evictable and is
-            # left to ``cache_stats``), by kind, against what ONE table for
-            # all layers would hold for the same slots: every page of
-            # every sequence, as the full-attention kind does
-            # (recounted only after a pool changed hands: ``changes``)
-            for name, pool, attr in (("global", self.pool, "pages"),
-                                     ("window", self.wpool, "wpages")):
-                at, n = self._held.get(name, (-1, 0))
-                if at != pool.changes:
-                    n = np.unique(np.fromiter(
-                        (p for st in self._slots if st is not None
-                         for p in getattr(st, attr) if p), np.int64)).size
-                    self._held[name] = (pool.changes, n)
-                self.metrics.inc(f"kv_pages_held_{name}", n)
-                if name == "global":
-                    self.metrics.inc("kv_pages_uniform_equiv", n)
-        else:
+        if len(caches) == 1:
             # (a latent spec's pages too: ONE pool row a token, read once
             # for key and value, ``paged_mla_decode``)
-            self.metrics.inc("paged_attn_pages_read", int(held.sum()))
-            self.metrics.inc("paged_attn_table_pages", table.size)
+            self.metrics.inc("paged_attn_pages_read",
+                             caches[0].pages_read(pos))
+            self.metrics.inc("paged_attn_table_pages", tables[0].size)
+        else:
+            for i, cache in enumerate(caches):
+                # per layer of each kind: a full-attention layer walks
+                # what a slot holds, a window layer from its window's
+                # first page
+                self.metrics.inc(f"paged_attn_pages_read_{cache.name}",
+                                 cache.pages_read(pos))
+                # ... against what ONE table for all layers would hold for
+                # the same slots: every page, as the full-attention kind
+                n = cache.held_pages(st.held[i] for st in self._slots
+                                     if st is not None)
+                self.metrics.inc(f"kv_pages_held_{cache.name}", n)
+                if not cache.window:
+                    self.metrics.inc("kv_pages_uniform_equiv", n)
         # the state a tick's recurrent layers read and write: every row of
         # the static batch, in and out (a vacant row's tiles move too)
         if self._state:
@@ -2351,7 +2148,7 @@ class GenerationEngine:
             self.metrics.inc("state_bytes_live_ticks",
                              len(live) * self.spec.state_bytes_per_slot)
             self.metrics.inc("kv_bytes_held_ticks",
-                             sum(len(st.pages) for st in live)
+                             sum(len(st.held[0].pages) for st in live)
                              * self.page_size
                              * self.spec.cache_bytes_per_token)
         return self._call_feed(None, arr, cols)
@@ -2364,6 +2161,11 @@ class GenerationEngine:
                                 fetch_list=self._fetches(outs),
                                 scope=self.scope)
         self._count_experts(res, self.slots)
+        return self._tokens_of(res)
+
+    def _tokens_of(self, res):
+        """A paged call's fetches on the host: -> (NextTok, TopV, TopI),
+        the last two None without the beam plane."""
         if self.beam_width:
             return (np.asarray(res[0]), np.asarray(res[1]),
                     np.asarray(res[2]))
@@ -2436,13 +2238,9 @@ class GenerationEngine:
         slot = (job.parent_slot if not job.expanded
                 else job.live_slots()[0])
         st = self._slots[slot]
-        _, own_n, partial = self._fork_layout(st.pages, n_written)
+        _, own_n, partial = self._fork_layout(st.held[0].pages, n_written)
         per = own_n + (2 if partial else 0)  # fork COW + source top-up
-        need = n_forks * per
-        if need and self.pool.available() < need \
-                and self.prefix_index is not None:
-            self.prefix_index.evict_until(need)
-        return self.pool.available() >= need
+        return self._caches[0].make_room(n_forks * per)
 
     def _beam_fork(self, src_slot: int, hold_slot: int,
                    n_written: int) -> int:
@@ -2451,24 +2249,18 @@ class GenerationEngine:
         — no cache bytes move), the boundary page gets a copy-on-write
         spare, and future entries allocate fresh. Feasibility was
         checked by _beam_can_fork."""
-        st_src = self._slots[src_slot]
-        n_share, own_n, partial = self._fork_layout(st_src.pages,
-                                                    n_written)
-        shared = st_src.pages[:n_share]
-        for pid in shared:
-            self.pool.incref(pid)
-        owned = [self.pool.alloc() for _ in range(own_n)]
+        cache, src = self._caches[0], self._slots[src_slot].held[0]
+        n_share, own_n, partial = self._fork_layout(src.pages, n_written)
+        shared = src.pages[:n_share]
+        cache.hold(shared)
         st = self._slots[hold_slot]
-        st.pages = list(shared) + owned
-        if partial:
-            self.pool.reserve(1)
-            st.cow_reserve = 1
-            if st_src.cow_reserve == 0:
-                # the source's boundary page just became shared too —
-                # whichever sibling writes first copies, so both hold a
-                # spare
-                self.pool.reserve(1)
-                st_src.cow_reserve = 1
+        cache.take(st.held[0], shared, 0, len(src.pages),
+                   own_n + partial, int(partial))
+        if partial and src.cow == 0:
+            # the source's boundary page just became shared too —
+            # whichever sibling writes first copies, so both hold a spare
+            cache.pool.reserve(1)
+            src.cow = 1
         st.state = "decode"
         st.role = "beam"
         st.prefill_done = int(st.prompt.size)
@@ -2485,22 +2277,19 @@ class GenerationEngine:
         st.role = "hold"
         job.holds.append(slot)
 
-    def _beam_park(self, job) -> None:
+    def _beam_park(self, job, state: str = "beam_wait") -> None:
         """Pool-parked: the job's slots sit out decode ticks until a
-        retry (serve_step) finds pages."""
+        retry (serve_step) finds pages (``_beam_unpark``)."""
         for h in job.hyps:
             if h.slot is not None:
-                self._slots[h.slot].state = "beam_wait"
+                self._slots[h.slot].state = state
         if not job.expanded:
-            self._slots[job.parent_slot].state = "beam_wait"
-        self.metrics.inc("beam_parked")
+            self._slots[job.parent_slot].state = state
+        if state == "beam_wait":
+            self.metrics.inc("beam_parked")
 
     def _beam_unpark(self, job) -> None:
-        for h in job.hyps:
-            if h.slot is not None:
-                self._slots[h.slot].state = "decode"
-        if not job.expanded:
-            self._slots[job.parent_slot].state = "decode"
+        self._beam_park(job, "decode")
 
     def _beam_free_slots(self, job) -> None:
         slots = list(job.holds)
@@ -2510,7 +2299,7 @@ class GenerationEngine:
         for slot in set(slots):
             st = self._slots[slot]
             if st is not None:
-                if st.pages:
+                if st.held[0].pages:
                     self._release_pages(st)
                 self._slots[slot] = None
         job.holds = []
@@ -2599,19 +2388,15 @@ class GenerationEngine:
 
     def _gauges(self):
         self.metrics.set_gauge("active_slots", self.active)
-        self.metrics.set_gauge("mem/kv_pages_in_use",
-                               self.pool.pages_in_use())
-        self.metrics.set_gauge("mem/kv_pages_free",
-                               self.pool.available())
+        for cache in self._caches:
+            self.metrics.set_gauge(f"mem/{cache.stem}_in_use",
+                                   cache.pool.pages_in_use())
+            self.metrics.set_gauge(f"mem/{cache.stem}_free",
+                                   cache.pool.available())
         self.metrics.set_gauge("beam_active_jobs", len(self._beam_jobs))
         self.metrics.set_gauge(
             "mem/state_bytes_live",
             float(self.active * self.spec.state_bytes_per_slot))
-        if self._by_kind:
-            self.metrics.set_gauge("mem/kv_window_pages_in_use",
-                                   self.wpool.pages_in_use())
-            self.metrics.set_gauge("mem/kv_window_pages_free",
-                                   self.wpool.available())
         if self.prefix_index is not None:
             self.metrics.set_gauge("kv_prefix_entries",
                                    len(self.prefix_index))
@@ -2631,37 +2416,26 @@ class GenerationEngine:
         """Live engine state for the flight recorder: per-slot decode
         progress, the pool, plus the last-N completed request
         timelines."""
-        slots = []
-        for i, st in enumerate(self._slots):
-            if st is None:
-                continue
-            slots.append({
-                "slot": i,
-                "state": st.state,
-                "prompt_len": int(st.prompt.size),
-                "generated": len(st.generated),
-                "max_new": st.max_new,
-                "pos": int(self._pos[i]),
-            })
+        slots = [{"slot": i, "state": st.state,
+                  "prompt_len": int(st.prompt.size),
+                  "generated": len(st.generated), "max_new": st.max_new,
+                  "pos": int(self._pos[i])}
+                 for i, st in enumerate(self._slots) if st is not None]
         state = {
             "engine": type(self).__name__,
             "slots_total": self.slots,
             "killed": self._killed,
             "slots": slots,
             "recent_requests": list(self._recent),
-            "pool": self.pool.stats(),
             "deferred": len(self._deferred),
             "state_bytes_per_slot": self.spec.state_bytes_per_slot,
             "state_bytes_live": (self.active
                                  * self.spec.state_bytes_per_slot),
         }
-        if self.prefix_index is not None:
-            state["prefix_index"] = self.prefix_index.stats()
-        if self._by_kind:
-            # "pool" is then the full-attention layers' alone
-            state["pool_window"] = self.wpool.stats()
-            if self.wprefix_index is not None:
-                state["prefix_index_window"] = self.wprefix_index.stats()
+        for cache in self._caches:  # "pool": the full-attention kind's
+            state[f"pool{cache.suffix}"] = cache.pool.stats()
+            if cache.index is not None:
+                state[f"prefix_index{cache.suffix}"] = cache.index.stats()
         return state
 
     def cache_stats(self) -> dict:
@@ -2669,14 +2443,12 @@ class GenerationEngine:
         flattened to numbers so the server can export every key as a
         gauge."""
         stats = dict(self.executor.cache_stats())
-        for k, v in self.pool.stats().items():
-            stats[f"kv_pages_{k}"] = v
+        for cache in self._caches:  # kv_pages_*: the full-attention kind's
+            for k, v in cache.pool.stats().items():
+                stats[f"{cache.stem}_{k}"] = v
         if self.prefix_index is not None:
             for k, v in self.prefix_index.stats().items():
                 stats[f"kv_prefix_{k}"] = v
-        if self._by_kind:   # kv_pages_* are then the full-attention kind's
-            for k, v in self.wpool.stats().items():
-                stats[f"kv_window_pages_{k}"] = v
         stats["state_bytes_per_slot"] = self.spec.state_bytes_per_slot
         stats["state_bytes_live"] = (self.active
                                      * self.spec.state_bytes_per_slot)
@@ -2701,34 +2473,33 @@ class GenerationEngine:
             self._beam_free_slots(job)
             self._beam_jobs.remove(job)
             job.done = True
-            job.request.end_trace(status="killed")
-            if not job.request.future.done():
-                job.request.future.set_exception(exc)
-                failed += 1
+            failed += self._fail_killed(job.request, exc)
         in_flight = 0
         for slot in range(self.slots):
             st = self._slots[slot]
             if st is None:
                 continue
             self._slots[slot] = None
-            if st.pages:
+            if st.held[0].pages:
                 self._release_pages(st)
-            st.request.end_trace(status="killed")
-            if not st.request.future.done():
-                st.request.future.set_exception(exc)
-                in_flight += 1
+            in_flight += self._fail_killed(st.request, exc)
         self._killed = True
         self.metrics.inc("replica_kills")
         self.metrics.inc("killed_in_flight", in_flight)
         self._gauges()
         failed += in_flight
         while self._deferred:
-            req = self._deferred.popleft()[0]
-            req.end_trace(status="killed")
-            if not req.future.done():
-                req.future.set_exception(exc)
-                failed += 1
+            failed += self._fail_killed(self._deferred.popleft()[0], exc)
         return failed
+
+    @staticmethod
+    def _fail_killed(req: Request, exc: Exception) -> int:
+        """Fail ``req`` retryable; -> 1 if its future was still open."""
+        req.end_trace(status="killed")
+        if req.future.done():
+            return 0
+        req.future.set_exception(exc)
+        return 1
 
     def revive(self) -> None:
         """Bring a killed engine back (slots are empty; the KV pages a
@@ -2765,9 +2536,7 @@ class GenerationEngine:
             return False
         exc = ConnectionError("replica is down (killed mid-stream)")
         for req in reqs:
-            req.end_trace(status="killed")
-            if not req.future.done():
-                req.future.set_exception(exc)
+            self._fail_killed(req, exc)
         return True
 
     def swap_params(self, source, *, strict: bool = True):
@@ -2794,15 +2563,22 @@ class GenerationEngine:
                                   metrics=self.metrics)
         self._cast_operands(self._stale_operands())
         if self.prefix_index is not None:
-            dropped = self.prefix_index.clear()
-            if self.wprefix_index is not None:
-                self.wprefix_index.clear()
-            if dropped:
-                self.metrics.inc("prefix_entries_invalidated", dropped)
+            dropped = [cache.index.clear() for cache in self._caches]
+            if dropped[0]:
+                self.metrics.inc("prefix_entries_invalidated", dropped[0])
             self._gauges()
         return stats
 
     # -- prefill/decode disaggregation: KV handoff -------------------------
+    def _require_one_table(self, who: str) -> None:
+        """The slot handoff moves ONE table of K and V pages and nothing
+        beside it: it was never run over pages by layer kind, a latent
+        pool or state a slot, and refuses them by name."""
+        block = self.spec.block
+        block.require_one_kind(who)
+        block.require_mha(who)
+        block.require_stateless(who)
+
     def handoff_ready(self) -> List[int]:
         """Slots eligible to migrate to a decode pool: prompt K/V fully
         cached, next step a plain decode tick. Beam-owned slots stay
@@ -2825,16 +2601,14 @@ class GenerationEngine:
         refcount; cross-process: ``disagg.serialize_handoff`` moves the
         page bytes. Either way the migration is the block table + pages
         — never a prefill recompute."""
-        self.spec.block.require_one_kind("export_slot (the KV handoff)")
-        self.spec.block.require_mha("export_slot (the KV handoff)")
-        self.spec.block.require_stateless("export_slot (the KV handoff)")
+        self._require_one_table("export_slot (the KV handoff)")
         st = self._slots[slot]
         if st is None or st.state != "decode" or st.beam_job is not None \
                 or st.xrow is not None:
             raise ValueError(f"slot {slot} is not handoff-eligible")
         self._slots[slot] = None
         self.metrics.inc("kv_handoffs_out")
-        self.metrics.inc("kv_handoff_pages", len(st.pages))
+        self.metrics.inc("kv_handoff_pages", len(st.held[0].pages))
         self._gauges()
         return {"st": st, "tok": int(self._tok[slot]),
                 "pos": int(self._pos[slot]), "pool": self.pool}
@@ -2845,9 +2619,7 @@ class GenerationEngine:
         pages' refcounts simply transfer with the block table. Returns
         the slot index; decode resumes on the next tick, bit-identically
         (copy-on-write still guards any page the prefix index shares)."""
-        self.spec.block.require_one_kind("adopt_slot (the KV handoff)")
-        self.spec.block.require_mha("adopt_slot (the KV handoff)")
-        self.spec.block.require_stateless("adopt_slot (the KV handoff)")
+        self._require_one_table("adopt_slot (the KV handoff)")
         if handoff.get("pool") is not self.pool:
             raise ValueError(
                 "same-process adoption needs a shared page pool — build "

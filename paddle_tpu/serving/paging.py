@@ -7,8 +7,9 @@ which pages cache which prompt prefixes. Everything here is plain Python
 over numpy ints: no device traffic, no locks (the engine is single-
 threaded per tick). A model whose layers differ in kind (full attention /
 a sliding window) has one such pool, ``PagePool`` and ``PrefixIndex`` PER
-KIND: both invariants below hold for each, and the engine keeps the two
-indexes in lockstep (the same content keys, page by page).
+KIND, behind one :class:`PageCache` each: both invariants below hold for
+each, and the engine, which walks a list of them, keeps the indexes in
+lockstep (the same content keys, page by page).
 
 Two invariants the engine relies on:
 
@@ -27,11 +28,27 @@ from __future__ import annotations
 
 import hashlib
 from collections import OrderedDict
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 SCRAP_PAGE = 0  # padding rows / vacant decode slots write here
+
+PAGED_CACHE_K = "serving.paged_cache_k"
+PAGED_CACHE_V = "serving.paged_cache_v"
+# the window layers' pools of a spec whose layers differ in kind
+PAGED_CACHE_KW = "serving.paged_cache_kw"
+PAGED_CACHE_VW = "serving.paged_cache_vw"
+#: kind -> the scope names and op slots of its pools, the plane column and
+#: op slot of its table, the suffix of its statistics' keys (and of the
+#: engine keyword that sizes it), how an error names its pages
+_KINDS = {
+    "global": ((PAGED_CACHE_K, PAGED_CACHE_V), ("CacheK", "CacheV"),
+               "serving.block_table", "BlockTable", "", "pages"),
+    "window": ((PAGED_CACHE_KW, PAGED_CACHE_VW), ("CacheKW", "CacheVW"),
+               "serving.block_table_w", "BlockTableW", "_window",
+               "window-layer pages"),
+}
 
 
 class PagePool:
@@ -106,15 +123,6 @@ class PagePool:
         self._ref[page] = 1
         self.changes += 1
         return page
-
-    def alloc_many(self, n: int) -> List[int]:
-        """Pop ``n`` free pages atomically: either all allocate or a
-        RuntimeError leaves the pool untouched (a multi-page claim — a
-        migrated-in KV handoff — must never half-land)."""
-        if self.available() < n:
-            raise RuntimeError(
-                f"alloc_many({n}) with only {self.available()} available")
-        return [self.alloc() for _ in range(n)]
 
     def incref(self, page: int) -> None:
         if page == SCRAP_PAGE or self._ref[page] < 1:
@@ -361,3 +369,219 @@ class PrefixIndex:
             out.update(snapshots=len(self._snaps),
                        snapshot_evictions=self.snapshot_evictions)
         return out
+
+
+class Held:
+    """What ONE slot holds of one :class:`PageCache` (``_Slot.held``: a
+    list parallel to the engine's list of caches)."""
+
+    __slots__ = ("pages", "first", "entries", "reserve", "cow")
+
+    def __init__(self):
+        self.pages: List[int] = []  # physical page per table entry so
+                                    # far; 0 once it lies behind the window
+        self.first = 0      # first entry that may hold a page
+        self.entries = 0    # entries the slot will ever touch
+        self.reserve = 0    # pool pages held for the entries to come
+        self.cow = 0        # ... and for one copy-on-write
+
+
+class PageCache:
+    """ONE kind of page cache: the pools of the layers of that kind
+    (``shape``, under ``scope_names`` in the scope and ``op_slots`` of the
+    paged ops), their :class:`PagePool` and :class:`PrefixIndex` (None:
+    no prefix sharing), the column of a call's plane that carries a
+    slot's table, and the rule by which a slot's :class:`Held` grows.
+
+    The rule is ``window``. 0, the full-attention kind (a latent pool
+    too): a slot holds every page of its sequence, all of them allocated
+    at admission (``take``), so decode never allocates. A window of
+    ``window`` tokens: a query at ``pos`` reaches keys ``pos - window < j
+    <= pos``, so admission only HOLDS pages, ``live`` of them (the
+    window, the chunk in flight, one page of slack each way) plus the
+    prompt pages the slot will leave to the index (``need``), and
+    ``advance`` allocates and lets go. ``count(name, n)`` is the
+    engine's ``metrics.inc``."""
+
+    def __init__(self, name: str, pool: PagePool,
+                 index: Optional[PrefixIndex], *, layers: int,
+                 row_width: int, n_pools: int = 2, window: int = 0,
+                 live: int = 0, count: Callable[..., None]):
+        names, slots, self.table, self.table_slot, self.suffix, \
+            self.noun = _KINDS[name]
+        self.name = name
+        #: gauges ``mem/<stem>_in_use`` / ``_free``, ``cache_stats()`` keys
+        self.stem = f"kv{self.suffix}_pages"
+        self.pool, self.index = pool, index
+        self.page_size = pool.page_size
+        self.scope_names, self.op_slots = names[:n_pools], slots[:n_pools]
+        #: [L, n_pages, page_size, row]: a token's row of one layer is
+        #: contiguous, so a page is contiguous and lane-dense on the device
+        #: (ops/pipeline_ops.py says why the head-major form was not)
+        self.shape = (layers, pool.n_pages, pool.page_size, row_width)
+        self.window, self.live = int(window), int(live)
+        self._count = count
+        self._held_at, self._held_n = -1, 0     # ``held_pages``' memo
+
+    # -- the growth rule ---------------------------------------------------
+    def first_entry(self, pos: int) -> int:
+        """The first table entry a query at ``pos`` can still reach."""
+        if not self.window:
+            return 0
+        return max(0, pos - self.window + 1) // self.page_size
+
+    def never_fits(self, entries: int) -> bool:
+        """Whether the kind that holds every page of a sequence can never
+        hold ``entries`` of them, whatever the index shares (a shared
+        prefix trades >=1 page for <=1 copy-on-write spare)."""
+        return not self.window and entries > self.pool.capacity
+
+    def need(self, entries: int, n_hit: int, unshared: int, cow: int) -> int:
+        """Pages an admission must find available: its own (``entries``
+        less the ``n_hit`` it shares; a window kind at most its live
+        window plus the full pages of its ``unshared`` prompt tokens: it
+        leaves them to the index, where they stay resident, so their
+        successors need pages too) and the ``cow`` spare of a slot whose
+        generation writes a shared page."""
+        own = entries - n_hit
+        if self.window:
+            donate = (unshared // self.page_size
+                      if self.index is not None else 0)
+            own = min(own, self.live + donate)
+        return own + cow
+
+    # -- admission ---------------------------------------------------------
+    def hold(self, pages: Sequence[int]) -> None:
+        """Take a reference on a hit's pages before any eviction runs."""
+        for pid in pages:
+            self.pool.incref(pid)
+
+    def unhold(self, pages: Sequence[int]) -> None:
+        for pid in pages:
+            self.pool.decref(pid)
+
+    def make_room(self, need: int) -> bool:
+        """Evict the index until ``need`` pages are available; False:
+        they are not (the admission defers, by this kind)."""
+        if self.pool.available() < need and self.index is not None:
+            self.index.evict_until(need)
+        return self.pool.available() >= need
+
+    def take(self, held: Held, hit: List[int], keep: int, entries: int,
+             need: int, cow: int) -> None:
+        """Give an admitted slot what it holds from here on: of the hit
+        the pages from entry ``keep`` (held since ``hold``), and its own,
+        allocated now or held for ``advance``, by the rule."""
+        held.pages = [0] * min(keep, len(hit)) + hit[keep:]
+        held.first, held.entries, held.cow = keep, entries, cow
+        if self.window:
+            self.pool.reserve(need)
+            held.reserve = need - cow
+        else:
+            held.pages += [self.pool.alloc() for _ in range(need - cow)]
+            if cow:
+                self.pool.reserve(cow)
+
+    # -- a slot's table as it advances -------------------------------------
+    def _alloc(self, held: Held) -> int:
+        if held.reserve > 0:
+            held.reserve -= 1
+            return self.pool.alloc(reserved=True)
+        # defensive: the admission hold covers every allocation
+        self._count("kv_window_unreserved_allocs")
+        self.make_room(1)
+        return self.pool.alloc()
+
+    def advance(self, held: Held, q_first: int, q_last: int) -> None:
+        """Before a call whose queries for this slot sit at positions
+        ``q_first..q_last`` (nothing to do for the kind that never lets
+        go): release the pages no query of the call (or any later one)
+        can reach, and allocate, out of the slot's hold, the entries the
+        call writes. A page that came back to the pool is held again at
+        once while the slot still has entries to come, so the hold never
+        shrinks under it."""
+        if not self.window:
+            return
+        keep_from = self.first_entry(q_first)
+        pages = held.pages
+        for e in range(held.first, min(keep_from, len(pages))):
+            pid = pages[e]
+            if not pid:
+                continue
+            pages[e] = 0
+            self._count("kv_window_pages_released")
+            if self.pool.decref(pid) \
+                    and held.reserve < held.entries - len(pages):
+                self.pool.reserve(1)
+                held.reserve += 1
+        held.first = max(held.first, keep_from)
+        while len(pages) <= q_last // self.page_size:
+            pages.append(self._alloc(held)
+                         if len(pages) >= held.first else 0)
+
+    def before_write(self, held: Held, pos: int, copy_fn) -> None:
+        """What a decode tick that writes position ``pos`` needs of the
+        slot's table first: the page under it, and that its own. One
+        still shared (refcount > 1) is copied (``copy_fn(cache, src,
+        dst)``) to a fresh page from the slot's admission-time spare and
+        the table redirected: copy-on-write. (Every live slot passes here
+        every tick: the common case is one refcount read.)"""
+        if self.window:
+            self.advance(held, pos, pos)
+        entry = pos // self.page_size
+        pid = held.pages[entry]
+        if self.pool._ref[pid] <= 1:
+            return
+        if held.cow > 0:
+            held.cow -= 1
+            new = self.pool.alloc(reserved=True)
+        else:  # defensive: never expected, but never corrupt a share
+            self.make_room(1)
+            new = self.pool.alloc()
+        copy_fn(self, pid, new)
+        self.pool.decref(pid)
+        held.pages[entry] = new
+        self._count("kv_cow_copies")
+
+    def trade(self, held: Held, entry: int, page: int) -> None:
+        """Hold ``page`` (another slot's, of the same content) for
+        ``entry`` in place of the slot's own."""
+        self.pool.incref(page)
+        self.pool.decref(held.pages[entry])
+        held.pages[entry] = page
+
+    def table_of(self, held: Held) -> List[int]:
+        """The slot's page ids in table order (a copy)."""
+        return list(held.pages)
+
+    def release(self, held: Held) -> None:
+        """Everything the slot holds goes back: a reference a page, and
+        what is left of its hold."""
+        for pid in held.pages:
+            if pid:
+                self.pool.decref(pid)
+        held.pages = []
+        if held.reserve + held.cow:
+            self.pool.release_reservation(held.reserve + held.cow)
+            held.reserve = held.cow = 0
+
+    # -- accounting --------------------------------------------------------
+    def pages_read(self, pos: np.ndarray) -> int:
+        """The pages a decode tick's attention walks in a layer of this
+        kind for queries at ``pos`` (one a row at least: a vacant slot
+        reads the scrap page)."""
+        first = (np.maximum(pos + 1 - self.window, 0) // self.page_size
+                 if self.window else 0)
+        return int((pos // self.page_size + 1 - first).sum())
+
+    def held_pages(self, helds: Iterable[Held]) -> int:
+        """Distinct pages the slots hold (``helds``: theirs of this
+        cache); what only the index still caches is evictable and not
+        counted. Recounted only after the pool changed hands
+        (``PagePool.changes``)."""
+        if self._held_at != self.pool.changes:
+            self._held_n = np.unique(np.fromiter(
+                (p for held in helds for p in held.pages if p),
+                np.int64)).size
+            self._held_at = self.pool.changes
+        return self._held_n

@@ -53,12 +53,16 @@ def fresh_programs():
     # tri-state in ops/common.py): a test that ends with set_amp(False)
     # would make a later test on the same worker blind to --use_amp.
     # Which test runs before which is the scheduler's choice
-    # (--dist loadfile), so every test hands back what it found.
+    # (--dist loadfile), so every test starts AND ends unpinned. Handing
+    # back what was found is not enough: pytest builds a module-scoped
+    # fixture before this one, so a pin made there (test_kda_parity's
+    # ``served``, test_kda_gqa_parity's ``twice``) was "found", handed
+    # back after every test of that file and carried into the next file.
     from paddle_tpu.ops import common
 
-    pinned = common._AMP, common._MXU_PRECISION
+    common._AMP = common._MXU_PRECISION = common._UNSET
     yield
-    common._AMP, common._MXU_PRECISION = pinned
+    common._AMP = common._MXU_PRECISION = common._UNSET
 
 
 # ---------------------------------------------------------------------------

@@ -54,6 +54,14 @@ def _engine(seed=3, **engine):
     return eng
 
 
+def _window_kind(eng):
+    """The window kind's pool and prefix index: the second of the
+    engine's caches (``eng.pool`` / ``eng.prefix_index`` are the first's)."""
+    full, window = eng._caches
+    assert (full.name, window.name) == ("global", "window")
+    return window.pool, window.index
+
+
 def _prompt(n, seed=0):
     return np.random.RandomState(seed).randint(1, 500, size=n)
 
@@ -217,9 +225,9 @@ def test_the_held_page_counters_equal_a_recount_at_every_tick(no_amp):
     run, seen = eng._run_decode, []
 
     def counted():
-        want = {name: len({p for st in eng._slots if st is not None
-                           for p in getattr(st, attr) if p})
-                for name, attr in (("global", "pages"), ("window", "wpages"))}
+        want = {cache.name: len({p for st in eng._slots if st is not None
+                                 for p in st.held[i].pages if p})
+                for i, cache in enumerate(eng._caches)}
         before = _counters(eng)
         out = run()
         after = _counters(eng)
@@ -240,19 +248,21 @@ def test_the_held_page_counters_equal_a_recount_at_every_tick(no_amp):
 def test_a_long_slot_holds_a_window_of_pages_and_never_a_shared_write(
         no_amp):
     eng = _engine()
+    wpool, windex = _window_kind(eng)
     held, writes_shared = [], []
 
     def watch(eng):
         for slot, st in enumerate(eng._slots):
             if st is None or st.state != "decode":
                 continue
-            held.append(sum(1 for p in st.wpages if p))
+            w = st.held[1]
+            held.append(sum(1 for p in w.pages if p))
             entry = int(eng._pos[slot]) // PS
             # the page the NEXT tick writes is copied first if shared
-            if entry < len(st.wpages) and st.wpages[entry]:
+            if entry < len(w.pages) and w.pages[entry]:
                 writes_shared.append(
-                    eng.wpool.refcount(st.wpages[entry]) > 1
-                    and int(eng._pos[slot]) % PS != 0 and st.wcow == 0
+                    wpool.refcount(w.pages[entry]) > 1
+                    and int(eng._pos[slot]) % PS != 0 and w.cow == 0
                     and st.shared_tokens != st.prompt.size)
     _drive(eng, [(_prompt(6, seed=7), 3 * WINDOW + 6)], watch)
     assert max(held) <= WINDOW // PS + 2
@@ -264,9 +274,9 @@ def test_a_long_slot_holds_a_window_of_pages_and_never_a_shared_write(
     assert c["kv_pages_held_window"] < c["kv_pages_held_global"]
     assert c["kv_pages_held_global"] == c["kv_pages_uniform_equiv"]
     # everything came back: only the prefix indexes hold pages now
-    assert eng.wpool.stats()["reserved"] == 0
+    assert wpool.stats()["reserved"] == 0
     assert eng.pool.stats()["reserved"] == 0
-    assert eng.wpool.pages_in_use() == len(eng.wprefix_index)
+    assert wpool.pages_in_use() == len(windex)
     assert eng.pool.pages_in_use() == len(eng.prefix_index)
 
 
@@ -278,9 +288,10 @@ def test_releasing_behind_the_window_never_frees_an_indexed_page(no_amp):
     eng = _engine()
     prompt = _prompt(24, seed=8)
     eng.generate_all([prompt], max_new_tokens=2 * WINDOW)
-    indexed = list(eng.wprefix_index._entries.values())
+    wpool, windex = _window_kind(eng)
+    indexed = list(windex._entries.values())
     assert len(indexed) >= 6
-    assert all(eng.wpool.refcount(p) == 1 for p in indexed)
+    assert all(wpool.refcount(p) == 1 for p in indexed)
     assert _counters(eng)["kv_window_pages_released"] >= 6
     err, _ = _served_error(eng, np.concatenate([prompt, _prompt(3, seed=9)]),
                            4)
@@ -305,8 +316,9 @@ def test_either_pool_defers_an_admission_and_leaks_nothing(no_amp, short,
              - {counter}).pop()
     assert c.get(other, 0) == 0
     assert c.get("kv_window_unreserved_allocs", 0) == 0
-    assert eng.wpool.stats()["reserved"] == eng.pool.stats()["reserved"] == 0
-    assert eng.wpool.pages_in_use() == len(eng.wprefix_index)
+    wpool, windex = _window_kind(eng)
+    assert wpool.stats()["reserved"] == eng.pool.stats()["reserved"] == 0
+    assert wpool.pages_in_use() == len(windex)
     assert eng.pool.pages_in_use() == len(eng.prefix_index)
     # deferral changes when a request runs, not what it says
     alone = _engine()

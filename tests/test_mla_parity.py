@@ -383,7 +383,7 @@ def test_requests_over_one_cold_document_prefill_it_once_between_them(
     assert eng.active == 3 and not eng._deferred
     # the document's ten pages are held ONCE: the second request took the
     # first one's pages into its table (written or not), not a set of its own
-    held = [eng._slots[i].pages for i in range(2)]
+    held = [eng._slots[i].held[0].pages for i in range(2)]
     assert held[0][:10] == held[1][:10]
     assert eng.pool.stats()["in_use"] == sum(
         eng._entries_for(p.size + 5) for p in prompts) - 10

@@ -378,6 +378,19 @@ _ENGINE_KW = dict(slots=2, page_size=8, prompt_buckets=(8, 16),
                   prefill_chunk=16)
 
 
+def _tiny_spec(family, config):
+    """The spec of a family's tiny configuration (benchmark/tests/data)."""
+    import importlib
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "tests", "data", "configs",
+                           config)) as f:
+        return importlib.import_module(
+            f"benchmark.families.{family}").spec_of(json.load(f))
+
+
 @pytest.mark.parametrize("spec,kw,want", [
     (LMSpec(vocab_size=32, d_model=16, n_layers=2, num_heads=2, max_len=64),
      {},
@@ -387,20 +400,42 @@ _ENGINE_KW = dict(slots=2, page_size=8, prompt_buckets=(8, 16),
      dict(max_seq_len=64, prefill_batch_buckets=(1,), eos_id=None),
      {"decode": "9828c54cf4fabc59", "prefill16": "134a2be61ec3cc06",
       "prefill8": "72006d4d2f9df612", "page_copy": "38351444fa9c0df6"}),
-], ids=["gpt2", "olmoe"])
+    # one case a shape of cache, recorded at the parent of PR 48 (which put
+    # each kind of page cache behind one object and moved no program):
+    # pages by layer kind (BOTH kinds' page copies), a latent pool, state a
+    # slot, state with a snapshot pool
+    (_tiny_spec("window_moe_lm", "smallthinker-tiny.json"),
+     dict(max_seq_len=64, n_pages_window=12),
+     {"decode": "98b45120d1a6c8fc", "prefill16": "81f86892a5eec66d",
+      "prefill8": "33882d21b6dba658", "page_copy": "38351444fa9c0df6",
+      "page_copy_window": "21c1bd1907528fce"}),
+    (_tiny_spec("mla_moe_lm", "mistral4-tiny.json"), dict(max_seq_len=64),
+     {"decode": "526811be43ea444e", "prefill16": "0ba61c842661ac3e",
+      "prefill8": "a9ad73f07afc8b55", "page_copy": "59dce240be45b6b7"}),
+    (_tiny_spec("kda_mla_moe_lm", "ling3-tiny.json"), dict(max_seq_len=64),
+     {"decode": "e154cb44613136b4", "prefill16": "153476ed1a6e1c61",
+      "prefill8": "6f1a44039526bb3b", "page_copy": "1a667a6dca82e5b9"}),
+    (_tiny_spec("kda_gqa_moe_lm", "solar2-tiny.json"),
+     dict(max_seq_len=64, snapshot_stride=2, n_snapshots=4),
+     {"decode": "6bee585d3fc83ad6", "prefill16": "6ae54e0e828cc35a",
+      "prefill8": "23442468fef902f7", "page_copy": "d5270f0b76e90d8b"}),
+], ids=["gpt2", "olmoe", "window", "latent", "state", "state_snapshots"])
 def test_engine_programs_are_bit_identical_to_the_recorded_ones(
         spec, kw, want):
     """``program_digest`` (the ``program_to_dict`` JSON, call sites
     stripped) of the decode step, every prefill chunk width and the page
-    copy. A change that means to move a program re-records them: PR 28
-    merged the two engine classes and moved none; PR 47 gave the decode
-    and prefill programs ONE packed feed and the ``unpack_plane`` op that
-    splits it (the page copy is the recorded one still)."""
+    copy of every kind of cache. A change that means to move a program
+    re-records them: PR 28 merged the two engine classes and moved none;
+    PR 47 gave the decode and prefill programs ONE packed feed and the
+    ``unpack_plane`` op that splits it (the page copy is the recorded one
+    still)."""
     from paddle_tpu.core.manifest import program_digest
 
     eng = GenerationEngine(spec, **_ENGINE_KW, **kw)
-    got = {"decode": program_digest(eng._decode_prog[0]),
-           "page_copy": program_digest(eng._page_copy_prog[0])}
+    got = {"decode": program_digest(eng._decode_prog[0])}
+    for cache in eng._caches:
+        got["page_copy" + cache.suffix] = program_digest(
+            eng._page_copy_prog_of(cache)[0])
     for tc in eng._chunk_widths:
         got[f"prefill{tc}"] = program_digest(eng._prefill_prog(tc)[0])
     assert got == want

@@ -215,7 +215,7 @@ class TestPrefixSharing:
                 for p, n in zip(prompts, new)]
         eng.admit(reqs)
         assert eng.active == 3
-        tables = [eng._slots[i].pages for i in range(3)]
+        tables = [eng._slots[i].held[0].pages for i in range(3)]
         assert tables[0][:5] == tables[1][:5] == tables[2][:5]
         eng._drive([])
         for req, want in zip(reqs, ref):
@@ -489,7 +489,7 @@ class TestInPlaceStep:
         want_ids = _reference_decode(scope_r, exe, prompt[None], 3)[0]
         assert st_p.generated == want_ids[len(prompt):].tolist()
         written = len(prompt) + 2  # the prompt + one row per tick
-        pages = list(st_p.pages)
+        pages = list(st_p.held[0].pages)
         assert 0 not in pages and len(pages) >= -(-written // 8)
         # every layer's K/V over prompt + the two decoded tokens, from
         # the one-shot family's prefill: [L, 1, Hkv, T, dh]

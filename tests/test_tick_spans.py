@@ -268,9 +268,9 @@ def test_packed_feed_serves_the_tokens_of_the_separate_feeds(spec, rows):
     eng = _spec_engine(spec)
 
     def serve():
-        for index in (eng.prefix_index, eng.wprefix_index):
-            if index is not None:
-                index.clear()
+        for cache in eng._caches:
+            if cache.index is not None:
+                cache.index.clear()
         if rows == "beam":
             return list(eng.generate_beam(PROMPTS[0], beam_size=4,
                                           max_new_tokens=4))
