@@ -52,7 +52,9 @@ reads only the pages a row HOLDS, straight from the whole pool:
   because its bytes are counted differently (one pool).
 
 The jnp gather + ``reference_attention`` stays the semantic ground truth
-and the path of every other shape (t > 1, CPU, unaligned widths):
+and the path of every other shape (a prefill chunk, CPU, unaligned
+widths; the two positions of a verify tick stay on the page walk:
+``paged_attention_verify``):
 ``supported`` is the whole dispatch rule, read off the operands.
 """
 from __future__ import annotations
@@ -68,6 +70,9 @@ import jax.numpy as jnp
 KERNEL = "paged_attention_decode"
 MLA_KERNEL = "paged_mla_decode"
 
+#: query positions a row of a verify tick may bring (``supported``)
+VERIFY_POSITIONS = 2
+
 #: Q_bd's rows are padded to this many (zero rows score 0 against every
 #: key and are masked out of the result): one sublane tile of bf16, two of
 #: float32, so the two matmuls never see a ragged M
@@ -81,20 +86,22 @@ def _sublane_tile(dtype) -> int:
 
 def supported(q_width: int, pool, t: int) -> bool:
     """Whether the decode kernel can take this call: one query token a
-    row, a TPU backend, whole groups of query heads over the cached heads
+    row (or the ``VERIFY_POSITIONS`` of a verify tick:
+    ``paged_attention_verify``), a TPU backend, whole groups of query
+    heads over the cached heads
     (a query row of ``q_width`` = H*dh floats against a pool [L, N, ps,
     Hkv*dh]: H a multiple of Hkv), a lane-aligned row and a page of whole
     sublane tiles. Everything here is a shape, a dtype or the backend:
     nothing names a model."""
     ps, width = pool.shape[2:]
-    return (t == 1 and jax.default_backend() == "tpu"
+    return (1 <= t <= VERIFY_POSITIONS and jax.default_backend() == "tpu"
             and q_width % width == 0 and width % 128 == 0
             and ps % _sublane_tile(pool.dtype) == 0)
 
 
 def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, *rest,
                    d_head, pmax, group=1, window=None, sm_scale=None,
-                   shared_kv=False):
+                   shared_kv=False, positions=1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -112,7 +119,11 @@ def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, *rest,
     # would fault the chip where XLA reads the nearest page
     layer = jnp.clip(layer_ref[0], 0, k_hbm.shape[0] - 1)
     length = len_ref[s]
-    n_pages = jnp.clip((length + ps - 1) // ps, 1, pmax)
+    # a verify tick (``positions`` > 1): query position j of the row holds
+    # length + j keys; the walk covers the LAST position's pages and starts
+    # at the first position's window
+    last = length if positions == 1 else length + (positions - 1)
+    n_pages = jnp.clip((last + ps - 1) // ps, 1, pmax)
 
     def first_page(row):
         """Where row ``row``'s walk starts: 0, or the window's page."""
@@ -150,16 +161,23 @@ def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, *rest,
     # the block-diagonal query: row h keeps columns h*dh..h*dh+dh-1 (the
     # padding rows past H own no column). Selected in float32: Mosaic does
     # not re-tile the mask for bf16
-    shape = acc_ref.shape                                       # [hp, width]
+    shape = acc_ref.shape                           # [positions * hp, width]
     row_id = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     col_id = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    hp_one = shape[0] // positions      # rows of ONE position (heads, padded)
+    if positions > 1:
+        # rows j * hp_one .. hold position j's heads: the position a row
+        # belongs to, and its head, by comparisons
+        row_pos = sum((row_id >= j * hp_one).astype(jnp.int32)
+                      for j in range(1, positions))
+        row_id = row_id - row_pos * hp_one
     # the cached head a query row reads: n // group, by comparisons (a
     # padding row past H lands past the last head or on zero queries)
     kv_id = row_id if group == 1 else sum(
         (row_id >= j * group).astype(jnp.int32)
         for j in range(1, width // d_head))
     own = (col_id >= kv_id * d_head) & (col_id < (kv_id + 1) * d_head)
-    if group == 1:
+    if group == 1 and positions == 1:
         q_bd = jnp.where(own, q_ref[0].astype(jnp.float32), 0.0).astype(
             q_ref.dtype)
     else:
@@ -188,9 +206,15 @@ def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, *rest,
             precision=precision,
             preferred_element_type=jnp.float32) * sm_scale      # [hp, ps]
         key = i * ps + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        seen = key < length
+        held = length
+        if positions > 1:       # a later position holds as many more keys
+            held = length + sum(
+                (jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0)
+                 >= j * hp_one).astype(jnp.int32)
+                for j in range(1, positions))
+        seen = key < held
         if window is not None:
-            seen = seen & (key >= length - window)
+            seen = seen & (key >= held - window)
         sc = jnp.where(seen, sc, -jnp.inf)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
@@ -213,17 +237,25 @@ def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, *rest,
     # accumulated nothing and stays zero
     l = l_ref[...]
     ctx = jnp.where(own, acc_ref[...] / jnp.where(l > 0, l, 1.0), 0.0)
-    if group == 1:
+    if group == 1 and positions == 1:
         o_ref[0] = jnp.sum(ctx, axis=0, keepdims=True).astype(o_ref.dtype)
     else:
         # G rows share a column block: out[g] = the sum of rows g, G+g,
         # 2G+g, .. (one per cached head), a 0/1 selection done as an exact
-        # float32 matmul [G_pad, hp] x [hp, width]
+        # float32 matmul [G_pad, hp] x [hp, width]; a verify tick's
+        # positions stack their G_pad rows (out row p * G_pad + g from the
+        # rows p * hp_one + ..)
         gp, hp = o_ref.shape[1], shape[0]
         g_id = jax.lax.broadcasted_iota(jnp.int32, (gp, hp), 0)
         r_id = jax.lax.broadcasted_iota(jnp.int32, (gp, hp), 1)
+        at = 0      # where the out row's position starts among the rows
+        if positions > 1:
+            gp_one = gp // positions
+            g_pos = sum((g_id >= j * gp_one).astype(jnp.int32)
+                        for j in range(1, positions))
+            g_id, at = g_id - g_pos * gp_one, g_pos * hp_one
         sel = (g_id < group) & functools.reduce(
-            jnp.logical_or, [r_id == j * group + g_id
+            jnp.logical_or, [r_id == at + j * group + g_id
                              for j in range(width // d_head)])
         o_ref[0] = jax.lax.dot_general(
             sel.astype(jnp.float32), ctx,
@@ -254,7 +286,19 @@ def paged_attention_decode(q, cache_k, cache_v, layer, table, lengths,
 
     if q.ndim != 3:
         raise ValueError(f"q must be [b, H, dh], got {q.shape}")
-    b, heads, d_head = q.shape
+    return _walk(q[:, None], cache_k, cache_v, layer, table, lengths,
+                 interpret, window, sm_scale, name)[:, 0]
+
+
+def _walk(q, cache_k, cache_v, layer, table, lengths, interpret, window,
+          sm_scale, name):
+    """The kernel's call for q [b, t, H, dh]: t query positions a row (one:
+    a decode tick; more: a verify tick, position j holding ``lengths + j``
+    keys) -> [b, t, H*dh]."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, heads, d_head = q.shape
     ps, width = cache_k.shape[2:]
     shared_kv = cache_v is None
     if (heads * d_head) % width or width % d_head \
@@ -267,21 +311,25 @@ def paged_attention_decode(q, cache_k, cache_v, layer, table, lengths,
     pmax = table.shape[1]
     hp = -(-heads // _ROW_TILE) * _ROW_TILE
     dtype = cache_k.dtype
-    if group == 1:
+    if group == 1 and t == 1:
         q_in, q_rows, out_rows = q.reshape(b, 1, width).astype(dtype), 1, 1
     else:
-        # the block-diagonal query [b, H_pad, Hkv*dh]: row n = q_n in the
-        # columns of cached head n // group, zeros elsewhere
+        # the block-diagonal query [b, t * H_pad, Hkv*dh]: row n of a
+        # position = q_n in the columns of cached head n // group, zeros
+        # elsewhere; a verify tick's positions stack their H_pad rows
         own = (jnp.arange(heads)[:, None] // group
                == jnp.arange(kv_heads)[None, :])
-        q_in = jnp.where(own[None, :, :, None], q.astype(dtype)[:, :, None],
-                         jnp.zeros((), dtype)).reshape(b, heads, width)
-        q_in = jnp.pad(q_in, ((0, 0), (0, hp - heads), (0, 0)))
-        q_rows, out_rows = hp, -(-group // 8) * 8
+        q_in = jnp.where(own[None, None, :, :, None],
+                         q.astype(dtype)[:, :, :, None],
+                         jnp.zeros((), dtype)).reshape(b, t, heads, width)
+        q_in = jnp.pad(q_in, ((0, 0), (0, 0), (0, hp - heads), (0, 0)))
+        q_in = q_in.reshape(b, t * hp, width)
+        q_rows, out_rows = t * hp, t * (-(-group // 8) * 8)
     kernel = functools.partial(_decode_kernel, d_head=d_head, pmax=pmax,
                                group=group, window=window, sm_scale=sm_scale,
-                               shared_kv=shared_kv)
+                               shared_kv=shared_kv, positions=t)
     pools = (cache_k,) if shared_kv else (cache_k, cache_v)
+    rows = t * hp
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # layer, the flattened table, lengths
         grid=(b,),
@@ -295,9 +343,9 @@ def paged_attention_decode(q, cache_k, cache_v, layer, table, lengths,
             pltpu.VMEM((2, ps, width), dtype) for _ in pools] + [
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),            # buffer of the next page
-            pltpu.VMEM((hp, 1), jnp.float32),       # running max
-            pltpu.VMEM((hp, 1), jnp.float32),       # running denominator
-            pltpu.VMEM((hp, width), jnp.float32),   # un-normalised P @ V
+            pltpu.VMEM((rows, 1), jnp.float32),     # running max
+            pltpu.VMEM((rows, 1), jnp.float32),     # running denominator
+            pltpu.VMEM((rows, width), jnp.float32),  # un-normalised P @ V
         ],
     )
     out = pl.pallas_call(
@@ -313,8 +361,26 @@ def paged_attention_decode(q, cache_k, cache_v, layer, table, lengths,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
       table.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
       q_in, *pools)
-    if group == 1:
-        return out.reshape(b, width)
-    # out[b, g, j*dh..] is head j*group + g: back to head order
-    return out[:, :group].reshape(b, group, kv_heads, d_head).transpose(
-        0, 2, 1, 3).reshape(b, heads * d_head)
+    if group == 1 and t == 1:
+        return out.reshape(b, 1, width)
+    # out[b, p * G_pad + g, j*dh..] is head j*group + g of position p: back
+    # to head order
+    return out.reshape(b, t, out_rows // t, kv_heads, d_head)[
+        :, :, :group].transpose(0, 1, 3, 2, 4).reshape(b, t, heads * d_head)
+
+
+def paged_attention_verify(q, cache_k, cache_v, layer, table, lengths,
+                           interpret=False, window=None):
+    """Attention of the t consecutive query positions of a VERIFY tick over
+    the pages a row holds: q [b, H, t, dh], query j of row s sitting at
+    position ``lengths[s] - 1 + j`` and seeing keys ``< lengths[s] + j``
+    (its own K/V row and the earlier queries' were written before the
+    call), inside ``window`` on a window layer -> [b, t, H*dh].
+
+    ONE walk a row: the t positions fold into the query-head group of each
+    cached head (t * G rows a KV head, t * H_pad rows of scores a page, a
+    later position's rows masked past ITS length), so every page the row
+    holds is read once for all of them. The walk starts at the FIRST
+    position's window and ends at the last position's page."""
+    return _walk(q.transpose(0, 2, 1, 3), cache_k, cache_v, layer, table,
+                 lengths, interpret, window, None, KERNEL)

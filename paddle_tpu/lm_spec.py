@@ -62,6 +62,24 @@ and limit its choice to the best ``topk_group`` of ``n_group`` groups
 (``router_score`` / ``router_bias`` / ``n_group`` / ``topk_group``:
 DeepSeek-V3's ``noaux_tc``).
 
+``first_dense`` also heads a stack of ``full`` / ``window`` layers (planes
+``[L, ..]`` for what every layer has, ``[first_dense, ..]`` for the dense
+FFN, ``[L - first_dense, ..]`` for the experts: ``plane_layers``).
+
+``draft_block``: a DRAFTING (multi-token-prediction) block behind the stack,
+DeepSeek-V3's form (arXiv:2412.19437 section 2.2): with h_i the stack's
+output before the final norm and t_{i+1} the next token, ``u_i = M
+[RMSNorm_h(h_i) ; RMSNorm_e(Emb(t_{i+1}))]`` (M ``[2d, d]``: rows 0..d-1
+take the hidden half, rows d..2d-1 the embedding half), ``g = Block(u)``
+(ONE full-attention block of the spec's own attention and FFN, rotating at
+position i, with K/V of its own) and ``draft_{i+2} = argmax Head(
+RMSNorm_m(g_i))``, embedding and head shared with the stack. Its planes are
+no layer of the stack (``DRAFT_PLANES`` under ``mtp.*``, the block's as a
+one-layer stack under ``mtp_stack.stack_*``: ``LMSpec.draft_spec``); its
+K/V is one more layer of the full-attention page pool. Only the paged
+serving ops run it: a decode tick then feeds two positions a slot and emits
+one or two tokens (serving/generation.py).
+
 Selection between blocks is made from the spec and nothing else: no flag,
 no environment variable.
 """
@@ -210,6 +228,9 @@ class Block:
     kda_decay: str = "bounded"
     kda_neg_eigval: bool = False
     kda_proj_rank: int = 0
+    # a drafting (multi-token-prediction) block behind the stack: the
+    # module docstring has its equations
+    draft_block: bool = False
     # the dtype the weights are STATED in, where the matmul operands a
     # program hands the op are not the weights themselves: a serving
     # engine's bf16 AMP operand copies of float32 weights
@@ -299,10 +320,16 @@ class Block:
         if self.n_group < 1 or not 1 <= self.topk_group <= self.n_group:
             raise ValueError(f"topk_group {self.topk_group} outside "
                              f"[1, n_group {self.n_group}]")
-        if self.first_dense and not (self.ffn == "swiglu_moe"
-                                     and self.attn_kinds):
+        if self.first_dense and not (self.ffn == "swiglu_moe" and (
+                self.attn_kinds or not self.is_mla)):
             raise ValueError("first_dense: the leading dense layers of an "
-                             "expert stack whose layers are held by kind")
+                             "expert stack (held by attention kind, or of "
+                             "full / window K/V layers)")
+        if self.draft_block and (self.is_mla or self.attn_kinds
+                                 or not self.use_rope):
+            raise ValueError("draft_block: the drafting block is one RoPE "
+                             "K/V full-attention layer behind a stack of "
+                             "such layers (no latent pool, no state)")
         if self.expert_act not in EXPERT_ACTS:
             raise ValueError(f"expert_act {self.expert_act!r} not in "
                              f"{EXPERT_ACTS}")
@@ -442,6 +469,21 @@ class Block:
                 "(and, for full / window kinds, the train op with T <= "
                 "window and the one-shot generate op)")
 
+    def require_no_draft(self, who: str) -> None:
+        if self.draft_block:
+            raise BlockNotSupportedError(
+                f"{who} moves or forks ONE token a step; this spec has a "
+                "drafting block (draft_block=True), whose slots carry a "
+                "pending draft and advance one or two positions a tick: "
+                "the paged prefill / decode ops behind GenerationEngine / "
+                "Server run it")
+
+    def draft(self) -> "Block":
+        """The drafting block's own block: one full-attention layer of
+        this spec's attention and expert FFN."""
+        return dataclasses.replace(self, layer_pattern=None, window=0,
+                                   first_dense=0, draft_block=False)
+
     @property
     def is_gpt2(self) -> bool:
         """The block the not-yet-converted ops hard-code."""
@@ -486,6 +528,10 @@ class Block:
         if ln:
             slots["Ln2B"] = "ln2_b"
         if self.is_moe:
+            if self.first_dense:
+                slots.update(DenseGateW="dense_gate_w",
+                             DenseUpW="dense_up_w",
+                             DenseDownW="dense_down_w")
             slots.update(RouterW="router_w", MoeGateW="moe_gate_w",
                          MoeUpW="moe_up_w", MoeDownW="moe_down_w")
             if self.shared_expert:
@@ -614,6 +660,20 @@ SNAPSHOT_SLOTS = tuple(name + "Snap" for name in STATE_SLOTS) + (
     "SnapFrom", "SnapTake")
 
 
+#: the drafting block's planes outside its one-layer stack: op slot ->
+#: key (scope name ``mtp.<key>``); its block's planes ride the slots
+#: ``DRAFT_SLOT_PREFIX + <stack slot>`` (scope ``mtp_stack.stack_<key>``)
+DRAFT_PLANES = {"MtpProjW": "proj_w", "MtpNormHS": "norm_h_s",
+                "MtpNormES": "norm_e_s", "MtpHeadNormS": "head_norm_s"}
+DRAFT_SLOT_PREFIX = "Mtp"
+#: every slot a drafting block may add to a paged op
+DRAFT_SLOTS = tuple(DRAFT_PLANES) + tuple(
+    DRAFT_SLOT_PREFIX + s for s in (
+        "Ln1S", "QkvW", "QNormS", "KNormS", "OutW", "Ln2S", "RouterW",
+        "MoeGateW", "MoeUpW", "MoeDownW", "SharedGateW", "SharedUpW",
+        "SharedDownW"))
+
+
 @dataclasses.dataclass
 class LMSpec:
     """A stacked transformer LM: widths, heads, norm, positions, FFN,
@@ -644,7 +704,13 @@ class LMSpec:
     besides. ``first_dense`` leading layers run a dense SwiGLU
     of ``d_ff``; ``router_score`` / ``router_bias`` / ``n_group`` /
     ``topk_group``: the router; ``attn_gate="head"``: the latent
-    attention's head-wise output gate."""
+    attention's head-wise output gate.
+
+    ``draft_block=True``: a drafting (multi-token-prediction) block behind
+    the stack (the module docstring has its equations; ``draft_spec()`` is
+    its one-layer stack, ``draft_planes()`` its four planes beside it). An
+    expert stack of ``full`` / ``window`` layers may also lead with
+    ``first_dense`` dense SwiGLU layers of ``d_ff``."""
     vocab_size: int
     d_model: int
     n_layers: int
@@ -694,6 +760,7 @@ class LMSpec:
     kda_decay: str = "bounded"
     kda_neg_eigval: bool = False
     kda_proj_rank: int = 0
+    draft_block: bool = False
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
@@ -768,6 +835,33 @@ class LMSpec:
         return (self.n_layers // len(kinds)
                 * sum(1 for w, _ in kinds if w == windowed))
 
+    def pool_layers(self, windowed: bool) -> int:
+        """Layers of each kind's page POOL: ``layers_of`` and, in the
+        full-attention kind, the drafting block's one layer (the last)."""
+        return self.layers_of(windowed) + int(self.draft_block
+                                              and not windowed)
+
+    def draft_spec(self) -> "LMSpec":
+        """The drafting block as a one-layer stack of this spec's
+        attention and expert FFN (full attention, RoPE): its planes are
+        ``draft_spec().stack_planes()`` under ``mtp_stack.stack_<key>``."""
+        return dataclasses.replace(self, n_layers=1, layer_pattern=None,
+                                   window=0, first_dense=0,
+                                   draft_block=False)
+
+    def draft_planes(self) -> List[Tuple[str, str, list, Optional[tuple]]]:
+        """(slot, key, shape, fan) of the drafting block's planes beside
+        its block (``DRAFT_PLANES``, scope names ``mtp.<key>``): the
+        projection M [2d, d] (rows 0..d-1: the hidden half, d..2d-1: the
+        embedding half) and the three norm scales; [] without one."""
+        if not self.draft_block:
+            return []
+        d = self.d_model
+        shapes = {"proj_w": ([2 * d, d], (2 * d, d)), "norm_h_s": ([d], None),
+                  "norm_e_s": ([d], None), "head_norm_s": ([d], None)}
+        return [(slot, key, *shapes[key])
+                for slot, key in DRAFT_PLANES.items()]
+
     @property
     def ffn_width(self) -> int:
         return self.d_ff or 4 * self.d_model
@@ -799,7 +893,7 @@ class LMSpec:
         import numpy as np
 
         layers = (self.layers_of(False) if self.block.attn_kinds
-                  else self.n_layers)
+                  else self.n_layers) + int(self.draft_block)
         return (layers * self.cache_pools * self.cache_row_width
                 * np.dtype(to_dtype(self.page_dtype)).itemsize)
 
@@ -824,7 +918,10 @@ class LMSpec:
         """The leading (layer) axis of stacked plane ``key``: every layer,
         or, for a stack held by kind, the layers of the key's group."""
         if not self.block.attn_kinds:
-            return self.n_layers
+            group = Block.plane_group(key) if self.first_dense else "all"
+            return {"dense": self.first_dense,
+                    "experts": self.n_layers - self.first_dense}.get(
+                        group, self.n_layers)
         ix = self.block.group_index(self.n_layers)[Block.plane_group(key)]
         return sum(1 for i in ix if i is not None)
 
@@ -909,8 +1006,13 @@ class LMSpec:
         names += ["final_ln.scale"] + (
             ["final_ln.bias"] if self.norm == "layer_norm" else [])
         names.append("lm_head.w")
-        return names + [f"{base}.stack_{key}"
-                        for key in self.block.stack_slots().values()]
+        names += [f"{base}.stack_{key}"
+                  for key in self.block.stack_slots().values()]
+        if self.draft_block:
+            names += [f"mtp.{key}" for _, key, _, _ in self.draft_planes()]
+            names += [f"mtp_stack.stack_{key}" for key
+                      in self.draft_spec().block.stack_slots().values()]
+        return names
 
     def n_params(self) -> int:
         """Parameters of the whole model (embedding, position table,
@@ -921,4 +1023,13 @@ class LMSpec:
         pos = 0 if self.use_rope else self.max_len * self.d_model
         final = self.d_model * (2 if self.block.norm == "layer_norm"
                                 and self.bias else 1)
-        return stack + emb + pos + final
+        return stack + emb + pos + final + self.draft_param_count()
+
+    def draft_param_count(self) -> int:
+        """Parameters of the drafting block alone (0 without one)."""
+        if not self.draft_block:
+            return 0
+        return (sum(math.prod(shape) for _, _, shape, _
+                    in self.draft_planes())
+                + sum(math.prod(shape) for _, _, shape, _
+                      in self.draft_spec().stack_planes()))

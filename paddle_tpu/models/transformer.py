@@ -208,16 +208,42 @@ def _shared_lm_params(helper, spec):
     return ins
 
 
+def draft_params(helper, spec):
+    """The drafting block's parameters (``LMSpec(draft_block=True)``)
+    under their fixed names — ``mtp.<key>`` and its one-layer stack
+    ``mtp_stack.stack_<key>`` — as the paged ops' input slots; {} for a
+    spec without one."""
+    from ..initializer import ConstantInitializer, XavierInitializer
+    from ..layers.attention import make_stack_params
+    from ..lm_spec import DRAFT_SLOT_PREFIX
+
+    if not spec.draft_block:
+        return {}
+    ins = {}
+    for slot, key, shape, fan in spec.draft_planes():
+        init = (XavierInitializer(fan_in=fan[0], fan_out=fan[1])
+                if fan is not None else ConstantInitializer(1.0))
+        ins[slot] = [helper.create_parameter(
+            ParamAttr(name=f"mtp.{key}"), shape=shape,
+            dtype=spec.param_dtype, is_bias=fan is None,
+            default_initializer=init, stored_dtype=True)]
+    block = make_stack_params(helper, "mtp_stack", spec.draft_spec())
+    ins.update({DRAFT_SLOT_PREFIX + slot: v for slot, v in block.items()})
+    return ins
+
+
 def lm_parameters(spec, main_program=None, startup_program=None):
     """Declare the parameters of ``spec``'s stacked LM under their fixed
-    names (tok_emb, final_ln.*, lm_head.w, lm_stack.stack_*) and nothing
-    else: running the startup program then initialises a scope that
-    ``GenerationEngine(spec, scope)`` serves. For a spec only the paged ops
-    run (a stack held by attention kind), whose weights no train or
-    one-shot generation program can declare. Returns the op-input dict."""
+    names (tok_emb, final_ln.*, lm_head.w, lm_stack.stack_*; a drafting
+    block's mtp.* and mtp_stack.stack_*) and nothing else: running the
+    startup program then initialises a scope that ``GenerationEngine(spec,
+    scope)`` serves. For a spec only the paged ops run (a stack held by
+    attention kind, a dense head under layer kinds), whose weights no train
+    or one-shot generation program can declare. Returns the op-input
+    dict."""
     helper = LayerHelper("lm_parameters", main_program=main_program,
                          startup_program=startup_program)
-    return _shared_lm_params(helper, spec)
+    return {**_shared_lm_params(helper, spec), **draft_params(helper, spec)}
 
 
 def transformer_lm_generate(prompt, vocab_size=None, d_model=256,
@@ -245,6 +271,9 @@ def transformer_lm_generate(prompt, vocab_size=None, d_model=256,
     helper = LayerHelper("transformer_lm_generate", **kw)
     ins = {"Prompt": [prompt]}
     ins.update(_shared_lm_params(helper, spec))
+    # a drafting block is declared (so the startup program seeds it) and
+    # not run: the one-shot op emits the stack's own tokens
+    draft_params(helper, spec)
     o = helper.simple_op("transformer_stack_generate", ins,
                          {**spec.block.attrs(),
                           "max_new_tokens": max_new_tokens,

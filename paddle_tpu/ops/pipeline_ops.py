@@ -23,7 +23,8 @@ from .. import trace
 from ..core.registry import register_op
 from ..kernels.flash_attention import (RESIDUAL_NAMES, flash_attention,
                                        rotary)
-from ..lm_spec import (OPTIONAL_STACK_SLOTS, SNAPSHOT_SLOTS, STATE_SLOTS,
+from ..lm_spec import (DRAFT_PLANES, DRAFT_SLOT_PREFIX, DRAFT_SLOTS,
+                       OPTIONAL_STACK_SLOTS, SNAPSHOT_SLOTS, STATE_SLOTS,
                        Block, BlockNotSupportedError)
 from .common import amp_cast, maybe, mxu_precision, out, single
 from .moe_ops import moe_topk
@@ -139,6 +140,16 @@ def _scan_stack(kinds, body, carry, xs):
     carry, ys = jax.lax.scan(period, carry, tmap(
         lambda a: a.reshape((a.shape[0] // p, p) + a.shape[1:]), xs))
     return carry, tmap(lambda a: a.reshape((-1,) + a.shape[2:]), ys)
+
+
+def _require_uniform_planes(blk, who):
+    """The one-scan ops slice EVERY plane by the layer's index."""
+    if blk.first_dense and not blk.attn_kinds:
+        raise BlockNotSupportedError(
+            f"{who} scans planes that all lead with the layer axis; this "
+            f"stack's first {blk.first_dense} layer(s) run a dense FFN whose "
+            "planes lead with their own count (and the experts' with the "
+            "rest): the paged prefill / decode ops run it")
 
 
 def _hold_to_window(blk, T, who):
@@ -390,6 +401,7 @@ def pipelined_transformer_stack(attrs, ins):
             "gradient test exists for any): the paged prefill / decode ops "
             "run this spec")
 
+    _require_uniform_planes(blk, "pipelined_transformer_stack (training)")
     _hold_to_window(blk, x.shape[1], "pipelined_transformer_stack")
 
     def scan_stats(p, h):
@@ -433,6 +445,7 @@ def pipelined_transformer_stack(attrs, ins):
                 "a swiglu_moe stack under a pp mesh axis: the GPipe "
                 "schedule carries no per-layer router statistics")
         blk.require_one_kind("a pp pipeline (stages of whole layers)")
+        blk.require_no_draft("a pp pipeline (stages of whole layers)")
         if L % pp:
             raise ValueError(
                 f"{L} layers not divisible by pipeline size {pp}")
@@ -587,6 +600,7 @@ def transformer_stack_generate(attrs, ins, rng):
             "and cannot hold a stack by attention kind "
             f"({list(blk.attn_kinds)}): the paged prefill / decode ops "
             "behind GenerationEngine run it")
+    _require_uniform_planes(blk, "transformer_stack_generate")
     N = attrs["max_new_tokens"]
     temperature = attrs.get("temperature") or 0.0
     top_k = attrs.get("top_k") or 0
@@ -808,14 +822,20 @@ def _pick_rows(attrs, ins, rng, vocab, logits, step0=0):
     return sample_rows(logits, temp, top_k, top_p, seed, step, mask)
 
 
-def _maybe_topk(attrs, ins, logits, outs):
+def _maybe_topk(attrs, ins, logits, outs, draft_logits=None):
     """Attach TopV/TopI (each row's top-``emit_topk`` masked log-probs)
-    to ``outs`` when the program asks for the beam plane."""
+    to ``outs`` when the program asks for the beam plane; a drafting
+    block's rows (``draft_logits``, unmasked: a draft is a plain argmax)
+    go BELOW the stack's — how a check reads the block's logits."""
     k = attrs.get("emit_topk") or 0
     if k:
         from ..kernels.sampling import top_logprobs
 
         vals, ids = top_logprobs(logits, int(k), maybe(ins, "Mask"))
+        if draft_logits is not None:
+            more = top_logprobs(draft_logits, int(k))
+            vals = jnp.concatenate([vals, more[0]])
+            ids = jnp.concatenate([ids, more[1]])
         outs["TopV"], outs["TopI"] = [vals], [ids]
     return outs
 
@@ -917,7 +937,8 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
     ckw, cvw, table_w, page_id_w, page_row_w = win or (None,) * 5
     ix_w = (None if win is None
             else (page_id_w.reshape(b, t), page_row_w.reshape(b, t)))
-    n_layers = jax.tree_util.tree_leaves(params)[0].shape[0]
+    # (the planes every layer has lead with L; a dense head's lead with less)
+    n_layers = max(a.shape[0] for a in jax.tree_util.tree_leaves(params))
     within = None       # a layer's index within its kind: l itself for one
     if kinds is not None:
         seen = {False: 0, True: 0}
@@ -927,12 +948,15 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
             within.append(seen[windowed])
             seen[windowed] += 1
         within = jnp.asarray(within, jnp.int32)
+    # leading dense layers (``first_dense`` of a full / window stack): the
+    # dense planes lead with THEIR count, the experts' with the rest
+    fd = blk.first_dense if blk is not None else 0
 
     def layer(carry, inp, kind):
         h, ck, cv, ckw, cvw = carry
         layer_p, l, l_kind, x_l = inp
-        if whole:
-            layer_p = {**layer_p, **whole, "layer": l}
+        if whole and "dense_gate_w" not in layer_p:
+            layer_p = {**layer_p, **whole, "layer": l - fd if fd else l}
         windowed, rope = kind or (False, None)
         if l_kind is None:
             l_kind = l
@@ -945,10 +969,53 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
                                       table, *ix, rope=rope)
         return (h, ck, cv, ckw, cvw), stats
 
-    (h, cache_k, cache_v, ckw, cvw), stats = _scan_stack(
-        kinds, layer, (h, cache_k, cache_v, ckw, cvw),
-        (params, jnp.arange(n_layers, dtype=jnp.int32), within, xs))
+    carry = (h, cache_k, cache_v, ckw, cvw)
+    if not fd:
+        carry, stats = _scan_stack(
+            kinds, layer, carry,
+            (params, jnp.arange(n_layers, dtype=jnp.int32), within, xs))
+    else:
+        carry, stats = _dense_head_then_scan(
+            kinds, layer, carry, params, within, xs, fd, n_layers)
+    h, cache_k, cache_v, ckw, cvw = carry
     return h, cache_k, cache_v, stats, (None if win is None else (ckw, cvw))
+
+
+def _dense_head_then_scan(kinds, layer, carry, params, within, xs, fd,
+                          n_layers):
+    """``_scan_paged_layers``' walk for a full / window stack whose first
+    ``fd`` layers run a dense FFN: the periods that hold a dense layer are
+    unrolled (their positions differ from the later periods'), the whole
+    periods after them run under ``_scan_stack``. A plane of the ``dense``
+    group leads with fd, one of the ``experts`` group with n_layers - fd,
+    every other with n_layers (``LMSpec.plane_layers``). -> (carry, the
+    EXPERT layers' stats stacked)."""
+    tmap = jax.tree_util.tree_map
+    group = {k: Block.plane_group(k) for k in params}
+    P = len(kinds) if kinds else 1
+    head = min(-(-fd // P) * P, n_layers)
+    stats = []
+    for l in range(head):
+        p_l = {k: v[l - fd if group[k] == "experts" else l]
+               for k, v in params.items()
+               if group[k] != ("experts" if l < fd else "dense")}
+        carry, st = layer(
+            carry, (p_l, jnp.asarray(l, jnp.int32),
+                    None if within is None else within[l],
+                    tmap(lambda a: a[l], xs)),
+            kinds[l % P] if kinds else None)
+        if st is not None:
+            stats.append(st)
+    stats = [tmap(lambda *a: jnp.stack(a), *stats)] if stats else []
+    if head < n_layers:
+        rest = {k: v[head - fd if group[k] == "experts" else head:]
+                for k, v in params.items() if group[k] != "dense"}
+        carry, ys = _scan_stack(kinds, layer, carry, (
+            rest, jnp.arange(head, n_layers, dtype=jnp.int32),
+            None if within is None else within[head:],
+            tmap(lambda a: a[head:], xs)))
+        stats.append(ys)
+    return carry, tmap(lambda *a: jnp.concatenate(a), *stats)
 
 
 def _mla_paged_step(blk, b, t, project, mask, finish):
@@ -980,7 +1047,8 @@ def _mla_paged_step(blk, b, t, project, mask, finish):
         q_abs = _mm(blk, "bhtn,rhn->bhtr", q_nope, w_uk)
         q_lat = jnp.concatenate([q_abs, q_rope], axis=-1) * scale
         q_lat = jnp.pad(q_lat, ((0, 0),) * 3 + ((0, W - r - rope_d),))
-        if set(mask) == {"lengths"} and paged_attention.supported(W, ck, t):
+        if t == 1 and set(mask) == {"lengths"} \
+                and paged_attention.supported(W, ck, t):
             o_lat = paged_attention.paged_attention_decode(
                 q_lat[:, :, 0], ck, None, l, tbl, mask["lengths"],
                 sm_scale=1.0, name=paged_attention.MLA_KERNEL)
@@ -1023,16 +1091,24 @@ def _paged_layer_step(b, t, ps, project, mask, finish, mla=None):
         # a decode step (one query token a row, keys j < lengths) on a
         # chip walks the block table in one kernel; every other call
         # gathers
-        if set(mask) == {"lengths"} and paged_attention.supported(
-                q.shape[1] * q.shape[3], ck, t):
+        on_walk = paged_attention.supported(q.shape[1] * q.shape[3], ck, t)
+        if t == 1 and set(mask) == {"lengths"} and on_walk:
             ctx = paged_attention.paged_attention_decode(
                 q[:, :, 0], ck, cv, l, tbl, mask["lengths"],
                 window=window)[:, None]
+        elif set(mask) == {"first_len"} and on_walk:
+            # a verify tick: t positions a row on the page walk
+            ctx = paged_attention.paged_attention_verify(
+                q, ck, cv, l, tbl, mask["first_len"], window=window)
         else:
             m = mask
+            if set(mask) == {"first_len"}:
+                # a verify tick off the chip: query j of a row sits at
+                # position first_len - 1 + j and sees the keys up to itself
+                m = dict(causal=True, q_pos0=mask["first_len"] - 1)
             if window is not None:
-                tbl, k_pos0 = _window_span(tbl, mask, t, ps, window)
-                m = dict(mask, k_pos0=k_pos0, window=window)
+                tbl, k_pos0 = _window_span(tbl, m, t, ps, window)
+                m = dict(m, k_pos0=k_pos0, window=window)
             ctx = reference_attention(q.astype(ck.dtype),
                                       _gather_pages(ck, l, tbl, hkv),
                                       _gather_pages(cv, l, tbl, hkv), **m)
@@ -1300,6 +1376,55 @@ def _channel_gate(blk, p, h, ctx):
     return ctx * gate.astype(ctx.dtype)
 
 
+def _draft_block(blk, ins, h, t_next, pool, table, page_id, page_row, pos0,
+                 mask):
+    """The drafting block (``Block.draft_block``; lm_spec.py has its
+    equations) over the t positions a row brings: h [b, t, d] the stack's
+    output BEFORE the final norm, t_next [b, t] the token that follows each
+    position -> (g [b, t, d] the block's output, the full-attention pools
+    with its K/V rows written at (its layer, page_id, page_row), its expert
+    layer's stats). Its layer is the LAST of the full-attention pools
+    (``LMSpec.pool_layers``): same table, same targets as the stack's
+    full-attention layers. ``mask``: the call's own (a chunk's block-causal
+    one, a verify tick's ``first_len``), so a tick's two positions walk the
+    pages as the stack's did."""
+    mblk = blk.draft()
+    b, t, _ = h.shape
+    cache_k, cache_v = pool
+    p = {key: single(ins, DRAFT_SLOT_PREFIX + slot)
+         for slot, key in mblk.stack_slots().items()}
+    # the block's planes are a stack of ONE layer: the resident expert
+    # planes stay whole (``moe_topk`` addresses layer 0 of them)
+    layer_p = {k: (v if k in _RESIDENT_PLANES else v[0])
+               for k, v in p.items()}
+    layer_p["layer"] = jnp.zeros((), jnp.int32)
+    own = {key: single(ins, slot) for slot, key in DRAFT_PLANES.items()}
+    emb = _embed_rows(single(ins, "TokEmb"), t_next)
+    u = jnp.concatenate([_norm(mblk, h, own["norm_h_s"]),
+                         _norm(mblk, emb, own["norm_e_s"])], axis=-1)
+    u = _mm(mblk, "bte,ed->btd", u, own["proj_w"])
+    attend = _paged_layer_step(
+        b, t, cache_k.shape[2],
+        lambda lp, hh: _attn_proj(mblk, lp, hh, pos0=pos0), mask,
+        lambda lp, hh, ctx, _x: _attn_out_ffn(mblk, lp, hh, ctx))
+    g, cache_k, cache_v, stats = attend(
+        u, cache_k, cache_v, cache_k.shape[0] - 1, layer_p, None, table,
+        page_id.reshape(b, t), page_row.reshape(b, t))
+    return g, (cache_k, cache_v), stats
+
+
+def _draft_logits(blk, ins, g):
+    """The shared head over the drafting block's output rows g [n, d]."""
+    return _logits_fn(single(ins, "MtpHeadNormS"), None,
+                      single(ins, "HeadW"), blk.draft())(g)
+
+
+def _with_draft_stats(stats, more):
+    """The stack's expert stats ([Lexp, E] each) with the drafting
+    block's layer appended (its rows count as one more layer's)."""
+    return tuple(jnp.concatenate([a, m[None]]) for a, m in zip(stats, more))
+
+
 def _paged_outs(blk, stats, win, **outs):
     """The paged ops' outputs; an expert block adds ExpertCounts [L, E]
     int32 (rows each expert took in each layer of THIS call) so the
@@ -1349,7 +1474,8 @@ def _window_ins(blk, ins, targets):
 @register_op("transformer_stack_paged_prefill",
              optional_inputs=(_LM_OPTIONAL + _SAMPLING_SLOTS + _WINDOW_SLOTS
                               + _POOL_SLOTS + STATE_SLOTS + ("StateSlot",)
-                              + SNAPSHOT_SLOTS),
+                              + SNAPSHOT_SLOTS + DRAFT_SLOTS
+                              + ("DraftNext",)),
              needs_rng=lambda attrs: (attrs.get("temperature") or 0) > 0)
 def transformer_stack_paged_prefill(attrs, ins, rng=None):
     """Prefill ONE CHUNK of each row's prompt into its block-table pages.
@@ -1387,6 +1513,16 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     pure function of (request, seed, step). ``emit_topk`` > 0 adds
     TopV/TopI [b, emit_topk] (masked top-k log-probs of the last valid
     position) — the beam-search expansion plane.
+
+    A spec with a drafting block (``draft_block``): the block runs over the
+    chunk too, position i with the token that FOLLOWS it — the chunk's
+    next one, after its last valid token DraftNext [b] (the prompt's next
+    token; below 0: the token this call samples, the prompt ends here) —
+    and writes its K/V rows into the last layer of the full-attention
+    pools. NextTok is then [b, 2]: the sampled token and the block's
+    draft of the one after it (argmax; meaningful where the prompt ends);
+    TopV/TopI, where compiled in, [2 b, emit_topk]: the block's top-k
+    log-probs at the same position below the stack's rows.
     """
     # per-row sampling slots, read via _row_sampling/_maybe_topk:
     # "Temperature", "TopK", "TopP", "Seed", "Step", "Mask"
@@ -1457,19 +1593,43 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
         h, cache_k, cache_v, stats, win = _scan_paged_layers(
             params, x, cache_k, cache_v, table, page_id, page_row,
             _paged_project(blk, start), dict(causal=True, q_pos0=start),
-            lambda p, h, ctx, _x_l: _attn_out_ffn(blk, p, h, ctx), blk=blk,
+            lambda p, h, ctx, _x_l: _attn_out_ffn(
+                blk, p, h, ctx, dense="dense_gate_w" in p), blk=blk,
             win=_window_ins(blk, ins, lambda tw: (page_of(tw), page_row)))
-    last = h[jnp.arange(b), jnp.clip(lengths, 1, Tc) - 1]  # [b, d]
+    at_last = jnp.clip(lengths, 1, Tc) - 1
+    last = h[jnp.arange(b), at_last]  # [b, d]
     logits = _logits_fn(ln_s, ln_b, head_w, blk)(last)
     nxt = _pick_rows(attrs, ins, rng, head_w.shape[1], logits)
-    outs = _paged_outs(blk, stats, win, NextTok=nxt.astype(chunk.dtype),
+    nxt = nxt.astype(chunk.dtype)
+    draft_logits = None
+    if blk.draft_block:
+        # "DraftNext": the token after the chunk's last
+        # a drafting block's slots (``lm_spec.DRAFT_SLOTS``), read via
+        # ``_draft_block`` / ``_draft_logits``: "MtpProjW" "MtpNormHS"
+        # "MtpNormES" "MtpHeadNormS" and its one-layer stack's "MtpLn1S"
+        # "MtpQkvW" "MtpQNormS" "MtpKNormS" "MtpOutW" "MtpLn2S" "MtpRouterW"
+        # "MtpMoeGateW" "MtpMoeUpW" "MtpMoeDownW" "MtpSharedGateW"
+        # "MtpSharedUpW" "MtpSharedDownW"
+        after = single(ins, "DraftNext").astype(chunk.dtype)
+        t_next = jnp.concatenate([chunk[:, 1:], chunk[:, :1]], axis=1)
+        t_next = t_next.at[jnp.arange(b), at_last].set(
+            jnp.where(after >= 0, after, nxt))
+        g, (cache_k, cache_v), more = _draft_block(
+            blk, ins, h, t_next, (cache_k, cache_v), table, page_id,
+            page_row, start, dict(causal=True, q_pos0=start))
+        stats = _with_draft_stats(stats, more)
+        draft_logits = _draft_logits(blk, ins, g[jnp.arange(b), at_last])
+        draft = jnp.argmax(draft_logits, axis=-1)
+        nxt = jnp.stack([nxt, draft.astype(nxt.dtype)], axis=1)
+    outs = _paged_outs(blk, stats, win, NextTok=nxt,
                        CacheK=cache_k, CacheV=cache_v, **states)
-    return _maybe_topk(attrs, ins, logits, outs)
+    return _maybe_topk(attrs, ins, logits, outs, draft_logits)
 
 
 @register_op("transformer_stack_paged_decode",
              optional_inputs=(_LM_OPTIONAL + _SAMPLING_SLOTS + _WINDOW_SLOTS
-                              + _POOL_SLOTS + STATE_SLOTS),
+                              + _POOL_SLOTS + STATE_SLOTS + DRAFT_SLOTS
+                              + ("Draft",)),
              needs_rng=lambda attrs: (attrs.get("temperature") or 0) > 0)
 def transformer_stack_paged_decode(attrs, ins, rng=None):
     """One decode step over every slot's paged context.
@@ -1503,6 +1663,9 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     policy, its seed, its step). ``emit_topk`` > 0 adds TopV/TopI
     [S, emit_topk] — beam hypotheses expand from these without a second
     model pass.
+
+    A spec with a drafting block (``draft_block``) runs a VERIFY tick
+    (``_verify_tick``): two positions a slot, one or two tokens out.
     """
     # per-row sampling slots, read via _row_sampling/_maybe_topk:
     # "Temperature", "TopK", "TopP", "Seed", "Step", "Mask"
@@ -1535,6 +1698,16 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
                          f"{table.shape[0]}")
     ps = cache_k.shape[2]
     P = table.shape[1]
+    if blk.draft_block:
+        # "Draft": the tick's second position
+        # a drafting block's slots (``lm_spec.DRAFT_SLOTS``), read via
+        # ``_draft_block`` / ``_draft_logits``: "MtpProjW" "MtpNormHS"
+        # "MtpNormES" "MtpHeadNormS" and its one-layer stack's "MtpLn1S"
+        # "MtpQkvW" "MtpQNormS" "MtpKNormS" "MtpOutW" "MtpLn2S" "MtpRouterW"
+        # "MtpMoeGateW" "MtpMoeUpW" "MtpMoeDownW" "MtpSharedGateW"
+        # "MtpSharedUpW" "MtpSharedDownW"
+        return _verify_tick(attrs, ins, blk, params, tok, pos, table,
+                            cache_k, cache_v)
     pos = jnp.clip(pos, 0, P * ps - 1)
     x = _embed_rows(tok_emb, tok)
     if pos_emb is not None:
@@ -1558,7 +1731,8 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
         h1, cache_k, cache_v, stats, win = _scan_paged_layers(
             params, h1, cache_k, cache_v, table, page_id, page_row,
             _paged_project(blk, pos), dict(lengths=pos + 1),
-            lambda p, h, ctx, _x_l: _attn_out_ffn(blk, p, h, ctx), blk=blk,
+            lambda p, h, ctx, _x_l: _attn_out_ffn(
+                blk, p, h, ctx, dense="dense_gate_w" in p), blk=blk,
             win=_window_ins(blk, ins,
                             lambda tw: (tw[srange, pos // ps], page_row)))
     logits = _logits_fn(ln_s, ln_b, head_w, blk)(h1[:, 0])
@@ -1566,6 +1740,88 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     outs = _paged_outs(blk, stats, win, NextTok=nxt.astype(tok.dtype),
                        CacheK=cache_k, CacheV=cache_v, **states)
     return _maybe_topk(attrs, ins, logits, outs)
+
+
+def _verify_tick(attrs, ins, blk, params, tok, pos, table, cache_k,
+                 cache_v):
+    """The decode tick of a spec with a drafting block: every slot brings
+    TWO positions, its last committed token Tok [S] at Pos [S] and the
+    block's draft of the next one, Draft [S] (below 0: none, the second
+    position then writes the scrap page and is never accepted), at Pos + 1.
+    The stack runs both (K/V rows of both written, each position seeing the
+    keys up to itself: on a chip the page walk, ``paged_attention_verify``),
+    the token after Tok is drawn from row 0 exactly as a one-position tick
+    draws it (``sample_rows``: a pure function of logits, policy, seed and
+    step), and where it EQUALS the draft the token after that is drawn from
+    row 1 at step + 1: acceptance by exact match, so the slot emits the
+    tokens the same engine without the block would. The drafting block
+    then runs over the same two positions with the drawn tokens as their
+    successors and leaves the next draft: from row 1 where the draft was
+    accepted, from row 0 otherwise (row 1's K/V rows, the stack's and the
+    block's, are then overwritten by the next tick, which starts there).
+
+    -> NextTok [S, 3]: the token after Tok, the one after that (-1 unless
+    the draft was accepted) and the next draft; TopV / TopI (the beam
+    plane, where compiled in) [4 S, k]: rows 2 s and 2 s + 1 the two
+    positions of slot s, rows 2 S + 2 s and 2 S + 2 s + 1 the drafting
+    block's at the same two."""
+    S = tok.shape[0]
+    ps, P = cache_k.shape[2], table.shape[1]
+    head_w = single(ins, "HeadW")
+    plane = _row_sampling(ins)
+    if plane is None:
+        raise ValueError("a verify tick draws by the per-row sampling "
+                         "plane (Temperature .. Step)")
+    draft = single(ins, "Draft").astype(jnp.int32)
+    has = draft >= 0
+    pos = jnp.clip(pos, 0, P * ps - 2)
+    poss = pos[:, None] + jnp.arange(2, dtype=jnp.int32)[None, :]   # [S, 2]
+    toks = jnp.stack([tok.astype(jnp.int32), jnp.maximum(draft, 0)], axis=1)
+    live2 = jnp.stack([jnp.ones_like(has), has], axis=1)
+
+    def targets(tbl):
+        page = jnp.take_along_axis(tbl, poss // ps, axis=1)
+        return jnp.where(live2, page, 0), poss % ps
+
+    page_id, page_row = targets(table)
+    x = _embed_rows(single(ins, "TokEmb"), toks)                    # [S, 2, d]
+    # query j of a slot sees the keys below first_len + j
+    mask = dict(first_len=pos + 1)
+    # "CacheKW" "CacheVW" "BlockTableW": the window kind (_window_ins)
+    h, cache_k, cache_v, stats, win = _scan_paged_layers(
+        params, x, cache_k, cache_v, table, page_id, page_row,
+        _paged_project(blk, pos), mask,
+        lambda p, hh, ctx, _x_l: _attn_out_ffn(
+            blk, p, hh, ctx, dense="dense_gate_w" in p), blk=blk,
+        win=_window_ins(blk, ins, targets))
+    logits = _logits_fn(single(ins, "FinalLnS"), maybe(ins, "FinalLnB"),
+                        head_w, blk)(h.reshape(2 * S, -1))
+    from ..kernels.sampling import sample_rows
+
+    temp, top_k, top_p, seed, step, row_mask = plane
+    def twice(a):       # a slot's policy, for both of its rows
+        return jnp.repeat(a, 2, axis=0)
+
+    step2 = (step.astype(jnp.int32)[:, None]
+             + jnp.arange(2, dtype=jnp.int32)[None, :]).reshape(-1)
+    y = sample_rows(logits, twice(temp), twice(top_k), twice(top_p),
+                    twice(seed), step2,
+                    None if row_mask is None else twice(row_mask))
+    y = y.reshape(S, 2).astype(jnp.int32)
+    accept = has & (y[:, 0] == draft)
+    g, (cache_k, cache_v), more = _draft_block(
+        blk, ins, h, y, (cache_k, cache_v), table, page_id, page_row, pos,
+        mask)
+    draft_logits = _draft_logits(blk, ins, g.reshape(2 * S, -1))
+    cand = jnp.argmax(draft_logits, axis=-1).reshape(S, 2).astype(jnp.int32)
+    nxt = jnp.stack([y[:, 0], jnp.where(accept, y[:, 1], -1),
+                     jnp.where(accept, cand[:, 1], cand[:, 0])], axis=1)
+    outs = _paged_outs(blk, _with_draft_stats(stats, more), win,
+                       NextTok=nxt.astype(tok.dtype), CacheK=cache_k,
+                       CacheV=cache_v)
+    return _maybe_topk(attrs, {**ins, "Mask": [twice(row_mask)]}
+                       if row_mask is not None else ins, logits, outs,
+                       draft_logits)
 
 
 @register_op("kv_cache_page_copy", optional_inputs=_POOL_SLOTS)

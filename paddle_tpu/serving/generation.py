@@ -405,6 +405,32 @@ class GenerationEngine:
     prefill chunk, so a request that enters at a boundary is chunked
     where the cold one was and serves the same bits.
 
+    **A drafting block** (``LMSpec(draft_block=True)``: a
+    multi-token-prediction block behind the stack): the decode tick is a
+    VERIFY tick (``ops.pipeline_ops._verify_tick``). A slot brings two
+    positions, its last committed token and the block's draft of the next
+    (``serving.draft``, one more column of the tick's plane; -1: none),
+    the device decides acceptance by exact match with the token it draws
+    anyway, and the slot emits ONE OR TWO tokens and advances as many
+    positions; a request that ends on the first of two stops there. The
+    rejected position's K/V rows are overwritten by the next tick, which
+    starts at that position; a window kind lets go only of pages behind
+    the COMMITTED position. The block's K/V is one more layer of the
+    full-attention pools (same table, same prefix index), so a prefix hit
+    brings its pages too, cut back to the last page boundary strictly
+    inside the match (the block's row at a position depends on the token
+    AFTER it). Prefill runs the block over the prompt and returns the
+    first draft beside the first token. A slot whose request carries a
+    logits processor is fed no draft (its mask for the second position
+    would need the first's token). Tokens are those of the same engine
+    without the block, greedy and sampled alike. Counters:
+    ``mtp_drafted`` / ``mtp_accepted`` / ``mtp_first_ticks`` (a live
+    slot's tick without a draft) / ``verify_rows_rejected`` /
+    ``decode_live_rows`` (live slots summed over ticks; ``decode_tokens``
+    stays the tokens EMITTED); gauge ``mem/mtp_param_bytes``. Beams,
+    ``share_cache_with=`` and the slot handoff raise
+    ``BlockNotSupportedError`` (``Block.require_no_draft``).
+
     **What a call feeds**: ONE host buffer, the packed int32 plane of its
     rows (``FeedPlane``: token and position or chunk, start and length,
     the sampling policy, the row's slot and snapshot rows, the block
@@ -613,7 +639,7 @@ class GenerationEngine:
         kw = dict(row_width=spec.cache_row_width, n_pools=spec.cache_pools,
                   count=self.metrics.inc)
         self._caches: List[PageCache] = [PageCache(
-            "global", pool, index, layers=spec.layers_of(False), **kw)]
+            "global", pool, index, layers=spec.pool_layers(False), **kw)]
         if spec.block.has_window:
             # what a slot's window layers can hold at once (the window,
             # the chunk in flight, one page of slack each way)
@@ -624,13 +650,18 @@ class GenerationEngine:
             self._caches.append(PageCache(
                 "window", wpool,
                 PrefixIndex(wpool) if self._prefix_sharing else None,
-                layers=spec.layers_of(True), window=spec.window, live=live,
+                layers=spec.pool_layers(True), window=spec.window, live=live,
                 **kw))
         # no scrap SLOT — padding/vacant rows write the scrap PAGE, so
         # the decode batch is exactly the slot count
         self._slots: List[Optional[_Slot]] = [None] * self.slots
         self._tok = np.zeros(self.slots, np.int64)
         self._pos = np.zeros(self.slots, np.int32)
+        #: a drafting block: the draft each slot's next tick verifies (-1:
+        #: none) and what the last tick was fed of it
+        self._draft = bool(spec.draft_block)
+        self._draft_tok = np.full(self.slots, -1, np.int32)
+        self._fed_draft = self._draft_tok.copy()
         self._deferred = deque()  # pool-blocked validated admissions
         self._pf_cursor = 0       # round-robin over prefilling slots
         self._beam_jobs: List[BeamJob] = []
@@ -791,6 +822,13 @@ class GenerationEngine:
                     for rows in {self.slots, *self.prefill_batch_buckets}}
         self.metrics.set_gauge("mem/state_bytes_per_slot",
                                float(self.spec.state_bytes_per_slot))
+        if self._draft:
+            from ..core.types import to_dtype as _dt
+
+            self.metrics.set_gauge(
+                "mem/mtp_param_bytes",
+                float(self.spec.draft_param_count()
+                      * np.dtype(_dt(self.spec.param_dtype)).itemsize))
         if self._snapshots:
             self.metrics.set_gauge(
                 "mem/state_snapshot_bytes",
@@ -850,9 +888,10 @@ class GenerationEngine:
         """The ops' weight slots; a weight with an AMP operand copy is
         bound to the copy (the float32 parameter stays declared: it is
         resident, and the memory analysis prices both)."""
-        from ..models.transformer import _shared_lm_params
+        from ..models.transformer import _shared_lm_params, draft_params
 
-        ins = _shared_lm_params(helper, self.spec)
+        ins = {**_shared_lm_params(helper, self.spec),
+               **draft_params(helper, self.spec)}
         for slot, (var,) in ins.items():
             if var.name in self._operands:
                 ins[slot] = [helper.create_global_variable(
@@ -885,6 +924,12 @@ class GenerationEngine:
                 [("serving.chunk", "Chunk", tc, "int32", self.pad_id),
                  ("serving.start", "StartPos", 0, "int32", 0),
                  ("serving.chunk_len", "Lengths", 0, "int32", 0)])
+        if self._draft:
+            # the tick's second position (-1: none); the token after a
+            # chunk's last (-1: the one the call samples)
+            cols.append(("serving.draft", "Draft", 0, "int32", -1)
+                        if tc is None else
+                        ("serving.draft_next", "DraftNext", 0, "int32", 0))
         # a row without a policy is greedy (warmup, vacant slots, padding)
         cols += [("serving.topk", "TopK", 0, "int32", 0),
                  ("serving.seed", "Seed", 0, "int32", 0),
@@ -939,7 +984,7 @@ class GenerationEngine:
             return {}
         counts = helper.block.create_var(
             name="serving.expert_counts",
-            shape=[self.spec.plane_layers("router_w"),
+            shape=[self.spec.plane_layers("router_w") + int(self._draft),
                    self.spec.num_experts],
             dtype="int32", stop_gradient=True)
         return {"ExpertCounts": [counts]}
@@ -1000,15 +1045,20 @@ class GenerationEngine:
                                  startup_program=startup)
             ins = self._call_ins(helper, tc)
             pools = self._pool_io(helper, self._caches[:1])
+            # a drafting block: (token, second token or -1, next draft) a
+            # slot of a tick, (first token, first draft) a prefill row
             nxt = helper.block.create_var(
-                name="serving.next_tok", shape=[rows],
+                name="serving.next_tok", shape=[rows] + (
+                    [3 if tc is None else 2] if self._draft else []),
                 dtype="int64", stop_gradient=True)
             held = {**pools, **self._pool_io(helper, self._caches[1:]),
                     **self._state_io(helper, snapshots=tc is not None)}
             ins.update({**held, **self._lm_ins(helper)})
             outs = {"NextTok": [nxt], **held}
+            # (a drafting block's rows lie below the stack's: 2 + 2 a slot)
             outs.update(self._beam_out_vars(
-                helper, rows, "serving.dec" if tc is None else "serving.pf"))
+                helper, rows * 4 if self._draft and tc is None else rows,
+                "serving.dec" if tc is None else "serving.pf"))
             outs.update(self._expert_out_vars(helper))
             helper.append_op(f"transformer_stack_paged_{what}", ins,
                              outs, self._decode_attrs())
@@ -1307,8 +1357,12 @@ class GenerationEngine:
         copy = self._run_page_copy
         for i, cache in enumerate(self._caches):
             for slot in decoding:
-                cache.before_write(self._slots[slot].held[i],
-                                    int(self._pos[slot]), copy)
+                pos = int(self._pos[slot])
+                # (a verify tick also writes the draft's position)
+                cache.before_write(
+                    self._slots[slot].held[i], pos, copy,
+                    last=pos + int(self._draft
+                                   and self._feeds_draft(slot)))
 
     def _table_rows(self, st: _Slot, cols: dict, row: int, q_first: int,
                     q_last: int) -> None:
@@ -1397,7 +1451,8 @@ class GenerationEngine:
         behind the slot as it advances)."""
         ps, start = self.page_size, st.prefill_done
         index = self.prefix_index
-        if index is None or len(self._caches) > 1 or start % ps:
+        if index is None or len(self._caches) > 1 or start % ps \
+                or self._draft:     # (its rows follow the NEXT token)
             return
         i, last = start // ps, (int(st.prompt.size) - 1) // ps
         key, hits, best = st.prefix_key, [], None
@@ -1543,6 +1598,8 @@ class GenerationEngine:
                 "beam search (a fork shares its parent's pages)"
                 if beam is not None else "resume-from-token")
         if beam is not None:
+            self.spec.block.require_no_draft(
+                "beam search (a hypothesis advances one token a step)")
             if len(self._caches) > 1:
                 raise BlockNotSupportedError(
                     "beam search forks one block table; this engine's "
@@ -1693,7 +1750,13 @@ class GenerationEngine:
             found = [cache.index.lookup(prompt) for cache in caches]
             shared, key = min(f[0] for f in found), found[0][2]
             hits = [f[1][:self._entries_for(shared)] for f in found]
-            if len(caches) == 1:
+            if self._draft and shared:
+                # the drafting block's row at a position was made with the
+                # token AFTER it: of a match only the pages strictly
+                # inside it hold rows this prompt would have made
+                shared = (shared - 1) // self.page_size * self.page_size
+                hits = [hit[:shared // self.page_size] for hit in hits]
+            if len(caches) == 1 and not self._draft:
                 # ... and the pages a slot is still prefilling for the
                 # same tokens: held from now on, written by whichever of
                 # the two comes to a chunk first (``_adopt_prefilled``)
@@ -1784,6 +1847,7 @@ class GenerationEngine:
             st.state = "decode"
             self._tok[slot] = prompt[-1]
             self._pos[slot] = plen - 1
+            self._draft_tok[slot] = -1
         elif remaining <= self.prefill_chunk:
             st.state = "prefill"
             group.append((req, st, slot))
@@ -1843,6 +1907,8 @@ class GenerationEngine:
                 cols["serving.chunk"][row, :r] = st.prompt[st.prefill_done:]
                 cols["serving.start"][row] = st.prefill_done
                 cols["serving.chunk_len"][row] = r
+                if self._draft:     # the prompt ends here
+                    cols["serving.draft_next"][row] = -1
                 self._table_rows(st, cols, row, st.prefill_done,
                                  st.prompt.size - 1)
                 # step = tokens already sampled: 0 for a fresh request; a
@@ -1892,9 +1958,12 @@ class GenerationEngine:
             st.role = "beam"
             st.beam_job.on_parent_row(topv[row], topi[row])
             return
-        self._tok[slot] = first[row]
+        tok = first[row]
+        if self._draft:
+            tok, self._draft_tok[slot] = tok
+        self._tok[slot] = tok
         self._pos[slot] = st.prompt.size
-        self._emit(slot, int(first[row]))
+        self._emit(slot, int(tok))
 
     def _admit_deferred(self) -> int:
         """Retry pool-blocked admissions in arrival order. Expired ones
@@ -2051,6 +2120,9 @@ class GenerationEngine:
             cols["serving.chunk"][0, :k] = st.prompt[start0:start0 + k]
             cols["serving.start"][0] = start0
             cols["serving.chunk_len"][0] = k
+            if self._draft:
+                cols["serving.draft_next"][0] = (
+                    st.prompt[start0 + k] if start0 + k < plen else -1)
             self._table_rows(st, cols, 0, start0, start0 + k - 1)
             # same step contract as the group path: 0 unless resumed
             self._slot_sampling_feed(0, st, cols, step=len(st.generated))
@@ -2091,6 +2163,12 @@ class GenerationEngine:
         caches, slots = self._caches, self._slots
         rows = [s for s in range(self.slots)
                 if slots[s] is not None and slots[s].state == "decode"]
+        if self._draft:
+            draft = cols["serving.draft"]
+            for s in rows:
+                if self._feeds_draft(s):
+                    draft[s] = self._draft_tok[s]
+            self._fed_draft = draft.copy()
         for s in rows:
             st = slots[s]
             tok[s] = self._tok[s]
@@ -2116,11 +2194,15 @@ class GenerationEngine:
         # the pages the decode attention walks this tick (one per slot at
         # least: a vacant slot reads the scrap page) against the table it
         # would gather whole (kernels/paged_attention.py)
+        # a verify tick's walk covers both positions' reach in ONE pass
+        # (``paged_attention_verify``): from the first position's first page
+        # to the second position's page
+        last = pos + 1 if self._draft else None
         if len(caches) == 1:
             # (a latent spec's pages too: ONE pool row a token, read once
             # for key and value, ``paged_mla_decode``)
             self.metrics.inc("paged_attn_pages_read",
-                             caches[0].pages_read(pos))
+                             caches[0].pages_read(pos, last))
             self.metrics.inc("paged_attn_table_pages", tables[0].size)
         else:
             for i, cache in enumerate(caches):
@@ -2128,7 +2210,7 @@ class GenerationEngine:
                 # what a slot holds, a window layer from its window's
                 # first page
                 self.metrics.inc(f"paged_attn_pages_read_{cache.name}",
-                                 cache.pages_read(pos))
+                                 cache.pages_read(pos, last))
                 # ... against what ONE table for all layers would hold for
                 # the same slots: every page, as the full-attention kind
                 n = cache.held_pages(st.held[i] for st in self._slots
@@ -2160,8 +2242,15 @@ class GenerationEngine:
         res = self.executor.run(prog, feed=feed,
                                 fetch_list=self._fetches(outs),
                                 scope=self.scope)
-        self._count_experts(res, self.slots)
+        self._count_experts(res, self.slots * (2 if self._draft else 1))
         return self._tokens_of(res)
+
+    def _feeds_draft(self, slot: int) -> bool:
+        """Whether ``slot``'s next tick brings its pending draft: it has
+        one, and no logits processor (whose mask for the second position
+        would need the first's token)."""
+        return (self._draft_tok[slot] >= 0
+                and self._slots[slot].mask_proc is None)
 
     def _tokens_of(self, res):
         """A paged call's fetches on the host: -> (NextTok, TopV, TopI),
@@ -2183,12 +2272,14 @@ class GenerationEngine:
             return False
         self._cow_guard(decoding)
         t0 = time.perf_counter()
-        with trace.span("serving/decode_step", active=len(decoding)):
+        with trace.span("serving/decode_step", active=len(decoding),
+                        **({"positions": 2} if self._draft else {})):
             nxt, topv, topi = self._run_decode()
         self.metrics.observe_latency(time.perf_counter() - t0,
                                      name="decode_step")
         self.metrics.inc("decode_steps")
-        self.metrics.inc("decode_tokens", len(decoding))
+        if not self._draft:
+            self.metrics.inc("decode_tokens", len(decoding))
         self.metrics.set_gauge("batch_occupancy",
                                len(decoding) / self.slots)
         beam_rows: Dict[BeamJob, dict] = {}
@@ -2205,6 +2296,9 @@ class GenerationEngine:
                     beam_rows.setdefault(st.beam_job, {})[slot] = (
                         topv[slot], topi[slot])
                 continue
+            if self._draft:
+                self._emit_verified(slot, st, nxt[slot])
+                continue
             self._pos[slot] += 1
             self._tok[slot] = nxt[slot]
             self._emit(slot, int(nxt[slot]))
@@ -2215,6 +2309,28 @@ class GenerationEngine:
         self._maybe_replica_kill()
         self._gauges()
         return True
+
+    def _emit_verified(self, slot: int, st: _Slot, row) -> None:
+        """One slot's share of a verify tick: ``row`` = (the token after
+        the slot's last, the one after that or -1: the draft was not
+        accepted, the next draft). Emits one or two tokens and advances as
+        many positions; a request that ends on the first stops there."""
+        first, second, nxt_draft = (int(v) for v in row)
+        drafted = self._fed_draft[slot] >= 0
+        self.metrics.inc("decode_live_rows")
+        self.metrics.inc("mtp_drafted", int(drafted))
+        self.metrics.inc("mtp_first_ticks", int(not drafted))
+        self.metrics.inc("mtp_accepted", int(second >= 0))
+        self.metrics.inc("verify_rows_rejected",
+                         int(drafted and second < 0))
+        self._draft_tok[slot] = nxt_draft
+        for token in (first, second):
+            if token < 0 or self._slots[slot] is not st:
+                break
+            self._pos[slot] += 1
+            self._tok[slot] = token
+            self.metrics.inc("decode_tokens")
+            self._emit(slot, token)
 
     # -- beam search as paged forks ----------------------------------------
     def _fork_layout(self, pages: List[int], n_written: int):
@@ -2578,6 +2694,7 @@ class GenerationEngine:
         block.require_one_kind(who)
         block.require_mha(who)
         block.require_stateless(who)
+        block.require_no_draft(who)
 
     def handoff_ready(self) -> List[int]:
         """Slots eligible to migrate to a decode pool: prompt K/V fully

@@ -519,16 +519,25 @@ class PageCache:
             pages.append(self._alloc(held)
                          if len(pages) >= held.first else 0)
 
-    def before_write(self, held: Held, pos: int, copy_fn) -> None:
-        """What a decode tick that writes position ``pos`` needs of the
-        slot's table first: the page under it, and that its own. One
-        still shared (refcount > 1) is copied (``copy_fn(cache, src,
-        dst)``) to a fresh page from the slot's admission-time spare and
-        the table redirected: copy-on-write. (Every live slot passes here
-        every tick: the common case is one refcount read.)"""
+    def before_write(self, held: Held, pos: int, copy_fn,
+                     last: Optional[int] = None) -> None:
+        """What a decode tick that writes position ``pos`` (a verify tick:
+        ``pos..last``, its queries' positions too) needs of the slot's
+        table first: the page under it, and that its own. One still shared
+        (refcount > 1) is copied (``copy_fn(cache, src, dst)``) to a fresh
+        page from the slot's admission-time spare and the table
+        redirected: copy-on-write. A window kind lets go only of what the
+        query at ``pos``, the COMMITTED position, can no longer reach.
+        (Every live slot passes here every tick: the common case is one
+        refcount read.)"""
+        last = pos if last is None else last
         if self.window:
-            self.advance(held, pos, pos)
-        entry = pos // self.page_size
+            self.advance(held, pos, last)
+        for entry in range(pos // self.page_size,
+                           last // self.page_size + 1):
+            self._own(held, entry, copy_fn)
+
+    def _own(self, held: Held, entry: int, copy_fn) -> None:
         pid = held.pages[entry]
         if self.pool._ref[pid] <= 1:
             return
@@ -566,13 +575,15 @@ class PageCache:
             held.reserve = held.cow = 0
 
     # -- accounting --------------------------------------------------------
-    def pages_read(self, pos: np.ndarray) -> int:
+    def pages_read(self, pos: np.ndarray, last=None) -> int:
         """The pages a decode tick's attention walks in a layer of this
         kind for queries at ``pos`` (one a row at least: a vacant slot
-        reads the scrap page)."""
+        reads the scrap page); with ``last``, the pages queries at
+        ``pos..last`` reach between them, each counted once."""
         first = (np.maximum(pos + 1 - self.window, 0) // self.page_size
                  if self.window else 0)
-        return int((pos // self.page_size + 1 - first).sum())
+        last = pos if last is None else last
+        return int((last // self.page_size + 1 - first).sum())
 
     def held_pages(self, helds: Iterable[Held]) -> int:
         """Distinct pages the slots hold (``helds``: theirs of this

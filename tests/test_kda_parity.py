@@ -594,7 +594,10 @@ def test_a_latent_row_is_held_at_whole_lane_rows(widths, row):
     (dict(router_score="tanh"), "router_score"),
     (dict(n_group=2, topk_group=3), "topk_group"),
     (dict(attn_gate="row"), "attn_gate"),
-    (dict(first_dense=1, layer_pattern=None, attn="mha"), "first_dense"),
+    # (a dense head under full / window K/V layers is a spec since PR 49;
+    # one latent kind without a pattern still has no planes for it)
+    (dict(first_dense=1, layer_pattern=None), "first_dense"),
+    (dict(draft_block=True), "draft_block"),
 ])
 def test_block_refuses_what_it_cannot_mean(kw, msg):
     base = dict(num_heads=2, use_rope=True, norm="rms_norm", bias=False,

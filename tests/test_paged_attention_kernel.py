@@ -596,7 +596,9 @@ def test_supported_reads_shapes_dtype_and_backend_only(monkeypatch, dtype,
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     pool = jax.ShapeDtypeStruct((2, 8, ps, 256), dtype)
     assert paged_attention.supported(256, pool, 1) is ok
-    assert not paged_attention.supported(256, pool, 2)
+    # the two positions of a verify tick stay on the page walk; a chunk not
+    assert paged_attention.supported(256, pool, 2) is ok
+    assert not paged_attention.supported(256, pool, 3)
     assert paged_attention.supported(512, pool, 1) is ok    # Hkv = H / 2
     assert not paged_attention.supported(384, pool, 1)      # no whole groups
     narrow = jax.ShapeDtypeStruct((2, 8, ps, 64), dtype)

@@ -78,15 +78,24 @@ MODULES = [
      "holds beside its pages, which GenerationEngine(snapshot_stride=, "
      "n_snapshots=) also keeps as snapshot rows for its prefix index; "
      "require_stateless still refuses beams, resume, the slot handoff and "
-     "share_cache_with=), first_dense leading dense layers, the router's "
-     "score / bias / groups"),
+     "share_cache_with=), first_dense leading dense layers (of a stack "
+     "held by attention kind, or of full / window K/V layers: "
+     "plane_layers), the router's score / bias / groups; draft_block = a "
+     "drafting (multi-token-prediction) block behind the stack "
+     "(draft_planes / draft_spec / pool_layers: its K/V is one more "
+     "full-attention layer of the pools; GenerationEngine then runs "
+     "verify ticks of two positions a slot that emit one or two tokens; "
+     "require_no_draft refuses beams, the slot handoff, "
+     "share_cache_with=, DisaggEngine.build and a pp mesh)"),
     ("paddle_tpu.ops.moe_ops",
      "The expert layer: moe_topk (dropless top-k; shared=, held=, "
      "routed_scale=; score= softmax | sigmoid, bias=, n_group=, "
      "topk_group=) and the Switch op"),
     ("paddle_tpu.kernels.flash_attention", "Pallas flash attention"),
     ("paddle_tpu.kernels.paged_attention",
-     "Pallas paged decode attention: walks the block table"),
+     "Pallas paged decode attention: walks the block table (one query "
+     "position a row, or a verify tick's two folded into one walk: "
+     "paged_attention_verify)"),
     ("paddle_tpu.kernels.kda",
      "Kimi Delta Attention: the gated delta rule with a per-channel decay "
      "token by token, chunked (prefill), and the kda_decode_step Pallas "
