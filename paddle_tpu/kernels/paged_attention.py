@@ -51,11 +51,27 @@ reads only the pages a row HOLDS, straight from the whole pool:
   online softmax are the ones above; the call has a name of its own
   because its bytes are counted differently (one pool).
 
+* a prefill chunk (``paged_attention_prefill``, the call name
+  ``PREFILL_KERNEL``): Tc queries a row at positions ``start .. start + Tc
+  - 1``, the first ``lengths`` of them real. The same operands and the
+  same mathematics in a chunk-shaped body (``_prefill_kernel``): the grid
+  is (row, query tile); a tile of tq queries walks the pages ITS queries
+  can reach (from the window's first page to the page of its last real
+  key, never the table's width) in blocks of up to 1024 keys, each page one
+  contiguous [ps, Hkv*dh] DMA, double buffered; per cached head the
+  tile's G * tq query rows [G*tq, dh] meet the lane-aligned [keys, dh]
+  column block of the page tile, so the float32 scores [G*tq, keys] live
+  in VMEM and nowhere else (no block-diagonal query here: at chunk shapes
+  it would multiply the MXU work by Hkv). Padding queries and padding
+  rows attend nothing and leave zeros.
+
 The jnp gather + ``reference_attention`` stays the semantic ground truth
-and the path of every other shape (a prefill chunk, CPU, unaligned
-widths; the two positions of a verify tick stay on the page walk:
-``paged_attention_verify``):
-``supported`` is the whole dispatch rule, read off the operands.
+and the path of every other shape (the CPU, unaligned widths, a head
+narrower than the lanes under a chunk, the latent block's chunk): the two
+positions of a verify tick stay on the page walk
+(``paged_attention_verify``). ``supported`` (a tick) and
+``chunk_supported`` (a prefill chunk) are the whole dispatch rule, read
+off the operands.
 """
 from __future__ import annotations
 
@@ -69,6 +85,9 @@ import jax.numpy as jnp
 #: roofline readers tell a call by
 KERNEL = "paged_attention_decode"
 MLA_KERNEL = "paged_mla_decode"
+#: a prefill chunk's call: a name of its own, so that no reader of the
+#: decode calls (a TICK's page read) counts it
+PREFILL_KERNEL = "paged_attention_prefill"
 
 #: query positions a row of a verify tick may bring (``supported``)
 VERIFY_POSITIONS = 2
@@ -97,6 +116,57 @@ def supported(q_width: int, pool, t: int) -> bool:
     return (1 <= t <= VERIFY_POSITIONS and jax.default_backend() == "tpu"
             and q_width % width == 0 and width % 128 == 0
             and ps % _sublane_tile(pool.dtype) == 0)
+
+
+#: the keys of a prefill chunk's mask (``ops/pipeline_ops``): block-causal
+#: from ``q_pos0`` [b], the first ``q_len`` [b] queries of a row real
+CHUNK_MASK = frozenset({"causal", "q_pos0", "q_len"})
+#: query rows (heads x queries) one grid step of the chunk walk holds: its
+#: running max / denominator / accumulator are ~1.5 KB of VMEM a row
+_CHUNK_ROWS = 4096
+#: keys one step of the chunk walk meets, at most (whole pages; at least
+#: one), and the bytes of its K block (as many again of V, both twice: two
+#: in flight). By my chip runs (PR 50; 64 / 8 heads of 128, bf16, 12k keys):
+#: 2.66 ms a call at 256 keys, 1.39 at 512, 0.90 at 1024, and no loss at 1k
+#: keys: a step's fixed part (running max, denominator, rescale of the
+#: accumulator) is as many vector ops as its scores at 128 keys
+_CHUNK_KEYS = 1024
+_CHUNK_BLOCK_BYTES = 2 * 2 ** 20
+#: the chunk walk's VMEM: the tile's queries and context (double buffered by
+#: the pipeline), two K and two V blocks, its float32 state and a few
+#: [rows of a head, keys] score temporaries come to 20-30 MB at float32
+#: pages, over the compiler's default
+_CHUNK_VMEM = 64 * 2 ** 20
+
+
+def _query_tile(t: int, heads: int, dtype):
+    """The queries one grid step of the chunk walk takes: the largest
+    divisor of ``t`` in whole sublane tiles with heads * tq <=
+    ``_CHUNK_ROWS`` (the smallest such divisor where none fits); None
+    where ``t`` is not whole tiles."""
+    tile = _sublane_tile(dtype)
+    fits = [d for d in range(tile, t + 1, tile) if t % d == 0]
+    if not fits:
+        return None
+    return max([d for d in fits if heads * d <= _CHUNK_ROWS] or fits[:1])
+
+
+def chunk_supported(q_shape, pool, mask) -> bool:
+    """Whether the chunk walk (``paged_attention_prefill``) can take this
+    call: queries ``q_shape`` = [b, H, t, dh] of a prefill chunk (more
+    positions than a verify tick's, a mask of the ``CHUNK_MASK`` kind), a
+    TPU backend, whole groups of query heads over the cached heads, a head
+    of whole lane rows (the body slices the page tile by head), a page and
+    a chunk of whole sublane tiles. Shapes, a dtype, the mask's keys and
+    the backend: nothing names a model."""
+    _, heads, t, d_head = q_shape
+    ps, width = pool.shape[2:]
+    return (t > VERIFY_POSITIONS and set(mask) == CHUNK_MASK
+            and jax.default_backend() == "tpu"
+            and d_head % 128 == 0 and width % d_head == 0
+            and (heads * d_head) % width == 0
+            and ps % _sublane_tile(pool.dtype) == 0
+            and _query_tile(t, heads, pool.dtype) is not None)
 
 
 def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, *rest,
@@ -384,3 +454,220 @@ def paged_attention_verify(q, cache_k, cache_v, layer, table, lengths,
     position's window and ends at the last position's page."""
     return _walk(q.transpose(0, 2, 1, 3), cache_k, cache_v, layer, table,
                  lengths, interpret, window, None, KERNEL)
+
+
+def chunk_pages_in_reach(start, length, ps, window=None, xp=jnp):
+    """(first, end): the pages ``first <= i < end`` of its table a chunk
+    row's walk reads: queries at positions ``start .. start + length - 1``
+    reach back to position 0 (a full layer) or to ``start - window + 1``,
+    and forward to the chunk's last real key. A padding row (``length`` 0)
+    reads none. ``xp``: ``numpy`` for host arrays (the engine counts with
+    this rule what the kernel walks)."""
+    first = (xp.zeros_like(start) if window is None
+             else xp.maximum(start - window + 1, 0) // ps)
+    return first, xp.where(length > 0, (start + length + ps - 1) // ps, first)
+
+
+def _prefill_kernel(layer_ref, table_ref, start_ref, len_ref, q_ref, k_hbm,
+                    v_hbm, o_ref, kbuf, vbuf, sems, m_ref, l_ref, acc_ref, *,
+                    d_head, pmax, group, window):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s, qt = pl.program_id(0), pl.program_id(1)
+    ps = k_hbm.shape[2]
+    kv_heads, rows, _ = acc_ref.shape           # rows = group * tq
+    tq = rows // group
+    keys = kbuf.shape[1]                        # a block: whole pages
+    per = keys // ps
+    sm_scale = 1.0 / math.sqrt(d_head)
+    layer = jnp.clip(layer_ref[0], 0, k_hbm.shape[0] - 1)
+    start, length = start_ref[s], len_ref[s]
+    # this tile's queries sit at chunk offsets q0 .. q0 + tq - 1, of which
+    # the first ``real`` are real; the pages they reach: [first, end)
+    q0 = qt * tq
+    real = jnp.clip(length - q0, 0, tq)
+    first, end = chunk_pages_in_reach(start + q0, real, ps, window)
+    end = jnp.minimum(end, pmax)
+    n_blocks = (jnp.maximum(end - first, 0) + per - 1) // per
+
+    def page_copies(buf, j, c):
+        """Page ``c`` of block ``j`` into its rows of buffer ``buf``."""
+        # a block's tail past the walk's end reads the last page in reach
+        # again (its keys sit past every query: masked)
+        i = jnp.minimum(first + j * per + c, end - 1)
+        page = jnp.clip(table_ref[s * pmax + i], 0, k_hbm.shape[1] - 1)
+        at = pl.ds(pl.multiple_of(c * ps, ps), ps)
+        return (pltpu.make_async_copy(k_hbm.at[layer, page],
+                                      kbuf.at[buf, at], sems.at[0, buf, c]),
+                pltpu.make_async_copy(v_hbm.at[layer, page],
+                                      vbuf.at[buf, at], sems.at[1, buf, c]))
+
+    def each_page(buf, j, do):
+        def one(c, _):
+            for copy in page_copies(buf, j, c):
+                do(copy)
+            return 0
+        jax.lax.fori_loop(0, per, one, 0)
+
+    @pl.when(n_blocks > 0)
+    def _():
+        each_page(0, 0, lambda copy: copy.start())
+
+    precision = (jax.lax.Precision.HIGHEST if kbuf.dtype == jnp.float32
+                 else None)
+    # row r of a cached head's [group * tq] query rows is query r % tq of
+    # head r // tq of its group: the query's position, by comparisons; a
+    # padding query sits at -1, before every key
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    i = r - tq * sum((r >= j * tq).astype(jnp.int32)
+                     for j in range(1, group))
+    q_pos = jnp.where(q0 + i < length, start + q0 + i, -1)
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def block(j, _):
+        buf = j % 2
+
+        @pl.when(j + 1 < n_blocks)
+        def _():
+            each_page(1 - buf, j + 1, lambda copy: copy.start())
+
+        each_page(buf, j, lambda copy: copy.wait())
+        key = (first + j * per) * ps + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, keys), 1)
+        seen = key <= q_pos
+        if window is not None:
+            seen = seen & (q_pos - key < window)
+
+        def head(h, _):
+            cols = pl.ds(pl.multiple_of(h * d_head, d_head), d_head)
+            q = q_ref[0, pl.ds(h * group, group)].reshape(rows, d_head)
+            sc = jax.lax.dot_general(
+                q, kbuf[buf, :, cols],
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                precision=precision,
+                preferred_element_type=jnp.float32) * sm_scale  # [rows, keys]
+            sc = jnp.where(seen, sc, -jnp.inf)
+            m = m_ref[h]
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+            # a query with no key yet keeps exp() off inf - inf
+            m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+            p = jnp.exp(sc - m_safe)
+            alpha = jnp.exp(m - m_safe)
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = alpha * acc_ref[h] + jax.lax.dot_general(
+                p.astype(vbuf.dtype), vbuf[buf, :, cols],
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                precision=precision,
+                preferred_element_type=jnp.float32)             # [rows, dh]
+            m_ref[h] = m_new
+            return 0
+
+        # a LOOP over the cached heads, not an unrolled body: unrolled it is
+        # a third faster at 12k keys (my chip run, PR 50) and costs every
+        # start-up ~0.3 s a call site in tracing and lowering
+        jax.lax.fori_loop(0, kv_heads, head, 0)
+        return 0
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+    # normalised, a token's heads side by side; a query without a key has
+    # accumulated nothing and stays zero
+    for h in range(kv_heads):
+        l = l_ref[h]
+        ctx = acc_ref[h] / jnp.where(l > 0, l, 1.0)
+        for g in range(group):
+            n = h * group + g
+            o_ref[0, :, n * d_head:(n + 1) * d_head] = ctx[
+                g * tq:(g + 1) * tq].astype(o_ref.dtype)
+
+
+def paged_attention_prefill(q, cache_k, cache_v, layer, table, start,
+                            lengths, interpret=False, window=None):
+    """Attention of a prefill CHUNK over the pages each row holds.
+
+    q [b, H, Tc, dh] (cast to the pools' dtype): query i of row s sits at
+    position ``start[s] + i`` and is real while ``i < lengths[s]``; it sees
+    keys ``j <= start[s] + i`` (its own K/V row and the chunk's earlier
+    ones were written before the call), inside ``window`` (static) on a
+    window layer. cache_k / cache_v the WHOLE pools [L, N, ps, Hkv*dh],
+    layer a scalar int32, table [b, P] int32 -> the context [b, Tc, H*dh]
+    in the pools' dtype, a token's heads side by side; zeros for a padding
+    query. The walk reads pages ``chunk_pages_in_reach`` of each row (by
+    query tile: a tile stops at ITS last real key), never a page past the
+    chunk's last real key."""
+    if q.ndim != 4:
+        raise ValueError(f"q must be [b, H, Tc, dh], got {q.shape}")
+    heads, t, d_head = q.shape[1:]
+    width = cache_k.shape[3]
+    if (heads * d_head) % width or width % d_head \
+            or cache_v.shape != cache_k.shape:
+        raise ValueError(f"q {q.shape} does not match the pools "
+                         f"{cache_k.shape} / {cache_v.shape}")
+    if _query_tile(t, heads, cache_k.dtype) is None:
+        raise ValueError(f"a chunk of {t} queries is not whole sublane "
+                         f"tiles of {jnp.dtype(cache_k.dtype).name}")
+    # operands of ONE type whatever the caller holds them as (a layer index
+    # is a Python int here, a scan's counter there): the K/V layers of one
+    # prefill program then share a trace and a lowering a kind of pool,
+    # where each call site of the bare ``pallas_call`` cost ~0.3 s of every
+    # start-up, cold or warm
+    return _chunk_walk(
+        q.astype(cache_k.dtype), cache_k, cache_v,
+        jnp.reshape(layer, (1,)).astype(jnp.int32),
+        table.reshape(-1).astype(jnp.int32), start.astype(jnp.int32),
+        lengths.astype(jnp.int32), pmax=table.shape[1], interpret=interpret,
+        window=window)
+
+
+@functools.partial(jax.jit, static_argnames=("pmax", "interpret", "window"))
+def _chunk_walk(q, cache_k, cache_v, layer, table, start, lengths, *, pmax,
+                interpret, window):
+    """``paged_attention_prefill``'s call, on checked operands: layer [1],
+    table [b * pmax] flattened, start / lengths [b], all int32."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, heads, t, d_head = q.shape
+    ps, width = cache_k.shape[2:]
+    dtype = cache_k.dtype
+    tq = _query_tile(t, heads, dtype)
+    kv_heads = width // d_head
+    group = heads // kv_heads
+    keys = min(_CHUNK_KEYS,
+               _CHUNK_BLOCK_BYTES // (width * jnp.dtype(dtype).itemsize))
+    per = max(keys // ps, 1)
+    kernel = functools.partial(_prefill_kernel, d_head=d_head, pmax=pmax,
+                               group=group, window=window)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,  # layer, the flattened table, start, lengths
+        grid=(b, t // tq),
+        in_specs=[
+            pl.BlockSpec((1, heads, tq, d_head),
+                         lambda s, i, *_: (s, 0, i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, tq, heads * d_head),
+                               lambda s, i, *_: (s, i, 0)),
+        scratch_shapes=[
+            # K and V blocks of ``per`` pages, two in flight
+            pltpu.VMEM((2, per * ps, width), dtype),
+            pltpu.VMEM((2, per * ps, width), dtype),
+            pltpu.SemaphoreType.DMA((2, 2, per)),
+            pltpu.VMEM((kv_heads, group * tq, 1), jnp.float32),  # running max
+            pltpu.VMEM((kv_heads, group * tq, 1), jnp.float32),  # denominator
+            pltpu.VMEM((kv_heads, group * tq, d_head), jnp.float32),  # P @ V
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, t, heads * d_head), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_CHUNK_VMEM),
+        interpret=interpret,
+        name=PREFILL_KERNEL,
+    )(layer, table, start, lengths, q, cache_k, cache_v)

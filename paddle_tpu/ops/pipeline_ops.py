@@ -889,11 +889,14 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
     ONE scatter per pool at (l, page_id, page_row) — page_id / page_row
     are [b, t] (decode: t == 1), the three index arrays are adjacent and
     the update window is a token's whole [Hkv*dh] row, contiguous in the
-    pool; the context is attended either by the paged decode kernel, which
-    reads the pages each row holds straight from the whole pool (a decode
-    step on a chip: ``paged_attention.supported`` and a ``lengths``-only
-    ``mask``), or gathered at (l, table) and attended with
-    ``reference_attention(**mask)`` (everything else: the ground truth);
+    pool; the context is attended either by a paged attention kernel, which
+    reads the pages each row holds (a chunk: reaches) straight from the
+    whole pool (on a chip: a decode step, ``paged_attention.supported`` and
+    a ``lengths``-only ``mask``; a verify tick's ``first_len``; a prefill
+    chunk, ``paged_attention.chunk_supported`` and ``chunk_mask``), or
+    gathered at (l, table) and attended with ``reference_attention`` under
+    the same mask (everything else — the CPU, a head narrower than the
+    lanes under a chunk, the latent block's chunk: the ground truth);
     ``finish(layer_p, h, ctx, x_l)``
     -> (h, stats) closes the block, x_l being layer l's slice of the
     optional scanned-over ``xs`` and stats what the layer reports (None,
@@ -910,7 +913,7 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
     unrolls a period in the scan's body): each layer writes and reads its
     own kind's pool at its index WITHIN the kind, and ``project(layer_p,
     h, rope)`` takes the layer's positions. A window layer attends keys
-    ``0 <= i - j < blk.window``: the kernel starts its page walk at the
+    ``0 <= i - j < blk.window``: the kernels start their page walk at the
     window's first page, the gathered form gathers the window's span of
     the table (``_window_span``), not its width. A stack of one kind is
     the period of one layer, no window and ``project``'s own positions.
@@ -924,8 +927,8 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
     it and copied the whole pool once per call — all proportional to N,
     none to the tokens in flight. As carry the donated buffers ARE the
     loop state and the only pool-shaped ops are the two in-place
-    scatters; the decode kernel takes the carry whole and addresses it at
-    (l, page), so it adds none."""
+    scatters; the kernels take the carry whole and address it at (l,
+    page), so they add none."""
     b, t, _ = h.shape
     whole = {k: params[k] for k in _RESIDENT_PLANES if k in params}
     params = {k: v for k, v in params.items() if k not in whole}
@@ -1056,7 +1059,8 @@ def _mla_paged_step(blk, b, t, project, mask, finish):
         else:
             lat = ck[l, tbl].reshape(b, 1, tbl.shape[1] * ck.shape[2], W)
             o_lat = reference_attention(q_lat.astype(ck.dtype), lat,
-                                        lat[..., :r], sm_scale=1.0, **mask)
+                                        lat[..., :r], sm_scale=1.0,
+                                        **_gathered_mask(mask))
         ctx = _mm(blk, "bhtr,rhv->bthv", o_lat[..., :r].astype(h.dtype),
                   w_uv).reshape(b, t, -1)
         h, stats = finish(layer_p, h, ctx, x_l)
@@ -1065,12 +1069,31 @@ def _mla_paged_step(blk, b, t, project, mask, finish):
     return attend
 
 
+def chunk_mask(start, lengths):
+    """A prefill chunk's ``mask``: block-causal from each row's ``start``
+    [b], the first ``lengths`` [b] queries of a row real (what
+    ``paged_attention.chunk_supported`` knows a chunk by)."""
+    return dict(causal=True, q_pos0=start, q_len=lengths)
+
+
+def _gathered_mask(mask):
+    """``mask`` as ``reference_attention`` takes it: the gathered form
+    attends a chunk's padding queries too (their rows are never read), so
+    the chunk's length stays behind."""
+    return {k: v for k, v in mask.items() if k != "q_len"}
+
+
 def _paged_layer_step(b, t, ps, project, mask, finish, mla=None):
     """The per-layer step of the paged loop (``_scan_paged_layers`` says
     what it does): ``attend(h, ck, cv, l, layer_p, x_l, table, ix_page,
     ix_row, window=None, rope=None)`` -> (h, ck, cv, stats) against the
-    pools (ck, cv) of the layer's KIND at its index l within the kind.
-    ``mla``: a latent block (``_mla_paged_step``: one pool, cv None)."""
+    pools (ck, cv) of the layer's KIND at its index l within the kind:
+    the layer's rows written, then its attention by whichever of
+    ``kernels/paged_attention``'s three calls the operands allow (a decode
+    step, a verify tick, a prefill chunk: all on a chip only), else the
+    table gathered (a window layer: its span) under
+    ``reference_attention``. ``mla``: a latent block (``_mla_paged_step``:
+    one pool, cv None; its chunk gathers)."""
     from ..kernels import paged_attention
     from ..kernels.flash_attention import reference_attention
 
@@ -1088,9 +1111,9 @@ def _paged_layer_step(b, t, ps, project, mask, finish, mla=None):
         hkv = k.shape[1]
         ck = ck.at[l, ix_page, ix_row].set(token_rows(k).astype(ck.dtype))
         cv = cv.at[l, ix_page, ix_row].set(token_rows(v).astype(cv.dtype))
-        # a decode step (one query token a row, keys j < lengths) on a
-        # chip walks the block table in one kernel; every other call
-        # gathers
+        # on a chip a decode step (one query token a row, keys j <
+        # lengths), a verify tick and a prefill chunk each walk the block
+        # table in one kernel; every call the kernels cannot take gathers
         on_walk = paged_attention.supported(q.shape[1] * q.shape[3], ck, t)
         if t == 1 and set(mask) == {"lengths"} and on_walk:
             ctx = paged_attention.paged_attention_decode(
@@ -1100,8 +1123,13 @@ def _paged_layer_step(b, t, ps, project, mask, finish, mla=None):
             # a verify tick: t positions a row on the page walk
             ctx = paged_attention.paged_attention_verify(
                 q, ck, cv, l, tbl, mask["first_len"], window=window)
+        elif paged_attention.chunk_supported(q.shape, ck, mask):
+            # a prefill chunk: the pages its queries reach, never the table
+            ctx = paged_attention.paged_attention_prefill(
+                q, ck, cv, l, tbl, mask["q_pos0"], mask["q_len"],
+                window=window)
         else:
-            m = mask
+            m = _gathered_mask(mask)
             if set(mask) == {"first_len"}:
                 # a verify tick off the chip: query j of a row sits at
                 # position first_len - 1 + j and sees the keys up to itself
@@ -1495,16 +1523,21 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
 
     The pools ride the layer loop as its in-place carry
     (``_scan_paged_layers``): a chunk writes b*Tc token rows per layer
-    and pool and gathers b table-width contexts; nothing it moves is
-    proportional to N, and no page outside the written (layer, page,
-    row) cells changes.
+    and pool and, on a chip, reads the pages each row's queries REACH in
+    one kernel a layer (``paged_attention_prefill``: to the page of the
+    chunk's last real key, on a window layer from the window's page);
+    elsewhere (the CPU, a head narrower than the lanes, a latent pool) it
+    gathers b table-width contexts. Nothing it moves is proportional to
+    N, and no page outside the written (layer, page, row) cells changes.
 
-    Queries attend the row's WHOLE gathered context block-causally (chunk
-    token at absolute position p sees cached position j iff j <= p), so a
-    later chunk attends every earlier chunk's pages and a shared-prefix
-    row attends the shared pages it never prefilled — token-exact vs the
-    dense one-shot prefill. Pages beyond a row's extent sit at flattened
-    positions > p and are masked by the same rule.
+    Queries attend the row's WHOLE context block-causally (chunk token at
+    absolute position p sees cached position j iff j <= p), so a later
+    chunk attends every earlier chunk's pages and a shared-prefix row
+    attends the shared pages it never prefilled — token-exact vs the
+    dense one-shot prefill. Pages beyond a row's extent sit at positions
+    > p: the walk never reads them, the gathered form masks them by the
+    same rule. A padding query's context is unspecified (the walk leaves
+    zeros, the gathered form attends it): nothing reads its row.
 
     Optional per-row sampling plane (Temperature/TopK/TopP/Seed/Step [b]
     + Mask [b, V]): when fed, NextTok comes from
@@ -1581,7 +1614,7 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
                      single(ins, "SnapTake").astype(jnp.int32))
         h, cache_k, cache_v, states, snaps, stats = _scan_kind_layers(
             blk, params, x, cache_k, table, page_id, page_row, start,
-            dict(causal=True, q_pos0=start), _state_ins(blk, ins),
+            chunk_mask(start, lengths), _state_ins(blk, ins),
             (single(ins, "StateSlot").astype(jnp.int32), start, lengths),
             pool_v=cache_v, snaps=snaps)
         if snaps is not None:
@@ -1592,7 +1625,7 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
         # "CacheKW" "CacheVW" "BlockTableW": the window kind (_window_ins)
         h, cache_k, cache_v, stats, win = _scan_paged_layers(
             params, x, cache_k, cache_v, table, page_id, page_row,
-            _paged_project(blk, start), dict(causal=True, q_pos0=start),
+            _paged_project(blk, start), chunk_mask(start, lengths),
             lambda p, h, ctx, _x_l: _attn_out_ffn(
                 blk, p, h, ctx, dense="dense_gate_w" in p), blk=blk,
             win=_window_ins(blk, ins, lambda tw: (page_of(tw), page_row)))
@@ -1616,7 +1649,7 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
             jnp.where(after >= 0, after, nxt))
         g, (cache_k, cache_v), more = _draft_block(
             blk, ins, h, t_next, (cache_k, cache_v), table, page_id,
-            page_row, start, dict(causal=True, q_pos0=start))
+            page_row, start, chunk_mask(start, lengths))
         stats = _with_draft_stats(stats, more)
         draft_logits = _draft_logits(blk, ins, g[jnp.arange(b), at_last])
         draft = jnp.argmax(draft_logits, axis=-1)
@@ -1647,8 +1680,8 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     pool and, on a chip, reads the pages each slot HOLDS (``Pos // ps +
     1``; the scrap page for a vacant slot) in one paged-attention kernel
     a layer — the weights plus the K/V of the tokens in flight are what
-    a tick moves. Elsewhere (CPU, grouped-query heads, a row not
-    lane-aligned) it gathers S table-width contexts [P*ps, Hkv*dh]. No
+    a tick moves. Elsewhere (CPU, a row not lane-aligned) it gathers S
+    table-width contexts [P*ps, Hkv*dh]. No
     layer of the pool is sliced, re-laid out, restacked or copied.
 
     The slot axis is the batch axis and the table width is static, so the

@@ -30,7 +30,8 @@ from ..lm_spec import Block
 from .common import maybe, out, single
 from .pipeline_ops import (_SAMPLING_SLOTS, _STACK_SLOTS, _attn_out_ffn,
                            _attn_proj, _expand_kv, _logits_fn, _ln,
-                           _maybe_topk, _pick_rows, _scan_paged_layers)
+                           _maybe_topk, _pick_rows, _scan_paged_layers,
+                           chunk_mask)
 
 # encoder stack slots: the same 10-weight block layout, Enc-prefixed
 _ENC_SLOTS = {f"Enc{slot}": key for slot, key in _STACK_SLOTS.items()}
@@ -207,7 +208,7 @@ def transformer_stack_cross_prefill(attrs, ins, rng=None):
     h, cache_k, cache_v, _, _ = _scan_paged_layers(
         params, x, cache_k, cache_v, table, page_id, page_row,
         lambda p, h: _attn_proj(blk, p, h, pos0=start),
-        dict(causal=True, q_pos0=start),
+        chunk_mask(start, lengths),
         finish=_cross_block(xslot, src_len, num_heads),
         xs=(cross_k, cross_v, xparams))
     last = h[jnp.arange(b), jnp.clip(lengths, 1, Tc) - 1]

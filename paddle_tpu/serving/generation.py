@@ -640,6 +640,7 @@ class GenerationEngine:
                   count=self.metrics.inc)
         self._caches: List[PageCache] = [PageCache(
             "global", pool, index, layers=spec.pool_layers(False), **kw)]
+        self._chunk_walk: Dict[int, bool] = {}   # ``_chunk_walks``' memo
         if spec.block.has_window:
             # what a slot's window layers can hold at once (the window,
             # the chunk in flight, one page of slack each way)
@@ -1233,7 +1234,39 @@ class GenerationEngine:
         if tc is None:
             self.metrics.inc("decode_feed_host_bytes",
                              sum(v.nbytes for v in on_host))
+        elif self._chunk_walks(tc):
+            # the pages this unit's attention walks, a layer of each kind,
+            # against the table it would gather whole
+            for cache in self._caches:
+                table = cols[cache.table]
+                kind = f"_{cache.name}" if self._caches[1:] else ""
+                self.metrics.inc(
+                    f"prefill_attn_pages_read{kind}", cache.chunk_pages_read(
+                        cols["serving.start"], cols["serving.chunk_len"],
+                        table.shape[1]))
+                self.metrics.inc(f"prefill_attn_table_pages{kind}",
+                                 table.size)
         return CallFeed(feed, cols)
+
+    def _chunk_walks(self, tc: int) -> bool:
+        """Whether the prefill programs of chunk width ``tc`` attend on the
+        chunk walk: the ops' own predicate
+        (``kernels/paged_attention.chunk_supported``) over the shapes their
+        K/V layers see. A latent pool's chunk gathers."""
+        if tc not in self._chunk_walk:
+            import jax
+
+            from ..core.types import to_dtype
+            from ..kernels import paged_attention
+
+            spec = self.spec
+            q = (1, spec.num_heads, tc, spec.block.dh(spec.d_model))
+            self._chunk_walk[tc] = spec.cache_pools == 2 and all(
+                paged_attention.chunk_supported(
+                    q, jax.ShapeDtypeStruct(cache.shape,
+                                            to_dtype(spec.page_dtype)),
+                    paged_attention.CHUNK_MASK) for cache in self._caches)
+        return self._chunk_walk[tc]
 
     # -- warmup / manifests ----------------------------------------------
     def warmup(self) -> int:

@@ -585,6 +585,19 @@ class PageCache:
         last = pos if last is None else last
         return int((last // self.page_size + 1 - first).sum())
 
+    def chunk_pages_read(self, start: np.ndarray, lengths: np.ndarray,
+                         width: int) -> int:
+        """The pages a prefill chunk's attention walks in a layer of this
+        kind (``kernels/paged_attention.chunk_pages_in_reach``, the
+        kernel's own rule): rows at ``start`` with ``lengths`` real tokens
+        (0: a padding row, which reads none) under tables ``width`` wide."""
+        from ..kernels.paged_attention import chunk_pages_in_reach
+
+        first, end = chunk_pages_in_reach(
+            start.astype(np.int64), lengths.astype(np.int64),
+            self.page_size, self.window or None, xp=np)
+        return int((np.minimum(end, width) - np.minimum(first, width)).sum())
+
     def held_pages(self, helds: Iterable[Held]) -> int:
         """Distinct pages the slots hold (``helds``: theirs of this
         cache); what only the index still caches is evictable and not

@@ -280,6 +280,37 @@ def test_a_long_slot_holds_a_window_of_pages_and_never_a_shared_write(
     assert eng.pool.pages_in_use() == len(eng.prefix_index)
 
 
+@pytest.mark.parametrize("walks", [True, False], ids=["walk", "gather"])
+def test_prefill_units_count_the_pages_their_chunk_walk_reads(no_amp, walks):
+    """``prefill_attn_pages_read_<kind>`` / ``prefill_attn_table_pages_<kind>``,
+    a prefill unit: the pages in reach of the unit's real rows by the chunk
+    walk's own rule (a window layer from its window's page) against rows x
+    table width, where the engine's prefill programs took the kernel (the
+    ops' predicate, here answered for it: no chip); an engine whose
+    programs gather counts neither."""
+    eng = _engine()
+    assert eng._chunk_walks(8) is False     # the CPU mesh: the gathered form
+    eng._chunk_walk = {tc: walks for tc in (4, 8)}
+    before = _counters(eng)
+    # 19 tokens: chunks at 0 and 8 (8 tokens each), then 3 tokens at 16
+    eng.generate_all([_prompt(19, seed=5)], max_new_tokens=2)
+    c = {k: v - before.get(k, 0) for k, v in _counters(eng).items()
+         if k.startswith("prefill_attn")}
+    if not walks:
+        assert not c
+        return
+    units = _counters(eng)["prefill_feed_host_arrays"] \
+        - before.get("prefill_feed_host_arrays", 0)
+    assert units == 3
+    width = ENGINE["max_len"] // PS
+    assert c["prefill_attn_table_pages_global"] == units * width
+    assert c["prefill_attn_table_pages_window"] == units * width
+    # full layers: pages 0 .. the chunk's last: 2 + 4 + 5
+    assert c["prefill_attn_pages_read_global"] == 11
+    # window 8, pages of 4: from (start - 7) // 4: 2, 4 - 0, 5 - 2
+    assert c["prefill_attn_pages_read_window"] == 9
+
+
 def test_releasing_behind_the_window_never_frees_an_indexed_page(no_amp):
     """A 24-token prompt leaves six window pages to the prefix index as
     its chunks complete; the slot moves past them while it decodes, and

@@ -1,7 +1,8 @@
-"""The paged decode-attention kernel (kernels/paged_attention.py) against
-the semantic ground truth — ``reference_attention`` over
-``_gather_pages`` — in Pallas interpret mode on the CPU mesh, and the
-rule that chooses between them in ``_scan_paged_layers``."""
+"""The paged attention kernels (kernels/paged_attention.py: the decode
+walk and the prefill chunk's) against the semantic ground truth —
+``reference_attention`` over ``_gather_pages`` — in Pallas interpret mode
+on the CPU mesh, and the rule that chooses between them in
+``_scan_paged_layers``."""
 import re
 
 import jax
@@ -11,7 +12,8 @@ import pytest
 
 from paddle_tpu.kernels import paged_attention
 from paddle_tpu.kernels.flash_attention import reference_attention
-from paddle_tpu.kernels.paged_attention import paged_attention_decode
+from paddle_tpu.kernels.paged_attention import (paged_attention_decode,
+                                                paged_attention_prefill)
 from paddle_tpu.ops.pipeline_ops import _gather_pages, _scan_paged_layers
 
 L, N, PS, P = 2, 24, 16, 4          # layers, pages, page size, table width
@@ -153,6 +155,141 @@ def test_a_row_depends_on_its_own_context_alone():
 
 
 # ---------------------------------------------------------------------------
+# the prefill chunk's walk
+# ---------------------------------------------------------------------------
+C_N, C_PS, C_P, C_T = 48, 16, 24, 32    # pages, page size, table width, chunk
+#: name -> the rows' (start, real tokens of the chunk)
+CHUNKS = {
+    # the first chunk of a prompt, whole
+    "b1-start0": [(0, C_T)],
+    # mid-page with padding queries; page-aligned, ten pages deep
+    "b2-ragged": [(37, 11), (10 * C_PS, C_T)],
+    # a short first chunk, a deep one, a padding row, a tail of five tokens
+    "b4-padding-row": [(0, 20), (300, C_T), (4 * C_PS, 0), (203, 5)],
+}
+#: (page dtype, query heads, cached heads, the row budget of a query tile:
+#: None = the kernel's, one tile here; 1024 splits 64 heads into two tiles)
+CHUNK_WIDTHS = [
+    pytest.param(jnp.bfloat16, 16, 16, None, id="bf16-16x128"),
+    pytest.param(jnp.float32, 16, 16, None, id="f32-16x128"),
+    pytest.param(jnp.bfloat16, 28, 4, None, id="bf16-28/4x128"),
+    pytest.param(jnp.bfloat16, 64, 8, 1024, id="bf16-64/8x128-two-tiles"),
+]
+
+
+def _chunk_case(dtype, heads, kv_heads, rows, seed=0, d_head=128):
+    """Pools, a table a row (the pages its chunk reaches, permuted; the
+    tail the scrap page), queries, start and lengths."""
+    rng = np.random.default_rng(seed)
+    width = kv_heads * d_head
+    ck, cv = (jnp.asarray(rng.standard_normal((L, C_N, C_PS, width)), dtype)
+              for _ in range(2))
+    table = np.zeros((len(rows), C_P), np.int32)
+    for s, (start, n) in enumerate(rows):
+        held = -(-(start + n) // C_PS) if n else 0
+        table[s, :held] = rng.permutation(np.arange(1, C_N))[:held]
+    q = jnp.asarray(2 * rng.standard_normal((len(rows), heads, C_T, d_head)),
+                    dtype)
+    start, lengths = (jnp.asarray([r[i] for r in rows], jnp.int32)
+                      for i in (0, 1))
+    return q, ck, cv, table, start, lengths
+
+
+def _chunk_reference(q, ck, cv, layer, table, start, lengths, window):
+    """The gathered path's answer with the padding queries' rows zeroed
+    (it attends them too; nobody reads them)."""
+    kv_heads = ck.shape[-1] // q.shape[-1]
+    m = dict(causal=True, q_pos0=start)
+    if window is not None:
+        m.update(window=window, k_pos0=jnp.zeros_like(start))
+    table = jnp.asarray(table)
+    ctx = reference_attention(q, _gather_pages(ck, layer, table, kv_heads),
+                              _gather_pages(cv, layer, table, kv_heads), **m)
+    ctx = ctx.transpose(0, 2, 1, 3).reshape(q.shape[0], q.shape[2], -1)
+    real = jnp.arange(q.shape[2])[None, :] < lengths[:, None]
+    return jnp.where(real[..., None], ctx, 0)
+
+
+@pytest.mark.parametrize("window", [None, 128, 4096],
+                         ids=["full", "window128", "window4096"])
+@pytest.mark.parametrize("chunk", sorted(CHUNKS))
+@pytest.mark.parametrize("dtype,heads,kv_heads,tile_rows", CHUNK_WIDTHS)
+def test_chunk_walk_is_reference_attention_over_the_gathered_pages(
+        monkeypatch, dtype, heads, kv_heads, tile_rows, chunk, window):
+    if tile_rows is not None:
+        monkeypatch.setattr(paged_attention, "_CHUNK_ROWS", tile_rows)
+        assert paged_attention._query_tile(C_T, heads, dtype) == C_T // 2
+    q, ck, cv, table, start, lengths = _chunk_case(dtype, heads, kv_heads,
+                                                   CHUNKS[chunk])
+    layer = jnp.int32(1)
+    got = paged_attention_prefill(q, ck, cv, layer, jnp.asarray(table), start,
+                                  lengths, interpret=True, window=window)
+    assert got.shape == (len(table), C_T, heads * 128) and got.dtype == dtype
+    want = _chunk_reference(q, ck, cv, layer, table, start, lengths, window)
+    f32 = [a.astype(jnp.float32) for a in (q, ck, cv)]
+    truth = np.asarray(_chunk_reference(*f32, layer, table, start, lengths,
+                                        window))
+    got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
+    tol = _tolerance(dtype, want, truth)
+    np.testing.assert_allclose(got, truth, atol=tol, rtol=0)
+    np.testing.assert_allclose(got, want, atol=2 * tol, rtol=0)
+    # padding queries and the padding row: zeros, as the reference gives a
+    # fully masked row
+    for s, (_, n) in enumerate(CHUNKS[chunk]):
+        assert not got[s, n:].any()
+
+
+@pytest.mark.parametrize("window", [None, 40], ids=["full", "window"])
+def test_chunk_walk_never_reads_a_page_out_of_reach(window):
+    """Every page outside ``chunk_pages_in_reach`` — the other layer whole,
+    pages no row holds, on a window layer the pages behind the window — is
+    NaN, and every table entry outside the walk names a page that does not
+    exist: the result is finite and bitwise what the clean operands give.
+    (The gathered reference reads them all: 0 x NaN.)"""
+    rows = [(37, 11), (10 * C_PS, C_T), (4 * C_PS, 0), (203, 5)]
+    q, ck, cv, table, start, lengths = _chunk_case(jnp.float32, 4, 2, rows,
+                                                   seed=3)
+    layer = jnp.int32(0)
+    clean = paged_attention_prefill(q, ck, cv, layer, jnp.asarray(table),
+                                    start, lengths, interpret=True,
+                                    window=window)
+    first, end = paged_attention.chunk_pages_in_reach(
+        np.asarray(start), np.asarray(lengths), C_PS, window, xp=np)
+    poison, walked = np.ones((L, C_N), bool), np.full_like(table, 10 ** 6)
+    for s in range(len(rows)):
+        walked[s, first[s]:end[s]] = table[s, first[s]:end[s]]
+        poison[0, table[s, first[s]:end[s]]] = False
+    assert (first[1] > 0) == (window is not None) and end[2] == first[2]
+    mask = jnp.asarray(poison)[:, :, None, None]
+    ck_p, cv_p = (jnp.where(mask, jnp.nan, a) for a in (ck, cv))
+    got = paged_attention_prefill(q, ck_p, cv_p, layer, jnp.asarray(walked),
+                                  start, lengths, interpret=True,
+                                  window=window)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
+    assert np.isnan(np.asarray(_chunk_reference(
+        q, ck_p, cv_p, layer, table, start, lengths, window))).any()
+
+
+def test_the_engine_counts_the_pages_the_chunk_walk_reads():
+    """``PageCache.chunk_pages_read`` is the kernel's rule summed over a
+    unit's rows: a full layer from page 0, a window layer from its
+    window's page, to the chunk's last real key; a padding row none."""
+    from paddle_tpu.serving.paging import PageCache, PagePool
+
+    start = np.array([0, 300, 64, 1000], np.int32)
+    lengths = np.array([20, 256, 0, 256], np.int32)
+    kw = dict(layers=1, row_width=128, count=lambda *a: None)
+    full = PageCache("global", PagePool(8, 64), None, **kw)
+    assert full.chunk_pages_read(start, lengths, 96) == 1 + 9 + 0 + 20
+    win = PageCache("window", PagePool(8, 64), None, window=128, **kw)
+    # (300 - 127) // 64 = 2 .. 8; (1000 - 127) // 64 = 13 .. 19
+    assert win.chunk_pages_read(start, lengths, 96) == 1 + 7 + 0 + 7
+    # a table narrower than the reach bounds the walk, as the kernel's
+    assert full.chunk_pages_read(start, lengths, 16) == 1 + 9 + 0 + 16
+
+
+# ---------------------------------------------------------------------------
 # Mosaic takes the kernel at the two serving cells' shapes: interpret mode
 # cannot see tiling, VMEM or DMA-slice refusals; the chip's compiler is
 # installed here and compiles for a chip that is described, not attached
@@ -198,6 +335,112 @@ def test_kernel_compiles_for_the_v5e_with_the_pool_whole(
     assert ops
     assert {op for shape, op in ops
             if f"{pages},{ps},{width}]" in shape} == {"parameter"}
+
+
+def _prefill_unit_text(cell_name, one_chip, monkeypatch):
+    """The 256-token prefill unit of a serving cell, lowered from the op at
+    the cell's own shapes (its spec, its engine's pools and table) and
+    compiled for the described chip -> (the HLO text, spec, engine
+    settings, table width)."""
+    import paddle_tpu as pt
+    from benchmark import harness
+    from paddle_tpu.lm_spec import DRAFT_SLOT_PREFIX
+    from paddle_tpu.ops.pipeline_ops import transformer_stack_paged_prefill
+
+    cell = harness.load_cell(cell_name)
+    e = cell.mix["engine"]
+    spec = cell.family.spec_of(cell.config)
+    pt.set_amp(True)    # (conftest's autouse fixture puts the policy back)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ps, dt = e["page_size"], spec.param_dtype
+    P = e["max_len"] // ps
+    row = (ps, spec.cache_row_width)
+    rows = {"Chunk": ((1, e["prefill_chunk"]), "int32"),
+            "StartPos": ((1,), "int32"), "Lengths": ((1,), "int32"),
+            "BlockTable": ((1, P), "int32"),
+            "Temperature": ((1,), "float32"), "TopK": ((1,), "int32"),
+            "TopP": ((1,), "float32"), "Seed": ((1,), "int32"),
+            "Step": ((1,), "int32")}
+    pools = {n: ((spec.pool_layers(False), e["n_pages"], *row),
+                 spec.page_dtype) for n in ("CacheK", "CacheV")}
+    if spec.block.has_window:
+        rows["BlockTableW"] = ((1, P), "int32")
+        pools.update({n: ((spec.pool_layers(True), e["n_pages_window"],
+                           *row), spec.page_dtype)
+                      for n in ("CacheKW", "CacheVW")})
+    weights = {"TokEmb": ((spec.vocab_size, spec.d_model), dt),
+               "FinalLnS": ((spec.d_model,), dt),
+               "HeadW": ((spec.d_model, spec.vocab_size), dt)}
+    for slot, key, shape, _ in spec.stack_planes():
+        weights[slot] = ((spec.plane_layers(key), *shape), dt)
+    if spec.draft_block:
+        rows["DraftNext"] = ((1,), "int32")
+        for slot, _, shape, _ in spec.draft_planes():
+            weights[slot] = (tuple(shape), dt)
+        for slot, _, shape, _ in spec.draft_spec().stack_planes():
+            weights[DRAFT_SLOT_PREFIX + slot] = ((1, *shape), dt)
+    state = {}
+    for name, shape, dtype, layers in spec.slot_state():
+        state[name] = ((layers, e["slots"], *shape), dtype)
+        state[name + "Snap"] = ((layers, e["n_snapshots"], *shape), dtype)
+    if state:
+        rows.update({n: ((1,), "int32")
+                     for n in ("StateSlot", "SnapFrom", "SnapTake")})
+    shapes = {**rows, **pools, **weights, **state}
+    names = sorted(shapes)
+    attrs = dict(spec.block.attrs(), page_size=ps, temperature=0.0, top_k=0)
+
+    def step(*args):
+        outs = transformer_stack_paged_prefill(
+            attrs, {k: [a] for k, a in zip(names, args)})
+        return {k: v[0] for k, v in outs.items()}
+
+    text = jax.jit(step, donate_argnums=tuple(
+        names.index(n) for n in (*pools, *state))).lower(*[
+            jax.ShapeDtypeStruct(shapes[n][0], shapes[n][1],
+                                 sharding=one_chip)
+            for n in names]).compile().as_text()
+    return re.sub(r"\{[^{}]*\}", "", text), spec, e, P
+
+
+@pytest.mark.parametrize("cell_name,calls", [
+    # two full + six window layers and the drafting block's full layer
+    ("kexaone-serve-reason", 9),
+    # one period under the scan: a full layer, three window layers
+    ("smallthinker-serve-mixed", 4),
+    # the one softmax layer of the period, beside three recurrent ones
+    ("solar2-serve-agent", 1),
+])
+def test_prefill_unit_on_the_v5e_walks_the_pages_of_whole_pools(
+        one_chip, monkeypatch, cell_name, calls):
+    """The prefill unit of the three cells whose temporaries the gathered
+    scores sized, compiled for the chip at the cell's shapes: every K/V
+    layer's attention is the chunk walk under its OWN call name, the pools
+    enter each call whole (the scan's carry, no slice, copy or re-layout),
+    and nothing shaped like the gathered table [.., P, ps, row] or its
+    float32 scores [b, heads.., Tc, P * ps] is compiled in (kexaone's table
+    is as wide as its stream, 6144: [Tc, 6144] alone is the hidden state)."""
+    flat, spec, e, P = _prefill_unit_text(cell_name, one_chip, monkeypatch)
+    ps, Tc, W = e["page_size"], e["prefill_chunk"], spec.cache_row_width
+    walks = [ln for ln in flat.splitlines() if "custom-call(" in ln
+             and "%paged_attention_prefill" in ln and "tpu_custom_call" in ln]
+    assert len(walks) == calls
+    assert "%paged_attention_decode" not in flat
+    kinds = [(spec.pool_layers(False), e["n_pages"])]
+    if spec.block.has_window:
+        kinds.append((spec.pool_layers(True), e["n_pages_window"]))
+    pools = tuple(f"[{layers},{n},{ps},{W}]" for layers, n in kinds)
+    # each call reads the K and the V pool of ONE kind, whole
+    assert all(sum(c.count(f"bf16{pool}") for pool in pools) == 2
+               for c in walks)
+    ops = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", flat, re.M)
+    moved = {op for shape, op in ops if shape.endswith(pools)
+             and not shape.startswith("(")}
+    assert moved <= {"parameter", "get-tuple-element", "scatter", "fusion",
+                     "bitcast"}, moved
+    assert not [shape for shape, _ in ops
+                if re.search(rf"f32\[(\d+,){{2,}}{Tc},{P * ps}\]", shape)
+                or f",{P},{ps},{W}]" in shape or f"[{P},{ps},{W}]" in shape]
 
 
 def test_latent_kernel_compiles_for_the_v5e_with_the_one_pool_whole(one_chip):
@@ -523,25 +766,32 @@ def test_stacked_train_step_on_the_v5e_runs_its_forward_scan_once(
 # the dispatch rule of _scan_paged_layers
 # ---------------------------------------------------------------------------
 def _run_layers(monkeypatch, backend, dtype=jnp.float32, heads=4, kv_heads=4,
-                d_head=32, t=1, causal=False, ps=PS):
+                d_head=32, t=1, causal=False, ps=PS, chunk=False):
     """One pass of ``_scan_paged_layers`` over toy projections with the
-    backend reported as ``backend``; the kernel, where chosen, runs in
-    interpret mode. -> (h, how often the kernel was traced)."""
+    backend reported as ``backend``; the kernels, where chosen, run in
+    interpret mode. ``causal``: a block-causal mask without the chunk's
+    length; ``chunk``: a prefill chunk's own mask (``chunk_mask``), every
+    query real. -> (h, how often a kernel was traced)."""
+    from paddle_tpu.ops.pipeline_ops import chunk_mask
+
     calls = []
 
-    def spy(*args, **kwargs):
-        calls.append(args[0].shape)
-        return paged_attention_decode(*args, interpret=True, **kwargs)
+    def spy(kernel):
+        def run(*args, **kwargs):
+            calls.append((kernel.__name__, args[0].shape))
+            return kernel(*args, interpret=True, **kwargs)
+        return run
 
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    monkeypatch.setattr(paged_attention, "paged_attention_decode", spy)
+    for kernel in (paged_attention_decode, paged_attention_prefill):
+        monkeypatch.setattr(paged_attention, kernel.__name__, spy(kernel))
     b, d = 3, heads * d_head
     width = kv_heads * d_head
     rng = np.random.default_rng(0)
     ck, cv = (jnp.asarray(rng.standard_normal((L, N, ps, width)), dtype)
               for _ in range(2))
     h = jnp.asarray(rng.standard_normal((b, t, d)), jnp.float32)
-    table = jnp.asarray(_table([[3, 4], [5], [6, 7, 8]]))
+    table = jnp.asarray(_table([[3, 4], [5, 9], [6, 7, 8, 10]]))
     pos = jnp.asarray([ps + 2, 0, 2 * ps + 5], jnp.int32)
     at = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
     page_id = jnp.take_along_axis(table, at // ps, axis=1)
@@ -552,13 +802,17 @@ def _run_layers(monkeypatch, backend, dtype=jnp.float32, heads=4, kv_heads=4,
         kv = kv.transpose(0, 2, 1, 3)
         return q, kv, 0.5 * kv
 
-    mask = (dict(causal=True, q_pos0=pos) if causal
-            else dict(lengths=pos + t))
+    if chunk:
+        mask = chunk_mask(pos, jnp.full((b,), t, jnp.int32))
+    elif causal:
+        mask = dict(causal=True, q_pos0=pos)
+    else:
+        mask = dict(lengths=pos + t)
     out, ck2, cv2, _, _ = _scan_paged_layers(
         {"w": jnp.linspace(0.5, 1.5, L)[:, None]}, h, ck, cv, table,
         page_id, at % ps, project, mask,
         lambda p, x, ctx, _x: (x + ctx.astype(x.dtype), None))
-    return np.asarray(out), len(calls)
+    return np.asarray(out), [name for name, _ in calls]
 
 
 def test_a_decode_step_on_a_chip_takes_the_kernel(monkeypatch):
@@ -566,26 +820,91 @@ def test_a_decode_step_on_a_chip_takes_the_kernel(monkeypatch):
     once in the scanned layer body, and the same h as the gathered path."""
     got, traced = _run_layers(monkeypatch, "tpu")
     want, none = _run_layers(monkeypatch, "cpu")
-    assert (traced, none) == (1, 0)
+    assert (traced, none) == (["paged_attention_decode"], [])
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (4, 2)],
+                         ids=["mha", "gqa"])
+def test_a_prefill_chunk_on_a_chip_takes_the_chunk_walk(monkeypatch, heads,
+                                                        kv_heads):
+    """A chunk's own mask + TPU + heads of whole lane rows + a chunk of
+    whole sublane tiles: the chunk walk, once in the scanned layer body,
+    never the decode kernel, and the same h as the gathered path."""
+    kw = dict(heads=heads, kv_heads=kv_heads, d_head=128, t=16, chunk=True)
+    got, traced = _run_layers(monkeypatch, "tpu", **kw)
+    want, none = _run_layers(monkeypatch, "cpu", **kw)
+    assert (traced, none) == (["paged_attention_prefill"], [])
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
 
 
 @pytest.mark.parametrize("why,kwargs", [
     ("not a TPU", dict(backend="cpu")),
     ("not a TPU", dict(backend="gpu")),
-    ("a prefill chunk: t > 1", dict(backend="tpu", t=4, causal=True)),
+    ("a block-causal mask without the chunk's length: t > 1",
+     dict(backend="tpu", t=4, causal=True)),
     ("block-causal mask", dict(backend="tpu", causal=True)),
     ("row narrower than the lanes", dict(backend="tpu", heads=2, kv_heads=2)),
     ("grouped-query over a row narrower than the lanes",
      dict(backend="tpu", heads=4, kv_heads=2)),
     ("page of half a bf16 tile", dict(backend="tpu", dtype=jnp.bfloat16,
                                       ps=8)),
+    ("a chunk off the chip", dict(backend="cpu", heads=2, kv_heads=2,
+                                  d_head=128, t=16, chunk=True)),
+    ("a chunk of heads narrower than the lanes",
+     dict(backend="tpu", heads=4, kv_heads=4, d_head=64, t=16, chunk=True)),
+    ("a chunk of half a sublane tile",
+     dict(backend="tpu", heads=2, kv_heads=2, d_head=128, t=4, chunk=True)),
+    ("a chunk over pages of half a bf16 tile",
+     dict(backend="tpu", heads=2, kv_heads=2, d_head=128, t=16, chunk=True,
+          dtype=jnp.bfloat16, ps=8)),
 ])
 def test_everything_else_keeps_the_gathered_reference(monkeypatch, why,
                                                       kwargs):
     got, traced = _run_layers(monkeypatch, **kwargs)
-    assert traced == 0, why
+    assert not traced, why
     assert np.isfinite(got).all()
+
+
+def test_a_latent_chunk_keeps_the_gathered_form(monkeypatch):
+    """``_mla_paged_step`` on a chip under a chunk's own mask: neither
+    kernel (the latent chunk walk is a later change), and the absorbed
+    gathered attention with the chunk's length left behind."""
+    from paddle_tpu.lm_spec import Block
+    from paddle_tpu.ops import pipeline_ops
+
+    def never(*args, **kwargs):
+        raise AssertionError("a latent chunk took a kernel")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for name in ("paged_attention_decode", "paged_attention_prefill"):
+        monkeypatch.setattr(paged_attention, name, never)
+    blk = Block(num_heads=4, use_rope=True, norm="rms_norm", bias=False,
+                attn="mla", q_lora_rank=8, kv_lora_rank=96,
+                qk_nope_head_dim=8, qk_rope_head_dim=32, v_head_dim=16)
+    b, t, W = 2, 16, 128
+    rng = np.random.default_rng(0)
+    ck = jnp.asarray(rng.standard_normal((1, 6, 16, W)), jnp.float32)
+    p = {"kv_b_w": jnp.asarray(rng.standard_normal((96, 4 * 24)),
+                               jnp.float32)}
+    proj = tuple(jnp.asarray(rng.standard_normal(shape), jnp.float32)
+                 for shape in ((b, 4, t, 8), (b, 4, t, 32), (b, t, 96),
+                               (b, t, 32)))
+    start = jnp.asarray([0, 16], jnp.int32)
+    table = jnp.asarray([[1, 0, 0], [2, 3, 0]], jnp.int32)
+    at = start[:, None] + jnp.arange(t)[None, :]
+    ctxs = []
+    for mask in (pipeline_ops.chunk_mask(start, jnp.full((b,), t, jnp.int32)),
+                 dict(causal=True, q_pos0=start)):
+        attend = pipeline_ops._mla_paged_step(
+            blk, b, t, lambda layer_p, h: proj, mask,
+            lambda layer_p, h, ctx, x_l: (ctx, None))
+        ctx, *_ = attend(jnp.zeros((b, t, 64)), ck, None, 0, p, None, table,
+                         jnp.take_along_axis(table, at // 16, axis=1),
+                         at % 16)
+        ctxs.append(np.asarray(ctx))
+    assert ctxs[0].shape == (b, t, 4 * 16) and np.isfinite(ctxs[0]).all()
+    np.testing.assert_array_equal(*ctxs)
 
 
 @pytest.mark.parametrize("dtype,ps,ok", [
@@ -603,6 +922,18 @@ def test_supported_reads_shapes_dtype_and_backend_only(monkeypatch, dtype,
     assert not paged_attention.supported(384, pool, 1)      # no whole groups
     narrow = jax.ShapeDtypeStruct((2, 8, ps, 64), dtype)
     assert not paged_attention.supported(64, narrow, 1)
+    # the chunk form: the same page rule, over [b, H, t, dh] and the mask
+    chunk = paged_attention.CHUNK_MASK
+    assert paged_attention.chunk_supported((1, 2, 64, 128), pool, chunk) is ok
+    assert paged_attention.chunk_supported((1, 4, 64, 128), pool, chunk) is ok
+    assert not paged_attention.chunk_supported((1, 2, 2, 128), pool, chunk)
+    assert not paged_attention.chunk_supported((1, 3, 64, 128), pool, chunk)
+    assert not paged_attention.chunk_supported((1, 4, 64, 64), pool, chunk)
+    assert not paged_attention.chunk_supported((1, 2, 60, 128), pool, chunk)
+    assert not paged_attention.chunk_supported(
+        (1, 2, 64, 128), pool, {"causal", "q_pos0"})
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert not paged_attention.chunk_supported((1, 2, 64, 128), pool, chunk)
 
 
 def test_wrapper_refuses_mismatched_operands():
@@ -615,3 +946,12 @@ def test_wrapper_refuses_mismatched_operands():
     with pytest.raises(ValueError, match=r"\[b, H, dh\]"):
         paged_attention_decode(jnp.zeros((2, 256)), ck, cv, 0, table,
                                lengths, interpret=True)
+    with pytest.raises(ValueError, match="does not match the pools"):
+        paged_attention_prefill(jnp.zeros((2, 3, 16, 128)), ck, cv, 0, table,
+                                lengths, lengths, interpret=True)
+    with pytest.raises(ValueError, match=r"\[b, H, Tc, dh\]"):
+        paged_attention_prefill(jnp.zeros((2, 2, 128)), ck, cv, 0, table,
+                                lengths, lengths, interpret=True)
+    with pytest.raises(ValueError, match="whole sublane tiles"):
+        paged_attention_prefill(jnp.zeros((2, 2, 12, 128)), ck, cv, 0, table,
+                                lengths, lengths, interpret=True)

@@ -785,9 +785,82 @@ def paged_attention_decode_matches_reference():
     return "; ".join(detail)
 
 
+@check
+def paged_attention_prefill_matches_gathered():
+    """The chunk walk (``paged_attention_prefill``) against the gathered
+    path (``_gather_pages`` + ``reference_attention``) at the
+    ``kexaone-serve-reason`` cell's shapes (64 query over 8 KV heads of 128,
+    bf16 pages of 64, chunks of 256, a table 96 wide), one full layer and
+    one window layer (128 keys), two chunks of ONE prompt: the first at
+    start 0, whole; the second at start 256 with 190 real tokens. The
+    kernel's pools hold NaN in every page out of the walk's reach (the
+    window layer: every page behind the window too); the bf16 results are
+    no further from a float32 softmax than the gathered path's own."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels.flash_attention import reference_attention
+    from paddle_tpu.kernels.paged_attention import (chunk_pages_in_reach,
+                                                    paged_attention_prefill)
+    from paddle_tpu.ops.pipeline_ops import _gather_pages
+
+    L, N, ps, P, layer = 2, 64, 64, 96, 1
+    H, Hkv, dh, Tc = 64, 8, 128, 256
+    rng = np.random.RandomState(7)
+    pools = [jnp.asarray(rng.randn(L, N, ps, Hkv * dh), jnp.bfloat16)
+             for _ in range(2)]
+    pages = rng.permutation(np.arange(1, N))[:7]
+    table = np.full((1, P), N + 5, np.int32)    # the tail: out of range
+    table[0, :len(pages)] = pages
+    detail = []
+    for window in (None, 128):
+        for start, length in ((0, Tc), (Tc, 190)):
+            q = jnp.asarray(2 * rng.randn(1, H, Tc, dh), jnp.bfloat16)
+            first, end = (int(a) for a in chunk_pages_in_reach(
+                np.int64(start), np.int64(length), ps, window, xp=np))
+            poison = np.ones((L, N, 1, 1), bool)
+            poison[layer, table[0, first:end]] = False
+            ck, cv = (jnp.where(jnp.asarray(poison), jnp.nan, a)
+                      for a in pools)
+            s0 = jnp.asarray([start], jnp.int32)
+            n0 = jnp.asarray([length], jnp.int32)
+            walk = jax.jit(lambda q, ck, cv, s0, n0, w=window:
+                           paged_attention_prefill(
+                               q, ck, cv, jnp.int32(layer),
+                               jnp.asarray(table), s0, n0, window=w))
+            got = np.asarray(walk(q, ck, cv, s0, n0).astype(jnp.float32))
+            t0 = time.perf_counter()
+            walk(q, ck, cv, s0, n0).block_until_ready()
+            ms = (time.perf_counter() - t0) * 1e3
+            seen = jnp.asarray(np.clip(table, 0, N - 1))
+            m = dict(causal=True, q_pos0=s0)
+            if window is not None:
+                m.update(window=window, k_pos0=jnp.zeros((1,), jnp.int32))
+
+            def gathered(q, k, v):
+                out = reference_attention(
+                    q, _gather_pages(k, layer, seen, Hkv),
+                    _gather_pages(v, layer, seen, Hkv), **m)
+                return out.transpose(0, 2, 1, 3).reshape(1, Tc, -1)
+
+            want = np.asarray(gathered(q, *pools).astype(jnp.float32))
+            truth = np.asarray(gathered(*(
+                a.astype(jnp.float32) for a in (q, *pools))))
+            err = np.abs(got - truth)[0, :length].max()
+            ref_err = np.abs(want - truth)[0, :length].max()
+            tol = max(2 * ref_err, 2.0 ** -8 * np.abs(truth).max())
+            assert np.isfinite(got).all() and err <= tol, (
+                window, start, err, tol)
+            assert not got[0, length:].any()    # padding queries: zeros
+            detail.append(f"w{window} s{start}: err {err:.1e} (gathered "
+                          f"{ref_err:.1e}) {ms:.2f} ms with dispatch")
+    return "; ".join(detail)
+
+
 def main():
     failures = 0
-    for fn in CHECKS:
+    # (names on the command line: those checks alone)
+    for fn in [c for c in CHECKS
+               if not sys.argv[1:] or c.__name__ in sys.argv[1:]]:
         t0 = time.perf_counter()
         try:
             detail = fn() or ""

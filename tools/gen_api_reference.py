@@ -93,9 +93,10 @@ MODULES = [
      "topk_group=) and the Switch op"),
     ("paddle_tpu.kernels.flash_attention", "Pallas flash attention"),
     ("paddle_tpu.kernels.paged_attention",
-     "Pallas paged decode attention: walks the block table (one query "
-     "position a row, or a verify tick's two folded into one walk: "
-     "paged_attention_verify)"),
+     "Pallas paged attention: walks the block table (one query position "
+     "a row, a verify tick's two folded into one walk: "
+     "paged_attention_verify, or a prefill chunk's queries over the pages "
+     "they reach: paged_attention_prefill)"),
     ("paddle_tpu.kernels.kda",
      "Kimi Delta Attention: the gated delta rule with a per-channel decay "
      "token by token, chunked (prefill), and the kda_decode_step Pallas "

@@ -6,7 +6,8 @@
         lowered from the ops at the cell's shapes and compiled for a
         DESCRIBED v5e with the chip's own compiler, no chip needed:
         ``memory_analysis()`` and which kernels are in each program (the
-        tick's attention must be the page walk, never a gathered table);
+        tick's attention AND the unit's must be the page walk, never a
+        gathered table);
         ``--emit-topk 8``: the check's twin, the beam plane compiled in
     variants [--seed N] [--requests 2] [--only a,b] [--plain-init 0|1]
         the check's readings (served top-8 log-prob error of the stack and
@@ -114,6 +115,9 @@ def compile_(args) -> int:
             "alias_gb": mem.alias_size_in_bytes / 1e9,
             "attention_kernel_calls": len(re.findall(
                 r"%paged_attention_decode[.\d]* = ", text)),
+            # the chunk walk: one call a K/V layer of the prefill unit
+            "prefill_kernel_calls": len(re.findall(
+                r"%paged_attention_prefill[.\d]* = ", text)),
             # a gathered table-width context: pages [rows, P, ps, W]
             "gathered_tables": len(re.findall(
                 rf"\[\d+,{P},{ps},{W}\]", text))}
