@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
+from ..kernels import grouped_matmul
 from .common import amp_cast, amp_enabled, mxu_precision, out, single
 
 
@@ -82,6 +83,22 @@ def switch_moe(attrs, ins):
 _EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
+def experts_on_kernel(n_rows: int, d: int, f: int, layer) -> bool:
+    """Whether ``moe_topk`` multiplies ``n_rows`` assignment rows by [d, f]
+    / [f, d] expert planes under ``layer`` with the Pallas kernel. ONE
+    algorithm, two implementations by what the call can observe: on a TPU
+    the serving form (``layer``: a decode tick's rows and a prefill unit's
+    alike) goes to ``kernels/grouped_matmul`` (it visits only the (row
+    tile, expert) pairs that hold rows; its docstring has the per-shape
+    table), the train op (``layer`` None: a gradient is taken), a call of
+    a few rows and the CPU keep XLA's ``ragged_dot``. The serving engine
+    asks the same question of the programs it builds
+    (``moe_kernel_layer_calls``)."""
+    dtype = jnp.bfloat16 if amp_enabled() else jnp.float32
+    return all(grouped_matmul.grouped_supported(n_rows, a, b, dtype, layer)
+               for a, b in ((d, f), (f, d)))
+
+
 def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
              layer=None, act="silu", router_x=None, shared=None, held=None,
              routed_scale=1.0, score="softmax", bias=None, n_group=1,
@@ -114,7 +131,11 @@ def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
     per-layer slice [E, d, f] to the grouped-matmul custom call makes XLA
     COPY it first: 3 x 268 MB a layer at OLMoE's widths, 19.6 of an 82 ms
     decode tick (my chip run, PR 26). The serving ops pass ``layer``; the
-    train op scans slices (its weight gradient must be one layer's).
+    train op scans slices (its weight gradient must be one layer's). On a
+    TPU the ``layer`` form multiplies on ``kernels/grouped_matmul`` instead
+    (``experts_on_kernel``): the same operands and accumulation, the layer
+    a prefetched scalar of the kernel's weight index, only the experts
+    that took a row ever read.
 
     ``act``: the gate's activation (``silu``: SwiGLU, ``relu``: ReGLU).
     ``router_x`` [N, d]: what the router reads when that is not ``x`` (a
@@ -178,11 +199,13 @@ def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
         flat_e = jnp.where(present, flat_e - first, n_here)
         sizes = jax.lax.dynamic_slice(counts, (first,), (n_here,))
     order = jnp.argsort(flat_e, stable=True)                  # by expert
+    on_kernel = experts_on_kernel(N * k, d, gate_w.shape[-1], layer)
     if layer is not None:
         n_layers = gate_w.shape[0]
-        sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((n_layers * n_here,), jnp.int32), sizes,
-            (layer * n_here,))
+        if not on_kernel:
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((n_layers * n_here,), jnp.int32), sizes,
+                (layer * n_here,))
         gate_w, up_w, down_w = (w.reshape((n_layers * n_here,) + w.shape[2:])
                                 for w in (gate_w, up_w, down_w))
     rows = x32[order // k]                                    # [N*k, d]
@@ -190,6 +213,9 @@ def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
         rows = rows.astype(jnp.bfloat16)
 
     def grouped(a, w):
+        if on_kernel:
+            return grouped_matmul.grouped_matmul(
+                a, w, sizes, layer=layer, precision=mxu_precision())
         if w.dtype != a.dtype:      # bf16 weights under float32 compute
             w = w.astype(a.dtype)
         return jax.lax.ragged_dot(a, w, sizes, precision=mxu_precision(),

@@ -641,6 +641,7 @@ class GenerationEngine:
         self._caches: List[PageCache] = [PageCache(
             "global", pool, index, layers=spec.pool_layers(False), **kw)]
         self._chunk_walk: Dict[int, bool] = {}   # ``_chunk_walks``' memo
+        self._expert_kernel: Dict[int, bool] = {}   # ``_experts_on_kernel``'s
         if spec.block.has_window:
             # what a slot's window layers can hold at once (the window,
             # the chunk in flight, one page of slack each way)
@@ -996,7 +997,9 @@ class GenerationEngine:
         layers), ``moe_hot_expert_rows`` (the busiest expert's rows,
         summed over layers), ``moe_touched_experts`` (experts that took
         at least one row, summed over the call's ``moe_layer_calls``
-        layers: their weights are what the grouped matmuls must read)
+        layers: their weights are what the grouped matmuls must read),
+        ``moe_kernel_layer_calls`` (those of the layer calls whose grouped
+        matmuls ran on the Pallas kernel: ``_experts_on_kernel``)
         and ``moe_dropped_tokens`` (the ``rows`` token rows of the call x
         top-k x layers, minus what the experts took: dropless routing
         keeps it 0). Every row of the static batch is routed, vacant
@@ -1019,9 +1022,24 @@ class GenerationEngine:
             self.metrics.inc("moe_absent_assignments", took - held)
         self.metrics.inc("moe_touched_experts", int((here > 0).sum()))
         self.metrics.inc("moe_layer_calls", int(counts.shape[0]))
+        self.metrics.inc("moe_kernel_layer_calls", int(
+            counts.shape[0]) if self._experts_on_kernel(rows) else 0)
         self.metrics.inc("moe_dropped_tokens",
                          rows * self.spec.experts_per_tok
                          * int(counts.shape[0]) - took)
+
+    def _experts_on_kernel(self, rows: int) -> bool:
+        """Whether the expert layers of a program of ``rows`` token rows
+        multiply on the Pallas grouped matmul: the op's own predicate
+        (``ops/moe_ops.experts_on_kernel``) over the shape it sees."""
+        if rows not in self._expert_kernel:
+            from ..ops.moe_ops import experts_on_kernel
+
+            spec = self.spec
+            self._expert_kernel[rows] = experts_on_kernel(
+                rows * spec.experts_per_tok, spec.d_model, spec.d_expert,
+                layer=0)
+        return self._expert_kernel[rows]
 
     def _beam_out_vars(self, helper, rows: int, prefix: str):
         """TopV/TopI output vars when the beam plane is on."""

@@ -403,16 +403,17 @@ def _prefill_unit_text(cell_name, one_chip, monkeypatch):
     return re.sub(r"\{[^{}]*\}", "", text), spec, e, P
 
 
-@pytest.mark.parametrize("cell_name,calls", [
-    # two full + six window layers and the drafting block's full layer
-    ("kexaone-serve-reason", 9),
+@pytest.mark.parametrize("cell_name,calls,experts", [
+    # two full + six window layers and the drafting block's full layer;
+    # seven expert layers and the drafting block's, three products each
+    ("kexaone-serve-reason", 9, 24),
     # one period under the scan: a full layer, three window layers
-    ("smallthinker-serve-mixed", 4),
+    ("smallthinker-serve-mixed", 4, 12),
     # the one softmax layer of the period, beside three recurrent ones
-    ("solar2-serve-agent", 1),
+    ("solar2-serve-agent", 1, 12),
 ])
 def test_prefill_unit_on_the_v5e_walks_the_pages_of_whole_pools(
-        one_chip, monkeypatch, cell_name, calls):
+        one_chip, monkeypatch, cell_name, calls, experts):
     """The prefill unit of the three cells whose temporaries the gathered
     scores sized, compiled for the chip at the cell's shapes: every K/V
     layer's attention is the chunk walk under its OWN call name, the pools
@@ -441,6 +442,16 @@ def test_prefill_unit_on_the_v5e_walks_the_pages_of_whole_pools(
     assert not [shape for shape, _ in ops
                 if re.search(rf"f32\[(\d+,){{2,}}{Tc},{P * ps}\]", shape)
                 or f",{P},{ps},{W}]" in shape or f"[{P},{ps},{W}]" in shape]
+    # the expert layers' three products a layer are the grouped-matmul
+    # kernel's (a unit's rows are over its threshold), each on the WHOLE
+    # flattened stack; XLA's ragged_dot is gone from the unit
+    rows = Tc * spec.experts_per_tok
+    products = [ln for ln in flat.splitlines() if "custom-call(" in ln
+                and "%grouped_matmul" in ln and "tpu_custom_call" in ln]
+    assert len(products) == experts and "ragged" not in flat
+    stacks = tuple(f"f32[{rows},{n}] custom-call(" for n in
+                   (spec.d_expert, spec.d_model))
+    assert all(any(st in c for st in stacks) for c in products)
 
 
 def test_latent_kernel_compiles_for_the_v5e_with_the_one_pool_whole(one_chip):

@@ -856,6 +856,45 @@ def paged_attention_prefill_matches_gathered():
     return "; ".join(detail)
 
 
+@check
+def grouped_matmul_matches_ragged_dot():
+    """The grouped-matmul kernel against ``jax.lax.ragged_dot`` on the
+    sliced layer, compiled, at two serving shapes: smallthinker's prefill
+    unit (1536 rows over 64 experts, 2560 -> 768, layer 5 of 12, uneven
+    groups with empty ones) and kexaone's (2048 static rows of which 130
+    are owned by 8 held experts, 6144 -> 2048: rows behind the groups are
+    visited by nothing). bf16 operands, float32 accumulation on both sides:
+    the owned rows agree to the last bits of a K-long float32 sum."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels.grouped_matmul import grouped_matmul
+
+    rng = np.random.RandomState(11)
+    detail = []
+    for m, k, n, layers, groups, layer, owned in (
+            (1536, 2560, 768, 12, 64, 5, 1536),
+            (2048, 6144, 2048, 3, 8, 2, 130)):
+        cut = np.sort(rng.randint(0, owned + 1, groups - 1))
+        sizes = np.diff(np.concatenate([[0], cut, [owned]])).astype(np.int32)
+        sizes[rng.randint(0, groups, 2)] = 0        # empty groups too
+        owned = int(sizes.sum())
+        rows = jnp.asarray(rng.randn(m, k), jnp.bfloat16)
+        w = jnp.asarray(0.05 * rng.randn(layers * groups, k, n), jnp.bfloat16)
+        got = jax.jit(lambda r, w, s, l: grouped_matmul(r, w, s, layer=l))(
+            rows, w, jnp.asarray(sizes), jnp.int32(layer))
+        want = jax.jit(lambda r, w, s: jax.lax.ragged_dot(
+            r, w, s, preferred_element_type=jnp.float32))(
+                rows, w[layer * groups:(layer + 1) * groups],
+                jnp.asarray(sizes))
+        got, want = np.asarray(got)[:owned], np.asarray(want)[:owned]
+        err = np.abs(got - want).max()
+        tol = 1e-5 * np.abs(want).max() * np.sqrt(k)
+        assert np.isfinite(got).all() and err <= tol, (m, k, n, err, tol)
+        detail.append(f"{m}x{k}->{n}: {int((sizes > 0).sum())}/{groups} "
+                      f"groups, {owned} rows, err {err:.1e} (tol {tol:.1e})")
+    return "; ".join(detail)
+
+
 def main():
     failures = 0
     # (names on the command line: those checks alone)

@@ -97,6 +97,11 @@ MODULES = [
      "a row, a verify tick's two folded into one walk: "
      "paged_attention_verify, or a prefill chunk's queries over the pages "
      "they reach: paged_attention_prefill)"),
+    ("paddle_tpu.kernels.grouped_matmul",
+     "Pallas grouped matmul for sorted assignment rows: the expert "
+     "layer's products in the serving programs, visiting only the (row "
+     "tile, expert) pairs that hold rows; grouped_supported is "
+     "moe_topk's dispatch rule"),
     ("paddle_tpu.kernels.kda",
      "Kimi Delta Attention: the gated delta rule with a per-channel decay "
      "token by token, chunked (prefill), and the kda_decode_step Pallas "
