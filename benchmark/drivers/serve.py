@@ -157,13 +157,18 @@ def run(cell, seed: int, seconds: float, traced: bool, devices,
         requests = [Sent(p, start + p.due) for p in planned]
         threads.append(threading.Thread(
             target=_generate, args=(srv, requests, log), name="bench-load"))
+        at_slice = []       # the engine's counters round the traced slice
         if traced:
             def profile():
                 _sleep_until(t_open + mix["trace_after_s"])
                 slice_.start()
-                _sleep_until(t_open + mix["trace_after_s"]
-                             + mix["trace_slice_s"])
-                slice_.stop()
+                try:
+                    at_slice.append(_engine_counters(eng))
+                    _sleep_until(t_open + mix["trace_after_s"]
+                                 + mix["trace_slice_s"])
+                    at_slice.append(_engine_counters(eng))
+                finally:
+                    slice_.stop()
 
             threads.append(threading.Thread(target=profile,
                                             name="bench-profiler"))
@@ -241,13 +246,21 @@ def run(cell, seed: int, seconds: float, traced: bool, devices,
 
     delta = {k: at_close[k] - at_open.get(k, 0) for k in at_close
              if k != "decode_step_p50_ms"}
+    # what the engine counted while the profiler ran: a reader that prices
+    # the slice's device ops by a counter's mean wants the mean over THOSE
+    # calls (a slice with fewer prefill units than the window's share of
+    # them read mistral4's expert roofline at 101%, PERF.md section 6)
+    in_slice = ({k: at_slice[1][k] - at_slice[0].get(k, 0)
+                 for k in at_slice[1] if k != "decode_step_p50_ms"}
+                if len(at_slice) == 2 else None)
     return harness.Measured(
         correct=not problems and not failed and len(due) > 0,
         attempted=len(due), failed=len(failed),
         setup_s=t_open - t0,
         end_to_end={"tpot_p95_ms": percentile(gaps, 95)},
         counters={
-            **delta, "window": (t_open, t_close), "slots": eng.slots,
+            **delta, "slice": in_slice,
+            "window": (t_open, t_close), "slots": eng.slots,
             "tokens_per_s": in_window / seconds,
             "decode_step_p50_ms": at_close["decode_step_p50_ms"],
             "window_fresh_compiles": delta["fresh_compiles"],

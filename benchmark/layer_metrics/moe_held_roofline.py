@@ -11,7 +11,11 @@ the weights read are those of the held experts a call touched
 only for such a spec). Never the static N*k: three quarters of those rows
 belong to absent chips and are masked. The trace does not say which groups
 of one call were empty, so the per-shape shares on stdout are rough and
-the sum is what the metric is. Source: device trace (+ those counters)."""
+the sum is what the metric is. The counters are those of the TRACED SLICE
+where the driver took them (``counters["slice"]``, PR 53), else the
+window's: the sum is right only while the mean is over the calls that are
+priced, and a slice holds its own share of prefill units (32 experts
+touched) among its ticks (6-7). Source: device trace (+ those counters)."""
 import json
 import re
 import sys
@@ -34,6 +38,10 @@ def read(trace, spans, counters, cell):
 
 def _read(trace, counters, cell):
     family = cell.family
+    in_slice = counters.get("slice")
+    if in_slice and in_slice.get("moe_layer_calls") \
+            and in_slice.get("moe_assignments"):
+        counters = in_slice
     took = counters.get("moe_assignments")
     if not hasattr(family, "grouped_matmul_cost") \
             or not counters.get("moe_layer_calls") or not took \
@@ -73,5 +81,7 @@ def _read(trace, counters, cell):
                                / row["seconds"])
     print(json.dumps({"moe_held_roofline": detail,
                       "held_share_pct": 100.0 * held_share,
+                      "counted_over": ("slice" if counters is in_slice
+                                       else "window"),
                       "touched_held_experts_mean": touched}), flush=True)
     return 100.0 * least / spent

@@ -193,6 +193,19 @@ def test_held_roofline_counts_the_held_rows_never_the_static_ones(capsys):
     assert moe_held_roofline.read(tr, [], counted, Cell) \
         == pytest.approx(50.0, rel=1e-6)
     assert '"held_share_pct": 25.0' in capsys.readouterr().out
+    # the traced slice's own counters, where the driver took them, price
+    # the slice's calls: the window's mean (here 40 experts a call, which
+    # would read over 100%) is then not used; an empty slice falls back
+    skewed = dict(counted, moe_touched_experts=400, slice=counted)
+    assert moe_held_roofline.read(tr, [], skewed, Cell) \
+        == pytest.approx(50.0, rel=1e-6)
+    assert '"counted_over": "slice"' in capsys.readouterr().out
+    assert moe_held_roofline.read(
+        tr, [], dict(counted, slice={"moe_layer_calls": 0}), Cell) \
+        == pytest.approx(50.0, rel=1e-6)
+    assert '"counted_over": "window"' in capsys.readouterr().out
+    assert moe_held_roofline.read(tr, [], dict(counted, slice=None), Cell) \
+        == pytest.approx(50.0, rel=1e-6)
     assert moe_held_rows_pct.read(None, [], counted, Cell) == 25.0
     assert moe_held_rows_pct.read(None, [], {}, Cell) is None
     assert moe_held_roofline.read(tr, [], {"moe_assignments": 5,

@@ -2,7 +2,10 @@
 ``gaps_over_tick_pct`` (the distance of ``tpot_p95_ms`` from the cliff
 between a tick and a tick plus a prefill unit) and the driver's
 ``slo_met`` (whose limit on the time to first token may grow with the
-prompt). Run by hand: ``pytest benchmark/tests``."""
+prompt); and the ``rated`` block a re-rated mix carries (PR 53): what the
+rate was set from, as data. Run by hand: ``pytest benchmark/tests``."""
+import glob
+import json
 import os
 import time
 
@@ -14,6 +17,7 @@ from benchmark.layer_metrics import (gaps_over_p95_mode_pct,
                                      gaps_over_tick_pct)
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+MIXES = os.path.join(os.path.dirname(os.path.dirname(DATA)), "mixes")
 FLAT = {"ttft_ms": 1000, "mean_gap_ms": 100}
 GROWS = dict(FLAT, ttft_ms_per_prompt_token=0.4)
 
@@ -84,6 +88,43 @@ def test_nothing_to_read_gives_none(reader, counters):
 ])
 def test_slo_met(slo, prompt, first_ms, gap_ms, met):
     assert slo_met(slo, prompt, first_ms, gap_ms) is met
+
+
+def _rated_mixes():
+    """Every serve mix under ``mixes/`` that carries a ``rated`` block."""
+    out = []
+    for path in sorted(glob.glob(os.path.join(MIXES, "*.json"))):
+        with open(path) as f:
+            mix = json.load(f)
+        if mix.get("kind") == "serve" and "rated" in mix:
+            out.append(pytest.param(mix, id=os.path.basename(path)[:-5]))
+    return out
+
+
+#: the keys of a ``rated`` block: the PR that rated the mix, the knee its
+#: sweep showed, the rate as a share of it, the tick and the unit it was
+#: rated at (README step 6 compares the ledger's with these), and the
+#: check runs' ranges
+RATED_KEYS = {"pr", "knee_req_s", "share_of_knee", "decode_step_p50_ms",
+              "prefill_chunk_p50_ms", "gaps_over_tick_pct", "tpot_p95_ms",
+              "runs"}
+
+
+@pytest.mark.parametrize("mix", _rated_mixes())
+def test_a_rated_mix_says_what_its_rate_was_set_from(mix):
+    rated = mix["rated"]
+    assert set(rated) >= RATED_KEYS
+    assert 0.6 <= rated["share_of_knee"] <= 0.9
+    assert mix["arrivals"]["rate_per_s"] == pytest.approx(
+        rated["share_of_knee"] * rated["knee_req_s"], abs=0.05)
+    lo, hi = rated["gaps_over_tick_pct"]
+    # one side of the cliff in every run, and outside the 3-7 stretch
+    assert lo <= hi and (hi < 3.0 or lo > 7.0)
+    lo, hi = rated["tpot_p95_ms"]
+    assert 0 < lo <= hi
+    assert rated["decode_step_p50_ms"] > 0
+    assert rated["prefill_chunk_p50_ms"] > 0
+    assert rated["runs"] >= 10
 
 
 def test_sweep_runs_the_cells_driver_at_the_rate_asked(tmp_path):
