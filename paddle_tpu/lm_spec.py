@@ -62,6 +62,27 @@ and limit its choice to the best ``topk_group`` of ``n_group`` groups
 (``router_score`` / ``router_bias`` / ``n_group`` / ``topk_group``:
 DeepSeek-V3's ``noaux_tc``).
 
+The same vocabulary names HALF a block (a stack whose every layer is ONE
+residual step ``x + F(norm(x))``, F a mixer OR a feed-forward: Nemotron-H):
+``"<kind>+none"`` is the kind's mixer alone (norm 1, no FFN), ``"none+ffn"``
+the feed-forward alone (norm 2; no mixer, no cache row, no state);
+``Block.layer_parts`` gives (mixer or None, has an FFN) a position, the norm
+planes lead with the layers that have that half (groups ``mixers`` /
+``ffns``) and every other plane with its kind's, as before. ``mamba2`` is the
+fourth kind: a Mamba-2 state-space layer (arXiv:2405.21060; ``mamba_heads``
+heads of ``mamba_head_dim`` over ``mamba_state`` dimensions, B and C shared
+by the heads of one of ``mamba_groups`` groups, a causal convolution of
+``mamba_conv`` taps with a bias, the read-out gated BEFORE a group RMSNorm;
+a prefill chunk scans blocks of ``mamba_chunk`` tokens), whose slot holds a
+float32 state [heads, head_dim, state] and the convolution's history
+(``slot_state``: "MambaState" "MambaConv"): like ``kda`` it caches no token,
+and every gate that refuses a spec with state refuses it too. An expert
+layer of such a stack may be UNGATED (``expert_act="relu2"``:
+``relu(x W_up)^2 W_down``, no gate plane, the shared expert alike) and work
+in a LATENT (``expert_latent``: one down-projection before the routed
+experts and one up-projection after them, shared by all; the router and the
+shared expert read the model's width).
+
 ``first_dense`` also heads a stack of ``full`` / ``window`` layers (planes
 ``[L, ..]`` for what every layer has, ``[first_dense, ..]`` for the dense
 FFN, ``[L - first_dense, ..]`` for the experts: ``plane_layers``).
@@ -98,14 +119,22 @@ ROPE_PAIRINGS = ("interleaved", "half")
 LAYER_KINDS = ("full+rope", "full+nope", "window+rope", "window+nope")
 #: ... or, for a stack whose layers differ in attention KIND (planes held by
 #: kind, a recurrent state beside the pages): every entry one of these
-ATTN_KINDS = ("kda", "mla", "gqa")
+ATTN_KINDS = ("kda", "mla", "gqa", "mamba2")
+#: ... or, in the same vocabulary, HALF a block: ``"<kind>+none"`` is the
+#: mixer alone (x + Mixer(norm 1(x)): no FFN, no second norm), ``"none+ffn"``
+#: the feed-forward alone (x + FFN(norm 2(x)): no mixer, no cache row, no
+#: state). A bare ``"<kind>"`` is the whole block it always was.
+MIXER_ONLY = tuple(k + "+none" for k in ATTN_KINDS)
+FFN_ONLY = "none+ffn"
+_KIND_ENTRIES = ATTN_KINDS + MIXER_ONLY + (FFN_ONLY,)
 #: the kinds of ``ATTN_KINDS`` that cache tokens in pages (a stack holds at
 #: most one of them: one page pool layout, one table)
 PAGED_KINDS = ("mla", "gqa")
 ROUTER_SCORES = ("softmax", "sigmoid")
 ATTN_GATES = ("none", "head", "channel")
 KDA_DECAYS = ("bounded", "softplus")
-EXPERT_ACTS = ("silu", "relu")          # SwiGLU | ReGLU
+# SwiGLU | ReGLU | relu(x W_up)^2 W_down: UNGATED (no gate plane)
+EXPERT_ACTS = ("silu", "relu", "relu2")
 ROUTER_INPUTS = ("post_attn_norm", "attn_input")
 ATTNS = ("mha", "mla")
 
@@ -228,6 +257,25 @@ class Block:
     kda_decay: str = "bounded"
     kda_neg_eigval: bool = False
     kda_proj_rank: int = 0
+    # Mamba-2 (a ``mamba2`` entry of the pattern; arXiv:2405.21060, the
+    # state-space duality form with ONE scalar decay a head):
+    # ``mamba_heads`` heads of ``mamba_head_dim`` channels read and write a
+    # state [head_dim, mamba_state] through B and C vectors shared by the
+    # heads of one of ``mamba_groups`` groups; x | B | C pass a causal
+    # depthwise convolution of ``mamba_conv`` taps (with a bias) and SiLU;
+    # the read-out is gated by silu(z) BEFORE an RMSNorm over each group's
+    # channels. A prefill chunk scans blocks of ``mamba_chunk`` tokens
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_groups: int = 1
+    mamba_state: int = 0
+    mamba_conv: int = 4
+    mamba_chunk: int = 128
+    # routed experts that work in a LATENT of this width: one down-
+    # projection [d, expert_latent] before them and one up-projection after
+    # them, shared by all; the shared expert stays at the model's width.
+    # 0: experts at the model's width
+    expert_latent: int = 0
     # a drafting (multi-token-prediction) block behind the stack: the
     # module docstring has its equations
     draft_block: bool = False
@@ -265,30 +313,46 @@ class Block:
             object.__setattr__(self, "layer_pattern",
                                tuple(self.layer_pattern))
             pattern = self.layer_pattern
-            by_attn = bool(pattern) and all(k in ATTN_KINDS for k in pattern)
+            by_attn = bool(pattern) and all(k in _KIND_ENTRIES
+                                            for k in pattern)
             if not pattern or not (by_attn or all(k in LAYER_KINDS
                                                   for k in pattern)):
                 raise ValueError(
                     f"layer_pattern {pattern!r}: every entry is one of "
-                    f"{LAYER_KINDS}, or every entry one of {ATTN_KINDS}")
-            if self.is_mla != ("mla" in pattern) or (
+                    f"{LAYER_KINDS}, or every entry one of {ATTN_KINDS} "
+                    f"(a whole block), '<kind>+none' (the mixer alone) or "
+                    f"{FFN_ONLY!r} (the feed-forward alone)")
+            mixers = self.mixers
+            if self.is_mla != ("mla" in mixers) or (
                     self.is_mla and not by_attn):
                 raise ValueError(
                     "a latent block (attn='mla') is one kind of layer "
                     "(no layer_pattern) or the 'mla' entries of a pattern "
                     f"over {ATTN_KINDS}; got attn={self.attn!r} with "
                     f"{pattern!r}")
-            if by_attn and sum(k in pattern for k in PAGED_KINDS) != 1:
+            if by_attn and sum(k in mixers for k in PAGED_KINDS) != 1:
                 raise ValueError(
                     f"a pattern over {ATTN_KINDS} holds ONE kind that "
                     f"caches tokens (one of {PAGED_KINDS}: one pool layout "
                     f"and one table); got {pattern!r}")
-            if "gqa" in pattern and self.qk_norm:
+            if by_attn and not any(ffn for _, ffn in self.layer_parts):
+                raise ValueError(f"layer_pattern {pattern!r} has no "
+                                 "feed-forward position")
+            if "gqa" in mixers and self.qk_norm:
                 raise ValueError("the 'gqa' kind has no QK-norm")
+            if "mamba2" in mixers and (
+                    min(self.mamba_heads, self.mamba_head_dim,
+                        self.mamba_groups, self.mamba_state,
+                        self.mamba_chunk) < 1 or self.mamba_conv < 2
+                    or self.mamba_heads % self.mamba_groups):
+                raise ValueError(
+                    "a 'mamba2' layer needs mamba_heads (a multiple of "
+                    "mamba_groups), mamba_head_dim, mamba_state and "
+                    "mamba_chunk >= 1 and mamba_conv >= 2 taps")
             if self.kda_decay not in KDA_DECAYS:
                 raise ValueError(f"kda_decay {self.kda_decay!r} not in "
                                  f"{KDA_DECAYS}")
-            if "kda" in pattern and (
+            if "kda" in mixers and (
                     self.kda_head_dim < 1 or self.kda_conv < 2
                     or self.kda_proj_rank < 0
                     or (self.kda_decay == "bounded"
@@ -310,8 +374,7 @@ class Block:
         if self.attn_gate not in ATTN_GATES:
             raise ValueError(f"attn_gate {self.attn_gate!r} not in "
                              f"{ATTN_GATES}")
-        if self.attn_gate == "channel" and "gqa" not in (
-                self.attn_kinds or ()):
+        if self.attn_gate == "channel" and "gqa" not in self.mixers:
             raise ValueError("attn_gate='channel' gates the 'gqa' kind of a "
                              f"layer_pattern over {ATTN_KINDS}")
         if self.router_score not in ROUTER_SCORES:
@@ -345,6 +408,13 @@ class Block:
                              f"{ROPE_PAIRINGS}")
         if self.ffn == "swiglu_moe" and self.experts_per_tok < 1:
             raise ValueError("swiglu_moe needs experts_per_tok >= 1")
+        if (self.expert_latent or self.expert_act == "relu2") and not (
+                self.attn_kinds and self.is_moe
+                and self.expert_latent >= 0 and not self.first_dense):
+            raise ValueError(
+                "expert_latent / expert_act='relu2' (ungated experts): the "
+                "expert layers of a stack held by attention kind, without "
+                "leading dense layers")
 
     # the three keys every stacked-LM program has always carried, in the
     # order it carried them; further keys only where they differ from the
@@ -423,20 +493,49 @@ class Block:
         """The period of a stack whose layers differ in attention KIND
         (every entry of ``ATTN_KINDS``); None for every other spec."""
         p = self.layer_pattern
-        return p if p and p[0] in ATTN_KINDS else None
+        return p if p and p[0] in _KIND_ENTRIES else None
+
+    @property
+    def layer_parts(self) -> Tuple[Tuple[Optional[str], bool], ...]:
+        """(mixer kind or None, has a feed-forward) of every position of a
+        period held by attention kind; () for every other spec."""
+        return tuple(
+            (None, True) if k == FFN_ONLY
+            else (k.partition("+")[0], not k.endswith("+none"))
+            for k in self.attn_kinds or ())
+
+    @property
+    def mixers(self) -> Tuple[str, ...]:
+        """The mixer kinds a pattern over attention kinds names."""
+        return tuple(dict.fromkeys(m for m, _ in self.layer_parts if m))
+
+    @property
+    def mamba_conv_width(self) -> int:
+        """Channels of a ``mamba2`` layer's convolution: x | B | C."""
+        return (self.mamba_heads * self.mamba_head_dim
+                + 2 * self.mamba_groups * self.mamba_state)
 
     def slot_state(self, d_model: int) -> List[Tuple[str, tuple, str]]:
         """What a serving SLOT holds, a layer that has it, beside its
         pages and whatever its length: (name, per-slot shape, dtype). The
         name is the paged ops' slot. A ``kda`` layer: the recurrent state
         S [H, K, V] in float32 and the last ``kda_conv - 1`` inputs of the
-        q | k | v convolutions in the page dtype. Empty for a spec without
-        such a layer."""
-        if "kda" not in (self.attn_kinds or ()):
-            return []
-        H, K = self.num_heads, self.kda_head_dim
-        return [("KdaState", (H, K, K), "float32"),
-                ("KdaConv", (self.kda_conv - 1, 3 * H * K), self.page_dtype)]
+        q | k | v convolutions in the page dtype. A ``mamba2`` layer: the
+        state S [heads, head_dim, mamba_state] in float32 and the last
+        ``mamba_conv - 1`` inputs of the x | B | C convolution. Empty for a
+        spec without such a layer."""
+        out = []
+        if "kda" in self.mixers:
+            H, K = self.num_heads, self.kda_head_dim
+            out += [("KdaState", (H, K, K), "float32"),
+                    ("KdaConv", (self.kda_conv - 1, 3 * H * K),
+                     self.page_dtype)]
+        if "mamba2" in self.mixers:
+            out += [("MambaState", (self.mamba_heads, self.mamba_head_dim,
+                                    self.mamba_state), "float32"),
+                    ("MambaConv", (self.mamba_conv - 1,
+                                   self.mamba_conv_width), self.page_dtype)]
+        return out
 
     def require_stateless(self, who: str) -> None:
         """What still refuses a spec with state: beams (a fork shares its
@@ -563,7 +662,7 @@ class Block:
             raise ValueError("a layer_pattern over attention kinds is an "
                              "RMSNorm, bias-free expert stack")
         slots = {"Ln1S": "ln1_s", "Ln2S": "ln2_s"}
-        if "kda" in self.attn_kinds:
+        if "kda" in self.mixers:
             low = self.kda_proj_rank > 0
             slots.update(KdaQkvW="kda_qkv_w", KdaConvW="kda_conv_w")
             slots.update(dict(KdaADownW="kda_a_down_w",
@@ -576,9 +675,15 @@ class Block:
                               KdaGateB="kda_gate_b") if low
                          else dict(KdaGateW="kda_gate_w"))
             slots.update(KdaNormS="kda_norm_s", KdaOutW="kda_out_w")
-        if "mla" in self.attn_kinds:
+        if "mamba2" in self.mixers:
+            slots.update(MambaInW="mamba_in_w", MambaConvW="mamba_conv_w",
+                         MambaConvB="mamba_conv_b",
+                         MambaDtBias="mamba_dt_bias", MambaALog="mamba_a_log",
+                         MambaD="mamba_d", MambaNormS="mamba_norm_s",
+                         MambaOutW="mamba_out_w")
+        if "mla" in self.mixers:
             slots.update(self._mla_slots())
-        if "gqa" in self.attn_kinds:
+        if "gqa" in self.mixers:
             slots["GqaQkvW"] = "gqa_qkv_w"
             if self.attn_gate == "channel":
                 slots["GqaGateW"] = "gqa_gate_w"
@@ -589,22 +694,33 @@ class Block:
         slots["RouterW"] = "router_w"
         if self.router_bias:
             slots["RouterB"] = "router_b"
-        slots.update(MoeGateW="moe_gate_w", MoeUpW="moe_up_w",
-                     MoeDownW="moe_down_w")
+        gated = self.expert_act != "relu2"
+        if self.expert_latent:
+            slots["MoeLatentDownW"] = "moe_latent_down_w"
+        if gated:
+            slots["MoeGateW"] = "moe_gate_w"
+        slots.update(MoeUpW="moe_up_w", MoeDownW="moe_down_w")
+        if self.expert_latent:
+            slots["MoeLatentUpW"] = "moe_latent_up_w"
         if self.shared_expert:
-            slots.update(SharedGateW="shared_gate_w",
-                         SharedUpW="shared_up_w",
+            if gated:
+                slots["SharedGateW"] = "shared_gate_w"
+            slots.update(SharedUpW="shared_up_w",
                          SharedDownW="shared_down_w")
         return slots
 
     @staticmethod
     def plane_group(key: str) -> str:
-        """Which layers of a by-kind stack own plane ``key``: ``all`` |
-        ``kda`` | ``mla`` | ``gqa`` | ``dense`` | ``experts``."""
+        """Which layers of a by-kind stack own plane ``key``: ``mixers``
+        (norm 1: the layers with a mixer) | ``ffns`` (norm 2: those with a
+        feed-forward; both are every layer of a stack of whole blocks) |
+        ``kda`` | ``mamba2`` | ``mla`` | ``gqa`` | ``dense`` | ``experts``."""
         if key in ("ln1_s", "ln2_s"):
-            return "all"
+            return "mixers" if key == "ln1_s" else "ffns"
         if key.startswith("kda_"):
             return "kda"
+        if key.startswith("mamba_"):
+            return "mamba2"
         if key.startswith("gqa_"):
             return "gqa"
         if key.startswith("dense_"):
@@ -616,13 +732,19 @@ class Block:
     def group_index(self, n_layers: int) -> Dict[str, List[Optional[int]]]:
         """group -> for each of the stack's ``n_layers`` layers its index
         WITHIN the group's planes (None: the layer has none)."""
-        kinds = self.attn_kinds
-        of = {"all": lambda l: True,
-              "kda": lambda l: kinds[l % len(kinds)] == "kda",
-              "mla": lambda l: kinds[l % len(kinds)] == "mla",
-              "gqa": lambda l: kinds[l % len(kinds)] == "gqa",
-              "dense": lambda l: l < self.first_dense,
-              "experts": lambda l: l >= self.first_dense}
+        parts = self.layer_parts
+
+        def mixer(l):
+            return parts[l % len(parts)][0]
+
+        def ffn(l):
+            return parts[l % len(parts)][1]
+
+        of = {"mixers": lambda l: mixer(l) is not None, "ffns": ffn,
+              **{kind: (lambda l, kind=kind: mixer(l) == kind)
+                 for kind in ATTN_KINDS},
+              "dense": lambda l: ffn(l) and l < self.first_dense,
+              "experts": lambda l: ffn(l) and l >= self.first_dense}
         out = {}
         for group, has in of.items():
             n, ix = 0, []
@@ -644,20 +766,25 @@ OPTIONAL_STACK_SLOTS = ("Ln1B", "Ln2B", "QNormS", "KNormS", "FfW1", "FfB1",
                         "KdaALog", "KdaBetaW", "KdaGateW", "KdaNormS",
                         "KdaOutW", "DenseGateW", "DenseUpW", "DenseDownW",
                         "KdaADownW", "KdaAUpW", "KdaGateDownW", "KdaGateUpW",
-                        "KdaGateB", "GqaQkvW", "GqaGateW", "GqaOutW")
+                        "KdaGateB", "GqaQkvW", "GqaGateW", "GqaOutW",
+                        "MambaInW", "MambaConvW", "MambaConvB",
+                        "MambaDtBias", "MambaALog", "MambaD", "MambaNormS",
+                        "MambaOutW", "MoeLatentDownW", "MoeLatentUpW")
 #: matrix planes the ops read in float32 under AMP too: the router's
 #: logits (``moe_topk``) and the taps of a ``kda`` layer's convolution
-_F32_READ_PLANES = ("router_w", "kda_conv_w")
+_F32_READ_PLANES = ("router_w", "kda_conv_w", "mamba_conv_w")
 #: what ``Block.slot_state`` may list: the paged ops' state slots (inputs,
 #: and outputs updated in place)
-STATE_SLOTS = ("KdaState", "KdaConv")
+STATE_SLOTS = ("KdaState", "KdaConv", "MambaState", "MambaConv")
+#: the plane whose layer count each state array shares
+_STATE_PLANE = dict(zip(STATE_SLOTS, ("kda_qkv_w", "kda_qkv_w",
+                                      "mamba_in_w", "mamba_in_w")))
 #: ... and, for an engine with a snapshot pool, the snapshot rows of each
 #: ([layers, n_snapshots, *shape], the prefill op's alone) with the two
 #: feeds that name, row by row of a prefill call, the snapshot row a row's
 #: state STARTS from and the one its state is copied into after the chunk
 #: (a value beyond the rows: neither)
-SNAPSHOT_SLOTS = tuple(name + "Snap" for name in STATE_SLOTS) + (
-    "SnapFrom", "SnapTake")
+SNAPSHOT_SLOTS = ("KdaStateSnap", "KdaConvSnap", "SnapFrom", "SnapTake")
 
 
 #: the drafting block's planes outside its one-layer stack: op slot ->
@@ -693,6 +820,13 @@ class LMSpec:
     = (first, count): the routed experts this program holds of the
     router's ``num_experts`` (the expert stacks are [L, count, ..], the
     router [d, num_experts]). ``routed_scale``: ``routed_scaling_factor``.
+
+    A pattern entry may also be HALF a block (``"mamba2+none"``,
+    ``"gqa+none"``, ``"none+ffn"``: the module docstring), ``mamba2`` with
+    ``mamba_heads`` / ``mamba_head_dim`` / ``mamba_groups`` / ``mamba_state``
+    / ``mamba_conv`` / ``mamba_chunk``; ``expert_act="relu2"`` ungated
+    experts, ``expert_latent`` the width of the latent they work in
+    (``d_expert`` stays their inner width).
 
     ``layer_pattern`` over ``("kda", "mla", "gqa")`` with ``kda_head_dim``
     / ``kda_conv`` / ``kda_lower_bound`` / ``kda_decay`` /
@@ -760,6 +894,13 @@ class LMSpec:
     kda_decay: str = "bounded"
     kda_neg_eigval: bool = False
     kda_proj_rank: int = 0
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_groups: int = 1
+    mamba_state: int = 0
+    mamba_conv: int = 4
+    mamba_chunk: int = 128
+    expert_latent: int = 0
     draft_block: bool = False
 
     def __post_init__(self):
@@ -825,10 +966,9 @@ class LMSpec:
         attention kinds the layers that cache tokens (latent or ``gqa``)
         are the full kind (a ``kda`` layer has no pages) and there is no
         window kind."""
-        kinds = self.block.attn_kinds
-        if kinds:
+        if self.block.attn_kinds:
             return 0 if windowed else self.plane_layers(
-                "gqa_qkv_w" if "gqa" in kinds else "kv_a_w")
+                "gqa_qkv_w" if "gqa" in self.block.mixers else "kv_a_w")
         kinds = self.block.kinds
         if kinds is None:
             return 0 if windowed else self.n_layers
@@ -902,7 +1042,7 @@ class LMSpec:
         slot holds that does not grow with its tokens
         (``Block.slot_state``); the engine keeps one array [layers, slots,
         *shape] of each. Empty for a spec without a recurrent layer."""
-        return [(name, shape, dtype, self.plane_layers("kda_qkv_w"))
+        return [(name, shape, dtype, self.plane_layers(_STATE_PLANE[name]))
                 for name, shape, dtype in self.block.slot_state(self.d_model)]
 
     @property
@@ -941,7 +1081,18 @@ class LMSpec:
             d_q = H * dv                # what the out-projection reads
         Kd, taps = self.kda_head_dim, self.kda_conv
         dK, ff, rk = H * Kd, self.ffn_width, self.kda_proj_rank
+        Hm, d_in = self.mamba_heads, self.mamba_heads * self.mamba_head_dim
+        cw, mt = self.block.mamba_conv_width, self.mamba_conv
+        dl = self.expert_latent or d    # what a routed expert reads / writes
         shapes = {
+            # z | x B C | dt of a ``mamba2`` layer, in that order
+            "mamba_in_w": ([d, d_in + cw + Hm], (d, d_in + cw + Hm)),
+            "mamba_conv_w": ([mt, cw], (mt, 1)), "mamba_conv_b": ([cw], None),
+            "mamba_dt_bias": ([Hm], None), "mamba_a_log": ([Hm], None),
+            "mamba_d": ([Hm], None), "mamba_norm_s": ([d_in], None),
+            "mamba_out_w": ([d_in, d], (d_in, d)),
+            "moe_latent_down_w": ([d, dl], (d, dl)),
+            "moe_latent_up_w": ([dl, d], (dl, d)),
             "kda_a_down_w": ([d, rk], (d, rk)),
             "kda_a_up_w": ([rk, dK], (rk, dK)),
             "kda_gate_down_w": ([d, rk], (d, rk)),
@@ -973,9 +1124,9 @@ class LMSpec:
             "ff_w2": ([self.ffn_width, d], (self.ffn_width, d)),
             "ff_b2": ([d], None),
             "router_w": ([d, E], (d, E)),
-            "moe_gate_w": ([Eh, d, f], (d, f)),
-            "moe_up_w": ([Eh, d, f], (d, f)),
-            "moe_down_w": ([Eh, f, d], (f, d)),
+            "moe_gate_w": ([Eh, dl, f], (dl, f)),
+            "moe_up_w": ([Eh, dl, f], (dl, f)),
+            "moe_down_w": ([Eh, f, dl], (f, dl)),
             "shared_gate_w": ([d, fs], (d, fs)),
             "shared_up_w": ([d, fs], (d, fs)),
             "shared_down_w": ([fs, d], (fs, d)),

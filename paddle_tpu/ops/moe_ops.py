@@ -80,7 +80,8 @@ def switch_moe(attrs, ins):
                AuxLoss=aux.reshape(1))
 
 
-_EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+_EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+                "relu2": lambda u: jnp.square(jax.nn.relu(u))}
 
 
 def experts_on_kernel(n_rows: int, d: int, f: int, layer) -> bool:
@@ -102,7 +103,7 @@ def experts_on_kernel(n_rows: int, d: int, f: int, layer) -> bool:
 def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
              layer=None, act="silu", router_x=None, shared=None, held=None,
              routed_scale=1.0, score="softmax", bias=None, n_group=1,
-             topk_group=1):
+             topk_group=1, latent=None):
     """Dropless token-choice top-``k`` gated experts — the expert layer
     of the ``swiglu_moe`` block (ops/pipeline_ops.py calls it from the
     block's FFN half; it is not a program op of its own).
@@ -164,6 +165,15 @@ def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
     groups, a group's score the sum of its two largest (biased) scores;
     only the experts of the ``topk_group`` best groups can be chosen. The
     defaults are the call it always was, bit for bit.
+
+    ``gate_w`` None: UNGATED experts, ``act(x W_up) W_down`` (``act``
+    ``relu2``: the squared ReLU), two grouped matmuls and not three; the
+    shared expert is ungated alike (``shared[0]`` None). ``latent`` =
+    (down_w [d, dl], up_w [dl, d]): the routed experts work in a latent of
+    width dl (up_w / down_w [E, dl, f] / [E, f, dl]): ONE projection of the
+    N token rows down before the sort and one of the combined rows up
+    after it, shared by all experts; the router and the shared expert read
+    ``x`` at the model's width.
     """
     N, d = x.shape
     E = router_w.shape[-1]
@@ -199,16 +209,29 @@ def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
         flat_e = jnp.where(present, flat_e - first, n_here)
         sizes = jax.lax.dynamic_slice(counts, (first,), (n_here,))
     order = jnp.argsort(flat_e, stable=True)                  # by expert
-    on_kernel = experts_on_kernel(N * k, d, gate_w.shape[-1], layer)
+
+    def dense(a, w):
+        if w.dtype != a.dtype:
+            w = w.astype(a.dtype)
+        return jnp.dot(a, w, precision=mxu_precision(),
+                       preferred_element_type=jnp.float32)
+
+    def operand(a):     # a matmul's left operand under the AMP rule
+        return a.astype(jnp.bfloat16) if amp_enabled() else a
+
+    src = x32 if latent is None else dense(operand(x32), latent[0])
+    on_kernel = experts_on_kernel(N * k, src.shape[-1], up_w.shape[-1], layer)
     if layer is not None:
-        n_layers = gate_w.shape[0]
+        n_layers = up_w.shape[0]
         if not on_kernel:
             sizes = jax.lax.dynamic_update_slice(
                 jnp.zeros((n_layers * n_here,), jnp.int32), sizes,
                 (layer * n_here,))
-        gate_w, up_w, down_w = (w.reshape((n_layers * n_here,) + w.shape[2:])
-                                for w in (gate_w, up_w, down_w))
-    rows = x32[order // k]                                    # [N*k, d]
+        gate_w, up_w, down_w = (
+            None if w is None
+            else w.reshape((n_layers * n_here,) + w.shape[2:])
+            for w in (gate_w, up_w, down_w))
+    rows = src[order // k]                                    # [N*k, d | dl]
     if amp_enabled():
         rows = rows.astype(jnp.bfloat16)
 
@@ -221,24 +244,25 @@ def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
         return jax.lax.ragged_dot(a, w, sizes, precision=mxu_precision(),
                                   preferred_element_type=jnp.float32)
 
-    h = _EXPERT_ACTS[act](grouped(rows, gate_w)) * grouped(rows, up_w)
+    if gate_w is None:
+        h = _EXPERT_ACTS[act](grouped(rows, up_w))
+    else:
+        h = _EXPERT_ACTS[act](grouped(rows, gate_w)) * grouped(rows, up_w)
     o = grouped(h.astype(rows.dtype), down_w)                 # [N*k, d] f32
     if present is not None:
         o = jnp.where(present[order][:, None], o, 0.0)
     inv = jnp.argsort(order)                                  # unsort
-    y = jnp.sum(o[inv].reshape(N, k, d) * top_p[..., None], axis=1)
+    y = jnp.sum(o[inv].reshape(N, k, -1) * top_p[..., None], axis=1)
     if routed_scale != 1.0:
         y = y * routed_scale
+    if latent is not None:
+        y = dense(operand(y), latent[1])
     if shared is not None:
-        xs = x32.astype(jnp.bfloat16) if amp_enabled() else x32
-
-        def dense(a, w):
-            if w.dtype != a.dtype:
-                w = w.astype(a.dtype)
-            return jnp.dot(a, w, precision=mxu_precision(),
-                           preferred_element_type=jnp.float32)
-
+        xs = operand(x32)
         s_gate, s_up, s_down = shared
-        hs = _EXPERT_ACTS[act](dense(xs, s_gate)) * dense(xs, s_up)
+        if s_gate is None:
+            hs = _EXPERT_ACTS[act](dense(xs, s_up))
+        else:
+            hs = _EXPERT_ACTS[act](dense(xs, s_gate)) * dense(xs, s_up)
         y = y + dense(hs.astype(xs.dtype), s_down)
     return y.astype(x.dtype), counts, jnp.mean(probs, axis=0)

@@ -275,18 +275,25 @@ def _expand_kv(k, v, num_heads):
     return k, v
 
 
-def _attn_out_ffn(blk, p, x, ctx, out_key="out_w", dense=False):
+def _attn_out_ffn(blk, p, x, ctx, out_key="out_w", dense=False, mixer=True,
+                  ffn=True):
     """Out-projection + residual + FFN half of a block; x [b, t, d] the
     block's input, ctx [b, t, H*dh]. -> (x, stats), stats as ``_block``
     says. A block whose router reads the attention's input
     (``router_input="attn_input"``) routes from norm 1 of ``x``: the same
     expression the attention half computed, which XLA shares. A stack held
     by kind names the layer's out-projection (``out_key``) and whether it
-    is one of the leading ``dense`` SwiGLU layers."""
+    is one of the leading ``dense`` SwiGLU layers; a position of its
+    pattern that is HALF a block leaves the other half out (``mixer``
+    False: no out-projection, ctx None; ``ffn`` False: the mixer's
+    residual alone, stats None)."""
     early = blk.is_moe and blk.router_input == "attn_input"
     router_x = _norm(blk, x, p["ln1_s"], p.get("ln1_b")) if early else None
-    x = x + _mm(blk, "btd,de->bte", ctx.astype(x.dtype), p[out_key],
-                name="attn_out")
+    if mixer:
+        x = x + _mm(blk, "btd,de->bte", ctx.astype(x.dtype), p[out_key],
+                    name="attn_out")
+    if not ffn:
+        return x, None
     h2 = _norm(blk, x, p["ln2_s"], p.get("ln2_b"))
     if dense:
         ff = jax.nn.silu(_mm(blk, "btd,df->btf", h2, p["dense_gate_w"])) \
@@ -300,8 +307,10 @@ def _attn_out_ffn(blk, p, x, ctx, out_key="out_w", dense=False):
         if blk.expert_act != "silu":
             more["act"] = blk.expert_act
         if blk.shared_expert:
-            more["shared"] = (p["shared_gate_w"], p["shared_up_w"],
+            more["shared"] = (p.get("shared_gate_w"), p["shared_up_w"],
                               p["shared_down_w"])
+        if blk.expert_latent:
+            more["latent"] = (p["moe_latent_down_w"], p["moe_latent_up_w"])
         if blk.experts_held is not None:
             more["held"] = blk.experts_held
         if blk.routed_scale != 1.0:
@@ -313,7 +322,7 @@ def _attn_out_ffn(blk, p, x, ctx, out_key="out_w", dense=False):
         if blk.n_group > 1:
             more.update(n_group=blk.n_group, topk_group=blk.topk_group)
         y, counts, prob_mean = moe_topk(
-            h2.reshape(b * t, d), p["router_w"], p["moe_gate_w"],
+            h2.reshape(b * t, d), p["router_w"], p.get("moe_gate_w"),
             p["moe_up_w"], p["moe_down_w"], blk.experts_per_tok,
             blk.norm_topk_prob, layer=p.get("layer"), **more)
         return x + y.reshape(b, t, d), (counts, prob_mean)
@@ -385,6 +394,8 @@ def pipelined_transformer_stack(attrs, ins):
     # "KdaALog" "KdaBetaW" "KdaGateW" "KdaNormS" "KdaOutW" "DenseGateW"
     # "DenseUpW" "DenseDownW" "KdaADownW" "KdaAUpW" "KdaGateDownW"
     # "KdaGateUpW" "KdaGateB" "GqaQkvW" "GqaGateW" "GqaOutW"
+    # "MambaInW" "MambaConvW" "MambaConvB" "MambaDtBias" "MambaALog" "MambaD"
+    # "MambaNormS" "MambaOutW" "MoeLatentDownW" "MoeLatentUpW"
     params = _stack_params(blk, ins)
     causal = attrs.get("causal", True)
 
@@ -592,6 +603,8 @@ def transformer_stack_generate(attrs, ins, rng):
     # "KdaALog" "KdaBetaW" "KdaGateW" "KdaNormS" "KdaOutW" "DenseGateW"
     # "DenseUpW" "DenseDownW" "KdaADownW" "KdaAUpW" "KdaGateDownW"
     # "KdaGateUpW" "KdaGateB" "GqaQkvW" "GqaGateW" "GqaOutW"
+    # "MambaInW" "MambaConvW" "MambaConvB" "MambaDtBias" "MambaALog" "MambaD"
+    # "MambaNormS" "MambaOutW" "MoeLatentDownW" "MoeLatentUpW"
     (prompt, tok_emb, pos_emb, ln_s, ln_b, head_w,
      params) = _unpack_lm_ins(blk, ins)
     if blk.attn_kinds:
@@ -1171,6 +1184,23 @@ def _kda_low_rank(blk, p, hn, key):
     return _mm(blk, "btr,re->bte", low, p[key + "_up_w"])
 
 
+def _short_conv(conv, u, w, n_valid, bias=None):
+    """The causal depthwise convolution of a recurrent layer: u [b, t, C]
+    (float32) behind the row's history conv [b, taps - 1, C] (its last
+    tokens' u), taps w [taps, C] oldest first (+ ``bias`` [C]) -> (y [b, t,
+    C] float32, before its activation; the history after the row's
+    ``n_valid`` [b] tokens of this call, in conv's dtype)."""
+    t, taps = u.shape[1], w.shape[0]
+    full = jnp.concatenate([conv.astype(jnp.float32), u], axis=1)
+    w = w.astype(jnp.float32)
+    y = sum(full[:, i:i + t] * w[i] for i in range(taps))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    at = n_valid[:, None] + jnp.arange(taps - 1, dtype=jnp.int32)[None, :]
+    return y, jnp.take_along_axis(full, at[..., None],
+                                  axis=1).astype(conv.dtype)
+
+
 def _kda_project(blk, p, hn, conv, n_valid):
     """The inputs of the delta rule from the normed stream hn [b, t, d]
     and the row's convolution history conv [b, taps - 1, 3HK] (the q | k |
@@ -1181,14 +1211,10 @@ def _kda_project(blk, p, hn, conv, n_valid):
     after the row's ``n_valid`` [b] tokens of this call. Tokens beyond
     ``n_valid`` get g = 0 and beta = 0: they leave the state as it is."""
     b, t, _ = hn.shape
-    H, K, taps = blk.num_heads, blk.kda_head_dim, blk.kda_conv
+    H, K = blk.num_heads, blk.kda_head_dim
     f32 = jnp.float32
     qkv = _mm(blk, "btd,de->bte", hn, p["kda_qkv_w"]).astype(f32)
-    full = jnp.concatenate([conv.astype(f32), qkv], axis=1)
-    w = p["kda_conv_w"].astype(f32)                     # [taps, 3HK]
-    y = sum(full[:, i:i + t] * w[i] for i in range(taps))
-    at = n_valid[:, None] + jnp.arange(taps - 1, dtype=jnp.int32)[None, :]
-    conv = jnp.take_along_axis(full, at[..., None], axis=1).astype(conv.dtype)
+    y, conv = _short_conv(conv, qkv, p["kda_conv_w"], n_valid)  # [taps, 3HK]
     y = jax.nn.silu(y)
     q, k, v = (y[..., i * H * K:(i + 1) * H * K].reshape(b, t, H, K)
                for i in range(3))
@@ -1275,6 +1301,83 @@ def _kda_layer(blk, p, h, state, conv, l, rows, snaps=None):
     return (o * jax.nn.sigmoid(gate)).astype(h.dtype), state, conv, snaps
 
 
+def _mamba_project(blk, p, hn, conv, n_valid):
+    """The inputs of the Mamba-2 recurrence from the normed stream hn [b,
+    t, d] and the row's convolution history conv [b, taps - 1, x | B | C
+    columns] (its last tokens' projections): -> z [b, t, H*P] (the gate's
+    input), x [b, t, H, P], B, C [b, t, G, N], dt [b, t, H] (softplus(. +
+    dt_bias)), g [b, t, H] (the log-decay -exp(A_log) dt), all float32, and
+    the history after the row's ``n_valid`` [b] tokens of this call. Tokens
+    beyond ``n_valid`` get dt = 0 and g = 0: they leave the state as it
+    is."""
+    b, t, _ = hn.shape
+    H, P, G, N = (blk.mamba_heads, blk.mamba_head_dim, blk.mamba_groups,
+                  blk.mamba_state)
+    d_in, cw = H * P, blk.mamba_conv_width
+    f32 = jnp.float32
+    zxbcdt = _mm(blk, "btd,de->bte", hn, p["mamba_in_w"]).astype(f32)
+    z, xbc, dt = (zxbcdt[..., :d_in], zxbcdt[..., d_in:d_in + cw],
+                  zxbcdt[..., d_in + cw:])
+    y, conv = _short_conv(conv, xbc, p["mamba_conv_w"], n_valid,
+                          bias=p["mamba_conv_b"])       # [taps, x | B | C]
+    y = jax.nn.silu(y)
+    x = y[..., :d_in].reshape(b, t, H, P)
+    B = y[..., d_in:d_in + G * N].reshape(b, t, G, N)
+    C = y[..., d_in + G * N:].reshape(b, t, G, N)
+    valid = jnp.arange(t, dtype=jnp.int32)[None, :] < n_valid[:, None]
+    dt = jnp.where(valid[..., None],
+                   jax.nn.softplus(dt + p["mamba_dt_bias"].astype(f32)), 0.0)
+    g = -jnp.exp(p["mamba_a_log"].astype(f32)) * dt
+    return z, x, B, C, dt, g, conv
+
+
+def _mamba_layer(blk, p, h, state, conv, l, rows):
+    """One ``mamba2`` layer's mixer against the state arrays, in place: h
+    [b, t, d], ``state`` [Lm, slots, H, P, N] float32, ``conv`` [Lm, slots,
+    taps - 1, x | B | C columns], l the layer's index among the mamba2
+    layers, ``rows`` as ``_kda_layer`` takes them. -> (ctx [b, t, H*P]: the
+    read-out plus the D skip, gated by silu(z) and THEN RMSNorm'd over each
+    group's channels, state, conv). A row whose call starts at position 0
+    reads a ZERO state and history whatever its slot held; a row with no
+    valid token leaves both as they were. t == 1 is the recurrent step (on
+    a chip the ``mamba2_decode_step`` kernel over the whole state array), a
+    chunk the chunked (SSD) form."""
+    from ..kernels import mamba2
+
+    slot, start, n_valid = rows
+    b, t, _ = h.shape
+    H, P, G = blk.mamba_heads, blk.mamba_head_dim, blk.mamba_groups
+    live = n_valid > 0
+    fresh = live & (start == 0)
+    hn = _norm(blk, h, p["ln1_s"])
+    ix = jnp.arange(b) if slot is None else slot
+    conv0 = jnp.where(fresh[:, None, None], 0, conv[l, ix])
+    z, x, B, C, dt, g, conv1 = _mamba_project(blk, p, hn, conv0, n_valid)
+    conv1 = jnp.where(live[:, None, None], conv1, conv0)
+    conv = conv.at[l, ix].set(conv1, mode="drop")
+    if slot is None and mamba2.supported(state, t):
+        # (a live decode row never sits at position 0: nothing is fresh)
+        y, state = mamba2.mamba2_decode_step(
+            x[:, 0] * dt[:, 0, :, None], jnp.exp(g[:, 0]), B[:, 0], C[:, 0],
+            state, l, live)
+        y = y[:, None]
+    else:
+        s_old = state[l, ix]
+        s0 = jnp.where(fresh[:, None, None, None], 0.0, s_old)
+        if t == 1:
+            y, s1 = mamba2.mamba2_recurrent(x, dt, g, B, C, s0)
+        else:
+            y, s1 = mamba2.mamba2_chunked(x, dt, g, B, C, s0,
+                                          block=blk.mamba_chunk)
+        s1 = jnp.where(live[:, None, None, None], s1, s_old)
+        state = state.at[l, ix].set(s1, mode="drop")
+    y = y + p["mamba_d"].astype(jnp.float32)[:, None] * x
+    y = y.reshape(b, t, H * P) * jax.nn.silu(z)         # the gate, THEN the norm
+    y = _rms(y.reshape(b, t, G, -1),
+             p["mamba_norm_s"].reshape(G, -1), blk.norm_eps)
+    return y.reshape(b, t, H * P).astype(h.dtype), state, conv
+
+
 def _scan_kind_layers(blk, params, h, pool, table, page_id, page_row, pos0,
                       mask, states, rows, pool_v=None, snaps=None):
     """``_scan_paged_layers`` for a stack held by attention kind: h [b, t,
@@ -1283,11 +1386,14 @@ def _scan_kind_layers(blk, params, h, pool, table, page_id, page_row, pos0,
     layers' K and V pools [Lgqa, N, ps, Hkv*dh]: ``pool_v``) AND the
     slot-state arrays (``states``: name -> [Lk, slots, ..]) as the loop's
     in-place carry. Layer l runs its kind's attention half (``kda``:
-    ``_kda_layer`` against the state; ``mla``: ``_mla_paged_step`` against
-    the pool; ``gqa``: ``_paged_layer_step``, the paged grouped-query step
-    of every K/V stack, without rotation and with the channel gate) and
-    its FFN kind (dense SwiGLU below ``first_dense``, experts after), each
-    on the planes of ITS group at the layer's index within the group. The
+    ``_kda_layer`` against the state; ``mamba2``: ``_mamba_layer`` against
+    its own; ``mla``: ``_mla_paged_step`` against the pool; ``gqa``:
+    ``_paged_layer_step``, the paged grouped-query step of every K/V stack,
+    without rotation and with the channel gate) and its FFN kind (dense
+    SwiGLU below ``first_dense``, experts after), each on the planes of
+    ITS group at the layer's index within the group; a position that is
+    HALF a block (``Block.layer_parts``: a mixer alone, a feed-forward
+    alone) runs that half and its one norm. The
     periods that hold a dense layer are unrolled (a prologue: their
     positions differ from the later periods'), the whole periods after
     them run under ONE ``lax.scan`` with the period's positions unrolled
@@ -1295,43 +1401,58 @@ def _scan_kind_layers(blk, params, h, pool, table, page_id, page_row, pos0,
     (``_kda_layer``), carried like the states. -> (h, pool, pool_v, states,
     snaps, (counts [Lexp, E], router prob mean [Lexp, E]))."""
     b, t, _ = h.shape
-    kinds = blk.attn_kinds
+    kinds = blk.layer_parts     # (mixer or None, has an FFN) a position
     P = len(kinds)
-    n_layers = params["ln1_s"].shape[0]
+    # norm 1 leads with the layers that have a mixer
+    n_layers = params["ln1_s"].shape[0] * P // sum(
+        1 for mixer, _ in kinds if mixer)
     index = blk.group_index(n_layers)
     group_of = {key: Block.plane_group(key) for key in params}
     whole = {k: params[k] for k in _RESIDENT_PLANES if k in params}
-    if "mla" in kinds:
+    # (``halves`` = (dense, ffn): what the layer's feed-forward half is)
+    if "mla" in blk.mixers:
         paged = _mla_paged_step(
             blk, b, t, lambda p, hh: _mla_latent(blk, p, hh, pos0), mask,
-            lambda p, hh, ctx, dense: _attn_out_ffn(
-                blk, p, hh, _head_gate(blk, p, hh, ctx), dense=dense))
+            lambda p, hh, ctx, halves: _attn_out_ffn(
+                blk, p, hh, _head_gate(blk, p, hh, ctx), dense=halves[0],
+                ffn=halves[1]))
     else:
         paged = _paged_layer_step(
             b, t, pool.shape[2],
             lambda p, hh: _attn_proj(blk, {**p, "qkv_w": p["gqa_qkv_w"]},
                                      hh, pos0=pos0, rope=False),
-            mask, lambda p, hh, ctx, dense: _attn_out_ffn(
+            mask, lambda p, hh, ctx, halves: _attn_out_ffn(
                 blk, p, hh, _channel_gate(blk, p, hh, ctx),
-                out_key="gqa_out_w", dense=dense))
-    paged_kind = "mla" if "mla" in kinds else "gqa"
+                out_key="gqa_out_w", dense=halves[0], ffn=halves[1]))
+    paged_kind = "mla" if "mla" in blk.mixers else "gqa"
     ix = (page_id.reshape(b, t), page_row.reshape(b, t))
 
     def layer(carry, p, kind, dense, at):
         """One layer; ``at``: group -> the layer's index in the group."""
         hh, pool, pool_v, st, sn = carry
-        if whole and not dense:
+        mixer, ffn = kind
+        if whole and ffn and not dense:
             p = {**p, **whole, "layer": at["experts"]}
-        if kind == "kda":
+        if mixer == "kda":
             ctx, s_new, c_new, sn = _kda_layer(
                 blk, p, hh, st["KdaState"], st["KdaConv"], at["kda"], rows,
                 sn)
             st = {**st, "KdaState": s_new, "KdaConv": c_new}
             hh, stats = _attn_out_ffn(blk, p, hh, ctx, out_key="kda_out_w",
-                                      dense=dense)
+                                      dense=dense, ffn=ffn)
+        elif mixer == "mamba2":
+            ctx, s_new, c_new = _mamba_layer(
+                blk, p, hh, st["MambaState"], st["MambaConv"], at["mamba2"],
+                rows)
+            st = {**st, "MambaState": s_new, "MambaConv": c_new}
+            hh, stats = _attn_out_ffn(blk, p, hh, ctx, out_key="mamba_out_w",
+                                      dense=dense, ffn=ffn)
+        elif mixer is None:             # the feed-forward alone
+            hh, stats = _attn_out_ffn(blk, p, hh, None, dense=dense,
+                                      mixer=False)
         else:
             hh, pool, pool_v, stats = paged(hh, pool, pool_v, at[paged_kind],
-                                            p, dense, table, *ix)
+                                            p, (dense, ffn), table, *ix)
         return (hh, pool, pool_v, st, sn), stats
 
     def planes(l):      # layer l's own planes (python l)
@@ -1371,7 +1492,8 @@ def _scan_kind_layers(blk, params, h, pool, table, page_id, page_row, pos0,
                 c, y = layer(c, p_j, kinds[j], False,
                              {g: None if at_j[g] is None
                               else at_j[g] + n * per[g] for g in index})
-                ys.append(y)
+                if y is not None:       # (a mixer alone reports nothing)
+                    ys.append(y)
             return c, tuple(jnp.stack(a) for a in zip(*ys))
 
         carry, ys = jax.lax.scan(period, carry, (
@@ -1469,7 +1591,8 @@ def _paged_outs(blk, stats, win, **outs):
 
 def _state_ins(blk, ins):
     """The slot-state arrays the spec lists (``Block.slot_state``), by
-    slot name: "KdaState" "KdaConv"; {} for a spec without any."""
+    slot name: "KdaState" "KdaConv" | "MambaState" "MambaConv"; {} for a
+    spec without any."""
     return {name: single(ins, name) for name, _, _ in blk.slot_state(0)}
 
 
@@ -1580,7 +1703,10 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     # "KdaALog" "KdaBetaW" "KdaGateW" "KdaNormS" "KdaOutW" "DenseGateW"
     # "DenseUpW" "DenseDownW" "KdaADownW" "KdaAUpW" "KdaGateDownW"
     # "KdaGateUpW" "KdaGateB" "GqaQkvW" "GqaGateW" "GqaOutW"
+    # "MambaInW" "MambaConvW" "MambaConvB" "MambaDtBias" "MambaALog" "MambaD"
+    # "MambaNormS" "MambaOutW" "MoeLatentDownW" "MoeLatentUpW"
     # and its slot-state arrays, via ``_state_ins``: "KdaState" "KdaConv"
+    # "MambaState" "MambaConv"
     # (a prefill row's slot: "StateSlot")
     params = _stack_params(blk, ins)
     b, Tc = chunk.shape
@@ -1722,7 +1848,10 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     # "KdaALog" "KdaBetaW" "KdaGateW" "KdaNormS" "KdaOutW" "DenseGateW"
     # "DenseUpW" "DenseDownW" "KdaADownW" "KdaAUpW" "KdaGateDownW"
     # "KdaGateUpW" "KdaGateB" "GqaQkvW" "GqaGateW" "GqaOutW"
+    # "MambaInW" "MambaConvW" "MambaConvB" "MambaDtBias" "MambaALog" "MambaD"
+    # "MambaNormS" "MambaOutW" "MoeLatentDownW" "MoeLatentUpW"
     # and its slot-state arrays, via ``_state_ins``: "KdaState" "KdaConv"
+    # "MambaState" "MambaConv"
     # (a prefill row's slot: "StateSlot")
     params = _stack_params(blk, ins)
     S = tok.shape[0]

@@ -24,6 +24,7 @@ arrived.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 import weakref
@@ -99,10 +100,10 @@ def spec_from_program_dict(pd: dict,
         # the router's width is the model's expert count; the stacks hold
         # the experts this program has (``experts_held``)
         sizes["num_experts"] = var["lm_stack.stack_router_w"]["shape"][2]
-        sizes["d_expert"] = var["lm_stack.stack_moe_gate_w"]["shape"][3]
+        sizes["d_expert"] = var["lm_stack.stack_moe_up_w"]["shape"][3]
         if blk.shared_expert:
             sizes["d_shared"] = \
-                var["lm_stack.stack_shared_gate_w"]["shape"][2]
+                var["lm_stack.stack_shared_up_w"]["shape"][2]
     else:
         sizes["d_ff"] = var["lm_stack.stack_ff_w1"]["shape"][2]
     if blk.first_dense:                 # the leading dense layers' width
@@ -372,7 +373,11 @@ class GenerationEngine:
     (read and written in place; a prefill row names its slot in the
     ``serving.state_slot`` column of the call's plane), and counts them
     with the cache (``mem/state_bytes_per_slot``, ``mem/state_bytes_live``,
-    ``cache_stats()``). A slot IS its state: admission allocates nothing,
+    ``cache_stats()``; the per-call counters carry the recurrence's name:
+    ``kda_layer_calls`` / ``kda_state_bytes``, or for ``mamba2`` layers
+    ``mamba_layer_calls`` / ``mamba_state_bytes`` and ``mamba_chunks``, the
+    SSD blocks a prefill call scans, which is also what its span
+    ``serving/mamba_prefill_unit`` says). A slot IS its state: admission allocates nothing,
     and a row whose first chunk starts at position 0 reads zeros whatever
     the slot's last tenant left. The state is held at the slot's LAST token
     only, so everything that enters a sequence elsewhere than position 0
@@ -571,9 +576,17 @@ class GenerationEngine:
             in self._state + self._snapshots) + tuple(
             self._operands.values())
         #: layers that carry state: what a call counts as its
-        #: ``kda_layer_calls``
+        #: ``kda_layer_calls`` (``mamba_layer_calls``: the counters of a
+        #: state are named by its recurrence)
         self._state_layers = max((shape[0] for _, _, shape, _
                                   in self._state), default=0)
+        self._state_kind = ("mamba" if "mamba2" in spec.block.mixers
+                            else "kda")
+        if n_snapshots and self._state_kind == "mamba":
+            raise BlockNotSupportedError(
+                "n_snapshots: the snapshot rows are the KDA state's; a "
+                "'mamba2' layer's state is held at the slot's last token "
+                "alone (no prefix hit for it yet)")
         if src is not None:
             self._require_one_table("share_cache_with= (the slot handoff "
                                     "between engines)")
@@ -878,13 +891,31 @@ class GenerationEngine:
         if not self._state:
             return
         cols["serving.state_slot"][:len(slots_of_rows)] = slots_of_rows
-        self.metrics.inc("kda_layer_calls", self._state_layers)
+        self.metrics.inc(f"{self._state_kind}_layer_calls",
+                         self._state_layers)
+        if self._state_kind == "mamba":
+            self.metrics.inc("mamba_chunks",
+                             self._ssd_blocks(*cols["serving.chunk"].shape))
         if self._snapshots:
             for row, pair in enumerate(snaps):
                 for col, v in zip(("serving.snap_from", "serving.snap_take"),
                                   pair):
                     if v is not None:
                         cols[col][row] = v
+
+    def _ssd_blocks(self, rows: int, tc: int) -> int:
+        """The blocks of the chunked (SSD) scan a prefill call of ``rows``
+        x ``tc`` tokens runs over its ``mamba2`` layers."""
+        return self._state_layers * rows * -(-tc // self.spec.mamba_chunk)
+
+    def _mamba_unit_span(self, rows: int, tc: int):
+        """The span ``serving/mamba_prefill_unit`` round a prefill call of
+        a spec with ``mamba2`` layers (``chunks``: what ``mamba_chunks``
+        counts of it); nothing for any other spec."""
+        if not self._state or self._state_kind != "mamba":
+            return contextlib.nullcontext()
+        return trace.span("serving/mamba_prefill_unit", rows=rows, tokens=tc,
+                          chunks=self._ssd_blocks(rows, tc))
 
     def _lm_ins(self, helper):
         """The ops' weight slots; a weight with an AMP operand copy is
@@ -1024,6 +1055,9 @@ class GenerationEngine:
         self.metrics.inc("moe_layer_calls", int(counts.shape[0]))
         self.metrics.inc("moe_kernel_layer_calls", int(
             counts.shape[0]) if self._experts_on_kernel(rows) else 0)
+        if self.spec.expert_latent:
+            # token rows through the latent down- and up-projection
+            self.metrics.inc("moe_latent_rows", rows * int(counts.shape[0]))
         self.metrics.inc("moe_dropped_tokens",
                          rows * self.spec.experts_per_tok
                          * int(counts.shape[0]) - took)
@@ -1037,8 +1071,8 @@ class GenerationEngine:
 
             spec = self.spec
             self._expert_kernel[rows] = experts_on_kernel(
-                rows * spec.experts_per_tok, spec.d_model, spec.d_expert,
-                layer=0)
+                rows * spec.experts_per_tok,
+                spec.expert_latent or spec.d_model, spec.d_expert, layer=0)
         return self._expert_kernel[rows]
 
     def _beam_out_vars(self, helper, rows: int, prefix: str):
@@ -1976,7 +2010,8 @@ class GenerationEngine:
         prog, outs = self._prefill_prog(tc)
         t0 = time.perf_counter()
         with trace.span("serving/prefill_group", rows=len(group),
-                        bucket=bucket, tokens=tc):
+                        bucket=bucket, tokens=tc), \
+                self._mamba_unit_span(bucket, tc):
             res = self.executor.run(prog, feed=feed,
                                     fetch_list=self._fetches(outs),
                                     scope=self.scope)
@@ -2183,7 +2218,8 @@ class GenerationEngine:
         prog, outs = self._prefill_prog(tc)
         t0 = time.perf_counter()
         with trace.span("serving/prefill_chunk", slot=slot,
-                        offset=start0, tokens=k):
+                        offset=start0, tokens=k), \
+                self._mamba_unit_span(bucket, tc):
             res = self.executor.run(prog, feed=feed,
                                     fetch_list=self._fetches(outs),
                                     scope=self.scope)
@@ -2272,8 +2308,9 @@ class GenerationEngine:
         # the state a tick's recurrent layers read and write: every row of
         # the static batch, in and out (a vacant row's tiles move too)
         if self._state:
-            self.metrics.inc("kda_layer_calls", self._state_layers)
-            self.metrics.inc("kda_state_bytes",
+            self.metrics.inc(f"{self._state_kind}_layer_calls",
+                             self._state_layers)
+            self.metrics.inc(f"{self._state_kind}_state_bytes",
                              2 * self.slots * self.spec.state_bytes_per_slot)
             # which cache sets the batch: the live slots' state against
             # the pages they hold, tick by tick (the gauges' summed twins)

@@ -71,8 +71,12 @@ MODULES = [
      "The stacked LM's model spec: Block (what a block computes), LMSpec "
      "(plus sizes), RopeScaling; attention mha | mla, a shared expert, a "
      "held share of the router's experts; a layer_pattern over attention "
-     "kinds (kda | mla | gqa: planes by kind, kda beside ONE kind that "
-     "caches tokens; gqa = grouped-query K/V pages without positions, "
+     "kinds (kda | mla | gqa | mamba2, each a whole block, or HALF a "
+     "block: '<kind>+none' the mixer alone, 'none+ffn' the feed-forward "
+     "alone; planes by kind, recurrent kinds beside ONE kind that "
+     "caches tokens; mamba2 = Mamba-2 (SSD) with mamba_heads / "
+     "mamba_head_dim / mamba_groups / mamba_state / mamba_conv / "
+     "mamba_chunk; gqa = grouped-query K/V pages without positions, "
      "attn_gate head | channel; kda_decay bounded | softplus, "
      "kda_neg_eigval, kda_proj_rank; slot_state = what a serving slot "
      "holds beside its pages, which GenerationEngine(snapshot_stride=, "
@@ -80,7 +84,10 @@ MODULES = [
      "require_stateless still refuses beams, resume, the slot handoff and "
      "share_cache_with=), first_dense leading dense layers (of a stack "
      "held by attention kind, or of full / window K/V layers: "
-     "plane_layers), the router's score / bias / groups; draft_block = a "
+     "plane_layers), the router's score / bias / groups, expert_act "
+     "silu | relu | relu2 (relu2: UNGATED experts, no gate plane), "
+     "expert_latent = routed experts in a latent behind one shared down- "
+     "and up-projection; draft_block = a "
      "drafting (multi-token-prediction) block behind the stack "
      "(draft_planes / draft_spec / pool_layers: its K/V is one more "
      "full-attention layer of the pools; GenerationEngine then runs "
@@ -90,7 +97,8 @@ MODULES = [
     ("paddle_tpu.ops.moe_ops",
      "The expert layer: moe_topk (dropless top-k; shared=, held=, "
      "routed_scale=; score= softmax | sigmoid, bias=, n_group=, "
-     "topk_group=) and the Switch op"),
+     "topk_group=; gate_w None = ungated experts, act= relu2, latent= "
+     "the latent's down- and up-projection) and the Switch op"),
     ("paddle_tpu.kernels.flash_attention", "Pallas flash attention"),
     ("paddle_tpu.kernels.paged_attention",
      "Pallas paged attention: walks the block table (one query position "
@@ -106,6 +114,10 @@ MODULES = [
      "Kimi Delta Attention: the gated delta rule with a per-channel decay "
      "token by token, chunked (prefill), and the kda_decode_step Pallas "
      "kernel over the whole slot-state array"),
+    ("paddle_tpu.kernels.mamba2",
+     "Mamba-2 (state-space duality, one scalar decay a head): the "
+     "recurrence token by token, the chunked SSD form (prefill), and the "
+     "mamba2_decode_step Pallas kernel over the whole slot-state array"),
     ("paddle_tpu.kernels.sampling",
      "Per-request sampling plane: each decode row's own temperature / "
      "top-k / top-p / seed; cut-offs by a counted search, run only when "
