@@ -65,9 +65,41 @@ reads only the pages a row HOLDS, straight from the whole pool:
   it would multiply the MXU work by Hkv). Padding queries and padding
   rows attend nothing and leave zeros.
 
+* a latent block's prefill chunk (``cache_v=None`` there too, the call name
+  ``MLA_PREFILL_KERNEL``): the chunk-shaped body over ONE pool. The head
+  loop steps over groups of QUERY heads (as many as make a score tile of
+  ``_LATENT_SCORE_ROWS`` rows) that all meet the SAME [keys, W] tile: scores
+  Q [rows, W] @ tile^T, context P @ the tile's lane-aligned first r
+  columns (the latent: a token's value), so the result is [b, Tc, H*r] and
+  the second dot does r / W of the whole tile's work. The scale is handed
+  in (the caller's queries carry theirs). By my chip runs (PR 55,
+  ``tools/mla_prefill_sweep.py``; 32 heads, bf16 pages of 256, one row; ms
+  a call, share of the MXU's 197 TFLOP/s by 2 x heads x unmasked (query,
+  key) pairs x (W + r)):
+
+  ====================================  ========  ============  =====
+  chunk behind keys (W, r, table)       gathered  walk          MXU
+  ====================================  ========  ============  =====
+  256 behind 16896 (384, 256, 20480)    2.82      1.17          77.5%
+  64 behind 16896                       0.77      0.34          66.2%
+  256 behind 0                          2.78      0.13          5.4%
+  256 behind 12032 (640, 512, 12288)    1.90      1.39          83.6%
+  256 behind 3840                       1.88      0.50          75.7%
+  256 behind 256                        1.89      0.18          20.2%
+  64 behind 448                         0.39      0.08          13.7%
+  ====================================  ========  ============  =====
+
+  The gathered form costs the table's width whatever the row holds; the
+  walk the keys walked: it wins at every length, so the rule has no length
+  in it. Tiles (query rows a grid step : rows of a score tile : keys a
+  step) 4096:512:1024 as above; 8192:512:1024, 2048:512:1024 and
+  4096:1024:1024 read within 1% of it at 16896 keys (1.165-1.186), 256
+  score rows +6%, 2048 keys +8%, 512 keys +9% (but 0.10-0.12 where the
+  context is under 512 keys: a block's tail is multiplied and masked).
+
 The jnp gather + ``reference_attention`` stays the semantic ground truth
 and the path of every other shape (the CPU, unaligned widths, a head
-narrower than the lanes under a chunk, the latent block's chunk): the two
+narrower than the lanes under a chunk): the two
 positions of a verify tick stay on the page walk
 (``paged_attention_verify``). ``supported`` (a tick) and
 ``chunk_supported`` (a prefill chunk) are the whole dispatch rule, read
@@ -88,6 +120,9 @@ MLA_KERNEL = "paged_mla_decode"
 #: a prefill chunk's call: a name of its own, so that no reader of the
 #: decode calls (a TICK's page read) counts it
 PREFILL_KERNEL = "paged_attention_prefill"
+#: a latent block's prefill chunk (one pool as key and value): its own name,
+#: so that no reader of the K/V chunk walk or of the latent TICK counts it
+MLA_PREFILL_KERNEL = "paged_mla_prefill"
 
 #: query positions a row of a verify tick may bring (``supported``)
 VERIFY_POSITIONS = 2
@@ -132,6 +167,10 @@ _CHUNK_ROWS = 4096
 #: accumulator) is as many vector ops as its scores at 128 keys
 _CHUNK_KEYS = 1024
 _CHUNK_BLOCK_BYTES = 2 * 2 ** 20
+#: the latent chunk walk (one pool, every query head over the SAME tile):
+#: query rows (heads x queries) of one score tile, the K/V form's order (its
+#: G * tq: 512 at 64 / 8 heads; the module's table: my chip runs, PR 55)
+_LATENT_SCORE_ROWS = 512
 #: the chunk walk's VMEM: the tile's queries and context (double buffered by
 #: the pipeline), two K and two V blocks, its float32 state and a few
 #: [rows of a head, keys] score temporaries come to 20-30 MB at float32
@@ -151,20 +190,26 @@ def _query_tile(t: int, heads: int, dtype):
     return max([d for d in fits if heads * d <= _CHUNK_ROWS] or fits[:1])
 
 
-def chunk_supported(q_shape, pool, mask) -> bool:
+def chunk_supported(q_shape, pool, mask, value_width=None) -> bool:
     """Whether the chunk walk (``paged_attention_prefill``) can take this
     call: queries ``q_shape`` = [b, H, t, dh] of a prefill chunk (more
     positions than a verify tick's, a mask of the ``CHUNK_MASK`` kind), a
     TPU backend, whole groups of query heads over the cached heads, a head
     of whole lane rows (the body slices the page tile by head), a page and
-    a chunk of whole sublane tiles. Shapes, a dtype, the mask's keys and
-    the backend: nothing names a model."""
+    a chunk of whole sublane tiles. ``value_width`` (a latent pool: no V
+    pool, the row's first ``value_width`` columns the value): queries as
+    wide as the pool's row, and row and value of whole lane rows. Shapes,
+    a dtype, the mask's keys and the backend: nothing names a model."""
     _, heads, t, d_head = q_shape
     ps, width = pool.shape[2:]
+    if value_width is None:
+        rows_fit = width % d_head == 0 and (heads * d_head) % width == 0
+    else:
+        rows_fit = d_head == width and 0 < value_width <= width \
+            and value_width % 128 == 0
     return (t > VERIFY_POSITIONS and set(mask) == CHUNK_MASK
             and jax.default_backend() == "tpu"
-            and d_head % 128 == 0 and width % d_head == 0
-            and (heads * d_head) % width == 0
+            and d_head % 128 == 0 and rows_fit
             and ps % _sublane_tile(pool.dtype) == 0
             and _query_tile(t, heads, pool.dtype) is not None)
 
@@ -469,18 +514,26 @@ def chunk_pages_in_reach(start, length, ps, window=None, xp=jnp):
 
 
 def _prefill_kernel(layer_ref, table_ref, start_ref, len_ref, q_ref, k_hbm,
-                    v_hbm, o_ref, kbuf, vbuf, sems, m_ref, l_ref, acc_ref, *,
-                    d_head, pmax, group, window):
+                    *rest, d_head, pmax, group, window, sm_scale=None,
+                    shared_kv=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if shared_kv:       # one pool: the key tile's first columns are the value
+        o_ref, kbuf, sems, m_ref, l_ref, acc_ref = rest
+        v_hbm, vbuf = None, kbuf
+    else:
+        v_hbm, o_ref, kbuf, vbuf, sems, m_ref, l_ref, acc_ref = rest
     s, qt = pl.program_id(0), pl.program_id(1)
     ps = k_hbm.shape[2]
-    kv_heads, rows, _ = acc_ref.shape           # rows = group * tq
+    # the head loop's steps: the cached heads, ``group`` query heads over
+    # each; under one pool ``group`` query heads a step over the SAME tile
+    steps, rows, d_value = acc_ref.shape        # rows = group * tq
     tq = rows // group
     keys = kbuf.shape[1]                        # a block: whole pages
     per = keys // ps
-    sm_scale = 1.0 / math.sqrt(d_head)
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d_head)
     layer = jnp.clip(layer_ref[0], 0, k_hbm.shape[0] - 1)
     start, length = start_ref[s], len_ref[s]
     # this tile's queries sit at chunk offsets q0 .. q0 + tq - 1, of which
@@ -498,8 +551,11 @@ def _prefill_kernel(layer_ref, table_ref, start_ref, len_ref, q_ref, k_hbm,
         i = jnp.minimum(first + j * per + c, end - 1)
         page = jnp.clip(table_ref[s * pmax + i], 0, k_hbm.shape[1] - 1)
         at = pl.ds(pl.multiple_of(c * ps, ps), ps)
-        return (pltpu.make_async_copy(k_hbm.at[layer, page],
-                                      kbuf.at[buf, at], sems.at[0, buf, c]),
+        k_copy = pltpu.make_async_copy(k_hbm.at[layer, page],
+                                       kbuf.at[buf, at], sems.at[0, buf, c])
+        if shared_kv:
+            return (k_copy,)
+        return (k_copy,
                 pltpu.make_async_copy(v_hbm.at[layer, page],
                                       vbuf.at[buf, at], sems.at[1, buf, c]))
 
@@ -542,13 +598,19 @@ def _prefill_kernel(layer_ref, table_ref, start_ref, len_ref, q_ref, k_hbm,
             seen = seen & (q_pos - key < window)
 
         def head(h, _):
-            cols = pl.ds(pl.multiple_of(h * d_head, d_head), d_head)
+            if shared_kv:   # every head: the whole tile, its first columns
+                cols, v_cols = slice(None), slice(0, d_value)
+            else:
+                cols = v_cols = pl.ds(pl.multiple_of(h * d_head, d_head),
+                                      d_head)
             q = q_ref[0, pl.ds(h * group, group)].reshape(rows, d_head)
             sc = jax.lax.dot_general(
                 q, kbuf[buf, :, cols],
                 dimension_numbers=(((1,), (1,)), ((), ())),
                 precision=precision,
-                preferred_element_type=jnp.float32) * sm_scale  # [rows, keys]
+                preferred_element_type=jnp.float32)             # [rows, keys]
+            if sm_scale != 1.0:
+                sc = sc * sm_scale
             sc = jnp.where(seen, sc, -jnp.inf)
             m = m_ref[h]
             m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
@@ -558,33 +620,34 @@ def _prefill_kernel(layer_ref, table_ref, start_ref, len_ref, q_ref, k_hbm,
             alpha = jnp.exp(m - m_safe)
             l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=-1, keepdims=True)
             acc_ref[h] = alpha * acc_ref[h] + jax.lax.dot_general(
-                p.astype(vbuf.dtype), vbuf[buf, :, cols],
+                p.astype(vbuf.dtype), vbuf[buf, :, v_cols],
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 precision=precision,
-                preferred_element_type=jnp.float32)             # [rows, dh]
+                preferred_element_type=jnp.float32)             # [rows, dv]
             m_ref[h] = m_new
             return 0
 
         # a LOOP over the cached heads, not an unrolled body: unrolled it is
         # a third faster at 12k keys (my chip run, PR 50) and costs every
         # start-up ~0.3 s a call site in tracing and lowering
-        jax.lax.fori_loop(0, kv_heads, head, 0)
+        jax.lax.fori_loop(0, steps, head, 0)
         return 0
 
     jax.lax.fori_loop(0, n_blocks, block, 0)
     # normalised, a token's heads side by side; a query without a key has
     # accumulated nothing and stays zero
-    for h in range(kv_heads):
+    for h in range(steps):
         l = l_ref[h]
         ctx = acc_ref[h] / jnp.where(l > 0, l, 1.0)
         for g in range(group):
             n = h * group + g
-            o_ref[0, :, n * d_head:(n + 1) * d_head] = ctx[
+            o_ref[0, :, n * d_value:(n + 1) * d_value] = ctx[
                 g * tq:(g + 1) * tq].astype(o_ref.dtype)
 
 
 def paged_attention_prefill(q, cache_k, cache_v, layer, table, start,
-                            lengths, interpret=False, window=None):
+                            lengths, interpret=False, window=None,
+                            sm_scale=None, value_width=None):
     """Attention of a prefill CHUNK over the pages each row holds.
 
     q [b, H, Tc, dh] (cast to the pools' dtype): query i of row s sits at
@@ -596,15 +659,26 @@ def paged_attention_prefill(q, cache_k, cache_v, layer, table, start,
     in the pools' dtype, a token's heads side by side; zeros for a padding
     query. The walk reads pages ``chunk_pages_in_reach`` of each row (by
     query tile: a tile stops at ITS last real key), never a page past the
-    chunk's last real key."""
+    chunk's last real key.
+
+    ``cache_v=None`` (latent attention, the call ``MLA_PREFILL_KERNEL``):
+    q [b, H, Tc, W] against the ONE pool [L, N, ps, W], every head reading
+    the row's whole width as key and its first ``value_width`` columns as
+    value -> [b, Tc, H*value_width]; ``sm_scale`` replaces 1/sqrt(dh)."""
     if q.ndim != 4:
         raise ValueError(f"q must be [b, H, Tc, dh], got {q.shape}")
     heads, t, d_head = q.shape[1:]
     width = cache_k.shape[3]
-    if (heads * d_head) % width or width % d_head \
-            or cache_v.shape != cache_k.shape:
+    if cache_v is None:
+        bad = d_head != width or not 0 < (value_width or 0) <= width
+    else:
+        bad = ((heads * d_head) % width or width % d_head
+               or cache_v.shape != cache_k.shape or value_width is not None)
+    if bad:
         raise ValueError(f"q {q.shape} does not match the pools "
-                         f"{cache_k.shape} / {cache_v.shape}")
+                         f"{cache_k.shape} / "
+                         f"{None if cache_v is None else cache_v.shape}"
+                         f" (value_width {value_width})")
     if _query_tile(t, heads, cache_k.dtype) is None:
         raise ValueError(f"a chunk of {t} queries is not whole sublane "
                          f"tiles of {jnp.dtype(cache_k.dtype).name}")
@@ -618,12 +692,13 @@ def paged_attention_prefill(q, cache_k, cache_v, layer, table, start,
         jnp.reshape(layer, (1,)).astype(jnp.int32),
         table.reshape(-1).astype(jnp.int32), start.astype(jnp.int32),
         lengths.astype(jnp.int32), pmax=table.shape[1], interpret=interpret,
-        window=window)
+        window=window, sm_scale=sm_scale, value_width=value_width)
 
 
-@functools.partial(jax.jit, static_argnames=("pmax", "interpret", "window"))
+@functools.partial(jax.jit, static_argnames=(
+    "pmax", "interpret", "window", "sm_scale", "value_width"))
 def _chunk_walk(q, cache_k, cache_v, layer, table, start, lengths, *, pmax,
-                interpret, window):
+                interpret, window, sm_scale=None, value_width=None):
     """``paged_attention_prefill``'s call, on checked operands: layer [1],
     table [b * pmax] flattened, start / lengths [b], all int32."""
     from jax.experimental import pallas as pl
@@ -633,41 +708,50 @@ def _chunk_walk(q, cache_k, cache_v, layer, table, start, lengths, *, pmax,
     ps, width = cache_k.shape[2:]
     dtype = cache_k.dtype
     tq = _query_tile(t, heads, dtype)
-    kv_heads = width // d_head
-    group = heads // kv_heads
+    shared_kv = cache_v is None
+    if shared_kv:
+        # one cached "head" of the row's whole width under ALL the query
+        # heads: the head loop steps over groups of query heads instead, as
+        # many as make a score tile of ``_LATENT_SCORE_ROWS`` rows
+        group = max(g for g in range(1, heads + 1) if heads % g == 0
+                    and (g == 1 or g * tq <= _LATENT_SCORE_ROWS))
+        steps, d_value = heads // group, value_width
+    else:
+        steps, d_value = width // d_head, d_head
+        group = heads // steps
     keys = min(_CHUNK_KEYS,
                _CHUNK_BLOCK_BYTES // (width * jnp.dtype(dtype).itemsize))
     per = max(keys // ps, 1)
+    pools = (cache_k,) if shared_kv else (cache_k, cache_v)
     kernel = functools.partial(_prefill_kernel, d_head=d_head, pmax=pmax,
-                               group=group, window=window)
+                               group=group, window=window, sm_scale=sm_scale,
+                               shared_kv=shared_kv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,  # layer, the flattened table, start, lengths
         grid=(b, t // tq),
         in_specs=[
             pl.BlockSpec((1, heads, tq, d_head),
                          lambda s, i, *_: (s, 0, i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, tq, heads * d_head),
+        ] + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
+        out_specs=pl.BlockSpec((1, tq, heads * d_value),
                                lambda s, i, *_: (s, i, 0)),
         scratch_shapes=[
-            # K and V blocks of ``per`` pages, two in flight
-            pltpu.VMEM((2, per * ps, width), dtype),
-            pltpu.VMEM((2, per * ps, width), dtype),
+            # K (and V: not under one pool) blocks of ``per`` pages, two in
+            # flight
+            pltpu.VMEM((2, per * ps, width), dtype) for _ in pools] + [
             pltpu.SemaphoreType.DMA((2, 2, per)),
-            pltpu.VMEM((kv_heads, group * tq, 1), jnp.float32),  # running max
-            pltpu.VMEM((kv_heads, group * tq, 1), jnp.float32),  # denominator
-            pltpu.VMEM((kv_heads, group * tq, d_head), jnp.float32),  # P @ V
+            pltpu.VMEM((steps, group * tq, 1), jnp.float32),  # running max
+            pltpu.VMEM((steps, group * tq, 1), jnp.float32),  # denominator
+            pltpu.VMEM((steps, group * tq, d_value), jnp.float32),  # P @ V
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, t, heads * d_head), dtype),
+        out_shape=jax.ShapeDtypeStruct((b, t, heads * d_value), dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_CHUNK_VMEM),
         interpret=interpret,
-        name=PREFILL_KERNEL,
-    )(layer, table, start, lengths, q, cache_k, cache_v)
+        name=MLA_PREFILL_KERNEL if shared_kv else PREFILL_KERNEL,
+    )(layer, table, start, lengths, q, *pools)

@@ -909,7 +909,7 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
     chunk, ``paged_attention.chunk_supported`` and ``chunk_mask``), or
     gathered at (l, table) and attended with ``reference_attention`` under
     the same mask (everything else — the CPU, a head narrower than the
-    lanes under a chunk, the latent block's chunk: the ground truth);
+    lanes under a chunk: the ground truth);
     ``finish(layer_p, h, ctx, x_l)``
     -> (h, stats) closes the block, x_l being layer l's slice of the
     optional scanned-over ``xs`` and stats what the layer reports (None,
@@ -1041,8 +1041,11 @@ def _mla_paged_step(blk, b, t, project, mask, finish):
     expands the cache: q~[h] = q_nope[h] W_UK[h]^T scores the latent
     directly, every head reads ONE shared key row whose first r columns
     are also the value, and o[h] = (sum_j p_j c_kv[j]) W_UV[h]. The softmax
-    scale rides the queries (sm_scale 1 in the attention). A decode tick
-    on a chip walks the pages in the kernel; a prefill chunk (and the CPU)
+    scale rides the queries (sm_scale 1 in the attention). On a chip a
+    decode tick and a prefill chunk each walk the pages in a kernel
+    (``paged_mla_decode``; ``paged_mla_prefill`` where
+    ``paged_attention.chunk_supported`` takes the operands: row and latent
+    of whole lane rows); everything else (the CPU, unaligned widths)
     gathers them and attends absorbed too: rebuilding every head's keys
     and values from the gathered rows was 25.5 ms a 256-token unit over a
     16k context where this is 20.7 (my chip run, PR 35, PERF.md section
@@ -1069,6 +1072,12 @@ def _mla_paged_step(blk, b, t, project, mask, finish):
                 q_lat[:, :, 0], ck, None, l, tbl, mask["lengths"],
                 sm_scale=1.0, name=paged_attention.MLA_KERNEL)
             o_lat = o_lat.reshape(b, -1, 1, W)
+        elif paged_attention.chunk_supported(q_lat.shape, ck, mask, r):
+            # a prefill chunk: the pages its queries reach, never the table
+            o_lat = paged_attention.paged_attention_prefill(
+                q_lat, ck, None, l, tbl, mask["q_pos0"], mask["q_len"],
+                sm_scale=1.0, value_width=r)
+            o_lat = o_lat.reshape(b, t, -1, r).transpose(0, 2, 1, 3)
         else:
             lat = ck[l, tbl].reshape(b, 1, tbl.shape[1] * ck.shape[2], W)
             o_lat = reference_attention(q_lat.astype(ck.dtype), lat,
@@ -1106,7 +1115,7 @@ def _paged_layer_step(b, t, ps, project, mask, finish, mla=None):
     step, a verify tick, a prefill chunk: all on a chip only), else the
     table gathered (a window layer: its span) under
     ``reference_attention``. ``mla``: a latent block (``_mla_paged_step``:
-    one pool, cv None; its chunk gathers)."""
+    one pool, cv None; the same rule over its one pool)."""
     from ..kernels import paged_attention
     from ..kernels.flash_attention import reference_attention
 
@@ -1648,9 +1657,10 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     (``_scan_paged_layers``): a chunk writes b*Tc token rows per layer
     and pool and, on a chip, reads the pages each row's queries REACH in
     one kernel a layer (``paged_attention_prefill``: to the page of the
-    chunk's last real key, on a window layer from the window's page);
-    elsewhere (the CPU, a head narrower than the lanes, a latent pool) it
-    gathers b table-width contexts. Nothing it moves is proportional to
+    chunk's last real key, on a window layer from the window's page; a
+    latent pool: the same walk over its one pool, ``paged_mla_prefill``);
+    elsewhere (the CPU, a head narrower than the lanes) it gathers b
+    table-width contexts. Nothing it moves is proportional to
     N, and no page outside the written (layer, page, row) cells changes.
 
     Queries attend the row's WHOLE context block-causally (chunk token at

@@ -1304,7 +1304,8 @@ class GenerationEngine:
         """Whether the prefill programs of chunk width ``tc`` attend on the
         chunk walk: the ops' own predicate
         (``kernels/paged_attention.chunk_supported``) over the shapes their
-        K/V layers see. A latent pool's chunk gathers."""
+        caching layers see (K/V pools: a head's queries; a latent pool:
+        queries as wide as its row, the latent the value)."""
         if tc not in self._chunk_walk:
             import jax
 
@@ -1312,12 +1313,16 @@ class GenerationEngine:
             from ..kernels import paged_attention
 
             spec = self.spec
-            q = (1, spec.num_heads, tc, spec.block.dh(spec.d_model))
-            self._chunk_walk[tc] = spec.cache_pools == 2 and all(
+            latent = spec.cache_pools == 1
+            q = (1, spec.num_heads, tc, spec.cache_row_width if latent
+                 else spec.block.dh(spec.d_model))
+            self._chunk_walk[tc] = all(
                 paged_attention.chunk_supported(
                     q, jax.ShapeDtypeStruct(cache.shape,
                                             to_dtype(spec.page_dtype)),
-                    paged_attention.CHUNK_MASK) for cache in self._caches)
+                    paged_attention.CHUNK_MASK,
+                    spec.block.kv_lora_rank if latent else None)
+                for cache in self._caches)
         return self._chunk_walk[tc]
 
     # -- warmup / manifests ----------------------------------------------

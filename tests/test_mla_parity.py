@@ -438,6 +438,65 @@ def test_engine_holds_one_latent_pool_and_counts_the_held_share(no_amp):
     assert 0 < c["moe_touched_experts"] <= 4 * c["moe_layer_calls"]
 
 
+@pytest.mark.parametrize("walks", [True, False], ids=["walk", "gather"])
+def test_prefill_units_count_the_pages_the_latent_chunk_walk_reads(no_amp,
+                                                                   walks):
+    """A latent engine (ONE pool, ``cache_pools`` 1) counts
+    ``prefill_attn_pages_read`` / ``prefill_attn_table_pages`` (one kind: no
+    suffix) where its prefill programs took the chunk walk (the ops'
+    predicate, here answered for it: no chip), by the walk's own rule
+    (``PageCache.chunk_pages_read``: page 0 to the chunk's last real key);
+    an engine whose programs gather counts neither."""
+    eng = _engine()
+    assert eng.spec.cache_pools == 1
+    assert eng._chunk_walks(8) is False     # the CPU mesh: the gathered form
+    eng._chunk_walk = {tc: walks for tc in (4, 8)}
+    before = _counters(eng)
+    # 19 tokens: chunks at 0 and 8 (8 tokens each), then 3 tokens at 16
+    eng.generate_all([_prompt(19, seed=5)], max_new_tokens=2)
+    c = {k: v - before.get(k, 0) for k, v in _counters(eng).items()
+         if k.startswith("prefill_attn")}
+    if not walks:
+        assert not c
+        return
+    units = _counters(eng)["prefill_feed_host_arrays"] \
+        - before.get("prefill_feed_host_arrays", 0)
+    assert units == 3
+    # a unit's row brings its whole table: 64 / 4 entries
+    assert c == {"prefill_attn_table_pages": units * ENGINE["max_len"] // PS,
+                 # pages 0 .. the chunk's last, of 4 tokens: 8 / 4, 16 / 4
+                 # and ceil(19 / 4)
+                 "prefill_attn_pages_read": 2 + 4 + 5}
+    (cache,) = eng._caches
+    assert cache.chunk_pages_read(np.array([0, 8, 16, 40]),
+                                  np.array([8, 8, 3, 0]), 16) == 11
+
+
+@pytest.mark.parametrize("backend,row,latent,ok", [
+    ("tpu", 384, 256, True),        # mistral4: 256 + 64 held at 384
+    ("tpu", 640, 512, True),        # ling3: 512 + 64 held at 640
+    ("tpu", 384, 320, False),       # a latent of two and a half lane rows
+    ("cpu", 384, 256, False),
+])
+def test_the_engine_asks_the_ops_own_rule_about_its_one_pool(
+        monkeypatch, backend, row, latent, ok):
+    """``GenerationEngine._chunk_walks`` for a latent spec: the ops'
+    predicate over queries as wide as the pool's row and the latent as the
+    value, at the cells' chunk widths, memoised a width."""
+    from types import SimpleNamespace
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    spec = SimpleNamespace(
+        cache_pools=1, cache_row_width=row, num_heads=32,
+        page_dtype="bfloat16", block=SimpleNamespace(kv_lora_rank=latent))
+    eng = SimpleNamespace(
+        spec=spec, _chunk_walk={},
+        _caches=[SimpleNamespace(shape=(6, 1536, 256, row))])
+    for tc in (64, 256):
+        assert GenerationEngine._chunk_walks(eng, tc) is ok
+    assert eng._chunk_walk == {64: ok, 256: ok}
+
+
 # ---------------------------------------------------------------------------
 # the expert layer's share
 # ---------------------------------------------------------------------------

@@ -271,6 +271,132 @@ def test_chunk_walk_never_reads_a_page_out_of_reach(window):
         q, ck_p, cv_p, layer, table, start, lengths, window))).any()
 
 
+#: the latent chunk walk: (row width W, latent r) of the two latent cells
+LATENT_WIDTHS = [pytest.param(384, 256, id="w384-r256"),
+                 pytest.param(640, 512, id="w640-r512")]
+#: name -> (chunk, heads, rows (start, real tokens), the module's tile
+#: constants for the case or None: the kernel's own)
+LATENT_CHUNKS = {
+    # eight heads in one score tile; a start that is not page-aligned, a
+    # row that ends mid-tile, a padding row, a tail of five tokens
+    "t64-ragged": (64, 8, [(37, 11), (10 * C_PS, 64), (4 * C_PS, 0),
+                           (203, 5)], None),
+    # the whole chunk one query tile, a head a step
+    "t256-one-tile": (256, 2, [(100, 256)], None),
+    # two query tiles (the second's real queries end mid-tile: each tile
+    # stops at ITS last real key), two heads a step, blocks of four pages
+    "t256-two-tiles-blocks": (256, 4, [(3 * C_PS + 5, 200), (0, 256)],
+                              dict(_CHUNK_ROWS=512, _LATENT_SCORE_ROWS=256,
+                                   _CHUNK_KEYS=4 * C_PS)),
+}
+L_N, L_P = 96, 40       # pages of the latent pool, table width
+
+
+def _latent_case(W, r, chunk, seed=0, dtype=jnp.bfloat16):
+    """The one pool, a table a row (the pages its chunk reaches, permuted;
+    the unheld tail names a page that does not exist), queries as wide as
+    the pool's row, start and lengths."""
+    t, heads, rows, _ = LATENT_CHUNKS[chunk]
+    rng = np.random.default_rng(seed)
+    ck = jnp.asarray(rng.standard_normal((L, L_N, C_PS, W)), dtype)
+    table = np.full((len(rows), L_P), 10 ** 6, np.int32)
+    for s, (start, n) in enumerate(rows):
+        held = -(-(start + n) // C_PS) if n else 0
+        table[s, :held] = rng.permutation(np.arange(1, L_N))[:held]
+    q = jnp.asarray(0.3 * rng.standard_normal((len(rows), heads, t, W)),
+                    dtype)
+    start, lengths = (jnp.asarray([x[i] for x in rows], jnp.int32)
+                      for i in (0, 1))
+    return q, ck, table, start, lengths
+
+
+def _latent_reference(q, ck, layer, table, start, lengths, r):
+    """``_mla_paged_step``'s gathered form (``ck[l, tbl]`` +
+    ``reference_attention``, the row's first r columns the value) as the
+    walk returns it, [b, Tc, H * r], the padding queries' rows zeroed."""
+    b, _, t, W = q.shape
+    tbl = jnp.clip(jnp.asarray(table), 0, ck.shape[1] - 1)
+    lat = ck[layer, tbl].reshape(b, 1, -1, W)
+    o = reference_attention(q, lat, lat[..., :r], sm_scale=1.0, causal=True,
+                            q_pos0=start)
+    real = jnp.arange(t)[None, :] < lengths[:, None]
+    return jnp.where(real[..., None], o.transpose(0, 2, 1, 3).reshape(
+        b, t, -1), 0)
+
+
+@pytest.fixture
+def latent_tiles(monkeypatch):
+    """-> set(chunk): the case's tile constants in place. The jitted walk
+    reads them as it traces, so no trace outlives a set of them."""
+    def set_tiles(chunk):
+        tiles = LATENT_CHUNKS[chunk][3]
+        for name, value in (tiles or {}).items():
+            monkeypatch.setattr(paged_attention, name, value)
+        return tiles
+
+    paged_attention._chunk_walk.clear_cache()
+    yield set_tiles
+    paged_attention._chunk_walk.clear_cache()
+
+
+@pytest.mark.parametrize("chunk", sorted(LATENT_CHUNKS))
+@pytest.mark.parametrize("W,r", LATENT_WIDTHS)
+def test_latent_chunk_walk_is_the_gathered_absorbed_attention(
+        latent_tiles, W, r, chunk):
+    """ONE pool as key and value (``cache_v=None``): every head's queries
+    [Tc, W] against the page tile whole, the tile's first r columns the
+    value, the scale handed in: ``reference_attention`` over ``ck[l, tbl]``
+    as ``_mla_paged_step`` gathers it, zeros for padding queries and rows."""
+    t, heads, rows, _ = LATENT_CHUNKS[chunk]
+    if latent_tiles(chunk):
+        assert paged_attention._query_tile(t, heads, jnp.bfloat16) == t // 2
+    q, ck, table, start, lengths = _latent_case(W, r, chunk)
+    layer = jnp.int32(1)
+    got = paged_attention_prefill(q, ck, None, layer, jnp.asarray(table),
+                                  start, lengths, interpret=True,
+                                  sm_scale=1.0, value_width=r)
+    assert got.shape == (len(rows), t, heads * r) and got.dtype == ck.dtype
+    want = _latent_reference(q, ck, layer, table, start, lengths, r)
+    truth = np.asarray(_latent_reference(
+        q.astype(jnp.float32), ck.astype(jnp.float32), layer, table, start,
+        lengths, r))
+    got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
+    tol = _tolerance(jnp.bfloat16, want, truth)
+    np.testing.assert_allclose(got, truth, atol=tol, rtol=0)
+    np.testing.assert_allclose(got, want, atol=2 * tol, rtol=0)
+    for s, (_, n) in enumerate(rows):
+        assert not got[s, n:].any()
+
+
+@pytest.mark.parametrize("chunk", ["t64-ragged", "t256-two-tiles-blocks"])
+def test_latent_chunk_walk_never_reads_a_page_out_of_reach(latent_tiles,
+                                                           chunk):
+    """Every page outside ``chunk_pages_in_reach`` (the other layer whole,
+    pages no row holds) is NaN and the table's unheld tails name a page that
+    does not exist: finite, and bitwise what the clean pool gives; a float32
+    pool multiplies as float32 (1e-5 of the gathered form)."""
+    latent_tiles(chunk)
+    W, r = 384, 256
+    q, ck, table, start, lengths = _latent_case(W, r, chunk, seed=3,
+                                                dtype=jnp.float32)
+    layer = jnp.int32(0)
+    held = np.where(table < L_N, table, 0)
+    clean = paged_attention_prefill(q, ck, None, layer, jnp.asarray(held),
+                                    start, lengths, interpret=True,
+                                    sm_scale=1.0, value_width=r)
+    poison = np.ones((L, L_N), bool)
+    poison[0, table[table < L_N]] = False
+    ck_p = jnp.where(jnp.asarray(poison)[:, :, None, None], jnp.nan, ck)
+    got = paged_attention_prefill(q, ck_p, None, layer, jnp.asarray(table),
+                                  start, lengths, interpret=True,
+                                  sm_scale=1.0, value_width=r)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_latent_reference(
+            q, ck, layer, table, start, lengths, r)), atol=1e-5, rtol=0)
+
+
 def test_the_engine_counts_the_pages_the_chunk_walk_reads():
     """``PageCache.chunk_pages_read`` is the kernel's rule summed over a
     unit's rows: a full layer from page 0, a window layer from its
@@ -362,7 +488,8 @@ def _prefill_unit_text(cell_name, one_chip, monkeypatch):
             "TopP": ((1,), "float32"), "Seed": ((1,), "int32"),
             "Step": ((1,), "int32")}
     pools = {n: ((spec.pool_layers(False), e["n_pages"], *row),
-                 spec.page_dtype) for n in ("CacheK", "CacheV")}
+                 spec.page_dtype)
+             for n in ("CacheK", "CacheV")[:spec.cache_pools]}
     if spec.block.has_window:
         rows["BlockTableW"] = ((1, P), "int32")
         pools.update({n: ((spec.pool_layers(True), e["n_pages_window"],
@@ -382,10 +509,12 @@ def _prefill_unit_text(cell_name, one_chip, monkeypatch):
     state = {}
     for name, shape, dtype, layers in spec.slot_state():
         state[name] = ((layers, e["slots"], *shape), dtype)
-        state[name + "Snap"] = ((layers, e["n_snapshots"], *shape), dtype)
+        if "n_snapshots" in e:
+            state[name + "Snap"] = ((layers, e["n_snapshots"], *shape), dtype)
     if state:
-        rows.update({n: ((1,), "int32")
-                     for n in ("StateSlot", "SnapFrom", "SnapTake")})
+        rows["StateSlot"] = ((1,), "int32")
+    if "n_snapshots" in e:
+        rows.update({n: ((1,), "int32") for n in ("SnapFrom", "SnapTake")})
     shapes = {**rows, **pools, **weights, **state}
     names = sorted(shapes)
     attrs = dict(spec.block.attrs(), page_size=ps, temperature=0.0, top_k=0)
@@ -452,6 +581,85 @@ def test_prefill_unit_on_the_v5e_walks_the_pages_of_whole_pools(
     stacks = tuple(f"f32[{rows},{n}] custom-call(" for n in
                    (spec.d_expert, spec.d_model))
     assert all(any(st in c for st in stacks) for c in products)
+
+
+@pytest.mark.parametrize("cell_name,calls,experts,r", [
+    # six latent layers under one scan; their expert layers' three products
+    ("mistral4-serve-longdoc", 1, 3, 256),
+    # the one latent layer of the period beside five recurrent ones; four
+    # expert layers after the two dense
+    ("ling3-serve-reason", 1, 12, 512),
+])
+def test_latent_prefill_unit_on_the_v5e_walks_the_pages_of_the_one_pool(
+        one_chip, monkeypatch, cell_name, calls, experts, r):
+    """The prefill unit of the two latent cells compiled for the chip at the
+    cell's shapes: the latent layers' attention is the chunk walk under its
+    OWN call name (not the K/V walk's, not the tick's), the ONE pool enters
+    the call whole, and nothing shaped like the gathered table [.., P, ps,
+    W] or its float32 scores [b, heads, Tc, P * ps] is compiled in (ling3's
+    table is as wide as its recurrent layers' projection, 12288: [1, Tc,
+    12288] alone is theirs)."""
+    flat, spec, e, P = _prefill_unit_text(cell_name, one_chip, monkeypatch)
+    ps, Tc, W = e["page_size"], e["prefill_chunk"], spec.cache_row_width
+    assert (spec.cache_pools, spec.block.kv_lora_rank) == (1, r)
+    walks = [ln for ln in flat.splitlines() if "custom-call(" in ln
+             and "%paged_mla_prefill" in ln and "tpu_custom_call" in ln]
+    assert len(walks) == calls
+    assert "%paged_attention_prefill" not in flat
+    assert "%paged_mla_decode" not in flat
+    pool = f"[{spec.pool_layers(False)},{e['n_pages']},{ps},{W}]"
+    assert all(c.count(f"bf16{pool}") == 1 for c in walks)
+    # the walk hands back the latent's columns alone, a token's heads side
+    # by side
+    assert all(f"bf16[1,{Tc},{spec.num_heads * r}] custom-call(" in c
+               for c in walks)
+    ops = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", flat, re.M)
+    moved = {op for shape, op in ops if shape.endswith(pool)
+             and not shape.startswith("(")}
+    assert moved <= {"parameter", "get-tuple-element", "scatter", "fusion",
+                     "bitcast"}, moved
+    assert not [shape for shape, _ in ops
+                if re.search(rf"f32\[(\d+,){{2,}}{Tc},{P * ps}\]", shape)
+                or f",{P},{ps},{W}]" in shape or f"[{P},{ps},{W}]" in shape
+                or f"[1,{P * ps},{W}]" in shape]
+    products = [ln for ln in flat.splitlines() if "custom-call(" in ln
+                and "%grouped_matmul" in ln and "tpu_custom_call" in ln]
+    assert len(products) == experts and "ragged" not in flat
+
+
+@pytest.mark.parametrize("heads,W,r,pages,table_width,chunk", [
+    pytest.param(32, 384, 256, 1536, 80, 256, id="mistral4-256"),
+    pytest.param(32, 384, 256, 1536, 80, 64, id="mistral4-64"),
+    pytest.param(32, 640, 512, 4096, 48, 256, id="ling3-256"),
+])
+def test_latent_chunk_walk_compiles_for_the_v5e_with_the_one_pool_whole(
+        one_chip, heads, W, r, pages, table_width, chunk):
+    """The latent chunk walk alone at the two latent cells' shapes: one
+    custom call under its own name, one pool operand, nothing pool-sized
+    moved, the result the latent's columns of every head."""
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    layers, ps = 6, 256
+
+    def call(q, pool, layer, table, start, lengths):
+        return paged_attention_prefill(q, pool, None, layer, table, start,
+                                       lengths, sm_scale=1.0, value_width=r)
+
+    text = jax.jit(call).lower(
+        arg((1, heads, chunk, W), jnp.bfloat16),
+        arg((layers, pages, ps, W), jnp.bfloat16), arg((), jnp.int32),
+        arg((1, table_width), jnp.int32), arg((1,), jnp.int32),
+        arg((1,), jnp.int32)).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "%paged_mla_prefill" in calls[0]
+    assert calls[0].count(f"[{layers},{pages},{ps},{W}]") == 1
+    assert f"bf16[1,{chunk},{heads * r}]" in calls[0]
+    ops = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(",
+                     re.sub(r"\{[^{}]*\}", "", text), re.M)
+    assert {op for shape, op in ops
+            if f"{pages},{ps},{W}]" in shape} == {"parameter"}
 
 
 def test_latent_kernel_compiles_for_the_v5e_with_the_one_pool_whole(one_chip):
@@ -776,26 +984,35 @@ def test_stacked_train_step_on_the_v5e_runs_its_forward_scan_once(
 # ---------------------------------------------------------------------------
 # the dispatch rule of _scan_paged_layers
 # ---------------------------------------------------------------------------
-def _run_layers(monkeypatch, backend, dtype=jnp.float32, heads=4, kv_heads=4,
-                d_head=32, t=1, causal=False, ps=PS, chunk=False):
-    """One pass of ``_scan_paged_layers`` over toy projections with the
-    backend reported as ``backend``; the kernels, where chosen, run in
-    interpret mode. ``causal``: a block-causal mask without the chunk's
-    length; ``chunk``: a prefill chunk's own mask (``chunk_mask``), every
-    query real. -> (h, how often a kernel was traced)."""
-    from paddle_tpu.ops.pipeline_ops import chunk_mask
-
+def _spy_on_the_walks(monkeypatch, backend):
+    """``jax.default_backend`` answered as ``backend``; the kernels, where
+    chosen, run in interpret mode -> the calls made, by name."""
     calls = []
 
     def spy(kernel):
         def run(*args, **kwargs):
-            calls.append((kernel.__name__, args[0].shape))
+            calls.append((kernel.__name__, kwargs))
             return kernel(*args, interpret=True, **kwargs)
         return run
 
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     for kernel in (paged_attention_decode, paged_attention_prefill):
         monkeypatch.setattr(paged_attention, kernel.__name__, spy(kernel))
+    return calls
+
+
+def _run_layers(monkeypatch, backend, dtype=jnp.float32, heads=4, kv_heads=4,
+                d_head=32, t=1, causal=False, ps=PS, chunk=False,
+                lower=False):
+    """One pass of ``_scan_paged_layers`` over toy projections with the
+    backend reported as ``backend``; the kernels, where chosen, run in
+    interpret mode. ``causal``: a block-causal mask without the chunk's
+    length; ``chunk``: a prefill chunk's own mask (``chunk_mask``), every
+    query real. -> (h, how often a kernel was traced), or with ``lower``
+    the pass's lowered text."""
+    from paddle_tpu.ops.pipeline_ops import chunk_mask
+
+    calls = _spy_on_the_walks(monkeypatch, backend)
     b, d = 3, heads * d_head
     width = kv_heads * d_head
     rng = np.random.default_rng(0)
@@ -819,11 +1036,15 @@ def _run_layers(monkeypatch, backend, dtype=jnp.float32, heads=4, kv_heads=4,
         mask = dict(causal=True, q_pos0=pos)
     else:
         mask = dict(lengths=pos + t)
-    out, ck2, cv2, _, _ = _scan_paged_layers(
-        {"w": jnp.linspace(0.5, 1.5, L)[:, None]}, h, ck, cv, table,
-        page_id, at % ps, project, mask,
-        lambda p, x, ctx, _x: (x + ctx.astype(x.dtype), None))
-    return np.asarray(out), [name for name, _ in calls]
+    def run(h, ck, cv):
+        return _scan_paged_layers(
+            {"w": jnp.linspace(0.5, 1.5, L)[:, None]}, h, ck, cv, table,
+            page_id, at % ps, project, mask,
+            lambda p, x, ctx, _x: (x + ctx.astype(x.dtype), None))[0]
+
+    if lower:
+        return jax.jit(run).lower(h, ck, cv).as_text()
+    return np.asarray(run(h, ck, cv)), [name for name, _ in calls]
 
 
 def test_a_decode_step_on_a_chip_takes_the_kernel(monkeypatch):
@@ -877,45 +1098,161 @@ def test_everything_else_keeps_the_gathered_reference(monkeypatch, why,
     assert np.isfinite(got).all()
 
 
-def test_a_latent_chunk_keeps_the_gathered_form(monkeypatch):
-    """``_mla_paged_step`` on a chip under a chunk's own mask: neither
-    kernel (the latent chunk walk is a later change), and the absorbed
-    gathered attention with the chunk's length left behind."""
+def _latent_step(mask, W=128, r=128, rope_d=32, t=16, dtype=jnp.float32):
+    """One layer of ``_mla_paged_step`` over toy projections (a latent of r
+    and rope_d rotary columns in a pool row W wide) -> (attend, its
+    arguments)."""
     from paddle_tpu.lm_spec import Block
     from paddle_tpu.ops import pipeline_ops
 
-    def never(*args, **kwargs):
-        raise AssertionError("a latent chunk took a kernel")
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    for name in ("paged_attention_decode", "paged_attention_prefill"):
-        monkeypatch.setattr(paged_attention, name, never)
     blk = Block(num_heads=4, use_rope=True, norm="rms_norm", bias=False,
-                attn="mla", q_lora_rank=8, kv_lora_rank=96,
-                qk_nope_head_dim=8, qk_rope_head_dim=32, v_head_dim=16)
-    b, t, W = 2, 16, 128
+                attn="mla", q_lora_rank=8, kv_lora_rank=r,
+                qk_nope_head_dim=8, qk_rope_head_dim=rope_d, v_head_dim=16)
+    b = 2
     rng = np.random.default_rng(0)
-    ck = jnp.asarray(rng.standard_normal((1, 6, 16, W)), jnp.float32)
-    p = {"kv_b_w": jnp.asarray(rng.standard_normal((96, 4 * 24)),
+    ck = jnp.asarray(rng.standard_normal((1, 6, 16, W)), dtype)
+    p = {"kv_b_w": jnp.asarray(rng.standard_normal((r, 4 * 24)),
                                jnp.float32)}
-    proj = tuple(jnp.asarray(rng.standard_normal(shape), jnp.float32)
-                 for shape in ((b, 4, t, 8), (b, 4, t, 32), (b, t, 96),
-                               (b, t, 32)))
+    proj = tuple(jnp.asarray(0.3 * rng.standard_normal(shape), jnp.float32)
+                 for shape in ((b, 4, t, 8), (b, 4, t, rope_d), (b, t, r),
+                               (b, t, rope_d)))
     start = jnp.asarray([0, 16], jnp.int32)
     table = jnp.asarray([[1, 0, 0], [2, 3, 0]], jnp.int32)
     at = start[:, None] + jnp.arange(t)[None, :]
+    if mask == "chunk":
+        mask = pipeline_ops.chunk_mask(start, jnp.full((b,), t, jnp.int32))
+    elif mask == "causal":
+        mask = dict(causal=True, q_pos0=start)
+    attend = pipeline_ops._mla_paged_step(
+        blk, b, t, lambda layer_p, h: proj, mask,
+        lambda layer_p, h, ctx, x_l: (ctx, None))
+    return attend, (jnp.zeros((b, t, 64)), ck, None, 0, p, None, table,
+                    jnp.take_along_axis(table, at // 16, axis=1), at % 16)
+
+
+@pytest.mark.parametrize("W,r,rope_d", [(256, 128, 64), (384, 256, 64)],
+                         ids=["w256-r128", "w384-r256"])
+def test_a_latent_chunk_on_a_chip_takes_the_chunk_walk(monkeypatch, W, r,
+                                                       rope_d):
+    """``_mla_paged_step`` under a chunk's own mask + TPU + row and latent
+    of whole lane rows: the chunk walk over the ONE pool (no V pool, the
+    scale on the queries, the latent the value), never the decode kernel,
+    and the context of the gathered path."""
+    calls = _spy_on_the_walks(monkeypatch, "tpu")
+    attend, args = _latent_step("chunk", W, r, rope_d)
+    got, *_ = attend(*args)
+    assert calls == [("paged_attention_prefill",
+                      {"sm_scale": 1.0, "value_width": r})]
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    attend, args = _latent_step("chunk", W, r, rope_d)
+    want, *_ = attend(*args)
+    assert len(calls) == 1 and got.shape == want.shape == (2, 16, 4 * 16)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4,
+                               rtol=0)
+
+
+@pytest.mark.parametrize("why,backend,mask,W,r", [
+    ("not a TPU", "cpu", "chunk", 256, 128),
+    ("a latent narrower than a lane row", "tpu", "chunk", 128, 96),
+    ("a block-causal mask without the chunk's length", "tpu", "causal",
+     256, 128),
+])
+def test_every_other_latent_chunk_keeps_the_gathered_form(monkeypatch, why,
+                                                          backend, mask, W,
+                                                          r):
+    """Off the rule (``chunk_supported``) neither kernel: the absorbed
+    gathered attention, the chunk's length left behind (the same context
+    with and without it)."""
+    calls = _spy_on_the_walks(monkeypatch, backend)
     ctxs = []
-    for mask in (pipeline_ops.chunk_mask(start, jnp.full((b,), t, jnp.int32)),
-                 dict(causal=True, q_pos0=start)):
-        attend = pipeline_ops._mla_paged_step(
-            blk, b, t, lambda layer_p, h: proj, mask,
-            lambda layer_p, h, ctx, x_l: (ctx, None))
-        ctx, *_ = attend(jnp.zeros((b, t, 64)), ck, None, 0, p, None, table,
-                         jnp.take_along_axis(table, at // 16, axis=1),
-                         at % 16)
+    for m in (mask, "causal"):
+        attend, args = _latent_step(m, W, r)
+        ctx, *_ = attend(*args)
         ctxs.append(np.asarray(ctx))
-    assert ctxs[0].shape == (b, t, 4 * 16) and np.isfinite(ctxs[0]).all()
+    assert not calls, why
+    assert ctxs[0].shape == (2, 16, 4 * 16) and np.isfinite(ctxs[0]).all()
     np.testing.assert_array_equal(*ctxs)
+
+
+def _parent_mla_attend(blk, b, t, project, mask, finish):
+    """``_mla_paged_step``'s ``attend`` as it was before a latent chunk
+    could walk: the decode kernel or the gathered form."""
+    from paddle_tpu.ops import pipeline_ops as po
+
+    r, rope_d = blk.kv_lora_rank, blk.qk_rope_head_dim
+    scale = po._sm_scale(blk)
+
+    def attend(h, ck, cv, l, layer_p, x_l, tbl, ix_page, ix_row, **_kw):
+        q_nope, q_rope, c_kv, k_rope = project(layer_p, h)
+        W = ck.shape[-1]
+        row = jnp.concatenate([c_kv, k_rope], axis=-1)
+        row = jnp.pad(row, ((0, 0), (0, 0), (0, W - row.shape[-1])))
+        ck = ck.at[l, ix_page, ix_row].set(row.astype(ck.dtype))
+        w_uk, w_uv = po._mla_up(blk, layer_p)
+        q_abs = po._mm(blk, "bhtn,rhn->bhtr", q_nope, w_uk)
+        q_lat = jnp.concatenate([q_abs, q_rope], axis=-1) * scale
+        q_lat = jnp.pad(q_lat, ((0, 0),) * 3 + ((0, W - r - rope_d),))
+        if t == 1 and set(mask) == {"lengths"} \
+                and paged_attention.supported(W, ck, t):
+            o_lat = paged_attention.paged_attention_decode(
+                q_lat[:, :, 0], ck, None, l, tbl, mask["lengths"],
+                sm_scale=1.0, name=paged_attention.MLA_KERNEL)
+            o_lat = o_lat.reshape(b, -1, 1, W)
+        else:
+            lat = ck[l, tbl].reshape(b, 1, tbl.shape[1] * ck.shape[2], W)
+            o_lat = reference_attention(q_lat.astype(ck.dtype), lat,
+                                        lat[..., :r], sm_scale=1.0,
+                                        **po._gathered_mask(mask))
+        ctx = po._mm(blk, "bhtr,rhv->bthv", o_lat[..., :r].astype(h.dtype),
+                     w_uv).reshape(b, t, -1)
+        h, stats = finish(layer_p, h, ctx, x_l)
+        return h, ck, cv, stats
+
+    return attend
+
+
+def _paged_texts(monkeypatch):
+    """The lowered text of every kind of paged layer step this file drives,
+    as the CPU mesh runs them: a latent chunk at lane-aligned and at
+    unaligned widths, a latent tick, and the K/V stack's tick, verify-free
+    chunk and block-causal call (``_run_layers``' operands)."""
+    texts = {}
+    for name, kw in (("latent-chunk", dict(mask="chunk", W=384, r=256,
+                                           rope_d=64)),
+                     ("latent-chunk-narrow", dict(mask="chunk", W=128, r=96)),
+                     ("latent-causal", dict(mask="causal", W=256, r=128))):
+        attend, args = _latent_step(**kw)
+        texts[name] = jax.jit(attend).lower(*args).as_text()
+
+    for name, kw in (("kv-tick", {}),
+                     ("kv-chunk", dict(heads=2, kv_heads=2, d_head=128, t=16,
+                                       chunk=True)),
+                     ("kv-gqa-chunk", dict(heads=4, kv_heads=2, d_head=128,
+                                           t=16, chunk=True)),
+                     ("kv-causal", dict(t=4, causal=True))):
+        texts[name] = _run_layers(monkeypatch, "cpu", lower=True, **kw)
+    return texts
+
+
+def test_the_cpu_programs_lower_to_the_text_they_lowered_to(monkeypatch):
+    """Off the chip nothing changed: the latent block's prefill chunk (and
+    its other calls) lower byte for byte to what the step's parent form
+    lowers to (kept above: decode kernel or gather, no chunk walk, the old
+    ``chunk_supported``), and so does every K/V program."""
+    from paddle_tpu.ops import pipeline_ops
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    texts = _paged_texts(monkeypatch)
+    assert "reduce" in texts["latent-chunk"]    # the gathered softmax
+    monkeypatch.setattr(pipeline_ops, "_mla_paged_step", _parent_mla_attend)
+    rule = paged_attention.chunk_supported
+    monkeypatch.setattr(
+        paged_attention, "chunk_supported",
+        lambda q_shape, pool, mask: rule(q_shape, pool, mask))
+    parent = _paged_texts(monkeypatch)
+    assert sorted(texts) == sorted(parent) and len(texts) == 7
+    for name in texts:
+        assert texts[name] == parent[name], name
 
 
 @pytest.mark.parametrize("dtype,ps,ok", [
@@ -943,8 +1280,29 @@ def test_supported_reads_shapes_dtype_and_backend_only(monkeypatch, dtype,
     assert not paged_attention.chunk_supported((1, 2, 60, 128), pool, chunk)
     assert not paged_attention.chunk_supported(
         (1, 2, 64, 128), pool, {"causal", "q_pos0"})
+    # a latent pool (no V pool: the row's first ``value_width`` columns the
+    # value): queries as wide as the row, row and latent of whole lane rows
+    for W, r, fits in ((384, 256, True), (640, 512, True), (128, 128, True),
+                       (384, 320, False), (320, 256, False),
+                       (128, 96, False), (384, 0, False), (384, 512, False)):
+        lat = jax.ShapeDtypeStruct((1, 8, ps, W), dtype)
+        assert paged_attention.chunk_supported(
+            (1, 32, 256, W), lat, chunk, r) is (ok and fits), (W, r)
+        assert paged_attention.chunk_supported(
+            (1, 32, 64, W), lat, chunk, r) is (ok and fits), (W, r)
+    lat = jax.ShapeDtypeStruct((1, 8, ps, 384), dtype)
+    assert not paged_attention.chunk_supported((1, 32, 2, 384), lat, chunk,
+                                               256)
+    assert not paged_attention.chunk_supported((1, 32, 60, 384), lat, chunk,
+                                               256)
+    assert not paged_attention.chunk_supported((1, 32, 64, 256), lat, chunk,
+                                               256)       # q not the row's
+    assert not paged_attention.chunk_supported(
+        (1, 32, 64, 384), lat, {"causal", "q_pos0"}, 256)
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     assert not paged_attention.chunk_supported((1, 2, 64, 128), pool, chunk)
+    assert not paged_attention.chunk_supported((1, 32, 64, 384), lat, chunk,
+                                               256)
 
 
 def test_wrapper_refuses_mismatched_operands():
@@ -966,3 +1324,13 @@ def test_wrapper_refuses_mismatched_operands():
     with pytest.raises(ValueError, match="whole sublane tiles"):
         paged_attention_prefill(jnp.zeros((2, 2, 12, 128)), ck, cv, 0, table,
                                 lengths, lengths, interpret=True)
+    # one pool: queries as wide as its row, and a value width inside it
+    for q_width, value_width in ((128, 128), (256, None), (256, 512)):
+        with pytest.raises(ValueError, match="does not match the pools"):
+            paged_attention_prefill(jnp.zeros((2, 2, 16, q_width)), ck, None,
+                                    0, table, lengths, lengths,
+                                    interpret=True, value_width=value_width)
+    with pytest.raises(ValueError, match="does not match the pools"):
+        paged_attention_prefill(jnp.zeros((2, 2, 16, 128)), ck, cv, 0, table,
+                                lengths, lengths, interpret=True,
+                                value_width=128)

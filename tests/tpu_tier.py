@@ -857,6 +857,76 @@ def paged_attention_prefill_matches_gathered():
 
 
 @check
+def paged_mla_prefill_matches_gathered():
+    """The latent chunk walk (``paged_mla_prefill``: ONE pool as key and
+    value) against the gathered form ``_mla_paged_step`` ran (``ck[l, tbl]``
+    + ``reference_attention``, the row's first r columns the value, scale 1
+    on the queries) at the two latent cells' shapes, 32 heads, bf16 pages of
+    256: ``mistral4-serve-longdoc`` (row 384, latent 256, a table 80 wide:
+    a 256-token question chunk and a 64-token tail with 40 real tokens,
+    both behind a 16,640-key context, mid-page) and ``ling3-serve-reason``
+    (row 640, latent 512, a table 48 wide: a first chunk, whole, and a
+    chunk at 3,840 with 190 real tokens). The kernel's pool holds NaN in
+    every page out of the walk's reach and the table's tail names a page
+    that does not exist; the bf16 results are no further from a float32
+    softmax than the gathered form's own."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels.flash_attention import reference_attention
+    from paddle_tpu.kernels.paged_attention import (chunk_pages_in_reach,
+                                                    paged_attention_prefill)
+
+    L, N, ps, H, layer = 2, 96, 256, 32, 1
+    rng = np.random.RandomState(9)
+    detail = []
+    for W, r, P, calls in (
+            (384, 256, 80, ((16640 + 37, 256, 256), (16640 + 293, 64, 40))),
+            (640, 512, 48, ((0, 256, 256), (3840, 256, 190)))):
+        pool = jnp.asarray(rng.randn(L, N, ps, W), jnp.bfloat16)
+        for start, Tc, length in calls:
+            first, end = (int(a) for a in chunk_pages_in_reach(
+                np.int64(start), np.int64(length), ps, None, xp=np))
+            table = np.full((1, P), N + 5, np.int32)    # the tail: no page
+            table[0, :end] = rng.permutation(np.arange(1, N))[:end]
+            q = jnp.asarray(0.3 * rng.randn(1, H, Tc, W), jnp.bfloat16)
+            poison = np.ones((L, N, 1, 1), bool)
+            poison[layer, table[0, first:end]] = False
+            ck = jnp.where(jnp.asarray(poison), jnp.nan, pool)
+            s0 = jnp.asarray([start], jnp.int32)
+            n0 = jnp.asarray([length], jnp.int32)
+            walk = jax.jit(lambda q, ck, s0, n0, table=table, r=r:
+                           paged_attention_prefill(
+                               q, ck, None, jnp.int32(layer),
+                               jnp.asarray(table), s0, n0, sm_scale=1.0,
+                               value_width=r))
+            got = np.asarray(walk(q, ck, s0, n0).astype(jnp.float32))
+            t0 = time.perf_counter()
+            walk(q, ck, s0, n0).block_until_ready()
+            ms = (time.perf_counter() - t0) * 1e3
+            seen = jnp.asarray(np.clip(table, 0, N - 1))
+
+            def gathered(q, ck):
+                lat = ck[layer, seen].reshape(1, 1, P * ps, W)
+                out = reference_attention(q, lat, lat[..., :r], sm_scale=1.0,
+                                          causal=True, q_pos0=s0)
+                return out.transpose(0, 2, 1, 3).reshape(1, Tc, -1)
+
+            want = np.asarray(jax.jit(gathered)(q, pool).astype(jnp.float32))
+            truth = np.asarray(jax.jit(gathered)(
+                q.astype(jnp.float32), pool.astype(jnp.float32)))
+            err = np.abs(got - truth)[0, :length].max()
+            ref_err = np.abs(want - truth)[0, :length].max()
+            tol = max(2 * ref_err, 2.0 ** -8 * np.abs(truth).max())
+            assert got.shape == (1, Tc, H * r), got.shape
+            assert np.isfinite(got).all() and err <= tol, (
+                W, start, err, tol)
+            assert not got[0, length:].any()    # padding queries: zeros
+            detail.append(f"w{W} s{start}+{length}/{Tc}: err {err:.1e} "
+                          f"(gathered {ref_err:.1e}) {ms:.2f} ms")
+    return "; ".join(detail)
+
+
+@check
 def grouped_matmul_matches_ragged_dot():
     """The grouped-matmul kernel against ``jax.lax.ragged_dot`` on the
     sliced layer, compiled, at two serving shapes: smallthinker's prefill
