@@ -24,7 +24,6 @@ arrived.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 import weakref
@@ -278,6 +277,86 @@ class RequestTimeline:
         }
 
 
+class PassAccount:
+    """What ONE pass of the serve loop held, counted where the pass runs:
+    ``with eng._pass:`` round the pass (``serve_step``, each turn of
+    ``_drive``). A pass is ``[admit -> prefill group call(s)] -> [one
+    prefill chunk] -> [one decode tick]`` and a client's gap between two
+    tokens is one pass, so its modes are the gap's: a tick alone, a tick
+    plus one prefill UNIT (one ``Executor.run`` of a prefill program: a
+    group call or a chunk), a tick plus two or more.
+
+    At close a pass that ran a decode tick over ``n`` decoding rows puts
+    its duration (open -> close, ``perf_counter``) into the fixed-bucket
+    histogram of its mode and ``n`` into the mode's row counter (a verify
+    tick is one pass and ``n`` rows whatever it emits):
+
+    ==========  ===================  ========================
+    units       histogram            row counter
+    ==========  ===================  ========================
+    0           ``pass_tick_only``   ``pass_rows_tick_only``
+    1           ``pass_one_unit``    ``pass_rows_one_unit``
+    2 or more   ``pass_multi_unit``  ``pass_rows_multi_unit``
+    ==========  ===================  ========================
+
+    and its units into ``pass_units``. A pass of two or more bumps each
+    cause that applies, once: ``pass_multi_unit_by_split`` (a group beyond
+    the largest batch bucket went out in several calls),
+    ``pass_multi_unit_by_deferred`` (a deferred admission ran a group of
+    its own: ``_admit_deferred`` sets ``deferred`` on the open pass),
+    ``pass_multi_unit_by_group_and_chunk`` (a group call and a chunk);
+    one of them always does. A pass that ran
+    units and no tick is ``passes_without_tick``: nobody was decoding, so
+    no client saw it as a gap. Everything lands in the engine's registry,
+    whose histograms merge across replicas; the benchmark reads the
+    counters and ``_sum_ms`` / ``_count`` as window differences
+    (``pass_*`` readers, PERF.md section 3). One object an engine, reset
+    at open: a pass allocates nothing. Calls made with no pass open (a
+    test's or ``DisaggEngine``'s own loop) count into nothing."""
+
+    __slots__ = ("metrics", "t0", "groups", "calls", "chunks", "deferred",
+                 "rows")
+
+    HISTS = ("pass_tick_only", "pass_one_unit", "pass_multi_unit")
+    ROWS = ("pass_rows_tick_only", "pass_rows_one_unit",
+            "pass_rows_multi_unit")
+
+    def __init__(self, metrics: MetricsRegistry):
+        self.metrics = metrics
+        self.__enter__()
+
+    def __enter__(self) -> "PassAccount":
+        self.groups = self.calls = self.chunks = self.rows = 0
+        self.deferred = False
+        self.t0 = time.perf_counter()
+        return self
+
+    def group(self, calls: int) -> None:
+        """One admitted group went out in ``calls`` prefill calls."""
+        self.groups += 1
+        self.calls += calls
+
+    def __exit__(self, *exc) -> None:
+        seconds = time.perf_counter() - self.t0
+        units, inc = self.calls + self.chunks, self.metrics.inc
+        if not self.rows:
+            if units:
+                inc("passes_without_tick")
+            return
+        mode = min(units, 2)
+        self.metrics.observe_hist(self.HISTS[mode], seconds)
+        inc(self.ROWS[mode], self.rows)
+        if units:
+            inc("pass_units", units)
+        if mode == 2:
+            if self.calls > self.groups:    # some group took several
+                inc("pass_multi_unit_by_split")
+            if self.deferred:
+                inc("pass_multi_unit_by_deferred")
+            if self.calls and self.chunks:
+                inc("pass_multi_unit_by_group_and_chunk")
+
+
 class _Slot:
     __slots__ = ("request", "generated", "max_new", "eos_id", "prompt",
                  "timeline", "truncate_to", "held", "shared_tokens",
@@ -376,8 +455,8 @@ class GenerationEngine:
     ``cache_stats()``; the per-call counters carry the recurrence's name:
     ``kda_layer_calls`` / ``kda_state_bytes``, or for ``mamba2`` layers
     ``mamba_layer_calls`` / ``mamba_state_bytes`` and ``mamba_chunks``, the
-    SSD blocks a prefill call scans, which is also what its span
-    ``serving/mamba_prefill_unit`` says). A slot IS its state: admission allocates nothing,
+    SSD blocks a prefill call scans). A slot IS its state: admission
+    allocates nothing,
     and a row whose first chunk starts at position 0 reads zeros whatever
     the slot's last tenant left. The state is held at the slot's LAST token
     only, so everything that enters a sequence elsewhere than position 0
@@ -529,6 +608,7 @@ class GenerationEngine:
         # tenant's warmup manifest under its own filename
         self.namespace = str(namespace or "")
         self.metrics = metrics or MetricsRegistry()
+        self._pass = PassAccount(self.metrics)
         # flight recorder: live engine state + last-N request timelines
         # become part of every crash/SIGUSR1/admin dump (weak
         # registration — the recorder never keeps an engine alive)
@@ -908,15 +988,6 @@ class GenerationEngine:
         x ``tc`` tokens runs over its ``mamba2`` layers."""
         return self._state_layers * rows * -(-tc // self.spec.mamba_chunk)
 
-    def _mamba_unit_span(self, rows: int, tc: int):
-        """The span ``serving/mamba_prefill_unit`` round a prefill call of
-        a spec with ``mamba2`` layers (``chunks``: what ``mamba_chunks``
-        counts of it); nothing for any other spec."""
-        if not self._state or self._state_kind != "mamba":
-            return contextlib.nullcontext()
-        return trace.span("serving/mamba_prefill_unit", rows=rows, tokens=tc,
-                          chunks=self._ssd_blocks(rows, tc))
-
     def _lm_ins(self, helper):
         """The ops' weight slots; a weight with an AMP operand copy is
         bound to the copy (the float32 parameter stays declared: it is
@@ -1055,9 +1126,6 @@ class GenerationEngine:
         self.metrics.inc("moe_layer_calls", int(counts.shape[0]))
         self.metrics.inc("moe_kernel_layer_calls", int(
             counts.shape[0]) if self._experts_on_kernel(rows) else 0)
-        if self.spec.expert_latent:
-            # token rows through the latent down- and up-projection
-            self.metrics.inc("moe_latent_rows", rows * int(counts.shape[0]))
         self.metrics.inc("moe_dropped_tokens",
                          rows * self.spec.experts_per_tok
                          * int(counts.shape[0]) - took)
@@ -1498,6 +1566,15 @@ class GenerationEngine:
         tail = prompt[n_full * ps:]
         if include_tail and done and tail.size:
             insert(key, tail, n_full)
+
+    def _register_unit_prefix(self, st: _Slot) -> None:
+        """``_register_prefix`` after a prefill unit, the index walk alone
+        under the span ``serving/register_prefix`` (it re-walks every full
+        page the prompt holds so far, after every chunk: the benchmark's
+        ``prefix_register_ms``); nothing where the index is off."""
+        if self.prefix_index is not None:
+            with trace.span("serving/register_prefix"):
+                self._register_prefix(st)
 
     def _pages_in_flight(self, prompt: np.ndarray, shared: int) -> List[int]:
         """The pages some PREFILLING slot holds for full pages of
@@ -1978,15 +2055,19 @@ class GenerationEngine:
         self.metrics.inc("requests_resumed")
 
     def _run_prefill_group(self, group) -> None:
-        """One bucketed prefill call over freshly-admitted requests whose
-        unshared remainder fits a single chunk (mixed prefix offsets ride
-        the per-row StartPos plane). A group beyond the largest warm
-        batch bucket splits into bucket-sized calls."""
+        """Prefill freshly-admitted requests whose unshared remainder
+        fits a single chunk: one bucketed call, or bucket-sized calls
+        where the group is beyond the largest warm batch bucket. Each
+        call is one unit of the pass (``PassAccount``)."""
         cap = self.prefill_batch_buckets[-1]
-        if len(group) > cap:
-            for i in range(0, len(group), cap):
-                self._run_prefill_group(group[i:i + cap])
-            return
+        self._pass.group(-(-len(group) // cap))
+        for i in range(0, len(group), cap):
+            self._prefill_group_call(group[i:i + cap])
+
+    def _prefill_group_call(self, group) -> None:
+        """One bucketed prefill call over ``group``, at most the largest
+        batch bucket's rows (mixed prefix offsets ride the per-row
+        StartPos plane)."""
         with trace.span("serving/build_feed", phase="prefill_group"):
             rem = [st.prompt.size - st.prefill_done for _, st, _ in group]
             tc = self._chunk_bucket_for(max(rem))
@@ -2015,28 +2096,28 @@ class GenerationEngine:
         prog, outs = self._prefill_prog(tc)
         t0 = time.perf_counter()
         with trace.span("serving/prefill_group", rows=len(group),
-                        bucket=bucket, tokens=tc), \
-                self._mamba_unit_span(bucket, tc):
+                        bucket=bucket, tokens=tc):
             res = self.executor.run(prog, feed=feed,
                                     fetch_list=self._fetches(outs),
                                     scope=self.scope)
         t1 = time.perf_counter()
-        self._count_experts(res, bucket * tc)
-        first, topv, topi = self._tokens_of(res)
-        self.metrics.observe_latency(t1 - t0, name="prefill")
-        self.metrics.inc("prefills")
-        self.metrics.set_gauge("prefill_occupancy", len(group) / bucket)
-        for row, (req, st, slot) in enumerate(group):
-            if req.span is not None:
-                trace.record("serving/execute", t0, t1, parent=req.span,
-                             phase="prefill", slot=slot,
-                             prompt_len=int(st.prompt.size),
-                             prompt_bucket=tc, batch_bucket=bucket)
-            st.timeline.chunk(t0, t1, rem[row])
-            st.prefill_done = st.prompt.size
-            self._register_prefix(st)
-            self._snapshot_done(st, plans[row])
-            self._prefilled(slot, row, first, topv, topi)
+        with trace.span("serving/after_unit"):
+            self._count_experts(res, bucket * tc)
+            first, topv, topi = self._tokens_of(res)
+            self.metrics.observe_latency(t1 - t0, name="prefill")
+            self.metrics.inc("prefills")
+            self.metrics.set_gauge("prefill_occupancy", len(group) / bucket)
+            for row, (req, st, slot) in enumerate(group):
+                if req.span is not None:
+                    trace.record("serving/execute", t0, t1, parent=req.span,
+                                 phase="prefill", slot=slot,
+                                 prompt_len=int(st.prompt.size),
+                                 prompt_bucket=tc, batch_bucket=bucket)
+                st.timeline.chunk(t0, t1, rem[row])
+                st.prefill_done = st.prompt.size
+                self._register_unit_prefix(st)
+                self._snapshot_done(st, plans[row])
+                self._prefilled(slot, row, first, topv, topi)
 
     def _prefilled(self, slot: int, row: int, first, topv, topi) -> None:
         """The prompt of ``slot`` is cached whole; row ``row`` of the call
@@ -2102,6 +2183,7 @@ class GenerationEngine:
                 break
             self._deferred.popleft()
             if group:
+                self._pass.deferred = True
                 self._run_prefill_group(group)
             if r == "ok":
                 admitted += 1
@@ -2185,26 +2267,30 @@ class GenerationEngine:
         tokens): the tokens-per-tick budget that keeps decode latency
         flat while a long prompt streams in. Round-robin across
         prefilling slots; returns True when a chunk ran."""
-        order = [(self._pf_cursor + i) % self.slots
-                 for i in range(self.slots)]
-        slot = None
-        for i in order:
-            if self._slots[i] is None or self._slots[i].state != "prefill":
-                continue
-            # (a slot with state first moves to whatever boundary another
-            # slot has cached for it, then sits out while one is ahead)
-            self._adopt_prefilled(self._slots[i])
-            if not (self._snap_block and self._waits_for_prefix(i)):
-                slot = i
-                break
-        if slot is None:
-            return False
-        self._pf_cursor = (slot + 1) % self.slots
-        st = self._slots[slot]
-        plen = int(st.prompt.size)
-        start0 = st.prefill_done
-        k = min(self.prefill_chunk, plen - start0)
-        tc = self._chunk_bucket_for(k)
+        with trace.span("serving/prefill_pick"):
+            order = [(self._pf_cursor + i) % self.slots
+                     for i in range(self.slots)]
+            slot = None
+            for i in order:
+                if (self._slots[i] is None
+                        or self._slots[i].state != "prefill"):
+                    continue
+                # (a slot with state first moves to whatever boundary
+                # another slot has cached for it, then sits out while one
+                # is ahead)
+                self._adopt_prefilled(self._slots[i])
+                if not (self._snap_block and self._waits_for_prefix(i)):
+                    slot = i
+                    break
+            if slot is None:
+                return False
+            self._pf_cursor = (slot + 1) % self.slots
+            st = self._slots[slot]
+            plen = int(st.prompt.size)
+            start0 = st.prefill_done
+            k = min(self.prefill_chunk, plen - start0)
+            tc = self._chunk_bucket_for(k)
+        self._pass.chunks += 1
         with trace.span("serving/build_feed", phase="prefill_chunk"):
             bucket = self._batch_bucket_for(1)
             arr, cols = self._plane(tc).new(bucket)
@@ -2223,27 +2309,27 @@ class GenerationEngine:
         prog, outs = self._prefill_prog(tc)
         t0 = time.perf_counter()
         with trace.span("serving/prefill_chunk", slot=slot,
-                        offset=start0, tokens=k), \
-                self._mamba_unit_span(bucket, tc):
+                        offset=start0, tokens=k):
             res = self.executor.run(prog, feed=feed,
                                     fetch_list=self._fetches(outs),
                                     scope=self.scope)
         t1 = time.perf_counter()
-        self._count_experts(res, bucket * tc)
-        self.metrics.observe_latency(t1 - t0, name="prefill_chunk")
-        self.metrics.inc("prefill_chunks")
-        st.timeline.chunk(t0, t1, k)
-        if st.request.span is not None:
-            trace.record("serving/execute", t0, t1,
-                         parent=st.request.span, phase="prefill_chunk",
-                         slot=slot, offset=start0, tokens=k)
-        st.prefill_done = start0 + k
-        self._register_prefix(st)       # page by page, as they fill
-        self._snapshot_done(st, plan)
-        if st.prefill_done >= plen:
-            self.metrics.inc("prefills")
-            self._prefilled(slot, 0, *self._tokens_of(res))
-            self._gauges()
+        with trace.span("serving/after_unit"):
+            self._count_experts(res, bucket * tc)
+            self.metrics.observe_latency(t1 - t0, name="prefill_chunk")
+            self.metrics.inc("prefill_chunks")
+            st.timeline.chunk(t0, t1, k)
+            if st.request.span is not None:
+                trace.record("serving/execute", t0, t1,
+                             parent=st.request.span, phase="prefill_chunk",
+                             slot=slot, offset=start0, tokens=k)
+            st.prefill_done = start0 + k
+            self._register_unit_prefix(st)      # page by page, as they fill
+            self._snapshot_done(st, plan)
+            if st.prefill_done >= plen:
+                self.metrics.inc("prefills")
+                self._prefilled(slot, 0, *self._tokens_of(res))
+                self._gauges()
         return True
 
     def _decode_feed(self) -> CallFeed:
@@ -2358,49 +2444,52 @@ class GenerationEngine:
         out — their block tables are mid-write; a pool-parked beam job's
         slots wait in ``beam_wait``). One compiled step, same shape
         regardless of occupancy or policy mix."""
-        decoding = [s for s in range(self.slots)
-                    if self._slots[s] is not None
-                    and self._slots[s].state == "decode"]
-        if not decoding:
-            return False
-        self._cow_guard(decoding)
+        with trace.span("serving/cow_guard"):   # and the rows it guards
+            decoding = [s for s in range(self.slots)
+                        if self._slots[s] is not None
+                        and self._slots[s].state == "decode"]
+            if not decoding:
+                return False
+            self._cow_guard(decoding)
         t0 = time.perf_counter()
         with trace.span("serving/decode_step", active=len(decoding),
                         **({"positions": 2} if self._draft else {})):
             nxt, topv, topi = self._run_decode()
-        self.metrics.observe_latency(time.perf_counter() - t0,
-                                     name="decode_step")
-        self.metrics.inc("decode_steps")
-        if not self._draft:
-            self.metrics.inc("decode_tokens", len(decoding))
-        self.metrics.set_gauge("batch_occupancy",
-                               len(decoding) / self.slots)
-        beam_rows: Dict[BeamJob, dict] = {}
-        parent_rows = []  # (job, slot) — full-prefix-hit first rows
-        for slot in decoding:
-            st = self._slots[slot]
-            if st is None:
-                continue
-            if st.beam_job is not None:
-                if st.role == "beam_parent":
-                    st.role = "beam"
-                    parent_rows.append((st.beam_job, slot))
-                else:
-                    beam_rows.setdefault(st.beam_job, {})[slot] = (
-                        topv[slot], topi[slot])
-                continue
-            if self._draft:
-                self._emit_verified(slot, st, nxt[slot])
-                continue
-            self._pos[slot] += 1
-            self._tok[slot] = nxt[slot]
-            self._emit(slot, int(nxt[slot]))
-        for job, slot in parent_rows:
-            job.on_parent_row(topv[slot], topi[slot])
-        for job, rows in beam_rows.items():
-            job.on_decode_rows(rows)
-        self._maybe_replica_kill()
-        self._gauges()
+        t1 = time.perf_counter()
+        with trace.span("serving/after_tick"):
+            self.metrics.observe_latency(t1 - t0, name="decode_step")
+            self.metrics.inc("decode_steps")
+            self._pass.rows = len(decoding)
+            if not self._draft:
+                self.metrics.inc("decode_tokens", len(decoding))
+            self.metrics.set_gauge("batch_occupancy",
+                                   len(decoding) / self.slots)
+            beam_rows: Dict[BeamJob, dict] = {}
+            parent_rows = []  # (job, slot) — full-prefix-hit first rows
+            for slot in decoding:
+                st = self._slots[slot]
+                if st is None:
+                    continue
+                if st.beam_job is not None:
+                    if st.role == "beam_parent":
+                        st.role = "beam"
+                        parent_rows.append((st.beam_job, slot))
+                    else:
+                        beam_rows.setdefault(st.beam_job, {})[slot] = (
+                            topv[slot], topi[slot])
+                    continue
+                if self._draft:
+                    self._emit_verified(slot, st, nxt[slot])
+                    continue
+                self._pos[slot] += 1
+                self._tok[slot] = nxt[slot]
+                self._emit(slot, int(nxt[slot]))
+            for job, slot in parent_rows:
+                job.on_parent_row(topv[slot], topi[slot])
+            for job, rows in beam_rows.items():
+                job.on_decode_rows(rows)
+            self._maybe_replica_kill()
+            self._gauges()
         return True
 
     def _emit_verified(self, slot: int, st: _Slot, row) -> None:
@@ -2858,8 +2947,11 @@ class GenerationEngine:
                                       wait_s=idle_wait_s)
             if not reqs:
                 return False
-        with trace.span("serving/pass", active=self.active):
-            did = self._beam_maintenance()
+        with trace.span("serving/pass", active=self.active), self._pass:
+            did = False
+            if self._beam_jobs:
+                with trace.span("serving/beam_maintenance"):
+                    did = self._beam_maintenance()
             with trace.span("serving/admit"):
                 did = self._admit_deferred() > 0 or did
                 free = self.free_slots
@@ -2876,14 +2968,15 @@ class GenerationEngine:
         in-process analogue of a loaded server, beam jobs included)."""
         pending = list(reqs)
         while pending or self.active or self._deferred or self._beam_jobs:
-            if pending and self.free_slots and not self._deferred:
-                k = min(len(pending), self.free_slots)
-                self.admit(pending[:k])
-                pending = pending[k:]
-            self._beam_maintenance()
-            self._admit_deferred()
-            self.prefill_tick()
-            self.decode_tick()
+            with self._pass:    # counted as a loaded server's pass is
+                if pending and self.free_slots and not self._deferred:
+                    k = min(len(pending), self.free_slots)
+                    self.admit(pending[:k])
+                    pending = pending[k:]
+                self._beam_maintenance()
+                self._admit_deferred()
+                self.prefill_tick()
+                self.decode_tick()
 
     def generate_all(self, prompts: Sequence[Sequence[int]],
                      max_new_tokens: Optional[int] = None,

@@ -291,31 +291,23 @@ def test_the_engine_counts_the_state_and_the_latent(served):
     assert c["kv_bytes_held_ticks"] > 0
     # four expert layers a call, every row through the latent twice
     assert c["moe_layer_calls"] % 4 == 0 and c["moe_dropped_tokens"] == 0
-    assert c["moe_latent_rows"] * 3 == c["moe_assignments"]
     assert c["moe_held_assignments"] + c["moe_absent_assignments"] \
         == c["moe_assignments"]
 
 
 def test_a_prefill_call_is_spanned_as_a_mamba_unit():
-    """With the tracer on, a prefill call of this spec carries the span
-    ``serving/mamba_prefill_unit`` with the SSD blocks it scans (one
-    16-token call over the 4 mamba layers in blocks of 8)."""
-    from paddle_tpu import trace
-
+    """A prefill call of this spec counts the SSD blocks it scans in
+    ``mamba_chunks`` (one 16-token call over the 4 mamba layers in blocks
+    of 8: 8), and the pass that ran it holds that one unit. (A span of
+    its own round the call said the same and had no reader: PR 56 took it
+    out; the test keeps its name so that its id stays.)"""
     pt.set_amp(False)
     eng = _engine(beam=False)
-    tracer = trace.get_tracer()
-    tracer.clear()
-    trace.enable(level=1)
-    try:
-        eng.generate_all([_prompt(2, 11)], max_new_tokens=3)
-        got = [s.attrs for s in tracer.spans()
-               if s.name == "serving/mamba_prefill_unit"]
-    finally:
-        trace.disable()
-        tracer.clear()
-    assert [(a["rows"], a["tokens"], a["chunks"]) for a in got] == [
-        (1, 16, 8)]
+    eng.generate_all([_prompt(2, 11)], max_new_tokens=3)
+    c = eng.metrics.snapshot()["counters"]
+    assert c["mamba_chunks"] == 8
+    assert c["prefills"] == 1 and c["pass_units"] == 1
+    assert c["pass_rows_one_unit"] == 1
 
 
 def test_a_slots_second_tenant_starts_from_zero():

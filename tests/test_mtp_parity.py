@@ -59,6 +59,10 @@ def tiny_config():
 
 @pytest.fixture(scope="module")
 def model():
+    return build_model()
+
+
+def build_model():
     """(config, weights by name): seeded, the embedding scaled, the
     drafting block started as the configuration says, and the norm scales
     moved off 1 so that a norm too many or too few shows."""

@@ -214,6 +214,26 @@ class TestNmtDecode:
         assert eng.pool.pages_in_use() == 0
         assert int(eng._xrow_ref.sum()) == 0
 
+    def test_a_deferred_admission_completes_token_exact(self):
+        """A pool too small for the sources at once defers admissions
+        (``_admit_deferred`` runs the deferred request's group through
+        THIS engine's ``_run_prefill_group``, which flushes the encodes
+        first): every source still translates, token-exact vs the roomy
+        engine, and the pages and cross rows come back."""
+        rng = np.random.RandomState(23)
+        srcs = [rng.randint(2, VS, (n,)).astype("int64")
+                for n in (5, 7, 6)]
+        want = _shared_engine().translate(srcs, max_new_tokens=5)
+        eng = Seq2SeqGenerationEngine(
+            _spec(), _nmt_scope()[0], slots=4, page_size=4, bos_id=BOS,
+            beam_width=1, n_pages=3)
+        got = eng.translate(srcs, max_new_tokens=5)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+        assert eng.metrics.counter("admission_deferred") > 0
+        assert eng.pool.pages_in_use() == 0
+        assert int(eng._xrow_ref.sum()) == 0
+
     def test_cross_kv_priced_by_memplan(self):
         """The analysis plane prices the cross-KV slot cache: the
         engine-scope decode target's resident bytes cover the page pool
