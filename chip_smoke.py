@@ -176,8 +176,8 @@ def mosaic_calls(hlo_text):
     """[(kernel, [operand type, ...])] of a compiled module's Mosaic call
     instructions, under the names its trace shows: ``%flash_fwd.16 = ...
     custom-call(...), custom_call_target="tpu_custom_call",
-    operand_layout_constraints={s32[128]{0}, bf16[128,1024,64]{2,1,0},
-    ...}`` -> ("flash_fwd", ["s32[128]", "bf16[128,1024,64]", ...])."""
+    operand_layout_constraints={s32[8]{0}, bf16[8,1024,1024]{2,1,0},
+    ...}`` -> ("flash_fwd", ["s32[8]", "bf16[8,1024,1024]", ...])."""
     return [(name, re.findall(r"(\w+\[[\d,]*\])", operands))
             for name, operands in _MOSAIC_CALL.findall(hlo_text)]
 
@@ -194,14 +194,15 @@ def compiled_step_loops_and_kernels(tr):
     return loops, calls
 
 
-def flash_operands_not_bf16(calls, rows, seq, d_head):
-    """The flash calls among ``calls`` (``mosaic_calls``) whose
-    ``[rows, seq, d_head]`` operands (q, k, v and, in the backward, dO) are
-    not all bf16, or are not all there: what AMP must leave empty."""
-    want = f"bf16[{rows},{seq},{d_head}]"
+def flash_operands_not_bf16(calls, batch, seq, width):
+    """The flash calls among ``calls`` (``mosaic_calls``) whose packed
+    ``[batch, seq, heads * d_head]`` operands (q, k, v and, in the
+    backward, dO) are not all bf16, or are not all there: what AMP must
+    leave empty."""
+    want = f"bf16[{batch},{seq},{width}]"
     return [(name, types) for name, types in calls
             if name.startswith("flash_")
-            and [t for t in types if t.endswith(f"[{rows},{seq},{d_head}]")]
+            and [t for t in types if t.endswith(f"[{batch},{seq},{width}]")]
             != [want] * (3 if name == "flash_fwd" else 4)]
 
 
@@ -250,10 +251,10 @@ def phase_train(cfg):
               "second is remat running the kernel again), one flash_dq, "
               "one flash_dkv")
         f32_fed = flash_operands_not_bf16(
-            calls, cfg["batch"] * cfg["heads"], cfg["seq"],
-            cfg["d_model"] // cfg["heads"])
+            calls, cfg["batch"], cfg["seq"], cfg["d_model"])
         check(not f32_fed, "under AMP every flash call takes bf16 "
-              f"[rows, T, d_head] operands; these do not: {f32_fed}")
+              f"[batch, T, heads * d_head] operands; these do not: "
+              f"{f32_fed}")
     emit("lm_train", t0, steps=len(losses),
          first_loss=round(losses[0], 4), last_loss=round(losses[-1], 4),
          loss_margin=cfg["loss_margin"],
@@ -512,13 +513,13 @@ def _state_bytes_per_device(scope, devices):
 
 
 def _local_kernel_batches(tr):
-    """Batch*heads extent of every Mosaic call's first operand in the
-    COMPILED (partitioned) train step: the kernel must see the local
-    shard, not the all-gathered batch."""
+    """Batch extent of every Mosaic call's first operand in the COMPILED
+    (partitioned) train step: the kernel must see the local shard, not
+    the all-gathered batch."""
     found = set()
     for c in tr.sgd.exe._cache.values():
-        # every flash kernel's first operand is the [batch*heads] lengths
-        # vector the scalar prefetch reads
+        # every flash kernel's first operand is the [batch] lengths vector
+        # the scalar prefetch reads
         found.update(int(n) for n in re.findall(
             r'custom_call_target="tpu_custom_call", '
             r'operand_layout_constraints=\{s32\[(\d+)\]',
@@ -565,11 +566,11 @@ def phase_multichip(cfg, ref_first_loss, model_dir):
             check(all(s and s["bytes_in_use"] > 0 for s in stats),
                   f"{name}: a chip holds no memory")
             bh = _local_kernel_batches(tr)
-            local = cfg["batch"] // axes["dp"] * cfg["heads"]
+            local = cfg["batch"] // axes["dp"]
             check(bh and max(bh) <= local,
-                  f"{name}: flash kernel runs on batch*heads {bh}, local "
+                  f"{name}: flash kernel runs on batch {bh}, local "
                   f"shard is {local} — GSPMD gathered the batch")
-            leg["kernel_batch_heads"] = bh
+            leg["kernel_batch"] = bh
         executors.append(tr.sgd.exe)
         del tr
         gc.collect()

@@ -22,6 +22,7 @@ from jax.ad_checkpoint import checkpoint_name
 from .. import trace
 from ..core.registry import register_op
 from ..kernels.flash_attention import (RESIDUAL_NAMES, flash_attention,
+                                       flash_attention_packed, lane_block,
                                        rotary)
 from ..lm_spec import (DRAFT_PLANES, DRAFT_SLOT_PREFIX, DRAFT_SLOTS,
                        OPTIONAL_STACK_SLOTS, SNAPSHOT_SLOTS, STATE_SLOTS,
@@ -106,12 +107,23 @@ def _block(blk, p, x, causal, rope=None):
     for a dense FFN, (counts [E], router prob mean [E]) for experts.
     ``rope``: whether THIS layer rotates q / k (a ``layer_pattern``;
     None: as ``blk.use_rope`` says). A window layer is the causal block
-    here: the callers hold T to the window."""
-    b, T, _ = x.shape
-    q, k, v = _attn_proj(blk, p, x, rope=rope)
-    k, v = _expand_kv(k, v, blk.num_heads)
-    ctx = flash_attention(q, k, v, causal=causal, sm_scale=_sm_scale(blk))
-    ctx = ctx.transpose(0, 2, 1, 3).reshape(b, T, -1)
+    here: the callers hold T to the window. Heads the kernels have a lane
+    block for (``lane_block``: a width that divides or is divided by 128)
+    stay PACKED on the minor axis from the qkv projection's row to the
+    out-projection's operand: no [b, H, T, dh] array, no transpose."""
+    b, T, d = x.shape
+    packed = (not blk.is_mla
+              and lane_block(blk.num_heads, blk.dh(d)) is not None)
+    q, k, v = _attn_proj(blk, p, x, rope=rope, heads_first=not packed)
+    k, v = _expand_kv(k, v, blk.num_heads, axis=2 if packed else 1)
+    if packed:
+        ctx = flash_attention_packed(
+            *(a.reshape(b, T, -1) for a in (q, k, v)), blk.num_heads,
+            causal=causal)
+    else:
+        ctx = flash_attention(q, k, v, causal=causal,
+                              sm_scale=_sm_scale(blk))
+        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, T, -1)
     return _attn_out_ffn(blk, p, x, ctx)
 
 
@@ -161,8 +173,10 @@ def _hold_to_window(blk, T, who):
             "decode ops serve longer contexts")
 
 
-def _attn_proj(blk, p, h, pos0=0, rope=None):
-    """norm 1 + qkv projection -> q [b, H, t, dh], k/v [b, Hkv, t, dh].
+def _attn_proj(blk, p, h, pos0=0, rope=None, heads_first=True):
+    """norm 1 + qkv projection -> q [b, H, t, dh], k/v [b, Hkv, t, dh]
+    (``heads_first`` False: [b, t, H, dh] and [b, t, Hkv, dh], the views
+    of the projection's row as it is, nothing transposed).
     Hkv < H is grouped-query attention: the stacked qkv weight is
     [L, d, d + 2*Hkv*dh] and the KV planes (and decode caches) shrink by
     H/Hkv. ``qk_norm``: RMSNorm over the WHOLE q and k vectors before the
@@ -189,13 +203,17 @@ def _attn_proj(blk, p, h, pos0=0, rope=None):
         k = _rms(k, p["k_norm_s"], blk.norm_eps)
 
     def heads(a, n):
-        return a.reshape(b, t, n, head_d).transpose(0, 2, 1, 3)
+        a = a.reshape(b, t, n, head_d)
+        return a.transpose(0, 2, 1, 3) if heads_first else a
 
     q, k, v = (heads(q, num_heads), heads(k, num_kv_heads),
                heads(v, num_kv_heads))
     if blk.use_rope if rope is None else rope:
-        q = rotary(q, pos0, blk.rope_theta, blk.rope_pairing)
-        k = rotary(k, pos0, blk.rope_theta, blk.rope_pairing)
+        time_axis = 2 if heads_first else 1
+        q = rotary(q, pos0, blk.rope_theta, blk.rope_pairing,
+                   time_axis=time_axis)
+        k = rotary(k, pos0, blk.rope_theta, blk.rope_pairing,
+                   time_axis=time_axis)
     return q, k, v
 
 
@@ -266,12 +284,12 @@ def _mla_expand(blk, p, c_kv, k_rope):
     return jnp.concatenate([k_nope, k_r], axis=-1), v
 
 
-def _expand_kv(k, v, num_heads):
-    """Broadcast Hkv heads to their H/Hkv query groups."""
-    rep = num_heads // k.shape[1]
+def _expand_kv(k, v, num_heads, axis=1):
+    """Broadcast Hkv heads (on ``axis``) to their H/Hkv query groups."""
+    rep = num_heads // k.shape[axis]
     if rep > 1:
-        k = jnp.repeat(k, rep, axis=1)
-        v = jnp.repeat(v, rep, axis=1)
+        k = jnp.repeat(k, rep, axis=axis)
+        v = jnp.repeat(v, rep, axis=axis)
     return k, v
 
 

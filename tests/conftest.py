@@ -65,6 +65,36 @@ def fresh_programs():
     common._AMP = common._MXU_PRECISION = common._UNSET
 
 
+@pytest.fixture
+def pallas_path(monkeypatch):
+    """``flash_attention``'s TPU branch on the CPU: the backend reads
+    "tpu" and the three kernels run in interpret mode. -> the inner calls
+    made, as (pass, operand dtypes, preferred blocks)."""
+    from paddle_tpu.kernels import flash_attention as fa
+
+    calls = []
+    forward, backward = fa._flash_forward, fa._flash_backward
+
+    def fwd(q, k, v, lengths, causal, sm_scale, block_q, block_k,
+            interpret, **kw):
+        calls.append(("fwd", {a.dtype for a in (q, k, v)},
+                      (block_q, block_k)))
+        return forward(q, k, v, lengths, causal, sm_scale, block_q, block_k,
+                       interpret=True, **kw)
+
+    def bwd(q, k, v, o, lse, lengths, g, causal, sm_scale, block_q,
+            block_k, interpret, **kw):
+        calls.append(("bwd", {a.dtype for a in (q, k, v, o, g)},
+                      (block_q, block_k)))
+        return backward(q, k, v, o, lse, lengths, g, causal, sm_scale,
+                        block_q, block_k, interpret=True, **kw)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "_flash_forward", fwd)
+    monkeypatch.setattr(fa, "_flash_backward", bwd)
+    return calls
+
+
 # ---------------------------------------------------------------------------
 # Shared virtual-mesh fixtures: ONE mesh object per session instead of a
 # per-test rebuild — sharding tests that only need "the 8 CPU devices,

@@ -55,43 +55,72 @@ class TestFlashAttention:
         ref = naive_attention(q, k, v, lengths=lengths)
         np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
 
-    def test_pallas_kernel_interpret_matches(self):
+    # (H, d_head) -> heads a lane block: the [B * H, T, D] rows of the
+    # unpacked entry (one head a row, the full minor axis), then packed
+    # [b, T, H * d] rows: a row narrower than the lanes (one block of all
+    # its heads), d_head 32 (four a block), 64 (two), 128 (one)
+    LAYOUTS = [("rows", 2, 8), ("packed", 2, 8), ("packed", 8, 32),
+               ("packed", 4, 64), ("packed", 2, 128)]
+
+    @staticmethod
+    def _kernel_operands(layout, *arrays):
+        """[B, H, T, D] arrays as the kernels take them, and the heads a
+        row holds."""
+        B, H, T, D = arrays[0].shape
+        if layout == "rows":
+            return [jnp.asarray(a).reshape(B * H, T, D) for a in arrays], 1
+        return [_pack(jnp.asarray(a)) for a in arrays], H
+
+    @staticmethod
+    def _heads_first(layout, a, shape):
+        """A kernel result back as [B, H, T, D]."""
+        a = np.asarray(a)
+        return a.reshape(shape) if layout == "rows" else _unpack(a, shape[1])
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("layout,H,D", LAYOUTS)
+    def test_pallas_kernel_interpret_matches(self, layout, H, D, causal):
         """Run the actual Pallas kernel in interpret mode on CPU."""
-        q, k, v = self._rand(B=1, H=2, T=32, D=8, seed=3)
-        lengths = np.array([25], np.int32)
+        q, k, v = self._rand(B=2, H=H, T=32, D=D, seed=3)
+        lengths = np.array([25, 32], np.int32)
+        (qj, kj, vj), heads = self._kernel_operands(layout, q, k, v)
+        assert (fa.lane_block(H, D) if heads > 1 else D) == {
+            8: 8 * heads, 32: 128, 64: 128, 128: 128}[D]
         out, lse = fa._flash_forward(
-            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
-            jnp.asarray(lengths), True, 1.0 / math.sqrt(8),
-            block_q=16, block_k=8, interpret=True)
-        got = np.asarray(out)
-        ref = naive_attention(q, k, v, lengths=lengths, causal=True)
+            qj, kj, vj, jnp.asarray(lengths), causal, 1.0 / math.sqrt(D),
+            block_q=16, block_k=8, interpret=True, num_heads=heads)
+        assert lse.shape == (2 * H, 1, 32)
+        got = self._heads_first(layout, out, q.shape)
+        ref = naive_attention(q, k, v, lengths=lengths, causal=causal)
         np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
 
-    def test_pallas_backward_interpret_matches(self):
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("layout,H,D", LAYOUTS)
+    def test_pallas_backward_interpret_matches(self, layout, H, D, causal):
         """The Pallas dq/dkv backward kernels in interpret mode vs the
         reference vjp — multi-block grids (bq != bk) with causal masking
         and padded lengths, so the block-skip bounds are exercised."""
-        q, k, v = self._rand(B=2, H=2, T=64, D=8, seed=7)
+        q, k, v = self._rand(B=2, H=H, T=64, D=D, seed=7)
         lengths = np.array([64, 40], np.int32)
-        sm = 1.0 / math.sqrt(8)
-        qj, kj, vj = (jnp.asarray(t) for t in (q, k, v))
+        sm = 1.0 / math.sqrt(D)
+        g = np.random.RandomState(9).randn(*q.shape).astype(np.float32)
+        (qj, kj, vj, gj), heads = self._kernel_operands(layout, q, k, v, g)
         lj = jnp.asarray(lengths)
-        out, lse = fa._flash_forward(qj, kj, vj, lj, True, sm,
-                                     block_q=16, block_k=8, interpret=True)
-        g = jnp.asarray(np.random.RandomState(9).randn(*out.shape)
-                        .astype(np.float32))
-        dq, dk, dv = fa._flash_backward(qj, kj, vj, out, lse, lj, g, True,
-                                        sm, 16, 8, interpret=True)
+        out, lse = fa._flash_forward(qj, kj, vj, lj, causal, sm, block_q=16,
+                                     block_k=8, interpret=True,
+                                     num_heads=heads)
+        got = fa._flash_backward(qj, kj, vj, out, lse, lj, gj, causal, sm,
+                                 16, 8, interpret=True, num_heads=heads)
 
         def f(q, k, v):
-            return fa.reference_attention(q, k, v, lengths=lj, causal=True,
+            return fa.reference_attention(q, k, v, lengths=lj, causal=causal,
                                           sm_scale=sm)
 
-        _, vjp = jax.vjp(f, qj, kj, vj)
-        rq, rk, rv = vjp(g)
-        for name, a, b in (("dq", dq, rq), ("dk", dk, rk), ("dv", dv, rv)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-4, atol=2e-4, err_msg=name)
+        _, vjp = jax.vjp(f, *(jnp.asarray(a) for a in (q, k, v)))
+        for name, a, b in zip(("dq", "dk", "dv"), got, vjp(jnp.asarray(g))):
+            np.testing.assert_allclose(
+                self._heads_first(layout, a, q.shape), np.asarray(b),
+                rtol=2e-4, atol=2e-4, err_msg=name)
 
     def test_gradients_flow(self):
         q, k, v = self._rand(B=1, H=1, T=8, D=4, seed=4)
@@ -121,38 +150,32 @@ class TestFlashAttention:
 BF16_TOL = 2.0 ** -6
 
 
-@pytest.fixture
-def pallas_path(monkeypatch):
-    """``flash_attention``'s TPU branch on the CPU: the backend reads
-    "tpu" and the three kernels run in interpret mode. -> the inner calls
-    made, as (pass, operand dtypes, preferred blocks)."""
-    calls = []
-    forward, backward = fa._flash_forward, fa._flash_backward
-
-    def fwd(q, k, v, lengths, causal, sm_scale, block_q, block_k,
-            interpret):
-        calls.append(("fwd", {a.dtype for a in (q, k, v)},
-                      (block_q, block_k)))
-        return forward(q, k, v, lengths, causal, sm_scale, block_q, block_k,
-                       interpret=True)
-
-    def bwd(q, k, v, o, lse, lengths, g, causal, sm_scale, block_q,
-            block_k, interpret):
-        calls.append(("bwd", {a.dtype for a in (q, k, v, o, g)},
-                      (block_q, block_k)))
-        return backward(q, k, v, o, lse, lengths, g, causal, sm_scale,
-                        block_q, block_k, interpret=True)
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(fa, "_flash_forward", fwd)
-    monkeypatch.setattr(fa, "_flash_backward", bwd)
-    return calls
-
-
 def _qkvg(T, D=64, B=2, H=1, seed=0):
     rng = np.random.RandomState(seed)
     return tuple(jnp.asarray(rng.randn(B, H, T, D).astype(np.float32))
                  for _ in range(4))
+
+
+def _pack(a):
+    """[B, H, T, D] -> [B, T, H * D]: heads packed on the minor axis."""
+    B, H, T, D = a.shape
+    return a.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+
+
+def _unpack(a, H):
+    """[B, T, H * D] -> [B, H, T, D]."""
+    B, T, _ = a.shape
+    return a.reshape(B, T, H, -1).transpose(0, 2, 1, 3)
+
+
+def _packed_out_and_grads(q, k, v, g, **kw):
+    """``flash_attention_packed`` on packed copies of [B, H, T, D]
+    arrays: (o, dq, dk, dv) back as [B, H, T, D]."""
+    H = q.shape[1]
+    got = _out_and_grads(
+        lambda q, k, v: fa.flash_attention_packed(q, k, v, H, **kw),
+        _pack(q), _pack(k), _pack(v), _pack(g))
+    return tuple(_unpack(a, H) for a in got)
 
 
 def _out_and_grads(fn, q, k, v, g):
@@ -166,19 +189,26 @@ class TestFlashBf16:
     @pytest.mark.parametrize("T", [1024, 2048, 640, 200])
     @pytest.mark.parametrize("masking", ["causal", "lengths",
                                          "causal+lengths"])
+    @pytest.mark.parametrize("entry", ["heads", "packed"])
     def test_kernels_on_bf16_match_float32_reference(self, pallas_path, T,
-                                                     masking):
+                                                     masking, entry):
         """The three Pallas kernels (interpret mode) as AMP runs them —
         bf16 operands, at the blocks ``_pick_block`` returns for the
-        shape — against the float32 reference: o, dq, dk, dv."""
-        q, k, v, g = _qkvg(T, seed=T)
+        shape — against the float32 reference: o, dq, dk, dv. ``packed``:
+        two heads of 64, ONE 128-lane block, through
+        ``flash_attention_packed``."""
+        q, k, v, g = _qkvg(T, H=1 if entry == "heads" else 2, seed=T)
         causal = "causal" in masking
         lengths = (jnp.asarray([T, T - T // 3], jnp.int32)
                    if "lengths" in masking else None)
         pt.set_amp(True)    # (conftest's autouse fixture puts it back)
-        got = _out_and_grads(
-            lambda q, k, v: fa.flash_attention(q, k, v, lengths=lengths,
-                                               causal=causal), q, k, v, g)
+        if entry == "packed":
+            got = _packed_out_and_grads(q, k, v, g, lengths=lengths,
+                                        causal=causal)
+        else:
+            got = _out_and_grads(
+                lambda q, k, v: fa.flash_attention(
+                    q, k, v, lengths=lengths, causal=causal), q, k, v, g)
         pt.set_amp(False)
         ref = _out_and_grads(
             lambda q, k, v: fa.reference_attention(q, k, v, lengths=lengths,
@@ -231,8 +261,8 @@ class TestFlashBf16:
         seen = []
         bwd = fa._attention.bwd     # what defvjp registered
         try:
-            fa._attention.bwd = lambda c, s, res, g: (
-                seen.append(g.dtype), bwd(c, s, res, g))[1]
+            fa._attention.bwd = lambda *a: (     # (.., residuals, g)
+                seen.append(a[-1].dtype), bwd(*a))[1]
             jax.vjp(f, q, k, v)[1](g)
         finally:
             fa._attention.bwd = bwd
@@ -277,6 +307,164 @@ class TestFlashBf16:
                         _out_and_grads(parent, q, k, v, g)):
             assert a.dtype == jnp.float32
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+class TestPackedEntry:
+    """``flash_attention_packed``: the same kernels over [b, T, H * d]
+    rows, a lane block of whole heads a grid step."""
+
+    # T 200: padded to 256 on the way in, sliced on the way out
+    @pytest.mark.parametrize("H,D", [(4, 32), (2, 64), (2, 128)])
+    @pytest.mark.parametrize("masking,T", [
+        ("causal", 256), ("plain", 256), ("lengths", 256),
+        ("causal+lengths", 200)])
+    def test_float32_kernels_match_reference(self, pallas_path, H, D,
+                                             masking, T):
+        """AMP off: float32 operands through the interpret kernels at
+        the float32 tolerance, o, dq, dk, dv."""
+        q, k, v, g = _qkvg(T, D=D, H=H, seed=D)
+        causal = "causal" in masking
+        lengths = (jnp.asarray([T, T - T // 3], jnp.int32)
+                   if "lengths" in masking else None)
+        got = _packed_out_and_grads(q, k, v, g, lengths=lengths,
+                                    causal=causal)
+        ref = _out_and_grads(
+            lambda q, k, v: fa.reference_attention(q, k, v, lengths=lengths,
+                                                   causal=causal),
+            q, k, v, g)
+        for name, a, b in zip(("o", "dq", "dk", "dv"), got, ref):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-4, err_msg=name)
+        assert [c[0] for c in pallas_path] == ["fwd", "bwd"]
+
+    @pytest.mark.parametrize("backend", ["reference", "kernels"])
+    def test_gqa_through_expand_kv(self, backend, request):
+        """Hkv < H: ``_expand_kv`` repeats the kv heads on the [b, t, Hkv,
+        dh] view and the packed entry equals the grouped reference, o and
+        the gradients of the UNEXPANDED k / v."""
+        from paddle_tpu.ops.pipeline_ops import _expand_kv
+
+        if backend == "kernels":
+            request.getfixturevalue("pallas_path")
+        B, H, Hkv, T, D = 2, 4, 2, 128, 32
+        rng = np.random.RandomState(11)
+        q, g = (jnp.asarray(rng.randn(B, T, H, D).astype(np.float32))
+                for _ in range(2))
+        k, v = (jnp.asarray(rng.randn(B, T, Hkv, D).astype(np.float32))
+                for _ in range(2))
+
+        def packed(q, k, v):
+            kx, vx = _expand_kv(k, v, H, axis=2)
+            return fa.flash_attention_packed(
+                *(a.reshape(B, T, -1) for a in (q, kx, vx)), H,
+                causal=True).reshape(B, T, H, D)
+
+        def grouped(q, k, v):
+            return fa.reference_attention(
+                *(a.transpose(0, 2, 1, 3) for a in (q, k, v)),
+                causal=True).transpose(0, 2, 1, 3)
+
+        for name, a, b in zip(("o", "dq", "dk", "dv"),
+                              _out_and_grads(packed, q, k, v, g),
+                              _out_and_grads(grouped, q, k, v, g)):
+            assert a.shape == b.shape, name
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-4, err_msg=name)
+
+    @pytest.mark.parametrize("H,D,block", [
+        (16, 64, 128), (8, 128, 128), (4, 256, 256), (32, 32, 128),
+        (4, 8, 32), (16, 8, 128),       # a row no wider than the lanes
+        (2, 96, None), (12, 80, None),  # neither divides nor is divided
+        (3, 64, None)])                 # a row that is not whole blocks
+    def test_lane_block(self, H, D, block):
+        assert fa.lane_block(H, D) == block
+        if block is None:
+            with pytest.raises(ValueError, match="lane block"):
+                fa.flash_attention_packed(
+                    *(jnp.zeros((1, 8, H * D)),) * 3, H)
+
+    @pytest.mark.parametrize("time_axis", [1, 2])
+    @pytest.mark.parametrize("pairing", ["interleaved", "half"])
+    def test_rotary_on_the_projections_view(self, time_axis, pairing):
+        """``rotary(time_axis=1)`` over [B, T, H, D] is ``rotary`` over
+        [B, H, T, D], scalar and per-row offsets."""
+        x = jnp.asarray(np.random.RandomState(5).randn(2, 3, 6, 8)
+                        .astype(np.float32))     # [B, H, T, D]
+        for pos0 in (3, jnp.asarray([0, 5])):
+            want = fa.rotary(x, pos0, pairing=pairing)
+            if time_axis == 1:
+                got = fa.rotary(x.transpose(0, 2, 1, 3), pos0,
+                                pairing=pairing,
+                                time_axis=1).transpose(0, 2, 1, 3)
+            else:
+                got = fa.rotary(x, pos0, pairing=pairing, time_axis=2)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _gpt2_block(d, H, seed=0):
+    """A GPT-2 block of the train stack and its one layer of weights."""
+    from paddle_tpu.lm_spec import Block
+
+    rng = np.random.RandomState(seed)
+    shapes = {"ln1_s": (d,), "ln1_b": (d,), "qkv_w": (d, 3 * d),
+              "out_w": (d, d), "ln2_s": (d,), "ln2_b": (d,),
+              "ff_w1": (d, 2 * d), "ff_b1": (2 * d,), "ff_w2": (2 * d, d),
+              "ff_b2": (d,)}
+    return Block(num_heads=H), {
+        k: jnp.asarray(0.1 * rng.randn(*s).astype(np.float32))
+        for k, s in shapes.items()}
+
+
+def _primitives(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _primitives(sub)
+
+
+class TestTrainBlockLayout:
+    @pytest.mark.parametrize("H,D,packed", [(2, 64, True), (4, 32, True),
+                                            (2, 96, False)])
+    def test_no_head_transpose_between_the_projections(self, pallas_path,
+                                                       H, D, packed):
+        """The train stack's block, TPU branch forced, forward and
+        backward: with a head width the kernels have a lane block for, no
+        rank-4 ``transpose`` stands between the qkv projection and the
+        out projection and the packed entry is the one counted; a head
+        width of 96 takes the [B, H, T, D] entry and its transposes."""
+        from paddle_tpu import profiler
+        from paddle_tpu.ops import pipeline_ops
+
+        blk, p = _gpt2_block(H * D, H)
+        x = jnp.asarray(np.random.RandomState(1).randn(2, 128, H * D)
+                        .astype(np.float32))
+
+        def loss(p, x):
+            return jnp.sum(pipeline_ops._block(blk, p, x, True)[0] ** 2)
+
+        def count(name):
+            return profiler.global_stat.as_dict().get(
+                name, {"total_ms": 0})["total_ms"]
+
+        before = {n: count(n) for n in ("flash/packed_calls",
+                                        "flash/unpacked_calls")}
+        jaxpr = jax.make_jaxpr(jax.grad(loss))(p, x)
+        took = {n: count(n) - before[n] for n in before}
+        rank4 = [e for e in _primitives(jaxpr.jaxpr)
+                 if e.primitive.name == "transpose"
+                 and e.invars[0].aval.ndim == 4]
+        kernels = [e.params["name"] for e in _primitives(jaxpr.jaxpr)
+                   if e.primitive.name == "pallas_call"]
+        assert sorted(kernels) == ["flash_dkv", "flash_dq", "flash_fwd"]
+        if packed:
+            assert not rank4
+            assert took["flash/packed_calls"] >= 1
+            assert took["flash/unpacked_calls"] == 0
+        else:
+            assert rank4
+            assert took["flash/unpacked_calls"] >= 1
+            assert took["flash/packed_calls"] == 0
 
 
 def _executed_share(T, block_q, block_k):

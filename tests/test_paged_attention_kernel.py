@@ -908,8 +908,9 @@ def test_stacked_train_step_on_the_v5e_runs_its_forward_scan_once(
     second time (before core/backward.py paired them) the chip's compiler
     kept three loops and three ``flash_fwd``: XLA does not merge two loops.
     Under AMP, as the train cells run it: every flash call takes bf16
-    ``[rows, T, d_head]`` operands at the blocks the kernel file picks
-    for them, so a block shape Mosaic refuses for bf16 fails HERE.
+    packed ``[batch, T, heads * d_head]`` operands at the blocks the kernel
+    file picks for them, so a block shape Mosaic refuses for bf16 fails
+    HERE.
     (Lives here because one file a worker may describe the topology.)"""
     import chip_smoke
     import paddle_tpu as pt
@@ -967,10 +968,10 @@ def test_stacked_train_step_on_the_v5e_runs_its_forward_scan_once(
     assert sorted(name for name, _ in calls) == [
         "flash_dkv", "flash_dq"] + ["flash_fwd"] * flash_fwd
     assert text.count(" convolution(") == matmuls
-    assert not chip_smoke.flash_operands_not_bf16(calls, B * 16, T, 64)
+    assert not chip_smoke.flash_operands_not_bf16(calls, B, T, 1024)
     # the check can fail: it tells a float32-fed call
     assert chip_smoke.flash_operands_not_bf16(
-        [("flash_fwd", ["s32[32]"] + ["f32[32,1024,64]"] * 3)], 32, T, 64)
+        [("flash_fwd", ["s32[2]"] + ["f32[2,1024,1024]"] * 3)], 2, T, 1024)
     if remat is True:
         # with tracing on the op also asks JAX what a layer's backward
         # holds (the gauge on the compile span: 5 d of bf16 a token a

@@ -115,3 +115,56 @@ def test_stack_remat_policies_match_numerically():
     np.testing.assert_allclose(saved, plain, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(full, plain, rtol=1e-5, atol=1e-6)
     assert plain[-1] < plain[0]
+
+
+@pytest.mark.parametrize("rope", [False, True])
+@pytest.mark.parametrize("backend", ["reference", "kernels"])
+def test_packed_and_heads_first_entries_agree(backend, rope, request,
+                                              monkeypatch):
+    """A two-layer train stack through ``flash_attention_packed`` (what
+    its head width picks) and through ``flash_attention`` over
+    [B, H, T, D] (``lane_block`` made to find no block): the loss and
+    every weight gradient, under ``remat=True`` so that the backward reads
+    the saved residuals (``_STACK_SAVED``) of whichever entry ran. On the
+    reference backend the two are the same arithmetic, bit for bit; the
+    interpret kernels differ by the order of their float32 sums."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.core.registry import get_op
+    from paddle_tpu.lm_spec import Block
+    from paddle_tpu.ops import pipeline_ops
+
+    if backend == "kernels":
+        request.getfixturevalue("pallas_path")
+    n_layers, heads, d, t = 2, 2, 128, 128     # d_head 64: two a lane block
+    blk = Block(num_heads=heads, use_rope=rope)
+    rng = np.random.RandomState(3)
+    shapes = {"ln1_s": (d,), "ln1_b": (d,), "qkv_w": (d, 3 * d),
+              "out_w": (d, d), "ln2_s": (d,), "ln2_b": (d,),
+              "ff_w1": (d, 2 * d), "ff_b1": (2 * d,), "ff_w2": (2 * d, d),
+              "ff_b2": (d,)}
+    params = {k: jnp.asarray(0.1 * rng.randn(n_layers, *s)
+                             .astype(np.float32))
+              for k, s in shapes.items()}
+    x = jnp.asarray(rng.randn(2, t, d).astype(np.float32))
+    attrs = dict(blk.attrs(), causal=True, remat=True)
+    stack = get_op("pipelined_transformer_stack").fn
+
+    def loss(params):
+        ins = {slot: [params[key]]
+               for slot, key in blk.stack_slots().items()}
+        return jnp.sum(stack(attrs, dict(ins, X=[x]))["Out"][0] ** 2)
+
+    assert pipeline_ops.lane_block(heads, d // heads) == 128
+    packed = jax.jit(jax.value_and_grad(loss))(params)
+    monkeypatch.setattr(pipeline_ops, "lane_block", lambda *a: None)
+    heads_first = jax.jit(jax.value_and_grad(loss))(params)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(packed),
+                            jax.tree_util.tree_leaves(heads_first)):
+        a, b = np.asarray(a), np.asarray(b)
+        if backend == "reference":
+            np.testing.assert_array_equal(a, b, err_msg=str(path))
+        else:
+            np.testing.assert_allclose(a, b, rtol=1e-5, err_msg=str(path),
+                                       atol=1e-5 * np.abs(b).max())

@@ -15,6 +15,7 @@ async dispatch — things the CPU mesh cannot exercise.
 Prints one JSON line per check: {"check": name, "ok": bool, "detail": str}.
 Exit code 0 iff every check passed.
 """
+import itertools
 import json
 import os
 import sys
@@ -463,6 +464,57 @@ def flash_attention_d128_matches_reference():
         scale = max(float(jnp.abs(b).max()), 1.0)
         assert err < 2e-2 * scale, (name, err, scale)
     return f"fwd err {err_f:.1e}"
+
+
+@check
+def flash_attention_packed_matches_reference():
+    """``flash_attention_packed`` over [b, T, H * d] rows, fwd+bwd, at the
+    three lane-block cases (d_head 32: four heads a block, 64: two, 128:
+    one), causal with a ``lengths`` mask and a T the pad to 128 serves,
+    vs the jnp reference on the heads-first view; float32 and, under AMP,
+    bf16 operands."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu as pt
+    from paddle_tpu.kernels.flash_attention import (flash_attention_packed,
+                                                    reference_attention)
+
+    worst = 0.0
+    for (H, D), T, amp in itertools.product(
+            ((8, 32), (4, 64), (2, 128)), (1024, 200), (False, True)):
+        rng = np.random.RandomState(D + T)
+        q, k, v, g = (jnp.asarray(rng.randn(2, T, H * D)
+                                  .astype(np.float32) * s)
+                      for s in (0.5, 0.5, 1.0, 1.0))
+        lengths = jnp.asarray([T, T - T // 3], jnp.int32)
+
+        def heads(a):
+            return a.reshape(2, T, H, D).transpose(0, 2, 1, 3)
+
+        def ref(q, k, v):
+            o = reference_attention(heads(q), heads(k), heads(v), lengths,
+                                    True, None)
+            return o.transpose(0, 2, 1, 3).reshape(2, T, H * D)
+
+        def run(fn):
+            o, vjp = jax.vjp(fn, q, k, v)
+            return (o,) + vjp(g)
+
+        pt.set_amp(amp)
+        try:
+            got = jax.jit(lambda: run(
+                lambda q, k, v: flash_attention_packed(
+                    q, k, v, H, lengths=lengths, causal=True)))()
+        finally:
+            pt.set_amp(False)
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(lambda: run(ref))()
+        for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+            assert a.shape == b.shape and a.dtype == b.dtype, name
+            err = float(jnp.abs(a - b).max() / jnp.abs(b).max())
+            assert err < 2e-2, (H, D, T, amp, name, err)
+            worst = max(worst, err)
+    return f"worst rel err {worst:.1e}"
 
 
 @check

@@ -1,10 +1,12 @@
 """Sweep the flash kernels' blocks on the chip: time a call of
-``flash_fwd`` / ``flash_dq`` / ``flash_dkv`` at ``[rows, T, d_head]``
-for every (block_q, block_k) of ``--block-q`` x ``--block-k`` that fits
-T, causal, by operand type, one JSON row a reading on stdout. The table
-in ``kernels/flash_attention.py`` is this tool's output.
+``flash_fwd`` / ``flash_dq`` / ``flash_dkv`` over packed ``[b, T, H *
+d_head]`` operands (``--layout rows``: the ``[b * H, T, d_head]`` view
+of the ``[B, H, T, D]`` entry) for every (block_q, block_k) of
+``--block-q`` x ``--block-k`` that fits T, causal, by operand type, one
+JSON row a reading on stdout. The table in
+``kernels/flash_attention.py`` is this tool's output.
 
-    python tools/flash_block_sweep.py [--shapes 128,1024,64 128,2048,64 ...]
+    python tools/flash_block_sweep.py [--shapes 8,1024,16,64 8,2048,16,64 ..]
 
 Each reading is one jitted chain of ``--calls`` dependent kernel calls
 (the output feeds the next call's input, so nothing overlaps or is
@@ -30,9 +32,9 @@ import jax.numpy as jnp
 from paddle_tpu.kernels import flash_attention as fa
 
 
-def chain(kernel, blocks, T, d, calls, causal=True):
+def chain(kernel, blocks, d, heads, calls, causal=True):
     """q, k, v, do -> the last call's outputs, after ``calls`` dependent
-    calls of ``kernel`` at ``blocks``."""
+    calls of ``kernel`` at ``blocks`` (``heads`` a row of d each)."""
     sm = d ** -0.5
 
     def run(q, k, v, do, lse, o):
@@ -40,11 +42,12 @@ def chain(kernel, blocks, T, d, calls, causal=True):
             q, k, v = c
             if kernel == "fwd":
                 out, _ = fa._flash_forward(q, k, v, None, causal, sm,
-                                           *blocks, interpret=False)
+                                           *blocks, interpret=False,
+                                           num_heads=heads)
                 return out, k, v
             dq, dk, dv = fa._flash_backward(
                 q, k, v, o, lse, None, do, causal, sm, *blocks,
-                interpret=False)
+                interpret=False, num_heads=heads)
             # (the kernel whose outputs go unused is dead code to XLA)
             return (dq, k, v) if kernel == "dq" else (q, dk, dv)
         return jax.lax.fori_loop(0, calls, body, (q, k, v))
@@ -54,8 +57,9 @@ def chain(kernel, blocks, T, d, calls, causal=True):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--shapes", nargs="+", metavar="ROWS,T,D_HEAD",
-                    default=["128,1024,64", "128,2048,64", "64,2048,128"])
+    ap.add_argument("--shapes", nargs="+", metavar="B,T,H,D_HEAD",
+                    default=["8,1024,16,64", "8,2048,16,64", "8,2048,8,128"])
+    ap.add_argument("--layout", choices=["packed", "rows"], default="packed")
     ap.add_argument("--dtypes", nargs="+", default=["bfloat16", "float32"])
     ap.add_argument("--calls", type=int, default=24)
     ap.add_argument("--block-q", type=int, nargs="+",
@@ -75,27 +79,30 @@ def main():
     elif jax.default_backend() != "tpu":
         sys.exit("flash_block_sweep: needs a TPU (or --compile-only)")
 
-    for shape3, dt in itertools.product(args.shapes, args.dtypes):
-        n_rows, T, d = (int(n) for n in shape3.split(","))
-        shape = (1, n_rows, T, d)
+    for shape4, dt in itertools.product(args.shapes, args.dtypes):
+        b, T, H, d = (int(n) for n in shape4.split(","))
+        shape, heads = (((b, T, H * d), H) if args.layout == "packed"
+                        else ((b * H, T, d), 1))
         if args.compile_only:
             x = jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=where)
             operands = (x, x, x, x, jax.ShapeDtypeStruct(
-                (n_rows, 1, T), jnp.float32, sharding=where), x)
+                (b * H, 1, T), jnp.float32, sharding=where), x)
         else:
             q, k, v, do = (
                 jax.random.normal(key, shape, jnp.float32).astype(dt)
                 for key in jax.random.split(jax.random.PRNGKey(0), 4))
             o, lse = fa._flash_forward(q, k, v, None, True, d ** -0.5,
-                                       512, 512, interpret=False)
+                                       512, 512, interpret=False,
+                                       num_heads=heads)
             operands = (q, k, v, do, lse, o)
         for kernel, blocks in itertools.product(
                 ("fwd", "dq", "dkv"),
                 itertools.product(args.block_q, args.block_k)):
             if max(blocks) > T:
                 continue
-            fn = jax.jit(chain(kernel, blocks, T, d, args.calls))
-            row = {"shape": shape3, "dtype": dt, "kernel": kernel,
+            fn = jax.jit(chain(kernel, blocks, d, heads, args.calls))
+            row = {"shape": shape4, "layout": args.layout, "dtype": dt,
+                   "kernel": kernel,
                    "block_q": blocks[0], "block_k": blocks[1]}
             try:
                 if args.compile_only:
