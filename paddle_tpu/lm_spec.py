@@ -101,6 +101,34 @@ K/V is one more layer of the full-attention page pool. Only the paged
 serving ops run it: a decode tick then feeds two positions a slot and emits
 one or two tokens (serving/generation.py).
 
+A latent layer may come WITHOUT a rotary key (``qk_rope_head_dim`` 0: the
+cache row is the latent alone, key = value = the whole row) and with LEARNED
+SPARSE ATTENTION (``index_topk`` > 0; DeepSeek-V3.2's indexer over pooled
+keys): beside the latent row a second, narrow pool holds ONE indexer key for
+every ``index_pool`` tokens (the running mean of the group's LayerNorm'd keys
+``h W_Ik`` of ``index_dim``; ``[Lsparse, pages, page / index_pool,
+index_dim]`` under the latent pool's page ids). A query at position t scores
+the groups wholly before its own, ``I[t, g] = sum_j w[t, j] relu(qI[t, j] .
+kbar_g)`` over ``index_heads`` heads (``qI = c_q W_Iq`` from the query latent,
+``w = (h W_Iw) (index_heads index_dim)^-1/2``), picks the ``index_topk /
+index_pool - 1`` best EXACTLY (ties to the lower index; all of them while
+there are fewer) and attends their tokens and its own group's (positions <=
+t): at most ``index_topk`` tokens a query, in a prefill chunk and in a decode
+tick alike, the reads following the pick (``ops/pipeline_ops._dsa_attend``).
+
+``residual="mhc"`` (manifold-constrained hyper-connections, arXiv:2512.24880):
+the residual is ``hc_mult`` = n streams X [n, d] a token, float32, the
+embedding in every stream at the start and their sum at the final norm. Every
+HALF block (a mixer, a feed-forward; planes ``hc1_*`` / ``hc2_*``: ``_w`` [n d,
+n | n | n n], ``_alpha`` [3], ``_b``) reads ``u = sum_i H_pre[i] X[i]`` and
+writes ``X[i] <- sum_j H_res[i, j] X[j] + H_post[i] F(norm(u))`` with ``H_pre =
+sigmoid(.)``, ``H_post = 2 sigmoid(.)`` and ``H_res`` = ``hc_iters`` Sinkhorn
+rounds of ``exp(.)`` (rows then columns, each sum + ``hc_eps``), all three from
+the RMS-normed flattened streams (``ops/pipeline_ops._res_read`` /
+``_res_write``; ``"add"``: the identity and ``x + y``). ``ffn_limit`` L > 0:
+the gated feed-forwards compute ``act(min(x W_g, L)) * clip(x W_u, -L, L)``,
+the dense head, the shared expert and every routed expert alike.
+
 Selection between blocks is made from the spec and nothing else: no flag,
 no environment variable.
 """
@@ -137,6 +165,7 @@ KDA_DECAYS = ("bounded", "softplus")
 EXPERT_ACTS = ("silu", "relu", "relu2")
 ROUTER_INPUTS = ("post_attn_norm", "attn_input")
 ATTNS = ("mha", "mla")
+RESIDUALS = ("add", "mhc")
 
 
 class BlockNotSupportedError(NotImplementedError):
@@ -279,6 +308,26 @@ class Block:
     # a drafting (multi-token-prediction) block behind the stack: the
     # module docstring has its equations
     draft_block: bool = False
+    # learned sparse attention inside the ``mla`` kind (``index_topk`` > 0;
+    # the module docstring has its equations): an indexer of
+    # ``index_heads`` heads of ``index_dim`` scores the cached tokens in
+    # groups of ``index_pool`` (ONE pooled key a group in a second, narrow
+    # page pool) and a query attends the ``index_topk`` tokens of its best
+    # groups, its own group always among them
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+    index_pool: int = 1
+    # "add": x + F(norm(x)); "mhc": ``hc_mult`` residual streams, read and
+    # written back through learned mixes a HALF block (``hc_iters`` Sinkhorn
+    # rounds make the stream-to-stream mix doubly stochastic)
+    residual: str = "add"
+    hc_mult: int = 1
+    hc_iters: int = 0
+    hc_eps: float = 1e-6
+    # > 0: the gated feed-forwards clamp their pre-activations: act(min(
+    # gate, limit)) * clip(up, -limit, limit), dense, shared and routed alike
+    ffn_limit: float = 0.0
     # the dtype the weights are STATED in, where the matmul operands a
     # program hands the op are not the weights themselves: a serving
     # engine's bf16 AMP operand copies of float32 weights
@@ -298,12 +347,14 @@ class Block:
         if self.is_mla:
             widths = (self.kv_lora_rank, self.qk_nope_head_dim,
                       self.qk_rope_head_dim, self.v_head_dim)
-            if min(widths) < 1 or self.qk_rope_head_dim % 2 \
-                    or self.q_lora_rank < 0:
+            if min(self.kv_lora_rank, self.qk_nope_head_dim,
+                   self.v_head_dim) < 1 or self.qk_rope_head_dim < 0 \
+                    or self.qk_rope_head_dim % 2 or self.q_lora_rank < 0:
                 raise ValueError(
                     "attn='mla' needs kv_lora_rank, qk_nope_head_dim, an "
-                    "even qk_rope_head_dim and v_head_dim (got "
-                    f"{widths}); q_lora_rank 0 is a full-rank query")
+                    "even qk_rope_head_dim (0: a latent row without a "
+                    f"rotary key) and v_head_dim (got {widths}); "
+                    "q_lora_rank 0 is a full-rank query")
             if not self.use_rope or self.qk_norm or self.num_kv_heads:
                 raise ValueError(
                     "attn='mla' rotates its rotary dims (use_rope=True) "
@@ -371,6 +422,31 @@ class Block:
                                        for k in pattern):
                 raise ValueError("a layer_pattern needs a full-attention "
                                  "layer (the cache's first kind)")
+        if self.index_topk and not (
+                "mla" in self.mixers and self.q_lora_rank
+                and min(self.index_heads, self.index_dim,
+                        self.index_pool) >= 1
+                and self.index_topk % self.index_pool == 0
+                and self.index_topk // self.index_pool >= 2):
+            raise ValueError(
+                "index_topk: sparse selection is an option of the 'mla' kind "
+                f"of a layer_pattern over {ATTN_KINDS} with a query "
+                "bottleneck (q_lora_rank), index_heads, index_dim >= 1 and "
+                "index_topk two or more whole groups of index_pool")
+        if self.residual not in RESIDUALS:
+            raise ValueError(f"residual {self.residual!r} not in "
+                             f"{RESIDUALS}")
+        if self.residual == "mhc" and not (
+                self.attn_kinds and self.hc_mult >= 2 and self.hc_iters >= 1
+                and self.router_input == "post_attn_norm"):
+            raise ValueError(
+                "residual='mhc': hc_mult >= 2 streams and hc_iters >= 1 "
+                "Sinkhorn rounds over a stack held by attention kind (the "
+                "router reads the feed-forward's own input)")
+        if self.ffn_limit < 0 or (self.ffn_limit and (
+                not self.is_moe or self.expert_act == "relu2")):
+            raise ValueError("ffn_limit clamps the GATED feed-forwards of "
+                             "an expert stack (dense head, shared, routed)")
         if self.attn_gate not in ATTN_GATES:
             raise ValueError(f"attn_gate {self.attn_gate!r} not in "
                              f"{ATTN_GATES}")
@@ -651,8 +727,11 @@ class Block:
              else dict(QaW="q_a_w", QaNormS="q_a_norm_s", QbW="q_b_w"))
         gate = dict(AttnGateW="attn_gate_w") if self.attn_gate == "head" \
             else {}
+        index = dict(IdxQW="idx_q_w", IdxKW="idx_k_w",
+                     IdxKNormS="idx_k_norm_s", IdxKNormB="idx_k_norm_b",
+                     IdxHeadW="idx_head_w") if self.index_topk else {}
         return dict(**q, KvaW="kv_a_w", KvaNormS="kv_a_norm_s",
-                    KvbW="kv_b_w", **gate, OutW="out_w")
+                    KvbW="kv_b_w", **index, **gate, OutW="out_w")
 
     def _slots_by_kind(self) -> Dict[str, str]:
         """The planes of a stack held BY KIND (``plane_group`` says which
@@ -662,6 +741,9 @@ class Block:
             raise ValueError("a layer_pattern over attention kinds is an "
                              "RMSNorm, bias-free expert stack")
         slots = {"Ln1S": "ln1_s", "Ln2S": "ln2_s"}
+        if self.residual == "mhc":      # a half block's stream mixes
+            slots.update(Hc1W="hc1_w", Hc1Alpha="hc1_alpha", Hc1B="hc1_b",
+                         Hc2W="hc2_w", Hc2Alpha="hc2_alpha", Hc2B="hc2_b")
         if "kda" in self.mixers:
             low = self.kda_proj_rank > 0
             slots.update(KdaQkvW="kda_qkv_w", KdaConvW="kda_conv_w")
@@ -715,8 +797,10 @@ class Block:
         (norm 1: the layers with a mixer) | ``ffns`` (norm 2: those with a
         feed-forward; both are every layer of a stack of whole blocks) |
         ``kda`` | ``mamba2`` | ``mla`` | ``gqa`` | ``dense`` | ``experts``."""
-        if key in ("ln1_s", "ln2_s"):
-            return "mixers" if key == "ln1_s" else "ffns"
+        if key == "ln1_s" or key.startswith("hc1_"):
+            return "mixers"
+        if key == "ln2_s" or key.startswith("hc2_"):
+            return "ffns"
         if key.startswith("kda_"):
             return "kda"
         if key.startswith("mamba_"):
@@ -769,10 +853,14 @@ OPTIONAL_STACK_SLOTS = ("Ln1B", "Ln2B", "QNormS", "KNormS", "FfW1", "FfB1",
                         "KdaGateB", "GqaQkvW", "GqaGateW", "GqaOutW",
                         "MambaInW", "MambaConvW", "MambaConvB",
                         "MambaDtBias", "MambaALog", "MambaD", "MambaNormS",
-                        "MambaOutW", "MoeLatentDownW", "MoeLatentUpW")
+                        "MambaOutW", "MoeLatentDownW", "MoeLatentUpW",
+                        "IdxQW", "IdxKW", "IdxKNormS", "IdxKNormB",
+                        "IdxHeadW", "Hc1W", "Hc1Alpha", "Hc1B", "Hc2W",
+                        "Hc2Alpha", "Hc2B")
 #: matrix planes the ops read in float32 under AMP too: the router's
 #: logits (``moe_topk``) and the taps of a ``kda`` layer's convolution
-_F32_READ_PLANES = ("router_w", "kda_conv_w", "mamba_conv_w")
+_F32_READ_PLANES = ("router_w", "kda_conv_w", "mamba_conv_w", "hc1_w",
+                    "hc2_w")
 #: what ``Block.slot_state`` may list: the paged ops' state slots (inputs,
 #: and outputs updated in place)
 STATE_SLOTS = ("KdaState", "KdaConv", "MambaState", "MambaConv")
@@ -840,6 +928,13 @@ class LMSpec:
     ``topk_group``: the router; ``attn_gate="head"``: the latent
     attention's head-wise output gate.
 
+    ``index_topk`` / ``index_heads`` / ``index_dim`` / ``index_pool``:
+    learned sparse attention inside the ``mla`` kind (a second, narrow pool of
+    pooled indexer keys: ``index_bytes_per_token``); ``qk_rope_head_dim`` 0:
+    a latent row without a rotary key; ``residual="mhc"`` with ``hc_mult`` /
+    ``hc_iters`` / ``hc_eps``: the multi-stream residual; ``ffn_limit``: the
+    clamped SwiGLU (the module docstring has the equations of all three).
+
     ``draft_block=True``: a drafting (multi-token-prediction) block behind
     the stack (the module docstring has its equations; ``draft_spec()`` is
     its one-layer stack, ``draft_planes()`` its four planes beside it). An
@@ -902,6 +997,15 @@ class LMSpec:
     mamba_chunk: int = 128
     expert_latent: int = 0
     draft_block: bool = False
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+    index_pool: int = 1
+    residual: str = "add"
+    hc_mult: int = 1
+    hc_iters: int = 0
+    hc_eps: float = 1e-6
+    ffn_limit: float = 0.0
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
@@ -1037,6 +1141,19 @@ class LMSpec:
         return (layers * self.cache_pools * self.cache_row_width
                 * np.dtype(to_dtype(self.page_dtype)).itemsize)
 
+    @property
+    def index_bytes_per_token(self) -> float:
+        """Bytes a cached token costs in the indexer's pool over the sparse
+        layers (one pooled key of ``index_dim`` a group of ``index_pool``
+        tokens); 0 without selection."""
+        from .core.types import to_dtype
+        import numpy as np
+
+        if not self.index_topk:
+            return 0.0
+        return (self.layers_of(False) * self.index_dim / self.index_pool
+                * np.dtype(to_dtype(self.page_dtype)).itemsize)
+
     def slot_state(self) -> List[Tuple[str, tuple, str, int]]:
         """(name, per-slot shape, dtype, layers) of everything a serving
         slot holds that does not grow with its tokens
@@ -1084,7 +1201,21 @@ class LMSpec:
         Hm, d_in = self.mamba_heads, self.mamba_heads * self.mamba_head_dim
         cw, mt = self.block.mamba_conv_width, self.mamba_conv
         dl = self.expert_latent or d    # what a routed expert reads / writes
+        Hi, Di = self.index_heads, self.index_dim
+        n = self.hc_mult
+        hc = n * n + 2 * n              # pre | post | stream-to-stream
         shapes = {
+            # the indexer of a sparse latent layer: queries from the query
+            # latent, ONE key a token (LayerNorm'd), a weight a head
+            "idx_q_w": ([rq, Hi * Di], (rq, Hi * Di)),
+            "idx_k_w": ([d, Di], (d, Di)),
+            "idx_k_norm_s": ([Di], None), "idx_k_norm_b": ([Di], None),
+            "idx_head_w": ([d, Hi], (d, Hi)),
+            # a half block's mixes over the flattened streams [n d]:
+            # columns pre [n] | post [n] | stream-to-stream [n, n]
+            **{f"hc{i}_w": ([n * d, hc], (n * d, hc)) for i in (1, 2)},
+            **{f"hc{i}_alpha": ([3], None) for i in (1, 2)},
+            **{f"hc{i}_b": ([hc], None) for i in (1, 2)},
             # z | x B C | dt of a ``mamba2`` layer, in that order
             "mamba_in_w": ([d, d_in + cw + Hm], (d, d_in + cw + Hm)),
             "mamba_conv_w": ([mt, cw], (mt, 1)), "mamba_conv_b": ([cw], None),

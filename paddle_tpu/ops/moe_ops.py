@@ -103,7 +103,7 @@ def experts_on_kernel(n_rows: int, d: int, f: int, layer) -> bool:
 def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
              layer=None, act="silu", router_x=None, shared=None, held=None,
              routed_scale=1.0, score="softmax", bias=None, n_group=1,
-             topk_group=1, latent=None):
+             topk_group=1, latent=None, limit=None):
     """Dropless token-choice top-``k`` gated experts — the expert layer
     of the ``swiglu_moe`` block (ops/pipeline_ops.py calls it from the
     block's FFN half; it is not a program op of its own).
@@ -174,6 +174,10 @@ def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
     N token rows down before the sort and one of the combined rows up
     after it, shared by all experts; the router and the shared expert read
     ``x`` at the model's width.
+
+    ``limit`` L > 0: the clamped gated form ``act(min(x W_gate, L)) *
+    clip(x W_up, -L, L)``, applied between the grouped products, in the
+    routed experts and the shared one alike.
     """
     N, d = x.shape
     E = router_w.shape[-1]
@@ -244,10 +248,17 @@ def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
         return jax.lax.ragged_dot(a, w, sizes, precision=mxu_precision(),
                                   preferred_element_type=jnp.float32)
 
+    def gated(gate, up):
+        """act(gate()) * up(), each clamped under ``limit``."""
+        g = gate()
+        a = _EXPERT_ACTS[act](jnp.minimum(g, limit) if limit else g)
+        u = up()
+        return a * (jnp.clip(u, -limit, limit) if limit else u)
+
     if gate_w is None:
         h = _EXPERT_ACTS[act](grouped(rows, up_w))
     else:
-        h = _EXPERT_ACTS[act](grouped(rows, gate_w)) * grouped(rows, up_w)
+        h = gated(lambda: grouped(rows, gate_w), lambda: grouped(rows, up_w))
     o = grouped(h.astype(rows.dtype), down_w)                 # [N*k, d] f32
     if present is not None:
         o = jnp.where(present[order][:, None], o, 0.0)
@@ -263,6 +274,6 @@ def moe_topk(x, router_w, gate_w, up_w, down_w, k, norm_topk_prob=False,
         if s_gate is None:
             hs = _EXPERT_ACTS[act](dense(xs, s_up))
         else:
-            hs = _EXPERT_ACTS[act](dense(xs, s_gate)) * dense(xs, s_up)
+            hs = gated(lambda: dense(xs, s_gate), lambda: dense(xs, s_up))
         y = y + dense(hs.astype(xs.dtype), s_down)
     return y.astype(x.dtype), counts, jnp.mean(probs, axis=0)

@@ -254,8 +254,11 @@ def _mla_latent(blk, p, h, pos0=0):
     def rot(a):
         return rotary(a, pos0, blk.rope_theta, blk.rope_pairing, scaling=sc)
 
-    q_nope, q_rope = q[..., :nope], rot(q[..., nope:])
-    k_rope = rot(kv_a[:, None, :, r:])[:, 0]
+    if rope:
+        q_nope, q_rope = q[..., :nope], rot(q[..., nope:])
+        k_rope = rot(kv_a[:, None, :, r:])[:, 0]
+    else:                   # NoPE: the latent is the whole row
+        q_nope, q_rope, k_rope = q, q[..., nope:], kv_a[..., r:]
     if sc is not None and sc.temp_beta:
         pos = (jnp.asarray(pos0, jnp.int32).reshape(-1, 1)
                + jnp.arange(t, dtype=jnp.int32)[None, :])       # [b | 1, t]
@@ -293,8 +296,49 @@ def _expand_kv(k, v, num_heads, axis=1):
     return k, v
 
 
+def _res_read(blk, p, x, half):
+    """A half block's input from the residual carry, and what its output
+    is written back with (``_res_write``). ``residual="add"``: the carry
+    [b, t, d] itself and None. ``"mhc"`` (manifold-constrained
+    hyper-connections, arXiv:2512.24880): the carry is X [b, t, n, d]
+    float32; from x~ = RMSNorm(vec X) (no scale, eps ``hc_eps``) and the
+    half's planes ``<half>_w`` [n d, n | n | n n], ``<half>_alpha`` [3],
+    ``<half>_b``: H_pre = sigmoid(.) [n], H_post = 2 sigmoid(.) [n], H_res =
+    ``hc_iters`` Sinkhorn rounds (rows, then columns, each sum + ``hc_eps``)
+    of exp(.) [n, n] -> (sum_i H_pre[i] X[i], (H_post, H_res)), all float32."""
+    if blk.residual != "mhc":
+        return x, None
+    b, t, n, d = x.shape
+    f32 = jnp.float32
+    v = x.reshape(b, t, n * d)
+    v = v * jax.lax.rsqrt(jnp.mean(jnp.square(v), axis=-1, keepdims=True)
+                          + blk.hc_eps)
+    z = jnp.einsum("btk,km->btm", v, p[half + "_w"].astype(f32),
+                   precision=jax.lax.Precision.HIGHEST)
+    alpha, bias = p[half + "_alpha"].astype(f32), p[half + "_b"].astype(f32)
+    pre = jax.nn.sigmoid(alpha[0] * z[..., :n] + bias[:n])
+    post = 2.0 * jax.nn.sigmoid(alpha[1] * z[..., n:2 * n] + bias[n:2 * n])
+    res = jnp.exp(alpha[2] * z[..., 2 * n:].reshape(b, t, n, n)
+                  + bias[2 * n:].reshape(n, n))
+    for _ in range(blk.hc_iters):
+        res = res / (jnp.sum(res, axis=-1, keepdims=True) + blk.hc_eps)
+        res = res / (jnp.sum(res, axis=-2, keepdims=True) + blk.hc_eps)
+    return jnp.einsum("btn,btnd->btd", pre, x), (post, res)
+
+
+def _res_write(x, y, mix):
+    """The carry after a half block whose output is y [b, t, d]: ``x + y``,
+    or (``mix`` = (H_post, H_res) of ``_res_read``) X[i] <- sum_j H_res[i, j]
+    X[j] + H_post[i] y."""
+    if mix is None:
+        return x + y
+    post, res = mix
+    return (jnp.einsum("btij,btjd->btid", res, x)
+            + post[..., None] * y[:, :, None, :].astype(x.dtype))
+
+
 def _attn_out_ffn(blk, p, x, ctx, out_key="out_w", dense=False, mixer=True,
-                  ffn=True):
+                  ffn=True, mix=None):
     """Out-projection + residual + FFN half of a block; x [b, t, d] the
     block's input, ctx [b, t, H*dh]. -> (x, stats), stats as ``_block``
     says. A block whose router reads the attention's input
@@ -304,21 +348,29 @@ def _attn_out_ffn(blk, p, x, ctx, out_key="out_w", dense=False, mixer=True,
     is one of the leading ``dense`` SwiGLU layers; a position of its
     pattern that is HALF a block leaves the other half out (``mixer``
     False: no out-projection, ctx None; ``ffn`` False: the mixer's
-    residual alone, stats None)."""
+    residual alone, stats None). Both halves go through the residual
+    pair (``_res_read`` / ``_res_write``: x is the CARRY, [b, t, n, d] under
+    ``residual="mhc"``, and ``mix`` what the mixer half's read left to write
+    its output back with); ``ffn_limit`` clamps the gated feed-forwards."""
     early = blk.is_moe and blk.router_input == "attn_input"
     router_x = _norm(blk, x, p["ln1_s"], p.get("ln1_b")) if early else None
     if mixer:
-        x = x + _mm(blk, "btd,de->bte", ctx.astype(x.dtype), p[out_key],
-                    name="attn_out")
+        x = _res_write(x, _mm(blk, "btd,de->bte", ctx.astype(x.dtype),
+                              p[out_key], name="attn_out"), mix)
     if not ffn:
         return x, None
-    h2 = _norm(blk, x, p["ln2_s"], p.get("ln2_b"))
+    u, mix = _res_read(blk, p, x, "hc2")
+    h2 = _norm(blk, u, p["ln2_s"], p.get("ln2_b"))
+    lim = blk.ffn_limit
     if dense:
-        ff = jax.nn.silu(_mm(blk, "btd,df->btf", h2, p["dense_gate_w"])) \
-            * _mm(blk, "btd,df->btf", h2, p["dense_up_w"])
-        return x + _mm(blk, "btf,fd->btd", ff, p["dense_down_w"]), None
+        gate = _mm(blk, "btd,df->btf", h2, p["dense_gate_w"])
+        gate = jax.nn.silu(jnp.minimum(gate, lim) if lim else gate)
+        up = _mm(blk, "btd,df->btf", h2, p["dense_up_w"])
+        ff = gate * (jnp.clip(up, -lim, lim) if lim else up)
+        return _res_write(x, _mm(blk, "btf,fd->btd", ff, p["dense_down_w"]),
+                          mix), None
     if blk.is_moe:
-        b, t, d = x.shape
+        b, t, d = u.shape
         more = {}
         if early:
             more["router_x"] = router_x.reshape(b * t, d)
@@ -339,11 +391,13 @@ def _attn_out_ffn(blk, p, x, ctx, out_key="out_w", dense=False, mixer=True,
             more["bias"] = p["router_b"]
         if blk.n_group > 1:
             more.update(n_group=blk.n_group, topk_group=blk.topk_group)
+        if lim:
+            more["limit"] = lim
         y, counts, prob_mean = moe_topk(
             h2.reshape(b * t, d), p["router_w"], p.get("moe_gate_w"),
             p["moe_up_w"], p["moe_down_w"], blk.experts_per_tok,
             blk.norm_topk_prob, layer=p.get("layer"), **more)
-        return x + y.reshape(b, t, d), (counts, prob_mean)
+        return _res_write(x, y.reshape(b, t, d), mix), (counts, prob_mean)
     ff = _mm(blk, "btd,df->btf", h2, p["ff_w1"])
     if blk.bias:
         ff = ff + p["ff_b1"]
@@ -414,6 +468,9 @@ def pipelined_transformer_stack(attrs, ins):
     # "KdaGateUpW" "KdaGateB" "GqaQkvW" "GqaGateW" "GqaOutW"
     # "MambaInW" "MambaConvW" "MambaConvB" "MambaDtBias" "MambaALog" "MambaD"
     # "MambaNormS" "MambaOutW" "MoeLatentDownW" "MoeLatentUpW"
+    # a sparse latent layer's indexer and the residual streams' mixes:
+    # "IdxQW" "IdxKW" "IdxKNormS" "IdxKNormB" "IdxHeadW" "Hc1W" "Hc1Alpha"
+    # "Hc1B" "Hc2W" "Hc2Alpha" "Hc2B"
     params = _stack_params(blk, ins)
     causal = attrs.get("causal", True)
 
@@ -623,6 +680,9 @@ def transformer_stack_generate(attrs, ins, rng):
     # "KdaGateUpW" "KdaGateB" "GqaQkvW" "GqaGateW" "GqaOutW"
     # "MambaInW" "MambaConvW" "MambaConvB" "MambaDtBias" "MambaALog" "MambaD"
     # "MambaNormS" "MambaOutW" "MoeLatentDownW" "MoeLatentUpW"
+    # a sparse latent layer's indexer and the residual streams' mixes:
+    # "IdxQW" "IdxKW" "IdxKNormS" "IdxKNormB" "IdxHeadW" "Hc1W" "Hc1Alpha"
+    # "Hc1B" "Hc2W" "Hc2Alpha" "Hc2B"
     (prompt, tok_emb, pos_emb, ln_s, ln_b, head_w,
      params) = _unpack_lm_ins(blk, ins)
     if blk.attn_kinds:
@@ -1052,6 +1112,120 @@ def _dense_head_then_scan(kinds, layer, carry, params, within, xs, fd,
     return carry, tmap(lambda *a: jnp.concatenate(a), *stats)
 
 
+#: bytes of gathered latent rows one query tile of a sparse layer may hold
+_DSA_TILE_BYTES = 1 << 28
+
+
+def _dsa_project(blk, p, h):
+    """The indexer's projections of a sparse latent layer from the stream h
+    [b, t, d] (norm 1 and the query latent are the expressions
+    ``_mla_latent`` computes, which XLA shares): -> qI [b, t, Hi, Di], kI
+    [b, t, Di] (LayerNorm'd: the token's indexer key) and the heads' weights
+    w [b, t, Hi] = (hn W_w) Hi^-1/2 Di^-1/2, no rotation anywhere."""
+    b, t, _ = h.shape
+    Hi, Di = blk.index_heads, blk.index_dim
+    hn = _norm(blk, h, p["ln1_s"], p.get("ln1_b"))
+    c_q = _rms(_mm(blk, "btd,dr->btr", hn, p["q_a_w"]), p["q_a_norm_s"],
+               blk.norm_eps)
+    q_i = _mm(blk, "btr,re->bte", c_q, p["idx_q_w"]).reshape(b, t, Hi, Di)
+    k_i = _ln(_mm(blk, "btd,de->bte", hn, p["idx_k_w"]),
+              p["idx_k_norm_s"].astype(jnp.float32),
+              p["idx_k_norm_b"].astype(jnp.float32), blk.norm_eps)
+    w = _mm(blk, "btd,dh->bth", hn, p["idx_head_w"]) * (Hi * Di) ** -0.5
+    return q_i, k_i, w
+
+
+def _index_write(blk, pool, l, k_i, ix_page, ix_row, pos, valid):
+    """The call's indexer keys k_i [b, t, Di] into the pooled-key pool
+    [L, N, ps / G, Di] (G = ``index_pool``), in place: group g of a
+    sequence (positions G g .. G g + G - 1, never across a page) holds the
+    running mean sum / G of the keys that have arrived. Each group a row of
+    the call touches is written ONCE, at the row's last token in it: the sum
+    of the call's tokens of the group (float32), on top of what the pool
+    held if the group began before this call, else on zero (a page needs no
+    clearing when it is taken). ``pos`` [b, t] the tokens' positions,
+    ``valid`` [b, t] which are real."""
+    G = blk.index_pool
+    t = k_i.shape[1]
+    f32 = jnp.float32
+    k_i = jnp.where(valid[..., None], k_i.astype(f32), 0.0)
+    j = pos % G                                         # place in its group
+    acc = k_i
+    for back in range(1, min(G, t)):                    # the group's earlier
+        prev = jnp.pad(k_i, ((0, 0), (back, 0), (0, 0)))[:, :t]
+        acc = acc + jnp.where((j >= back)[..., None], prev, 0.0)
+    g_row = ix_row // G
+    began_before = (pos - j) < pos[:, :1]
+    old = jnp.where(began_before[..., None],
+                    pool[l, ix_page, g_row].astype(f32), 0.0)
+    nxt_valid = jnp.pad(valid[:, 1:], ((0, 0), (0, 1)))   # none after t
+    last = valid & ((j == G - 1) | ~nxt_valid)
+    page = jnp.where(last, ix_page, pool.shape[1])      # others: dropped
+    return pool.at[l, page, g_row].set((old + acc / G).astype(pool.dtype),
+                                       mode="drop")
+
+
+def _dsa_attend(blk, q_lat, q_i, w_i, ck, ci, l, tbl, pos):
+    """Sparse attention of one latent layer: queries q_lat [b, H, t, W]
+    (absorbed, scaled) at positions ``pos`` [b, t] against the latent pool
+    ck [L, N, ps, W] THROUGH the indexer's pool ci [L, N, ps / G, Di]. The
+    row's pooled keys are scored (I[t, g] = sum_j w_j relu(qI_j . kbar_g),
+    groups wholly before the query's own), the ``index_topk / G - 1`` best
+    picked exactly (``lax.top_k``: ties to the lower index), the query's own
+    group added, and ONLY the picked groups' latent rows are gathered (G rows
+    of W contiguous a group) and attended, keys beyond the query masked. A
+    chunk runs in query tiles whose gathered rows stay under
+    ``_DSA_TILE_BYTES``. -> o_lat [b, H, t, r]."""
+    b, H, t, W = q_lat.shape
+    G, r = blk.index_pool, blk.kv_lora_rank
+    L, N, ps, _ = ck.shape
+    gp = ps // G                                        # groups a page
+    n_groups = tbl.shape[1] * gp
+    k_pick = min(blk.index_topk // G - 1, n_groups)
+    keys = ci[l, tbl].reshape(b, n_groups, -1)          # [b, NG, Di]
+    groups = ck.reshape(L, N * gp, G * W)               # a group's rows
+    f32 = jnp.float32
+    per_query = (k_pick + 1) * G * W * ck.dtype.itemsize
+    tile = max(1, min(t, _DSA_TILE_BYTES // (b * per_query)))
+    while t % tile:
+        tile -= 1
+
+    def attend(args):
+        q, qi, wi, qpos = args          # [b, H, T, W] [b, T, Hi, Di] ..
+        s = jnp.einsum("bthd,bgd->bthg", qi.astype(keys.dtype), keys,
+                       preferred_element_type=f32)
+        s = jnp.einsum("bthg,bth->btg", jax.nn.relu(s), wi.astype(f32))
+        own = qpos // G                                 # [b, T]
+        before = jnp.arange(n_groups, dtype=jnp.int32) < own[..., None]
+        top, pick = jax.lax.top_k(jnp.where(before, s, -jnp.inf), k_pick)
+        pick = jnp.concatenate([pick.astype(jnp.int32), own[..., None]], -1)
+        ok = jnp.concatenate([top > -jnp.inf,
+                              jnp.ones_like(own[..., None], bool)], -1)
+        page = jnp.take_along_axis(
+            tbl, (pick // gp).reshape(b, -1), axis=1).reshape(pick.shape)
+        rows = groups[l, page * gp + pick % gp]         # [b, T, k + 1, G W]
+        rows = rows.reshape(b, rows.shape[1], -1, W)
+        kpos = (pick[..., None] * G
+                + jnp.arange(G, dtype=jnp.int32)).reshape(b, -1, rows.shape[2])
+        seen = jnp.repeat(ok, G, axis=-1) & (kpos <= qpos[..., None])
+        sc = jnp.einsum("bhtw,btkw->bhtk", q.astype(rows.dtype), rows,
+                        preferred_element_type=f32)
+        sc = jnp.where(seen[:, None], sc, -jnp.inf)
+        pr = jax.nn.softmax(sc, axis=-1)
+        return jnp.einsum("bhtk,btkr->bhtr", pr.astype(rows.dtype),
+                          rows[..., :r], preferred_element_type=f32)
+
+    if tile == t:
+        return attend((q_lat, q_i, w_i, pos))
+    n = t // tile
+    o = jax.lax.map(attend, (
+        q_lat.reshape(b, H, n, tile, W).transpose(2, 0, 1, 3, 4),
+        q_i.reshape((b, n, tile) + q_i.shape[2:]).swapaxes(0, 1),
+        w_i.reshape(b, n, tile, -1).swapaxes(0, 1),
+        pos.reshape(b, n, tile).swapaxes(0, 1)))        # [n, b, H, tile, r]
+    return o.transpose(1, 2, 0, 3, 4).reshape(b, H, t, r)
+
+
 def _mla_paged_step(blk, b, t, project, mask, finish):
     """``_paged_layer_step`` for a latent block: ONE pool ``ck`` [L, N,
     ps, W] whose row is a token's [c_kv | k_rope | zero pad to W];
@@ -1067,7 +1241,9 @@ def _mla_paged_step(blk, b, t, project, mask, finish):
     gathers them and attends absorbed too: rebuilding every head's keys
     and values from the gathered rows was 25.5 ms a 256-token unit over a
     16k context where this is 20.7 (my chip run, PR 35, PERF.md section
-    6)."""
+    6). ``index_topk``: learned sparse attention (``_dsa_attend``): ``cv``
+    is then the pool of the indexer's pooled keys, written beside the
+    latent rows, and the attention reads the picked groups' rows alone."""
     from ..kernels import paged_attention
     from ..kernels.flash_attention import reference_attention
 
@@ -1084,7 +1260,20 @@ def _mla_paged_step(blk, b, t, project, mask, finish):
         q_abs = _mm(blk, "bhtn,rhn->bhtr", q_nope, w_uk)
         q_lat = jnp.concatenate([q_abs, q_rope], axis=-1) * scale
         q_lat = jnp.pad(q_lat, ((0, 0),) * 3 + ((0, W - r - rope_d),))
-        if t == 1 and set(mask) == {"lengths"} \
+        if blk.index_topk:
+            # selection: ``cv`` is the indexer's pool; the reads follow the
+            # pick, in the tick and in a chunk alike
+            if "lengths" in mask:
+                pos = (mask["lengths"] - 1)[:, None]
+                valid = jnp.ones_like(pos, bool)
+            else:
+                steps = jnp.arange(t, dtype=jnp.int32)[None, :]
+                pos = mask["q_pos0"][:, None] + steps
+                valid = steps < mask["q_len"][:, None]
+            q_i, k_i, w_i = _dsa_project(blk, layer_p, h)
+            cv = _index_write(blk, cv, l, k_i, ix_page, ix_row, pos, valid)
+            o_lat = _dsa_attend(blk, q_lat, q_i, w_i, ck, cv, l, tbl, pos)
+        elif t == 1 and set(mask) == {"lengths"} \
                 and paged_attention.supported(W, ck, t):
             o_lat = paged_attention.paged_attention_decode(
                 q_lat[:, :, 0], ck, None, l, tbl, mask["lengths"],
@@ -1428,6 +1617,9 @@ def _scan_kind_layers(blk, params, h, pool, table, page_id, page_row, pos0,
     (``_kda_layer``), carried like the states. -> (h, pool, pool_v, states,
     snaps, (counts [Lexp, E], router prob mean [Lexp, E]))."""
     b, t, _ = h.shape
+    if blk.residual == "mhc":   # X^0: the embedding in every stream
+        h = jnp.broadcast_to(h.astype(jnp.float32)[:, :, None, :],
+                             (b, t, blk.hc_mult, h.shape[-1]))
     kinds = blk.layer_parts     # (mixer or None, has an FFN) a position
     P = len(kinds)
     # norm 1 leads with the layers that have a mixer
@@ -1441,16 +1633,17 @@ def _scan_kind_layers(blk, params, h, pool, table, page_id, page_row, pos0,
         paged = _mla_paged_step(
             blk, b, t, lambda p, hh: _mla_latent(blk, p, hh, pos0), mask,
             lambda p, hh, ctx, halves: _attn_out_ffn(
-                blk, p, hh, _head_gate(blk, p, hh, ctx), dense=halves[0],
-                ffn=halves[1]))
+                blk, p, halves[2], _head_gate(blk, p, hh, ctx),
+                dense=halves[0], ffn=halves[1], mix=halves[3]))
     else:
         paged = _paged_layer_step(
             b, t, pool.shape[2],
             lambda p, hh: _attn_proj(blk, {**p, "qkv_w": p["gqa_qkv_w"]},
                                      hh, pos0=pos0, rope=False),
             mask, lambda p, hh, ctx, halves: _attn_out_ffn(
-                blk, p, hh, _channel_gate(blk, p, hh, ctx),
-                out_key="gqa_out_w", dense=halves[0], ffn=halves[1]))
+                blk, p, halves[2], _channel_gate(blk, p, hh, ctx),
+                out_key="gqa_out_w", dense=halves[0], ffn=halves[1],
+                mix=halves[3]))
     paged_kind = "mla" if "mla" in blk.mixers else "gqa"
     ix = (page_id.reshape(b, t), page_row.reshape(b, t))
 
@@ -1460,26 +1653,29 @@ def _scan_kind_layers(blk, params, h, pool, table, page_id, page_row, pos0,
         mixer, ffn = kind
         if whole and ffn and not dense:
             p = {**p, **whole, "layer": at["experts"]}
+        # the mixer reads u off the carry and writes back through ``mix``
+        u, mix = _res_read(blk, p, hh, "hc1") if mixer else (hh, None)
         if mixer == "kda":
             ctx, s_new, c_new, sn = _kda_layer(
-                blk, p, hh, st["KdaState"], st["KdaConv"], at["kda"], rows,
+                blk, p, u, st["KdaState"], st["KdaConv"], at["kda"], rows,
                 sn)
             st = {**st, "KdaState": s_new, "KdaConv": c_new}
             hh, stats = _attn_out_ffn(blk, p, hh, ctx, out_key="kda_out_w",
-                                      dense=dense, ffn=ffn)
+                                      dense=dense, ffn=ffn, mix=mix)
         elif mixer == "mamba2":
             ctx, s_new, c_new = _mamba_layer(
-                blk, p, hh, st["MambaState"], st["MambaConv"], at["mamba2"],
+                blk, p, u, st["MambaState"], st["MambaConv"], at["mamba2"],
                 rows)
             st = {**st, "MambaState": s_new, "MambaConv": c_new}
             hh, stats = _attn_out_ffn(blk, p, hh, ctx, out_key="mamba_out_w",
-                                      dense=dense, ffn=ffn)
+                                      dense=dense, ffn=ffn, mix=mix)
         elif mixer is None:             # the feed-forward alone
             hh, stats = _attn_out_ffn(blk, p, hh, None, dense=dense,
                                       mixer=False)
         else:
-            hh, pool, pool_v, stats = paged(hh, pool, pool_v, at[paged_kind],
-                                            p, (dense, ffn), table, *ix)
+            hh, pool, pool_v, stats = paged(u, pool, pool_v, at[paged_kind],
+                                            p, (dense, ffn, hh, mix), table,
+                                            *ix)
         return (hh, pool, pool_v, st, sn), stats
 
     def planes(l):      # layer l's own planes (python l)
@@ -1527,6 +1723,8 @@ def _scan_kind_layers(blk, params, h, pool, table, page_id, page_row, pos0,
             xs, jnp.arange(periods, dtype=jnp.int32)))
         stats.append(tuple(a.reshape((-1,) + a.shape[2:]) for a in ys))
     h, pool, pool_v, states, snaps = carry
+    if blk.residual == "mhc":   # the streams' sum goes to the final norm
+        h = jnp.sum(h, axis=2)
     stats = tuple(jnp.concatenate(a) for a in zip(*stats))
     return h, pool, pool_v, states, snaps, stats
 
@@ -1611,8 +1809,9 @@ def _paged_outs(blk, stats, win, **outs):
         outs["ExpertCounts"] = stats[0]
     if win is not None:
         outs["CacheKW"], outs["CacheVW"] = win
-    if outs.get("CacheV", 0) is None:       # a latent block's one pool
-        del outs["CacheV"]
+    for slot in _POOL_SLOTS:    # a latent block's one pool; no indexer
+        if outs.get(slot, 0) is None:
+            del outs[slot]
     return out(**outs)
 
 
@@ -1627,7 +1826,7 @@ def _state_ins(blk, ins):
 #: (which a ``layer_pattern`` spec reads as its full-attention kind's)
 _WINDOW_SLOTS = ("CacheKW", "CacheVW", "BlockTableW")
 #: absent for a latent block, whose cache is the one pool under CacheK
-_POOL_SLOTS = ("CacheV",)
+_POOL_SLOTS = ("CacheV", "CacheIndex")
 
 
 def _paged_project(blk, pos0):
@@ -1716,6 +1915,9 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     table = single(ins, "BlockTable").astype(jnp.int32)
     cache_k = single(ins, "CacheK")
     cache_v = maybe(ins, "CacheV")      # None: a latent block's one pool
+    # [L, N, ps / index_pool, index_dim]: a sparse latent layer's pooled
+    # indexer keys, under the latent pool's page ids
+    cache_i = maybe(ins, "CacheIndex")
     tok_emb = single(ins, "TokEmb")
     pos_emb = maybe(ins, "PosEmb")
     ln_s, ln_b = single(ins, "FinalLnS"), maybe(ins, "FinalLnB")
@@ -1733,6 +1935,9 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     # "KdaGateUpW" "KdaGateB" "GqaQkvW" "GqaGateW" "GqaOutW"
     # "MambaInW" "MambaConvW" "MambaConvB" "MambaDtBias" "MambaALog" "MambaD"
     # "MambaNormS" "MambaOutW" "MoeLatentDownW" "MoeLatentUpW"
+    # a sparse latent layer's indexer and the residual streams' mixes:
+    # "IdxQW" "IdxKW" "IdxKNormS" "IdxKNormB" "IdxHeadW" "Hc1W" "Hc1Alpha"
+    # "Hc1B" "Hc2W" "Hc2Alpha" "Hc2B"
     # and its slot-state arrays, via ``_state_ins``: "KdaState" "KdaConv"
     # "MambaState" "MambaConv"
     # (a prefill row's slot: "StateSlot")
@@ -1766,11 +1971,15 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
             snaps = (single(ins, "KdaStateSnap"), single(ins, "KdaConvSnap"),
                      single(ins, "SnapFrom").astype(jnp.int32),
                      single(ins, "SnapTake").astype(jnp.int32))
-        h, cache_k, cache_v, states, snaps, stats = _scan_kind_layers(
+        # (beside the one paged kind's first pool: its V pool, or a sparse
+        # latent layer's pooled indexer keys)
+        h, cache_k, pool_v, states, snaps, stats = _scan_kind_layers(
             blk, params, x, cache_k, table, page_id, page_row, start,
             chunk_mask(start, lengths), _state_ins(blk, ins),
             (single(ins, "StateSlot").astype(jnp.int32), start, lengths),
-            pool_v=cache_v, snaps=snaps)
+            pool_v=cache_i if blk.index_topk else cache_v, snaps=snaps)
+        cache_v, cache_i = (None, pool_v) if blk.index_topk else (pool_v,
+                                                                  None)
         if snaps is not None:
             states = {**states, "KdaStateSnap": snaps[0],
                       "KdaConvSnap": snaps[1]}
@@ -1808,8 +2017,8 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
         draft_logits = _draft_logits(blk, ins, g[jnp.arange(b), at_last])
         draft = jnp.argmax(draft_logits, axis=-1)
         nxt = jnp.stack([nxt, draft.astype(nxt.dtype)], axis=1)
-    outs = _paged_outs(blk, stats, win, NextTok=nxt,
-                       CacheK=cache_k, CacheV=cache_v, **states)
+    outs = _paged_outs(blk, stats, win, NextTok=nxt, CacheK=cache_k,
+                       CacheV=cache_v, CacheIndex=cache_i, **states)
     return _maybe_topk(attrs, ins, logits, outs, draft_logits)
 
 
@@ -1861,6 +2070,9 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     table = single(ins, "BlockTable").astype(jnp.int32)
     cache_k = single(ins, "CacheK")
     cache_v = maybe(ins, "CacheV")      # None: a latent block's one pool
+    # [L, N, ps / index_pool, index_dim]: a sparse latent layer's pooled
+    # indexer keys, under the latent pool's page ids
+    cache_i = maybe(ins, "CacheIndex")
     tok_emb = single(ins, "TokEmb")
     pos_emb = maybe(ins, "PosEmb")
     ln_s, ln_b = single(ins, "FinalLnS"), maybe(ins, "FinalLnB")
@@ -1878,6 +2090,9 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     # "KdaGateUpW" "KdaGateB" "GqaQkvW" "GqaGateW" "GqaOutW"
     # "MambaInW" "MambaConvW" "MambaConvB" "MambaDtBias" "MambaALog" "MambaD"
     # "MambaNormS" "MambaOutW" "MoeLatentDownW" "MoeLatentUpW"
+    # a sparse latent layer's indexer and the residual streams' mixes:
+    # "IdxQW" "IdxKW" "IdxKNormS" "IdxKNormB" "IdxHeadW" "Hc1W" "Hc1Alpha"
+    # "Hc1B" "Hc2W" "Hc2Alpha" "Hc2B"
     # and its slot-state arrays, via ``_state_ins``: "KdaState" "KdaConv"
     # "MambaState" "MambaConv"
     # (a prefill row's slot: "StateSlot")
@@ -1911,10 +2126,13 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
         # row s IS slot s; a live row's token lands in a page of its own
         # (a vacant or still-prefilling slot rides on the scrap page and
         # leaves its state alone)
-        h1, cache_k, cache_v, states, _, stats = _scan_kind_layers(
+        h1, cache_k, pool_v, states, _, stats = _scan_kind_layers(
             blk, params, h1, cache_k, table, page_id, page_row, pos,
             dict(lengths=pos + 1), _state_ins(blk, ins),
-            (None, pos, (page_id != 0).astype(jnp.int32)), pool_v=cache_v)
+            (None, pos, (page_id != 0).astype(jnp.int32)),
+            pool_v=cache_i if blk.index_topk else cache_v)
+        cache_v, cache_i = (None, pool_v) if blk.index_topk else (pool_v,
+                                                                  None)
         win = None
     else:
         # "CacheKW" "CacheVW" "BlockTableW": the window kind (_window_ins)
@@ -1928,7 +2146,8 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
     logits = _logits_fn(ln_s, ln_b, head_w, blk)(h1[:, 0])
     nxt = _pick_rows(attrs, ins, rng, head_w.shape[1], logits)
     outs = _paged_outs(blk, stats, win, NextTok=nxt.astype(tok.dtype),
-                       CacheK=cache_k, CacheV=cache_v, **states)
+                       CacheK=cache_k, CacheV=cache_v, CacheIndex=cache_i,
+                       **states)
     return _maybe_topk(attrs, ins, logits, outs)
 
 
@@ -2028,8 +2247,9 @@ def kv_cache_page_copy(attrs, ins):
     dst = single(ins, "Dst").astype(jnp.int32)
     cache_k = single(ins, "CacheK")
     cache_v = maybe(ins, "CacheV")      # None: a latent block's one pool
-    cache_k = cache_k.at[:, dst].set(cache_k[:, src])
-    if cache_v is None:
-        return out(Ok=dst, CacheK=cache_k)
-    cache_v = cache_v.at[:, dst].set(cache_v[:, src])
-    return out(Ok=dst, CacheK=cache_k, CacheV=cache_v)
+    # (a sparse latent layer's pooled indexer keys go with their page)
+    pools = {"CacheK": cache_k, "CacheV": cache_v,
+             "CacheIndex": maybe(ins, "CacheIndex")}
+    return out(Ok=dst, **{slot: pool.at[:, dst].set(pool[:, src])
+                          for slot, pool in pools.items()
+                          if pool is not None})

@@ -47,9 +47,9 @@ from ..ops.common import amp_enabled
 from .batcher import Request
 from .errors import BadRequestError
 from .metrics import MetricsRegistry
-from .paging import (PAGED_CACHE_K, PAGED_CACHE_KW, PAGED_CACHE_V,
-                     PAGED_CACHE_VW, Held, PageCache, PagePool, PrefixIndex,
-                     chain_key)
+from .paging import (PAGED_CACHE_INDEX, PAGED_CACHE_K, PAGED_CACHE_KW,
+                     PAGED_CACHE_V, PAGED_CACHE_VW, Held, PageCache, PagePool,
+                     PrefixIndex, chain_key)
 
 # what a slot holds beside its pages (``LMSpec.slot_state``): one array
 # [layers, slots, *shape] a name, "serving.state.<name>"
@@ -547,7 +547,7 @@ class GenerationEngine:
 
     # scope tensors swap_params must never clobber (live decode state)
     _cache_names = (PAGED_CACHE_K, PAGED_CACHE_V, PAGED_CACHE_KW,
-                    PAGED_CACHE_VW)
+                    PAGED_CACHE_VW, PAGED_CACHE_INDEX)
 
     def __init__(self, spec: LMSpec, scope: Optional[Scope] = None, *,
                  slots: int = 8, max_seq_len: Optional[int] = None,
@@ -667,6 +667,11 @@ class GenerationEngine:
                 "n_snapshots: the snapshot rows are the KDA state's; a "
                 "'mamba2' layer's state is held at the slot's last token "
                 "alone (no prefix hit for it yet)")
+        if n_snapshots and spec.index_topk:
+            raise BlockNotSupportedError(
+                "n_snapshots: a prefix hit of a spec with sparse selection "
+                "(index_topk) would start a chunk on adopted indexer rows; "
+                "not run yet")
         if src is not None:
             self._require_one_table("share_cache_with= (the slot handoff "
                                     "between engines)")
@@ -732,7 +737,9 @@ class GenerationEngine:
         kw = dict(row_width=spec.cache_row_width, n_pools=spec.cache_pools,
                   count=self.metrics.inc)
         self._caches: List[PageCache] = [PageCache(
-            "global", pool, index, layers=spec.pool_layers(False), **kw)]
+            "global", pool, index, layers=spec.pool_layers(False),
+            index_row=((spec.index_pool, spec.index_dim)
+                       if spec.index_topk else None), **kw)]
         self._chunk_walk: Dict[int, bool] = {}   # ``_chunk_walks``' memo
         self._expert_kernel: Dict[int, bool] = {}   # ``_experts_on_kernel``'s
         if spec.block.has_window:
@@ -898,8 +905,8 @@ class GenerationEngine:
         from ..core.types import to_dtype
 
         page_dtype = jnp.dtype(to_dtype(self.spec.page_dtype))
-        pools = {name: cache.shape for cache in self._caches
-                 for name in cache.scope_names}
+        pools = {name: shp for cache in self._caches
+                 for name, shp in cache.shapes.items()}
         if self._owns_pool:
             with self.executor.device_ctx():
                 for name, shp in pools.items():
@@ -948,7 +955,8 @@ class GenerationEngine:
         the cache's kind, row = ``spec.cache_row_width`` (Hkv*dh for K and
         V pools, the latent row for a latent block's one pool)."""
         return {slot: [helper.create_global_variable(
-            name=name, shape=list(cache.shape), dtype=self.spec.page_dtype)]
+            name=name, shape=list(cache.shapes[name]),
+            dtype=self.spec.page_dtype)]
             for cache in caches
             for slot, name in zip(cache.op_slots, cache.scope_names)}
 
@@ -1203,9 +1211,10 @@ class GenerationEngine:
                 helper = LayerHelper("serving_page_copy",
                                      main_program=prog,
                                      startup_program=startup)
-                # (the copy op knows one cache: CacheK / CacheV)
-                pools = dict(zip(("CacheK", "CacheV"),
-                                 self._pool_io(helper, [kind]).values()))
+                # (the copy op knows one cache: CacheK / CacheV, and an
+                # indexer's pool beside them: CacheIndex)
+                pools = {slot.removesuffix("W"): var for slot, var
+                         in self._pool_io(helper, [kind]).items()}
                 ok = helper.block.create_var(
                     name="serving.cow_ok", shape=[1], dtype="int32",
                     stop_gradient=True)
@@ -1351,10 +1360,13 @@ class GenerationEngine:
         what = "decode" if tc is None else "prefill"
         self.metrics.inc(f"{what}_feed_host_arrays", len(on_host))
         self.metrics.inc("mask_host_feeds", int("serving.mask" in cols))
+        if self.spec.index_topk:
+            # (a sparse latent layer gathers what it picked: no walk below)
+            self._count_selection(tc, cols)
         if tc is None:
             self.metrics.inc("decode_feed_host_bytes",
                              sum(v.nbytes for v in on_host))
-        elif self._chunk_walks(tc):
+        elif not self.spec.index_topk and self._chunk_walks(tc):
             # the pages this unit's attention walks, a layer of each kind,
             # against the table it would gather whole
             for cache in self._caches:
@@ -1367,6 +1379,41 @@ class GenerationEngine:
                 self.metrics.inc(f"prefill_attn_table_pages{kind}",
                                  table.size)
         return CallFeed(feed, cols)
+
+    def _count_selection(self, tc: Optional[int], cols: dict) -> None:
+        """What a call's sparse latent layers select, ONE layer's worth
+        (``dsa_layer_calls`` layers ran it), from the fed plane: a query at
+        position p has ``p // index_pool`` whole groups before its own to
+        score (``dsa_groups_scored``), attends its own group and the best
+        ``index_topk / index_pool - 1`` of them (``dsa_rows_attended``:
+        picked groups x ``index_pool`` latent rows, the tail's masked rows
+        included) where a walk without selection reads p + 1
+        (``dsa_rows_in_reach``); a query with no more groups than it may
+        pick selects nothing (``dsa_dense_queries``). Rows without a page
+        (vacant slots, padding, warm-up) are not counted."""
+        spec = self.spec
+        held = cols[self._caches[0].table][:, 0] != 0
+        if tc is None:
+            pos = cols["serving.pos"][held].astype(np.int64)
+        else:
+            pos = np.concatenate([
+                np.arange(s, s + n, dtype=np.int64) for s, n in zip(
+                    cols["serving.start"][held],
+                    cols["serving.chunk_len"][held])] or [np.zeros(0, np.int64)])
+        before = pos // spec.index_pool
+        k = spec.index_topk // spec.index_pool - 1
+        scored = int(before.sum())
+        attended = int((np.minimum(before, k) + 1).sum()) * spec.index_pool
+        self.metrics.inc("dsa_calls")
+        self.metrics.inc("dsa_layer_calls", spec.layers_of(False))
+        self.metrics.inc("dsa_queries", int(pos.size))
+        self.metrics.inc("dsa_groups_scored", scored)
+        self.metrics.inc("dsa_rows_attended", attended)
+        self.metrics.inc("dsa_rows_in_reach", int((pos + 1).sum()))
+        self.metrics.inc("dsa_dense_queries", int((before <= k).sum()))
+        if tc is None:      # the tick's share of both, for who prices a tick
+            self.metrics.inc("dsa_tick_groups_scored", scored)
+            self.metrics.inc("dsa_tick_rows_attended", attended)
 
     def _chunk_walks(self, tc: int) -> bool:
         """Whether the prefill programs of chunk width ``tc`` attend on the
@@ -2408,10 +2455,10 @@ class GenerationEngine:
             live = [st for st in self._slots if st is not None]
             self.metrics.inc("state_bytes_live_ticks",
                              len(live) * self.spec.state_bytes_per_slot)
+            held_tokens = sum(len(st.held[0].pages)
+                              for st in live) * self.page_size
             self.metrics.inc("kv_bytes_held_ticks",
-                             sum(len(st.held[0].pages) for st in live)
-                             * self.page_size
-                             * self.spec.cache_bytes_per_token)
+                             held_tokens * self.spec.cache_bytes_per_token)
         return self._call_feed(None, arr, cols)
 
     def _run_decode(self):

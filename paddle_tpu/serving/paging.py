@@ -39,6 +39,8 @@ PAGED_CACHE_V = "serving.paged_cache_v"
 # the window layers' pools of a spec whose layers differ in kind
 PAGED_CACHE_KW = "serving.paged_cache_kw"
 PAGED_CACHE_VW = "serving.paged_cache_vw"
+# a sparse latent layer's pooled indexer keys, under the latent pool's pages
+PAGED_CACHE_INDEX = "serving.paged_cache_index"
 #: kind -> the scope names and op slots of its pools, the plane column and
 #: op slot of its table, the suffix of its statistics' keys (and of the
 #: engine keyword that sizes it), how an error names its pages
@@ -406,7 +408,8 @@ class PageCache:
     def __init__(self, name: str, pool: PagePool,
                  index: Optional[PrefixIndex], *, layers: int,
                  row_width: int, n_pools: int = 2, window: int = 0,
-                 live: int = 0, count: Callable[..., None]):
+                 live: int = 0, index_row: Optional[Tuple[int, int]] = None,
+                 count: Callable[..., None]):
         names, slots, self.table, self.table_slot, self.suffix, \
             self.noun = _KINDS[name]
         self.name = name
@@ -419,6 +422,23 @@ class PageCache:
         #: contiguous, so a page is contiguous and lane-dense on the device
         #: (ops/pipeline_ops.py says why the head-major form was not)
         self.shape = (layers, pool.n_pages, pool.page_size, row_width)
+        #: scope name -> shape of every pool. ``index_row`` = (tokens a
+        #: group, width): one more, narrow pool under the SAME page ids,
+        #: a sparse latent layer's pooled indexer keys [L, n_pages,
+        #: page_size / group, width]; it moves, is copied and is let go
+        #: with its page
+        self.shapes = {name: self.shape for name in self.scope_names}
+        if index_row is not None:
+            group, width = index_row
+            if name != "global" or pool.page_size % group:
+                raise ValueError(
+                    f"index_row: pooled keys of {group} tokens lie inside a "
+                    f"page of the full-attention kind (page_size "
+                    f"{pool.page_size} is not whole groups)")
+            self.scope_names += (PAGED_CACHE_INDEX,)
+            self.op_slots += ("CacheIndex",)
+            self.shapes[PAGED_CACHE_INDEX] = (
+                layers, pool.n_pages, pool.page_size // group, width)
         self.window, self.live = int(window), int(live)
         self._count = count
         self._held_at, self._held_n = -1, 0     # ``held_pages``' memo

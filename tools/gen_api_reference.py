@@ -93,12 +93,20 @@ MODULES = [
      "full-attention layer of the pools; GenerationEngine then runs "
      "verify ticks of two positions a slot that emit one or two tokens; "
      "require_no_draft refuses beams, the slot handoff, "
-     "share_cache_with=, DisaggEngine.build and a pp mesh)"),
+     "share_cache_with=, DisaggEngine.build and a pp mesh); "
+     "qk_rope_head_dim 0 = a latent row without a rotary key; index_topk "
+     "/ index_heads / index_dim / index_pool = learned sparse attention "
+     "inside the mla kind (an indexer over pooled keys in a second, "
+     "narrow page pool picks the cached tokens a query attends); "
+     "residual add | mhc with hc_mult / hc_iters / hc_eps = the "
+     "multi-stream (manifold-constrained) residual round every half "
+     "block; ffn_limit = the clamped SwiGLU"),
     ("paddle_tpu.ops.moe_ops",
      "The expert layer: moe_topk (dropless top-k; shared=, held=, "
      "routed_scale=; score= softmax | sigmoid, bias=, n_group=, "
      "topk_group=; gate_w None = ungated experts, act= relu2, latent= "
-     "the latent's down- and up-projection) and the Switch op"),
+     "the latent's down- and up-projection; limit= the clamped SwiGLU) "
+     "and the Switch op"),
     ("paddle_tpu.kernels.flash_attention", "Pallas flash attention"),
     ("paddle_tpu.kernels.paged_attention",
      "Pallas paged attention: walks the block table (one query position "
