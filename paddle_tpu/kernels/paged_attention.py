@@ -97,13 +97,54 @@ reads only the pages a row HOLDS, straight from the whole pool:
   score rows +6%, 2048 keys +8%, 512 keys +9% (but 0.10-0.12 where the
   context is under 512 keys: a block's tail is multiplied and masked).
 
+* a group mask (``group_mask`` with ``group_rows``, on the decode and the
+  chunk walk): a SPARSE latent layer's pick (``ops/pipeline_ops._dsa_pick``:
+  the best ``index_topk / G - 1`` groups of G = ``group_rows`` consecutive
+  keys before the query's own, and its own) handed over as one int8 flag a
+  GROUP of the table in logical order, [b, NG] a tick, [b, Tc, NG] a chunk
+  (NG = table width x page / G). The walk is the unselected layer's (every
+  page the row holds, straight from the pool as it lies: no index list, no
+  gathered row, no groups-of-G view of the pool); a key is seen iff the
+  body's own rule holds AND its group is flagged. The row's (the query
+  tile's) flags ride a ``BlockSpec`` into VMEM; a step's slice of them
+  (``_mask_lanes``: whole lane rows, or the one lane row the step's groups
+  lie in) is widened to the step's keys by ONE small 0/1 product a step
+  (``_group_spread``), not once a head step, and tiled over the head group.
+  The products over rows that were not picked run on an MXU that idled
+  under the gather. By my chip runs (PR 59, ``tools/mla_prefill_sweep.py
+  --cells glm53f``: 64 heads, W = r = 512, bf16 pages of 256, a table of
+  33792 keys, ONE random pick of 511 groups + its own a query; the gathered
+  form = ``_dsa_attend``'s: page ids, the picked groups' rows in query
+  tiles of 128, a batched product over them; ms a call of 1024 queries,
+  share of the MXU's 197 TFLOP/s by 2 x heads x causal (query, key) pairs x
+  (W + r), picked or not):
+
+  ====================  ========  ======  =====
+  1024 queries behind   gathered  walk    MXU
+  ====================  ========  ======  =====
+  0 keys                35.20     1.03    34.0%
+  4096                  35.58     4.18    75.1%
+  8192                  35.64     7.34    80.8%
+  16384                 35.67     13.66   84.3%
+  32768 (the table's)   35.68     26.25   86.4%
+  ====================  ========  ======  =====
+
+  The gathered form costs the picks (2 MB of rows a query) whatever the
+  context; the walk 0.77 ms a 1024 keys walked: they cross near 46k keys
+  of reach, past the table. ``MASK_WALK_KEYS`` (the widest TABLE the masked
+  walk takes, ``mask_supported``) stands under that, at 40960: a full row
+  of such a table walks in ~32 ms. The threshold of the k-th score over
+  [128, 8448] float32 (a unit's tile; standalone calls, host dispatch
+  included): ``lax.top_k`` 1.35 ms, the counted search 0.59, the whole of
+  ``_picked_groups`` 0.48; over the tick's [32, 8448]: 0.89 / 0.50 / 0.52.
+
 The jnp gather + ``reference_attention`` stays the semantic ground truth
 and the path of every other shape (the CPU, unaligned widths, a head
 narrower than the lanes under a chunk): the two
 positions of a verify tick stay on the page walk
 (``paged_attention_verify``). ``supported`` (a tick) and
 ``chunk_supported`` (a prefill chunk) are the whole dispatch rule, read
-off the operands.
+off the operands (a sparse layer's pick besides: ``mask_supported``).
 """
 from __future__ import annotations
 
@@ -136,6 +177,33 @@ _ROW_TILE = 16
 def _sublane_tile(dtype) -> int:
     """Rows of one (sublane x 128-lane) tile of ``dtype`` on the TPU."""
     return 8 * max(4 // jnp.dtype(dtype).itemsize, 1)
+
+
+def _mask_lanes(groups: int):
+    """The lanes of a group mask one step of a walk loads for the ``groups``
+    groups of its keys: the groups themselves where they are whole lane
+    rows, else the one lane row they lie in (``groups`` a divisor of 128: a
+    step's groups never straddle two); None where neither holds."""
+    if groups % 128 == 0:
+        return groups
+    return 128 if groups and 128 % groups == 0 else None
+
+
+def mask_supported(pool, table_width: int, group_rows: int,
+                   chunk: bool) -> bool:
+    """Whether a walk over ``pool`` [L, N, ps, W] can take a GROUP mask
+    (``group_mask``: groups of ``group_rows`` consecutive keys, never across
+    a page): whole groups a page, a step's groups loadable as lane rows
+    (``_mask_lanes``; a tick's step is a page, a chunk's a block of pages),
+    and a table of at most ``MASK_WALK_KEYS`` keys: the walk reads EVERY
+    row the table's row holds, so past that width reading the picked rows
+    alone is the cheaper form."""
+    ps = pool.shape[2]
+    if group_rows < 1 or ps % group_rows \
+            or table_width * ps > MASK_WALK_KEYS:
+        return False
+    per = _block_pages(pool) if chunk else 1
+    return _mask_lanes(per * ps // group_rows) is not None
 
 
 def supported(q_width: int, pool, t: int) -> bool:
@@ -171,11 +239,26 @@ _CHUNK_BLOCK_BYTES = 2 * 2 ** 20
 #: query rows (heads x queries) of one score tile, the K/V form's order (its
 #: G * tq: 512 at 64 / 8 heads; the module's table: my chip runs, PR 55)
 _LATENT_SCORE_ROWS = 512
+#: the widest table (in keys) whose rows a walk under a GROUP mask reads
+#: whole (``mask_supported``): the walk's time follows the keys the row
+#: holds, the gathered form's the picks alone; they cross near 46k keys of
+#: reach at 2048 picked keys a query (the module's table: my chip runs,
+#: PR 59), and a table's rows may all be full
+MASK_WALK_KEYS = 40960
 #: the chunk walk's VMEM: the tile's queries and context (double buffered by
 #: the pipeline), two K and two V blocks, its float32 state and a few
 #: [rows of a head, keys] score temporaries come to 20-30 MB at float32
 #: pages, over the compiler's default
 _CHUNK_VMEM = 64 * 2 ** 20
+
+
+def _block_pages(pool) -> int:
+    """Pages of ``pool`` [L, N, ps, W] one step of the chunk walk meets: up
+    to ``_CHUNK_KEYS`` keys and ``_CHUNK_BLOCK_BYTES`` of K, at least one."""
+    ps, width = pool.shape[2:]
+    keys = min(_CHUNK_KEYS, _CHUNK_BLOCK_BYTES
+               // (width * jnp.dtype(pool.dtype).itemsize))
+    return max(keys // ps, 1)
 
 
 def _query_tile(t: int, heads: int, dtype):
@@ -214,18 +297,60 @@ def chunk_supported(q_shape, pool, mask, value_width=None) -> bool:
             and _query_tile(t, heads, pool.dtype) is not None)
 
 
+def _group_spread(lanes, keys, group_rows, off):
+    """The 0/1 matrix [lanes, keys] that widens a step's slice of a group
+    mask to its keys: key k of the step belongs to lane ``off + k //
+    group_rows`` of the slice (by comparisons: no vector division)."""
+    g = jax.lax.broadcasted_iota(jnp.int32, (lanes, keys), 0) - off
+    k = jax.lax.broadcasted_iota(jnp.int32, (lanes, keys), 1)
+    return jnp.where((k >= g * group_rows) & (k < (g + 1) * group_rows),
+                     1.0, 0.0).astype(jnp.bfloat16)
+
+
+def _mask_slice(g0, groups, lanes):
+    """(at, off): a step whose ``groups`` groups start at group ``g0`` of
+    its row's mask loads lanes ``at .. at + lanes - 1`` of it (``lanes`` =
+    ``_mask_lanes(groups)``) and finds its first group at lane ``off`` of
+    them."""
+    from jax.experimental import pallas as pl
+
+    if lanes == groups:
+        return pl.multiple_of(g0, lanes), 0
+    at = pl.multiple_of((g0 // lanes) * lanes, lanes)
+    return at, g0 - at
+
+
+def _picked_keys(picks, spread, tiles=1):
+    """picks [n, lanes] (a step's slice of the group mask, 0 / 1) -> [tiles *
+    n, keys] bool, which keys of the step lie in a picked group (the n rows
+    ``tiles`` times over): one small 0/1 product a step, exact in any
+    precision."""
+    wide = jax.lax.dot_general(
+        picks.astype(jnp.float32).astype(jnp.bfloat16), spread,
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    if tiles > 1:
+        wide = jnp.concatenate([wide] * tiles, axis=0)
+    return wide > 0.5
+
+
 def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, *rest,
                    d_head, pmax, group=1, window=None, sm_scale=None,
-                   shared_kv=False, positions=1):
+                   shared_kv=False, positions=1, group_rows=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if shared_kv:       # one pool: the key tile is the value tile
+    rest = list(rest)
+    # one pool: the key tile is the value tile
+    v_hbm = None if shared_kv else rest.pop(0)
+    # a group mask [1, 1, NG]: which groups of ``group_rows`` keys the row
+    # may see, beside the length's rule
+    mask_ref = rest.pop(0) if group_rows else None
+    if shared_kv:
         o_ref, kbuf, sems, cur_ref, m_ref, l_ref, acc_ref = rest
-        v_hbm = vbuf = None
+        vbuf = None
     else:
-        (v_hbm, o_ref, kbuf, vbuf, sems, cur_ref, m_ref, l_ref,
-         acc_ref) = rest
+        o_ref, kbuf, vbuf, sems, cur_ref, m_ref, l_ref, acc_ref = rest
     s, rows = pl.program_id(0), pl.num_programs(0)
     ps, width = kbuf.shape[1:]
     if sm_scale is None:
@@ -330,6 +455,15 @@ def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, *rest,
         seen = key < held
         if window is not None:
             seen = seen & (key >= held - window)
+        if mask_ref is not None:
+            gp = ps // group_rows                   # groups a page
+            lanes = _mask_lanes(gp)
+            at, off = _mask_slice(i * gp, gp, lanes)
+            picks = jnp.broadcast_to(
+                mask_ref[0, :, pl.ds(at, lanes)].astype(jnp.float32),
+                (sc.shape[0], lanes))
+            seen = seen & _picked_keys(
+                picks, _group_spread(lanes, ps, group_rows, off))
         sc = jnp.where(seen, sc, -jnp.inf)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
@@ -379,9 +513,34 @@ def _decode_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, *rest,
             preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
+def _mask_operand(group_mask, group_rows, pool, table_width, per):
+    """``group_mask`` [.., NG] (bool or int8; NG = the table's groups) as the
+    kernels load it: int8, its groups padded (never at lane-aligned widths)
+    to whole steps of ``per`` pages and whole loads of ``_mask_lanes``."""
+    ps = pool.shape[2]
+    if not group_rows or ps % group_rows:
+        raise ValueError(f"group_rows {group_rows} does not divide the "
+                         f"page's {ps} rows")
+    groups = per * ps // group_rows
+    lanes = _mask_lanes(groups)
+    n_groups = table_width * ps // group_rows
+    if lanes is None or group_mask.shape[-1] != n_groups:
+        raise ValueError(
+            f"a group mask {group_mask.shape} does not fit a table of "
+            f"{table_width} pages of {ps // group_rows} groups walked "
+            f"{groups} groups a step")
+    steps = -(-table_width // per)
+    padded = -(-steps * groups // lanes) * lanes
+    mask = group_mask.astype(jnp.int8)
+    if padded != n_groups:
+        mask = jnp.pad(mask, ((0, 0),) * (mask.ndim - 1)
+                       + ((0, padded - n_groups),))
+    return mask
+
+
 def paged_attention_decode(q, cache_k, cache_v, layer, table, lengths,
                            interpret=False, window=None, sm_scale=None,
-                           name=KERNEL):
+                           name=KERNEL, group_mask=None, group_rows=None):
     """Attention of one query token a row over the pages the row holds.
 
     q [b, H, dh] (cast to the pools' dtype), cache_k / cache_v the WHOLE
@@ -395,18 +554,23 @@ def paged_attention_decode(q, cache_k, cache_v, layer, table, lengths,
     ``cache_v=None`` (latent attention): q [b, H, W] against the ONE pool
     [L, N, ps, W], every head reading the row's whole width as key AND
     value -> [b, H*W] (the caller keeps the latent's columns of each
-    head); ``sm_scale`` replaces 1/sqrt(dh); ``name`` is the call's."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    head); ``sm_scale`` replaces 1/sqrt(dh); ``name`` is the call's.
 
+    ``group_mask`` [b, NG] (bool or int8) with ``group_rows``: the row sees
+    key j iff ``j < length`` AND ``group_mask[s, j // group_rows]``, NG =
+    the table's width x the page's rows / ``group_rows`` groups in LOGICAL
+    order (a group never crosses a page). The walk is the same (every page
+    the row holds); the mask rides into VMEM a row and a page's slice of it
+    is widened to the page's keys inside the kernel."""
     if q.ndim != 3:
         raise ValueError(f"q must be [b, H, dh], got {q.shape}")
     return _walk(q[:, None], cache_k, cache_v, layer, table, lengths,
-                 interpret, window, sm_scale, name)[:, 0]
+                 interpret, window, sm_scale, name, group_mask,
+                 group_rows)[:, 0]
 
 
 def _walk(q, cache_k, cache_v, layer, table, lengths, interpret, window,
-          sm_scale, name):
+          sm_scale, name, group_mask=None, group_rows=None):
     """The kernel's call for q [b, t, H, dh]: t query positions a row (one:
     a decode tick; more: a verify tick, position j holding ``lengths + j``
     keys) -> [b, t, H*dh]."""
@@ -440,17 +604,27 @@ def _walk(q, cache_k, cache_v, layer, table, lengths, interpret, window,
         q_in = jnp.pad(q_in, ((0, 0), (0, 0), (0, hp - heads), (0, 0)))
         q_in = q_in.reshape(b, t * hp, width)
         q_rows, out_rows = t * hp, t * (-(-group // 8) * 8)
+    pools = (cache_k,) if shared_kv else (cache_k, cache_v)
+    masks, mask_specs = (), []
+    if group_mask is not None:
+        if t != 1 or group_mask.shape[:-1] != (b,):
+            raise ValueError(f"a group mask {group_mask.shape} needs one "
+                             f"query position a row of {b}, got {t}")
+        mask = _mask_operand(group_mask, group_rows, cache_k, pmax, 1)
+        masks = (mask[:, None],)
+        mask_specs = [pl.BlockSpec((1, 1, mask.shape[-1]),
+                                   lambda s, *_: (s, 0, 0))]
     kernel = functools.partial(_decode_kernel, d_head=d_head, pmax=pmax,
                                group=group, window=window, sm_scale=sm_scale,
-                               shared_kv=shared_kv, positions=t)
-    pools = (cache_k,) if shared_kv else (cache_k, cache_v)
+                               shared_kv=shared_kv, positions=t,
+                               group_rows=group_rows if masks else None)
     rows = t * hp
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # layer, the flattened table, lengths
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, q_rows, width), lambda s, *_: (s, 0, 0)),
-        ] + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
+        ] + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools] + mask_specs,
         out_specs=pl.BlockSpec((1, out_rows, width),
                                lambda s, *_: (s, 0, 0)),
         scratch_shapes=[
@@ -475,7 +649,7 @@ def _walk(q, cache_k, cache_v, layer, table, lengths, interpret, window,
         name=name,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
       table.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
-      q_in, *pools)
+      q_in, *pools, *masks)
     if group == 1 and t == 1:
         return out.reshape(b, 1, width)
     # out[b, p * G_pad + g, j*dh..] is head j*group + g of position p: back
@@ -515,15 +689,21 @@ def chunk_pages_in_reach(start, length, ps, window=None, xp=jnp):
 
 def _prefill_kernel(layer_ref, table_ref, start_ref, len_ref, q_ref, k_hbm,
                     *rest, d_head, pmax, group, window, sm_scale=None,
-                    shared_kv=False):
+                    shared_kv=False, group_rows=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if shared_kv:       # one pool: the key tile's first columns are the value
+    rest = list(rest)
+    # one pool: the key tile's first columns are the value
+    v_hbm = None if shared_kv else rest.pop(0)
+    # a group mask [1, tq, NG]: which groups of ``group_rows`` keys each of
+    # the tile's queries may see, beside the causal rule
+    mask_ref = rest.pop(0) if group_rows else None
+    if shared_kv:
         o_ref, kbuf, sems, m_ref, l_ref, acc_ref = rest
-        v_hbm, vbuf = None, kbuf
+        vbuf = kbuf
     else:
-        v_hbm, o_ref, kbuf, vbuf, sems, m_ref, l_ref, acc_ref = rest
+        o_ref, kbuf, vbuf, sems, m_ref, l_ref, acc_ref = rest
     s, qt = pl.program_id(0), pl.program_id(1)
     ps = k_hbm.shape[2]
     # the head loop's steps: the cached heads, ``group`` query heads over
@@ -582,6 +762,12 @@ def _prefill_kernel(layer_ref, table_ref, start_ref, len_ref, q_ref, k_hbm,
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
+    if mask_ref is not None:
+        groups = keys // group_rows                 # groups a block
+        lanes = _mask_lanes(groups)
+        # (whole lane rows a block: one spread for every block)
+        spread0 = (_group_spread(lanes, keys, group_rows, 0)
+                   if lanes == groups else None)
 
     def block(j, _):
         buf = j % 2
@@ -596,6 +782,15 @@ def _prefill_kernel(layer_ref, table_ref, start_ref, len_ref, q_ref, k_hbm,
         seen = key <= q_pos
         if window is not None:
             seen = seen & (q_pos - key < window)
+        if mask_ref is not None:
+            # the block's slice of the tile's mask, widened to its keys
+            # once a block and tiled over the head group's query rows
+            at, off = _mask_slice((first + j * per) * (ps // group_rows),
+                                  groups, lanes)
+            seen = seen & _picked_keys(
+                mask_ref[0, :, pl.ds(at, lanes)],
+                spread0 if spread0 is not None
+                else _group_spread(lanes, keys, group_rows, off), group)
 
         def head(h, _):
             if shared_kv:   # every head: the whole tile, its first columns
@@ -647,7 +842,8 @@ def _prefill_kernel(layer_ref, table_ref, start_ref, len_ref, q_ref, k_hbm,
 
 def paged_attention_prefill(q, cache_k, cache_v, layer, table, start,
                             lengths, interpret=False, window=None,
-                            sm_scale=None, value_width=None):
+                            sm_scale=None, value_width=None, group_mask=None,
+                            group_rows=None):
     """Attention of a prefill CHUNK over the pages each row holds.
 
     q [b, H, Tc, dh] (cast to the pools' dtype): query i of row s sits at
@@ -664,7 +860,15 @@ def paged_attention_prefill(q, cache_k, cache_v, layer, table, start,
     ``cache_v=None`` (latent attention, the call ``MLA_PREFILL_KERNEL``):
     q [b, H, Tc, W] against the ONE pool [L, N, ps, W], every head reading
     the row's whole width as key and its first ``value_width`` columns as
-    value -> [b, Tc, H*value_width]; ``sm_scale`` replaces 1/sqrt(dh)."""
+    value -> [b, Tc, H*value_width]; ``sm_scale`` replaces 1/sqrt(dh).
+
+    ``group_mask`` [b, Tc, NG] (bool or int8) with ``group_rows`` (no
+    window): query i of row s sees key j iff ``j <= start[s] + i`` AND
+    ``group_mask[s, i, j // group_rows]``, NG = the table's width x the
+    page's rows / ``group_rows`` groups in LOGICAL order. The walk is the
+    same (the pages the tile's queries reach); the tile's [tq, NG] mask
+    rides into VMEM and a block's slice of it is widened to the block's
+    keys once a block."""
     if q.ndim != 4:
         raise ValueError(f"q must be [b, H, Tc, dh], got {q.shape}")
     heads, t, d_head = q.shape[1:]
@@ -687,20 +891,31 @@ def paged_attention_prefill(q, cache_k, cache_v, layer, table, start,
     # prefill program then share a trace and a lowering a kind of pool,
     # where each call site of the bare ``pallas_call`` cost ~0.3 s of every
     # start-up, cold or warm
+    if group_mask is not None:
+        if window is not None or group_mask.shape[:-1] != (q.shape[0], t):
+            raise ValueError(f"a group mask {group_mask.shape} needs a full "
+                             f"layer's chunk [{q.shape[0]}, {t}, NG], "
+                             f"window {window}")
+        group_mask = _mask_operand(group_mask, group_rows, cache_k,
+                                   table.shape[1], _block_pages(cache_k))
     return _chunk_walk(
         q.astype(cache_k.dtype), cache_k, cache_v,
         jnp.reshape(layer, (1,)).astype(jnp.int32),
         table.reshape(-1).astype(jnp.int32), start.astype(jnp.int32),
         lengths.astype(jnp.int32), pmax=table.shape[1], interpret=interpret,
-        window=window, sm_scale=sm_scale, value_width=value_width)
+        window=window, sm_scale=sm_scale, value_width=value_width,
+        group_mask=group_mask,
+        group_rows=None if group_mask is None else group_rows)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "pmax", "interpret", "window", "sm_scale", "value_width"))
+    "pmax", "interpret", "window", "sm_scale", "value_width", "group_rows"))
 def _chunk_walk(q, cache_k, cache_v, layer, table, start, lengths, *, pmax,
-                interpret, window, sm_scale=None, value_width=None):
+                interpret, window, sm_scale=None, value_width=None,
+                group_mask=None, group_rows=None):
     """``paged_attention_prefill``'s call, on checked operands: layer [1],
-    table [b * pmax] flattened, start / lengths [b], all int32."""
+    table [b * pmax] flattened, start / lengths [b], all int32; a group
+    mask as ``_mask_operand`` leaves it."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -719,20 +934,23 @@ def _chunk_walk(q, cache_k, cache_v, layer, table, start, lengths, *, pmax,
     else:
         steps, d_value = width // d_head, d_head
         group = heads // steps
-    keys = min(_CHUNK_KEYS,
-               _CHUNK_BLOCK_BYTES // (width * jnp.dtype(dtype).itemsize))
-    per = max(keys // ps, 1)
+    per = _block_pages(cache_k)
     pools = (cache_k,) if shared_kv else (cache_k, cache_v)
+    masks, mask_specs = (), []
+    if group_mask is not None:
+        masks = (group_mask,)
+        mask_specs = [pl.BlockSpec((1, tq, group_mask.shape[-1]),
+                                   lambda s, i, *_: (s, i, 0))]
     kernel = functools.partial(_prefill_kernel, d_head=d_head, pmax=pmax,
                                group=group, window=window, sm_scale=sm_scale,
-                               shared_kv=shared_kv)
+                               shared_kv=shared_kv, group_rows=group_rows)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,  # layer, the flattened table, start, lengths
         grid=(b, t // tq),
         in_specs=[
             pl.BlockSpec((1, heads, tq, d_head),
                          lambda s, i, *_: (s, 0, i, 0)),
-        ] + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
+        ] + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools] + mask_specs,
         out_specs=pl.BlockSpec((1, tq, heads * d_value),
                                lambda s, i, *_: (s, i, 0)),
         scratch_shapes=[
@@ -754,4 +972,4 @@ def _chunk_walk(q, cache_k, cache_v, layer, table, start, lengths, *, pmax,
             vmem_limit_bytes=_CHUNK_VMEM),
         interpret=interpret,
         name=MLA_PREFILL_KERNEL if shared_kv else PREFILL_KERNEL,
-    )(layer, table, start, lengths, q, *pools)
+    )(layer, table, start, lengths, q, *pools, *masks)

@@ -1112,7 +1112,8 @@ def _dense_head_then_scan(kinds, layer, carry, params, within, xs, fd,
     return carry, tmap(lambda *a: jnp.concatenate(a), *stats)
 
 
-#: bytes of gathered latent rows one query tile of a sparse layer may hold
+#: bytes one query tile of a sparse layer may hold: of gathered latent rows
+#: (``_dsa_attend``), of the indexer's per-head scores (``_dsa_pick``)
 _DSA_TILE_BYTES = 1 << 28
 
 
@@ -1165,17 +1166,75 @@ def _index_write(blk, pool, l, k_i, ix_page, ix_row, pos, valid):
                                        mode="drop")
 
 
+def _dsa_scores(keys, q_i, w_i, qpos, G):
+    """The indexer's scores of queries q_i [b, T, Hi, Di] (head weights w_i
+    [b, T, Hi]) at positions ``qpos`` [b, T] over a row's pooled keys [b,
+    NG, Di]: I[t, g] = sum_j w_j relu(qI_j . kbar_g) in float32 (a -0.0
+    folded onto 0.0: one score, whoever orders them), -inf for every group
+    not wholly before the query's own. -> (scores [b, T, NG], own [b, T]
+    the query's group)."""
+    f32 = jnp.float32
+    s = jnp.einsum("bthd,bgd->bthg", q_i.astype(keys.dtype), keys,
+                   preferred_element_type=f32)
+    s = jnp.einsum("bthg,bth->btg", jax.nn.relu(s), w_i.astype(f32))
+    own = qpos // G                                     # [b, T]
+    before = jnp.arange(keys.shape[1], dtype=jnp.int32) < own[..., None]
+    return jnp.where(before, jnp.where(s == 0, 0.0, s), -jnp.inf), own
+
+
+def _query_tiles(t, tile, *arrays):
+    """``arrays`` [b, t, ..] as ``lax.map`` walks them in tiles of ``tile``
+    queries: [t / tile, b, tile, ..]."""
+    return tuple(a.reshape((a.shape[0], t // tile, tile) + a.shape[2:])
+                 .swapaxes(0, 1) for a in arrays)
+
+
+def _dsa_tile(t, per_query):
+    """Queries a tile of a sparse layer's chunk: the largest divisor of t
+    whose ``per_query`` bytes each stay under ``_DSA_TILE_BYTES``."""
+    tile = max(1, min(t, _DSA_TILE_BYTES // per_query))
+    while t % tile:
+        tile -= 1
+    return tile
+
+
+def _attend_picked(q, groups, l, tbl, pick, ok, qpos, gp, r):
+    """Attention of queries q [b, H, T, W] at ``qpos`` [b, T] over the
+    latent rows of the groups they picked: ``pick`` [b, T, K] group ids in
+    the table's logical order (``ok``: which are picks at all), ``groups``
+    [L, N gp, G W] the latent pool by group (layer ``l`` of it is read),
+    ``gp`` groups a page. The picked groups' rows are gathered (page ids
+    off ``tbl``, then G rows of W a group, ONE gather at (l, group)) and
+    attended, keys beyond the query masked -> [b, H, T, r] float32."""
+    b, _, _, W = q.shape
+    G = groups.shape[2] // W
+    page = jnp.take_along_axis(
+        tbl, (pick // gp).reshape(b, -1), axis=1).reshape(pick.shape)
+    rows = groups[l, page * gp + pick % gp]             # [b, T, K, G W]
+    rows = rows.reshape(b, rows.shape[1], -1, W)
+    kpos = (pick[..., None] * G
+            + jnp.arange(G, dtype=jnp.int32)).reshape(b, -1, rows.shape[2])
+    seen = jnp.repeat(ok, G, axis=-1) & (kpos <= qpos[..., None])
+    sc = jnp.einsum("bhtw,btkw->bhtk", q.astype(rows.dtype), rows,
+                    preferred_element_type=jnp.float32)
+    sc = jnp.where(seen[:, None], sc, -jnp.inf)
+    pr = jax.nn.softmax(sc, axis=-1)
+    return jnp.einsum("bhtk,btkr->bhtr", pr.astype(rows.dtype),
+                      rows[..., :r], preferred_element_type=jnp.float32)
+
+
 def _dsa_attend(blk, q_lat, q_i, w_i, ck, ci, l, tbl, pos):
     """Sparse attention of one latent layer: queries q_lat [b, H, t, W]
     (absorbed, scaled) at positions ``pos`` [b, t] against the latent pool
     ck [L, N, ps, W] THROUGH the indexer's pool ci [L, N, ps / G, Di]. The
-    row's pooled keys are scored (I[t, g] = sum_j w_j relu(qI_j . kbar_g),
-    groups wholly before the query's own), the ``index_topk / G - 1`` best
-    picked exactly (``lax.top_k``: ties to the lower index), the query's own
-    group added, and ONLY the picked groups' latent rows are gathered (G rows
-    of W contiguous a group) and attended, keys beyond the query masked. A
-    chunk runs in query tiles whose gathered rows stay under
-    ``_DSA_TILE_BYTES``. -> o_lat [b, H, t, r]."""
+    row's pooled keys are scored (``_dsa_scores``), the ``index_topk / G -
+    1`` best picked exactly (``lax.top_k``: ties to the lower index), the
+    query's own group added, and ONLY the picked groups' latent rows are
+    gathered (G rows of W contiguous a group) and attended, keys beyond the
+    query masked. A chunk runs in query tiles whose gathered rows stay under
+    ``_DSA_TILE_BYTES``. The semantic ground truth of the selection, and the
+    path of every call the masked page walk cannot take (``_dsa_pick``).
+    -> o_lat [b, H, t, r]."""
     b, H, t, W = q_lat.shape
     G, r = blk.index_pool, blk.kv_lora_rank
     L, N, ps, _ = ck.shape
@@ -1184,46 +1243,82 @@ def _dsa_attend(blk, q_lat, q_i, w_i, ck, ci, l, tbl, pos):
     k_pick = min(blk.index_topk // G - 1, n_groups)
     keys = ci[l, tbl].reshape(b, n_groups, -1)          # [b, NG, Di]
     groups = ck.reshape(L, N * gp, G * W)               # a group's rows
-    f32 = jnp.float32
-    per_query = (k_pick + 1) * G * W * ck.dtype.itemsize
-    tile = max(1, min(t, _DSA_TILE_BYTES // (b * per_query)))
-    while t % tile:
-        tile -= 1
+    tile = _dsa_tile(t, b * (k_pick + 1) * G * W * ck.dtype.itemsize)
 
     def attend(args):
         q, qi, wi, qpos = args          # [b, H, T, W] [b, T, Hi, Di] ..
-        s = jnp.einsum("bthd,bgd->bthg", qi.astype(keys.dtype), keys,
-                       preferred_element_type=f32)
-        s = jnp.einsum("bthg,bth->btg", jax.nn.relu(s), wi.astype(f32))
-        own = qpos // G                                 # [b, T]
-        before = jnp.arange(n_groups, dtype=jnp.int32) < own[..., None]
-        top, pick = jax.lax.top_k(jnp.where(before, s, -jnp.inf), k_pick)
+        s, own = _dsa_scores(keys, qi, wi, qpos, G)
+        top, pick = jax.lax.top_k(s, k_pick)
         pick = jnp.concatenate([pick.astype(jnp.int32), own[..., None]], -1)
         ok = jnp.concatenate([top > -jnp.inf,
                               jnp.ones_like(own[..., None], bool)], -1)
-        page = jnp.take_along_axis(
-            tbl, (pick // gp).reshape(b, -1), axis=1).reshape(pick.shape)
-        rows = groups[l, page * gp + pick % gp]         # [b, T, k + 1, G W]
-        rows = rows.reshape(b, rows.shape[1], -1, W)
-        kpos = (pick[..., None] * G
-                + jnp.arange(G, dtype=jnp.int32)).reshape(b, -1, rows.shape[2])
-        seen = jnp.repeat(ok, G, axis=-1) & (kpos <= qpos[..., None])
-        sc = jnp.einsum("bhtw,btkw->bhtk", q.astype(rows.dtype), rows,
-                        preferred_element_type=f32)
-        sc = jnp.where(seen[:, None], sc, -jnp.inf)
-        pr = jax.nn.softmax(sc, axis=-1)
-        return jnp.einsum("bhtk,btkr->bhtr", pr.astype(rows.dtype),
-                          rows[..., :r], preferred_element_type=f32)
+        return _attend_picked(q, groups, l, tbl, pick, ok, qpos, gp, r)
 
     if tile == t:
         return attend((q_lat, q_i, w_i, pos))
     n = t // tile
     o = jax.lax.map(attend, (
         q_lat.reshape(b, H, n, tile, W).transpose(2, 0, 1, 3, 4),
-        q_i.reshape((b, n, tile) + q_i.shape[2:]).swapaxes(0, 1),
-        w_i.reshape(b, n, tile, -1).swapaxes(0, 1),
-        pos.reshape(b, n, tile).swapaxes(0, 1)))        # [n, b, H, tile, r]
+        *_query_tiles(t, tile, q_i, w_i, pos)))         # [n, b, H, tile, r]
     return o.transpose(1, 2, 0, 3, 4).reshape(b, H, t, r)
+
+
+def _picked_groups(scores, own, k_pick):
+    """The set ``_dsa_attend`` picks, as a mask: scores [.., NG] (-inf for a
+    group not before the query's own, no -0.0: ``_dsa_scores``), own [..]
+    -> [.., NG] bool, true for the ``k_pick`` best groups (every group
+    before, where there are fewer) and the query's own. EXACTLY ``lax.top_k``'s set: with ``thr`` the
+    k-th best score, every score above it and, of those that equal it, the
+    lowest indices that fill the k. ``thr`` comes from the counted search
+    the sampling plane ships (32 compare-and-count passes, no sort); the
+    ranks among the equal ones (a prefix count over the table's width) are
+    taken only where some row's k-th score is tied beyond the k."""
+    from ..kernels.sampling import _search_threshold
+
+    n_groups = scores.shape[-1]
+    at_own = jnp.arange(n_groups, dtype=jnp.int32) == own[..., None]
+    if k_pick <= 0:
+        return at_own
+    thr = _search_threshold(scores.reshape(-1, n_groups), jnp.int32(1),
+                            jnp.int32(k_pick)).reshape(own.shape)[..., None]
+    above = scores > thr
+    # a group not before the query sits at -inf: it may equal ``thr`` (fewer
+    # than k groups before) and is never picked
+    equal = (scores == thr) & (scores > -jnp.inf)
+    room = k_pick - jnp.sum(above, axis=-1, keepdims=True)
+
+    def lowest_equal():
+        rank = jnp.cumsum(equal, axis=-1, dtype=jnp.int32)  # 1-based
+        return equal & (rank <= room)
+
+    crowded = jnp.any(jnp.sum(equal, axis=-1, keepdims=True) > room)
+    return above | jax.lax.cond(crowded, lowest_equal, lambda: equal) | at_own
+
+
+def _dsa_pick(blk, q_i, w_i, ci, l, tbl, pos):
+    """A sparse latent layer's pick as a GROUP mask for the page walks
+    (``kernels/paged_attention``: ``group_mask``): [b, t, NG] int8 over the
+    table's groups in logical order, 1 for the groups ``_dsa_attend`` would
+    gather for the query (``_picked_groups``). No index list, no page ids,
+    no gathered row: the walk reads the pages the row holds in the pool as
+    it lies and masks what was not picked out of the softmax. A chunk runs
+    in query tiles whose per-head scores stay under ``_DSA_TILE_BYTES``."""
+    b, t = pos.shape
+    G = blk.index_pool
+    n_groups = tbl.shape[1] * ci.shape[2]
+    k_pick = min(blk.index_topk // G - 1, n_groups)
+    keys = ci[l, tbl].reshape(b, n_groups, -1)          # [b, NG, Di]
+
+    def pick(args):
+        qi, wi, qpos = args
+        return _picked_groups(*_dsa_scores(keys, qi, wi, qpos, G),
+                              k_pick).astype(jnp.int8)
+
+    tile = _dsa_tile(t, b * blk.index_heads * n_groups * 4)
+    if tile == t:
+        return pick((q_i, w_i, pos))
+    picked = jax.lax.map(pick, _query_tiles(t, tile, q_i, w_i, pos))
+    return picked.swapaxes(0, 1).reshape(b, t, n_groups)
 
 
 def _mla_paged_step(blk, b, t, project, mask, finish):
@@ -1241,9 +1336,14 @@ def _mla_paged_step(blk, b, t, project, mask, finish):
     gathers them and attends absorbed too: rebuilding every head's keys
     and values from the gathered rows was 25.5 ms a 256-token unit over a
     16k context where this is 20.7 (my chip run, PR 35, PERF.md section
-    6). ``index_topk``: learned sparse attention (``_dsa_attend``): ``cv``
-    is then the pool of the indexer's pooled keys, written beside the
-    latent rows, and the attention reads the picked groups' rows alone."""
+    6). ``index_topk``: learned sparse attention: ``cv`` is then the pool
+    of the indexer's pooled keys, written beside the latent rows; on a chip
+    the pick rides into the SAME two walks as a group mask (``_dsa_pick``;
+    ``paged_attention.mask_supported``: the table no wider than the walk
+    wins at), everywhere else the attention gathers the picked groups' rows
+    alone (``_dsa_attend``). ``profiler.global_stat`` counts which a traced
+    layer took (``dsa/walk_calls`` / ``dsa/gather_calls``)."""
+    from .. import profiler
     from ..kernels import paged_attention
     from ..kernels.flash_attention import reference_attention
 
@@ -1272,7 +1372,35 @@ def _mla_paged_step(blk, b, t, project, mask, finish):
                 valid = steps < mask["q_len"][:, None]
             q_i, k_i, w_i = _dsa_project(blk, layer_p, h)
             cv = _index_write(blk, cv, l, k_i, ix_page, ix_row, pos, valid)
-            o_lat = _dsa_attend(blk, q_lat, q_i, w_i, ck, cv, l, tbl, pos)
+            # on a chip the pick travels as a group mask into the walks of
+            # the unselected layer (a tick; a chunk); every call they cannot
+            # take (the CPU, unaligned widths, a table too wide to read
+            # whole) gathers what it picked
+            tick = t == 1 and set(mask) == {"lengths"} \
+                and paged_attention.supported(W, ck, t)
+            walks = paged_attention.mask_supported(
+                ck, tbl.shape[1], blk.index_pool, chunk=not tick) and (
+                    tick or paged_attention.chunk_supported(
+                        q_lat.shape, ck, mask, r))
+            if walks:
+                picked = _dsa_pick(blk, q_i, w_i, cv, l, tbl, pos)
+            if walks and tick:
+                o_lat = paged_attention.paged_attention_decode(
+                    q_lat[:, :, 0], ck, None, l, tbl, mask["lengths"],
+                    sm_scale=1.0, name=paged_attention.MLA_KERNEL,
+                    group_mask=picked[:, 0], group_rows=blk.index_pool)
+                o_lat = o_lat.reshape(b, -1, 1, W)
+            elif walks:
+                o_lat = paged_attention.paged_attention_prefill(
+                    q_lat, ck, None, l, tbl, mask["q_pos0"], mask["q_len"],
+                    sm_scale=1.0, value_width=r, group_mask=picked,
+                    group_rows=blk.index_pool)
+                o_lat = o_lat.reshape(b, t, -1, r).transpose(0, 2, 1, 3)
+            else:
+                o_lat = _dsa_attend(blk, q_lat, q_i, w_i, ck, cv, l, tbl,
+                                    pos)
+            profiler.global_stat.add_count(
+                "dsa/walk_calls" if walks else "dsa/gather_calls", 1)
         elif t == 1 and set(mask) == {"lengths"} \
                 and paged_attention.supported(W, ck, t):
             o_lat = paged_attention.paged_attention_decode(

@@ -28,7 +28,7 @@ import dataclasses
 import time
 import weakref
 from collections import deque
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -740,7 +740,8 @@ class GenerationEngine:
             "global", pool, index, layers=spec.pool_layers(False),
             index_row=((spec.index_pool, spec.index_dim)
                        if spec.index_topk else None), **kw)]
-        self._chunk_walk: Dict[int, bool] = {}   # ``_chunk_walks``' memo
+        # ``_chunk_walks``' memo by chunk width (and ``_selection_walks``')
+        self._chunk_walk: Dict[Any, bool] = {}
         self._expert_kernel: Dict[int, bool] = {}   # ``_experts_on_kernel``'s
         if spec.block.has_window:
             # what a slot's window layers can hold at once (the window,
@@ -1361,12 +1362,12 @@ class GenerationEngine:
         self.metrics.inc(f"{what}_feed_host_arrays", len(on_host))
         self.metrics.inc("mask_host_feeds", int("serving.mask" in cols))
         if self.spec.index_topk:
-            # (a sparse latent layer gathers what it picked: no walk below)
             self._count_selection(tc, cols)
         if tc is None:
             self.metrics.inc("decode_feed_host_bytes",
                              sum(v.nbytes for v in on_host))
-        elif not self.spec.index_topk and self._chunk_walks(tc):
+        elif self._chunk_walks(tc) and (not self.spec.index_topk
+                                        or self._selection_walks(tc)):
             # the pages this unit's attention walks, a layer of each kind,
             # against the table it would gather whole
             for cache in self._caches:
@@ -1389,17 +1390,32 @@ class GenerationEngine:
         picked groups x ``index_pool`` latent rows, the tail's masked rows
         included) where a walk without selection reads p + 1
         (``dsa_rows_in_reach``); a query with no more groups than it may
-        pick selects nothing (``dsa_dense_queries``). Rows without a page
-        (vacant slots, padding, warm-up) are not counted."""
+        pick selects nothing (``dsa_dense_queries``). Where the call's
+        attention is a page walk under the pick as a group mask
+        (``_selection_walks``) every query meets ALL the rows of the pages
+        its row's walk reaches (``dsa_rows_walked``: the tick's by its
+        length, a chunk's by ``chunk_pages_in_reach``, the kernels' own
+        rule): walked / attended is the over-read the mask pays for reading
+        the pool as it lies. Rows without a page (vacant slots, padding,
+        warm-up) are not counted."""
         spec = self.spec
-        held = cols[self._caches[0].table][:, 0] != 0
+        from ..kernels.paged_attention import chunk_pages_in_reach
+
+        cache = self._caches[0]
+        held = cols[cache.table][:, 0] != 0
+        ps = cache.page_size
         if tc is None:
             pos = cols["serving.pos"][held].astype(np.int64)
+            walked = int((pos // ps + 1).sum())         # pages x queries
         else:
-            pos = np.concatenate([
-                np.arange(s, s + n, dtype=np.int64) for s, n in zip(
-                    cols["serving.start"][held],
-                    cols["serving.chunk_len"][held])] or [np.zeros(0, np.int64)])
+            start = cols["serving.start"][held].astype(np.int64)
+            n = cols["serving.chunk_len"][held].astype(np.int64)
+            pos = np.concatenate([np.arange(s, s + k) for s, k in zip(
+                start, n)] or [np.zeros(0, np.int64)])
+            first, end = chunk_pages_in_reach(start, n, ps, xp=np)
+            walked = int(((end - first) * n).sum())
+        if self._selection_walks(tc):
+            self.metrics.inc("dsa_rows_walked", walked * ps)
         before = pos // spec.index_pool
         k = spec.index_topk // spec.index_pool - 1
         scored = int(before.sum())
@@ -1420,7 +1436,8 @@ class GenerationEngine:
         chunk walk: the ops' own predicate
         (``kernels/paged_attention.chunk_supported``) over the shapes their
         caching layers see (K/V pools: a head's queries; a latent pool:
-        queries as wide as its row, the latent the value)."""
+        queries as wide as its row, the latent the value; a sparse latent
+        layer's pick besides: ``_selection_walks``)."""
         if tc not in self._chunk_walk:
             import jax
 
@@ -1439,6 +1456,28 @@ class GenerationEngine:
                     spec.block.kv_lora_rank if latent else None)
                 for cache in self._caches)
         return self._chunk_walk[tc]
+
+    def _selection_walks(self, tc: Optional[int]) -> bool:
+        """Whether the sparse latent layer of the tick (``tc`` None) or of
+        the prefill programs of chunk width ``tc`` walks under its pick as a
+        group mask: the ops' own rule (``_mla_paged_step``: the unselected
+        layer's predicate and ``paged_attention.mask_supported`` at this
+        engine's table width)."""
+        if ("dsa", tc) not in self._chunk_walk:
+            import jax
+
+            from ..core.types import to_dtype
+            from ..kernels import paged_attention
+
+            spec = self.spec
+            pool = jax.ShapeDtypeStruct(self._caches[0].shape,
+                                        to_dtype(spec.page_dtype))
+            walks = (paged_attention.supported(spec.cache_row_width, pool, 1)
+                     if tc is None else self._chunk_walks(tc))
+            self._chunk_walk["dsa", tc] = walks and \
+                paged_attention.mask_supported(pool, self.pmax,
+                                               spec.index_pool, tc is not None)
+        return self._chunk_walk["dsa", tc]
 
     # -- warmup / manifests ----------------------------------------------
     def warmup(self) -> int:

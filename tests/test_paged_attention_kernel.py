@@ -490,6 +490,10 @@ def _prefill_unit_text(cell_name, one_chip, monkeypatch):
     pools = {n: ((spec.pool_layers(False), e["n_pages"], *row),
                  spec.page_dtype)
              for n in ("CacheK", "CacheV")[:spec.cache_pools]}
+    if spec.index_topk:     # the pooled indexer keys, under the same ids
+        pools["CacheIndex"] = ((spec.pool_layers(False), e["n_pages"],
+                                ps // spec.index_pool, spec.index_dim),
+                               spec.page_dtype)
     if spec.block.has_window:
         rows["BlockTableW"] = ((1, P), "int32")
         pools.update({n: ((spec.pool_layers(True), e["n_pages_window"],
@@ -660,6 +664,113 @@ def test_latent_chunk_walk_compiles_for_the_v5e_with_the_one_pool_whole(
                      re.sub(r"\{[^{}]*\}", "", text), re.M)
     assert {op for shape, op in ops
             if f"{pages},{ps},{W}]" in shape} == {"parameter"}
+
+
+def test_masked_latent_walks_compile_for_the_v5e_and_the_hooks_name_them(
+        one_chip):
+    """The two walks under a group mask at ``glm53f-serve-longctx``'s shapes
+    (64 heads over ONE 512-wide row, bf16 pages of 256, a table of 132 pages
+    = 8448 groups of 4, 32 slots, units of 1024): each ONE custom call under
+    the unselected layer's name, the pool whole, the pick an int8 operand BY
+    GROUP — which is what ``dsa_kda_moe_lm.dsa_op`` (the benchmark's hook,
+    imported as it stands) names a selection op by, and the tick's result
+    leads with the slot count (``dsa_tick_op``)."""
+    import json
+    import os
+
+    from benchmark.families import dsa_kda_moe_lm as fam
+    from paddle_tpu.kernels.paged_attention import MLA_KERNEL
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "glm-5.3-flash.json")) as f:
+        config = json.load(f)
+    slots, pages, ps, W, H, P, Tc, G = 32, 2560, 256, 512, 64, 132, 1024, 4
+    NG = P * ps // G
+    pool = arg((1, pages, ps, W), jnp.bfloat16)
+
+    def tick(q, pool, layer, table, lengths, picked):
+        return paged_attention_decode(q, pool, None, layer, table, lengths,
+                                      sm_scale=1.0, name=MLA_KERNEL,
+                                      group_mask=picked, group_rows=G)
+
+    def chunk(q, pool, layer, table, start, lengths, picked):
+        return paged_attention_prefill(q, pool, None, layer, table, start,
+                                       lengths, sm_scale=1.0, value_width=W,
+                                       group_mask=picked, group_rows=G)
+
+    texts = {
+        "tick": jax.jit(tick).lower(
+            arg((slots, H, W), jnp.bfloat16), pool, arg((), jnp.int32),
+            arg((slots, P), jnp.int32), arg((slots,), jnp.int32),
+            arg((slots, NG), jnp.int8)).compile().as_text(),
+        "chunk": jax.jit(chunk).lower(
+            arg((1, H, Tc, W), jnp.bfloat16), pool, arg((), jnp.int32),
+            arg((1, P), jnp.int32), arg((1,), jnp.int32),
+            arg((1,), jnp.int32), arg((1, Tc, NG), jnp.int8)
+        ).compile().as_text()}
+    for what, name, result in (
+            ("tick", "%paged_mla_decode", f"bf16[{slots},{H},{W}]"),
+            ("chunk", "%paged_mla_prefill", f"bf16[1,{Tc},{H * W}]")):
+        # (the compiled text names the operands' shapes under the call's
+        # layout constraints; a device event's text has them inline)
+        calls = [ln.strip().removeprefix("ROOT ")
+                 for ln in texts[what].splitlines()
+                 if "custom-call(" in ln and "tpu_custom_call" in ln]
+        assert len(calls) == 1 and calls[0].startswith(f"{name}."), calls
+        assert fam.dsa_op(calls[0], config) == "score"
+        assert fam.dsa_tick_op(calls[0], config, slots) == (what == "tick")
+        flat = re.sub(r"\{[^{}]*\}", "", texts[what])
+        call = re.sub(r"\{[^{}]*\}", "", calls[0])
+        assert f"= {result} custom-call(" in call
+        assert call.count(f"bf16[1,{pages},{ps},{W}]") == 1
+        assert re.search(rf"s8\[(\d+,)+{NG}\]", call)
+        ops = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", flat,
+                         re.M)
+        assert {op for shape, op in ops
+                if f"{pages},{ps},{W}]" in shape} == {"parameter"}
+
+
+def test_sparse_prefill_unit_on_the_v5e_hands_its_pick_to_the_walk(
+        one_chip, monkeypatch):
+    """``glm53f-serve-longctx``'s 1024-token unit compiled for the chip at
+    the cell's shapes: the sparse latent layer's attention is ONE
+    ``paged_mla_prefill`` call with the pick as an int8 group mask among its
+    operands, the latent pool enters it whole, and nothing of the gathered
+    form is compiled in: no gathered rows ``[queries x picks, G W]``, no
+    re-layout ``[tile, picked rows, W]``, no groups-of-four view of the pool
+    (``[N ps / G, G W]``), no sort over the table's groups; the trace-time
+    counter says the layer walked."""
+    from paddle_tpu import profiler
+
+    def count(name):
+        return profiler.global_stat.as_dict().get(
+            name, {"total_ms": 0})["total_ms"]
+
+    before = count("dsa/walk_calls"), count("dsa/gather_calls")
+    flat, spec, e, P = _prefill_unit_text("glm53f-serve-longctx", one_chip,
+                                          monkeypatch)
+    assert (count("dsa/walk_calls"), count("dsa/gather_calls")) == (
+        before[0] + 1, before[1])
+    ps, Tc, W, G = (e["page_size"], e["prefill_chunk"], spec.cache_row_width,
+                    spec.index_pool)
+    NG, N = P * ps // G, e["n_pages"]
+    walks = [ln for ln in flat.splitlines() if "custom-call(" in ln
+             and "%paged_mla_prefill" in ln and "tpu_custom_call" in ln]
+    assert len(walks) == 1 and f"s8[1,{Tc},{NG}]" in walks[0]
+    assert walks[0].count(f"bf16[1,{N},{ps},{W}]") == 1
+    shapes = re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", flat,
+                        re.M)
+    picked = (spec.index_topk, spec.index_topk + G)     # rows a query
+    assert not [shape for shape, op in shapes
+                if f"{N * ps // G},{G * W}]" in shape       # the pool by group
+                or (shape.startswith("bf16[") and shape.endswith(
+                    tuple(f",{rows},{W}]" for rows in picked)))
+                or (op == "gather" and shape.endswith(f",{G * W}]"))
+                or (op == "sort" and f",{NG}]" in shape)]
 
 
 def test_latent_kernel_compiles_for_the_v5e_with_the_one_pool_whole(one_chip):

@@ -979,6 +979,89 @@ def paged_mla_prefill_matches_gathered():
 
 
 @check
+def paged_mla_masked_walk_matches_the_gather():
+    """A sparse latent layer's two walks under its pick as a group mask
+    (``_dsa_pick`` + ``group_mask=``) against ``_dsa_attend``'s gather of
+    the picked rows, at ``glm53f-serve-longctx``'s shapes cut in heads and
+    depth of table (16 heads over a 512-wide row, bf16 pages of 256, groups
+    of 4, 2048 picked tokens, an indexer of 8 heads of 128): a 256-query
+    chunk at 5,000 (past the pick: 1,250 groups before) with 200 real
+    queries, one at 700 (inside it: every group picked), and a tick of rows
+    at 9,000 / 1,500 / vacant. Both sides score the same pooled keys, so the
+    picks are the same; both round P and the result to bfloat16."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels import paged_attention as pa
+    from paddle_tpu.ops import pipeline_ops as po
+
+    N, ps, W, H, G, Hi, Di, P = 96, 256, 512, 16, 4, 8, 128, 40
+    blk = types.SimpleNamespace(index_pool=G, index_topk=2048, index_heads=Hi,
+                                kv_lora_rank=W)
+    rng = np.random.RandomState(13)
+    ck = jnp.asarray(rng.randn(1, N, ps, W), jnp.bfloat16)
+    ci = jnp.asarray(rng.randn(1, N, ps // G, Di), jnp.bfloat16)
+    detail = []
+
+    def operands(b, t):
+        table = np.stack([rng.permutation(np.arange(1, N))[:P]
+                          for _ in range(b)]).astype(np.int32)
+        return (jnp.asarray(table),
+                jnp.asarray(0.3 * rng.randn(b, H, t, W), jnp.bfloat16),
+                jnp.asarray(rng.randn(b, t, Hi, Di), jnp.float32),
+                jnp.asarray(rng.randn(b, t, Hi), jnp.float32))
+
+    for start, Tc, real in ((5000, 256, 200), (700, 256, 256)):
+        table, q, q_i, w_i = operands(1, Tc)
+        s0, n0 = jnp.asarray([start], jnp.int32), jnp.asarray([real], jnp.int32)
+        pos = s0[:, None] + jnp.arange(Tc, dtype=jnp.int32)[None, :]
+
+        def walk(q, q_i, w_i):
+            picked = po._dsa_pick(blk, q_i, w_i, ci, 0, table, pos)
+            o = pa.paged_attention_prefill(
+                q, ck, None, 0, table, s0, n0, sm_scale=1.0, value_width=W,
+                group_mask=picked, group_rows=G)
+            return o.reshape(1, Tc, H, W).transpose(0, 2, 1, 3), picked
+
+        got, picked = jax.jit(walk)(q, q_i, w_i)
+        want = jax.jit(lambda q, q_i, w_i: po._dsa_attend(
+            blk, q, q_i, w_i, ck, ci, 0, table, pos))(q, q_i, w_i)
+        got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
+        err = np.abs(got - want)[0, :, :real].max()
+        n_picked = np.asarray(picked)[0, :real].sum(-1)
+        assert (n_picked == np.minimum((start + np.arange(real)) // G,
+                                       2048 // G - 1) + 1).all()
+        # (a few bfloat16 ulps of the largest result; a wrong pick reads 0.3+)
+        tol = 2.0 ** -6 * np.abs(want).max()
+        assert np.isfinite(got).all() and err <= tol, (start, err, tol)
+        assert not got[0, :, real:].any()
+        detail.append(f"chunk {start}+{real}/{Tc}: err {err:.1e} "
+                      f"(tol {tol:.1e})")
+    lengths = jnp.asarray([9000, 1500, 0], jnp.int32)
+    table, q, q_i, w_i = operands(3, 1)
+    pos = (lengths - 1)[:, None]
+
+    def tick(q, q_i, w_i):
+        picked = po._dsa_pick(blk, q_i, w_i, ci, 0, table, pos)
+        return pa.paged_attention_decode(
+            q[:, :, 0], ck, None, 0, table, lengths, sm_scale=1.0,
+            name=pa.MLA_KERNEL, group_mask=picked[:, 0],
+            group_rows=G).reshape(3, H, W)
+
+    got = np.asarray(jax.jit(tick)(q, q_i, w_i).astype(jnp.float32))
+    want = np.asarray(jax.jit(lambda q, q_i, w_i: po._dsa_attend(
+        blk, q, q_i, w_i, ck, ci, 0, table, pos))(q, q_i, w_i)[:, :, 0]
+        .astype(jnp.float32))
+    err = np.abs(got - want)[:2].max()
+    tol = 2.0 ** -6 * np.abs(want[:2]).max()
+    assert np.isfinite(got).all() and err <= tol, (err, tol)
+    assert not got[2].any()
+    detail.append(f"tick 9000/1500/vacant: err {err:.1e} (tol {tol:.1e})")
+    return "; ".join(detail)
+
+
+@check
 def grouped_matmul_matches_ragged_dot():
     """The grouped-matmul kernel against ``jax.lax.ragged_dot`` on the
     sliced layer, compiled, at two serving shapes: smallthinker's prefill

@@ -113,7 +113,8 @@ MODULES = [
      "a row, a verify tick's two folded into one walk: "
      "paged_attention_verify, or a prefill chunk's queries over the pages "
      "they reach: paged_attention_prefill, K/V pools or a latent block's "
-     "one pool)"),
+     "one pool, the latent walks under a sparse layer's pick as a group "
+     "mask)"),
     ("paddle_tpu.kernels.grouped_matmul",
      "Pallas grouped matmul for sorted assignment rows: the expert "
      "layer's products in the serving programs, visiting only the (row "

@@ -5,6 +5,12 @@ two checkouts and ``diff`` the output to show that a change leaves the
 programs of the configurations it does not touch as they were.
 
     JAX_PLATFORMS=cpu python tools/lowered_text.py [config.json ..]
+
+``--kernels`` hashes instead the traced program (the jaxpr, the kernel's
+body and grid included, no source location in it) of the paged attention
+kernels' UNMASKED calls at the serve cells' shapes (``KERNEL_CALLS``): what a
+change to ``kernels/paged_attention.py`` that other cells must not feel has
+to leave as it was (``tests/test_dsa_mask_walk.py`` holds the hashes).
 """
 import glob
 import hashlib
@@ -108,8 +114,79 @@ def texts_of(config):
                 p, x).as_text()
 
 
+#: cell -> (slots, heads, KV heads, d_head, page, pool layers, pages, table
+#: width, prefill chunk, window) of its K/V kernels' calls
+KV_CALLS = {
+    "kexaone-serve-reason": (64, 64, 8, 128, 64, 2, 4096, 96, 256, None),
+    "kexaone-serve-reason.window": (64, 64, 8, 128, 64, 6, 512, 96, 256, 128),
+    "olmoe-serve-chat": (32, 16, 16, 128, 64, 8, 1024, 32, 128, None),
+}
+#: cell -> (slots, heads, pool row W, latent r, page, pool layers, pages,
+#: table width, prefill chunk) of its latent kernels' calls
+LATENT_CALLS = {
+    "mistral4-serve-longdoc": (64, 32, 384, 256, 256, 6, 1536, 80, 256),
+    "ling3-serve-reason": (128, 32, 640, 512, 256, 1, 4096, 48, 256),
+}
+
+
+def kernel_texts():
+    """(call, jaxpr text) of every UNMASKED paged attention kernel call the
+    cells of ``KV_CALLS`` / ``LATENT_CALLS`` make: a tick (kexaone's: a
+    verify tick of two positions), a prefill chunk."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import paged_attention as pa
+
+    bf, i32 = jnp.bfloat16, jnp.int32
+
+    def arg(shape, dt=bf):
+        return jax.ShapeDtypeStruct(shape, dt)
+
+    for cell, (slots, H, Hkv, dh, ps, L, N, P, tc, window) in KV_CALLS.items():
+        pool = arg((L, N, ps, Hkv * dh))
+        rows = (arg(()), arg((slots, P), i32), arg((slots,), i32))
+        if cell.startswith("kexaone"):
+            yield f"{cell} verify", jax.make_jaxpr(
+                lambda q, k, v, l, t, n: pa.paged_attention_verify(
+                    q, k, v, l, t, n, window=window))(
+                        arg((slots, H, 2, dh)), pool, pool, *rows)
+        else:
+            yield f"{cell} decode", jax.make_jaxpr(
+                lambda q, k, v, l, t, n: pa.paged_attention_decode(
+                    q, k, v, l, t, n, window=window))(
+                        arg((slots, H, dh)), pool, pool, *rows)
+        yield f"{cell} prefill", jax.make_jaxpr(
+            lambda q, k, v, l, t, s, n: pa.paged_attention_prefill(
+                q, k, v, l, t, s, n, window=window))(
+                    arg((1, H, tc, dh)), pool, pool, arg(()),
+                    arg((1, P), i32), arg((1,), i32), arg((1,), i32))
+    for cell, (slots, H, W, r, ps, L, N, P, tc) in LATENT_CALLS.items():
+        pool = arg((L, N, ps, W))
+        yield f"{cell} decode", jax.make_jaxpr(
+            lambda q, k, l, t, n: pa.paged_attention_decode(
+                q, k, None, l, t, n, sm_scale=1.0, name=pa.MLA_KERNEL))(
+                    arg((slots, H, W)), pool, arg(()), arg((slots, P), i32),
+                    arg((slots,), i32))
+        yield f"{cell} prefill", jax.make_jaxpr(
+            lambda q, k, l, t, s, n: pa.paged_attention_prefill(
+                q, k, None, l, t, s, n, sm_scale=1.0, value_width=r))(
+                    arg((1, H, tc, W)), pool, arg(()), arg((1, P), i32),
+                    arg((1,), i32), arg((1,), i32))
+
+
+def kernel_hashes():
+    """call -> the first 16 hex digits of its traced program's sha256."""
+    return {call: hashlib.sha256(str(text).encode()).hexdigest()[:16]
+            for call, text in kernel_texts()}
+
+
 def main(paths):
     import paddle_tpu as pt
+
+    if paths[:1] == ["--kernels"]:
+        for call, digest in kernel_hashes().items():
+            print(call, digest)
+        return
 
     for path in paths:
         with open(path) as f:
