@@ -138,6 +138,34 @@ reads only the pages a row HOLDS, straight from the whole pool:
   included): ``lax.top_k`` 1.35 ms, the counted search 0.59, the whole of
   ``_picked_groups`` 0.48; over the tick's [32, 8448]: 0.89 / 0.50 / 0.52.
 
+  The same walks under a mask of ``group_rows`` 1 over K and V pools (PR 60:
+  learned sparse attention on a stack of K/V layers, one indexer key a TOKEN;
+  the step's flags are whole lane rows, 256 a page and 1024 a block, and the
+  0/1 product that widens them is an identity). By my chip runs (PR 60,
+  ``tools/keye2_chip_check.py kernel``: 32 query heads over K and V pools of
+  4 x 128, bf16 pages of 256, a table of 25,600 keys, 2047 picked keys + its
+  own a query; ms a call of ONE layer; the gathered form =
+  ``_dsa_attend_kv``'s; the pick = ``_dsa_pick``: the scores over the table's
+  width and the k-th score's counted search):
+
+  ====================  ========  ======  ========  ======
+  1024 queries behind   gathered  walk    unmasked  pick
+  ====================  ========  ======  ========  ======
+  0 keys                          0.36    0.37      1.88
+  8192                  132.6     1.44    1.28      1.88
+  16384                           2.49    2.26      2.30
+  24576 (the table's)             3.56    3.21      2.37
+  16 slots x 1 (tick)   gathered  walk    unmasked  pick
+  8192                  5.02      0.58    0.60      0.62
+  24576                           1.44    1.48      0.60
+  ====================  ========  ======  ========  ======
+
+  The mask costs ~10% of a chunk's walk and nothing of a tick's; the gather
+  (2 x 1 KB rows a pick: 4 GB a 1024-query unit) loses by 90 x at 8k keys,
+  and by the walk's slope (0.13 ms a 1024 keys walked a unit, 0.055 a tick)
+  would cross it only near 1M keys of reach (a tick: ~90k): for this shape
+  ``MASK_WALK_KEYS`` is far on the safe side, and stays one number for both.
+
 The jnp gather + ``reference_attention`` stays the semantic ground truth
 and the path of every other shape (the CPU, unaligned widths, a head
 narrower than the lanes under a chunk): the two

@@ -129,6 +129,23 @@ the RMS-normed flattened streams (``ops/pipeline_ops._res_read`` /
 the gated feed-forwards compute ``act(min(x W_g, L)) * clip(x W_u, -L, L)``,
 the dense head, the shared expert and every routed expert alike.
 
+The same selection runs on a stack of full-attention K/V layers
+(``Block.sparse_kv``: ``attn="mha"``, no ``layer_pattern``, ``index_pool`` 1):
+ONE indexer key a TOKEN in a third pool [L, pages, page, index_dim] beside the
+K and V pools, ``qI = h W_Iq`` from the normed stream, a query picks the
+``index_topk - 1`` best positions before it and itself, and all the heads
+attend that one set, in EVERY layer. ``rope="mrope"`` (Qwen2-VL's multimodal
+rotary) turns a head's frequency pairs by THREE ids a token (``mrope_section``
+pairs by the temporal id, by the height id, by the width id): the paged
+prefill op is fed a chunk's ids, the decode op a slot's offset, and pages,
+causality and the selection keep the sequence index. ``qk_norm_heads``: the
+QK-norm is an RMSNorm over each head with one scale of ``head_dim``.
+``LMSpec.vision`` (a :class:`VisionSpec`): a vision tower and its merger in
+front of the stack, whose rows stand at a clip's placeholder tokens; the paged
+prefill op runs it inside the unit that needs its rows
+(``ops/vision_tower.py``). The train op and the one-shot generate op refuse
+all three (``BlockNotSupportedError``).
+
 Selection between blocks is made from the spec and nothing else: no flag,
 no environment variable.
 """
@@ -138,7 +155,8 @@ import dataclasses
 import math
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["Block", "BlockNotSupportedError", "LMSpec", "RopeScaling"]
+__all__ = ["Block", "BlockNotSupportedError", "LMSpec", "RopeScaling",
+           "VisionSpec"]
 
 NORMS = ("layer_norm", "rms_norm")
 FFNS = ("gelu_mlp", "swiglu_moe")
@@ -166,6 +184,7 @@ EXPERT_ACTS = ("silu", "relu", "relu2")
 ROUTER_INPUTS = ("post_attn_norm", "attn_input")
 ATTNS = ("mha", "mla")
 RESIDUALS = ("add", "mhc")
+ROPES = ("rope", "mrope")
 
 
 class BlockNotSupportedError(NotImplementedError):
@@ -216,6 +235,159 @@ class RopeScaling:
         """What the attention's 1/sqrt(d) is multiplied by: m^2."""
         return self._m(self.factor, self.mscale_all_dim) ** 2 \
             if self.mscale_all_dim else 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class VisionSpec:
+    """A SigLIP-class vision tower and its merger in front of a stacked LM
+    (``LMSpec(vision=VisionSpec(..))``): what turns a clip's frames into the
+    prompt rows that stand at its placeholder tokens.
+
+    A frame ``image_size`` x ``image_size`` x 3 (uint8; x / 127.5 - 1) is cut
+    into ``grid`` x ``grid`` patches of ``patch_size``^2 x 3 values ->
+    Linear(.., d_model) + a learned position table [``pos_grid``^2, d_model]
+    interpolated bilinearly to the grid; ``n_layers`` pre-LayerNorm blocks
+    (eps ``norm_eps``, biases everywhere) of bidirectional attention WITHIN a
+    frame, ``num_heads`` heads with a 2-D rotary (half-split; of a head's
+    pairs the first half turn by the patch's row, the rest by its column,
+    theta ``rope_theta``) and an MLP d_model -> ``d_ff`` -> d_model with
+    tanh-GELU; a final LayerNorm. Merger: the ``merge`` x ``merge``
+    neighbouring patches' rows concatenated -> LayerNorm -> Linear(m, m) ->
+    GELU -> Linear(m, the LM's d_model), m = merge^2 d_model:
+    ``tokens_per_frame`` prompt rows a frame.
+
+    A prompt holds a clip as ``vision_start_id``, F x ``tokens_per_frame`` x
+    ``video_pad_id``, ``vision_end_id``; the rows at the pad positions are
+    the merger's, every other row the embedding's. Under ``rope="mrope"``
+    a clip starting at id b gives the merged patch (f, r, c) the ids (b + f,
+    b + r, b + c) and the text after it resumes at b + max(F, grid / merge)
+    (``media_layout``)."""
+    image_size: int = 448
+    patch_size: int = 14
+    d_model: int = 1152
+    n_layers: int = 27
+    num_heads: int = 16
+    d_ff: int = 4304
+    pos_grid: int = 27
+    merge: int = 2
+    norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    vision_start_id: int = 151652
+    video_pad_id: int = 151656
+    vision_end_id: int = 151653
+
+    def __post_init__(self):
+        if self.image_size % self.patch_size or self.grid % self.merge \
+                or self.d_model % self.num_heads \
+                or (self.d_model // self.num_heads) % 4:
+            raise ValueError(
+                "VisionSpec: whole patches a frame, whole merge x merge "
+                "blocks a grid, and heads whose pairs split in two halves "
+                f"(got {self})")
+
+    @property
+    def grid(self) -> int:
+        """Patches a side of a frame."""
+        return self.image_size // self.patch_size
+
+    @property
+    def patch_values(self) -> int:
+        return 3 * self.patch_size ** 2
+
+    @property
+    def tokens_per_frame(self) -> int:
+        return (self.grid // self.merge) ** 2
+
+    @property
+    def frame_shape(self) -> Tuple[int, int, int]:
+        return (self.image_size, self.image_size, 3)
+
+    def planes(self, d_out: int) -> List[tuple]:
+        """(slot, key, shape, fan, stacked) of every parameter (scope names
+        ``vision.<key>``; ``stacked``: the plane leads with ``n_layers``);
+        fan None: a vector (a scale starts at 1, a bias at 0)."""
+        d, f, m = self.d_model, self.d_ff, self.merge ** 2 * self.d_model
+        pv = self.patch_values
+        flat = [("VisPatchW", "patch_w", [pv, d], (pv, d)),
+                ("VisPatchB", "patch_b", [d], None),
+                ("VisPosEmb", "pos_emb", [self.pos_grid ** 2, d],
+                 (self.pos_grid ** 2, d))]
+        stack = [("VisLn1S", "ln1_s", [d], None),
+                 ("VisLn1B", "ln1_b", [d], None),
+                 ("VisQkvW", "qkv_w", [d, 3 * d], (d, 3 * d)),
+                 ("VisQkvB", "qkv_b", [3 * d], None),
+                 ("VisOutW", "out_w", [d, d], (d, d)),
+                 ("VisOutB", "out_b", [d], None),
+                 ("VisLn2S", "ln2_s", [d], None),
+                 ("VisLn2B", "ln2_b", [d], None),
+                 ("VisFc1W", "fc1_w", [d, f], (d, f)),
+                 ("VisFc1B", "fc1_b", [f], None),
+                 ("VisFc2W", "fc2_w", [f, d], (f, d)),
+                 ("VisFc2B", "fc2_b", [d], None)]
+        tail = [("VisPostLnS", "post_ln_s", [d], None),
+                ("VisPostLnB", "post_ln_b", [d], None),
+                ("VisMergeLnS", "merge_ln_s", [m], None),
+                ("VisMergeLnB", "merge_ln_b", [m], None),
+                ("VisMergeW1", "merge_w1", [m, m], (m, m)),
+                ("VisMergeB1", "merge_b1", [m], None),
+                ("VisMergeW2", "merge_w2", [m, d_out], (m, d_out)),
+                ("VisMergeB2", "merge_b2", [d_out], None)]
+        return ([p + (False,) for p in flat]
+                + [(s, "stack_" + k, [self.n_layers] + shp, fan, True)
+                   for s, k, shp, fan in stack]
+                + [p + (False,) for p in tail])
+
+    def media_layout(self, prompt, mrope: bool = True):
+        """Where a prompt's clips lie: -> (spans, ids, row) with ``spans``
+        [(first pad position, frames)] in order, ``ids`` [n, 3] int32 the
+        (temporal, height, width) id of every token (three times the
+        sequence index without ``mrope``) and ``row`` [n] int32 the merged
+        row a pad position takes (frame-major over ALL the prompt's frames:
+        frame x tokens_per_frame + r x (grid / merge) + c), -1 elsewhere.
+        ValueError for a malformed span: a pad outside start .. end, a span
+        that is not whole frames, a start without its end."""
+        import numpy as np
+
+        prompt = np.asarray(prompt).reshape(-1)
+        n, tpf = prompt.size, self.tokens_per_frame
+        side = self.grid // self.merge
+        is_pad = prompt == self.video_pad_id
+        ids = np.zeros((n, 3), np.int32)
+        row = np.full(n, -1, np.int32)
+        spans, nxt, i, frame0 = [], 0, 0, 0
+        starts = np.flatnonzero(prompt == self.vision_start_id)
+        inside = np.zeros(n, bool)
+        not_pad = np.flatnonzero(~is_pad)
+        for s in starts:
+            # the first position after the start id that is no placeholder
+            after = not_pad[np.searchsorted(not_pad, s + 1):][:1]
+            e = int(after[0]) if after.size else n
+            pads = e - s - 1
+            if e >= n or prompt[e] != self.vision_end_id or pads == 0 \
+                    or pads % tpf:
+                raise ValueError(
+                    f"vision span at {int(s)}: {pads} placeholder ids, not "
+                    f"whole frames of {tpf} closed by the end id")
+            inside[s + 1:e] = True
+            spans.append((int(s) + 1, int(pads // tpf)))
+        if np.any(is_pad & ~inside):
+            raise ValueError("a vision placeholder id outside a "
+                             "start .. end span")
+        for first, frames in spans:
+            ids[i:first] = (nxt + np.arange(first - i))[:, None]
+            b = nxt + first - i
+            k = np.arange(frames * tpf)
+            f, rc = k // tpf, k % tpf
+            ids[first:first + frames * tpf] = b + np.stack(
+                [f, rc // side, rc % side], axis=1)
+            row[first:first + frames * tpf] = frame0 * tpf + k
+            frame0 += frames
+            nxt = b + max(frames, side)
+            i = first + frames * tpf
+        ids[i:] = (nxt + np.arange(n - i))[:, None]
+        if not mrope:
+            ids[:] = np.arange(n)[:, None]
+        return spans, ids, row
 
 
 @dataclasses.dataclass(frozen=True)
@@ -328,6 +500,26 @@ class Block:
     # > 0: the gated feed-forwards clamp their pre-activations: act(min(
     # gate, limit)) * clip(up, -limit, limit), dense, shared and routed alike
     ffn_limit: float = 0.0
+    # what a rotating layer turns by: "rope" the sequence index; "mrope"
+    # (Qwen2-VL's multimodal rotary): a token carries THREE ids (temporal,
+    # height, width) and of a head's frequency pairs the first
+    # ``mrope_section[0]`` turn by the first, the next ``[1]`` by the second,
+    # the last ``[2]`` by the third; the paged ops are fed the ids (a chunk's
+    # [b, t, 3], a tick's offset a slot) and the cache index stays the
+    # sequence index
+    rope: str = "rope"
+    mrope_section: Tuple[int, ...] = ()
+    # with ``qk_norm``: RMSNorm over each HEAD's q / k with one scale of
+    # ``head_dim`` (Qwen3's), not over the whole vector (OLMoE's)
+    qk_norm_heads: bool = False
+    # a vision tower in front of the stack (``LMSpec.vision``, a
+    # ``VisionSpec``: its docstring has the equations): what the prefill op
+    # needs of it that no weight's shape says
+    vision_heads: int = 0
+    vision_patch: int = 0
+    vision_merge: int = 0
+    vision_eps: float = 1e-6
+    vision_theta: float = 10000.0
     # the dtype the weights are STATED in, where the matmul operands a
     # program hands the op are not the weights themselves: a serving
     # engine's bf16 AMP operand copies of float32 weights
@@ -423,16 +615,38 @@ class Block:
                 raise ValueError("a layer_pattern needs a full-attention "
                                  "layer (the cache's first kind)")
         if self.index_topk and not (
-                "mla" in self.mixers and self.q_lora_rank
-                and min(self.index_heads, self.index_dim,
-                        self.index_pool) >= 1
+                min(self.index_heads, self.index_dim, self.index_pool) >= 1
                 and self.index_topk % self.index_pool == 0
-                and self.index_topk // self.index_pool >= 2):
+                and self.index_topk // self.index_pool >= 2
+                and (("mla" in self.mixers and self.q_lora_rank)
+                     or self.sparse_kv)):
             raise ValueError(
                 "index_topk: sparse selection is an option of the 'mla' kind "
                 f"of a layer_pattern over {ATTN_KINDS} with a query "
-                "bottleneck (q_lora_rank), index_heads, index_dim >= 1 and "
+                "bottleneck (q_lora_rank), or of a stack of full-attention "
+                "K/V layers (attn='mha', no layer_pattern, no drafting "
+                "block, no leading dense layers, index_pool 1: one indexer "
+                "key a token), with index_heads, index_dim >= 1 and "
                 "index_topk two or more whole groups of index_pool")
+        if self.rope not in ROPES:
+            raise ValueError(f"rope {self.rope!r} not in {ROPES}")
+        object.__setattr__(self, "mrope_section",
+                           tuple(int(v) for v in self.mrope_section))
+        if (self.rope == "mrope") != bool(self.mrope_section) or (
+                self.rope == "mrope" and not (
+                    self.use_rope and len(self.mrope_section) == 3
+                    and min(self.mrope_section) >= 1
+                    and not self.is_mla and self.layer_pattern is None
+                    and not self.draft_block
+                    and self.rope_pairing == "half")):
+            raise ValueError(
+                "rope='mrope': three-axis rotary of a stack of full-attention "
+                "K/V layers (use_rope, half-split pairing, no layer_pattern, "
+                "no drafting block) with mrope_section = the frequency pairs "
+                "of the temporal, height and width ids (their sum half the "
+                "head width)")
+        if self.qk_norm_heads and not self.qk_norm:
+            raise ValueError("qk_norm_heads is a form of qk_norm")
         if self.residual not in RESIDUALS:
             raise ValueError(f"residual {self.residual!r} not in "
                              f"{RESIDUALS}")
@@ -525,6 +739,14 @@ class Block:
     @property
     def is_mla(self) -> bool:
         return self.attn == "mla"
+
+    @property
+    def sparse_kv(self) -> bool:
+        """Learned sparse attention over K and V pages: ``index_topk`` on a
+        stack of full-attention K/V layers, one indexer key a TOKEN."""
+        return bool(self.index_topk) and not self.is_mla \
+            and self.layer_pattern is None and not self.draft_block \
+            and not self.first_dense and self.index_pool == 1
 
     def require_mha(self, who: str) -> None:
         if self.is_mla:
@@ -696,6 +918,8 @@ class Block:
         if self.qk_norm:
             slots["QNormS"] = "q_norm_s"
             slots["KNormS"] = "k_norm_s"
+        if self.sparse_kv:      # the indexer beside the K/V projections
+            slots.update(self._index_slots())
         if self.is_mla and self.attn_gate == "head":
             slots["AttnGateW"] = "attn_gate_w"
         slots["OutW"] = "out_w"
@@ -727,11 +951,15 @@ class Block:
              else dict(QaW="q_a_w", QaNormS="q_a_norm_s", QbW="q_b_w"))
         gate = dict(AttnGateW="attn_gate_w") if self.attn_gate == "head" \
             else {}
-        index = dict(IdxQW="idx_q_w", IdxKW="idx_k_w",
-                     IdxKNormS="idx_k_norm_s", IdxKNormB="idx_k_norm_b",
-                     IdxHeadW="idx_head_w") if self.index_topk else {}
+        index = self._index_slots() if self.index_topk else {}
         return dict(**q, KvaW="kv_a_w", KvaNormS="kv_a_norm_s",
                     KvbW="kv_b_w", **index, **gate, OutW="out_w")
+
+    @staticmethod
+    def _index_slots() -> Dict[str, str]:
+        return dict(IdxQW="idx_q_w", IdxKW="idx_k_w",
+                    IdxKNormS="idx_k_norm_s", IdxKNormB="idx_k_norm_b",
+                    IdxHeadW="idx_head_w")
 
     def _slots_by_kind(self) -> Dict[str, str]:
         """The planes of a stack held BY KIND (``plane_group`` says which
@@ -1006,10 +1234,26 @@ class LMSpec:
     hc_iters: int = 0
     hc_eps: float = 1e-6
     ffn_limit: float = 0.0
+    rope: str = "rope"
+    mrope_section: Tuple[int, ...] = ()
+    qk_norm_heads: bool = False
+    #: a vision tower in front of the stack (``VisionSpec``): a prompt's
+    #: placeholder rows come from it; its parameters are the model's
+    #: (``vision_planes`` / ``n_params``), the paged prefill op runs it
+    vision: Optional[VisionSpec] = None
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
             self.rope_scaling = RopeScaling(**self.rope_scaling)
+        if isinstance(self.vision, dict):
+            self.vision = VisionSpec(**self.vision)
+        self.mrope_section = tuple(self.mrope_section)
+        if self.vision is not None and (self.attn == "mla"
+                                        or self.layer_pattern is not None
+                                        or self.draft_block):
+            raise ValueError("vision: a tower stands in front of a stack of "
+                             "full-attention K/V layers (no layer_pattern, "
+                             "no drafting block)")
         if self.n_group > 1 and self.num_experts % self.n_group:
             raise ValueError(f"{self.num_experts} experts are not "
                              f"{self.n_group} equal groups")
@@ -1048,6 +1292,10 @@ class LMSpec:
                 "swiglu_moe needs num_experts >= experts_per_tok >= 1 and "
                 f"d_expert > 0 (got {self.num_experts}, "
                 f"{self.experts_per_tok}, {self.d_expert})")
+        if self.rope == "mrope" and 2 * sum(self.mrope_section) \
+                != self.head_dim:
+            raise ValueError(f"mrope_section {self.mrope_section} is not "
+                             f"the {self.head_dim // 2} pairs of a head")
         self.block  # validates the kinds
 
     @property
@@ -1063,6 +1311,28 @@ class LMSpec:
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    # what the block's attrs carry of the tower (``Block.vision_*``)
+    vision_heads = property(lambda self: self.vision.num_heads
+                            if self.vision else 0)
+    vision_patch = property(lambda self: self.vision.patch_size
+                            if self.vision else 0)
+    vision_merge = property(lambda self: self.vision.merge
+                            if self.vision else 0)
+    vision_eps = property(lambda self: self.vision.norm_eps
+                          if self.vision else 1e-6)
+    vision_theta = property(lambda self: self.vision.rope_theta
+                            if self.vision else 10000.0)
+
+    def vision_planes(self) -> List[tuple]:
+        """(slot, key, shape, fan, stacked) of the tower's and the merger's
+        parameters (``VisionSpec.planes``; scope names ``vision.<key>``);
+        [] without a tower."""
+        return self.vision.planes(self.d_model) if self.vision else []
+
+    def vision_param_count(self) -> int:
+        return sum(math.prod(shape) for _, _, shape, _, _
+                   in self.vision_planes())
 
     def layers_of(self, windowed: bool) -> int:
         """How many of the stack's layers are window (or full) layers:
@@ -1207,7 +1477,7 @@ class LMSpec:
         shapes = {
             # the indexer of a sparse latent layer: queries from the query
             # latent, ONE key a token (LayerNorm'd), a weight a head
-            "idx_q_w": ([rq, Hi * Di], (rq, Hi * Di)),
+            "idx_q_w": ([rq or d, Hi * Di], (rq or d, Hi * Di)),
             "idx_k_w": ([d, Di], (d, Di)),
             "idx_k_norm_s": ([Di], None), "idx_k_norm_b": ([Di], None),
             "idx_head_w": ([d, Hi], (d, Hi)),
@@ -1247,7 +1517,8 @@ class LMSpec:
             "dense_down_w": ([ff, d], (ff, d)),
             "ln1_s": ([d], None), "ln1_b": ([d], None),
             "qkv_w": ([d, d_q + 2 * d_kv], (d, d_q + 2 * d_kv)),
-            "q_norm_s": ([d_q], None), "k_norm_s": ([d_kv], None),
+            "q_norm_s": ([dh if self.qk_norm_heads else d_q], None),
+            "k_norm_s": ([dh if self.qk_norm_heads else d_kv], None),
             "out_w": ([d_q, d], (d_q, d)),
             "ln2_s": ([d], None), "ln2_b": ([d], None),
             "ff_w1": ([d, self.ffn_width], (d, self.ffn_width)),
@@ -1294,6 +1565,8 @@ class LMSpec:
             names += [f"mtp.{key}" for _, key, _, _ in self.draft_planes()]
             names += [f"mtp_stack.stack_{key}" for key
                       in self.draft_spec().block.stack_slots().values()]
+        names += [f"vision.{key}" for _, key, _, _, _
+                  in self.vision_planes()]
         return names
 
     def n_params(self) -> int:
@@ -1305,7 +1578,8 @@ class LMSpec:
         pos = 0 if self.use_rope else self.max_len * self.d_model
         final = self.d_model * (2 if self.block.norm == "layer_norm"
                                 and self.bias else 1)
-        return stack + emb + pos + final + self.draft_param_count()
+        return (stack + emb + pos + final + self.draft_param_count()
+                + self.vision_param_count())
 
     def draft_param_count(self) -> int:
         """Parameters of the drafting block alone (0 without one)."""

@@ -232,6 +232,24 @@ def draft_params(helper, spec):
     return ins
 
 
+def vision_params(helper, spec):
+    """The vision tower's and the merger's parameters (``LMSpec(vision=)``)
+    under their fixed names ``vision.<key>`` as the paged prefill op's input
+    slots; {} for a spec without a tower."""
+    from ..initializer import ConstantInitializer, XavierInitializer
+
+    ins = {}
+    for slot, key, shape, fan, _ in spec.vision_planes():
+        init = (XavierInitializer(fan_in=fan[0], fan_out=fan[1])
+                if fan is not None
+                else ConstantInitializer(1.0 if key.endswith("_s") else 0.0))
+        ins[slot] = [helper.create_parameter(
+            ParamAttr(name=f"vision.{key}"), shape=shape,
+            dtype=spec.param_dtype, is_bias=fan is None,
+            default_initializer=init, stored_dtype=True)]
+    return ins
+
+
 def lm_parameters(spec, main_program=None, startup_program=None):
     """Declare the parameters of ``spec``'s stacked LM under their fixed
     names (tok_emb, final_ln.*, lm_head.w, lm_stack.stack_*; a drafting
@@ -243,7 +261,8 @@ def lm_parameters(spec, main_program=None, startup_program=None):
     dict."""
     helper = LayerHelper("lm_parameters", main_program=main_program,
                          startup_program=startup_program)
-    return {**_shared_lm_params(helper, spec), **draft_params(helper, spec)}
+    return {**_shared_lm_params(helper, spec), **draft_params(helper, spec),
+            **vision_params(helper, spec)}
 
 
 def transformer_lm_generate(prompt, vocab_size=None, d_model=256,

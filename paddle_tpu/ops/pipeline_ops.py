@@ -14,6 +14,7 @@ here placement is a sharding spec and movement is an ICI ppermute.
 from __future__ import annotations
 
 import collections
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +30,7 @@ from ..lm_spec import (DRAFT_PLANES, DRAFT_SLOT_PREFIX, DRAFT_SLOTS,
                        Block, BlockNotSupportedError)
 from .common import amp_cast, maybe, mxu_precision, out, single
 from .moe_ops import moe_topk
+from .vision_tower import VISION_SLOTS, splice_media, vision_params
 
 _EPS = 1e-5
 #: what the train stack's layer checkpoint saves a layer under
@@ -156,6 +158,17 @@ def _scan_stack(kinds, body, carry, xs):
 
 def _require_uniform_planes(blk, who):
     """The one-scan ops slice EVERY plane by the layer's index."""
+    if blk.rope == "mrope" or blk.sparse_kv or blk.vision_heads:
+        raise BlockNotSupportedError(
+            f"{who} embeds token ids, rotates by ONE position axis and "
+            "attends every key; this spec brings "
+            + ", ".join(w for w, on in (
+                ("three-axis rotary ids (rope='mrope')", blk.rope == "mrope"),
+                ("learned sparse attention over K/V pages (index_topk)",
+                 blk.sparse_kv),
+                ("a vision tower (vision)", blk.vision_heads)) if on)
+            + ": the paged prefill / decode ops behind GenerationEngine / "
+            "Server run it")
     if blk.first_dense and not blk.attn_kinds:
         raise BlockNotSupportedError(
             f"{who} scans planes that all lead with the layer axis; this "
@@ -198,23 +211,50 @@ def _attn_proj(blk, p, h, pos0=0, rope=None, heads_first=True):
     q = qkv[..., :d_q]
     k = qkv[..., d_q:d_q + d_kv]
     v = qkv[..., d_q + d_kv:]
-    if blk.qk_norm:
+    if blk.qk_norm and not blk.qk_norm_heads:
         q = _rms(q, p["q_norm_s"], blk.norm_eps)
         k = _rms(k, p["k_norm_s"], blk.norm_eps)
 
-    def heads(a, n):
+    def heads(a, n, scale=None):
         a = a.reshape(b, t, n, head_d)
+        if scale is not None:       # RMSNorm a head, one scale of head_d
+            a = _rms(a, scale, blk.norm_eps)
         return a.transpose(0, 2, 1, 3) if heads_first else a
 
-    q, k, v = (heads(q, num_heads), heads(k, num_kv_heads),
+    per_head = blk.qk_norm and blk.qk_norm_heads
+    q, k, v = (heads(q, num_heads, p["q_norm_s"] if per_head else None),
+               heads(k, num_kv_heads, p["k_norm_s"] if per_head else None),
                heads(v, num_kv_heads))
-    if blk.use_rope if rope is None else rope:
+    if blk.rope == "mrope":
+        # pos0: the tokens' (temporal, height, width) ids [b, t, 3]
+        time_axis = 2 if heads_first else 1
+        q = _mrope(blk, q, pos0, time_axis)
+        k = _mrope(blk, k, pos0, time_axis)
+    elif blk.use_rope if rope is None else rope:
         time_axis = 2 if heads_first else 1
         q = rotary(q, pos0, blk.rope_theta, blk.rope_pairing,
                    time_axis=time_axis)
         k = rotary(k, pos0, blk.rope_theta, blk.rope_pairing,
                    time_axis=time_axis)
     return q, k, v
+
+
+def _mrope(blk, x, ids, time_axis=2):
+    """Three-axis rotary (``Block.rope`` "mrope") of heads x [b, H, t, dh]
+    (``time_axis`` 1: [b, t, H, dh]) by the tokens' ids [b, t, 3]: half-split
+    pairs, pair i turning by the id of the axis ``mrope_section`` gives it
+    (the first section[0] pairs the temporal id, ..) times theta^(-i / half)."""
+    half = x.shape[-1] // 2
+    inv = blk.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    axis = jnp.asarray([a for a, n in enumerate(blk.mrope_section)
+                        for _ in range(n)], jnp.int32)
+    ang = jnp.take(ids.astype(jnp.float32), axis, axis=-1) * inv  # [b,t,half]
+    other = 3 - time_axis
+    cos = jnp.expand_dims(jnp.cos(ang), other).astype(x.dtype)
+    sin = jnp.expand_dims(jnp.sin(ang), other).astype(x.dtype)
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                           axis=-1)
 
 
 def _sm_scale(blk):
@@ -1023,8 +1063,11 @@ def _scan_paged_layers(params, h, cache_k, cache_v, table, page_id,
     b, t, _ = h.shape
     whole = {k: params[k] for k in _RESIDENT_PLANES if k in params}
     params = {k: v for k, v in params.items() if k not in whole}
+    # (a K/V block with selection: ``cache_v`` is (V pool, indexer's pool))
     attend = _paged_layer_step(b, t, cache_k.shape[2], project, mask, finish,
                                blk if blk is not None and blk.is_mla
+                               else None,
+                               blk if blk is not None and blk.sparse_kv
                                else None)
     ix = (page_id.reshape(b, t), page_row.reshape(b, t))
     kinds = blk.kinds if blk is not None else None
@@ -1126,8 +1169,10 @@ def _dsa_project(blk, p, h):
     b, t, _ = h.shape
     Hi, Di = blk.index_heads, blk.index_dim
     hn = _norm(blk, h, p["ln1_s"], p.get("ln1_b"))
-    c_q = _rms(_mm(blk, "btd,dr->btr", hn, p["q_a_w"]), p["q_a_norm_s"],
-               blk.norm_eps)
+    # (a K/V layer has no query latent: its indexer reads the normed stream)
+    c_q = hn if not blk.is_mla else _rms(
+        _mm(blk, "btd,dr->btr", hn, p["q_a_w"]), p["q_a_norm_s"],
+        blk.norm_eps)
     q_i = _mm(blk, "btr,re->bte", c_q, p["idx_q_w"]).reshape(b, t, Hi, Di)
     k_i = _ln(_mm(blk, "btd,de->bte", hn, p["idx_k_w"]),
               p["idx_k_norm_s"].astype(jnp.float32),
@@ -1149,6 +1194,10 @@ def _index_write(blk, pool, l, k_i, ix_page, ix_row, pos, valid):
     G = blk.index_pool
     t = k_i.shape[1]
     f32 = jnp.float32
+    if G == 1:      # one key a token: a row write, no running mean
+        page = jnp.where(valid, ix_page, pool.shape[1])
+        return pool.at[l, page, ix_row].set(k_i.astype(pool.dtype),
+                                            mode="drop")
     k_i = jnp.where(valid[..., None], k_i.astype(f32), 0.0)
     j = pos % G                                         # place in its group
     acc = k_i
@@ -1261,6 +1310,56 @@ def _dsa_attend(blk, q_lat, q_i, w_i, ck, ci, l, tbl, pos):
         q_lat.reshape(b, H, n, tile, W).transpose(2, 0, 1, 3, 4),
         *_query_tiles(t, tile, q_i, w_i, pos)))         # [n, b, H, tile, r]
     return o.transpose(1, 2, 0, 3, 4).reshape(b, H, t, r)
+
+
+def _dsa_attend_kv(blk, q, q_i, w_i, ck, cv, ci, l, tbl, pos):
+    """Sparse attention of one K/V layer, the reads following the pick:
+    queries q [b, H, t, dh] at positions ``pos`` [b, t] against the pools ck /
+    cv [L, N, ps, Hkv dh] THROUGH the indexer's pool ci [L, N, ps, Di] (one
+    key a token). The row's indexer keys are scored (``_dsa_scores``), the
+    ``index_topk - 1`` best positions before the query picked exactly
+    (``lax.top_k``: ties to the lower index), its own added, and ONLY the
+    picked tokens' K and V rows are gathered and attended, all H heads over
+    the same set. The semantic ground truth of the selection on this kind,
+    and the path of every call the masked page walk cannot take. -> ctx
+    [b, t, H dh] float32. A chunk runs in query tiles whose gathered rows
+    stay under ``_DSA_TILE_BYTES``."""
+    b, H, t, dh = q.shape
+    ps, width = ck.shape[2:]
+    hkv = width // dh
+    n_keys = tbl.shape[1] * ps
+    k_pick = min(blk.index_topk - 1, n_keys)
+    keys = ci[l, tbl].reshape(b, n_keys, -1)
+    tile = _dsa_tile(t, 2 * b * (k_pick + 1) * width * ck.dtype.itemsize)
+
+    def attend(args):
+        qt, qi, wi, qpos = args     # [b, H, T, dh] [b, T, Hi, Di] ..
+        s, own = _dsa_scores(keys, qi, wi, qpos, 1)
+        top, pick = jax.lax.top_k(s, k_pick)
+        pick = jnp.concatenate([pick.astype(jnp.int32), own[..., None]], -1)
+        ok = jnp.concatenate([top > -jnp.inf,
+                              jnp.ones_like(own[..., None], bool)], -1)
+        page = jnp.take_along_axis(
+            tbl, (pick // ps).reshape(b, -1), axis=1).reshape(pick.shape)
+        T = qt.shape[2]
+        rk = ck[l, page, pick % ps].reshape(b, T, -1, hkv, dh)
+        rv = cv[l, page, pick % ps].reshape(b, T, -1, hkv, dh)
+        qg = qt.reshape(b, hkv, H // hkv, T, dh).astype(rk.dtype)
+        sc = jnp.einsum("bgrtd,btkgd->bgrtk", qg, rk,
+                        preferred_element_type=jnp.float32) * dh ** -0.5
+        sc = jnp.where(ok[:, None, None], sc, -jnp.inf)
+        pr = jax.nn.softmax(sc, axis=-1)
+        o = jnp.einsum("bgrtk,btkgd->btgrd", pr.astype(rv.dtype), rv,
+                       preferred_element_type=jnp.float32)
+        return o.reshape(b, T, H * dh)
+
+    if tile == t:
+        return attend((q, q_i, w_i, pos))
+    n = t // tile
+    o = jax.lax.map(attend, (
+        q.reshape(b, H, n, tile, dh).transpose(2, 0, 1, 3, 4),
+        *_query_tiles(t, tile, q_i, w_i, pos)))         # [n, b, tile, H dh]
+    return o.swapaxes(0, 1).reshape(b, t, H * dh)
 
 
 def _picked_groups(scores, own, k_pick):
@@ -1440,7 +1539,8 @@ def _gathered_mask(mask):
     return {k: v for k, v in mask.items() if k != "q_len"}
 
 
-def _paged_layer_step(b, t, ps, project, mask, finish, mla=None):
+def _paged_layer_step(b, t, ps, project, mask, finish, mla=None,
+                      sparse=None):
     """The per-layer step of the paged loop (``_scan_paged_layers`` says
     what it does): ``attend(h, ck, cv, l, layer_p, x_l, table, ix_page,
     ix_row, window=None, rope=None)`` -> (h, ck, cv, stats) against the
@@ -1450,7 +1550,14 @@ def _paged_layer_step(b, t, ps, project, mask, finish, mla=None):
     step, a verify tick, a prefill chunk: all on a chip only), else the
     table gathered (a window layer: its span) under
     ``reference_attention``. ``mla``: a latent block (``_mla_paged_step``:
-    one pool, cv None; the same rule over its one pool)."""
+    one pool, cv None; the same rule over its one pool). ``sparse``: a K/V
+    block with learned sparse attention (``Block.sparse_kv``): ``cv`` is then
+    the pair (V pool, the indexer's pool [L, N, ps, Di]: one key a token,
+    written beside the K and V rows); on a chip the pick rides into the SAME
+    two walks as a mask of ``group_rows`` 1 (``_dsa_pick``;
+    ``paged_attention.mask_supported``), everywhere else the attention
+    gathers the picked tokens' rows alone (``_dsa_attend_kv``)."""
+    from .. import profiler
     from ..kernels import paged_attention
     from ..kernels.flash_attention import reference_attention
 
@@ -1466,12 +1573,47 @@ def _paged_layer_step(b, t, ps, project, mask, finish, mla=None):
         q, k, v = (project(layer_p, h) if rope is None
                    else project(layer_p, h, rope))
         hkv = k.shape[1]
+        if sparse is not None:
+            cv, ci = cv
         ck = ck.at[l, ix_page, ix_row].set(token_rows(k).astype(ck.dtype))
         cv = cv.at[l, ix_page, ix_row].set(token_rows(v).astype(cv.dtype))
         # on a chip a decode step (one query token a row, keys j <
         # lengths), a verify tick and a prefill chunk each walk the block
         # table in one kernel; every call the kernels cannot take gathers
         on_walk = paged_attention.supported(q.shape[1] * q.shape[3], ck, t)
+        if sparse is not None:
+            # selection: the reads follow the pick, tick and chunk alike
+            if "lengths" in mask:
+                pos = (mask["lengths"] - 1)[:, None]
+                valid = jnp.ones_like(pos, bool)
+            else:
+                steps = jnp.arange(t, dtype=jnp.int32)[None, :]
+                pos = mask["q_pos0"][:, None] + steps
+                valid = steps < mask["q_len"][:, None]
+            q_i, k_i, w_i = _dsa_project(sparse, layer_p, h)
+            ci = _index_write(sparse, ci, l, k_i, ix_page, ix_row, pos, valid)
+            tick = t == 1 and set(mask) == {"lengths"} and on_walk
+            walks = paged_attention.mask_supported(
+                ck, tbl.shape[1], 1, chunk=not tick) and (
+                    tick or paged_attention.chunk_supported(q.shape, ck,
+                                                            mask))
+            if walks:
+                picked = _dsa_pick(sparse, q_i, w_i, ci, l, tbl, pos)
+            if walks and tick:
+                ctx = paged_attention.paged_attention_decode(
+                    q[:, :, 0], ck, cv, l, tbl, mask["lengths"],
+                    group_mask=picked[:, 0], group_rows=1)[:, None]
+            elif walks:
+                ctx = paged_attention.paged_attention_prefill(
+                    q, ck, cv, l, tbl, mask["q_pos0"], mask["q_len"],
+                    group_mask=picked, group_rows=1)
+            else:
+                ctx = _dsa_attend_kv(sparse, q.astype(ck.dtype), q_i, w_i, ck,
+                                     cv, ci, l, tbl, pos).astype(h.dtype)
+            profiler.global_stat.add_count(
+                "dsa/walk_calls" if walks else "dsa/gather_calls", 1)
+            h, stats = finish(layer_p, h, ctx, x_l)
+            return h, ck, (cv, ci), stats
         if t == 1 and set(mask) == {"lengths"} and on_walk:
             ctx = paged_attention.paged_attention_decode(
                 q[:, :, 0], ck, cv, l, tbl, mask["lengths"],
@@ -1980,7 +2122,8 @@ def _window_ins(blk, ins, targets):
              optional_inputs=(_LM_OPTIONAL + _SAMPLING_SLOTS + _WINDOW_SLOTS
                               + _POOL_SLOTS + STATE_SLOTS + ("StateSlot",)
                               + SNAPSHOT_SLOTS + DRAFT_SLOTS
-                              + ("DraftNext",)),
+                              + ("DraftNext", "PosIds", "MediaRow", "Pixels")
+                              + VISION_SLOTS),
              needs_rng=lambda attrs: (attrs.get("temperature") or 0) > 0)
 def transformer_stack_paged_prefill(attrs, ins, rng=None):
     """Prefill ONE CHUNK of each row's prompt into its block-table pages.
@@ -2086,6 +2229,25 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
     x = _embed_rows(tok_emb, chunk)
     if pos_emb is not None:
         x = x + pos_emb[jnp.clip(pos, 0, pos_emb.shape[0] - 1)]
+    if blk.vision_heads:
+        # a tower in front of the stack: "Pixels" [b, Fc, S, S, 3] uint8 the
+        # frames the chunk touches, "MediaRow" [b, Tc] the merged row of
+        # those a placeholder position takes (-1: the embedding's), and the
+        # tower's parameters (``vision_tower.VISION_SLOTS``): "VisPatchW"
+        # "VisPatchB" "VisPosEmb" "VisLn1S" "VisLn1B" "VisQkvW" "VisQkvB"
+        # "VisOutW" "VisOutB" "VisLn2S" "VisLn2B" "VisFc1W" "VisFc1B"
+        # "VisFc2W" "VisFc2B" "VisPostLnS" "VisPostLnB" "VisMergeLnS"
+        # "VisMergeLnB" "VisMergeW1" "VisMergeB1" "VisMergeW2" "VisMergeB2"
+        x = splice_media(blk, functools.partial(_mm, blk), vision_params(ins),
+                         x, single(ins, "Pixels"),
+                         single(ins, "MediaRow").astype(jnp.int32))
+    # "PosIds" [b, 3 Tc] int32: under ``rope="mrope"`` every token's
+    # (temporal, height, width) id; pages, causality and the selection keep
+    # the sequence index (``start``)
+    rot_pos = (single(ins, "PosIds").astype(jnp.int32).reshape(b, Tc, 3)
+               if blk.rope == "mrope" else start)
+    if blk.sparse_kv:       # the indexer's pool rides beside the V pool
+        cache_v = (cache_v, cache_i)
     states = {}
     if blk.attn_kinds:
         # "StateSlot" [b] int32: the slot whose state each row reads and
@@ -2116,10 +2278,12 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
         # "CacheKW" "CacheVW" "BlockTableW": the window kind (_window_ins)
         h, cache_k, cache_v, stats, win = _scan_paged_layers(
             params, x, cache_k, cache_v, table, page_id, page_row,
-            _paged_project(blk, start), chunk_mask(start, lengths),
+            _paged_project(blk, rot_pos), chunk_mask(start, lengths),
             lambda p, h, ctx, _x_l: _attn_out_ffn(
                 blk, p, h, ctx, dense="dense_gate_w" in p), blk=blk,
             win=_window_ins(blk, ins, lambda tw: (page_of(tw), page_row)))
+        if blk.sparse_kv:
+            cache_v, cache_i = cache_v
     at_last = jnp.clip(lengths, 1, Tc) - 1
     last = h[jnp.arange(b), at_last]  # [b, d]
     logits = _logits_fn(ln_s, ln_b, head_w, blk)(last)
@@ -2153,7 +2317,7 @@ def transformer_stack_paged_prefill(attrs, ins, rng=None):
 @register_op("transformer_stack_paged_decode",
              optional_inputs=(_LM_OPTIONAL + _SAMPLING_SLOTS + _WINDOW_SLOTS
                               + _POOL_SLOTS + STATE_SLOTS + DRAFT_SLOTS
-                              + ("Draft",)),
+                              + ("Draft", "RopeOffset")),
              needs_rng=lambda attrs: (attrs.get("temperature") or 0) > 0)
 def transformer_stack_paged_decode(attrs, ins, rng=None):
     """One decode step over every slot's paged context.
@@ -2264,13 +2428,22 @@ def transformer_stack_paged_decode(attrs, ins, rng=None):
         win = None
     else:
         # "CacheKW" "CacheVW" "BlockTableW": the window kind (_window_ins)
+        # "RopeOffset" [S] int32: under ``rope="mrope"`` a slot's three ids
+        # are its position + this (last id + 1 - sequence length: what its
+        # clips shortened the ids by); the cache index stays the position
+        rot_pos = pos if blk.rope != "mrope" else jnp.broadcast_to(
+            (pos + single(ins, "RopeOffset").astype(jnp.int32))[
+                :, None, None], (S, 1, 3))
         h1, cache_k, cache_v, stats, win = _scan_paged_layers(
-            params, h1, cache_k, cache_v, table, page_id, page_row,
-            _paged_project(blk, pos), dict(lengths=pos + 1),
+            params, h1, cache_k,
+            (cache_v, cache_i) if blk.sparse_kv else cache_v, table, page_id,
+            page_row, _paged_project(blk, rot_pos), dict(lengths=pos + 1),
             lambda p, h, ctx, _x_l: _attn_out_ffn(
                 blk, p, h, ctx, dense="dense_gate_w" in p), blk=blk,
             win=_window_ins(blk, ins,
                             lambda tw: (tw[srange, pos // ps], page_row)))
+        if blk.sparse_kv:
+            cache_v, cache_i = cache_v
     logits = _logits_fn(ln_s, ln_b, head_w, blk)(h1[:, 0])
     nxt = _pick_rows(attrs, ins, rng, head_w.shape[1], logits)
     outs = _paged_outs(blk, stats, win, NextTok=nxt.astype(tok.dtype),

@@ -64,7 +64,7 @@ class Request:
     """
 
     __slots__ = ("payload", "meta", "future", "enqueue_t", "deadline",
-                 "span", "queue_span")
+                 "span", "queue_span", "media")
 
     def __init__(self, payload: Any, meta: dict,
                  timeout_ms: Optional[float]):
@@ -76,6 +76,10 @@ class Request:
                          if timeout_ms else None)
         self.span = None
         self.queue_span = None
+        #: what ``submit``'s check and then admission made of the payload's
+        #: media (``serving.media.MediaPlan``; None: a prompt of token ids
+        #: alone, or one nobody has looked at yet)
+        self.media = None
 
     def expired(self, now: Optional[float] = None) -> bool:
         return (self.deadline is not None
@@ -145,12 +149,15 @@ class DynamicBatcher:
 
     # -- admission ---------------------------------------------------------
     def submit(self, payload: Any, timeout_ms: Optional[float] = None,
-               **meta) -> Future:
+               *, media=None, **meta) -> Future:
         """Enqueue a request; raises QueueFullError at capacity (the
-        backpressure contract) and EngineClosedError after close()."""
+        backpressure contract) and EngineClosedError after close().
+        ``media``: what ``Server.submit``'s check made of the payload's
+        media (``Request.media``)."""
         req = Request(payload, meta,
                       timeout_ms if timeout_ms is not None
                       else self.default_timeout_ms)
+        req.media = media
         req.begin_trace()
         with self._cond:
             if self._closed:

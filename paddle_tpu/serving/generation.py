@@ -116,6 +116,13 @@ def spec_from_program_dict(pd: dict,
     block_kw = dataclasses.asdict(blk)
     block_kw.pop("shared_expert")       # the spec's d_shared says it
     block_kw.pop("param_dtype")         # the stored parameters' own says it
+    tower = [block_kw.pop(k) for k in list(block_kw)
+             if k.startswith("vision_")]
+    if blk.vision_heads:
+        raise BlockNotSupportedError(
+            "a saved program's attrs do not give a vision tower's sizes "
+            f"back ({tower}): build the LMSpec (vision=VisionSpec(..)) and "
+            "load the parameters into its scope")
     return LMSpec(vocab_size=vocab, d_model=d_model,
                   n_layers=var["lm_stack.stack_ln1_s"]["shape"][0],
                   max_len=max_len, param_dtype=str(var["tok_emb"]["dtype"]),
@@ -362,7 +369,7 @@ class _Slot:
                  "timeline", "truncate_to", "held", "shared_tokens",
                  "prefill_done", "state", "sampling", "stop_matcher",
                  "mask_proc", "beam_job", "role", "xrow", "resumed",
-                 "prefix_key", "snap_from", "waited")
+                 "prefix_key", "snap_from", "waited", "media", "rope_off")
 
     def __init__(self, request: Request, prompt: np.ndarray,
                  max_new: int, eos_id: Optional[int],
@@ -387,6 +394,11 @@ class _Slot:
         # another slot's prefill of its prefix
         self.snap_from: Optional[int] = None
         self.waited = False
+        # a request with media (``serving.media.MediaPlan``; its pixels are
+        # let go once the prompt is cached) and, under ``rope="mrope"``, what
+        # a decoding slot adds to its position for its three rotary ids
+        self.media = request.media
+        self.rope_off = self.media.rope_off if self.media else 0
         self.state = "decode"            # "prefill" while chunks stream in
                                          # ("hold"/"beam_wait" for beams)
         self.sampling = sampling or SamplingParams()
@@ -567,7 +579,8 @@ class GenerationEngine:
                  prefix_sharing: bool = True,
                  beam_width: int = 0, mask_plane: bool = True,
                  share_cache_with: Optional["GenerationEngine"] = None,
-                 snapshot_stride: int = 0, n_snapshots: int = 0):
+                 snapshot_stride: int = 0, n_snapshots: int = 0,
+                 media_resolver=None):
         if slots < 1:
             raise ValueError("need at least one decode slot")
         if page_size is not None and page_size < 1:
@@ -575,6 +588,14 @@ class GenerationEngine:
         if beam_width < 0:
             raise ValueError("beam_width must be >= 0")
         self.spec = spec
+        # a spec with a vision tower: ``media_resolver(prompt_ids, (first
+        # placeholder position, frames)) -> frames`` resolves, at admission,
+        # a vision span whose payload brought no media
+        self._vision = spec.vision
+        self._mrope = spec.rope == "mrope"
+        self.media_resolver = media_resolver
+        if media_resolver is not None and self._vision is None:
+            raise ValueError("media_resolver: this spec has no vision tower")
         self.scope = scope or Scope()
         self.slots = int(slots)
         self.tmax = int(max_seq_len or spec.max_len)
@@ -923,6 +944,19 @@ class GenerationEngine:
                 self._ones = {
                     rows: jnp.ones((rows, self.spec.vocab_size), jnp.float32)
                     for rows in {self.slots, *self.prefill_batch_buckets}}
+        #: (rows, frames) -> the pixels of a prefill call none of whose rows
+        #: holds a frame: zeros on the device (no frame of them is encoded)
+        self._no_pixels: Dict[tuple, Any] = {}
+        if self._vision is not None:
+            from ..core.types import to_dtype as _dt
+
+            self.metrics.set_gauge(
+                "mem/vision_param_bytes",
+                float(self.spec.vision_param_count()
+                      * np.dtype(_dt(self.spec.param_dtype)).itemsize))
+        if self.spec.index_topk:
+            self.metrics.set_gauge("mem/index_bytes_per_token",
+                                   float(self.spec.index_bytes_per_token))
         self.metrics.set_gauge("mem/state_bytes_per_slot",
                                float(self.spec.state_bytes_per_slot))
         if self._draft:
@@ -997,14 +1031,17 @@ class GenerationEngine:
         x ``tc`` tokens runs over its ``mamba2`` layers."""
         return self._state_layers * rows * -(-tc // self.spec.mamba_chunk)
 
-    def _lm_ins(self, helper):
+    def _lm_ins(self, helper, prefill: bool = False):
         """The ops' weight slots; a weight with an AMP operand copy is
         bound to the copy (the float32 parameter stays declared: it is
         resident, and the memory analysis prices both)."""
-        from ..models.transformer import _shared_lm_params, draft_params
+        from ..models.transformer import (_shared_lm_params, draft_params,
+                                          vision_params)
 
         ins = {**_shared_lm_params(helper, self.spec),
                **draft_params(helper, self.spec)}
+        if prefill:     # (a tick embeds token ids alone)
+            ins.update(vision_params(helper, self.spec))
         for slot, (var,) in ins.items():
             if var.name in self._operands:
                 ins[slot] = [helper.create_global_variable(
@@ -1037,6 +1074,16 @@ class GenerationEngine:
                 [("serving.chunk", "Chunk", tc, "int32", self.pad_id),
                  ("serving.start", "StartPos", 0, "int32", 0),
                  ("serving.chunk_len", "Lengths", 0, "int32", 0)])
+        if self._mrope:
+            # three-axis rotary: a chunk's (temporal, height, width) ids a
+            # token; what a decoding slot adds to its position
+            cols.append(("serving.rope_off", "RopeOffset", 0, "int32", 0)
+                        if tc is None else
+                        ("serving.pos_ids", "PosIds", 3 * tc, "int32", 0))
+        if self._vision is not None and tc is not None:
+            # the merged row (of the call's frames) a position takes; -1:
+            # the token's own embedding
+            cols.append(("serving.media_row", "MediaRow", tc, "int32", -1))
         if self._draft:
             # the tick's second position (-1: none); the token after a
             # chunk's last (-1: the one the call samples)
@@ -1071,7 +1118,13 @@ class GenerationEngine:
 
     @property
     def _prefill_feed_names(self):
-        return [PREFILL_PLANE] + ["serving.mask"] * self.mask_plane
+        return ([PREFILL_PLANE] + ["serving.mask"] * self.mask_plane
+                + ["serving.pixels"] * (self._vision is not None))
+
+    def _chunk_frames(self, tc: int) -> int:
+        """Frames a chunk of ``tc`` tokens can touch (whole ones and a
+        partial one at each end)."""
+        return -(-tc // self._vision.tokens_per_frame) + 1
 
     @property
     def _decode_feed_names(self):
@@ -1087,6 +1140,11 @@ class GenerationEngine:
             ins["Mask"] = [data_layer(
                 "serving.mask", shape=[V] if rows is None else [rows, V],
                 dtype="float32", append_batch_size=rows is None)]
+        if self._vision is not None and tc is not None:
+            ins["Pixels"] = [data_layer(
+                "serving.pixels", shape=[self._chunk_frames(tc),
+                                         *self._vision.frame_shape],
+                dtype="uint8", append_batch_size=True)]
         return ins
 
     def _expert_out_vars(self, helper):
@@ -1183,7 +1241,7 @@ class GenerationEngine:
                 dtype="int64", stop_gradient=True)
             held = {**pools, **self._pool_io(helper, self._caches[1:]),
                     **self._state_io(helper, snapshots=tc is not None)}
-            ins.update({**held, **self._lm_ins(helper)})
+            ins.update({**held, **self._lm_ins(helper, tc is not None)})
             outs = {"NextTok": [nxt], **held}
             # (a drafting block's rows lie below the stack's: 2 + 2 a slot)
             outs.update(self._beam_out_vars(
@@ -1195,7 +1253,8 @@ class GenerationEngine:
         fetches = [nxt.name] + [v[0].name for k, v in sorted(outs.items())
                                 if k in ("TopV", "TopI", "ExpertCounts")]
         self._transpile(
-            prog, [self._plane(tc).name] + ["serving.mask"] * self.mask_plane,
+            prog, self._decode_feed_names if tc is None
+            else [self._plane(tc).name] + self._prefill_feed_names[1:],
             fetches, f"transpile/{what}{'' if tc is None else tc}/")
         return prog, outs
 
@@ -1357,6 +1416,10 @@ class GenerationEngine:
         if self.mask_plane:
             feed["serving.mask"] = cols.get("serving.mask",
                                             self._ones[len(arr)])
+        if self._vision is not None and tc is not None:
+            feed["serving.pixels"] = cols["serving.pixels"] \
+                if "serving.pixels" in cols \
+                else self._blank_pixels(len(arr), tc)
         on_host = [v for v in feed.values() if isinstance(v, np.ndarray)]
         what = "decode" if tc is None else "prefill"
         self.metrics.inc(f"{what}_feed_host_arrays", len(on_host))
@@ -1380,6 +1443,50 @@ class GenerationEngine:
                 self.metrics.inc(f"prefill_attn_table_pages{kind}",
                                  table.size)
         return CallFeed(feed, cols)
+
+    def _blank_pixels(self, rows: int, tc: int):
+        key = (rows, self._chunk_frames(tc))
+        if key not in self._no_pixels:
+            import jax.numpy as jnp
+
+            with self.executor.device_ctx():
+                self._no_pixels[key] = jnp.zeros(
+                    key + self._vision.frame_shape, jnp.uint8)
+        return self._no_pixels[key]
+
+    def _media_rows(self, st: _Slot, cols: dict, row: int, start: int,
+                    k: int) -> None:
+        """Row ``row`` of a prefill call that takes ``st``'s prompt tokens
+        ``start .. start + k - 1``: their rotary ids, and for a chunk that
+        holds placeholder positions the frames it touches (at most
+        ``_chunk_frames``; a frame two chunks share is fed, and encoded,
+        with each) and the merged row each position takes."""
+        plan = st.media
+        if self._vision is not None:    # what vision_tokens_prefilled is of
+            self.metrics.inc("prompt_tokens_prefilled", k)
+        if self._mrope:
+            ids = (plan.ids[start:start + k] if plan is not None else
+                   np.arange(start, start + k, dtype=np.int32)[:, None]
+                   .repeat(3, axis=1))
+            cols["serving.pos_ids"][row, :3 * k] = ids.reshape(-1)
+        if plan is None or self._vision is None:
+            return
+        at = plan.row[start:start + k]
+        on = at >= 0
+        if not on.any():
+            return
+        tpf = self._vision.tokens_per_frame
+        f0, f1 = int(at[on][0]) // tpf, int(at[on][-1]) // tpf
+        tc = cols["serving.chunk"].shape[1]
+        if "serving.pixels" not in cols:
+            cols["serving.pixels"] = np.zeros(
+                (len(cols["serving.start"]), self._chunk_frames(tc))
+                + self._vision.frame_shape, np.uint8)
+        cols["serving.pixels"][row, :f1 - f0 + 1] = plan.frames[f0:f1 + 1]
+        cols["serving.media_row"][row, :k] = np.where(on, at - f0 * tpf, -1)
+        self.metrics.inc("media_bytes_fed", int(plan.frames[f0:f1 + 1].nbytes))
+        self.metrics.inc("vision_frames_encoded", f1 - f0 + 1)
+        self.metrics.inc("vision_tokens_prefilled", int(on.sum()))
 
     def _count_selection(self, tc: Optional[int], cols: dict) -> None:
         """What a call's sparse latent layers select, ONE layer's worth
@@ -1638,11 +1745,14 @@ class GenerationEngine:
         others = [(cache.index, held.pages) for cache, held
                   in zip(self._caches[1:], st.held[1:])]
 
+        media = st.media.page_media if st.media else None
+
         def insert(key, toks, i):   # (a long prompt's every page, every
+            m = media[i] if media else None
             for index, own in others:   # chunk: nothing is made a page)
                 if i < len(own) and own[i]:     # still held by the slot
-                    index.insert(key, toks, own[i])
-            return self.prefix_index.insert(key, toks, pages[i])
+                    index.insert(key, toks, own[i], m)
+            return self.prefix_index.insert(key, toks, pages[i], m)
 
         n_full = min(st.prefill_done, prompt.size) // ps
         key = b""
@@ -1662,7 +1772,8 @@ class GenerationEngine:
             with trace.span("serving/register_prefix"):
                 self._register_prefix(st)
 
-    def _pages_in_flight(self, prompt: np.ndarray, shared: int) -> List[int]:
+    def _pages_in_flight(self, prompt: np.ndarray, shared: int,
+                         media=None) -> List[int]:
         """The pages some PREFILLING slot holds for full pages of
         ``prompt`` beyond its ``shared``-token hit in the index (the
         longest such run): a request that arrives while a long shared
@@ -1683,6 +1794,16 @@ class GenerationEngine:
                 continue
             differ = np.flatnonzero(st.prompt[:n] != prompt[:n])
             common = (int(differ[0]) if differ.size else n) // ps * ps
+            # (two clips of one length have the same ids: their pages'
+            # media digests tell them apart)
+            theirs = st.media.page_media if st.media else None
+            if media or theirs:
+                same = 0
+                while same < common // ps and (
+                        (media[same] if media else None)
+                        == (theirs[same] if theirs else None)):
+                    same += 1
+                common = same * ps
             if common > shared + len(best) * ps:
                 best = st.held[0].pages[shared // ps:common // ps]
         return best
@@ -1710,7 +1831,9 @@ class GenerationEngine:
         i, last = start // ps, (int(st.prompt.size) - 1) // ps
         key, hits, best = st.prefix_key, [], None
         while i < last:
-            hit = index.page_after(key, st.prompt[i * ps:(i + 1) * ps])
+            hit = index.page_after(
+                key, st.prompt[i * ps:(i + 1) * ps],
+                st.media.page_media[i] if st.media else None)
             if hit is None:
                 break
             key = hit[0]
@@ -1823,6 +1946,8 @@ class GenerationEngine:
             raise BadRequestError(f"bad prompt payload: {exc}")
         if prompt.size < 1:
             raise BadRequestError("empty prompt")
+        if self._vision is not None:
+            self._plan_media(req)
         max_new = int(meta.get("max_new_tokens")
                       or self.default_max_new_tokens)
         if max_new < 1:
@@ -1846,6 +1971,12 @@ class GenerationEngine:
                 beam.validate(self.spec.vocab_size)
         except (ValueError, TypeError) as exc:
             raise BadRequestError(str(exc))
+        if (beam is not None or meta.get("resume_tokens")) and (
+                self._mrope or self._vision is not None):
+            raise BlockNotSupportedError(
+                "beam search and resume-from-token fork or re-enter a "
+                "slot's TOKENS; this spec's slots also carry a rotary "
+                "offset and media rows (rope='mrope' / vision): not run yet")
         if beam is not None or meta.get("resume_tokens"):
             self.spec.block.require_stateless(
                 "beam search (a fork shares its parent's pages)"
@@ -1885,6 +2016,49 @@ class GenerationEngine:
                     f"prompt ({prompt.size}) + max_tokens ({max_new}) "
                     f"exceeds the serving context ({self.tmax})")
         return prompt, max_new, eos, sampling, beam
+
+    def check_payload(self, payload):
+        """What ``Server.submit`` asks of an engine with a vision tower
+        before it queues a request: that the prompt's vision spans are whole
+        frames and the payload's ``media`` fits them (``serving.media.
+        check_payload``). -> the prompt's unresolved ``MediaPlan`` (None: no
+        span), which rides the request to admission. Raises
+        ``BadRequestError``, counted (``media_requests_refused``). An engine
+        without a tower has nothing to check: ``Server`` never calls it and
+        a ``media`` key is, as any other unknown key, not read."""
+        if self._vision is None:
+            return None
+        from .media import check_payload
+
+        try:
+            return check_payload(self._vision, payload,
+                                 self.media_resolver is not None, self._mrope)
+        except BadRequestError:
+            self.metrics.inc("media_requests_refused")
+            raise
+
+    def _plan_media(self, req: Request) -> None:
+        """Admission's part of a request's media (once: a deferred request
+        keeps its plan): the resolver's call for spans that brought none,
+        the frames' digests. The layout is ``submit``'s where the request
+        came through a ``Server`` (``req.media``), made here otherwise."""
+        if req.media is not None and req.media.page_media is not None:
+            return
+        from .media import plan_media
+
+        with trace.span("serving/media_resolve"):
+            t0 = time.perf_counter()
+            try:
+                req.media = plan_media(self._vision, req.payload,
+                                       self.page_size, self.media_resolver,
+                                       self._mrope, plan=req.media)
+            except BadRequestError:
+                self.metrics.inc("media_requests_refused")
+                raise
+            if req.media is not None:
+                self.metrics.inc("media_spans_admitted", len(req.media.spans))
+                self.metrics.observe_hist("media_resolve",
+                                          time.perf_counter() - t0)
 
     def admit(self, requests: List[Request]) -> int:
         """Admit a group of requests: prefix-cache lookup + page
@@ -2000,7 +2174,8 @@ class GenerationEngine:
             cutback = matched - shared
         elif self.prefix_index is not None:
             # a hit is as long as EVERY kind's index holds it
-            found = [cache.index.lookup(prompt) for cache in caches]
+            media = req.media.page_media if req.media else None
+            found = [cache.index.lookup(prompt, media) for cache in caches]
             shared, key = min(f[0] for f in found), found[0][2]
             hits = [f[1][:self._entries_for(shared)] for f in found]
             if self._draft and shared:
@@ -2013,7 +2188,8 @@ class GenerationEngine:
                 # ... and the pages a slot is still prefilling for the
                 # same tokens: held from now on, written by whichever of
                 # the two comes to a chunk first (``_adopt_prefilled``)
-                hits[0] = hits[0] + self._pages_in_flight(prompt, shared)
+                hits[0] = hits[0] + self._pages_in_flight(prompt, shared,
+                                                          media)
         cow = 1 if shared == plen else 0  # generation writes a shared page
         # of the hit a slot holds what its next query reaches, by kind
         keeps = [cache.first_entry(shared if shared < plen else plen - 1)
@@ -2080,6 +2256,9 @@ class GenerationEngine:
         if shared:
             self.metrics.inc("prefix_hits")
             self.metrics.inc("prefix_hit_tokens", shared)
+            if req.media is not None:
+                self.metrics.inc("media_prefix_hit_tokens",
+                                 int((req.media.row[:shared] >= 0).sum()))
         if req.span is not None:
             req.span.set_attrs(slot=slot, prompt_len=plen,
                                prefix_hit_tokens=shared)
@@ -2164,6 +2343,7 @@ class GenerationEngine:
                 cols["serving.chunk"][row, :r] = st.prompt[st.prefill_done:]
                 cols["serving.start"][row] = st.prefill_done
                 cols["serving.chunk_len"][row] = r
+                self._media_rows(st, cols, row, st.prefill_done, r)
                 if self._draft:     # the prompt ends here
                     cols["serving.draft_next"][row] = -1
                 self._table_rows(st, cols, row, st.prefill_done,
@@ -2210,6 +2390,8 @@ class GenerationEngine:
         that ended it holds its first token (a beam parent's top-K)."""
         st = self._slots[slot]
         st.state = "decode"
+        if st.media is not None:
+            st.media.frames = None      # the prompt is cached: pixels go
         if st.role == "beam_parent":
             # the parent's top-K row expands the hypothesis set; the
             # job takes over the slot bookkeeping from here
@@ -2383,6 +2565,7 @@ class GenerationEngine:
             cols["serving.chunk"][0, :k] = st.prompt[start0:start0 + k]
             cols["serving.start"][0] = start0
             cols["serving.chunk_len"][0] = k
+            self._media_rows(st, cols, 0, start0, k)
             if self._draft:
                 cols["serving.draft_next"][0] = (
                     st.prompt[start0 + k] if start0 + k < plen else -1)
@@ -2437,6 +2620,8 @@ class GenerationEngine:
             st = slots[s]
             tok[s] = self._tok[s]
             pos[s] = self._pos[s]
+            if self._mrope:
+                cols["serving.rope_off"][s] = st.rope_off
             # step = tokens this request has sampled so far — a pure
             # function of the request, never of the batch around it
             self._slot_sampling_feed(s, st, cols, step=len(st.generated))
@@ -2961,6 +3146,11 @@ class GenerationEngine:
         block = self.spec.block
         block.require_one_kind(who)
         block.require_mha(who)
+        if self._mrope or self._vision is not None:
+            raise BlockNotSupportedError(
+                f"{who} hands a slot's pages and position over; this "
+                "spec's slots also carry a rotary offset and media rows "
+                "(rope='mrope' / vision): not run yet")
         block.require_stateless(who)
         block.require_no_draft(who)
 
@@ -3073,7 +3263,8 @@ class GenerationEngine:
         max_new = max_new_tokens or self.default_max_new_tokens
         if sampling is None or isinstance(sampling, SamplingParams):
             sampling = [sampling] * len(list(prompts))
-        reqs = [Request({"prompt": p},
+        # (a prompt may be a payload of its own: {"prompt": ids, "media": ..})
+        reqs = [Request(p if isinstance(p, dict) else {"prompt": p},
                         {"max_new_tokens": max_new, "eos_id": eos_id,
                          "sampling_params": sp}, None)
                 for p, sp in zip(prompts, sampling)]

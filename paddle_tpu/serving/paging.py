@@ -152,14 +152,21 @@ class PagePool:
                 "shared": int(np.sum(self._ref[1:] > 1))}
 
 
-def chain_key(parent: Optional[bytes], tokens: Sequence[int]) -> bytes:
+def chain_key(parent: Optional[bytes], tokens: Sequence[int],
+              media: Optional[bytes] = None) -> bytes:
     """Content-derived prefix key: digest of (parent key, page tokens).
     Two prompts share page i iff their first i pages carry identical
     tokens — the digest chain makes the whole-prefix comparison O(1)
-    per page regardless of depth."""
+    per page regardless of depth. ``media``: the digest of the pixels
+    whose rows lie on the page (every vision placeholder has ONE id: two
+    clips of equal length differ only here); a page of text alone has
+    none, and its key is what it always was."""
     h = hashlib.blake2b(digest_size=16)
     h.update(parent or b"\x00")
     h.update(np.asarray(tokens, np.int64).tobytes())
+    if media:
+        h.update(b"\x01media")
+        h.update(media)
     return h.digest()
 
 
@@ -212,14 +219,16 @@ class PrefixIndex:
     def __len__(self) -> int:
         return len(self._entries)
 
-    def _walk(self, prompt: np.ndarray, n_full: int):
+    def _walk(self, prompt: np.ndarray, n_full: int, media=None):
         """The chain walk every lookup makes: (key, page) of each cached
         full page of ``prompt`` among its first ``n_full``, in order, up to
-        the first that is not cached (hits, one miss and recency counted)."""
+        the first that is not cached (hits, one miss and recency counted).
+        ``media``: the prompt's media digest a page (``chain_key``)."""
         ps = self._pool.page_size
         key: Optional[bytes] = None
         for i in range(n_full):
-            key = chain_key(key, prompt[i * ps:(i + 1) * ps])
+            key = chain_key(key, prompt[i * ps:(i + 1) * ps],
+                            media[i] if media else None)
             page = self._entries.get(key)
             if page is None:
                 self.misses += 1
@@ -228,7 +237,8 @@ class PrefixIndex:
             self.hits += 1
             yield key, page
 
-    def lookup(self, prompt: np.ndarray) -> Tuple[int, List[int], bytes]:
+    def lookup(self, prompt: np.ndarray, media=None
+               ) -> Tuple[int, List[int], bytes]:
         """Longest cached prefix of ``prompt``: returns
         ``(shared_tokens, page_ids, last_matched_key)``. Walks full
         pages, then tries the exact partial tail; ``shared_tokens`` is a
@@ -238,12 +248,12 @@ class PrefixIndex:
         n_full = len(prompt) // ps
         key: Optional[bytes] = None
         pages: List[int] = []
-        for key, page in self._walk(prompt, n_full):
+        for key, page in self._walk(prompt, n_full, media):
             pages.append(page)
         shared = len(pages) * ps
         tail = prompt[n_full * ps:]
         if len(pages) == n_full and len(tail):
-            k = chain_key(key, tail)
+            k = chain_key(key, tail, media[n_full] if media else None)
             page = self._entries.get(k)
             if page is not None:
                 self._entries.move_to_end(k)
@@ -255,21 +265,22 @@ class PrefixIndex:
                 self.misses += 1
         return shared, pages, key or b""
 
-    def page_after(self, parent_key: bytes, tokens: Sequence[int]
+    def page_after(self, parent_key: bytes, tokens: Sequence[int],
+                   media: Optional[bytes] = None
                    ) -> Optional[Tuple[bytes, int]]:
         """The cached page that continues ``parent_key`` (b"" for the
         first page) by the full page ``tokens``, with its key; None when
         it is not cached. No statistics, no reference taken."""
-        k = chain_key(parent_key or None, tokens)
+        k = chain_key(parent_key or None, tokens, media)
         page = self._entries.get(k)
         return None if page is None else (k, page)
 
     def insert(self, parent_key: bytes, tokens: Sequence[int],
-               page: int) -> bytes:
+               page: int, media: Optional[bytes] = None) -> bytes:
         """Cache ``page`` as the prefix continuation ``tokens`` of
         ``parent_key`` (b"" for the first page). Takes one pool
         reference; a no-op (key returned) when already cached."""
-        k = chain_key(parent_key or None, tokens)
+        k = chain_key(parent_key or None, tokens, media)
         if k not in self._entries:
             self._pool.incref(page)
             self._entries[k] = page

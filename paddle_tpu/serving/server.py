@@ -79,6 +79,12 @@ class Server:
         self.engines = list(engine) if isinstance(
             engine, (list, tuple)) else [engine]
         self.metrics = metrics or self.engines[0].metrics
+        # (None for every engine without a vision tower: ``submit`` asks
+        # nothing of their payloads)
+        self._check_media = (
+            self.engines[0].check_payload
+            if getattr(getattr(self.engines[0], "spec", None), "vision",
+                       None) is not None else None)
         # ids this replica answers a "model"/"tenant" request field
         # with; anything else is a typed 404 — an unknown id must never
         # silently fall through to the default engine
@@ -306,7 +312,8 @@ class Server:
                **meta) -> Future:
         """Enqueue a request; returns a Future. Raises QueueFullError on
         backpressure. For generation engines the payload is a prompt (or
-        {"prompt": ids}) with max_new_tokens/eos_id in ``meta``; for
+        {"prompt": ids}, or {"prompt": ids, "media": [frames, ...]} for a
+        spec with a vision tower) with max_new_tokens/eos_id in ``meta``; for
         inference engines it is a per-row feed dict."""
         if self._paused:
             raise EngineClosedError(
@@ -319,6 +326,13 @@ class Server:
                 f"unknown model/tenant {model!r}: this replica serves "
                 + (f"{sorted(self.model_ids)}" if self.model_ids
                    else "one unnamed model"))
+        # an engine with a vision tower checks a payload's media HERE
+        # (``{"prompt": ids, "media": [frames uint8 [F, S, S, 3], ...]}``:
+        # one entry a vision span; a span that is not whole frames, a missing
+        # entry or a wrong shape is refused, typed, and counted); the layout
+        # it made rides the request to admission
+        if self._check_media is not None:
+            meta["media"] = self._check_media(payload)
         fut = self.batcher.submit(payload, timeout_ms=timeout_ms, **meta)
         return self._feedback_tap(fut, payload, model)
 

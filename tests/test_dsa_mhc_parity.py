@@ -124,8 +124,9 @@ def test_the_selection_is_counted_from_the_fed_planes(served):
     assert c["dsa_rows_attended"] <= 16 * c["dsa_queries"]
     assert 0 < c["dsa_dense_queries"] < c["dsa_queries"]
     assert c["dsa_groups_scored"] > 0
-    # (what the indexer's pool costs a token is the spec's to say)
-    assert "mem/index_bytes_per_token" not in g
+    # (what the indexer's pool costs a token: the spec's number, a gauge
+    # since PR 60)
+    assert g["mem/index_bytes_per_token"] == 1 * 8 / 4 * 4
     assert "prefill_attn_pages_read" not in c   # nothing walks a table
 
 

@@ -51,7 +51,11 @@ MODULES = [
      "Model server: dynamic & continuous batching; GenerationEngine(spec, "
      "scope, slots=, page_size=, n_pages=, prefill_chunk=, ..; "
      "snapshot_stride=<pages>, n_snapshots=<rows>: state snapshots, the "
-     "prefix index for a spec whose slots carry a recurrent state)"),
+     "prefix index for a spec whose slots carry a recurrent state; "
+     "media_resolver=fn: a spec with a vision tower takes payloads "
+     "{prompt, media: [frames uint8 [F, S, S, 3], ..]} or resolves a "
+     "vision span at admission; serving.media has the check submit makes "
+     "and the plan admission makes)"),
     ("paddle_tpu.serving.fleet",
      "Multi-replica fleet: retries/hedging, breakers, load shedding, "
      "rolling weight updates"),
@@ -100,7 +104,13 @@ MODULES = [
      "narrow page pool picks the cached tokens a query attends); "
      "residual add | mhc with hc_mult / hc_iters / hc_eps = the "
      "multi-stream (manifold-constrained) residual round every half "
-     "block; ffn_limit = the clamped SwiGLU"),
+     "block; ffn_limit = the clamped SwiGLU; index_topk on a stack of "
+     "full-attention K/V layers too (sparse_kv: index_pool 1, one indexer "
+     "key a token in a third pool); rope mrope with mrope_section = "
+     "three-axis rotary ids; qk_norm_heads = RMSNorm a head; vision = a "
+     "VisionSpec (a tower and a merger in front of the stack, run inside "
+     "the prefill unit; media_layout = where a prompt's clips lie and every "
+     "token's three ids)"),
     ("paddle_tpu.ops.moe_ops",
      "The expert layer: moe_topk (dropless top-k; shared=, held=, "
      "routed_scale=; score= softmax | sigmoid, bias=, n_group=, "
@@ -113,8 +123,7 @@ MODULES = [
      "a row, a verify tick's two folded into one walk: "
      "paged_attention_verify, or a prefill chunk's queries over the pages "
      "they reach: paged_attention_prefill, K/V pools or a latent block's "
-     "one pool, the latent walks under a sparse layer's pick as a group "
-     "mask)"),
+     "one pool, either under a sparse layer's pick as a group mask)"),
     ("paddle_tpu.kernels.grouped_matmul",
      "Pallas grouped matmul for sorted assignment rows: the expert "
      "layer's products in the serving programs, visiting only the (row "

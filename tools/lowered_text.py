@@ -68,6 +68,16 @@ def op_shapes(spec, prefill):
             weights[slot] = (tuple(shape), dt)
         for slot, _, shape, _ in spec.draft_spec().stack_planes():
             weights[DRAFT_SLOT_PREFIX + slot] = ((1, *shape), dt)
+    if spec.rope == "mrope":        # three-axis rotary ids / a slot's offset
+        rows["PosIds" if prefill else "RopeOffset"] = (
+            (b, 3 * CHUNK) if prefill else (b,), "int32")
+    if spec.vision is not None and prefill:     # the tower in the unit
+        v = spec.vision
+        rows["MediaRow"] = ((b, CHUNK), "int32")
+        rows["Pixels"] = ((b, -(-CHUNK // v.tokens_per_frame) + 1)
+                          + v.frame_shape, "uint8")
+        for slot, _, shape, _, _ in spec.vision_planes():
+            weights[slot] = (tuple(shape), dt)
     state = {name: ((layers, SLOTS, *shape), dtype)
              for name, shape, dtype, layers in spec.slot_state()}
     if state and prefill:
@@ -102,7 +112,8 @@ def texts_of(config):
         yield what, jax.jit(step).lower(*[
             jax.ShapeDtypeStruct(*shapes[n]) for n in names]).as_text()
     if not spec.block.attn_kinds and not spec.first_dense \
-            and not spec.draft_block and spec.attn != "mla":
+            and not spec.draft_block and spec.attn != "mla" \
+            and spec.rope != "mrope" and not spec.index_topk:
         import jax.numpy as jnp
 
         blk = spec.block
@@ -191,7 +202,7 @@ def main(paths):
     for path in paths:
         with open(path) as f:
             config = json.load(f)
-        if not str(config.get("family", "")).endswith("_lm"):
+        if not str(config.get("family", "")).endswith(("_lm", "_vl")):
             continue
         pt.set_amp(config.get("amp") == "bfloat16")
         for what, text in texts_of(config):
